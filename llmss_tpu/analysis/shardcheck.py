@@ -177,7 +177,7 @@ def scan_ys_taint(jaxpr, tainted_in: dict[int, str]) -> dict[int, str]:
     equation (conservative) and is cleared by a replicated pin. Returns
     ``{outvar index: hazard description}`` for the jaxpr's outputs.
     """
-    from jax.core import Literal
+    from jax.extend.core import Literal
 
     taint: dict[Any, str] = {}
     for i, v in enumerate(jaxpr.invars):

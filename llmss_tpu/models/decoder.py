@@ -51,9 +51,7 @@ from llmss_tpu.ops.layers import (
     LinearParams, NormParams, dense, dense_t, embedding,
 )
 from llmss_tpu.ops.rope import apply_rope, sin_cos_tables
-from llmss_tpu.parallel.mesh import (
-    AXIS_DP, AXIS_SP, AXIS_TP, shard_map as compat_shard_map,
-)
+from llmss_tpu.parallel.mesh import AXIS_DP, AXIS_SP, AXIS_TP
 from llmss_tpu.parallel.sharding import constrain
 
 
@@ -337,13 +335,16 @@ def _make_decode_kernel_attn(cfg, mesh, cache, positions, slots):
     oracle the kernel is parity-tested against,
     tests/test_pallas_decode.py).
 
-    **Opt-in only** (``LLMSS_ATTN_IMPL=pallas``), never auto-dispatched:
-    measured on v5e at bench scale the kernel is *slower* than the XLA
-    einsum path (6.4 vs 4.25 ms/step) — per-call overhead across 20
+    **Opt-in only** (``LLMSS_ATTN_IMPL=pallas``), never auto-dispatched;
+    shapes or a cache dtype the kernel cannot take raise on TPU
+    (``forced_pallas_miss``). Measured on v5e in round 5 (an earlier JAX;
+    under JAX 0.9.0 the kernel did not compile in bf16 until PR 21 and has
+    not been timed since) it was *slower* than the XLA einsum path (6.4 vs
+    4.25 ms/step) — per-call overhead across 20
     layer invocations and strided per-head VMEM reads outweigh the
     dynamic-slice copy it eliminates. Kept because the scalar-prefetch
     stacked-cache read is the right building block for future paged /
-    quantized cache layouts (see PROFILE.md)."""
+    quantized cache layouts (see PROFILE.md@e57f952)."""
     import importlib
 
     from llmss_tpu.ops import pallas_decode
@@ -362,27 +363,22 @@ def _make_decode_kernel_attn(cfg, mesh, cache, positions, slots):
     kv_shard, heads_ok, kv_ax = attention_mod.tp_head_plan(Hq, Hkv, tp)
     local_Hq = Hq // tp
     local_Hkv = Hkv // tp if kv_shard else Hkv
-    if sp != 1 or B % dp or not heads_ok or not pallas_decode.supports(
-        T, local_Hq, local_Hkv, D
+    # The kernel reads the cache as values: an int8 cache's scales have no
+    # way in, so a quantized cache is out of envelope like a bad shape.
+    if cache.quantized or sp != 1 or B % dp or not heads_ok or not (
+        pallas_decode.supports(T, local_Hq, local_Hkv, D, cache.k.dtype)
     ):
-        # The pallas override keeps its documented graceful fallback
-        # (prefill may still use the flash kernel while decode shapes are
-        # out of envelope) — but say so, or an A/B run silently measures
-        # the XLA path.
-        import warnings
-
-        warnings.warn(
-            "LLMSS_ATTN_IMPL=pallas: decode shapes out of the stacked-cache "
-            f"kernel envelope (sp={sp}, B={B}, dp={dp}, T={T}, Hq={Hq}, "
-            f"Hkv={Hkv}, D={D}); decode runs the XLA path",
-            stacklevel=2,
+        attention_mod.forced_pallas_miss(
+            "decode shapes out of the stacked-cache kernel envelope "
+            f"(sp={sp}, B={B}, dp={dp}, T={T}, Hq={Hq}, Hkv={Hkv}, D={D}, "
+            f"{cache.k.dtype})"
         )
         return None
     qs = P(AXIS_DP, None, AXIS_TP, None)
     ks = P(None, AXIS_DP, None, kv_ax, None)
     kns = P(AXIS_DP, None, kv_ax, None)
     ps = P(AXIS_DP, None)
-    interp = jax.default_backend() != "tpu"
+    interp = attention_mod.pallas_interpret()
 
     def local(q, kc, vc, kn, vn, qp, kvp, sl, layer):
         return pallas_decode.decode_attention(
@@ -391,7 +387,7 @@ def _make_decode_kernel_attn(cfg, mesh, cache, positions, slots):
             interpret=interp,
         )
 
-    sharded = compat_shard_map(
+    sharded = jax.shard_map(
         local, mesh=mesh,
         in_specs=(qs, ks, ks, kns, kns, ps, ps, ps, P()),
         out_specs=qs, check_vma=False,
@@ -439,7 +435,7 @@ def _make_sp_decode_attn(cfg, mesh, cache, positions, slots):
             scale=cfg.attn_scale, window=cfg.sliding_window,
         )
 
-    sharded = compat_shard_map(
+    sharded = jax.shard_map(
         local, mesh=mesh,
         in_specs=(qs, ks, ks, ps, P(AXIS_DP, AXIS_SP), kns, kns, ps),
         out_specs=qs, check_vma=False,
@@ -535,7 +531,8 @@ def forward(
     ``t_bucket`` (static) bounds the decode attention's cache read to ring
     slots ``[0, t_bucket)``: KV-read HBM traffic scales with *live* context,
     not the provisioned ring size (the decode step is bandwidth-bound, so a
-    quarter-full cache decodes measurably faster — PROFILE.md). Writes still
+    quarter-full cache decodes measurably faster — round 5,
+    PROFILE.md@e57f952). Writes still
     land in the full buffer. **Caller contract** (DecodeEngine.decode_bucket
     enforces it): every live slot (position >= 0) of every row, and every
     slot written this call, is < ``t_bucket`` — i.e. no row has ring-wrapped
@@ -596,14 +593,14 @@ def forward(
 
     quant = cache.quantized
     if defer_write:
-        kernel_attn = None if (quant or S > 1) else _make_decode_kernel_attn(
+        kernel_attn = None if S > 1 else _make_decode_kernel_attn(
             cfg, mesh, cache, positions, slots
         )
         if kernel_attn is not None and _ablate is None:
             # Stacked-cache Pallas path: the scan carries only params + the
             # layer index; the kernel's block DMAs read the layer's KV
             # directly from the stacked buffer (no per-layer dynamic-slice
-            # copy — PROFILE.md's 0.5 ms/step sink).
+            # copy — the round-5 profile's 0.5 ms/step sink).
             def body(h, xs):
                 bp, layer = xs
                 h, k_f, v_f = _block(
@@ -804,10 +801,11 @@ def _make_paged_kernel_attn(cfg, mesh, cache, positions, slots, nblk):
     stays the implementation and the parity oracle.
 
     Same opt-in contract as the dense kernel: only under
-    ``LLMSS_ATTN_IMPL=pallas``, with a warning fallback when shapes leave
-    the kernel envelope so A/B runs never silently measure the XLA path.
-    The pool rides replicated over dp (block indices are global — see
-    ``paged_cache_specs``) while q/fresh-KV/tables shard over dp as usual.
+    ``LLMSS_ATTN_IMPL=pallas``; shapes outside the kernel envelope raise on
+    TPU (``forced_pallas_miss``) so an A/B run never measures the XLA path
+    under the kernel's name. The pool rides replicated over dp (block
+    indices are global — see ``paged_cache_specs``) while q/fresh-KV/tables
+    shard over dp as usual.
     """
     import importlib
 
@@ -825,23 +823,22 @@ def _make_paged_kernel_attn(cfg, mesh, cache, positions, slots, nblk):
     kv_shard, heads_ok, kv_ax = attention_mod.tp_head_plan(Hq, Hkv, tp)
     local_Hq = Hq // tp
     local_Hkv = Hkv // tp if kv_shard else Hkv
-    if sp != 1 or B % dp or not heads_ok or not pallas_paged_decode.supports(
-        cache.block_size, local_Hq, local_Hkv, D
+    if cache.quantized or sp != 1 or B % dp or not heads_ok or not (
+        pallas_paged_decode.supports(
+            cache.block_size, local_Hq, local_Hkv, D, cache.k.dtype
+        )
     ):
-        import warnings
-
-        warnings.warn(
-            "LLMSS_ATTN_IMPL=pallas: shapes out of the paged decode kernel "
-            f"envelope (sp={sp}, B={B}, dp={dp}, bs={cache.block_size}, "
-            f"Hq={Hq}, Hkv={Hkv}, D={D}); decode runs the XLA gather path",
-            stacklevel=2,
+        attention_mod.forced_pallas_miss(
+            "shapes out of the paged decode kernel envelope "
+            f"(sp={sp}, B={B}, dp={dp}, bs={cache.block_size}, Hq={Hq}, "
+            f"Hkv={Hkv}, D={D}, {cache.k.dtype})"
         )
         return None
     qs = P(AXIS_DP, None, AXIS_TP, None)
     pool_s = P(None, None, None, kv_ax, None)
     kns = P(AXIS_DP, None, kv_ax, None)
     ps = P(AXIS_DP, None)
-    interp = jax.default_backend() != "tpu"
+    interp = attention_mod.pallas_interpret()
 
     def local(q, kp, vp, kn, vn, qp, kvp, bt, nb, sl, layer):
         return pallas_paged_decode.paged_decode_attention(
@@ -850,7 +847,7 @@ def _make_paged_kernel_attn(cfg, mesh, cache, positions, slots, nblk):
             interpret=interp,
         )
 
-    sharded = compat_shard_map(
+    sharded = jax.shard_map(
         local, mesh=mesh,
         in_specs=(
             qs, pool_s, pool_s, kns, kns, ps, ps, ps, P(AXIS_DP), ps, P()
@@ -933,7 +930,7 @@ def _forward_paged(
         kv_pos_src = cache.positions[:, :Tv]
 
         kernel_attn = None
-        if not quant and _ablate is None:
+        if _ablate is None:
             occ = jnp.sum(
                 (cache.positions >= 0).astype(jnp.int32), axis=1
             )
@@ -1092,8 +1089,8 @@ def _make_ragged_kernel_attn(
     and the parity oracle.
 
     Same opt-in contract as the paged decode kernel: only under
-    ``LLMSS_ATTN_IMPL=pallas``, with a warning fallback when shapes leave
-    the kernel envelope so A/B runs never silently measure the XLA path.
+    ``LLMSS_ATTN_IMPL=pallas``; shapes outside the kernel envelope raise on
+    TPU (``forced_pallas_miss``).
     """
     import importlib
 
@@ -1111,17 +1108,17 @@ def _make_ragged_kernel_attn(
     kv_shard, heads_ok, kv_ax = attention_mod.tp_head_plan(Hq, Hkv, tp)
     local_Hq = Hq // tp
     local_Hkv = Hkv // tp if kv_shard else Hkv
-    if sp != 1 or B % dp or not heads_ok or not pallas_ragged.supports(
-        cache.block_size, local_Hq, local_Hkv, D
+    # (The kernel itself takes int8 scales — tests/test_ragged.py — but
+    # this dispatch does not pass them, so a quantized pool is refused.)
+    if cache.quantized or sp != 1 or B % dp or not heads_ok or not (
+        pallas_ragged.supports(
+            cache.block_size, local_Hq, local_Hkv, D, cache.k.dtype
+        )
     ):
-        import warnings
-
-        warnings.warn(
-            "LLMSS_ATTN_IMPL=pallas: shapes out of the ragged mixed-batch "
-            f"kernel envelope (sp={sp}, B={B}, dp={dp}, "
-            f"bs={cache.block_size}, Hq={Hq}, Hkv={Hkv}, D={D}); mixed "
-            "batches run the XLA gather path",
-            stacklevel=2,
+        attention_mod.forced_pallas_miss(
+            "shapes out of the ragged mixed-batch kernel envelope "
+            f"(sp={sp}, B={B}, dp={dp}, bs={cache.block_size}, Hq={Hq}, "
+            f"Hkv={Hkv}, D={D}, {cache.k.dtype})"
         )
         return None
     qs = P(AXIS_DP, None, AXIS_TP, None)
@@ -1129,7 +1126,7 @@ def _make_ragged_kernel_attn(
     kns = P(AXIS_DP, None, kv_ax, None)
     ps = P(AXIS_DP, None)
     row = P(AXIS_DP)
-    interp = jax.default_backend() != "tpu"
+    interp = attention_mod.pallas_interpret()
 
     def local(q, kp, vp, kn, vn, qp, ql, kvp, bt, nb, sl0, layer):
         return pallas_ragged.ragged_paged_attention(
@@ -1138,7 +1135,7 @@ def _make_ragged_kernel_attn(
             interpret=interp,
         )
 
-    sharded = compat_shard_map(
+    sharded = jax.shard_map(
         local, mesh=mesh,
         in_specs=(
             qs, pool_s, pool_s, kns, kns, row, row, ps, ps, row, row, P()
@@ -1218,13 +1215,11 @@ def forward_ragged(
     q_pos0 = positions[:, 0]
     slot0 = slots[:, 0]
 
-    kernel_attn = None
-    if not quant:
-        occ = jnp.sum((cache.positions >= 0).astype(jnp.int32), axis=1)
-        nblk = jnp.clip(-(-occ // bs), 0, MB).astype(jnp.int32)
-        kernel_attn = _make_ragged_kernel_attn(
-            cfg, mesh, cache, q_pos0, q_lens, slot0, nblk
-        )
+    occ = jnp.sum((cache.positions >= 0).astype(jnp.int32), axis=1)
+    nblk = jnp.clip(-(-occ // bs), 0, MB).astype(jnp.int32)
+    kernel_attn = _make_ragged_kernel_attn(
+        cfg, mesh, cache, q_pos0, q_lens, slot0, nblk
+    )
 
     if kernel_attn is not None:
         def body(h, xs):

@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from llmss_tpu.parallel.mesh import shard_map as compat_shard_map
 
 _NEG_INF = float(jnp.finfo(jnp.float32).min)
 
@@ -31,8 +30,41 @@ _NEG_INF = float(jnp.finfo(jnp.float32).min)
 # Env var LLMSS_ATTN_IMPL or set directly (tests force "pallas" to exercise
 # the kernel in interpret mode on CPU). "pallas" disables the sp ring path
 # (the kernel is single-shard: A/B it against "xla" on an sp=1 mesh);
-# "ring" requires an sp>1 mesh.
+# "ring" requires an sp>1 mesh. A forced implementation that cannot take
+# the shapes raises (``forced_pallas_miss``, and the ring check in
+# ``dispatch_attention``): a run under a kernel's name never measures
+# another implementation.
 IMPL_OVERRIDE: str | None = os.environ.get("LLMSS_ATTN_IMPL") or None
+
+
+def pallas_interpret() -> bool:
+    """The one place that decides how a Pallas kernel runs: compiled on
+    TPU, interpreted on CPU (the tests' forced-``pallas`` parity runs).
+    Any other backend has no Pallas TPU path — an error, never a silent
+    interpreter."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas TPU kernels run compiled on tpu or interpreted on cpu; "
+        f"the default backend is {backend!r}"
+    )
+
+
+def forced_pallas_miss(msg: str) -> None:
+    """``LLMSS_ATTN_IMPL=pallas`` named a kernel that cannot take these
+    shapes. Compiled (TPU) that is an error — the XLA path never runs
+    under the kernel's name. Interpreted (the CPU parity tests, which
+    force the override across whole engines at toy widths) the caller
+    continues on the XLA oracle, with a warning saying so."""
+    msg = "LLMSS_ATTN_IMPL=pallas: " + msg
+    if not pallas_interpret():
+        raise ValueError(msg)
+    import warnings
+
+    warnings.warn(msg + "; running the XLA path", stacklevel=3)
 
 
 class force_impl:
@@ -581,8 +613,6 @@ def dispatch_attention(
         if force == "ring" and not sp_ok:
             # A silent fallback would make an A/B run measure the wrong
             # implementation; forcing ring demands a satisfiable sp mesh.
-            # ("pallas" keeps its documented graceful fallback: decode
-            # steps are unsupported by design and must still run.)
             raise ValueError(
                 "LLMSS_ATTN_IMPL=ring requires sp>1, T % sp == 0, "
                 f"B % dp == 0 and shardable heads; got sp={sp}, T={T}, "
@@ -602,25 +632,30 @@ def dispatch_attention(
                 return fn(q, k, v, qp, kvp, axis_name=AXIS_SP, scale=scale,
                           window=window)
 
-            return compat_shard_map(
+            return jax.shard_map(
                 local_sp, mesh=mesh,
                 in_specs=(qs, ks, ks, P(AXIS_DP, q_seq_ax),
                           P(AXIS_DP, AXIS_SP)),
                 out_specs=qs, check_vma=False,
             )(q, k, v, q_positions, kv_positions)
 
-        pallas_ok = (
+        shapes_ok = (
             sp == 1
             and B % dp == 0
             and heads_ok
             and pallas_attention.supports(S, T, Hq, Hkv)
-            and (force == "pallas" or jax.default_backend() == "tpu")
         )
-        if pallas_ok:
+        if force == "pallas" and S > 1 and not shapes_ok:
+            forced_pallas_miss(
+                "prefill shapes out of the flash kernel envelope "
+                f"(sp={sp}, B={B}, dp={dp}, S={S}, T={T}, Hq={Hq}, "
+                f"Hkv={Hkv}, tp={tp})"
+            )
+        if shapes_ok and (force == "pallas" or not pallas_interpret()):
             qs = P(AXIS_DP, None, AXIS_TP, None)
             ks = P(AXIS_DP, None, kv_ax, None)
             ps = P(AXIS_DP, None)
-            interp = jax.default_backend() != "tpu"
+            interp = pallas_interpret()
 
             def local(q, k, v, qp, kvp):
                 return pallas_attention.flash_attention(
@@ -628,7 +663,7 @@ def dispatch_attention(
                     interpret=interp,
                 )
 
-            return compat_shard_map(
+            return jax.shard_map(
                 local, mesh=mesh, in_specs=(qs, ks, ks, ps, ps),
                 out_specs=qs, check_vma=False,
             )(q, k, v, q_positions, kv_positions)

@@ -4,10 +4,11 @@ Why this kernel exists: the decode step scans blocks over layer-stacked
 parameters and cache. XLA aliases the *weight* slices into their dots, but
 it materializes each layer's KV slice — a ``dynamic_slice`` copying the
 full ``[B, T, Hkv, D]`` layer (33 MB at bench scale) every layer every
-step, measured at ~0.5 ms of the ~4.3 ms step (PROFILE.md). This kernel
-takes the whole stacked cache ``[L, B, T, Hkv, D]`` plus the layer index as
-a **scalar-prefetch** argument, so the block DMAs read the layer's KV
-directly from the stacked buffer in HBM — the copy disappears.
+step, measured at ~0.5 ms of the ~4.3 ms step (round 5,
+PROFILE.md@e57f952). This kernel takes the whole stacked cache
+``[L, B, T, Hkv, D]`` plus the layer index as a **scalar-prefetch**
+argument, so the block DMAs read the layer's KV directly from the stacked
+buffer in HBM — the copy disappears.
 
 Semantics are identical to ``ops.attention.fresh_kv_decode_attention``
 (the XLA path, kept as the CPU/fallback implementation and the parity
@@ -41,11 +42,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# jax 0.4.x names this TPUCompilerParams; newer releases renamed it.
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
 
 _NEG_INF = float(jnp.finfo(jnp.float32).min)
 
@@ -137,10 +133,7 @@ def _kernel(
             qh = q_ref[0, r, :]  # [G, D]
             kn = kn_ref[0, h:h + 1, :]  # [1, D]
             vn = vn_ref[0, h:h + 1, :]
-            s_new = jax.lax.dot_general(
-                qh, kn, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale  # [G, 1]
+            s_new = fresh_key_score(qh, kn) * scale  # [G, 1]
             m_prev = m_ref[r, :1]
             m_next = jnp.maximum(m_prev, s_new)
             alpha = jnp.exp(m_prev - m_next)
@@ -150,27 +143,64 @@ def _kernel(
             o_ref[0, r, :] = (acc / l).astype(o_ref.dtype)
 
 
-def _pick_block_k(T: int, block_k: int = 512) -> int | None:
-    """Largest legal KV chunk: divides T and is lane-aligned (%128) unless
-    it covers T outright."""
-    if T <= block_k:
+def fresh_key_score(qh: jax.Array, kn: jax.Array) -> jax.Array:
+    """``qh [R, D] · kn [1, D] -> [R, 1]`` in fp32 on the VPU. Spelled as a
+    ``dot_general`` with one output column, Mosaic lowers it to a
+    broadcast-multiply whose bf16 operand reaches an f32 ``vector.broadcast``
+    and fails verification (v5e, JAX 0.9.0); the explicit upcast is the
+    same arithmetic and lowers in every dtype."""
+    return jnp.sum(
+        qh.astype(jnp.float32) * kn.astype(jnp.float32), axis=1,
+        keepdims=True,
+    )
+
+
+# VMEM the pipelined K and V chunk buffers may take together. The
+# compiler's scoped default on v5e is 16 MiB for the whole kernel; half of
+# it leaves room for q, the output, the accumulators and the score
+# temporaries.
+_KV_VMEM_BUDGET = 8 << 20
+
+
+def kv_block_vmem_bytes(rows: int, Hkv: int, D: int, dtype) -> int:
+    """VMEM held by the K and V buffers of one ``(rows, Hkv, D)`` block
+    pair: two arrays, double-buffered by the pipeline, with the head axis
+    padded to the dtype's sublane tile (8 rows of 32 bits: 8 for f32, 16
+    for bf16, 32 for int8) and D to the 128 lanes — so an MQA block
+    (Hkv = 1) occupies a full tile per slot."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sublane = 8 * (4 // itemsize)
+    hkv_pad = -(-Hkv // sublane) * sublane
+    d_pad = -(-D // 128) * 128
+    return 4 * rows * hkv_pad * d_pad * itemsize
+
+
+def _pick_block_k(
+    T: int, Hkv: int, D: int, dtype, block_k: int = 512
+) -> int | None:
+    """Largest legal KV chunk: divides T, is lane-aligned (%128) unless it
+    covers T outright, and its K/V buffers fit ``_KV_VMEM_BUDGET``."""
+    def fits(bk):
+        return kv_block_vmem_bytes(bk, Hkv, D, dtype) <= _KV_VMEM_BUDGET
+
+    if T <= block_k and fits(T):
         return T
     bk = block_k
     while bk >= 128:
-        if T % bk == 0 and bk % 128 == 0:
+        if T % bk == 0 and fits(bk):
             return bk
         bk //= 2
     return None
 
 
-def supports(T: int, Hq: int, Hkv: int, D: int) -> bool:
+def supports(T: int, Hq: int, Hkv: int, D: int, dtype) -> bool:
     """Shape envelope the kernel handles (else the caller stays on the XLA
     ``fresh_kv_decode_attention`` path)."""
     return (
         Hq % Hkv == 0
         and T % 8 == 0
         and D % 128 == 0
-        and _pick_block_k(T) is not None
+        and _pick_block_k(T, Hkv, D, dtype) is not None
     )
 
 
@@ -204,7 +234,7 @@ def decode_attention(
     L, _, T, Hkv, _ = k_cache.shape
     if scale is None:
         scale = 1.0 / (D**0.5)
-    bk = _pick_block_k(T, block_k)
+    bk = _pick_block_k(T, Hkv, D, k_cache.dtype, block_k)
     assert bk is not None, f"unsupported T={T} (see supports())"
 
     grid = (B, T // bk)
@@ -253,7 +283,7 @@ def decode_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -35,9 +35,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x names this TPUCompilerParams; newer releases renamed it.
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
+from .pallas_decode import (
+    _KV_VMEM_BUDGET, fresh_key_score, kv_block_vmem_bytes,
 )
 
 _NEG_INF = float(jnp.finfo(jnp.float32).min)
@@ -49,7 +48,7 @@ def _kernel(
     slot_ref,  # [B] int32 scalar-prefetch — LOGICAL slot the token takes
     nblk_ref,  # [B] int32 scalar-prefetch — occupied blocks per row
     bt_ref,  # [B*MB] int32 scalar-prefetch — flattened clamped block table
-    kvp_ref,  # [1, 1, bs] int32 — positions of this logical block's slots
+    kvp_ref,  # [1, 1, 1, bs] int32 — positions of this logical block's slots
     q_ref,  # [1, Hq, D]
     k_ref,  # [1, 1, bs, Hkv, D] — one pool block, all heads
     v_ref,  # [1, 1, bs, Hkv, D]
@@ -78,7 +77,7 @@ def _kernel(
 
     qp = qp_ref[b]  # scalar
     slot = slot_ref[b]  # scalar (logical)
-    kvp = kvp_ref[0, 0, :]  # [bs]
+    kvp = kvp_ref[0, 0, 0, :]  # [bs]
     slot_idx = j * block_size + jax.lax.broadcasted_iota(
         jnp.int32, (1, block_size), 1
     )[0]
@@ -133,10 +132,7 @@ def _kernel(
             qh = q_ref[0, r, :]  # [G, D]
             kn = kn_ref[0, h:h + 1, :]  # [1, D]
             vn = vn_ref[0, h:h + 1, :]
-            s_new = jax.lax.dot_general(
-                qh, kn, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale  # [G, 1]
+            s_new = fresh_key_score(qh, kn) * scale  # [G, 1]
             m_prev = m_ref[r, :1]
             m_next = jnp.maximum(m_prev, s_new)
             alpha = jnp.exp(m_prev - m_next)
@@ -146,11 +142,18 @@ def _kernel(
             o_ref[0, r, :] = (acc / l).astype(o_ref.dtype)
 
 
-def supports(block_size: int, Hq: int, Hkv: int, D: int) -> bool:
+def supports(block_size: int, Hq: int, Hkv: int, D: int, dtype) -> bool:
     """Shape envelope the kernel handles (else the caller stays on the XLA
     gather path). Per-block DMAs need sublane-aligned block_size and a
-    lane-aligned head dim."""
-    return Hq % Hkv == 0 and block_size % 8 == 0 and D % 128 == 0
+    lane-aligned head dim, and the pipelined K/V block buffers must fit
+    the VMEM budget."""
+    return (
+        Hq % Hkv == 0
+        and block_size % 8 == 0
+        and D % 128 == 0
+        and kv_block_vmem_bytes(block_size, Hkv, D, dtype)
+        <= _KV_VMEM_BUDGET
+    )
 
 
 @functools.partial(
@@ -204,9 +207,14 @@ def paged_decode_attention(
             num_scalar_prefetch=5,
             grid=grid,
             in_specs=[
+                # [B, MB, 1, bs]: the unit axis makes the block's last two
+                # dims equal the array's — a (1, bs) block of a [MB, bs]
+                # plane is not a legal TPU tile.
                 pl.BlockSpec(
-                    (1, 1, bs),
-                    lambda b, j, lr, qp, sl, nb, bt: (b, _col(j, nb, b), 0),
+                    (1, 1, 1, bs),
+                    lambda b, j, lr, qp, sl, nb, bt: (
+                        b, _col(j, nb, b), 0, 0
+                    ),
                 ),
                 pl.BlockSpec(
                     (1, Hq, D), lambda b, j, *_: (b, 0, 0),
@@ -246,7 +254,7 @@ def paged_decode_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -256,7 +264,7 @@ def paged_decode_attention(
         slots.astype(jnp.int32).reshape(B),
         nblk,
         bt_flat,
-        kv_pos.astype(jnp.int32).reshape(B, MB, bs),
+        kv_pos.astype(jnp.int32).reshape(B, MB, 1, bs),
         q.reshape(B, Hq, D),
         k_pool, v_pool,
         k_new.reshape(B, Hkv, D),

@@ -49,12 +49,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .pallas_decode import fresh_key_score
 from .pallas_paged_decode import supports  # noqa: F401  (same envelope)
-
-# jax 0.4.x names this TPUCompilerParams; newer releases renamed it.
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
 
 _NEG_INF = float(jnp.finfo(jnp.float32).min)
 
@@ -66,7 +62,7 @@ def _kernel(
     slot_ref,  # [B] int32 scalar-prefetch — LOGICAL slot of the first query
     nblk_ref,  # [B] int32 scalar-prefetch — occupied blocks per row
     bt_ref,  # [B*MB] int32 scalar-prefetch — flattened clamped block table
-    kvp_ref,  # [1, 1, bs] int32 — positions of this logical block's slots
+    kvp_ref,  # [1, 1, 1, bs] int32 — positions of this logical block's slots
     q_ref,  # [1, CB, Hq, D]
     k_ref,  # [1, 1, bs, Hkv, D] — one pool block, all heads
     v_ref,  # [1, 1, bs, Hkv, D]
@@ -98,7 +94,7 @@ def _kernel(
     qp = qp_ref[b]  # scalar — position of query row 0
     qlen = qlen_ref[b]  # scalar
     slot0 = slot_ref[b]  # scalar (logical)
-    kvp = kvp_ref[0, 0, :]  # [bs]
+    kvp = kvp_ref[0, 0, 0, :]  # [bs]
     slot_idx = j * block_size + jax.lax.broadcasted_iota(
         jnp.int32, (1, block_size), 1
     )[0]
@@ -188,10 +184,7 @@ def _kernel(
             for jj in range(CB):
                 kn = kn_ref[0, jj, h:h + 1, :]  # [1, D]
                 vn = vn_ref[0, jj, h:h + 1, :]
-                s_new = jax.lax.dot_general(
-                    qh, kn, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                ) * scale  # [CB*G, 1]
+                s_new = fresh_key_score(qh, kn) * scale  # [CB*G, 1]
                 vis = (jj <= row_q1) & (jj < qlen_b)
                 if window is not None:
                     vis &= (row_q1 - jj) < window
@@ -278,9 +271,10 @@ def ragged_paged_attention(
         )
 
     in_specs = [
+        # [B, MB, 1, bs] — see pallas_paged_decode.py for the unit axis.
         pl.BlockSpec(
-            (1, 1, bs),
-            lambda b, j, lr, qp, ql, sl, nb, bt: (b, _col(j, nb, b), 0),
+            (1, 1, 1, bs),
+            lambda b, j, lr, qp, ql, sl, nb, bt: (b, _col(j, nb, b), 0, 0),
         ),
         pl.BlockSpec(
             (1, CB, Hq, D), lambda b, j, *_: (b, 0, 0, 0),
@@ -290,7 +284,7 @@ def ragged_paged_attention(
         _pool_spec(),
     ]
     operands = [
-        kv_pos.astype(jnp.int32).reshape(B, MB, bs),
+        kv_pos.astype(jnp.int32).reshape(B, MB, 1, bs),
         q.reshape(B, CB, Hq, D),
         k_pool, v_pool,
     ]
@@ -332,7 +326,7 @@ def ragged_paged_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, CB, Hq, D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

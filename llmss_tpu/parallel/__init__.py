@@ -16,7 +16,6 @@ from llmss_tpu.parallel.mesh import (
     default_compute_dtype,
     initialize_runtime,
     make_mesh,
-    shard_map,
 )
 
 __all__ = [
@@ -27,5 +26,4 @@ __all__ = [
     "default_compute_dtype",
     "initialize_runtime",
     "make_mesh",
-    "shard_map",
 ]

@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+from pathlib import Path
 from typing import Sequence
 
 import jax
@@ -40,6 +41,12 @@ AXIS_TP = "tp"
 # Mesh axis order: dp outermost (rides DCN across slices), then sp, then tp
 # innermost so TP collectives map onto the fastest ICI links.
 AXIS_ORDER = (AXIS_DP, AXIS_SP, AXIS_TP)
+
+# Default home of the persistent compile cache (ignored by git); see
+# ``initialize_runtime``.
+COMPILE_CACHE_DIR = str(
+    Path(__file__).resolve().parents[2] / ".jax_compile_cache"
+)
 
 _initialized = False
 
@@ -63,24 +70,16 @@ def initialize_runtime(
     if _initialized:
         return
     # Persistent XLA compilation cache: the serving prewarm compiles the
-    # whole executable envelope (~2 min at 1B scale); with the cache a
-    # restarted worker reloads those executables in seconds instead of
-    # recompiling. Opt out with LLMSS_COMPILE_CACHE=0 or point it
-    # elsewhere with LLMSS_COMPILE_CACHE=/path.
-    cache_dir = os.environ.get("LLMSS_COMPILE_CACHE")
-    if cache_dir != "0":
-        if not cache_dir:
-            cache_dir = os.path.join(
-                os.path.expanduser("~"), ".cache", "llmss_tpu", "xla"
-            )
-        try:
-            os.makedirs(cache_dir, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 1.0
-            )
-        except Exception:  # noqa: BLE001 — cache is an optimization only
-            pass
+    # whole executable envelope; with the cache a restarted worker reloads
+    # those executables instead of recompiling. Where the operator placed
+    # it (JAX_COMPILATION_CACHE_DIR) JAX already reads the variable and no
+    # directory is set here. Otherwise it lives at one fixed path inside
+    # the checkout — the path is part of the cache key, so a directory
+    # that moves (home, temp name, pid) never hits.
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        os.makedirs(COMPILE_CACHE_DIR, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
     explicit = coordinator_address is not None or num_processes is not None
     in_multiprocess_env = explicit or os.environ.get("JAX_COORDINATOR_ADDRESS")
     if in_multiprocess_env:
@@ -140,37 +139,14 @@ def make_mesh(
     # propagates and inserts collectives. (JAX 0.9's default is the new
     # Explicit sharding-in-types mode, which requires per-op out_sharding
     # annotations; Auto is the mature path MaxText-class frameworks use.)
-    # Older JAX (< 0.5) predates AxisType entirely — Auto is its only
-    # mode, so simply omit the kwarg there instead of crashing at import.
-    axis_type_kw: dict = {}
-    if hasattr(jax.sharding, "AxisType"):
-        axis_type_kw["axis_types"] = (
-            jax.sharding.AxisType.Auto,
-        ) * len(AXIS_ORDER)
+    axis_types = (jax.sharding.AxisType.Auto,) * len(AXIS_ORDER)
     if devices is None:
         devices = jax.devices()
         dp, sp, tp = plan.resolve(len(devices))
-        return jax.make_mesh((dp, sp, tp), AXIS_ORDER, **axis_type_kw)
+        return jax.make_mesh((dp, sp, tp), AXIS_ORDER, axis_types=axis_types)
     dp, sp, tp = plan.resolve(len(devices))
     arr = np.asarray(devices, dtype=object).reshape(dp, sp, tp)
-    return Mesh(arr, AXIS_ORDER, **axis_type_kw)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-portable ``shard_map``: ``jax.shard_map`` (0.5+, ``check_vma``)
-    or ``jax.experimental.shard_map`` (0.4.x, where the same knob is spelled
-    ``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )
+    return Mesh(arr, AXIS_ORDER, axis_types=axis_types)
 
 
 def default_compute_dtype() -> jnp.dtype:

@@ -4,14 +4,13 @@ Two seeding paths, both ending in the same five knobs:
 
 - **table**: explicit per-op seconds (``prefill_token_s``,
   ``decode_step_s``, ...) — what the migrated bench tools use so their
-  receipts stay numerically comparable with the pre-sim trajectories in
-  TREND.json.
+  receipts stay numerically comparable with their pre-sim runs.
 - **devtel**: derived from the device-telemetry roofline (PR 15) — peak
   FLOPS / HBM bandwidth from :func:`devtel.device_peaks` (or a
   CostTable entry priced by XLA's ``cost_analysis``) pushed through
   :func:`devtel.roofline_seconds`, so sim time and real MFU/MBU
   accounting share one model. Peaks resolve deterministically (env
-  overrides, else device_kind table, else the v5e row on CPU), which
+  overrides, else the device_kind table, whose "cpu" row serves CPU), which
   keeps devtel-seeded scenarios byte-replayable.
 
 KV block accounting lives here too (``kv_blocks``): replicas charge and

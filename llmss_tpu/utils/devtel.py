@@ -43,8 +43,9 @@ be disabled independently with ``LLMSS_DEVTEL=0``; the enabled fast path
 adds one attribute check per call site. MFU is computed against the
 device peaks in :data:`DEVICE_PEAKS` (override with ``DEVTEL_PEAK_TFLOPS``
 / ``DEVTEL_HBM_GBPS``); on a CPU backend the analytical numbers are
-roofline-shaped but the peaks are the v5e defaults, so absolute MFU/MBU
-values are only meaningful on real accelerators (docs/observability.md).
+roofline-shaped but priced by the table's "cpu" row (the v5e figures), so
+absolute MFU/MBU values are only meaningful on real accelerators
+(docs/observability.md).
 """
 
 from __future__ import annotations
@@ -72,17 +73,21 @@ UTIL_BOUNDS = (
     0.2, 0.3, 0.4, 0.5, 0.6, 0.7, 0.8, 0.9, 1.0,
 )
 
-# device_kind substring -> (peak dense TFLOP/s bf16, HBM GB/s). Matched
-# case-insensitively against jax.devices()[0].device_kind; unmatched
-# backends (CPU included) fall back to the v5e row so CPU functional runs
-# still produce roofline-SHAPED numbers (see module docstring caveat).
+# device_kind -> (peak dense TFLOP/s bf16, HBM GB/s), keyed by the string
+# ``jax.devices()[0].device_kind`` reports (Google Cloud TPU documentation
+# per generation; v5e is "TPU v5 lite", v5p plain "TPU v5", v6e "TPU v6
+# lite" — checked against ``topologies.get_topology_desc``). A TPU that is
+# not in the table is an error, not a default. The "cpu" row is the CPU
+# backend's own: not a CPU's peaks but the v5e figures, so functional runs
+# keep producing finite, roofline-SHAPED gauges (see module docstring
+# caveat) — nothing priced by it is a device metric.
 DEVICE_PEAKS = {
-    "v6e": (918.0, 1640.0),
-    "v5p": (459.0, 2765.0),
-    "v5e": (197.0, 819.0),
-    "v4": (275.0, 1228.0),
+    "TPU v6 lite": (918.0, 1640.0),
+    "TPU v5": (459.0, 2765.0),
+    "TPU v5 lite": (197.0, 819.0),
+    "TPU v4": (275.0, 1228.0),
+    "cpu": (197.0, 819.0),
 }
-_DEFAULT_PEAKS = DEVICE_PEAKS["v5e"]
 
 # How many compile events / counter samples one process retains.
 MAX_COMPILE_EVENTS = 512
@@ -112,23 +117,21 @@ def device_peaks() -> tuple[float, float]:
 
     Env overrides win (``DEVTEL_PEAK_TFLOPS`` / ``DEVTEL_HBM_GBPS`` —
     the latter intentionally shares units with bench.py's
-    ``BENCH_HBM_GBPS``); otherwise the device_kind is matched against
-    :data:`DEVICE_PEAKS`.
+    ``BENCH_HBM_GBPS``); otherwise the device_kind is looked up in
+    :data:`DEVICE_PEAKS`, and an unknown device is an error.
     """
     global _PEAKS
     if _PEAKS is not None:
         return _PEAKS
-    tf, gb = _DEFAULT_PEAKS
-    try:
-        import jax
+    import jax
 
-        kind = jax.devices()[0].device_kind.lower()
-        for sub, peaks in DEVICE_PEAKS.items():
-            if sub in kind:
-                tf, gb = peaks
-                break
-    except Exception:  # no backend yet: keep defaults, stay lazy-safe
-        pass
+    kind = jax.devices()[0].device_kind
+    if kind not in DEVICE_PEAKS:
+        raise KeyError(
+            f"no peaks for device_kind {kind!r} in devtel.DEVICE_PEAKS "
+            f"(known: {sorted(DEVICE_PEAKS)}); add its row with a source"
+        )
+    tf, gb = DEVICE_PEAKS[kind]
     tf = float(os.environ.get("DEVTEL_PEAK_TFLOPS", tf))
     gb = float(os.environ.get(
         "DEVTEL_HBM_GBPS", os.environ.get("BENCH_HBM_GBPS", gb),

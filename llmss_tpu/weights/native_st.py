@@ -17,6 +17,7 @@ can't be built, reads fall back to ``np.memmap`` with identical semantics.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import json
 import os
 import struct
@@ -52,24 +53,31 @@ _NATIVE_DIR = Path(__file__).resolve().parent.parent / "native"
 _LIB_LOCK = threading.Lock()
 _LIB: ctypes.CDLL | None = None
 _LIB_FAILED = False
+_LIB_ERROR = ""  # why the build/load failed, for require_native()
 
 
 def _build_lib() -> ctypes.CDLL | None:
     """Compile-and-cache llmss_tpu/native/st_gather.cc → .../build/.
 
+    The built library is named by a hash of the source it was compiled
+    from, so a binary never outlives its source (a checkout copied with
+    its ignored build directory, a file whose mtime moved backwards).
+
     Returns None (→ single-threaded memmap fallback, with a one-time
-    warning) if no toolchain is available or the build fails. The compile
-    goes to a temp file then ``os.replace`` — atomic, so concurrent
-    processes never load a half-written .so or truncate one that another
-    process has mapped."""
-    global _LIB, _LIB_FAILED
+    warning) if no toolchain is available or the build fails; callers
+    that must not run on the fallback call ``require_native()``. The
+    compile goes to a temp file then ``os.replace`` — atomic, so
+    concurrent processes never load a half-written .so or truncate one
+    that another process has mapped."""
+    global _LIB, _LIB_FAILED, _LIB_ERROR
     with _LIB_LOCK:
         if _LIB is not None or _LIB_FAILED:
             return _LIB
         src = _NATIVE_DIR / "st_gather.cc"
-        so = _NATIVE_DIR / "build" / "libstgather.so"
         try:
-            if not so.exists() or so.stat().st_mtime < src.stat().st_mtime:
+            digest = hashlib.sha256(src.read_bytes()).hexdigest()[:16]
+            so = _NATIVE_DIR / "build" / f"libstgather-{digest}.so"
+            if not so.exists():
                 so.parent.mkdir(parents=True, exist_ok=True)
                 fd, tmp = tempfile.mkstemp(
                     suffix=".so", dir=str(so.parent)
@@ -98,13 +106,22 @@ def _build_lib() -> ctypes.CDLL | None:
             _LIB = lib
         except Exception as e:  # noqa: BLE001 — build/load failure → fallback
             _LIB_FAILED = True
+            _LIB_ERROR = f"{type(e).__name__}: {e}"
             warnings.warn(
-                f"native st_gather unavailable ({type(e).__name__}: {e}); "
+                f"native st_gather unavailable ({_LIB_ERROR}); "
                 "weight reads fall back to single-threaded memmap",
                 RuntimeWarning,
                 stacklevel=2,
             )
         return _LIB
+
+
+def require_native() -> None:
+    """Raise unless the C++ gather serves this process's reads — for
+    entry points (chip_smoke.py) where the memmap fallback would hide a
+    toolchain or build fault behind a slower load."""
+    if _build_lib() is None:
+        raise RuntimeError(f"native st_gather unavailable: {_LIB_ERROR}")
 
 
 class NativeSafetensors:

@@ -2,8 +2,8 @@
 
 The decode step reads only ring slots ``[0, t_bucket)`` when the engine can
 prove no row has (or will) wrap past the bucket — the throughput lever that
-makes a generously provisioned ring free (PROFILE.md). These tests pin the
-semantics: bucketed and full-ring decode produce *bitwise identical* logits
+makes a generously provisioned ring free (round 5, PROFILE.md@e57f952).
+These tests pin the semantics: bucketed and full-ring decode produce *bitwise identical* logits
 (masked slots contribute exact zeros to every reduction), the bucket policy
 refuses wrapped rows, and the whole serving envelope stays single-compile.
 """

@@ -6,7 +6,6 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from llmss_tpu.parallel import AXIS_DP, AXIS_TP, MeshPlan, make_mesh
-from llmss_tpu.parallel.mesh import shard_map as compat_shard_map
 
 
 def test_default_plan_is_all_tp(devices):
@@ -33,7 +32,7 @@ def test_psum_over_tp_axis(devices):
     def f(x):
         return jax.lax.psum(x, AXIS_TP)
 
-    y = compat_shard_map(
+    y = jax.shard_map(
         f, mesh=mesh, in_specs=P(AXIS_TP), out_specs=P()
     )(x)
     assert y.shape == (1,)

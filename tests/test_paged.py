@@ -399,7 +399,7 @@ def test_pallas_paged_kernel_matches_xla_oracle(devices):
     )
 
     B, MB, bs, Hq, Hkv, D, N = 2, 4, 16, 4, 2, 128, 8
-    assert supports(bs, Hq, Hkv, D)
+    assert supports(bs, Hq, Hkv, D, jnp.float32)
     rng = np.random.default_rng(3)
     k_pool = jnp.asarray(
         rng.standard_normal((N, bs, Hkv, D)) * 0.3, jnp.float32

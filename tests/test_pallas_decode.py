@@ -59,7 +59,7 @@ def test_parity_vs_xla(B, T, Hq, Hkv, D, n_valid, window):
     q, kc, vc, kn, vn, (q_pos, kv_pos, slots) = _mk(
         B, T, Hq, Hkv, D, n_valid=n_valid
     )
-    assert supports(T, Hq, Hkv, D)
+    assert supports(T, Hq, Hkv, D, kc.dtype)
     layer = 1
     want = fresh_kv_decode_attention(
         q, kc[layer], vc[layer], kn, vn, q_pos, kv_pos, slots,

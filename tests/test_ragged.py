@@ -88,7 +88,7 @@ def test_kernel_parity_vs_oracle_gqa():
     q, kn, vn, q_pos, qlen, kv_pos, bt, nblk, slot0 = _ragged_inputs(
         rng, CTX, QLEN
     )
-    assert pallas_ragged.supports(BS, HQ, HKV, D)
+    assert pallas_ragged.supports(BS, HQ, HKV, D, k_pool.dtype)
     for layer in range(L):
         got = pallas_ragged.ragged_paged_attention(
             q, k_pool, v_pool, kn, vn, q_pos, qlen, kv_pos, bt, nblk,

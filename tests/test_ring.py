@@ -23,7 +23,6 @@ from llmss_tpu.ops.attention import (
 from llmss_tpu.ops.ring_attention import lse_merge_attention, ring_attention
 from llmss_tpu.parallel import MeshPlan, make_mesh
 from llmss_tpu.parallel.mesh import AXIS_DP, AXIS_SP, AXIS_TP
-from llmss_tpu.parallel.mesh import shard_map as compat_shard_map
 
 
 def _rand(rng, *shape):
@@ -54,7 +53,7 @@ def test_ring_prefill_parity(sp_mesh):
     qs = P(AXIS_DP, AXIS_SP, AXIS_TP, None)
     ks = P(AXIS_DP, AXIS_SP, AXIS_TP, None)
     out = jax.jit(
-        compat_shard_map(
+        jax.shard_map(
             lambda q, k, v, qp, kvp: ring_attention(
                 q, k, v, qp, kvp, axis_name=AXIS_SP
             ),
@@ -84,7 +83,7 @@ def test_lse_merge_decode_parity(sp_mesh):
     qs = P(AXIS_DP, None, AXIS_TP, None)
     ks = P(AXIS_DP, AXIS_SP, AXIS_TP, None)
     out = jax.jit(
-        compat_shard_map(
+        jax.shard_map(
             lambda q, k, v, qp, kvp: lse_merge_attention(
                 q, k, v, qp, kvp, axis_name=AXIS_SP
             ),
@@ -189,7 +188,7 @@ def test_lse_merge_fresh_kv_decode_parity(sp_mesh):
     ks = P(AXIS_DP, AXIS_SP, AXIS_TP, None)
     ps = P(AXIS_DP, None)
     out = jax.jit(
-        compat_shard_map(
+        jax.shard_map(
             lambda q, k, v, qp, kvp, kn, vn, sl: (
                 lse_merge_fresh_kv_attention(
                     q, k, v, qp, kvp, kn, vn, sl, axis_name=AXIS_SP

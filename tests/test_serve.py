@@ -281,7 +281,7 @@ def test_cancel_race_orderings(serving):
 
 def test_health_flips_on_stale_heartbeat(serving):
     """A hung supervised worker must not look healthy: /health serves 503
-    once the published heartbeat goes stale (VERDICT: the reference at
+    once the published heartbeat goes stale (the reference at
     least dies visibly; a green light over a dead worker 504s clients)."""
     server, _ = serving
     broker = server.broker

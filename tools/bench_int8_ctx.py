@@ -12,7 +12,8 @@ on real hardware (1b2 flagship dims, ring 2048, 1024-token prompts):
    pre-dequantized before the shard_map'd attention (models/decoder.py),
    an analytic extra-traffic bound reported per step.
 
-Writes INT8_BENCH.json; prints one JSON line.
+Writes INT8_BENCH.json (not kept in the tree; the round-5 copy is at
+commit e57f952); prints one JSON line.
 """
 
 from __future__ import annotations

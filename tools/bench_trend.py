@@ -1,8 +1,8 @@
 #!/usr/bin/env python
 """Aggregate every ``*_BENCH.json`` receipt into one trajectory file.
 
-The repo accumulates bench receipts PR over PR — ``BENCH_r01..r05``,
-``TRACE_BENCH``, ``SLO_BENCH``, … — but nothing collates them, so the
+The repo accumulates bench receipts PR over PR — ``TRACE_BENCH``,
+``SLO_BENCH``, … — but nothing collates them, so the
 "bench trajectory" exists only as loose files. This tool builds
 ``TREND.json``: per-family, per-metric series ordered by revision, each
 sample carrying its value/unit/vs_baseline and the receipt's

@@ -1,7 +1,7 @@
 """Experiment: decode-mask variants for fresh_kv_decode_attention.
 
-PROFILE.md diagnoses a ~0.6 ms/step cost for the *dynamic* decode score
-mask (the hoisted additive [B, T] penalty) over a compile-time-foldable
+PROFILE.md (round 5, at commit e57f952) diagnoses a ~0.6 ms/step cost for
+the *dynamic* decode score mask (the hoisted additive [B, T] penalty) over a compile-time-foldable
 one. This measures candidate replacements on the real chip, all inside
 the actual fused decode scan (engine._decode_many via forward):
 

@@ -19,8 +19,9 @@ offline equivalent and drives the **full** CLI path against it:
    and HF ``model.generate`` on the same checkpoint, then writes the
    captured transcript to ``SMOKE_REAL_CKPT.md``.
 
-Run: ``python tools/smoke_real_ckpt.py`` (uses the default backend — the
-real TPU on the bench host, CPU elsewhere).
+Run on the chip: ``python tools/smoke_real_ckpt.py``. The CLI runs as a
+child process that needs the chip, so this parent stays off JAX (torch and
+tokenizers only), and the child refuses to start on any backend but TPU.
 """
 
 from __future__ import annotations
@@ -128,9 +129,14 @@ def main():
         gen = [t for t in gen.tolist() if t != tokenizer.eos_token_id]
         hf_out.append(tokenizer.decode(gen))
 
-    # Full CLI path, as a subprocess — the exact user entry point.
+    # Full CLI path, as a subprocess — the exact user entry point, behind
+    # one check that it has the chip.
     cmd = [
-        sys.executable, "-m", "llmss_tpu.cli.generate",
+        sys.executable, "-c",
+        "import sys, jax\n"
+        "assert jax.default_backend() == 'tpu', jax.default_backend()\n"
+        "from llmss_tpu.cli.generate import main\n"
+        "main(sys.argv[1:])",
         "--pretrained_model_path", workdir,
         "--prompts", *prompts,
         "--max_new_tokens", "16", "--is_greedy",
