@@ -88,6 +88,10 @@ class _Row:
     # preloaded with them and finish thresholds shift by this count, so
     # the resumed stream continues exactly where the evicted one stopped.
     replayed: int = 0
+    # The prompt's prefill is on the device queue (``prefill_dispatch`` is
+    # recorded). False only between a chunked admission and the ragged
+    # group that plans the prompt's first chunk.
+    prefill_dispatched: bool = True
 
 
 @dataclasses.dataclass
@@ -130,6 +134,10 @@ class _InFlightGroup:
     # interval into achieved MFU/MBU without recomputing the key.
     kind: str = "decode_group"
     cost: object = None  # devtel.KernelCost | None
+    # The batcher's running group number (from 0): on the loop track the
+    # group's ``sched.dispatch``, ``sched.fetch_wait`` and ``sched.callback``
+    # spans carry it, one iteration apart.
+    no: int = 0
 
 
 def select_preemption_victim(candidates, head_priority: int):
@@ -318,7 +326,7 @@ class ContinuousBatcher:
         # steady-state signature (DecodeEngine.canon_cache/canon_vec).
         self._tokens_dev = engine.canon_vec(jnp.zeros(rows, jnp.int32))
         self._cur_pos_dev = engine.canon_vec(jnp.zeros(rows, jnp.int32))
-        self._step_count = 0
+        self._step_count = 0  # groups dispatched: the next group's number
         self._cancelled: set[str] = set()  # guarded_by: self._lock
         self._inflight: _InFlightGroup | None = None
         self._pending_adm: _InFlightAdmission | None = None
@@ -824,6 +832,23 @@ class ContinuousBatcher:
             devtel.observer().mark_steady()
         return n_compiled
 
+    # -- the loop track ------------------------------------------------------
+
+    def loop_span(self, name: str, parent: int | None = None):
+        """Open a span on the flight recorder's loop track
+        (``utils/trace.py``). The same span is a
+        ``jax.profiler.TraceAnnotation``, so a profile shows the loop's
+        phases on the host plane beside the device ops, on the profiler's
+        clock; and its seconds go to ``metrics.loop_spans`` when it closes.
+        ``ContinuousWorker.run_once`` opens its spans here too. Tracing
+        off: the one shared no-op span."""
+        if not trace.enabled():
+            return trace.NO_LOOP_SPAN
+        return trace.loop_span(
+            name, parent, self.engine.metrics.add_loop_span,
+            jax.profiler.TraceAnnotation,
+        )
+
     # -- submission ---------------------------------------------------------
 
     def submit(
@@ -892,7 +917,9 @@ class ContinuousBatcher:
 
     # -- scheduling ---------------------------------------------------------
 
-    def _admit_dispatch(self) -> _InFlightAdmission | None:
+    def _admit_dispatch(
+        self, loop: int | None = None,
+    ) -> _InFlightAdmission | None:
         """Dispatch admission for every pending request that has a free
         row: ONE batched prefill + ONE row-scatter cache insert + ONE
         device-state merge, **no blocking fetch**. The rows become active
@@ -931,8 +958,16 @@ class ContinuousBatcher:
                     rest.append(item)
             self.pending = rest
             rows = [self._free.pop() for _ in taken]
-            n = len(taken)
+        with self.loop_span("sched.admit", loop) as sp:
+            return self._admit_taken(taken, rows, head_prefix, sp)
 
+    def _admit_taken(
+        self, taken: list, rows: list[int], head_prefix, sp,
+    ) -> _InFlightAdmission | None:
+        """``_admit_dispatch`` past the queue: reserve, prefill, insert and
+        merge for the requests ``taken`` on ``rows``, inside the
+        ``sched.admit`` span ``sp``."""
+        n = len(taken)
         if head_prefix is not None:
             # Ring-wrap guard (ADVICE.md high): the suffix prefill pads to
             # the BATCH's bucket, and padded columns still compute slots
@@ -964,7 +999,8 @@ class ContinuousBatcher:
         while P < n:
             P *= 2
         if self._chunked:
-            self._admit_chunked(taken, rows, P, head_prefix)
+            fed = self._admit_chunked(taken, rows, P, head_prefix)
+            sp.set(admitted=n, prompt_tokens=fed, P=P)
             return None
         plen = head_prefix.length if head_prefix is not None else 0
         # With a prefix, only each request's suffix is padded/prefilled.
@@ -972,6 +1008,8 @@ class ContinuousBatcher:
         S = _bucket(
             max(len(s) for s in suffixes), self.engine.max_seq_len,
         )
+        fed = sum(len(s) for s in suffixes)
+        sp.set(admitted=n, prompt_tokens=fed, P=P, S=S)
         padded = np.zeros((P, S), np.int32)
         lens = np.ones(P, np.int32)  # dummy rows prefill one pad token
         gens = []
@@ -1071,11 +1109,18 @@ class ContinuousBatcher:
             self.active[rows[i]] = r
             self._row_pos[rows[i]] = len(ids)
             entries.append((rows[i], r))
+            if req_id:
+                # The seam between the wait for a row and the first-token
+                # lag (prefill on the device + the pipelined fetch).
+                trace.record(
+                    req_id, "prefill_dispatch", row=rows[i],
+                    tokens=len(ids), bucket=S, loop=sp.seq,
+                )
         return _InFlightAdmission(entries=entries, tok=tok)
 
     def _admit_chunked(
         self, taken: list, rows: list[int], P: int, head_prefix,
-    ) -> None:
+    ) -> int:
         """Chunked-prefill admission: NO prefill program runs. The rows'
         blocks are already reserved (``_paged_reserve``) and their tables
         staged host-side; admission is one table upload, one positions
@@ -1087,7 +1132,8 @@ class ContinuousBatcher:
         Prefix rows resume after the shared full blocks (``start = ns·bs``)
         and re-feed the COW partial tail through the ragged steps — its KV
         lands in the row's first owned block, exactly where the dedicated
-        prefill's copy-on-write would put it."""
+        prefill's copy-on-write would put it. Returns the prompt tokens
+        left to feed."""
         eng = self.engine
         n = len(taken)
         row_idx = self._pad_row_idx(P, rows)
@@ -1123,14 +1169,15 @@ class ContinuousBatcher:
                 out=list(ids[len(ids) - rpl:]) if rpl else [],
                 done_cb=cb, stream_cb=scb, awaiting_first=True,
                 t_submit=t_submit, priority=pri, replayed=rpl,
-                emitted=rpl,
+                emitted=rpl, prefill_dispatched=False,
             )
             self.active[rows[i]] = r
             self._row_pos[rows[i]] = start
             self._inflight_prefill[rows[i]] = list(ids[start:])
             self._prefill_plen[rows[i]] = len(ids)
+        return sum(len(item[1]) - start for item in taken)
 
-    def _maybe_preempt(self) -> int:
+    def _maybe_preempt(self, loop: int | None = None) -> int:
         """Evict the lowest-priority running row when the head pending
         request strictly outranks it and admission is blocked on rows or
         pool blocks. At most ONE eviction per step — the freed capacity
@@ -1177,36 +1224,49 @@ class ContinuousBatcher:
         if row is None:
             return 0
         r = self.active[row]
-        self._flush_stream(r)
-        self.active.pop(row, None)
-        self._row_pos.pop(row, None)
-        self._prefill_plen.pop(row, None)
-        self._paged_release_row(row)
-        with self._lock:
-            self._free.append(row)
-        self.engine.metrics.add_preempted(1)
-        trace.record(
-            r.req_id, "evict", tokens=len(r.out), priority=r.priority,
-            for_priority=head_pri,
-        )
-        cb(r.req_id, list(r.out))
+        with self.loop_span("sched.preempt", loop) as sp:
+            sp.set(row=row)
+            self._flush_stream(r)
+            self.active.pop(row, None)
+            self._row_pos.pop(row, None)
+            self._prefill_plen.pop(row, None)
+            self._paged_release_row(row)
+            with self._lock:
+                self._free.append(row)
+            self.engine.metrics.add_preempted(1)
+            trace.record(
+                r.req_id, "evict", tokens=len(r.out), priority=r.priority,
+                for_priority=head_pri, loop=sp.seq,
+            )
+            cb(r.req_id, list(r.out))
         return 1
 
-    def _resolve_admission(self, adm: _InFlightAdmission | None) -> int:
+    def _resolve_admission(
+        self, adm: _InFlightAdmission | None, loop: int | None = None,
+    ) -> int:
         """Host bookkeeping for a dispatched admission (fetch its first
         tokens — by now overlapped with at least one decode chunk)."""
         if adm is None:
             return 0
-        firsts = np.asarray(adm.tok)
-        n = 0
-        for i, (row, r) in enumerate(adm.entries):
-            if self.active.get(row) is not r:
-                continue  # cancelled (and possibly re-admitted) meanwhile
-            self._resolve_first(row, r, int(firsts[i]))
-            n += 1
+        # The prefill runs on the device behind the group just fetched, so
+        # this fetch blocks: a wait for the device like a group's, under
+        # the same name, told apart by ``admission``.
+        with self.loop_span("sched.fetch_wait", loop) as sp:
+            sp.set(admission=len(adm.entries))
+            firsts = np.asarray(adm.tok)
+        with self.loop_span("sched.resolve", loop) as sp:
+            n = 0
+            for i, (row, r) in enumerate(adm.entries):
+                if self.active.get(row) is not r:
+                    continue  # cancelled (and possibly re-admitted) meanwhile
+                self._resolve_first(row, r, int(firsts[i]), sp.seq)
+                n += 1
+            sp.set(resolved=n)
         return n
 
-    def _resolve_first(self, row: int, r: _Row, first: int) -> None:
+    def _resolve_first(
+        self, row: int, r: _Row, first: int, loop: int | None = None,
+    ) -> None:
         """Host bookkeeping at a request's FIRST token — shared by the
         admission-prefill resolve and the ragged chunked path (there the
         first token arrives in the chunk that completed the prompt)."""
@@ -1226,7 +1286,9 @@ class ContinuousBatcher:
             # token — queue wait + prefill + overlapped chunk — while
             # the role worker's "prefill" span times only the export
             # call; distinct names keep phase sums from double-counting.
-            trace.record(r.req_id, "admit", dur_s=now - r.t_submit)
+            trace.record(
+                r.req_id, "admit", dur_s=now - r.t_submit, loop=loop,
+            )
         r.awaiting_first = False
         eos = (
             r.gen.eos_token_id if r.gen.eos_token_id is not None else -1
@@ -1644,7 +1706,9 @@ class ContinuousBatcher:
         sa = self.engine._sample_args(gens, self.rows)
         return done, eos_arr, sa
 
-    def _process_group(self, group: _InFlightGroup) -> int:
+    def _process_group(
+        self, group: _InFlightGroup, loop: int | None = None,
+    ) -> int:
         """Fetch a group's packed results (ONE device→host transfer,
         overlapped with the next group already running on device) and
         apply host bookkeeping chunk by chunk: per-row token accounting,
@@ -1652,18 +1716,23 @@ class ContinuousBatcher:
         granularity as the ungrouped path, so a row that finishes (or
         poisons) in chunk c never has chunk c+1's fill tokens read as
         output."""
-        R, k, nc = self.rows, group.k, group.n_chunks
-        with self.engine.metrics.host_fetch.time():
-            flat = np.asarray(group.packed)  # the ONE blocking fetch
+        with self.loop_span("sched.fetch_wait", loop) as sp:
+            sp.set(group=group.no)
+            with self.engine.metrics.host_fetch.time():
+                flat = np.asarray(group.packed)  # the ONE blocking fetch
         self.engine.metrics.add_host_sync()
-        for r in self.active.values():
-            if r.req_id and not r.awaiting_first:
-                # Throttled + sheddable (``group_`` prefix): per-group
-                # cadence would otherwise dominate a long request's ring.
-                trace.record(
-                    r.req_id, "group_fetch", throttle_s=0.05,
-                    chunks=group.n_chunks, k=group.k,
-                )
+        with self.loop_span("sched.callback", loop) as sp:
+            live = len(self.active)
+            n = self._apply_group(group, flat, sp.seq)
+            sp.set(group=group.no, tokens=n, finished=live - len(self.active))
+        return n
+
+    def _apply_group(
+        self, group: _InFlightGroup, flat: np.ndarray, loop: int | None,
+    ) -> int:
+        """The host's half of ``_process_group``, after the fetch (the
+        ``sched.callback`` span): the fetched group applied chunk by chunk."""
+        R, k, nc = self.rows, group.k, group.n_chunks
         toks_np = flat[: nc * R * k].reshape(nc, R, k)
         poisoned_np = flat[nc * R * k:].reshape(nc, R).astype(bool)
         now = time.perf_counter()
@@ -1711,7 +1780,7 @@ class ContinuousBatcher:
                                   "(NaN/inf in model output)",
                         )
                         continue
-                    self._resolve_first(i, r, int(toks_np[c, i, 0]))
+                    self._resolve_first(i, r, int(toks_np[c, i, 0]), loop)
                     continue
                 if poisoned_np[c, i]:
                     # Checked BEFORE token processing: the device
@@ -1753,7 +1822,7 @@ class ContinuousBatcher:
         self.engine.metrics.host_callback.record(time.perf_counter() - t_cb)
         return n
 
-    def _plan_ragged(self, n_steps: int):
+    def _plan_ragged(self, n_steps: int, loop: int | None = None):
         """Host-side schedule for one ragged mixed group: every active row
         advances one token per step; rows with an in-flight prompt feed
         ``chunked_prefill``-token slices instead, sampling suppressed
@@ -1771,6 +1840,17 @@ class ContinuousBatcher:
         for s in range(n_steps):
             for row in list(self._inflight_prefill):
                 rem = self._inflight_prefill[row]
+                r = self.active[row]
+                if not r.prefill_dispatched:
+                    # The chunked path's seam between the wait for a row
+                    # and the first-token lag: the prompt's first chunk.
+                    r.prefill_dispatched = True
+                    if r.req_id:
+                        trace.record(
+                            r.req_id, "prefill_dispatch", row=row,
+                            tokens=self._prefill_plen[row], bucket=CB,
+                            loop=loop,
+                        )
                 q = min(CB, len(rem))
                 ids[s, row, :q] = rem[:q]
                 del rem[:q]
@@ -1790,7 +1870,7 @@ class ContinuousBatcher:
         )
         return ids, qlens, feed, emit, firsts
 
-    def step(self) -> int:
+    def step(self, loop: int | None = None) -> int:
         """One scheduler iteration of the pipelined loop:
 
         1. dispatch decode group N+1 from the device-resident state — ONE
@@ -1808,8 +1888,47 @@ class ContinuousBatcher:
         Rows keep their exact solo tokens (row isolation is positional,
         and the device state never depends on host processing) — the
         pipeline only delays when the *host* learns them by one group.
+
+        Each phase is a span on the loop track (``sched.plan``,
+        ``sched.dispatch``, ``sched.fetch_wait``, ``sched.callback``,
+        ``sched.resolve``, ``sched.preempt``, ``sched.admit``,
+        ``sched.devtel``), a child of the worker's iteration span ``loop``.
         """
-        self._process_cancellations()
+        ragged = None
+        with self.loop_span("sched.plan", loop) as sp:
+            self._process_cancellations()
+            if self.active:
+                done, eos_arr, sa = self._chunk_args()
+                busy = len(self.active) >= (3 * self.rows) // 4
+                t0 = time.perf_counter()
+                if self._chunked and self._inflight_prefill:
+                    # Mixed batch: in-flight prompts stream through the
+                    # ragged dispatch as chunk-budget query rows while
+                    # decode rows advance one token per step. No t_bucket —
+                    # the ragged executable's identity is keyed purely by
+                    # the xs shapes, so exactly TWO programs exist (the busy
+                    # and low-load step counts). The group never records
+                    # decode_step (it is not a clean decode-only sample —
+                    # has_admission covers that).
+                    nc, k = (
+                        self.group_chunks * self.chunk_steps if busy
+                        else self.chunk_steps_low
+                    ), 1
+                    ragged = self._plan_ragged(nc, sp.seq)
+                else:
+                    # Busy → the full group of full chunks (host off the
+                    # critical path); low load → one short chunk
+                    # (admission/TTFT granularity). Exactly these two
+                    # (n_chunks, n_steps) combos exist, so the executable
+                    # envelope stays two programs per cache-read bucket —
+                    # same count as the ungrouped two-chunk-size scheme.
+                    nc, k = (
+                        (self.group_chunks, self.chunk_steps) if busy
+                        else (1, self.chunk_steps_low)
+                    )
+                    t_bucket = self.engine.decode_bucket(
+                        max(self._row_pos.values(), default=0) + nc * k
+                    )
 
         if not self.active:
             # Nothing running: drain the pipeline, then admit directly
@@ -1818,122 +1937,110 @@ class ContinuousBatcher:
             if self._inflight is not None:
                 group, self._inflight = self._inflight, None
                 self._last_fetch_t = None
-                n = self._process_group(group)
-                n += self._resolve_admission(self._pending_adm)
+                n = self._process_group(group, loop)
+                n += self._resolve_admission(self._pending_adm, loop)
                 self._pending_adm = None
                 return n
             if self._pending_adm is not None:
                 adm, self._pending_adm = self._pending_adm, None
-                return self._resolve_admission(adm)
-            adm = self._admit_dispatch()
+                return self._resolve_admission(adm, loop)
+            adm = self._admit_dispatch(loop)
             if adm is None:
                 return 0
             self._last_fetch_t = None
-            return self._resolve_admission(adm)
+            return self._resolve_admission(adm, loop)
 
-        done, eos_arr, sa = self._chunk_args()
-        busy = len(self.active) >= (3 * self.rows) // 4
-        t0 = time.perf_counter()
-        if self._chunked and self._inflight_prefill:
-            # Mixed batch: in-flight prompts stream through the ragged
-            # dispatch as chunk-budget query rows while decode rows
-            # advance one token per step. No t_bucket — the ragged
-            # executable's identity is keyed purely by the xs shapes, so
-            # exactly TWO programs exist (the busy and low-load step
-            # counts). The group never records decode_step (it is not a
-            # clean decode-only sample — has_admission covers that).
-            nc, k = (
-                self.group_chunks * self.chunk_steps if busy
-                else self.chunk_steps_low
-            ), 1
-            ids_seq, qlens_seq, feed_seq, emit_seq, firsts = (
-                self._plan_ragged(nc)
-            )
-            packed, last_tok, cache, cur_pos, _ = self.engine._ragged_group(
-                self.engine.params, self._tokens_dev, self.cache,
-                self._cur_pos_dev, sa, jnp.asarray(done),
-                jnp.asarray(eos_arr), jnp.asarray(ids_seq),
-                jnp.asarray(qlens_seq), jnp.asarray(feed_seq),
-                jnp.asarray(emit_seq),
-            )
-            adv = qlens_seq.sum(axis=0)
-            for row in self._row_pos:
-                self._row_pos[row] += int(adv[row])
-            group = _InFlightGroup(
-                packed=packed, n_chunks=nc, k=k, has_admission=True,
-                prefill_firsts=firsts,
-                kind="ragged_group",
-                cost=self.engine.devtel_cost(
-                    "ragged_group", (self.rows, nc, self.chunked_prefill),
-                    batch=self.rows, steps=nc, kv_len=None,
-                    prefill_tokens=nc * self.rows * self.chunked_prefill,
-                ) if devtel.enabled() else None,
-            )
-        else:
-            # Busy → the full group of full chunks (host off the critical
-            # path); low load → one short chunk (admission/TTFT
-            # granularity). Exactly these two (n_chunks, n_steps) combos
-            # exist, so the executable envelope stays two programs per
-            # cache-read bucket — same count as the ungrouped
-            # two-chunk-size scheme.
-            nc, k = (
-                (self.group_chunks, self.chunk_steps) if busy
-                else (1, self.chunk_steps_low)
-            )
-            t_bucket = self.engine.decode_bucket(
-                max(self._row_pos.values(), default=0) + nc * k
-            )
-            packed, last_tok, cache, cur_pos, _ = self.engine._decode_group(
-                self.engine.params, self._tokens_dev, self.cache,
-                self._cur_pos_dev, sa, jnp.asarray(done),
-                jnp.asarray(eos_arr),
-                n_chunks=nc, n_steps=k, t_bucket=t_bucket,
-            )
-            for row in self._row_pos:
-                self._row_pos[row] += nc * k
-            # The admission dispatched LAST step sits between the previous
-            # group and this one on the device queue, so this group's
-            # fetch-to-fetch interval includes its prefill+insert+merge
-            # time.
-            group = _InFlightGroup(
-                packed=packed, n_chunks=nc, k=k,
-                has_admission=self._pending_adm is not None,
-                cost=self.engine.devtel_cost(
-                    "decode_group", (self.rows, nc, k, t_bucket),
-                    batch=self.rows, steps=nc * k, kv_len=t_bucket,
-                ) if devtel.enabled() else None,
-            )
-        self.cache = self.engine.canon_cache(cache)
-        self._cur_pos_dev = self.engine.canon_vec(cur_pos)
-        self._tokens_dev = self.engine.canon_vec(last_tok)
-        try:
-            packed.copy_to_host_async()
-        except AttributeError:
-            pass
-        self.engine.metrics.host_dispatch.record(time.perf_counter() - t0)
-        self.engine.metrics.add_group()
-        for r in self.active.values():
-            if r.req_id and not r.awaiting_first:
-                trace.record(
-                    r.req_id, "group_dispatch", throttle_s=0.05,
-                    chunks=nc, k=k,
+        with self.loop_span("sched.dispatch", loop) as sp:
+            live = len(self.active)
+            if ragged is not None:
+                ids_seq, qlens_seq, feed_seq, emit_seq, firsts = ragged
+                packed, last_tok, cache, cur_pos, _ = (
+                    self.engine._ragged_group(
+                        self.engine.params, self._tokens_dev, self.cache,
+                        self._cur_pos_dev, sa, jnp.asarray(done),
+                        jnp.asarray(eos_arr), jnp.asarray(ids_seq),
+                        jnp.asarray(qlens_seq), jnp.asarray(feed_seq),
+                        jnp.asarray(emit_seq),
+                    )
                 )
+                adv = qlens_seq.sum(axis=0)
+                for row in self._row_pos:
+                    self._row_pos[row] += int(adv[row])
+                group = _InFlightGroup(
+                    packed=packed, n_chunks=nc, k=k, has_admission=True,
+                    prefill_firsts=firsts,
+                    kind="ragged_group",
+                    cost=self.engine.devtel_cost(
+                        "ragged_group",
+                        (self.rows, nc, self.chunked_prefill),
+                        batch=self.rows, steps=nc, kv_len=None,
+                        prefill_tokens=nc * self.rows * self.chunked_prefill,
+                    ) if devtel.enabled() else None,
+                    no=self._step_count,
+                )
+            else:
+                packed, last_tok, cache, cur_pos, _ = (
+                    self.engine._decode_group(
+                        self.engine.params, self._tokens_dev, self.cache,
+                        self._cur_pos_dev, sa, jnp.asarray(done),
+                        jnp.asarray(eos_arr),
+                        n_chunks=nc, n_steps=k, t_bucket=t_bucket,
+                    )
+                )
+                for row in self._row_pos:
+                    self._row_pos[row] += nc * k
+                # The admission dispatched LAST step sits between the
+                # previous group and this one on the device queue, so this
+                # group's fetch-to-fetch interval includes its
+                # prefill+insert+merge time.
+                group = _InFlightGroup(
+                    packed=packed, n_chunks=nc, k=k,
+                    has_admission=self._pending_adm is not None,
+                    cost=self.engine.devtel_cost(
+                        "decode_group", (self.rows, nc, k, t_bucket),
+                        batch=self.rows, steps=nc * k, kv_len=t_bucket,
+                    ) if devtel.enabled() else None,
+                    no=self._step_count,
+                )
+                sp.set(t_bucket=t_bucket)
+            self.cache = self.engine.canon_cache(cache)
+            self._cur_pos_dev = self.engine.canon_vec(cur_pos)
+            self._tokens_dev = self.engine.canon_vec(last_tok)
+            try:
+                packed.copy_to_host_async()
+            except AttributeError:
+                pass
+            sp.set(
+                group=group.no, kind=group.kind, chunks=nc, k=k,
+                rows_live=live, has_admission=group.has_admission,
+            )
+            self.engine.metrics.host_dispatch.record(
+                time.perf_counter() - t0
+            )
+            self.engine.metrics.add_group(steps=nc * k)
+            for r in self.active.values():
+                if r.req_id and not r.awaiting_first:
+                    trace.record(
+                        r.req_id, "group_dispatch", throttle_s=0.05,
+                        chunks=nc, k=k, loop=sp.seq,
+                    )
 
         prev, self._inflight = self._inflight, group
         n = 0
         if prev is not None:
-            n = self._process_group(prev)  # frees finished rows
-        n += self._resolve_admission(self._pending_adm)
+            n = self._process_group(prev, loop)  # frees finished rows
+        n += self._resolve_admission(self._pending_adm, loop)
         # Preemption sits between resolve and admit: an evicted row's slot
         # and blocks feed THIS step's admission, so a blocked interactive
         # request is running one group after its eviction decision.
-        self._maybe_preempt()
+        self._maybe_preempt(loop)
         # Admission takes the rows processing just freed; its device work
         # overlaps the in-flight group and lands before the next one.
-        self._pending_adm = self._admit_dispatch()
+        self._pending_adm = self._admit_dispatch(loop)
         self._step_count += 1
         if devtel.enabled():
-            self._devtel_sample()
+            with self.loop_span("sched.devtel", loop):
+                self._devtel_sample()
         return n
 
     def _devtel_sample(self) -> None:

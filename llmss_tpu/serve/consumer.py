@@ -607,13 +607,16 @@ class ContinuousWorker:
         ``prefix_prefill`` adds the prefix-reuse admission variants)."""
         return self.batcher.prewarm(seq_buckets, prefix_prefill)
 
-    def _drain_broker(self) -> int:
+    def _drain_broker(self, loop: int | None = None) -> int:
         n = 0
         while True:
-            req = self._pop(
-                timeout=self.poll_timeout_s if self.batcher.idle and n == 0
-                else 0.0
-            )
+            if self.batcher.idle and n == 0:
+                # Nothing to run: block on the queue. A span of its own
+                # (``loop.idle``), so that waiting is never read as host work.
+                with self.batcher.loop_span("loop.idle", loop):
+                    req = self._pop(timeout=self.poll_timeout_s)
+            else:
+                req = self._pop()
             if req is None:
                 return n
             if (
@@ -846,7 +849,9 @@ class ContinuousWorker:
             self.batcher.forget_park(req.id)
         return ok
 
-    def _drain_handoffs(self, backlog_only: bool = False) -> int:
+    def _drain_handoffs(
+        self, backlog_only: bool = False, loop: int | None = None,
+    ) -> int:
         """Decode-role intake: adopt backlogged records first (FIFO — they
         were popped earlier), then pop new ones while rows are free. A
         capacity-blocked record goes to the backlog and stops the intake;
@@ -860,13 +865,16 @@ class ContinuousWorker:
         if backlog_only:
             return n
         while not self._adopt_backlog:
-            rec = self.broker.pop_handoff(
-                timeout=(
-                    self.poll_timeout_s
-                    if self.batcher.idle and n == 0 else 0.0
-                ),
-                worker_id=self.worker_id,
-            )
+            if self.batcher.idle and n == 0:
+                with self.batcher.loop_span("loop.idle", loop):
+                    rec = self.broker.pop_handoff(
+                        timeout=self.poll_timeout_s,
+                        worker_id=self.worker_id,
+                    )
+            else:
+                rec = self.broker.pop_handoff(
+                    timeout=0.0, worker_id=self.worker_id,
+                )
             if rec is None:
                 break
             if self._try_adopt(rec):
@@ -970,43 +978,62 @@ class ContinuousWorker:
         return len(ids)
 
     def run_once(self) -> int:
+        """One iteration of the worker loop. On the loop track
+        (utils/trace.py) it is one ``loop`` span whose children are
+        ``loop.housekeep``, ``loop.drain`` (with ``loop.idle`` inside when
+        the pop blocks), the batcher's ``sched.*`` spans and
+        ``loop.publish``."""
         self.last_progress_ts = time.monotonic()
-        # Check the broker's TTL'd cancellation flags for exactly the ids
-        # this batcher holds (pending, in-flight admission, active): the
-        # flag persists until its request shows up, so cancel-before-submit
-        # races land, and other workers' ids are never swallowed.
-        live = self.batcher.live_ids()
-        # Renew this worker's leases on everything it holds — pending and
-        # active alike — so only a genuinely dead worker's requests are
-        # redelivered, never a busy one's.
-        self.broker.touch_requests(live)
-        if self.role == "decode":
-            # Adopted rows and backlogged records are held under HANDOFF
-            # leases (their request leases were settled at push_handoff);
-            # renew those at the same cadence. Unknown ids are ignored.
-            self.broker.touch_handoffs(
-                live + [r.req.id for r in self._adopt_backlog]
-            )
-        for rid in self.broker.check_cancelled(live):
-            # The batcher frees the row at the top of its next step; the
-            # request's done_cb fires with the tokens produced so far.
-            self.batcher.cancel(rid)
-        self._maybe_publish_load()
-        if self.role == "decode":
-            # Draining still adopts the backlog: those records are already
-            # this worker's responsibility (leased), and every adoption
-            # moves them toward their exactly-one terminal response.
-            n = self._drain_handoffs(backlog_only=self.draining)
-        else:
-            n = 0 if self.draining else self._drain_broker()
-        self.batcher.step()
-        self._publish_counter += 1
-        # Every 16 iterations even when idle: with chunked steps (~0.3 s
-        # each under load) a sparser cadence would let the supervisor
-        # heartbeat go stale mid-serve (producer /health flips at
-        # 3× heartbeat_s).
-        if n or self._publish_counter % 16 == 0:
-            self.broker.publish_metrics(self.engine.metrics.to_dict())
+        span = self.batcher.loop_span
+        with span("loop") as it:
+            it.set(iteration=self._publish_counter)
+            with span("loop.housekeep", it.seq) as sp:
+                # Check the broker's TTL'd cancellation flags for exactly
+                # the ids this batcher holds (pending, in-flight admission,
+                # active): the flag persists until its request shows up, so
+                # cancel-before-submit races land, and other workers' ids
+                # are never swallowed.
+                live = self.batcher.live_ids()
+                sp.set(live=len(live))
+                # Renew this worker's leases on everything it holds —
+                # pending and active alike — so only a genuinely dead
+                # worker's requests are redelivered, never a busy one's.
+                self.broker.touch_requests(live)
+                if self.role == "decode":
+                    # Adopted rows and backlogged records are held under
+                    # HANDOFF leases (their request leases were settled at
+                    # push_handoff); renew those at the same cadence.
+                    # Unknown ids are ignored.
+                    self.broker.touch_handoffs(
+                        live + [r.req.id for r in self._adopt_backlog]
+                    )
+                for rid in self.broker.check_cancelled(live):
+                    # The batcher frees the row at the top of its next
+                    # step; the request's done_cb fires with the tokens
+                    # produced so far.
+                    self.batcher.cancel(rid)
+                self._maybe_publish_load()
+            with span("loop.drain", it.seq) as sp:
+                if self.role == "decode":
+                    # Draining still adopts the backlog: those records are
+                    # already this worker's responsibility (leased), and
+                    # every adoption moves them toward their exactly-one
+                    # terminal response.
+                    n = self._drain_handoffs(
+                        backlog_only=self.draining, loop=sp.seq,
+                    )
+                else:
+                    n = 0 if self.draining else self._drain_broker(sp.seq)
+                sp.set(popped=n)
+            self.batcher.step(it.seq)
+            self._publish_counter += 1
+            # Every 16 iterations even when idle: with chunked steps (~0.3 s
+            # each under load) a sparser cadence would let the supervisor
+            # heartbeat go stale mid-serve (producer /health flips at
+            # 3× heartbeat_s).
+            if n or self._publish_counter % 16 == 0:
+                with span("loop.publish", it.seq):
+                    self.broker.publish_metrics(self.engine.metrics.to_dict())
         return n
 
     def abort_inflight(self, reason: str) -> int:

@@ -47,6 +47,13 @@ _PROFILE_GEN = 0  # guarded_by: _PROFILE_LOCK
 _PROFILE_DEADLINE = 0.0  # monotonic expiry of the active slot; guarded_by: _PROFILE_LOCK
 _PROFILE_GRACE_S = 5.0
 
+# A stream handler waits on the request's stream channel and, each time that
+# wait runs out, looks for the terminal response. While it looks it is deaf
+# to tokens: one that comes meanwhile is written when the look ends. So the
+# look is a check, far shorter than the time between two tokens; at tens of
+# milliseconds a client's first-token times bunch at multiples of the cycle.
+_DONE_CHECK_S = 0.001
+
 # Class-aware admission: the fraction of max_queue_depth each class may
 # fill before shedding. Batch saturates at half the backlog so a batch
 # burst leaves queue room for latency-sensitive traffic even before the
@@ -581,12 +588,22 @@ class ProducerServer:
                 # like a disconnect.
                 self.connection.settimeout(30.0)
 
+                wrote_first = False
+
                 def write_data(inc):
+                    nonlocal wrote_first
                     self.wfile.write(
                         b"data: " + json.dumps(
                             {"token_ids": inc}
                         ).encode() + b"\n\n"
                     )
+                    self.wfile.flush()
+                    if not wrote_first:
+                        # The end of the first-token path on the server:
+                        # ``admit`` -> here is the stream channel plus
+                        # this thread's wake-up.
+                        wrote_first = True
+                        trace.record(req.id, "first_write")
 
                 deadline = _time.monotonic() + outer.timeout_s
                 try:
@@ -594,10 +611,9 @@ class ProducerServer:
                         inc = outer.broker.pop_stream(req.id, timeout=0.1)
                         if inc is not None:
                             write_data(inc)
-                            self.wfile.flush()
                             continue
                         resp = outer.broker.wait_response(
-                            req.id, timeout=0.05
+                            req.id, timeout=_DONE_CHECK_S
                         )
                         if resp is not None:
                             # Drain increments that raced the response.
@@ -950,6 +966,17 @@ def create_fastapi_app(broker: Broker, timeout_s: float = 300.0,
         the terminal response. Client disconnect (GeneratorExit) cancels
         the request so the worker stops spending decode steps on it."""
         deadline = _time.monotonic() + timeout_s
+        wrote_first = False
+
+        def wrote():
+            # Resumed after a yield: the server has written that token
+            # event; the first one ends the first-token path
+            # (see _stream_response).
+            nonlocal wrote_first
+            if not wrote_first:
+                wrote_first = True
+                trace.record(req.id, "first_write")
+
         try:
             while _time.monotonic() < deadline:
                 inc = broker.pop_stream(req.id, timeout=0.1)
@@ -957,8 +984,9 @@ def create_fastapi_app(broker: Broker, timeout_s: float = 300.0,
                     yield (
                         "data: " + json.dumps({"token_ids": inc}) + "\n\n"
                     )
+                    wrote()
                     continue
-                resp = broker.wait_response(req.id, timeout=0.05)
+                resp = broker.wait_response(req.id, timeout=_DONE_CHECK_S)
                 if resp is not None:
                     while True:  # drain increments that raced the response
                         inc = broker.pop_stream(req.id)
@@ -968,6 +996,7 @@ def create_fastapi_app(broker: Broker, timeout_s: float = 300.0,
                             "data: " + json.dumps({"token_ids": inc})
                             + "\n\n"
                         )
+                        wrote()
                     yield "event: done\ndata: " + resp.to_json() + "\n\n"
                     return
             broker.cancel_request(req.id)

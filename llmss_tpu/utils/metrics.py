@@ -147,6 +147,13 @@ class EngineMetrics:
         self.mixed_prefill_rows = 0  # guarded_by: self._lock
         self.prefill_tokens_chunked = 0  # guarded_by: self._lock
         self.chunk_budget_tokens = 0  # guarded_by: self._lock
+        # Worker-loop accounting, cumulative so that a difference of two
+        # /metrics reads is exact over any window. Always on: decode steps
+        # of the dispatched groups (chunks x k). With tracing on: seconds
+        # and count of every loop span (utils/trace.py), added when the
+        # span closes.
+        self.decode_steps = 0  # guarded_by: self._lock
+        self.loop_spans: dict[str, list] = {}  # guarded_by: self._lock
         self._start = time.monotonic()
 
     def add_tokens(self, n: int) -> None:
@@ -238,10 +245,22 @@ class EngineMetrics:
         with self._lock:
             self.host_syncs += n
 
-    def add_group(self, n: int = 1) -> None:
-        """A grouped decode program was dispatched."""
+    def add_group(self, n: int = 1, steps: int = 0) -> None:
+        """A grouped decode program was dispatched, of ``steps`` decode
+        steps (chunks x k)."""
         with self._lock:
             self.groups_dispatched += n
+            self.decode_steps += steps
+
+    def add_loop_span(self, name: str, seconds: float) -> None:
+        """A loop span closed (``trace.loop_span(on_close=...)``)."""
+        with self._lock:
+            acc = self.loop_spans.get(name)
+            if acc is None:
+                self.loop_spans[name] = [seconds, 1]
+            else:
+                acc[0] += seconds
+                acc[1] += 1
 
     def to_dict(self) -> dict:
         uptime = time.monotonic() - self._start
@@ -266,6 +285,13 @@ class EngineMetrics:
                 self.mixed_prefill_rows, self.prefill_tokens_chunked,
                 self.chunk_budget_tokens,
             )
+            loop = {
+                "decode_steps": self.decode_steps,
+                "spans": {
+                    name: {"seconds": round(s, 6), "count": n}
+                    for name, (s, n) in sorted(self.loop_spans.items())
+                },
+            }
         return {
             "uptime_s": round(uptime, 1),
             "requests_served": reqs,
@@ -303,6 +329,7 @@ class EngineMetrics:
                     round(m_tok / m_budget, 4) if m_budget else None
                 ),
             },
+            "loop": loop,
             **(
                 {"speculative": self.spec_stats}
                 if self.spec_stats is not None else {}

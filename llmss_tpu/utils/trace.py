@@ -14,6 +14,12 @@ wall-clock read happens per process — the ``wall_anchor`` captured at
 skew: within a process ordering is monotonic-exact, across processes events
 are aligned by ``wall_anchor + (t_mono - mono_anchor)``.
 
+Work that belongs to an ITERATION of the worker loop and not to one request
+(plan, dispatch, the blocking fetch, the callback over the batch, admission,
+housekeeping) goes on the recorder's second, request-less store: the **loop
+track**, a bounded ring of spans that name their parent. Request events point
+into it with ``attrs["loop"]`` (the ``seq`` of the span that caused them).
+
 Tracing is ON by default at event granularity. Disable with
 ``LLMSS_TRACE=0`` in the environment or :func:`set_enabled` at runtime;
 the disabled fast path is a single attribute check per call site.
@@ -21,11 +27,12 @@ the disabled fast path is a single attribute check per call site.
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
 import time
-from collections import OrderedDict
+from collections import OrderedDict, deque
 
 # Event names a stitched timeline must end with exactly once: the broker's
 # response channel is the delivery contract's terminal ack.
@@ -83,12 +90,105 @@ class Span:
         return False
 
 
+class LoopSpan:
+    """One span of the loop track: a phase of one worker-loop iteration.
+
+    ``seq`` is known from the start, so children and request events can
+    name the span while it is still open; the span enters the ring when it
+    ends. ``annotate`` (``jax.profiler.TraceAnnotation``, handed in by the
+    modules that import JAX) puts the same name on the profiler's host plane
+    for the span's lifetime; ``on_close(name, seconds)`` feeds the cumulative
+    counters at the same boundary. ``end()`` is idempotent.
+    """
+
+    __slots__ = ("_rec", "seq", "parent", "name", "_t0", "_attrs",
+                 "_on_close", "_ann")
+
+    def __init__(self, rec, seq, parent, name, on_close, annotate):
+        self._rec = rec
+        self.seq = seq
+        self.parent = parent
+        self.name = name
+        self._attrs = None
+        self._on_close = on_close
+        self._ann = annotate(name) if annotate is not None else None
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = time.monotonic()
+
+    def set(self, **attrs) -> None:
+        if self._attrs is None:
+            self._attrs = attrs
+        else:
+            self._attrs.update(attrs)
+
+    def end(self, **attrs) -> None:
+        rec = self._rec
+        if rec is None:
+            return
+        self._rec = None
+        dur = time.monotonic() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        if attrs:
+            self.set(**attrs)
+        rec._close_loop_span(
+            (self.seq, self.parent, self.name, self._t0, dur, self._attrs)
+        )
+        if self._on_close is not None:
+            self._on_close(self.name, dur)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        if exc_type is not None:
+            self.set(error=exc_type.__name__)
+        self.end()
+        return False
+
+
+class _NoLoopSpan:
+    """What every ``loop_span`` call site gets while tracing is off: ONE
+    shared object, so the off path makes no span, takes no lock and reads
+    no clock (a ``set(k=v)`` still builds its keyword dictionary).
+    ``seq`` is None: it names no span."""
+
+    __slots__ = ()
+    seq = None
+
+    def set(self, **attrs) -> None:
+        pass
+
+    def end(self, **attrs) -> None:
+        pass
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        return False
+
+
+NO_LOOP_SPAN = _NoLoopSpan()
+
+# The loop track's thread name in a Chrome trace (no request id is spelled so).
+LOOP_LANE = "worker loop"
+
+# Loop spans kept: a traced window at 20 ms groups is ~50 iterations a
+# second of ~10 spans each, so 16 k spans hold half a minute of it.
+LOOP_RING_SPANS = 16384
+
+
 class FlightRecorder:
-    """Bounded ring of per-request event histories for one process.
+    """Bounded ring of per-request event histories for one process, and the
+    loop track beside it.
 
     Retains the ``max_requests`` most recently active requests; each keeps
     up to ``max_events`` events (overflow sheds group/renewal spam first and
     counts what it dropped, so a postmortem can see the ring was lossy).
+    The loop track keeps the ``max_loop_spans`` most recently ended loop
+    spans as plain tuples and counts what fell off the ring.
     """
 
     def __init__(
@@ -96,6 +196,7 @@ class FlightRecorder:
         max_requests: int = 256,
         max_events: int = 512,
         proc: str | None = None,
+        max_loop_spans: int = LOOP_RING_SPANS,
     ):
         self.max_requests = max_requests
         self.max_events = max_events
@@ -103,6 +204,13 @@ class FlightRecorder:
         self._lock = threading.Lock()
         # req_id -> {"trace_id", "events": [dict], "dropped", "last": {name: t}}
         self._reqs: OrderedDict[str, dict] = OrderedDict()  # guarded_by: self._lock
+        # The loop track has a lock of its own: its one writer is the worker
+        # loop, which must not queue behind the front end's request events.
+        self._loop_lock = threading.Lock()
+        # (seq, parent_seq, name, t0, dur, attrs) in order of ENDING
+        self._loop: deque = deque(maxlen=max_loop_spans)  # guarded_by: self._loop_lock
+        self._loop_dropped = 0  # guarded_by: self._loop_lock
+        self._loop_seq = itertools.count(1)
 
     # -- recording ----------------------------------------------------------
 
@@ -159,6 +267,24 @@ class FlightRecorder:
     def start_span(self, req_id: str, name: str, **attrs) -> Span:
         return Span(self, req_id, name, attrs)
 
+    def start_loop_span(
+        self, name: str, parent: int | None = None, on_close=None,
+        annotate=None,
+    ) -> LoopSpan:
+        return LoopSpan(
+            self, next(self._loop_seq), parent, name, on_close, annotate,
+        )
+
+    def _close_loop_span(self, span: tuple) -> None:
+        with self._loop_lock:
+            if len(self._loop) == self._loop.maxlen:
+                self._loop_dropped += 1
+            self._loop.append(span)
+
+    def loop_spans(self) -> list[tuple]:
+        with self._loop_lock:
+            return list(self._loop)
+
     # -- readout ------------------------------------------------------------
 
     def events_for(self, req_id: str) -> list[dict]:
@@ -182,6 +308,9 @@ class FlightRecorder:
     def clear(self) -> None:
         with self._lock:
             self._reqs.clear()
+        with self._loop_lock:
+            self._loop.clear()
+            self._loop_dropped = 0
 
     def export(
         self,
@@ -211,7 +340,7 @@ class FlightRecorder:
                     "dropped": e["dropped"],
                     "events": evs,
                 }
-        return {
+        out = {
             "proc": self.proc,
             "mono_anchor": time.monotonic(),
             # The ONE wall-clock read per process, taken only at export so
@@ -219,6 +348,16 @@ class FlightRecorder:
             "wall_anchor": time.time(),
             "requests": reqs,
         }
+        if req_ids is None and max_events is None:
+            # The whole-recorder export carries the loop track too; the
+            # bounded ones (registry heartbeats, one request's timeline)
+            # stay as small as they were.
+            with self._loop_lock:
+                spans, dropped = list(self._loop), self._loop_dropped
+            out["loop"] = {
+                "spans": [list(sp) for sp in spans], "dropped": dropped,
+            }
+        return out
 
 
 # -- module-level recorder (one per process) --------------------------------
@@ -254,6 +393,17 @@ def span(req_id: str | None, name: str, **attrs) -> Span:
     if not _ENABLED or req_id is None:
         return Span(None, req_id, name, attrs)
     return _RECORDER.start_span(req_id, name, **attrs)
+
+
+def loop_span(
+    name: str, parent: int | None = None, on_close=None, annotate=None,
+):
+    """Open a span on the loop track; its ``seq`` names it to children
+    (``parent=``) and to request events (``loop=``). While tracing is off
+    every call returns the one shared :data:`NO_LOOP_SPAN`."""
+    if not _ENABLED:
+        return NO_LOOP_SPAN
+    return _RECORDER.start_loop_span(name, parent, on_close, annotate)
 
 
 def ensure_context(req) -> None:
@@ -618,6 +768,11 @@ def to_chrome_trace(
     process label, one tid per request, ``X`` complete events for spans and
     ``i`` instants for point events, timestamps in microseconds.
 
+    An export's loop track becomes ONE more thread lane of its process
+    (``worker loop``): ``X`` events nested by their parents, each with its
+    ``seq`` and ``parent`` in ``args``. With ``req_id`` only the spans that
+    overlap that request's events are drawn.
+
     ``counters`` is an optional list of devtel export blobs (each carrying
     its own ``mono_anchor``/``wall_anchor`` pair plus ``counters`` samples
     of ``{"t": mono, "tracks": {name: {series: value}}}``); each track
@@ -625,6 +780,7 @@ def to_chrome_trace(
     like span events, so KV occupancy / queue depth / memory ride the
     same timeline as the requests that waited on them.
     """
+    exports = list(exports)
     evs = stitch(exports, req_id)
     out: list[dict] = []
     pids: dict[str, int] = {}
@@ -637,13 +793,30 @@ def to_chrome_trace(
         proc = ex.get("proc", "?")
         for s in ex.get("counters", ()):
             csamples.append((proc, base + s.get("t", 0.0), s.get("tracks") or {}))
-    t0 = evs[0]["ts_wall"] if evs else 0.0
-    if csamples:
-        ct0 = min(ts for _, ts, _ in csamples)
-        t0 = min(t0, ct0) if evs else ct0
+    # (proc, ts_wall of the start, span tuple), once per (proc, seq)
+    lspans: list[tuple[str, float, list]] = []
+    seen_spans = set()
+    for ex in exports:
+        base = ex.get("wall_anchor", 0.0) - ex.get("mono_anchor", 0.0)
+        proc = ex.get("proc", "?")
+        for sp in (ex.get("loop") or {}).get("spans", ()):
+            ts = base + sp[3]
+            if (proc, sp[0]) in seen_spans or (req_id is not None and (
+                not evs or ts > evs[-1]["ts_wall"]
+                or ts + sp[4] < evs[0]["ts_wall"]
+            )):
+                continue
+            seen_spans.add((proc, sp[0]))
+            lspans.append((proc, ts, sp))
+    starts = [evs[0]["ts_wall"]] if evs else []
+    starts += [ts for _, ts, _ in csamples] + [ts for _, ts, _ in lspans]
+    t0 = min(starts, default=0.0)
     for e in evs:
         pid = pids.setdefault(e["proc"], len(pids) + 1)
         tids.setdefault((e["proc"], e["req_id"]), len(tids) + 1)
+    for proc, _ts_w, _sp in lspans:
+        pids.setdefault(proc, len(pids) + 1)
+        tids.setdefault((proc, LOOP_LANE), len(tids) + 1)
     for proc, _ts_w, _tracks in csamples:
         pids.setdefault(proc, len(pids) + 1)
     for proc, pid in pids.items():
@@ -674,6 +847,13 @@ def to_chrome_trace(
                 "ph": "i", "pid": pid, "tid": tid, "name": e["name"],
                 "cat": "event", "ts": ts, "s": "t", "args": args,
             })
+    for proc, ts_wall, (seq, parent, name, _t, dur, attrs) in lspans:
+        out.append({
+            "ph": "X", "pid": pids[proc], "tid": tids[(proc, LOOP_LANE)],
+            "name": name, "cat": "loop", "ts": (ts_wall - t0) * 1e6,
+            "dur": dur * 1e6,
+            "args": {**(attrs or {}), "seq": seq, "parent": parent},
+        })
     for proc, ts_wall, tracks in csamples:
         pid = pids[proc]
         for track, values in tracks.items():

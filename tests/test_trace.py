@@ -12,7 +12,11 @@ The tentpole claims pinned here:
   consistent timestamps even under (simulated) cross-process clock skew —
   the one-wall-anchor-per-export discipline is what makes that true;
 - tracing off records nothing, and tracing on adds zero steady-state
-  recompiles (the instrumentation is host-side only).
+  recompiles (the instrumentation is host-side only);
+- the loop track: a bounded ring of request-less spans that name their
+  parent, one ``sched.dispatch`` a decode group whatever the group's length,
+  the same steps in ``EngineMetrics``, and the seams of a request's way to
+  its first token (``prefill_dispatch``, ``first_write``) in order.
 """
 
 import json
@@ -31,7 +35,7 @@ from llmss_tpu.serve.chaos import (
 )
 from llmss_tpu.serve.handoff import DecodeWorker, PrefillWorker
 from llmss_tpu.serve.producer import ProducerServer
-from llmss_tpu.serve.protocol import GenerateRequest
+from llmss_tpu.serve.protocol import GenerateRequest, GenerateResponse
 from llmss_tpu.utils import trace
 from llmss_tpu.utils.trace import FlightRecorder
 
@@ -80,12 +84,12 @@ def test_recorder_sheds_group_spam_before_lifecycle_events():
     rec = FlightRecorder(max_events=4, proc="p")
     rec.record("r", "enqueue")
     for _ in range(3):
-        rec.record("r", "group_fetch")
+        rec.record("r", "group_dispatch")
     # At capacity a lifecycle event evicts a sheddable one, never the
     # other way around...
     rec.record("r", "respond")
     names = [e["name"] for e in rec.events_for("r")]
-    assert names.count("group_fetch") == 2
+    assert names.count("group_dispatch") == 2
     assert names[0] == "enqueue" and names[-1] == "respond"
     # ...and new sheddable events at capacity are simply dropped.
     rec.record("r", "group_dispatch")
@@ -116,6 +120,157 @@ def test_span_records_duration_error_and_is_idempotent():
     s.end()
     s.end()  # idempotent: one event, not two
     assert len(rec.events_for("r")) == 3
+
+
+# -- the loop track -----------------------------------------------------------
+
+
+def test_loop_ring_is_bounded_and_counts_what_it_dropped():
+    rec = FlightRecorder(proc="p", max_loop_spans=4)
+    for i in range(6):
+        rec.start_loop_span("loop").end(iteration=i)
+    loop = rec.export()["loop"]
+    assert loop["dropped"] == 2
+    assert [sp[5]["iteration"] for sp in loop["spans"]] == [2, 3, 4, 5]
+    assert [sp[0] for sp in loop["spans"]] == [3, 4, 5, 6]  # seq from 1
+    rec.clear()
+    assert rec.export()["loop"] == {"spans": [], "dropped": 0}
+
+
+def test_loop_ring_loses_no_span_under_contention():
+    """Several loops (in-process replicas share the recorder) end spans while
+    an exporter reads the ring: every span is either in it or counted."""
+    import sys
+
+    rec = FlightRecorder(proc="p", max_loop_spans=64)
+    n_threads, per_thread = 16, 400
+    stop = threading.Event()
+
+    def work():
+        for _ in range(per_thread):
+            rec.start_loop_span("loop").end()
+
+    def read():
+        while not stop.is_set():
+            rec.export()
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        reader = threading.Thread(target=read)
+        reader.start()
+        workers = [threading.Thread(target=work) for _ in range(n_threads)]
+        for t in workers:
+            t.start()
+        for t in workers:
+            t.join(timeout=60)
+        stop.set()
+        reader.join(timeout=10)
+    finally:
+        sys.setswitchinterval(old)
+    assert not reader.is_alive() and not any(t.is_alive() for t in workers)
+    loop = rec.export()["loop"]
+    assert len(loop["spans"]) + loop["dropped"] == n_threads * per_thread
+    assert len({sp[0] for sp in loop["spans"]}) == 64  # every seq once
+
+
+def test_loop_spans_name_their_parent_and_feed_their_hooks():
+    rec = FlightRecorder(proc="p")
+    closed, entered = [], []
+
+    class Ann:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            entered.append(("in", self.name))
+
+        def __exit__(self, *exc):
+            entered.append(("out", self.name))
+
+    def on_close(name, seconds):
+        closed.append((name, seconds))
+
+    with rec.start_loop_span("loop", on_close=on_close, annotate=Ann) as it:
+        with rec.start_loop_span("sched.dispatch", it.seq, on_close, Ann) as d:
+            d.set(group=7)
+            d.set(chunks=1, k=4)
+        with pytest.raises(RuntimeError):
+            with rec.start_loop_span("sched.callback", it.seq):
+                raise RuntimeError("boom")
+        it.end(iteration=0)
+        it.end()  # idempotent: one span, one hook call
+    spans = {sp[2]: sp for sp in rec.loop_spans()}
+    assert len(rec.loop_spans()) == 3
+    seq, parent, _name, t0, dur, attrs = spans["sched.dispatch"]
+    assert parent == spans["loop"][0] and spans["loop"][1] is None
+    assert attrs == {"group": 7, "chunks": 1, "k": 4}
+    assert spans["sched.callback"][5] == {"error": "RuntimeError"}
+    # children end first and lie inside the parent, on one clock
+    assert [sp[2] for sp in rec.loop_spans()][-1] == "loop"
+    lt0, ldur = spans["loop"][3], spans["loop"][4]
+    assert lt0 <= t0 and t0 + dur <= lt0 + ldur
+    assert [n for n, _s in closed] == ["sched.dispatch", "loop"]
+    assert closed[0][1] == dur
+    assert entered == [("in", "loop"), ("in", "sched.dispatch"),
+                       ("out", "sched.dispatch"), ("out", "loop")]
+
+
+def test_loop_track_off_is_one_shared_noop():
+    trace.set_enabled(False)
+    a = trace.loop_span("loop")
+    b = trace.loop_span("sched.plan", 3, on_close=lambda *_: 1 / 0)
+    assert a is b is trace.NO_LOOP_SPAN and a.seq is None
+    with b as sp:
+        sp.set(x=1)
+    b.end(y=2)
+    assert trace.recorder().loop_spans() == []
+    assert trace.recorder().export()["loop"] == {"spans": [], "dropped": 0}
+
+
+def test_export_keeps_its_keys_beside_the_loop_track():
+    rec = FlightRecorder(proc="p")
+    rec.record("r", "enqueue")
+    rec.start_loop_span("loop").end()
+    full = rec.export()
+    assert {"proc", "mono_anchor", "wall_anchor", "requests"} < set(full)
+    assert set(full) - {"proc", "mono_anchor", "wall_anchor", "requests"} == {
+        "loop"}
+    assert list(full["requests"]["r"]) == ["trace_id", "dropped", "events"]
+    json.dumps(full)  # JSON-safe: spans are lists, attrs plain
+    # the bounded exports (heartbeats, one request) stay as small as before
+    assert "loop" not in rec.export(max_events=8)
+    assert "loop" not in rec.export(req_ids={"r"})
+
+
+def test_chrome_trace_draws_the_loop_as_one_lane():
+    rec = FlightRecorder(proc="w0")
+    rec.record("r1", "enqueue")
+    with rec.start_loop_span("loop") as it:
+        with rec.start_loop_span("sched.dispatch", it.seq) as d:
+            d.set(group=1)
+            rec.record("r1", "group_dispatch", loop=d.seq)
+    time.sleep(0.002)
+    rec.record("r1", "respond")
+    time.sleep(0.002)
+    rec.start_loop_span("loop").end()  # after r1's last event
+    ct = trace.to_chrome_trace([rec.export()])
+    lanes = [e for e in ct["traceEvents"]
+             if e["ph"] == "M" and e["args"]["name"] == trace.LOOP_LANE]
+    assert len(lanes) == 1
+    drawn = [e for e in ct["traceEvents"] if e.get("cat") == "loop"]
+    assert [e["name"] for e in drawn] == ["sched.dispatch", "loop", "loop"]
+    assert {e["tid"] for e in drawn} == {lanes[0]["tid"]}
+    assert all(e["ph"] == "X" for e in drawn)
+    assert all(e["ts"] >= 0 for e in ct["traceEvents"] if e["ph"] != "M")
+    disp, it0 = drawn[0], drawn[1]
+    assert disp["args"]["parent"] == it0["args"]["seq"]
+    assert disp["args"]["group"] == 1
+    assert it0["ts"] <= disp["ts"]
+    assert disp["ts"] + disp["dur"] <= it0["ts"] + it0["dur"] + 1e-3
+    # one request's timeline draws only the spans that overlap it
+    one = trace.to_chrome_trace([rec.export()], req_id="r1")
+    assert len([e for e in one["traceEvents"] if e.get("cat") == "loop"]) == 2
 
 
 def test_export_budget_keeps_most_recent():
@@ -455,14 +610,11 @@ from llmss_tpu.engine.scheduler import ContinuousBatcher  # noqa: E402
 from llmss_tpu.models.common import DecoderConfig  # noqa: E402
 from llmss_tpu.models.decoder import init_params  # noqa: E402
 from llmss_tpu.parallel import MeshPlan, make_mesh  # noqa: E402
+from llmss_tpu.serve.consumer import ContinuousWorker  # noqa: E402
 
 
-def test_tracing_adds_no_steady_state_recompiles(devices):
-    """The instrumentation is host-side only: with tracing ON and traced
-    req_ids flowing through the scheduler, a warmed batcher must hit the
-    jit caches exactly as before — zero new compiles."""
-    from llmss_tpu.analysis import CompileGuard
-
+@pytest.fixture(scope="module")
+def toy_engine(devices):
     cfg = DecoderConfig(
         model_type="llama", vocab_size=64, hidden_size=32, n_layers=2,
         n_heads=4, n_kv_heads=2, head_dim=8, intermediate_size=64,
@@ -473,7 +625,16 @@ def test_tracing_adds_no_steady_state_recompiles(devices):
     )
     mesh = make_mesh(MeshPlan(dp=2, tp=4))
     params = init_params(cfg, mesh, jax.random.key(0))
-    engine = DecodeEngine(cfg, params, mesh, max_seq_len=64)
+    return DecodeEngine(cfg, params, mesh, max_seq_len=64)
+
+
+def test_tracing_adds_no_steady_state_recompiles(toy_engine):
+    """The instrumentation is host-side only: with tracing ON and traced
+    req_ids flowing through the scheduler, a warmed batcher must hit the
+    jit caches exactly as before — zero new compiles."""
+    from llmss_tpu.analysis import CompileGuard
+
+    engine = toy_engine
     batcher = ContinuousBatcher(
         engine, rows=2, chunk_steps=2, group_chunks=2,
     )
@@ -493,3 +654,184 @@ def test_tracing_adds_no_steady_state_recompiles(devices):
     assert len(got) == 2
     names = {e["name"] for e in trace.recorder().events_for("g0")}
     assert {"sched_submit", "admit", "finish"} <= names
+
+
+def _loop_spans(name):
+    return [sp for sp in trace.recorder().loop_spans() if sp[2] == name]
+
+
+@pytest.mark.parametrize("tracing", [True, False])
+def test_one_step_is_one_dispatch_span_and_steps_are_counted(
+    toy_engine, tracing,
+):
+    """Every ``step()`` that dispatches a group leaves exactly one
+    ``sched.dispatch`` span with that group's ``chunks`` x ``k``, however
+    short the group (here far under the 50 ms by which the per-request
+    ``group_dispatch`` events are throttled), and ``EngineMetrics`` counts
+    the same steps with tracing on or off."""
+    trace.set_enabled(tracing)
+    batcher = ContinuousBatcher(
+        toy_engine, rows=2, chunk_steps=2, group_chunks=2,
+    )
+    m = toy_engine.metrics
+    before = m.to_dict()["loop"]
+    gen = GenerationParams(max_new_tokens=24, is_greedy=True)
+    for i, p in enumerate([[5, 9], [3, 14, 15]]):
+        batcher.submit(p, gen, lambda t: None, req_id=f"s{i}")
+    groups = []
+    while not batcher.idle:
+        n_disp = len(_loop_spans("sched.dispatch"))
+        g0 = m.groups_dispatched
+        batcher.step()
+        if m.groups_dispatched > g0:
+            groups.append(batcher._inflight)
+            assert len(_loop_spans("sched.dispatch")) - n_disp == int(tracing)
+    after = m.to_dict()["loop"]
+    steps = sum(g.n_chunks * g.k for g in groups)
+    assert len(groups) >= 6 and steps > 0
+    assert after["decode_steps"] - before["decode_steps"] == steps
+    disp = _loop_spans("sched.dispatch")
+    if not tracing:
+        assert disp == [] and after["spans"] == before["spans"]
+        return
+    assert [(sp[5]["group"], sp[5]["chunks"] * sp[5]["k"]) for sp in disp] == [
+        (g.no, g.n_chunks * g.k) for g in groups]
+    assert all(sp[5]["kind"] == "decode_group" and sp[5]["rows_live"] >= 1
+               for sp in disp)
+    # the groups came faster than the per-request events are recorded
+    gaps = [b[3] - a[3] for a, b in zip(disp, disp[1:])]
+    assert min(gaps) < 0.05
+    per_request = [e for e in trace.recorder().events_for("s0")
+                   if e["name"] == "group_dispatch"]
+    assert len(per_request) < len(groups)
+    assert {e["attrs"]["loop"] for e in per_request} <= {sp[0] for sp in disp}
+    # a group's fetch and callback carry its number, one step later; the
+    # wait for an admission's first tokens is a fetch_wait of its own
+    waits = [sp[5] for sp in _loop_spans("sched.fetch_wait")]
+    assert [a["group"] for a in waits if "group" in a] == [
+        g.no for g in groups]
+    assert sum(a.get("admission", 0) for a in waits) == 2
+    assert [sp[5]["group"] for sp in _loop_spans("sched.callback")] == [
+        g.no for g in groups]
+    counted = after["spans"]["sched.dispatch"]["count"] - (
+        before["spans"].get("sched.dispatch", {}).get("count", 0))
+    assert counted == len(groups)
+
+
+@pytest.mark.parametrize("chunked", [None, 4])
+def test_first_token_seams_lie_in_order_for_every_request(toy_engine, chunked):
+    """Over HTTP, streamed: ``enqueue`` <= ``lease`` <= ``sched_submit`` <=
+    ``prefill_dispatch`` <= ``admit`` <= ``first_write`` for every request,
+    on the bucketed and on the chunked admission path, and every loop span
+    of the worker hangs under one ``loop`` span an iteration."""
+    eng = toy_engine if chunked is None else DecodeEngine(
+        toy_engine.cfg, toy_engine.params, toy_engine.mesh, max_seq_len=64,
+        kv_layout="paged",
+    )
+    broker = InProcBroker()
+    worker = ContinuousWorker(
+        eng, broker, rows=2, poll_timeout_s=0.01, chunk_steps=2,
+        chunked_prefill=chunked,
+    )
+    stop = threading.Event()
+    t = threading.Thread(target=worker.run_forever, args=(stop,), daemon=True)
+    t.start()
+    server = ProducerServer(broker, host="127.0.0.1", port=0, timeout_s=60)
+    server.start()
+    ids = [f"f{chunked}-{i}" for i in range(4)]
+    try:
+        def one(rid, n):
+            with httpx.stream(
+                "POST", f"http://127.0.0.1:{server.port}/generate",
+                json={"id": rid, "token_ids": list(range(3, 3 + n)),
+                      "max_new_tokens": 6, "is_greedy": True, "stream": True},
+                timeout=60,
+            ) as r:
+                assert r.status_code == 200
+                for _line in r.iter_lines():
+                    pass
+
+        threads = [threading.Thread(target=one, args=(rid, 5 + 3 * i))
+                   for i, rid in enumerate(ids)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=120)
+    finally:
+        stop.set()
+        t.join(timeout=30)
+        server.stop()
+    seams = ["enqueue", "lease", "sched_submit", "prefill_dispatch", "admit",
+             "first_write"]
+    spans = {sp[0]: sp for sp in trace.recorder().loop_spans()}
+    for i, rid in enumerate(ids):
+        first = {}
+        for e in trace.recorder().events_for(rid):
+            first.setdefault(e["name"], e)
+        assert set(seams) <= set(first), (rid, sorted(first))
+        times = [first[n]["t"] for n in seams]
+        assert times == sorted(times), (rid, dict(zip(seams, times)))
+        pd = first["prefill_dispatch"]["attrs"]
+        assert pd["tokens"] == 5 + 3 * i
+        cause = "sched.plan" if chunked else "sched.admit"
+        assert spans[pd["loop"]][2] == cause
+        assert spans[first["admit"]["attrs"]["loop"]][2] in (
+            "sched.resolve", "sched.callback")
+    loops = {seq for seq, sp in spans.items() if sp[2] == "loop"}
+    children = [sp for sp in spans.values() if sp[2] != "loop"]
+    assert children and all(
+        sp[1] in loops or spans[sp[1]][2] == "loop.drain" for sp in children
+        if sp[1] in spans)
+    assert all(spans[sp[1]][2] == "loop.drain" for sp in children
+               if sp[2] == "loop.idle" and sp[1] in spans)
+    per_loop = toy_engine.metrics.to_dict()["loop"]["spans"]
+    assert per_loop["loop"]["count"] >= per_loop["sched.dispatch"]["count"] > 0
+    assert {"loop.housekeep", "loop.drain", "sched.plan", "sched.fetch_wait",
+            "sched.callback", "loop.publish"} <= set(per_loop)
+
+
+def test_stream_handler_writes_a_token_whenever_it_comes():
+    """``admit`` -> ``first_write`` is the stream channel and the handler's
+    wake-up, not a poll: a first token pushed 105-145 ms after the request
+    (where the handler used to sit 50 ms in its look for the terminal
+    response) is written at once."""
+    broker = InProcBroker()
+    server = ProducerServer(broker, host="127.0.0.1", port=0, timeout_s=30)
+    server.start()
+    lags = []
+    try:
+        for i, wait_s in enumerate((0.105, 0.115, 0.125, 0.135, 0.145)):
+            rid = f"lag-{i}"
+
+            def work(rid=rid, wait_s=wait_s):
+                req = broker.pop_request(timeout=10)
+                time.sleep(wait_s)
+                pushed = time.monotonic()
+                broker.push_stream(req.id, [7])
+                while not any(
+                    e["name"] == "first_write"
+                    for e in trace.recorder().events_for(rid)
+                ) and time.monotonic() < pushed + 5:
+                    time.sleep(0.001)
+                broker.push_response(GenerateResponse(id=rid, token_ids=[7]))
+                lags.append(pushed)
+
+            th = threading.Thread(target=work, daemon=True)
+            th.start()
+            with httpx.stream(
+                "POST", f"http://127.0.0.1:{server.port}/generate",
+                json={"id": rid, "token_ids": [3, 4], "max_new_tokens": 1,
+                      "is_greedy": True, "stream": True},
+                timeout=30,
+            ) as r:
+                assert r.status_code == 200
+                for _line in r.iter_lines():
+                    pass
+            th.join(timeout=30)
+            wrote = next(
+                e["t"] for e in trace.recorder().events_for(rid)
+                if e["name"] == "first_write")
+            lags[-1] = wrote - lags[-1]
+    finally:
+        server.stop()
+    assert len(lags) == 5 and sorted(lags)[2] < 0.015, lags

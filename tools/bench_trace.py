@@ -16,7 +16,7 @@ run_once stepping (deterministic; no scheduler-jitter noise). Each mode
 runs REPEATS times; best-of is compared (best-of isolates the code path
 from machine noise, which is the honest comparison for a <2% question).
 
-Two numbers come out:
+Three numbers come out:
 
 - ``host_overhead_us_per_request`` — the raw instrumentation microcost,
   measured with zero simulated chip time (every microsecond is tracing).
@@ -25,7 +25,16 @@ Two numbers come out:
   cost-model convention; the default 2 ms/chunk is conservative — real
   fused-step times are larger, which shrinks the relative overhead).
 
-Runs on CPU in one process (no JAX, no device). Writes TRACE_BENCH.json;
+- ``loop_overhead_us_per_iteration`` — what tracing adds to ONE iteration
+  of ``ContinuousWorker.run_once`` (the loop track's spans and counters,
+  the per-request ``group_dispatch`` events) with ``LOOP_LIVE`` of
+  ``LOOP_ROWS`` rows decoding, the occupancy of the benchmark's
+  ``starcoderbase-1b.gen`` cell: host CPU time of the loop's thread per
+  iteration, on less off, over a toy model on the CPU backend (the model's
+  own compute runs in XLA's threads and is not in it).
+
+The first two passes run on CPU in one process with no JAX and no device;
+the third runs a toy model on JAX's CPU backend. Writes TRACE_BENCH.json;
 prints one JSON line. Asserts zero lost requests in both modes and that
 the traced mode leaves a complete timeline for a sampled request.
 """
@@ -34,6 +43,7 @@ from __future__ import annotations
 
 import json
 import os
+import statistics
 import sys
 import time
 
@@ -92,6 +102,73 @@ def run_once(enabled: bool, chunk_delay_s: float = 0.0) -> float:
     return elapsed
 
 
+LOOP_ROWS, LOOP_LIVE, LOOP_ITERATIONS = 64, 40, 200
+
+
+def loop_engine():
+    """A toy decoder on the CPU backend, wide enough in rows and positions
+    for ``LOOP_LIVE`` requests to decode through a whole timed pass."""
+    import jax
+
+    from llmss_tpu.engine import DecodeEngine
+    from llmss_tpu.models.common import DecoderConfig
+    from llmss_tpu.models.decoder import init_params
+    from llmss_tpu.parallel import MeshPlan, make_mesh
+
+    cfg = DecoderConfig(
+        model_type="llama", vocab_size=64, hidden_size=32, n_layers=1,
+        n_heads=4, n_kv_heads=2, head_dim=8, intermediate_size=64,
+        max_position_embeddings=2048, activation="silu", norm="rmsnorm",
+        norm_eps=1e-5, mlp="swiglu", positions="rotary", rope_style="half",
+        rotary_dim=8, attn_bias=False, mlp_bias=False,
+        tie_word_embeddings=False, dtype="float32",
+    )
+    mesh = make_mesh(MeshPlan(), devices=jax.devices()[:1])
+    params = init_params(cfg, mesh, jax.random.key(0))
+    return DecodeEngine(cfg, params, mesh, max_seq_len=2048)
+
+
+def loop_pass(engine) -> dict[str, tuple[float, float]]:
+    """``2 x LOOP_ITERATIONS`` iterations of the worker loop with
+    ``LOOP_LIVE`` rows decoding, tracing switched at every iteration's
+    boundary (off, on, off, ...): the two modes see the same batch, the same
+    cache state and the same neighbours on a shared host. Returns the median
+    (thread CPU, wall) microseconds of an iteration for each mode."""
+    from llmss_tpu.serve.consumer import ContinuousWorker
+
+    trace.set_enabled(False)
+    trace.recorder().clear()
+    b = InProcBroker(lease_s=600.0)
+    w = ContinuousWorker(engine, b, rows=LOOP_ROWS, poll_timeout_s=0.0)
+    for i in range(LOOP_LIVE):
+        b.push_request(GenerateRequest(
+            id=f"l{i}", token_ids=[(i + j) % 60 + 1 for j in range(8)],
+            max_new_tokens=2000, is_greedy=True,
+        ))
+    for _ in range(4):  # admit, resolve, and one group in flight
+        w.run_once()
+    assert len(w.batcher.active) == LOOP_LIVE
+    cpu = {"off": [], "on": []}
+    wall = {"off": [], "on": []}
+    for i in range(2 * LOOP_ITERATIONS):
+        mode = ("off", "on")[i % 2]
+        trace.set_enabled(mode == "on")
+        c0, t0 = time.thread_time(), time.perf_counter()
+        w.run_once()
+        cpu[mode].append(time.thread_time() - c0)
+        wall[mode].append(time.perf_counter() - t0)
+    assert len(w.batcher.active) == LOOP_LIVE  # nobody finished meanwhile
+    iterations = [
+        sp for sp in trace.recorder().loop_spans() if sp[2] == "loop"
+    ]
+    assert len(iterations) == LOOP_ITERATIONS
+    w.abort_inflight("bench over")
+    return {
+        m: (statistics.median(cpu[m]) * 1e6, statistics.median(wall[m]) * 1e6)
+        for m in ("off", "on")
+    }
+
+
 def main() -> int:
     # Pass 1 — zero chip time: the instrumentation microcost itself.
     host = {"on": float("inf"), "off": float("inf")}
@@ -107,6 +184,13 @@ def main() -> int:
             best[mode] = min(
                 best[mode], run_once(mode == "on", DECODE_STEP_COST_S),
             )
+    # Pass 3 — one iteration of the continuous worker's loop, on and off.
+    engine = loop_engine()
+    loop_pass(engine)  # compiles; not timed
+    loop = {"on": (float("inf"),) * 2, "off": (float("inf"),) * 2}
+    for _ in range(REPEATS):
+        for mode, got in loop_pass(engine).items():
+            loop[mode] = min(loop[mode], got)
     trace.set_enabled(True)  # restore the default
 
     tokens = N_REQUESTS * MAX_NEW
@@ -126,6 +210,14 @@ def main() -> int:
         "tok_per_s_tracing_on": round(tput_on, 1),
         "overhead_pct": round(overhead_pct, 2),
         "within_2pct": overhead_pct < 2.0,
+        "loop_rows_live": LOOP_LIVE,
+        "loop_iterations": LOOP_ITERATIONS,
+        "loop_cpu_us_per_iteration_tracing_off": round(loop["off"][0], 1),
+        "loop_cpu_us_per_iteration_tracing_on": round(loop["on"][0], 1),
+        "loop_overhead_us_per_iteration": round(
+            loop["on"][0] - loop["off"][0], 1),
+        "loop_wall_us_per_iteration_tracing_off": round(loop["off"][1], 1),
+        "loop_wall_us_per_iteration_tracing_on": round(loop["on"][1], 1),
     }
     with open("TRACE_BENCH.json", "w") as f:
         json.dump(out, f, indent=2)
