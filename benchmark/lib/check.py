@@ -1,7 +1,14 @@
 """The comparison that decides the numerical half of ``correct``: the
-engine's prefill logits and one cached decode step through the paged cache,
+engine's prefill logits and one cached decode step through its cache,
 against the configuration's plain float32 reference. Runs in the server
 process, after the window, outside every timing.
+
+What is the yardstick's is here: the prompts, the two engine calls, the
+float32 forward of prompt + first token one layer at a time, the error
+statistic, the tolerance, and the rule that a control must FAIL it. What is a
+model family's is in ``benchmark/reference/<model_type>.py``: the config's
+keys, the parameter tree's layout, the layers and their kinds, and the one
+fault its control plants (the contract is in benchmark/README.md).
 
 ``engine_logits``, ``logits_error`` and the tolerance are taken over from
 ``chip_smoke.py`` (copied, not imported: a later PR may change the smoke,
@@ -22,10 +29,11 @@ from __future__ import annotations
 # (mean 0.092, s.d. 0.009; my chip runs, PR 23), so 0.1 would fail every
 # other run of a correct engine; 0.15 is six of those deviations above the
 # mean. It still sits far below what a wrong model does: one dropped bias
-# reads 0.18-0.23, the negative control every check makes (all of the
-# blocks' projection biases dropped, as a loader that skips biases would)
-# more, and a compute type with fewer mantissa bits than bfloat16 several
-# times the bound. float32: accumulation-order noise, measured near 1e-6.
+# reads 0.18-0.23, the negative control every check makes (the reference
+# module's ``control``: for the two families with biases, all of the blocks'
+# projection biases dropped, as a loader that skips biases would) more, and
+# a compute type with fewer mantissa bits than bfloat16 several times the
+# bound. float32: accumulation-order noise, measured near 1e-6.
 # Two bf16 engines compared with each other get twice the bf16 bound.
 LOGITS_TOL = {"float32": 2e-3, "bfloat16": 0.15}
 
@@ -37,7 +45,7 @@ def load_reference(model_type: str):
 
 def engine_logits(engine, prompts, *, params=None):
     """Next-token logits from the engine's prefill, and from one decode step
-    through the paged cache on the token the prefill picked (greedy).
+    through the engine's cache on the token the prefill picked (greedy).
     Returns ``(prefill [B, V], decode [B, V], first [B])``."""
     import jax.numpy as jnp
     import numpy as np
@@ -64,7 +72,9 @@ def reference_logits(ref, hf: dict, params, prompts, first):
     """The reference's logits at each prompt's last position and at the
     position of ``first`` appended to it: one full forward of prompt+[first],
     sequences padded at the END to one length (causal, so padding cannot
-    reach an earlier position)."""
+    reach an earlier position). The module yields its own layers in order,
+    ``(kind, lp)`` each; one layer at a time is upcast and run, and one
+    program is jitted a kind."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -74,11 +84,14 @@ def reference_logits(ref, hf: dict, params, prompts, first):
     ids = np.zeros((B, int(lens.max()) + 1), np.int32)
     for i, (p, t) in enumerate(zip(prompts, first)):
         ids[i, : len(p) + 1] = list(p) + [t]
+    steps = {}
     with jax.default_matmul_precision("highest"):
         h = ref.embed(hf, params, jnp.asarray(ids))
-        step = jax.jit(lambda lp, x: ref.layer(hf, lp, x))
-        for l in range(hf["n_layer"]):
-            h = step(jax.tree.map(lambda a: a[l], params["blocks"]), h)
+        for kind, lp in ref.layers(hf, params):
+            if kind not in steps:
+                steps[kind] = jax.jit(
+                    lambda lp, x, kind=kind: ref.layer(hf, kind, lp, x))
+            h = steps[kind](lp, h)
         rows = jnp.arange(B)
         pre = ref.head(hf, params, h[rows, lens - 1])
         dec = ref.head(hf, params, h[rows, lens])
@@ -109,33 +122,42 @@ def check_prompts(vocab: int, seed: int, lo: int, hi: int, n: int = 4):
     ]
 
 
-def reference_check(engine, hf: dict, seed: int, lo: int, hi: int) -> dict:
-    """Engine against reference on 4 seeded prompts of ``lo``..``hi`` tokens,
-    plus the negative control: with every projection bias of the blocks
-    dropped the same comparison must FAIL the tolerance."""
-    import jax
-
-    ref = load_reference(hf["model_type"])
-    prompts = check_prompts(hf["vocab_size"], seed, lo, hi)
-    got_pre, got_dec, first = engine_logits(engine, prompts)
-    ref_pre, ref_dec = reference_logits(ref, hf, engine.params, prompts, first)
-    tol = LOGITS_TOL[str(engine.cfg.compute_dtype)]
+def compare(ref, hf: dict, params, prompts, run, tol: float) -> dict:
+    """``run(params)`` - the system under test: ``(prefill [B, V], decode
+    [B, V], first [B])`` on ``prompts`` - against the reference on the same
+    parameters, and the negative control: ``run`` on the parameters with the
+    reference module's one fault planted must FAIL the tolerance. A control
+    that passes (a fault that changes nothing, a comparison that cannot see
+    it) makes ``ok`` false."""
+    got_pre, got_dec, first = run(params)
+    ref_pre, ref_dec = reference_logits(ref, hf, params, prompts, first)
     errs = {"prefill": logits_error(got_pre, ref_pre),
             "decode": logits_error(got_dec, ref_dec)}
-    blocks = {
-        k: p._replace(b=p.b * 0) if getattr(p, "b", None) is not None else p
-        for k, p in engine.params["blocks"].items()
-    }
-    dropped = {**engine.params, "blocks": blocks}
-    jax.block_until_ready(dropped)
-    ctl_pre, _, _ = engine_logits(engine, prompts, params=dropped)
+    fault, faulty = ref.control(params)
+    ctl_pre, _, _ = run(faulty)
     control = logits_error(ctl_pre, ref_pre)
     worst = max(errs.values())
     return {
-        "tolerance": tol, **errs, "control_dropped_bias": control,
+        "tolerance": tol, **errs, "control": control, "control_fault": fault,
         "rms": {"prefill": logits_error(got_pre, ref_pre, rms=True),
                 "decode": logits_error(got_dec, ref_dec, rms=True),
                 "control": logits_error(ctl_pre, ref_pre, rms=True)},
         "prompt_lens": [len(p) for p in prompts],
         "ok": bool(worst < tol and control > tol),
     }
+
+
+def reference_check(engine, hf: dict, seed: int, lo: int, hi: int) -> dict:
+    """Engine against reference on 4 seeded prompts of ``lo``..``hi``
+    tokens, with the family's negative control."""
+    import jax
+
+    ref = load_reference(hf["model_type"])
+    prompts = check_prompts(hf["vocab_size"], seed, lo, hi)
+
+    def run(params):
+        jax.block_until_ready(params)
+        return engine_logits(engine, prompts, params=params)
+
+    return compare(ref, hf, engine.params, prompts, run,
+                   LOGITS_TOL[str(engine.cfg.compute_dtype)])

@@ -62,7 +62,7 @@ def _ln(x, p, eps):
     return (x - mu) / jnp.sqrt(var + eps) * p.scale.astype(F32) + p.bias.astype(F32)
 
 
-def layer(hf: dict, lp, h):
+def layer(hf: dict, kind: str, lp, h):
     """One block on ``h`` [B, T, E] (float32); ``lp`` is one layer's slice of
     the engine's ``params["blocks"]``, still in its stored dtype."""
     B, T, E = h.shape
@@ -85,6 +85,23 @@ def layer(hf: dict, lp, h):
     x = _ln(h, lp["ln2"], eps)
     y = _act(hf["activation_function"])(x @ f(lp["fc_in"].w) + f(lp["fc_in"].b))
     return h + y @ f(lp["fc_out"].w) + f(lp["fc_out"].b)
+
+
+def layers(hf: dict, params):
+    """This family's layers in order, ``(kind, lp)`` each: one kind, every
+    leaf of ``params["blocks"]`` stacked over ``n_layer``."""
+    for l in range(hf["n_layer"]):
+        yield "block", jax.tree.map(lambda a: a[l], params["blocks"])
+
+
+def control(params):
+    """The negative control's one fault: every projection bias of the blocks
+    dropped, as a loader that skips biases would leave them."""
+    blocks = {
+        k: p._replace(b=p.b * 0) if getattr(p, "b", None) is not None else p
+        for k, p in params["blocks"].items()
+    }
+    return "dropped_bias", {**params, "blocks": blocks}
 
 
 def embed(hf: dict, params, ids):

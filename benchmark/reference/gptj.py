@@ -67,7 +67,7 @@ def _rotate(x, rotary_dim: int):
     return jnp.concatenate([out.reshape(rot.shape), rest], -1)
 
 
-def layer(hf: dict, lp, h):
+def layer(hf: dict, kind: str, lp, h):
     B, T, E = h.shape
     H = hf["n_head"]
     D = E // H
@@ -84,6 +84,23 @@ def layer(hf: dict, lp, h):
     attn = a.reshape(B, T, E) @ f(lp["o"].w)
     y = _act(hf["activation_function"])(x @ f(lp["fc_in"].w) + f(lp["fc_in"].b))
     return h + attn + y @ f(lp["fc_out"].w) + f(lp["fc_out"].b)
+
+
+def layers(hf: dict, params):
+    """This family's layers in order, ``(kind, lp)`` each: one kind, every
+    leaf of ``params["blocks"]`` stacked over ``n_layer``."""
+    for l in range(hf["n_layer"]):
+        yield "block", jax.tree.map(lambda a: a[l], params["blocks"])
+
+
+def control(params):
+    """The negative control's one fault: every projection bias of the blocks
+    dropped, as a loader that skips biases would leave them."""
+    blocks = {
+        k: p._replace(b=p.b * 0) if getattr(p, "b", None) is not None else p
+        for k, p in params["blocks"].items()
+    }
+    return "dropped_bias", {**params, "blocks": blocks}
 
 
 def embed(hf: dict, params, ids):

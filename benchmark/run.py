@@ -11,9 +11,10 @@ real sockets, and prints ONE JSON object as the last line of stdout:
 ``breakdown`` with ``--trace 1``). Everything else it says goes to stderr or
 to earlier stdout lines. See benchmark/README.md.
 
-    python3 benchmark/run.py --sweep <cell> [--rates 4,8,12] [--seconds 20]
+    python3 benchmark/run.py --sweep <cell> [--rates 4,8,12] [--seeds a,b,c]
 
-finds a fixed-rate cell's knee: one server, one set-up, a ladder of rates.
+finds a fixed-rate cell's knee: one server, one set-up, a ladder of rates,
+a window a rate a seed, and a row a rate with each tail's range over the seeds.
 """
 
 from __future__ import annotations
@@ -153,6 +154,7 @@ def server_spec(cell: dict, args) -> dict:
         "chips": cell["entry"]["chips"], "serve": cell["serve"],
         "traffic": cell["traffic"], "seed": args.seed,
         "trace": bool(args.trace), "allow_cpu": bool(args.allow_cpu),
+        "fault": args.fault,
     }
 
 
@@ -309,6 +311,15 @@ def label_gaps(trace: dict, records: list[dict]) -> list[list]:
     return out
 
 
+def layer_values(entries: list[dict], ctx: dict):
+    """Each per-layer metric's reader on ``ctx``: ``(entry, value)`` for
+    those that found something to read."""
+    for e in entries:
+        v = manifest.load_module("layer_metrics", e["name"]).read(ctx)
+        if v is not None:
+            yield e, v
+
+
 # -- a whole run --------------------------------------------------------------
 
 
@@ -390,10 +401,8 @@ def run_cell(args) -> int:
             "flight": flight, "flight_trace": state.get("flight_trace"),
             "trace": trace, "info": info,
         }
-        for e in cell["per_layer"]:
-            v = manifest.load_module("layer_metrics", e["name"]).read(ctx)
-            if v is not None:
-                result["metrics"][e["name"]] = {"value": v, "unit": e["unit"]}
+        for e, v in layer_values(cell["per_layer"], ctx):
+            result["metrics"][e["name"]] = {"value": v, "unit": e["unit"]}
         if trace and trace.get("devices"):
             device["busy_s"] = trace["busy_s"]
             device["window_s"] = trace["window_s"]
@@ -413,73 +422,160 @@ def run_cell(args) -> int:
             if name in e2e:
                 result["metrics"][name] = {"value": e2e[name], "unit": unit}
     print(json.dumps(result), flush=True)
+    # Each number compared beside its limit: the last lines of stderr.
+    tol = chk.get("tolerance")
+    for name, got, op, limit in [
+        ("logits prefill", chk.get("prefill"), "<", tol),
+        ("logits decode", chk.get("decode"), "<", tol),
+        (f"logits control ({chk.get('control_fault')})", chk.get("control"),
+         ">", tol),
+        ("compilations in the window", compiles_in_window, "==", 0),
+        ("/metrics counts", settled["got"], "==", settled["want"]),
+        ("faults of any kind", len(faults), "==", 0),
+    ]:
+        log(f"compared: {name} {got} {op} {limit}")
+    log(f"correct: {not faults}")
     return 0
 
 
 # -- the sweep ----------------------------------------------------------------
 
 
+def in_flight_max(records: list[dict], a: str, w0: float, w1: float) -> int:
+    """The most requests at once between their ``a`` time (``sent``: in
+    flight; ``first``: holding a row and decoding) and ``done``, at any
+    moment of the window, by the generator's own log."""
+    marks = []
+    for r in records:
+        if r.get(a) is None:
+            continue
+        end = r["done"] if r.get("done") is not None else w1
+        if r[a] <= w1 and end >= w0:
+            marks += [(max(r[a], w0), 1), (end, -1)]
+    n = most = 0
+    for _t, d in sorted(marks):  # at one instant an end sorts before a start
+        n += d
+        most = max(most, n)
+    return most
+
+
+def sweep_window(cell: dict, state: dict, flight: dict | None) -> dict:
+    """What one window of a sweep read. Sustained: >= 98% of the requests
+    due answered, and the last quarter's median first-token time under 1.5
+    times the first quarter's (a backlog that grows by more within one short
+    window is growing)."""
+    w0, w1, recs = state["w0"], state["w1"], state["records"]
+    win = [r for r in recs if r["segment"] == "window"]
+    ok = [r for r in win if not r["error"] and r["first"] is not None]
+    ttft = [(r["first"] - r["due"]) * 1e3 for r in ok]
+    q = max(1, len(ttft) // 4)
+    first_q = stats.percentile(ttft[:q], 50) if ttft else None
+    last_q = stats.percentile(ttft[-q:], 50) if ttft else None
+    e2e, info = end_to_end(state, "open")
+    done = [r for r in recs if not r["error"] and r["done"] is not None
+            and w0 <= r["done"] <= w1]
+    row = {
+        "due": len(win), "answered": len(ok),
+        "answered_share": len(ok) / max(1, len(win)),
+        "ttft_p50_ms": info.get("ttft_ms", {}).get("p50"),
+        "ttft_p90_ms": e2e.get("ttft_p90_ms"),
+        "tpot_p50_ms": info.get("tpot_ms", {}).get("p50"),
+        "tpot_p90_ms": e2e.get("tpot_p90_ms"),
+        "ttft_first_quarter_p50_ms": first_q,
+        "ttft_last_quarter_p50_ms": last_q,
+        "in_flight_max": in_flight_max(recs, "sent", w0, w1),
+        "decoding_max": in_flight_max(recs, "first", w0, w1),
+        "generated_tok_s": sum(len(r["final"]) for r in done) / (w1 - w0),
+        "gen_late_p90_ms": info.get("gen_late_ms", {}).get("p90"),
+        "compilations": state["snap_end"]["compile"]["compiles"]
+        - state["snap_start"]["compile"]["compiles"],
+    }
+    if flight:
+        # Spans are on (--trace 1; no profile is taken in a sweep): the
+        # cell's span- and counter-read metrics over this window, from this
+        # window's requests and the loop spans since it began.
+        ids = {r["body"]["id"] for r in recs}
+        spans_ = (flight.get("loop") or {}).get("spans", ())
+        flight = {**flight, "requests": {
+            k: v for k, v in flight.get("requests", {}).items() if k in ids
+        }, "loop": {"spans": [sp for sp in spans_ if sp[3] >= state["t0"]]}}
+        ctx = {"cell": cell, "stats": stats, "records": recs, "info": info,
+               "window": {"w0": w0, "w1": w1}, "flight": flight,
+               "metrics_before": state["metrics_start"],
+               "metrics_after": state["metrics_end"]}
+        row.update((e["name"], v) for e, v in layer_values(
+            [e for e in cell["per_layer"]
+             if e["source"] in ("program_span", "program_counter")], ctx))
+    row["sustained"] = bool(
+        row["answered_share"] >= 0.98 and first_q is not None
+        and last_q < 1.5 * first_q)
+    return row
+
+
+def sweep_rate(rate: float, windows: list[dict], bounds: dict) -> dict:
+    """One row a rate: each end-to-end metric's median and full range over
+    the seeds' windows. Sustained: every window is, and no bounded metric's
+    range over the seeds is wider than its bound allows a PR to move it - a
+    tail that the order of arrivals moves by more cannot be read there."""
+    row = {"rate": rate, "seeds": [w["seed"] for w in windows],
+           "in_flight_max": max(w["in_flight_max"] for w in windows)}
+    ok = all(w["sustained"] for w in windows)
+    for name, bound in bounds.items():
+        vals = [w[name] for w in windows if w.get(name) is not None]
+        if not vals:
+            continue
+        mid = stats.percentile(vals, 50)
+        share = (max(vals) - min(vals)) / mid
+        row[name] = {"median": mid, "min": min(vals), "max": max(vals),
+                     "range_share": share}
+        ok = ok and share < bound
+    row["sustained"] = bool(ok)
+    return row
+
+
 def run_sweep(args) -> int:
-    """One set-up, a ladder of rates, a short window each. A rate is
-    sustained when >= 98% of the requests due are answered and the last
-    quarter's median first-token time is under 1.5 times the first
-    quarter's (a backlog that grows by more within one short window is
-    growing)."""
+    """One set-up, a ladder of rates, at each rate one window a seed
+    (``--seeds``; the server's weights are the first seed's). ``--trace 1``
+    turns the program's spans on, not the profiler."""
     m = manifest.load(args.manifest)
     cell = manifest.cell(m, args.sweep)
     mix = {**cell["traffic"], "loop": "open"}
     rates = [float(x) for x in args.rates.split(",")]
-    args.trace = 0
+    seeds = [int(x) for x in args.seeds.split(",")] if args.seeds else [args.seed]
+    args.seed = seeds[0]
+    bounds = {e["name"]: e["bound"] for e in cell["end_to_end"]
+              if e["name"] != "setup_s"}
     server = Server(server_spec(cell, args))
-    rows = []
+    windows, rows = [], []
     try:
         ready = server.wait_ready(timeout=1150)
         log(f"ready: {json.dumps(ready)}")
         for rate in rates:
-            the_plan = loadgen.plan(
-                mix, seed=args.seed, seconds=args.seconds,
-                vocab=cell["model"]["vocab_size"], tag=f"r{rate:g}",
-                rate=rate, max_total=cell["serve"]["max_seq_len"])
-            before = server.get("/metrics")
-            state = run_window(server, the_plan, trace=False)
-            settle(server, state["records"], before)
-            win = [r for r in state["records"] if r["segment"] == "window"]
-            ok = [r for r in win if not r["error"] and r["first"] is not None]
-            ttft = [(r["first"] - r["due"]) * 1e3 for r in ok]
-            q = max(1, len(ttft) // 4)
-            first_q = stats.percentile(ttft[:q], 50) if ttft else None
-            last_q = stats.percentile(ttft[-q:], 50) if ttft else None
-            e2e, info = end_to_end(state, "open")
-            done = [r for r in state["records"]
-                    if not r["error"] and r["done"] is not None
-                    and state["w0"] <= r["done"] <= state["w1"]]
-            row = {
-                "rate": rate, "due": len(win), "answered": len(ok),
-                "answered_share": len(ok) / max(1, len(win)),
-                "ttft_p50_ms": info.get("ttft_ms", {}).get("p50"),
-                "ttft_p90_ms": e2e.get("ttft_p90_ms"),
-                "tpot_p50_ms": info.get("tpot_ms", {}).get("p50"),
-                "tpot_p90_ms": e2e.get("tpot_p90_ms"),
-                "ttft_first_quarter_p50_ms": first_q,
-                "ttft_last_quarter_p50_ms": last_q,
-                "generated_tok_s": sum(len(r["final"]) for r in done)
-                / (state["w1"] - state["w0"]),
-                "gen_late_p90_ms": info.get("gen_late_ms", {}).get("p90"),
-                "compilations": state["snap_end"]["compile"]["compiles"]
-                - state["snap_start"]["compile"]["compiles"],
-            }
-            row["sustained"] = bool(
-                row["answered_share"] >= 0.98 and first_q is not None
-                and last_q < 1.5 * first_q)
-            rows.append(row)
-            print(json.dumps(row), flush=True)
-            if not row["sustained"] and row["answered_share"] < 0.9:
+            here = []
+            for seed in seeds:
+                the_plan = loadgen.plan(
+                    mix, seed=seed, seconds=args.seconds,
+                    vocab=cell["model"]["vocab_size"], tag=f"r{rate:g}s{seed}",
+                    rate=rate, max_total=cell["serve"]["max_seq_len"])
+                before = server.get("/metrics")
+                state = run_window(server, the_plan, trace=False)
+                settle(server, state["records"], before)
+                flight = server.cmd({"cmd": "flight"}) if args.trace else None
+                w = {"rate": rate, "seed": seed,
+                     **sweep_window(cell, state, flight)}
+                here.append(w)
+                print(json.dumps(w), flush=True)
+            windows += here
+            rows.append(sweep_rate(rate, here, bounds))
+            print(json.dumps(rows[-1]), flush=True)
+            if any(w["answered_share"] < 0.9 for w in here):
                 break  # far above the knee: the rest would only queue
     finally:
         server.stop()
     knee = max((r["rate"] for r in rows if r["sustained"]), default=None)
     print(json.dumps({"sweep": args.sweep, "device": ready["device"],
-                      "knee": knee, "rows": rows}), flush=True)
+                      "knee": knee, "rows": rows, "windows": windows}),
+          flush=True)
     return 0
 
 
@@ -491,10 +587,15 @@ def main(argv=None) -> int:
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--sweep", metavar="CELL")
     ap.add_argument("--rates", default="2,4,8,12,16,20,24")
+    ap.add_argument("--seeds", default=None,
+                    help="with --sweep: a window a rate for each of a,b,c")
     # Test-only: another manifest (a toy tree under tests/benchmark/) and
-    # leave to run on whatever backend JAX finds. The driver passes neither.
+    # leave to run on whatever backend JAX finds. The driver passes none of
+    # the three.
     ap.add_argument("--manifest", default=None, help=argparse.SUPPRESS)
     ap.add_argument("--allow-cpu", action="store_true", help=argparse.SUPPRESS)
+    # Test-only: break the served path underneath a whole run.
+    ap.add_argument("--fault", default=None, help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
     if args.seconds is None:
         args.seconds = float(manifest.load(args.manifest)["run_seconds"])

@@ -140,16 +140,23 @@ class Served:
             max_seq_len=serve["max_seq_len"],
         )
         self.broker = InProcBroker()
+        if spec.get("fault") == "stream_token":
+            # Test-only (tests/benchmark): the timed path broken underneath,
+            # one streamed token altered where the worker hands it over, so
+            # that a whole run can be seen to come out not `correct`.
+            push = self.broker.push_stream
+            self.broker.push_stream = lambda rid, toks: push(
+                rid, [toks[0] ^ 1, *toks[1:]])
         self.worker = ContinuousWorker(
             self.engine, self.broker, tokenizer=None, rows=serve["rows"],
             chunked_prefill=serve.get("chunked_prefill"),
         )
         batcher = self.worker.batcher
-        pool = batcher.cache.k
-        log(f"paged pool: {pool.shape[1]} blocks x {self.engine.block_size} "
-            f"slots, k+v {2 * pool.nbytes / 1e9:.3f} GB, rows={serve['rows']}, "
-            f"max_seq_len={self.engine.max_seq_len}, chunked_prefill="
-            f"{serve.get('chunked_prefill')}")
+        held = jax.tree.leaves(batcher.cache)
+        log(f"cache: {sum(x.nbytes for x in held) / 1e9:.3f} GB in "
+            f"{len(held)} arrays, blocks of {self.engine.block_size} slots, "
+            f"rows={serve['rows']}, max_seq_len={self.engine.max_seq_len}, "
+            f"chunked_prefill={serve.get('chunked_prefill')}")
         buckets = prompt_buckets(spec["traffic"], self.engine.max_seq_len)
         c0, s0, t1 = counter.compiles, counter.compile_s, time.monotonic()
         n_exec = self.worker.prewarm(seq_buckets=buckets)

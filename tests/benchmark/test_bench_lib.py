@@ -198,3 +198,58 @@ def test_readers_on_counters_and_spans():
     }
     assert _reader("host_ms_per_group").read(ctx) == pytest.approx(2.0)
     assert _reader("queue_wait_p50_ms").read(ctx) == pytest.approx(250.0)
+
+
+def test_the_floor_prices_keys_and_values_where_they_are_and_state_twice():
+    """One attention layer of eleven, recurrent state in the others: the
+    bytes worked out by hand."""
+    dims = {"layers": 11, "kv_layers": 1, "heads": 32, "kv_heads": 2,
+            "head_dim": 128, "matmul_params": 3_000_000, "total_params": 10_000_000,
+            "state_bytes_per_row": 5 * 128 * 64 * 128 * 4}
+    pk = {"hbm_bytes_per_s": 1e9, "bf16_flops_per_s": 1e12}
+    assert costs.kv_bytes_per_token(dims, "bfloat16") == 1 * 2 * 2 * 128 * 2
+    out = costs.decode_step_floor_s(dims, "bfloat16", pk, rows=64, context=2048)
+    by_hand = (10_000_000 * 2            # every parameter held here, once
+               + 1024 * 64 * 2048        # 1 KiB a token in the one layer
+               + 2 * 64 * 20_971_520)    # each row's state read and written
+    assert out["bytes"] == by_hand == 2_838_572_288
+    assert out["floor_s"] == pytest.approx(by_hand / 1e9)
+    # attention's operations in the one layer that attends
+    assert out["flops"] == 64 * (2.0 * 3_000_000 + 4.0 * 2048 * 32 * 128 * 1)
+    # four chips: parameters and state split; 2 KV heads do not divide by 4,
+    # so every chip reads the whole cache
+    four = costs.decode_step_floor_s(
+        dims, "bfloat16", pk, rows=64, context=2048, chips=4)
+    assert four["bytes"] == (20_000_000 + 2 * 64 * 20_971_520) / 4 + 1024 * 64 * 2048
+
+
+@pytest.mark.parametrize("config,dtype,want_bytes,want_flops", [
+    ("tiny-bigcode", "float32", 3437824.0, 13025280.0),
+    ("tiny-bigcode", "bfloat16", 1718912.0, 13025280.0),
+    ("tiny-gptj", "float32", 12949504.0, 16629760.0),
+    ("tiny-gptj", "bfloat16", 6474752.0, 16629760.0),
+])
+def test_a_family_without_the_new_keys_is_priced_as_before(
+        config, dtype, want_bytes, want_flops):
+    """Pinned at the values ``costs.py`` gave before ``kv_layers`` and
+    ``state_bytes_per_row`` existed (40 rows, 300 tokens of context)."""
+    import json
+
+    hf = json.loads(
+        (ROOT / "tests/benchmark/toy/configs" / f"{config}.json").read_text())
+    dims = manifest.load_module("reference", hf["model_type"]).dims(hf)
+    assert "kv_layers" not in dims and "state_bytes_per_row" not in dims
+    out = costs.decode_step_floor_s(
+        dims, dtype, peaks.peaks_for("TPU v5 lite"), rows=40, context=300)
+    assert (out["bytes"], out["flops"]) == (want_bytes, want_flops)
+    assert out["floor_s"] == pytest.approx(want_bytes / 819e9)
+
+
+def test_the_manifests_cell_keeps_its_floor():
+    """`starcoderbase-1b` at 40 rows and 300 tokens: 2.957 ms, the number
+    behind every `decode_group_roofline` the ledger holds."""
+    dims, _hf = _dims("starcoderbase-1b")
+    out = costs.decode_step_floor_s(
+        dims, "bfloat16", peaks.peaks_for("TPU v5 lite"), rows=40, context=300)
+    assert out["bytes"] == 2421870592.0 and out["bound_by"] == "memory"
+    assert out["floor_s"] == pytest.approx(0.00295710694993895)

@@ -176,3 +176,32 @@ def test_loop_readers_take_the_windows_difference_of_the_counters():
     assert spans.loop_delta(off) is None
     assert _reader("loop_host_ms_per_step").read(off) is None
     assert _reader("host_turn_pct").read(off) is None
+
+
+def _dispatches(groups):
+    """A flight export whose loop track holds one ``sched.dispatch`` span a
+    group: ``(t0, chunks, k)``."""
+    return {"loop": {"dropped": 0, "spans": [
+        [i + 1, 0, "sched.dispatch", t0, 0.002,
+         {"group": i, "chunks": c, "k": k, "rows_live": 40}]
+        for i, (t0, c, k) in enumerate(groups)
+    ] + [[99, 0, "sched.plan", 20.0, 0.001, None]]}}
+
+
+def test_long_group_pct_is_the_share_of_the_windows_busy_groups():
+    read = _reader("long_group_pct").read
+    cell = {"params": {"long_group_steps": 8}}
+    window = {"w0": 20.0, "w1": 30.0}
+    # ten groups inside the window, three of them the busy group's 8 steps
+    # (one as 2 chunks of 4); a long one before the window is not counted
+    groups = [(19.5, 1, 8)] + [
+        (20.5 + i, *((1, 8) if i in (2, 3) else (2, 4) if i == 7 else (1, 4)))
+        for i in range(10)]
+    ctx = {"cell": cell, "window": window, "flight": _dispatches(groups)}
+    assert read(ctx) == pytest.approx(30.0)
+    assert read({**ctx, "flight": _dispatches([(21.0, 1, 4)])}) == 0.0
+    # nothing to read: spans off, no dispatch inside the window, or a cell
+    # that names no long group - never a zero
+    assert read({**ctx, "flight": {}}) is None
+    assert read({**ctx, "flight": _dispatches([(19.5, 1, 8)])}) is None
+    assert read({**ctx, "cell": {"params": {}}}) is None
