@@ -213,6 +213,21 @@ class PagedKVCache(NamedTuple):
     # same folding contract as the dense cache (ops/attention.py).
     k_scale: jax.Array | None = None  # [L, N, bs, Hkv] f32
     v_scale: jax.Array | None = None
+    # Recurrent state of a model with a Mamba-2 mixer (cfg.ssm), a ROW and
+    # layer, beside the row's paged keys and values: fixed size, so it needs
+    # no blocks and no allocator, only the row. Donated and returned with
+    # the rest of the tuple; a step updates each layer's slice in place. A
+    # row's state is rebuilt by the prefill that admits a request into the
+    # row (a prompt starts from nothing), so finishing a request frees
+    # nothing here and a done row's state just stays until then.
+    ssm: jax.Array | None = None  # [L, rows, H, P, N] float32
+    conv: jax.Array | None = None  # [L, rows, K-1, C] compute dtype
+    # Set on an admission view only ([B] int32): the pool row each of the
+    # view's B rows stands for. The prefill then starts every row from a
+    # zero state and writes its final state to that pool row; an index out
+    # of range (positive: the view's padding rows) writes nowhere. None:
+    # batch row i is pool row i and goes on from its state.
+    state_rows: jax.Array | None = None
 
     @property
     def max_len(self) -> int:
@@ -249,9 +264,26 @@ def paged_cache_specs(
     head_axis = AXIS_TP if n_kv_heads % tp == 0 else None
     kv = P(None, None, None, head_axis, None)
     scale = P(None, None, None, head_axis) if quantized else None
+    # The recurrent state is replicated, like the mixer's weights
+    # (models/decoder.py: param_specs). Specs of leaves a cache does not
+    # hold are skipped by the callers, leaf by leaf.
     return PagedKVCache(
         k=kv, v=kv, block_tables=P(None, None), positions=P(None, None),
-        k_scale=scale, v_scale=scale,
+        k_scale=scale, v_scale=scale, ssm=P(), conv=P(), state_rows=P(),
+    )
+
+
+def ssm_state_shapes(cfg) -> tuple | None:
+    """``((shape, dtype) of the SSM state, (shape, dtype) of the convolution
+    window)`` of ONE row and layer for a config with a mixer, else None. The
+    state is float32 whatever the compute dtype (it is summed into for the
+    whole life of a request); the window holds inputs of the compute dtype."""
+    m = cfg.ssm
+    if m is None:
+        return None
+    return (
+        ((m.n_heads, m.head_dim, m.d_state), jnp.float32),
+        ((m.d_conv - 1, m.conv_dim), cfg.compute_dtype),
     )
 
 
@@ -279,11 +311,14 @@ def init_paged_cache(
     block_size: int = 16,
     num_blocks: int | None = None,
     identity_tables: bool = True,
+    state_shapes: tuple | None = None,
 ) -> PagedKVCache:
     """Zeroed paged cache. ``identity_tables=True`` pre-maps row ``b`` to
     blocks ``[b*MB, (b+1)*MB)`` — a dense-equivalent static layout for the
     engine's own generate paths (no allocator in the loop). The scheduler
-    passes False and drives tables from its host-side ``BlockAllocator``."""
+    passes False and drives tables from its host-side ``BlockAllocator``.
+    ``state_shapes`` (``ssm_state_shapes(cfg)``) adds the zeroed recurrent
+    state of ``batch`` rows."""
     if max_len % block_size:
         raise ValueError(
             f"max_len {max_len} must be a multiple of block_size "
@@ -321,6 +356,12 @@ def init_paged_cache(
             put(specs.v_scale, jnp.zeros(pool_shape[:-1], jnp.float32))
             if quantized else None
         ),
+        **({} if state_shapes is None else {
+            name: put(spec, jnp.zeros((n_layers, batch) + shape, dt))
+            for name, spec, (shape, dt) in zip(
+                ("ssm", "conv"), (specs.ssm, specs.conv), state_shapes
+            )
+        }),
     )
 
 

@@ -34,7 +34,7 @@ import numpy as np
 
 from llmss_tpu.engine.cache import (
     KVCache, PagedKVCache, init_cache, init_paged_cache,
-    paged_write_stacked,
+    paged_write_stacked, ssm_state_shapes,
 )
 from llmss_tpu.models.common import DecoderConfig
 from llmss_tpu.ops.sampling import sample
@@ -138,6 +138,12 @@ class DecodeEngine:
             raise ValueError(
                 f"kv_layout must be 'dense' or 'paged', got {kv_layout!r}"
             )
+        if cfg.ssm is not None and kv_layout != "paged":
+            raise ValueError(
+                f"model_type {cfg.model_type!r} holds a recurrent state in "
+                "every layer, which lives in the paged cache only: pass "
+                "kv_layout='paged' (docs/recurrent-state.md)"
+            )
         self.kv_layout = kv_layout
         self.block_size = block_size
         self.kv_blocks = kv_blocks
@@ -240,6 +246,19 @@ class DecodeEngine:
         from llmss_tpu.models.decoder import forward
 
         B, S = ids.shape
+        if cfg.ssm is not None:
+            if start is not None:
+                raise ValueError(
+                    "prefix reuse is not carried for a model with a "
+                    "recurrent state (docs/recurrent-state.md)"
+                )
+            if cache.state_rows is None:
+                # A prompt starts from nothing, whatever the rows held. The
+                # scheduler's admission view says which pool rows it stands
+                # for; an engine-owned cache is a view onto its own rows.
+                cache = cache._replace(
+                    state_rows=jnp.arange(B, dtype=jnp.int32)
+                )
         rel = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
         off = jnp.zeros((B,), jnp.int32) if start is None else start
         positions = off[:, None] + rel
@@ -329,6 +348,12 @@ class DecodeEngine:
         the seed scatter compiles once per bucket, not once per prefix
         length — this removed a ~28 s one-time bespoke-shape compile per
         distinct prefix length (PREFIX_BENCH.json)."""
+        if self.cfg.ssm is not None:
+            raise ValueError(
+                "prefix reuse is not carried for a model with a recurrent "
+                "state: a retained segment would need the state at its end "
+                "(docs/recurrent-state.md)"
+            )
         P = len(token_ids)
         if not 0 < P < self.max_seq_len:
             raise ValueError(
@@ -910,6 +935,7 @@ class DecodeEngine:
             block_size=self.block_size,
             num_blocks=num_blocks,
             identity_tables=identity,
+            state_shapes=ssm_state_shapes(self.cfg),
         )
 
     # -- canonical state shardings ------------------------------------------

@@ -198,6 +198,14 @@ class ContinuousBatcher:
             raise ValueError(
                 f"group_chunks must be >= 1, got {group_chunks}"
             )
+        if prefill_only and engine.cfg.ssm is not None:
+            # What does not carry a model's recurrent state refuses the
+            # model, so that nothing runs and is silently wrong
+            # (docs/recurrent-state.md).
+            raise ValueError(
+                "prefill_only (the KV hand-off) does not carry the "
+                f"recurrent state of model_type {engine.cfg.model_type!r}"
+            )
         self.engine = engine
         self.rows = rows
         self.chunk_steps = chunk_steps
@@ -295,6 +303,20 @@ class ContinuousBatcher:
             # evicted to admit new work.
             self._paged_prefixes: dict[int, tuple] = {}
             engine.metrics.set_kv_blocks(total=n_blocks, in_use=0)
+            if self.cache.ssm is not None:
+                engine.metrics.set_state_bytes(
+                    self.cache.ssm.nbytes + self.cache.conv.nbytes
+                )
+                # Chunked admission runs no prefill program that could start
+                # its rows from nothing, so it zeroes their state itself
+                # (rows padded with the out-of-range sentinel: dropped).
+                self._zero_state = jax.jit(
+                    lambda ssm, conv, rows_: (
+                        ssm.at[:, rows_].set(0, mode="drop"),
+                        conv.at[:, rows_].set(0, mode="drop"),
+                    ),
+                    donate_argnums=(0, 1),
+                )
             self._merge_positions = jax.jit(
                 lambda big, sub, rows_: big.at[rows_].set(sub, mode="drop"),
                 donate_argnums=(0,),
@@ -405,16 +427,26 @@ class ContinuousBatcher:
         )
 
     def _paged_scratch_view(
-        self, P: int, tables: np.ndarray | None = None
+        self, P: int, tables: np.ndarray | None = None,
+        row_idx: np.ndarray | None = None,
     ) -> PagedKVCache:
         """A P-row admission 'scratch cache' that SHARES the big pool:
         fresh per-view positions and the admitted rows' tables, but the
         same pool buffers — prefill writes land in place, so absorbing an
-        admission is a positions merge + table upload, never a KV copy."""
+        admission is a positions merge + table upload, never a KV copy.
+        The recurrent state pool (a model with a mixer) is shared the same
+        way: ``row_idx`` ([P], padded with the out-of-range sentinel; None:
+        all padding) names the pool rows the prefill writes its final
+        state to."""
         eng = self.engine
         if tables is None:
             mb = eng.max_seq_len // eng.block_size
             tables = np.full((P, mb), self._sentinel, np.int32)
+        state_rows = None
+        if self.cache.ssm is not None:
+            if row_idx is None:
+                row_idx = self._pad_row_idx(P, [])
+            state_rows = eng.canon_vec(jnp.asarray(row_idx, jnp.int32))
         return PagedKVCache(
             k=self.cache.k, v=self.cache.v,
             block_tables=self._dev_tables(tables),
@@ -422,6 +454,8 @@ class ContinuousBatcher:
                 jnp.full((P, eng.max_seq_len), -1, jnp.int32)
             ),
             k_scale=self.cache.k_scale, v_scale=self.cache.v_scale,
+            ssm=self.cache.ssm, conv=self.cache.conv,
+            state_rows=state_rows,
         )
 
     def _paged_absorb(self, view: PagedKVCache, row_idx: np.ndarray) -> None:
@@ -444,7 +478,20 @@ class ContinuousBatcher:
                 jnp.asarray(row_idx),
             ),
             k_scale=view.k_scale, v_scale=view.v_scale,
+            # the admitted rows' state is already in the pool: the prefill
+            # wrote it there (models/decoder.py: _layer_scan)
+            ssm=view.ssm, conv=view.conv,
         ))
+
+    def _zeroed_state(self, row_idx: np.ndarray) -> dict:
+        """The state pool with rows ``row_idx`` at zero, as fields for
+        ``cache._replace``; nothing for a model without one."""
+        if self.cache.ssm is None:
+            return {}
+        ssm, conv = self._zero_state(
+            self.cache.ssm, self.cache.conv, jnp.asarray(row_idx)
+        )
+        return {"ssm": ssm, "conv": conv}
 
     def _paged_evict_idle_prefixes(self, keep: int | None = None) -> int:
         """Reclaim prefix block sets no live row references (every block
@@ -612,6 +659,7 @@ class ContinuousBatcher:
         self.cache = eng.canon_cache(self.cache._replace(
             k=scratch.k, v=scratch.v,
             k_scale=scratch.k_scale, v_scale=scratch.v_scale,
+            ssm=scratch.ssm, conv=scratch.conv,
         ))
 
     def prewarm(
@@ -710,7 +758,10 @@ class ContinuousBatcher:
                         ),
                         jnp.asarray(self._pad_row_idx(P, [])),
                     ),
+                    **(self._zeroed_state(self._pad_row_idx(P, []))
+                       if self._chunked else {}),
                 ))
+                n_compiled += bool(self._chunked and self.cache.ssm is not None)
             else:
                 scratch = eng.canon_cache(scratch)
                 self.cache = eng.canon_cache(self._insert(
@@ -877,6 +928,11 @@ class ContinuousBatcher:
         picks up where it stopped and ``max_new_tokens`` counts only the
         REMAINING tokens."""
         gen.validate()
+        if prefix is not None and self.engine.cfg.ssm is not None:
+            raise ValueError(
+                "prefix reuse is not carried for a model with a recurrent "
+                "state (docs/recurrent-state.md)"
+            )
         if replayed and not 0 < replayed < len(token_ids):
             raise ValueError(
                 f"replayed={replayed} must be in [0, len(token_ids))"
@@ -1025,7 +1081,7 @@ class ContinuousBatcher:
             mb = self.engine.max_seq_len // self.engine.block_size
             sub_tables = np.full((P, mb), self._sentinel, np.int32)
             sub_tables[:n] = self._host_tables[rows]
-            scratch = self._paged_scratch_view(P, sub_tables)
+            scratch = self._paged_scratch_view(P, sub_tables, row_idx)
             if head_prefix is not None:
                 # Seed through COW-masked tables: the SHARED full blocks'
                 # columns are sentineled out so the seed's writes to them
@@ -1149,6 +1205,7 @@ class ContinuousBatcher:
                 self.cache.positions, eng.canon_vec(jnp.asarray(sub)),
                 jnp.asarray(row_idx),
             ),
+            **self._zeroed_state(row_idx),
         ))
         starts = np.ones(P, np.int32)
         starts[:n] = start
@@ -1370,6 +1427,12 @@ class ContinuousBatcher:
         """
         if not self._paged:
             raise ValueError("adopt requires kv_layout='paged'")
+        if self.engine.cfg.ssm is not None:
+            raise ValueError(
+                "the KV hand-off does not carry a recurrent state: a row "
+                "adopted without it would decode from a wrong state "
+                "(docs/recurrent-state.md)"
+            )
         if self.prefill_only:
             raise ValueError("prefill-only batcher cannot adopt")
         gen.validate()
@@ -1481,6 +1544,11 @@ class ContinuousBatcher:
         when its row finishes served, ``park_cb`` receives the full token
         sequence (``token_ids`` + the non-replayed outputs) and the row's
         exported KV blocks. Idempotent; a no-op without ``park_cb``."""
+        if self.engine.cfg.ssm is not None:
+            raise ValueError(
+                "session parking does not carry a recurrent state "
+                "(docs/recurrent-state.md)"
+            )
         with self._lock:
             self._park_ids[req_id] = (list(token_ids), int(replayed))
 

@@ -268,6 +268,12 @@ def generate_speculative(
     window runs out, the tail finishes on plain single-token steps.
 
     Records acceptance stats on ``engine.metrics.spec_stats``."""
+    if engine.cfg.ssm is not None:
+        raise ValueError(
+            "speculative decoding does not carry a recurrent state: a "
+            "rejected draft would have to roll the state back "
+            "(docs/recurrent-state.md)"
+        )
     gen.validate()
     if not gen.is_greedy:
         raise ValueError(

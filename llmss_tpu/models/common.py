@@ -29,6 +29,41 @@ import jax
 
 
 @dataclasses.dataclass(frozen=True)
+class SSMConfig:
+    """A Mamba-2 mixer that runs BESIDE attention in every block (Falcon-H1):
+    both branches read the same normed input and add to the residual. The
+    sizes are the published config's; the multipliers are fixed scalars of
+    the architecture (muP), applied in the forward and never folded into a
+    weight, so a checkpoint's leaves load as published."""
+
+    d_ssm: int  # n_heads * head_dim
+    n_heads: int
+    head_dim: int
+    n_groups: int  # B and C are shared by n_heads / n_groups heads
+    d_state: int
+    d_conv: int
+    chunk_size: int = 128  # prefill scan chunk
+    in_multiplier: float = 1.0
+    out_multiplier: float = 1.0
+    # on the five segments of the input projection, in order z, x, B, C, dt
+    multipliers: tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
+
+    @property
+    def bc_dim(self) -> int:
+        return self.n_groups * self.d_state
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels the causal convolution runs over: x, B and C."""
+        return self.d_ssm + 2 * self.bc_dim
+
+    @property
+    def proj_dim(self) -> int:
+        """Width of the input projection: z, x, B, C, dt."""
+        return 2 * self.d_ssm + 2 * self.bc_dim + self.n_heads
+
+
+@dataclasses.dataclass(frozen=True)
 class DecoderConfig:
     model_type: str
     vocab_size: int
@@ -90,6 +125,17 @@ class DecoderConfig:
     # sqrt(head_dim) too but computes it as `scale_attn` applied post-mask
     # (gptj_modeling.py:153) — numerically the same scaled softmax.
     attn_scale: float | None = None
+
+    # Falcon-H1: a Mamba-2 mixer in parallel with attention in every block
+    # (None = the block is attention -> MLP), and the fixed scalars on the
+    # attention branch's input, keys and output, the MLP's gate and output,
+    # and the logits. 1.0 leaves the forward as every other family has it.
+    ssm: SSMConfig | None = None
+    attn_in_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    attn_out_multiplier: float = 1.0
+    mlp_multipliers: tuple[float, float] = (1.0, 1.0)
+    lm_head_multiplier: float = 1.0
 
     # compute dtype for activations; params are loaded in this dtype too
     dtype: str = "bfloat16"

@@ -51,6 +51,7 @@ from llmss_tpu.ops.layers import (
     LinearParams, NormParams, dense, dense_t, embedding,
 )
 from llmss_tpu.ops.rope import apply_rope, sin_cos_tables
+from llmss_tpu.ops.ssm import causal_conv, ssd_scan, ssm_step
 from llmss_tpu.parallel.mesh import AXIS_DP, AXIS_SP, AXIS_TP
 from llmss_tpu.parallel.sharding import constrain
 
@@ -124,6 +125,20 @@ def param_specs(cfg: DecoderConfig, tp: int) -> Params:
             w=P(None, AXIS_TP, None), b=P(None) if cfg.mlp_bias else None
         )
 
+    if cfg.ssm is not None:
+        # The mixer's leaves and its state are replicated over tp: a head
+        # split would cut the convolution's channels by segment (x by head,
+        # B and C by group). Attention and the MLP shard as everywhere.
+        blocks["ssm_in"] = LinearParams(w=P(None, None, None), b=None)
+        blocks["ssm_conv"] = LinearParams(
+            w=P(None, None, None), b=P(None, None)
+        )
+        blocks["ssm_dt_bias"] = P(None, None)
+        blocks["ssm_A_log"] = P(None, None)
+        blocks["ssm_D"] = P(None, None)
+        blocks["ssm_norm"] = _norm_specs(True, False)
+        blocks["ssm_out"] = LinearParams(w=P(None, None, None), b=None)
+
     specs: Params = {
         "wte": P(AXIS_TP, None),
         "blocks": blocks,
@@ -155,13 +170,70 @@ def init_params(cfg: DecoderConfig, mesh, key) -> Params:
         treedef, list(jax.random.split(key, len(leaves)))
     )
 
-    def _init(keys):
-        return jax.tree.map(
-            lambda sds, k: jax.random.normal(k, sds.shape, sds.dtype) * 0.02,
-            shapes, keys,
+    draw = _ssm_family_draw(cfg) if cfg.ssm is not None else {}
+
+    def _leaf(path, sds, k):
+        name = next(
+            (p.key for p in reversed(path) if getattr(p, "key", None) in draw),
+            None,
         )
+        if name is not None:
+            return draw[name](k, sds.shape).astype(sds.dtype)
+        return jax.random.normal(k, sds.shape, sds.dtype) * 0.02
+
+    def _init(keys):
+        return jax.tree_util.tree_map_with_path(_leaf, shapes, keys)
 
     return jax.jit(_init, out_shardings=shardings)(keys_tree)
+
+
+def _ssm_family_draw(cfg: DecoderConfig) -> dict:
+    """The seeded draw of a family with a parallel Mamba-2 mixer, leaf by
+    leaf: ``{leaf name: (key, shape) -> float32 array}``. N(0, 0.02) on
+    every leaf breaks such a family twice. ``A_log`` and ``dt_bias`` near 0
+    give a decay of a half a token, so the state forgets in five tokens and
+    a wrong recurrence passes every check; and a branch whose output is
+    times a multiplier of a hundredth is a few percent of the residual, so a
+    dropped branch sits inside a bfloat16 tolerance. So the recurrence's
+    leaves are drawn as the published initialisation draws them, and each
+    matrix ``N(0, (c / sqrt(fan_in))^2)`` with ``c`` chosen so that, under
+    the published multipliers, attention's scores have a deviation near 1
+    and attention, mixer and MLP each add about a twentieth to a residual
+    that starts at a ninth (0.02 x the embedding multiplier). Leaves not
+    named here (the embedding, norm scales: the benchmark's server and the
+    tests add 1 to those) stay N(0, 0.02)."""
+    E, Q, I = cfg.hidden_size, cfg.q_size, cfg.intermediate_size
+    s = cfg.ssm
+
+    def normal(c, fan_in):
+        return lambda k, shape: (
+            jax.random.normal(k, shape, jnp.float32) * (c / fan_in ** 0.5)
+        )
+
+    def uniform(lo, hi):
+        return lambda k, shape: jax.random.uniform(
+            k, shape, jnp.float32, lo, hi
+        )
+
+    def dt_bias(k, shape):
+        # inverse softplus of a log-uniform time step in [1e-3, 1e-1]
+        dt = jnp.exp(jax.random.uniform(
+            k, shape, jnp.float32, jnp.log(1e-3), jnp.log(1e-1)
+        ))
+        return dt + jnp.log(-jnp.expm1(-dt))
+
+    return {
+        "q": normal(8.0, E), "k": normal(11.3, E), "v": normal(1.0, E),
+        "o": normal(4.5, Q),
+        "gate": normal(8.5, E), "up": normal(1.0, E),
+        "down": normal(5.6, I),
+        "ssm_in": normal(16.0, E), "ssm_out": normal(0.6, s.d_ssm),
+        "ssm_conv": uniform(-0.5, 0.5),  # weight and bias
+        "ssm_A_log": lambda k, shape: jnp.log(uniform(1.0, 16.0)(k, shape)),
+        "ssm_dt_bias": dt_bias,
+        "ssm_D": lambda k, shape: jnp.ones(shape, jnp.float32),
+        "head": normal(1.0, E),
+    }
 
 
 def param_shapes(cfg: DecoderConfig) -> Params:
@@ -202,6 +274,19 @@ def param_shapes(cfg: DecoderConfig) -> Params:
             sds(L, I, E), sds(L, E) if cfg.mlp_bias else None
         )
 
+    if cfg.ssm is not None:
+        m = cfg.ssm
+        blocks["ssm_in"] = LinearParams(sds(L, E, m.proj_dim), None)
+        # [K, C]: channels minor (the published conv1d weight is [C, 1, K])
+        blocks["ssm_conv"] = LinearParams(
+            sds(L, m.d_conv, m.conv_dim), sds(L, m.conv_dim)
+        )
+        blocks["ssm_dt_bias"] = sds(L, m.n_heads)
+        blocks["ssm_A_log"] = sds(L, m.n_heads)
+        blocks["ssm_D"] = sds(L, m.n_heads)
+        blocks["ssm_norm"] = NormParams(scale=sds(L, m.d_ssm), bias=None)
+        blocks["ssm_out"] = LinearParams(sds(L, m.d_ssm, E), None)
+
     shapes: Params = {
         "wte": sds(V, E), "blocks": blocks, "ln_f": norm_shape(False)
     }
@@ -225,11 +310,80 @@ def _norm(cfg: DecoderConfig, x, p: NormParams):
     return layer_norm(x, p, cfg.norm_eps)
 
 
+def _scale(x, m: float):
+    """``x`` times a fixed scalar of the architecture, multiplied in float32
+    (a weak-typed product would round the scalar to the compute dtype: a
+    systematic 2^-9 on a whole branch). 1.0 adds nothing to the program."""
+    if m == 1.0:
+        return x
+    return (x.astype(jnp.float32) * m).astype(x.dtype)
+
+
 def _mlp(cfg: DecoderConfig, bp: Params, x):
     act = act_fn(cfg.activation)
     if cfg.mlp == "swiglu":
-        return dense(act(dense(x, bp["gate"])) * dense(x, bp["up"]), bp["down"])
+        m_gate, m_out = cfg.mlp_multipliers
+        gate = _scale(dense(x, bp["gate"]), m_gate)
+        return _scale(
+            dense(act(gate) * dense(x, bp["up"]), bp["down"]), m_out
+        )
     return dense(act(dense(x, bp["fc_in"])), bp["fc_out"])
+
+
+def _mixer(cfg: DecoderConfig, bp: Params, x, ssm, conv, lens):
+    """The Mamba-2 branch of a block: ``x`` [B, S, E] is the block's normed
+    input, ``ssm`` [B, H, P, N] float32 and ``conv`` [B, K-1, C] this layer's
+    state of every row, ``lens`` [B] how many of the S positions are real
+    (0: the row is done and its state stays as it is). Returns the branch's
+    output [B, S, E] and the new ``(ssm, conv)``.
+
+    S == 1 is the decode update (``ssm.decode``), anything longer the
+    chunked scan (``ssm.prefill``); both start from the state handed in and
+    treat positions at or after ``lens`` as no-ops (time step 0, window
+    taken at the true length)."""
+    m = cfg.ssm
+    B, S, _ = x.shape
+    H, Pd, G, N = m.n_heads, m.head_dim, m.n_groups, m.d_state
+    f32 = jnp.float32
+    mz, mx, mb, mc, mdt = m.multipliers
+
+    p = dense(_scale(x, m.in_multiplier), bp["ssm_in"])
+    z, xbc, dt = jnp.split(p, [m.d_ssm, m.d_ssm + m.conv_dim], axis=-1)
+    seg = jnp.asarray(
+        [mx] * m.d_ssm + [mb] * m.bc_dim + [mc] * m.bc_dim, f32
+    )
+    # rounded to the compute dtype here, so that the convolution reads the
+    # same values from this call's inputs as from the carried window
+    xbc = (xbc.astype(f32) * seg).astype(x.dtype)
+    xbc, conv = causal_conv(
+        xbc, conv, bp["ssm_conv"].w, bp["ssm_conv"].b, lens
+    )
+    xbc = jax.nn.silu(xbc)
+    xs, Bm, Cm = jnp.split(xbc, [m.d_ssm, m.d_ssm + m.bc_dim], axis=-1)
+    xs = xs.reshape(B, S, H, Pd)
+    Bm, Cm = Bm.reshape(B, S, G, N), Cm.reshape(B, S, G, N)
+    live = jnp.arange(S, dtype=lens.dtype)[None, :] < lens[:, None]
+    dt = jax.nn.softplus(dt.astype(f32) * mdt + bp["ssm_dt_bias"].astype(f32))
+    dt = jnp.where(live[..., None], dt, 0.0)
+    A = -jnp.exp(bp["ssm_A_log"].astype(f32))
+    if S == 1:
+        with jax.named_scope("ssm.decode"):
+            y, ssm = ssm_step(xs[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0], ssm)
+            y = y[:, None]
+    else:
+        with jax.named_scope("ssm.prefill"):
+            y, ssm = ssd_scan(xs, dt, A, Bm, Cm, ssm, m.chunk_size)
+    y = y + bp["ssm_D"].astype(f32)[:, None] * xs
+    # gate, then RMSNorm over each group's channels (mamba_norm_before_gate
+    # false, mamba_rms_norm true)
+    y = y.reshape(B, S, m.d_ssm) * jax.nn.silu(z.astype(f32) * mz)
+    yg = y.reshape(B, S, G, m.d_ssm // G)
+    yg = yg * jax.lax.rsqrt(
+        jnp.mean(yg * yg, axis=-1, keepdims=True) + cfg.norm_eps
+    )
+    y = yg.reshape(B, S, m.d_ssm) * bp["ssm_norm"].scale.astype(f32)
+    out = _scale(dense(y.astype(x.dtype), bp["ssm_out"]), m.out_multiplier)
+    return out, (ssm, conv)
 
 
 def _block(
@@ -256,8 +410,12 @@ def _block(
     # attention contractions (ops/attention.py) — no dequant materializes.
     k_scale=None,
     v_scale=None,
+    # (ssm [B, H, P, N], conv [B, K-1, C], lens [B]) of this layer, for a
+    # config with a mixer (see ``_mixer``)
+    ssm_in=None,
 ):
-    """One decoder block.
+    """One decoder block. The last element returned is the mixer's new
+    ``(ssm, conv)`` state, None for a config without one.
 
     ``defer_write=False``: current-token KV is scattered into the cache,
     then attention reads the updated cache (``kv_positions`` includes the
@@ -277,9 +435,11 @@ def _block(
     res = h
     x = _norm(cfg, h, bp["ln1"])
 
-    q = constrain(dense_t(x, bp["q"]).reshape(B, S, Hq, D), head_spec)
-    k = constrain(dense_t(x, bp["k"]).reshape(B, S, Hkv, D), kv_spec)
-    v = constrain(dense(x, bp["v"]).reshape(B, S, Hkv, D), kv_spec)
+    xa = _scale(x, cfg.attn_in_multiplier)
+    q = constrain(dense_t(xa, bp["q"]).reshape(B, S, Hq, D), head_spec)
+    k = _scale(dense_t(xa, bp["k"]), cfg.key_multiplier)
+    k = constrain(k.reshape(B, S, Hkv, D), kv_spec)
+    v = constrain(dense(xa, bp["v"]).reshape(B, S, Hkv, D), kv_spec)
 
     if cfg.positions == "rotary":
         q = apply_rope(
@@ -309,7 +469,15 @@ def _block(
             kv_positions=kv_positions, scale=cfg.attn_scale, mesh=mesh,
             window=cfg.sliding_window,
         )
-    attn = dense(attn.reshape(B, S, Hq * D), bp["o"])
+    attn = _scale(
+        dense(attn.reshape(B, S, Hq * D), bp["o"]), cfg.attn_out_multiplier
+    )
+    ssm_out = None
+    if cfg.ssm is not None:
+        # The second branch reads the same normed input and adds to the
+        # residual beside attention.
+        mix, ssm_out = _mixer(cfg, bp, x, *ssm_in)
+        attn = attn + mix
     attn = constrain(attn, P(AXIS_DP, seq_ax, None))
 
     if cfg.parallel_residual:
@@ -324,8 +492,60 @@ def _block(
         h = h + _mlp(cfg, bp, x2)
     h = constrain(h, P(AXIS_DP, seq_ax, None))
     if defer_write:
-        return h, k, v  # fresh KV for the single post-scan scatter
-    return h, k_cache, v_cache, k, v
+        return h, k, v, ssm_out  # fresh KV for the single post-scan scatter
+    return h, k_cache, v_cache, k, v, ssm_out
+
+
+def _ssm_lens(cache, kv_write_positions, slots):
+    """How many of a call's positions are real, a row: those that record a
+    position (padding carries -1) and write a slot (a done row's slot is
+    positive out of range). What the mixer may advance its state by."""
+    live = (kv_write_positions >= 0) & (slots < cache.max_len)
+    return jnp.sum(live.astype(jnp.int32), axis=1)
+
+
+def _layer_scan(cfg: DecoderConfig, cache, lens, body, h, xs):
+    """``lax.scan`` of ``body(h, xs, ssm_in) -> (h, ys, ssm_out)`` over the
+    stacked layers; returns ``(h, ys, state)``. For a config with a mixer
+    the recurrent state pool ``[L, rows, ...]`` rides the scan's carry and
+    each layer's slice is updated in place: the pool is donated with the
+    rest of the cache, so a step holds one copy of it and copies none.
+
+    ``cache.state_rows`` None: batch row i IS pool row i and goes on from
+    the state it has (decode, and a ragged chunk). Set (an admission view):
+    every batch row starts from zeros, as a prompt does, and its final state
+    is written to pool row ``state_rows[i]``; a row index out of range (the
+    view's padding rows) writes nowhere."""
+    if cfg.ssm is None:
+        def plain(h, xs):
+            h, ys, _ = body(h, xs, None)
+            return h, ys
+
+        h, ys = jax.lax.scan(plain, h, xs)
+        return h, ys, None
+    rows, B = cache.state_rows, h.shape[0]
+
+    def stateful(carry, xs_l):
+        h, ssm, conv = carry
+        xs, l = xs_l
+        if rows is None:
+            s_in, c_in = ssm[l], conv[l]
+        else:
+            s_in = jnp.zeros((B,) + ssm.shape[2:], ssm.dtype)
+            c_in = jnp.zeros((B,) + conv.shape[2:], conv.dtype)
+        h, ys, (s_l, c_l) = body(h, xs, (s_in, c_in, lens))
+        if rows is None:
+            ssm, conv = ssm.at[l].set(s_l), conv.at[l].set(c_l)
+        else:
+            ssm = ssm.at[l, rows].set(s_l, mode="drop")
+            conv = conv.at[l, rows].set(c_l, mode="drop")
+        return (h, ssm, conv), ys
+
+    (h, ssm, conv), ys = jax.lax.scan(
+        stateful, (h, cache.ssm, cache.conv),
+        (xs, jnp.arange(cfg.n_layers, dtype=jnp.int32)),
+    )
+    return h, ys, (ssm, conv)
 
 
 def _make_decode_kernel_attn(cfg, mesh, cache, positions, slots):
@@ -460,7 +680,17 @@ def _embed_in(cfg: DecoderConfig, params: Params, input_ids, positions, mesh):
     # gather — the one-hot matmul streams the whole [V, E] table through
     # the MXU for one token (~5% of all param bytes per step at 1B scale),
     # where a gather reads B·E floats.
-    one_hot = input_ids.shape[1] > 1
+    #
+    # The one-hot form exists to partition over a vocab-sharded table: with
+    # tp == 1 there is nothing to partition, and at a vocabulary of 261,120
+    # the matmul is 2.7 GFLOP a prompt token (more than five full-width
+    # layers) and the compiler holds a re-laid-out copy of the 2.7 GB table
+    # beside it (compiled for a described v5e, PR 29). So prefill gathers
+    # too unless the mesh shards the vocabulary. The rows are the same bits
+    # either way: a one-hot row sums one term.
+    one_hot = input_ids.shape[1] > 1 and (
+        mesh is not None and mesh.shape[AXIS_TP] > 1
+    )
     h = embedding(input_ids, params["wte"].astype(dtype), one_hot=one_hot)
     if cfg.embed_multiplier is not None:
         # Gemma scales hidden states by sqrt(hidden_size) post-embedding
@@ -499,6 +729,8 @@ def _head_out(
         from llmss_tpu.ops.layers import lm_head
 
         logits = lm_head(h, params["head"])
+    if cfg.lm_head_multiplier != 1.0:
+        logits = logits * cfg.lm_head_multiplier
     return constrain(logits, P(AXIS_DP, None, None))
 
 
@@ -548,6 +780,11 @@ def forward(
             t_bucket=t_bucket, _ablate=_ablate,
         )
 
+    if cfg.ssm is not None:
+        raise NotImplementedError(
+            "a model with a recurrent state is served from the paged cache "
+            "only (kv_layout='paged'): the dense ring has no state pool"
+        )
     dtype = cfg.compute_dtype
     h = _embed_in(cfg, params, input_ids, positions, mesh)
 
@@ -603,7 +840,7 @@ def forward(
             # copy — the round-5 profile's 0.5 ms/step sink).
             def body(h, xs):
                 bp, layer = xs
-                h, k_f, v_f = _block(
+                h, k_f, v_f, _ = _block(
                     cfg, bp, h, positions, None, None, cache.positions,
                     slots, None, mesh=mesh, defer_write=True,
                     attn_override=partial(kernel_attn, layer=layer),
@@ -698,7 +935,7 @@ def forward(
                     k_l = dequantize_kv(k_l, ks_l, dtype)
                     v_l = dequantize_kv(v_l, vs_l, dtype)
                     ks_l = vs_l = None
-                h, k_f, v_f = _block(
+                h, k_f, v_f, _ = _block(
                     cfg, bp, h, positions, k_l, v_l, kv_pos_src, slots,
                     None, mesh=mesh, defer_write=True,
                     attn_override=sp_attn if sp_attn is not None
@@ -752,7 +989,7 @@ def forward(
                 v_l = dequantize_kv(v_q, vs_l, dtype)
             else:
                 bp, k_l, v_l = xs
-            h, k_l, v_l, k_f, v_f = _block(
+            h, k_l, v_l, k_f, v_f, _ = _block(
                 cfg, bp, h, positions, k_l, v_l, new_kv_positions, slots,
                 mask, mesh=mesh, sin_cos=sin_cos,
             )
@@ -910,6 +1147,7 @@ def _forward_paged(
     B, S = input_ids.shape
     bs, MB = cache.block_size, cache.max_blocks
     quant = cache.quantized
+    lens = _ssm_lens(cache, kv_write_positions, slots)
 
     sin_cos = None
     if cfg.positions == "rotary":
@@ -940,18 +1178,18 @@ def _forward_paged(
             )
 
         if kernel_attn is not None:
-            def body(h, xs):
+            def body(h, xs, ssm_in):
                 bp, layer = xs
-                h, k_f, v_f = _block(
+                h, k_f, v_f, ssm_out = _block(
                     cfg, bp, h, positions, None, None, kv_pos_src, slots,
                     None, mesh=mesh, defer_write=True,
                     attn_override=partial(kernel_attn, layer=layer),
-                    sin_cos=sin_cos,
+                    sin_cos=sin_cos, ssm_in=ssm_in,
                 )
-                return h, (k_f, v_f)
+                return h, (k_f, v_f), ssm_out
 
-            h, ys = jax.lax.scan(
-                body, h,
+            h, ys, state = _layer_scan(
+                cfg, cache, lens, body, h,
                 (params["blocks"],
                  jnp.arange(cfg.n_layers, dtype=jnp.int32)),
             )
@@ -960,7 +1198,7 @@ def _forward_paged(
                 positions, kv_pos_src, slots, cfg.sliding_window
             )
 
-            def body(h, xs):
+            def body(h, xs, ssm_in):
                 if quant:
                     bp, kp_l, vp_l, ksp_l, vsp_l = xs
                 else:
@@ -977,21 +1215,21 @@ def _forward_paged(
                         v_scale_layer=vsp_l, n_blocks=nb,
                     )
 
-                h, k_f, v_f = _block(
+                h, k_f, v_f, ssm_out = _block(
                     cfg, bp, h, positions, None, None, kv_pos_src, slots,
                     None, mesh=mesh, defer_write=True,
                     attn_override=paged_attn, ablate=_ablate,
-                    sin_cos=sin_cos,
+                    sin_cos=sin_cos, ssm_in=ssm_in,
                 )
                 ys = None if _ablate == "no_scatter" else (k_f, v_f)
-                return h, ys
+                return h, ys, ssm_out
 
             if quant:
                 xs = (params["blocks"], cache.k, cache.v, cache.k_scale,
                       cache.v_scale)
             else:
                 xs = (params["blocks"], cache.k, cache.v)
-            h, ys = jax.lax.scan(body, h, xs)
+            h, ys, state = _layer_scan(cfg, cache, lens, body, h, xs)
 
         ks_new, vs_new = cache.k_scale, cache.v_scale
         if _ablate == "no_scatter":
@@ -1018,7 +1256,7 @@ def _forward_paged(
         mask = make_causal_mask(positions, new_kv_positions, kv_valid)
         blk, off = logical_to_physical(cache.block_tables, slots, bs)
 
-        def body(h, xs):
+        def body(h, xs, ssm_in):
             # Write-then-attend over the row-indirected logical view (same
             # values/slot order as a dense ring, so _block is reused
             # verbatim); then persist ONLY the fresh tokens back to the
@@ -1037,9 +1275,9 @@ def _forward_paged(
                 bp, kp_l, vp_l = xs
                 k_l = gather_block_view(kp_l, cache.block_tables)
                 v_l = gather_block_view(vp_l, cache.block_tables)
-            h, _, _, k_f, v_f = _block(
+            h, _, _, k_f, v_f, ssm_out = _block(
                 cfg, bp, h, positions, k_l, v_l, new_kv_positions, slots,
-                mask, mesh=mesh, sin_cos=sin_cos,
+                mask, mesh=mesh, sin_cos=sin_cos, ssm_in=ssm_in,
             )
             if quant:
                 # Quantize only the fresh tokens (storage bit-stability —
@@ -1050,31 +1288,34 @@ def _forward_paged(
                 vp_l = vp_l.at[blk, off].set(v8, mode="drop")
                 ksp_l = ksp_l.at[blk, off].set(ks_f, mode="drop")
                 vsp_l = vsp_l.at[blk, off].set(vs_f, mode="drop")
-                return h, (kp_l, vp_l, ksp_l, vsp_l)
+                return h, (kp_l, vp_l, ksp_l, vsp_l), ssm_out
             kp_l = kp_l.at[blk, off].set(
                 k_f.astype(kp_l.dtype), mode="drop"
             )
             vp_l = vp_l.at[blk, off].set(
                 v_f.astype(vp_l.dtype), mode="drop"
             )
-            return h, (kp_l, vp_l)
+            return h, (kp_l, vp_l), ssm_out
 
         if quant:
-            h, (k_new, v_new, ks_new, vs_new) = jax.lax.scan(
-                body, h,
+            h, (k_new, v_new, ks_new, vs_new), state = _layer_scan(
+                cfg, cache, lens, body, h,
                 (params["blocks"], cache.k, cache.v, cache.k_scale,
                  cache.v_scale),
             )
         else:
             ks_new, vs_new = None, None
-            h, (k_new, v_new) = jax.lax.scan(
-                body, h, (params["blocks"], cache.k, cache.v)
+            h, (k_new, v_new), state = _layer_scan(
+                cfg, cache, lens, body, h,
+                (params["blocks"], cache.k, cache.v),
             )
 
     logits = _head_out(cfg, params, h, gather_idx, last_only, _ablate)
+    ssm_new, conv_new = state if state is not None else (None, None)
     return logits, PagedKVCache(
         k=k_new, v=v_new, block_tables=cache.block_tables,
         positions=new_kv_positions, k_scale=ks_new, v_scale=vs_new,
+        ssm=ssm_new, conv=conv_new,
     )
 
 
@@ -1197,6 +1438,7 @@ def forward_ragged(
     B, S = input_ids.shape
     bs, MB = cache.block_size, cache.max_blocks
     quant = cache.quantized
+    lens = _ssm_lens(cache, kv_write_positions, slots)
 
     sin_cos = None
     if cfg.positions == "rotary":
@@ -1222,18 +1464,18 @@ def forward_ragged(
     )
 
     if kernel_attn is not None:
-        def body(h, xs):
+        def body(h, xs, ssm_in):
             bp, layer = xs
-            h, k_f, v_f = _block(
+            h, k_f, v_f, ssm_out = _block(
                 cfg, bp, h, positions, None, None, kv_pos_src, slots,
                 None, mesh=mesh, defer_write=True,
                 attn_override=partial(kernel_attn, layer=layer),
-                sin_cos=sin_cos,
+                sin_cos=sin_cos, ssm_in=ssm_in,
             )
-            return h, (k_f, v_f)
+            return h, (k_f, v_f), ssm_out
 
-        h, ys = jax.lax.scan(
-            body, h,
+        h, ys, state = _layer_scan(
+            cfg, cache, lens, body, h,
             (params["blocks"], jnp.arange(cfg.n_layers, dtype=jnp.int32)),
         )
     else:
@@ -1244,7 +1486,7 @@ def forward_ragged(
             q_lens, kv_pos_src, slot0, cache.max_len
         )
 
-        def body(h, xs):
+        def body(h, xs, ssm_in):
             if quant:
                 bp, kp_l, vp_l, ksp_l, vsp_l = xs
             else:
@@ -1261,19 +1503,19 @@ def forward_ragged(
                     v_scale_layer=vsp_l, n_blocks=nb,
                 )
 
-            h, k_f, v_f = _block(
+            h, k_f, v_f, ssm_out = _block(
                 cfg, bp, h, positions, None, None, kv_pos_src, slots,
                 None, mesh=mesh, defer_write=True,
-                attn_override=ragged_attn, sin_cos=sin_cos,
+                attn_override=ragged_attn, sin_cos=sin_cos, ssm_in=ssm_in,
             )
-            return h, (k_f, v_f)
+            return h, (k_f, v_f), ssm_out
 
         if quant:
             xs = (params["blocks"], cache.k, cache.v, cache.k_scale,
                   cache.v_scale)
         else:
             xs = (params["blocks"], cache.k, cache.v)
-        h, ys = jax.lax.scan(body, h, xs)
+        h, ys, state = _layer_scan(cfg, cache, lens, body, h, xs)
 
     ks_new, vs_new = cache.k_scale, cache.v_scale
     k_fresh, v_fresh = ys  # [L, B, CB, Hkv, D]
@@ -1294,7 +1536,9 @@ def forward_ragged(
     )
 
     logits = _head_out(cfg, params, h, q_lens - 1, False)
+    ssm_new, conv_new = state if state is not None else (None, None)
     return logits, PagedKVCache(
         k=k_new, v=v_new, block_tables=cache.block_tables,
         positions=new_kv_positions, k_scale=ks_new, v_scale=vs_new,
+        ssm=ssm_new, conv=conv_new,
     )

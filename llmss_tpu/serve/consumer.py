@@ -17,6 +17,7 @@ engine, respond. Structural upgrades over the reference (SURVEY.md §2.10,
 
 from __future__ import annotations
 
+import gc
 import logging
 import threading
 import time
@@ -454,6 +455,16 @@ class ContinuousWorker:
 
         if role not in ("unified", "prefill", "decode"):
             raise ValueError(f"unknown worker role: {role!r}")
+        if getattr(getattr(engine, "cfg", None), "ssm", None) is not None and (
+            role != "unified" or kvstore is not None
+        ):
+            # The hand-off's wire format and the tiered store's blobs hold
+            # keys and values only (docs/recurrent-state.md).
+            raise ValueError(
+                "a model with a recurrent state is served by a unified "
+                "worker without a tiered KV store: neither the prefill/"
+                "decode hand-off nor session parking carries the state"
+            )
         self.engine = engine
         self.broker = broker
         self.tokenizer = tokenizer
@@ -604,8 +615,17 @@ class ContinuousWorker:
     ) -> int:
         """Compile the batcher's full executable envelope up front
         (``seq_buckets`` narrows the prompt-length envelope when known;
-        ``prefix_prefill`` adds the prefix-reuse admission variants)."""
-        return self.batcher.prewarm(seq_buckets, prefix_prefill)
+        ``prefix_prefill`` adds the prefix-reuse admission variants).
+
+        Tracing the step programs leaves hundreds of thousands of objects
+        that live as long as the process; a full collection walks them all
+        and stops the loop for longer than a short group runs. They are put
+        out of the collector's reach here, once, so that a collection while
+        serving walks what serving made."""
+        n = self.batcher.prewarm(seq_buckets, prefix_prefill)
+        gc.collect()
+        gc.freeze()
+        return n
 
     def _drain_broker(self, loop: int | None = None) -> int:
         n = 0

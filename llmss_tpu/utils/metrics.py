@@ -154,6 +154,10 @@ class EngineMetrics:
         # span closes.
         self.decode_steps = 0  # guarded_by: self._lock
         self.loop_spans: dict[str, list] = {}  # guarded_by: self._lock
+        # A model with a recurrent state (cfg.ssm): the bytes of the state
+        # pool beside the paged keys and values (a gauge; None = the model
+        # has none and /metrics has no ``cache`` block).
+        self.cache_state_bytes: int | None = None  # guarded_by: self._lock
         self._start = time.monotonic()
 
     def add_tokens(self, n: int) -> None:
@@ -252,6 +256,10 @@ class EngineMetrics:
             self.groups_dispatched += n
             self.decode_steps += steps
 
+    def set_state_bytes(self, n: int) -> None:
+        with self._lock:
+            self.cache_state_bytes = n
+
     def add_loop_span(self, name: str, seconds: float) -> None:
         """A loop span closed (``trace.loop_span(on_close=...)``)."""
         with self._lock:
@@ -292,6 +300,9 @@ class EngineMetrics:
                     for name, (s, n) in sorted(self.loop_spans.items())
                 },
             }
+            state = {} if self.cache_state_bytes is None else {
+                "cache": {"state_bytes": self.cache_state_bytes},
+            }
         return {
             "uptime_s": round(uptime, 1),
             "requests_served": reqs,
@@ -330,6 +341,7 @@ class EngineMetrics:
                 ),
             },
             "loop": loop,
+            **state,
             **(
                 {"speculative": self.spec_stats}
                 if self.spec_stats is not None else {}

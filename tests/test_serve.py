@@ -198,7 +198,12 @@ def test_prewarm_covers_all_shapes(serving):
     worker = ContinuousWorker(
         engine, broker, rows=4, poll_timeout_s=0.01, chunk_steps=2
     )
+    import gc
+
+    frozen = gc.get_freeze_count()
     worker.prewarm()
+    # what tracing the step programs left is out of the collector's reach
+    assert gc.get_freeze_count() > frozen
     b = worker.batcher
     sizes = {
         "prefill_row": b._prefill_row._cache_size(),
