@@ -2085,7 +2085,14 @@ class ContinuousBatcher:
             self.engine.metrics.host_dispatch.record(
                 time.perf_counter() - t0
             )
-            self.engine.metrics.add_group(steps=nc * k)
+            self.engine.metrics.add_group(
+                steps=nc * k,
+                filtered=any(
+                    not r.gen.is_greedy
+                    and (r.gen.top_k > 0 or r.gen.top_p < 1.0)
+                    for r in self.active.values()
+                ),
+            )
             for r in self.active.values():
                 if r.req_id and not r.awaiting_first:
                     trace.record(

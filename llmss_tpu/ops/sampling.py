@@ -10,6 +10,13 @@ reference's per-token ``dist.broadcast`` (``generate.py:144``).
 
 All warper parameters are per-request arrays (dynamic under jit) so a batch
 can mix greedy and sampled requests — required for continuous batching.
+``sample`` has three branches, chosen from those parameters and never from
+the logits: argmax alone, one categorical, or the filtered draw. The
+filtered draw finds each row's top-k / top-p keep-set exactly, from a
+threshold on the value (``_keep_set``: two bisections of a fixed length,
+each step a pass over ``[B, V]``), with no sort of the vocabulary, no
+scatter and no candidate bucket: its cost is the same whatever the
+distributions look like, and it is compiled into every step program.
 
 Randomness is **per-row and stateless**: each draw uses
 ``fold_in(key(seed_row), counter_row)`` where the counter is the absolute
@@ -31,13 +38,6 @@ import jax.numpy as jnp
 # the docstring — so pin the flag explicitly rather than inheriting a
 # version-dependent default.
 jax.config.update("jax_threefry_partitionable", True)
-
-# Static candidate-set size for the fast top-k/top-p path: covers every
-# practical warper (HF's top_k default is 50) while keeping the partial
-# selection ~500x narrower than the 32k-vocab sort it replaces. Rows whose
-# keep-set provably fits are served from the bucket; others fall back to
-# the exact full sort at runtime.
-TOPK_BUCKET = 64
 
 
 def nonfinite_rows(logits: jax.Array) -> jax.Array:
@@ -86,6 +86,87 @@ def row_keys(seeds: jax.Array, counters: jax.Array) -> jax.Array:
     )(seeds, counters)
 
 
+def _value_at(place: jax.Array) -> jax.Array:
+    """The fp32 value at a place in the order of all fp32 values: uint32
+    places ascend as the values do (the inverse of the usual sort key: flip
+    the sign bit, and the rest too where it was set). The places below
+    ``-inf`` and above ``+inf`` are NaN patterns; those below read ``-inf``,
+    so that a comparison with them is one with the least value there is."""
+    s = jax.lax.bitcast_convert_type(place ^ jnp.uint32(1 << 31), jnp.int32)
+    v = jax.lax.bitcast_convert_type(
+        s ^ ((s >> 31) & jnp.int32(0x7FFFFFFF)), jnp.float32
+    )
+    return jnp.where(jnp.isnan(v) & (s < 0), -jnp.inf, v)
+
+
+def _keep_set(
+    scaled: jax.Array,  # [B, V] fp32
+    k_eff: jax.Array,  # [B, 1] int32 in [1, V]; V where top-k is off
+    p_eff: jax.Array,  # [B, 1] f32; 2.0 where top-p is off
+) -> jax.Array:
+    """[B, V] bool: what top-k then top-p keep of each row.
+
+    Order a row by value descending, equal values by lower id first; rank
+    ``r`` is kept iff ``r < k_eff`` and the probability mass strictly
+    before it (softmax over the WHOLE vocabulary) is ``< p_eff``; rank 0
+    always. Such a set is ``{scaled > tau}`` plus the lowest ids of
+    ``{scaled == tau}``, so it is found from a threshold on the value and
+    not from a permutation of the vocabulary. ``tau`` is the least value
+    whose strictly-greater set stays within both limits: a bisection over
+    the 32 bits of a value's place in the order of fp32 values, each step
+    one compare-and-sum pass over ``[B, V]``. The tie group at ``tau`` is
+    cut the same way, by a bisection over the ids. Both loops have a fixed
+    length, so the cost does not depend on what the distributions look
+    like. A row with no filter gets its least value for ``tau`` and keeps
+    everything. Inside the loops everything is computed from ``scaled`` and
+    the loop's own threshold, so that ``scaled`` is the one ``[B, V]``
+    array they read (an ``exp(scaled - lse)`` would be hoisted and stored
+    as a second).
+    """
+    V = scaled.shape[-1]
+    lse = jax.nn.logsumexp(scaled, axis=-1, keepdims=True)
+
+    def count(members):
+        return jnp.sum(members, axis=-1, keepdims=True, dtype=jnp.int32)
+
+    def mass(members):
+        return jnp.sum(
+            jnp.exp(jnp.where(members, scaled, -jnp.inf) - lse),
+            axis=-1, keepdims=True,
+        )
+
+    def fits(n_before, mass_before):  # is the rank after these kept
+        return ((n_before < k_eff) & (mass_before < p_eff)) | (n_before == 0)
+
+    def narrow(i, lo):
+        # ``lo`` holds tau's place down to ``bit``. With this bit clear the
+        # greatest candidate is ``lo | (bit - 1)``: where the rank below
+        # even that is kept, tau has the bit clear.
+        bit = jnp.uint32(1 << 31) >> i.astype(jnp.uint32)
+        above = scaled > _value_at(lo | (bit - 1))
+        return jnp.where(fits(count(above), mass(above)), lo, lo | bit)
+
+    tau = _value_at(jax.lax.fori_loop(
+        0, 32, narrow, jnp.zeros(k_eff.shape, jnp.uint32)
+    ))
+    above, tie = scaled > tau, scaled == tau
+    n_above, mass_above, p_tau = count(above), mass(above), jnp.exp(tau - lse)
+    ids = jnp.arange(V, dtype=jnp.int32)[None, :]
+    id_bits = max(1, (V - 1).bit_length())
+
+    def admit(i, last):
+        # The greatest id whose tie members before it leave it a kept rank.
+        j = last | (jnp.int32(1 << (id_bits - 1)) >> i)
+        n = count(tie & (ids < j))
+        ok = fits(n_above + n, mass_above + n.astype(jnp.float32) * p_tau)
+        return jnp.where(ok, j, last)
+
+    last = jax.lax.fori_loop(
+        0, id_bits, admit, jnp.zeros(k_eff.shape, jnp.int32)
+    )
+    return above | (tie & (ids <= last))
+
+
 def sample(
     logits: jax.Array,  # [B, V] fp32
     *,
@@ -98,20 +179,19 @@ def sample(
 ) -> jax.Array:
     """Sample next token ids [B] int32.
 
-    Dynamic per-request top-k/top-p warpers run, in the common case, over a
-    static ``lax.top_k`` bucket of ``TOPK_BUCKET`` candidates — a partial
-    selection, not the full descending ``argsort`` whose V·logV cost
-    dominated the sampled step at 32k+ vocab. The bucket path is *exact*
-    whenever every filtered row's keep-set provably lies inside the bucket
-    (``top_k <= TOPK_BUCKET``, or the bucket's probability mass already
-    reaches ``top_p``); otherwise a runtime ``lax.cond`` falls back to the
-    full sort with identical semantics. All paths pair the Gumbel noise
-    with token *ids* (scatter back to vocab order before the draw), so the
-    same (seed, counter) yields the same token whichever path — or batch
-    mix — executes it; greedy-only batches pay only the argmax. One
-    compiled program serves every mix; the conditions are data, not shapes.
+    Three branches, chosen batch-wide from the requests' parameters and
+    never from the logits: a batch with no sampled row pays the argmax; a
+    batch whose sampled rows have no active top-k/top-p pays one
+    ``categorical`` over the temperature-scaled logits; otherwise every
+    row's keep-set comes from ``_keep_set`` (a threshold search: no sort of
+    the vocabulary, no scatter, and a cost that does not depend on what
+    the distributions look like) and the draw runs over the filtered
+    logits in vocabulary order. The Gumbel noise thus pairs with token
+    *ids*, and the same (seed, counter) yields the same token whichever
+    branch - or batch mix - executes it. One compiled program serves
+    every mix; the conditions are data, not shapes.
     """
-    B, V = logits.shape
+    V = logits.shape[-1]
     greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
     temp = jnp.maximum(temperature, 1e-6)[:, None]
@@ -119,75 +199,22 @@ def sample(
     keys = row_keys(seeds, counters)
     categorical_rows = jax.vmap(jax.random.categorical)
 
-    rank_full = jnp.arange(V, dtype=jnp.int32)[None, :]
-    k_eff = jnp.where(top_k <= 0, V, top_k).astype(jnp.int32)[:, None]
-    # top_p >= 1.0 means disabled: compare against 2.0 so fp32 cumsum
-    # rounding (cum_before hitting exactly 1.0 at a tail token) can
-    # never mask a token a plain categorical could draw — keeping the
-    # keep-everything case *exactly* equal to _plain_sample.
-    p_eff = jnp.where(top_p >= 1.0, 2.0, top_p)[:, None]
-    rows = jnp.arange(B, dtype=jnp.int32)[:, None]
-
-    def _draw_from_keep(keep: jax.Array) -> jax.Array:
-        # Gumbel pairs with token ids, not sorted ranks (see docstring).
+    def _filtered_sample() -> jax.Array:
+        k_eff = jnp.where(top_k <= 0, V, jnp.minimum(top_k, V))[:, None]
+        # top_p >= 1.0 means disabled: compare against 2.0 so fp32 rounding
+        # of the mass (reaching exactly 1.0 at a tail token) can never mask
+        # a token a plain categorical could draw - keeping the
+        # keep-everything row *exactly* equal to _plain_sample.
+        p_eff = jnp.where(top_p >= 1.0, 2.0, top_p)[:, None]
         filtered = jnp.where(
-            keep, scaled, float(jnp.finfo(jnp.float32).min)
+            _keep_set(scaled, k_eff, p_eff), scaled,
+            float(jnp.finfo(jnp.float32).min),
         )
         return categorical_rows(keys, filtered).astype(jnp.int32)
 
-    def _keep_prefix(svals: jax.Array, order: jax.Array) -> jax.Array:
-        """Keep-set over (descending values, their token ids), scattered
-        back to vocab order. Works for the full sort and the top-k bucket
-        alike — both break value ties by lower token id first, so the two
-        paths compute identical keep-sets whenever both are applicable."""
-        Kb = svals.shape[1]
-        # Softmax denominator over the FULL vocab (not just the bucket):
-        # nucleus mass must be true probability mass.
-        lse = jax.nn.logsumexp(scaled, axis=-1, keepdims=True)
-        probs = jnp.exp(svals - lse)
-        # Probability mass strictly before each sorted token: nucleus keeps
-        # the smallest prefix whose mass reaches top_p (always >= 1 token).
-        cum_before = jnp.cumsum(probs, axis=-1) - probs
-        keep_sorted = (rank_full[:, :Kb] < k_eff) & (cum_before < p_eff)
-        keep_sorted = keep_sorted.at[:, 0].set(True)
-        return jnp.zeros((B, V), bool).at[rows, order].set(
-            keep_sorted, mode="drop"
-        )
-
-    def _filtered_sample() -> jax.Array:
-        Kb = min(TOPK_BUCKET, V)
-        bvals, border = jax.lax.top_k(scaled, Kb)
-        # Rows with no active warper keep the FULL vocab even on the
-        # bucket path — a mixed batch must not truncate an unfiltered
-        # row's distribution to the bucket (batch-mix determinism).
-        unfiltered = (top_k <= 0) & (top_p >= 1.0)
-
-        def _bucket() -> jax.Array:
-            keep = _keep_prefix(bvals, border) | unfiltered[:, None]
-            return _draw_from_keep(keep)
-
-        def _full_sort() -> jax.Array:
-            order = jnp.argsort(-scaled, axis=-1)
-            svals = jnp.take_along_axis(scaled, order, axis=-1)
-            return _draw_from_keep(_keep_prefix(svals, order))
-
-        # The bucket is exact for a row iff everything outside it is
-        # excluded by one of the active filters: top_k within the bucket,
-        # or the bucket's mass already reaching top_p. (Greedy/unfiltered
-        # rows don't constrain the choice.)
-        lse = jax.nn.logsumexp(scaled, axis=-1, keepdims=True)
-        bucket_mass = jnp.sum(jnp.exp(bvals - lse), axis=-1, keepdims=True)
-        row_ok = (
-            greedy[:, None]
-            | unfiltered[:, None]
-            | (k_eff <= Kb)
-            | (bucket_mass >= p_eff)
-        )
-        return jax.lax.cond(jnp.all(row_ok), _bucket, _full_sort)
-
     def _plain_sample() -> jax.Array:
         # No top-k/top-p anywhere in the batch: categorical over the
-        # temperature-scaled logits needs no sort.
+        # temperature-scaled logits needs no keep-set.
         return categorical_rows(keys, scaled).astype(jnp.int32)
 
     any_sampled = jnp.any(~greedy)

@@ -149,10 +149,13 @@ class EngineMetrics:
         self.chunk_budget_tokens = 0  # guarded_by: self._lock
         # Worker-loop accounting, cumulative so that a difference of two
         # /metrics reads is exact over any window. Always on: decode steps
-        # of the dispatched groups (chunks x k). With tracing on: seconds
-        # and count of every loop span (utils/trace.py), added when the
-        # span closes.
+        # of the dispatched groups (chunks x k), and those of them whose
+        # live rows put the sampler through its keep-set search (a sampled
+        # row with top_k > 0 or top_p < 1). With tracing on: seconds and
+        # count of every loop span (utils/trace.py), added when the span
+        # closes.
         self.decode_steps = 0  # guarded_by: self._lock
+        self.filter_steps = 0  # guarded_by: self._lock
         self.loop_spans: dict[str, list] = {}  # guarded_by: self._lock
         # A model with a recurrent state (cfg.ssm): the bytes of the state
         # pool beside the paged keys and values (a gauge; None = the model
@@ -249,12 +252,17 @@ class EngineMetrics:
         with self._lock:
             self.host_syncs += n
 
-    def add_group(self, n: int = 1, steps: int = 0) -> None:
+    def add_group(
+        self, n: int = 1, steps: int = 0, filtered: bool = False,
+    ) -> None:
         """A grouped decode program was dispatched, of ``steps`` decode
-        steps (chunks x k)."""
+        steps (chunks x k); ``filtered`` when a live row samples with an
+        active top-k / top-p, so that every step searches a keep-set."""
         with self._lock:
             self.groups_dispatched += n
             self.decode_steps += steps
+            if filtered:
+                self.filter_steps += steps
 
     def set_state_bytes(self, n: int) -> None:
         with self._lock:
@@ -295,6 +303,7 @@ class EngineMetrics:
             )
             loop = {
                 "decode_steps": self.decode_steps,
+                "filter_steps": self.filter_steps,
                 "spans": {
                     name: {"seconds": round(s, 6), "count": n}
                     for name, (s, n) in sorted(self.loop_spans.items())

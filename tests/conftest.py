@@ -33,3 +33,25 @@ def devices():
     devs = jax.devices()
     assert len(devs) == 8, f"expected 8 virtual CPU devices, got {len(devs)}"
     return devs
+
+
+@pytest.fixture(scope="module")
+def toy_engine(devices):
+    """A two-layer llama-shaped engine on a dp=2 x tp=4 mesh, for tests that
+    drive the scheduler or the worker and not a model."""
+    from llmss_tpu.engine import DecodeEngine
+    from llmss_tpu.models.common import DecoderConfig
+    from llmss_tpu.models.decoder import init_params
+    from llmss_tpu.parallel import MeshPlan, make_mesh
+
+    cfg = DecoderConfig(
+        model_type="llama", vocab_size=64, hidden_size=32, n_layers=2,
+        n_heads=4, n_kv_heads=2, head_dim=8, intermediate_size=64,
+        max_position_embeddings=64, activation="silu", norm="rmsnorm",
+        norm_eps=1e-5, mlp="swiglu", positions="rotary", rope_style="half",
+        rotary_dim=8, attn_bias=False, mlp_bias=False,
+        tie_word_embeddings=False, dtype="float32",
+    )
+    mesh = make_mesh(MeshPlan(dp=2, tp=4))
+    params = init_params(cfg, mesh, jax.random.key(0))
+    return DecodeEngine(cfg, params, mesh, max_seq_len=64)

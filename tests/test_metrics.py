@@ -1,6 +1,7 @@
 """Metrics: stats math + engine/serving integration."""
 
 import numpy as np
+import pytest
 
 from llmss_tpu.utils.metrics import EngineMetrics, LatencyStat
 
@@ -156,3 +157,54 @@ def test_render_prometheus():
     assert "ready" not in text and "alive" not in text
     assert "llmss_supervisor_restarts 0" in lines
     assert text.endswith("\n")
+
+
+@pytest.mark.parametrize("gen_kw, filtered", [
+    (dict(is_greedy=True, top_p=0.5, top_k=3), False),  # greedy: no draw
+    (dict(is_greedy=False, temperature=0.8), False),  # plain categorical
+    (dict(is_greedy=False, top_p=0.95), True),
+    (dict(is_greedy=False, top_k=5), True),
+])
+def test_filter_steps_count_the_groups_with_a_filtered_row_live(
+    toy_engine, gen_kw, filtered,
+):
+    """``loop.filter_steps`` rises by ``chunks x k`` for every group
+    dispatched while a live row samples with an active top-k / top-p, and
+    not otherwise; like ``decode_steps`` it is counted with tracing off."""
+    from llmss_tpu.engine import GenerationParams
+    from llmss_tpu.engine.scheduler import ContinuousBatcher
+    from llmss_tpu.utils import trace
+
+    was = trace.enabled()
+    trace.set_enabled(False)
+    try:
+        batcher = ContinuousBatcher(
+            toy_engine, rows=2, chunk_steps=2, group_chunks=2,
+        )
+        m = toy_engine.metrics
+        before = m.to_dict()["loop"]
+        # one greedy row beside the row under test: the count goes by ANY
+        # live row, and the greedy one outlives the other
+        batcher.submit(
+            [5, 9], GenerationParams(max_new_tokens=24), lambda t: None,
+        )
+        batcher.submit(
+            [3, 14, 15], GenerationParams(max_new_tokens=8, **gen_kw),
+            lambda t: None,
+        )
+        want = 0
+        while not batcher.idle:
+            g0 = m.groups_dispatched
+            # a group goes out with the rows live when step() is entered
+            live = any(not r.gen.is_greedy for r in batcher.active.values())
+            batcher.step()
+            if m.groups_dispatched > g0:
+                g = batcher._inflight
+                want += g.n_chunks * g.k * int(filtered and live)
+        after = m.to_dict()["loop"]
+    finally:
+        trace.set_enabled(was)
+    steps = after["decode_steps"] - before["decode_steps"]
+    assert after["spans"] == before["spans"]  # tracing was off
+    assert after["filter_steps"] - before["filter_steps"] == want
+    assert (0 < want < steps) if filtered else want == 0

@@ -209,13 +209,10 @@ def test_sampling_per_row_seed_determinism():
 
 
 def test_sampling_topk_bucket_matches_full_sort():
-    """The static top-k bucket path and the full-sort fallback must draw
-    identical tokens for rows they both serve: a row's draw is batch-mix
-    independent, so adding one bucket-busting row (top_k > TOPK_BUCKET)
-    flips the whole batch to the full sort without changing any other
-    row's token."""
-    from llmss_tpu.ops.sampling import TOPK_BUCKET
-
+    """A row's draw is batch-mix independent: adding one row whose keep-set
+    is wide (top_k 164, top_p 0.999) changes no other row's token. (The
+    name is from when such a row flipped the whole batch from a 64-wide
+    candidate bucket to a sort of the vocabulary; the property stays.)"""
     rng = np.random.default_rng(3)
     V = 512
     logits = jnp.asarray(rng.normal(size=(4, V)) * 3, jnp.float32)
@@ -227,15 +224,10 @@ def test_sampling_topk_bucket_matches_full_sort():
     )
     a = np.asarray(sample(logits, **_sargs(4, seed=11), **kw))
 
-    # Same rows + a fifth row whose top_k exceeds the bucket: the batch
-    # falls back to the full sort; shared rows must not move. (Peaked
-    # logits keep the top_p rows resolvable in-bucket for run A.)
     logits_b = jnp.concatenate([logits, logits[:1]], axis=0)
     kw_b = dict(
         temperature=jnp.full(5, 0.8),
-        top_k=jnp.asarray(
-            [40, 0, 5, 40, TOPK_BUCKET + 100], jnp.int32
-        ),
+        top_k=jnp.asarray([40, 0, 5, 40, 164], jnp.int32),
         top_p=jnp.asarray([1.0, 0.9, 0.95, 0.7, 0.999], jnp.float32),
         greedy=jnp.zeros(5, bool),
     )
@@ -247,11 +239,11 @@ def test_sampling_topk_bucket_matches_full_sort():
 
 
 def test_sampling_bucket_fallback_on_flat_nucleus():
-    """Near-uniform logits with a high top_p cannot resolve the nucleus
-    inside the bucket — the runtime guard must take the full sort, and the
-    draw stays deterministic and within the nucleus-eligible set."""
+    """Near-uniform logits with a high top_p: the nucleus is nearly the
+    whole vocabulary (no candidate bucket could hold it); the draw stays
+    deterministic and within the nucleus-eligible set."""
     V = 512
-    logits = jnp.zeros((2, V), jnp.float32)  # uniform: mass(bucket) = Kb/V
+    logits = jnp.zeros((2, V), jnp.float32)  # uniform: every value ties
     kw = dict(
         temperature=jnp.ones(2),
         top_k=jnp.zeros(2, jnp.int32),
@@ -261,9 +253,205 @@ def test_sampling_bucket_fallback_on_flat_nucleus():
     a = np.asarray(sample(logits, **_sargs(2, seed=5), **kw))
     b = np.asarray(sample(logits, **_sargs(2, seed=5), **kw))
     np.testing.assert_array_equal(a, b)
-    # uniform + top_p=0.99 keeps ~507 of 512 tokens; any id is plausible,
-    # but it must be a valid token id.
-    assert ((a >= 0) & (a < V)).all()
+    # uniform + top_p=0.99 keeps the 507 lowest ids of 512 (ties go by id:
+    # 506 / 512 < 0.99 <= 507 / 512).
+    assert ((a >= 0) & (a < 507)).all()
+    keep = np.asarray(_keep(logits, kw["top_k"], kw["top_p"]))
+    np.testing.assert_array_equal(
+        keep, np.broadcast_to(np.arange(V) < 507, (2, V)))
+
+
+# -- the keep-set against an oracle -------------------------------------------
+#
+# The oracle is the vocabulary-wide sort that ``sample()`` ran as its
+# fallback until the threshold search replaced it (ops/sampling.py): order
+# by value descending, equal values by lower id (a stable sort), keep rank r
+# iff r < k_eff and the mass strictly before it is < p_eff, rank 0 always,
+# scatter back to vocabulary order, draw with the row's own key.
+
+
+def _limits(V, top_k, top_p):
+    k_eff = jnp.where(top_k <= 0, V, jnp.minimum(top_k, V))[:, None]
+    p_eff = jnp.where(top_p >= 1.0, 2.0, top_p)[:, None]
+    return k_eff, p_eff
+
+
+@jax.jit
+def _keep(scaled, top_k, top_p):
+    from llmss_tpu.ops.sampling import _keep_set
+
+    return _keep_set(scaled, *_limits(scaled.shape[-1], top_k, top_p))
+
+
+@jax.jit
+def _oracle(scaled, top_k, top_p, seeds, counters):
+    """(keep-set, mass strictly before each token, drawn token), all in
+    vocabulary order, by a full sort."""
+    from llmss_tpu.ops.sampling import row_keys
+
+    B, V = scaled.shape
+    k_eff, p_eff = _limits(V, top_k, top_p)
+    order = jnp.argsort(-scaled, axis=-1, stable=True)
+    svals = jnp.take_along_axis(scaled, order, axis=-1)
+    probs = jnp.exp(svals - jax.nn.logsumexp(scaled, axis=-1, keepdims=True))
+    cum_before = jnp.cumsum(probs, axis=-1) - probs
+    rank = jnp.arange(V, dtype=jnp.int32)[None, :]
+    keep_sorted = ((rank < k_eff) & (cum_before < p_eff)).at[:, 0].set(True)
+    rows = jnp.arange(B, dtype=jnp.int32)[:, None]
+    keep = jnp.zeros((B, V), bool).at[rows, order].set(keep_sorted)
+    before = jnp.zeros((B, V), jnp.float32).at[rows, order].set(cum_before)
+    filtered = jnp.where(keep, scaled, float(jnp.finfo(jnp.float32).min))
+    tok = jax.vmap(jax.random.categorical)(row_keys(seeds, counters), filtered)
+    return keep, before, tok.astype(jnp.int32)
+
+
+_TOP_P = (0.1, 0.7, 0.95, 0.999, 1.0)
+_jit_sample = jax.jit(sample)
+
+
+@pytest.mark.parametrize("top_k", [0, 1, 5, 64, 65, 500])
+@pytest.mark.parametrize("shape", ["peaked", "flat"])
+@pytest.mark.parametrize("temperature", [0.3, 0.8, 3.0])
+@pytest.mark.parametrize("V", [512, 4096])
+def test_sampling_keep_set_matches_sort_oracle(V, temperature, shape, top_k):
+    """Every top_p of the grid in one batch (three logit rows each): the
+    drawn tokens are the oracle's for every row, and the keep-sets are the
+    oracle's but for tokens whose mass-before is within 1e-5 of top_p (the
+    two sum the same probabilities in another order)."""
+    B = 3 * len(_TOP_P)
+    rng = np.random.default_rng([V, top_k, int(temperature * 10)])
+    logits = jnp.asarray(
+        rng.normal(size=(B, V)) * (4.0 if shape == "peaked" else 0.05),
+        jnp.float32,
+    )
+    tk = jnp.full(B, top_k, jnp.int32)
+    tp = jnp.asarray(np.tile(_TOP_P, 3), jnp.float32)
+    temp = jnp.full(B, temperature, jnp.float32)
+    seeds = jnp.arange(B, dtype=jnp.int32) + 17
+    counters = jnp.arange(B, dtype=jnp.int32) * 3
+
+    scaled = logits / temp[:, None]
+    want_keep, before, want_tok = _oracle(scaled, tk, tp, seeds, counters)
+    got_tok = _jit_sample(
+        logits, seeds=seeds, counters=counters, temperature=temp,
+        top_k=tk, top_p=tp, greedy=jnp.zeros(B, bool),
+    )
+    np.testing.assert_array_equal(np.asarray(got_tok), np.asarray(want_tok))
+    differ = np.asarray(_keep(scaled, tk, tp)) != np.asarray(want_keep)
+    edge = np.abs(np.asarray(before) - np.asarray(tp)[:, None]) < 1e-5
+    assert not (differ & ~edge).any()
+    assert differ.sum() <= B  # a token at the edge, not a region
+
+
+@pytest.mark.parametrize("row, top_k, top_p, kept", [
+    # equal values across the top-k cutoff: rank 0 is id 0, then the 2.0s
+    # by id: 2, 3, 4, 6
+    ([3.0, 1.0, 2.0, 2.0, 2.0, 0.0, 2.0, -1.0], 3, 1.0, [0, 2, 3]),
+    ([3.0, 1.0, 2.0, 2.0, 2.0, 0.0, 2.0, -1.0], 5, 1.0, [0, 2, 3, 4, 6]),
+    # equal values across the top-p cutoff: 0.4 then four of 0.15; the mass
+    # before them is 0.4, 0.55, 0.70, 0.85
+    (np.log([0.15, 0.4, 0.15, 0.15, 0.15]), 0, 0.6, [1, 0, 2]),
+    (np.log([0.15, 0.4, 0.15, 0.15, 0.15]), 0, 0.75, [1, 0, 2, 3]),
+    (np.log([0.15, 0.4, 0.15, 0.15, 0.15]), 2, 0.75, [1, 0]),
+    # a tie at the very top: rank 0 is the lower id, whatever top_p says
+    ([1.0, 5.0, 5.0, 0.0], 0, 1e-6, [1]),
+    ([1.0, 5.0, 5.0, 0.0], 1, 1.0, [1]),
+    # a top_k beyond the vocabulary is no top_k
+    ([1.0, 5.0, 5.0, 0.0], 9, 1.0, [0, 1, 2, 3]),
+    ([1.0, 5.0, 5.0, 0.0], 9, 0.7, [1, 2]),
+    # -0.0 and 0.0 are one value
+    ([-1.0, 0.0, -0.0, 0.0, -2.0], 2, 1.0, [1, 2]),
+    ([-1.0, -0.0, 0.0, 0.0, -2.0], 2, 1.0, [1, 2]),
+])
+def test_sampling_ties_keep_the_lower_id(row, top_k, top_p, kept):
+    """A tie group cut by top-k or top-p keeps its lowest ids, exactly; the
+    draws stay inside the keep-set and reach all of it."""
+    V = len(row)
+    scaled = jnp.asarray([row], jnp.float32)
+    tk, tp = jnp.full(1, top_k, jnp.int32), jnp.full(1, top_p, jnp.float32)
+    want = np.isin(np.arange(V), kept)[None, :]
+    np.testing.assert_array_equal(np.asarray(_keep(scaled, tk, tp)), want)
+    np.testing.assert_array_equal(
+        np.asarray(_oracle(scaled, tk, tp, jnp.zeros(1, jnp.int32),
+                           jnp.zeros(1, jnp.int32))[0]), want)
+    n = 64
+    toks = np.asarray(_jit_sample(
+        jnp.broadcast_to(scaled, (n, V)), seeds=jnp.arange(n, dtype=jnp.int32),
+        counters=jnp.zeros(n, jnp.int32), temperature=jnp.full(n, 2.0),
+        top_k=jnp.full(n, top_k, jnp.int32), top_p=jnp.full(n, top_p),
+        greedy=jnp.zeros(n, bool),
+    ))
+    assert set(toks.tolist()) <= set(kept)
+
+
+def _sample_shapes(B, V):
+    S = jax.ShapeDtypeStruct
+    return S((B, V), jnp.float32), dict(
+        seeds=S((B,), jnp.int32), counters=S((B,), jnp.int32),
+        temperature=S((B,), jnp.float32), top_k=S((B,), jnp.int32),
+        top_p=S((B,), jnp.float32), greedy=S((B,), jnp.bool_),
+    )
+
+
+def test_sampling_program_has_no_sort_no_scatter_and_two_conds():
+    """The cost that was 77% of a decode step cannot come back unseen: at
+    the benchmark cell's size the program holds no sort, no top-k, no
+    scatter and no gather over [B, V], and chooses among three branches
+    with two conds, by the requests' parameters alone."""
+    logits, kw = _sample_shapes(64, 49152)
+    text = jax.jit(sample).lower(logits, **kw).as_text()
+    for op in ("sort", "top_k", "scatter", "gather"):
+        assert f"stablehlo.{op}" not in text and f"chlo.{op}" not in text, op
+    assert text.count("stablehlo.case") + text.count("stablehlo.if") == 2
+
+
+@pytest.mark.parametrize("plan", [MeshPlan(tp=8), MeshPlan(dp=2, tp=4)],
+                         ids=["tp8", "dp2tp4"])
+def test_sampling_adds_no_collective_on_a_mesh(devices, plan):
+    """Logits reach ``sample()`` whole in the vocabulary on every device
+    (``models/decoder.py: _head_out``), rows split over dp: the keep-set
+    search is row-wise, so the compiled program moves nothing between
+    devices, however many passes it makes."""
+    import re
+
+    m = make_mesh(plan)
+    rows = P("dp") if plan.dp > 1 else P()
+    logits, kw = _sample_shapes(8, 4096)
+
+    def on(s, spec):
+        return jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=NamedSharding(m, spec))
+
+    text = jax.jit(sample).lower(
+        on(logits, P(*rows, None)), **{k: on(v, rows) for k, v in kw.items()},
+    ).compile().as_text()
+    assert not re.findall(
+        r"= \S+ (all-reduce|all-gather|all-to-all|reduce-scatter|"
+        r"collective-permute)", text)
+
+
+@pytest.mark.parametrize("others_greedy", [False, True])
+def test_sampling_unfiltered_batch_is_plain_categorical(others_greedy):
+    """No active top-k / top-p in the batch (falcon-h1-34b-1chip.chat's
+    traffic): the tokens are ``jax.random.categorical`` over the scaled
+    logits with the rows' own keys, bit for bit."""
+    from llmss_tpu.ops.sampling import row_keys
+
+    B, V = 6, 4096
+    rng = np.random.default_rng(12)
+    logits = jnp.asarray(rng.normal(size=(B, V)), jnp.float32)
+    seeds = jnp.arange(B, dtype=jnp.int32) + 3
+    counters = jnp.arange(B, dtype=jnp.int32) + 40
+    temp = jnp.asarray([0.3, 0.8, 0.8, 1.0, 3.0, 3.0], jnp.float32)
+    greedy = jnp.asarray([False] + [others_greedy] * (B - 1))
+    got = np.asarray(sample(
+        logits, seeds=seeds, counters=counters, temperature=temp,
+        top_k=jnp.zeros(B, jnp.int32), top_p=jnp.ones(B), greedy=greedy,
+    ))
+    plain = np.asarray(jax.vmap(jax.random.categorical)(
+        row_keys(seeds, counters), logits / temp[:, None]))
+    want = np.where(np.asarray(greedy), np.asarray(logits).argmax(-1), plain)
+    np.testing.assert_array_equal(got, want)
 
 
 def test_sampling_unfiltered_row_keeps_full_vocab_in_mixed_batch():

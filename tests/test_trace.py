@@ -603,29 +603,9 @@ def test_profile_endpoint_serializes_captures(tmp_path):
 
 # -- tracing on adds zero steady-state recompiles ----------------------------
 
-import jax  # noqa: E402
-
 from llmss_tpu.engine import DecodeEngine, GenerationParams  # noqa: E402
 from llmss_tpu.engine.scheduler import ContinuousBatcher  # noqa: E402
-from llmss_tpu.models.common import DecoderConfig  # noqa: E402
-from llmss_tpu.models.decoder import init_params  # noqa: E402
-from llmss_tpu.parallel import MeshPlan, make_mesh  # noqa: E402
 from llmss_tpu.serve.consumer import ContinuousWorker  # noqa: E402
-
-
-@pytest.fixture(scope="module")
-def toy_engine(devices):
-    cfg = DecoderConfig(
-        model_type="llama", vocab_size=64, hidden_size=32, n_layers=2,
-        n_heads=4, n_kv_heads=2, head_dim=8, intermediate_size=64,
-        max_position_embeddings=64, activation="silu", norm="rmsnorm",
-        norm_eps=1e-5, mlp="swiglu", positions="rotary", rope_style="half",
-        rotary_dim=8, attn_bias=False, mlp_bias=False,
-        tie_word_embeddings=False, dtype="float32",
-    )
-    mesh = make_mesh(MeshPlan(dp=2, tp=4))
-    params = init_params(cfg, mesh, jax.random.key(0))
-    return DecodeEngine(cfg, params, mesh, max_seq_len=64)
 
 
 def test_tracing_adds_no_steady_state_recompiles(toy_engine):
