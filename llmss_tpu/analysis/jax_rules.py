@@ -183,8 +183,8 @@ class JitSite:
 class JitRegistry:
     sites: list[JitSite] = dataclasses.field(default_factory=list)
     #: simple names of functions known to be jit-compiled (pass B taints
-    #: their call results), including attribute names like ``_decode_many``
-    #: for ``self._decode_many = jax.jit(...)``.
+    #: their call results), including attribute names like ``_decode_group``
+    #: for ``self._decode_group = jax.jit(...)``.
     jit_value_names: set[str] = dataclasses.field(default_factory=set)
     #: function simple name -> (FunctionDef, path) for body analysis
     functions: dict[str, tuple[ast.FunctionDef, str]] = dataclasses.field(

@@ -4,7 +4,7 @@ graftlint (``jax_rules.py``) works on the AST and CompileGuard/devtel
 watch the runtime; this module audits the *lowered programs themselves*.
 It builds a tiny-but-real engine (2 layers, random params) over a
 configurable mesh, traces every production jitted program — the prefill
-buckets, single/fused/grouped decode, the ragged mixed group, the
+buckets, single and grouped decode, the ragged mixed group, the
 speculative group, and the paged absorb/merge scatters — and checks the
 jaxpr + optimized HLO of each:
 
@@ -483,15 +483,6 @@ def _build_decode(env: AuditEnv):
     return env.engine._decode, args, {"t_bucket": None}
 
 
-def _build_decode_many(env: AuditEnv):
-    args = (
-        env.params, _vec_i32(), env.engine.new_cache(BATCH),
-        jnp.ones((BATCH,), jnp.int32), env.sample_args,
-        jnp.zeros((BATCH,), bool), _vec_i32(-1),
-    )
-    return env.engine._decode_many, args, {"n_steps": 4, "t_bucket": None}
-
-
 def _build_decode_group(env: AuditEnv):
     args = (
         env.params, _vec_i32(), env.engine.new_cache(BATCH),
@@ -580,7 +571,7 @@ def _build_import_blocks(env: AuditEnv):
 
 def registry() -> list[Program]:
     """Every production program, named by its executable signature
-    (``utils/signatures.py`` — the same vocabulary devtel prices by).
+    (``utils/signatures.py``).
     One registration per line: suppression comments and findings anchor
     here."""
     from llmss_tpu.utils.signatures import signature, signature_str
@@ -597,7 +588,6 @@ def registry() -> list[Program]:
     _reg("prefill", (BATCH, 32), (0,), _build_prefill(32))
     _reg("prefill", (BATCH, 64), (0,), _build_prefill(64))
     _reg("decode", (BATCH, None), (0,), _build_decode)
-    _reg("decode_many", (BATCH, 4, None), (0, 4), _build_decode_many)
     _reg("decode_group", (BATCH, 2, 2, None), (0,), _build_decode_group)
     _reg("ragged_group", (BATCH, 2, 4), (0,), _build_ragged_group)
     _reg("spec_group", (BATCH, 2, 2, None), (0,), _build_spec_group)

@@ -192,7 +192,6 @@ class DecodeEngine:
         self.metrics = EngineMetrics()
         self._ladder = self.bucket_ladder()
         self._canon_cache_memo: dict[tuple, KVCache | PagedKVCache] = {}
-        self._devtel_model: devtel.EngineCostModel | None = None
 
         # mesh is partial-bound (a compile-time constant, not a traced arg):
         # it enables the shard_map'd Pallas attention path inside forward.
@@ -202,11 +201,6 @@ class DecodeEngine:
         self._decode = jax.jit(
             partial(self._decode_impl, cfg, mesh), donate_argnums=(2,),
             static_argnames=("t_bucket",),
-        )
-        self._decode_many = jax.jit(
-            partial(self._decode_many_impl, cfg, mesh),
-            donate_argnums=(2,),
-            static_argnames=("n_steps", "t_bucket"),
         )
         # Grouped decode: n_chunks fused chunks in ONE program with ONE
         # packed device→host fetch for the whole group. Donates the token
@@ -346,8 +340,8 @@ class DecodeEngine:
         (pad slots carry no positions): construction rides the exact
         executables ``prewarm(prefix_prefill=True)`` already compiled and
         the seed scatter compiles once per bucket, not once per prefix
-        length — this removed a ~28 s one-time bespoke-shape compile per
-        distinct prefix length (PREFIX_BENCH.json)."""
+        length — this removed a one-time bespoke-shape compile per
+        distinct prefix length."""
         if self.cfg.ssm is not None:
             raise ValueError(
                 "prefix reuse is not carried for a model with a recurrent "
@@ -454,43 +448,9 @@ class DecodeEngine:
         )
 
     @staticmethod
-    def _decode_many_impl(
-        cfg, mesh, params, tokens, cache, cur_pos, sample_args, done,
-        eos, *, n_steps: int, t_bucket: int | None = None,
-    ):
-        """Fused multi-token decode: lax.scan over the single-token step.
-
-        Returns a 5th array, ``poisoned`` [B] bool: rows whose logits went
-        non-finite at any step of this chunk (ops/sampling.nonfinite_rows).
-        A poisoned row is forced done on device — its later "tokens" are
-        EOS fills — and the host errors out exactly that row; co-batched
-        rows never see it (row isolation is positional)."""
-        from llmss_tpu.parallel.sharding import ys_pin
-
-        body = partial(
-            DecodeEngine._decode_step_body, cfg, mesh, params, sample_args,
-            eos, t_bucket,
-        )
-        poisoned0 = jnp.zeros_like(done)
-        carry, toks = jax.lax.scan(
-            body, (tokens, cache, cur_pos, done, poisoned0), None,
-            length=n_steps,
-        )
-        tokens, cache, cur_pos, done, poisoned = carry
-        # The host reads the stacked tokens: pin them replicated, same
-        # GSPMD partial-sum hazard as _decode_group_impl (found by
-        # shardcheck — this path predates the grouped fix and leaked the
-        # same unpinned ys to np.asarray in generate_fused/speculative).
-        pin = ys_pin(mesh)
-        return pin(toks.T), cache, cur_pos, done, poisoned  # [B, n_steps]
-
-    @staticmethod
     def _decode_step_body(cfg, mesh, params, sample_args, eos, t_bucket,
                           carry, _x=None):
-        """One fused decode step — the scanned body shared by
-        ``_decode_many`` and the grouped ``_decode_group`` (the two paths
-        are bit-identical by construction because this IS the same
-        traced program)."""
+        """One fused decode step — the body ``_decode_group`` scans."""
         from llmss_tpu.models.decoder import forward
         from llmss_tpu.ops.sampling import fold_step_outcome
 
@@ -523,7 +483,7 @@ class DecodeEngine:
         eos, *, n_chunks: int, n_steps: int, t_bucket: int | None = None,
     ):
         """A GROUP of ``n_chunks`` fused decode chunks as one program: an
-        outer ``lax.scan`` over the ``_decode_many`` chunk scan, with EOS/
+        outer ``lax.scan`` over a chunk's ``lax.scan`` of the step, with EOS/
         done and poison folded into the on-device carry so no host decision
         is needed between chunks. The host gets everything in ONE packed
         int32 transfer — ``n_chunks·B·n_steps`` tokens followed by
@@ -747,43 +707,6 @@ class DecodeEngine:
                 f"({self.max_seq_len})"
             )
 
-    def devtel_cost_model(self) -> devtel.EngineCostModel:
-        """Lazy analytical roofline model for this engine's config — the
-        fallback cost source when the backend's cost_analysis is empty
-        and the lazy source for signatures first seen mid-serve."""
-        if self._devtel_model is None:
-            count, nbytes = devtel.param_stats(self.params)
-            self._devtel_model = devtel.EngineCostModel(
-                self.cfg, count, nbytes,
-                kv_itemsize=jnp.dtype(self._cache_dtype).itemsize,
-                max_seq_len=self.max_seq_len,
-            )
-        return self._devtel_model
-
-    def devtel_cost(
-        self, kind: str, key: tuple, *, batch: int, steps: int,
-        kv_len: int | None, prefill_tokens: int = 0, lower_thunk=None,
-    ) -> devtel.KernelCost | None:
-        """Cost for one executable signature via the process cost table:
-        cache hit (the per-dispatch path — one dict get), else
-        ``lower_thunk().cost_analysis()`` (prewarm passes the thunk), else
-        the analytical model. ``key`` must be identical between the
-        prewarm derivation and the fold-site lookup."""
-        from llmss_tpu.utils.signatures import signature
-
-        full_key = signature(kind, *key)
-        hit = devtel.costs().get(full_key)
-        if hit is not None:
-            # The per-dispatch path: never price the analytical model on
-            # a hit — step_cost alone busts the 2 us/group budget
-            # (DEVTEL_BENCH.json).
-            return hit
-        m = self.devtel_cost_model()
-        return devtel.costs().derive(
-            full_key, lower_thunk,
-            fallback=m.step_cost(batch, steps, kv_len, prefill_tokens),
-        )
-
     def prewarm(
         self, batch: int, *, chunk_steps: tuple[int, ...] | int = (),
         buckets: bool = True, prefix_prefill: bool = False,
@@ -811,8 +734,7 @@ class DecodeEngine:
         if isinstance(chunk_steps, int):
             chunk_steps = (chunk_steps,)
         sa = self._sample_args(GenerationParams(), batch)
-        dt = devtel.enabled()
-        if dt:
+        if devtel.enabled():
             devtel.install_monitoring_hook()
             devtel.observer().watch_obj(self)
         n = 0
@@ -820,17 +742,6 @@ class DecodeEngine:
             cache = self.new_cache(batch)
             ids = jnp.zeros((batch, S), jnp.int32)
             lens = jnp.ones(batch, jnp.int32)
-            if dt:
-                # Derive roofline cost BEFORE the executing call: lower()
-                # only traces (nothing is donated), but after execution
-                # the donated cache buffer is gone.
-                self.devtel_cost(
-                    "prefill", (batch, S), batch=batch, steps=1, kv_len=S,
-                    prefill_tokens=batch * (S - 1),
-                    lower_thunk=lambda: self._prefill.lower(
-                        self.params, ids, cache, lens, sa
-                    ),
-                )
             tok, _, cache = self._prefill(self.params, ids, cache, lens, sa)
             del cache
             n += 1
@@ -847,13 +758,6 @@ class DecodeEngine:
         cache = self.canon_cache(self.new_cache(batch))
         cur = self.canon_vec(jnp.ones(batch, jnp.int32))
         for tb in bucket_set:
-            if dt:
-                self.devtel_cost(
-                    "decode", (batch, tb), batch=batch, steps=1, kv_len=tb,
-                    lower_thunk=lambda: self._decode.lower(
-                        self.params, tok, cache, cur, sa, t_bucket=tb
-                    ),
-                )
             _, _, c2 = self._decode(
                 self.params, tok, cache, cur, sa, t_bucket=tb
             )
@@ -868,15 +772,6 @@ class DecodeEngine:
                 # generate()'s chunked branch runs the grouped program at
                 # n_chunks=1 — token/position carries are donated, so
                 # rebind them from the outputs before the next compile.
-                if dt:
-                    self.devtel_cost(
-                        "decode_group", (batch, 1, k, tb),
-                        batch=batch, steps=k, kv_len=tb,
-                        lower_thunk=lambda: self._decode_group.lower(
-                            self.params, tok, cache, cur, sa, done, eos,
-                            n_chunks=1, n_steps=k, t_bucket=tb,
-                        ),
-                    )
                 _, t2, c2, cur2, _ = self._decode_group(
                     self.params, tok, cache, cur, sa, done, eos,
                     n_chunks=1, n_steps=k, t_bucket=tb,
@@ -1245,17 +1140,6 @@ class DecodeEngine:
                 poisoned_np = flat[B * k:].astype(bool)
                 t1 = time.perf_counter()
                 self.metrics.decode_step.record((t1 - t0) / k)
-                if devtel.enabled():
-                    # Dispatch→fetch covers the whole fused group, so the
-                    # fold prices the full k-step executable (cache hit
-                    # after prewarm; analytical for cold signatures).
-                    devtel.fold(
-                        "decode_group", t1 - t0,
-                        self.devtel_cost(
-                            "decode_group", (B, 1, k, tb),
-                            batch=B, steps=k, kv_len=tb,
-                        ),
-                    )
                 t_cb = time.perf_counter()
                 for col in range(k):
                     if process(chunk_np[:, col]):

@@ -129,11 +129,8 @@ class _InFlightGroup:
     # _InFlightAdmission). Rows absent from the map either finished
     # streaming earlier or are still mid-prompt (skip their chunks).
     prefill_firsts: dict | None = None
-    # Devtel roofline tagging, attached at dispatch (a cost-table dict
-    # get) so _process_group can fold the measured fetch-to-fetch
-    # interval into achieved MFU/MBU without recomputing the key.
+    # Which program the group ran: the ``sched.dispatch`` span carries it.
     kind: str = "decode_group"
-    cost: object = None  # devtel.KernelCost | None
     # The batcher's running group number (from 0): on the loop track the
     # group's ``sched.dispatch``, ``sched.fetch_wait`` and ``sched.callback``
     # spans carry it, one iteration apart.
@@ -616,7 +613,7 @@ class ContinuousBatcher:
         blocks free; shared prefix blocks decref). The device-side table
         stays stale until the next admission uploads tables — safe because
         done rows' KV writes are slot-suppressed on device
-        (DecodeEngine._decode_many_impl) and nobody reads a freed row.
+        (DecodeEngine._decode_step_body) and nobody reads a freed row.
 
         Returns the row's block-seconds (blocks held x hold duration) for
         per-request cost attribution; the cumulative also lands on the
@@ -679,8 +676,7 @@ class ContinuousBatcher:
         eng = self.engine
         if seq_buckets is None:
             seq_buckets = eng.seq_buckets()
-        dt = devtel.enabled()
-        if dt:
+        if devtel.enabled():
             devtel.install_monitoring_hook()
             # Watch both jit namespaces: the engine's grouped/ragged
             # programs AND the scheduler's own insert/prefill-row jits.
@@ -788,21 +784,6 @@ class ContinuousBatcher:
         })
         for nc, k in combos:
             for tb in eng.prewarm_bucket_set():
-                if dt:
-                    # Roofline cost from the unoptimized HLO, derived
-                    # BEFORE the executing call (lower() only traces;
-                    # execution deletes the donated carries).
-                    eng.devtel_cost(
-                        "decode_group", (self.rows, nc, k, tb),
-                        batch=self.rows, steps=nc * k, kv_len=tb,
-                        lower_thunk=lambda: eng._decode_group.lower(
-                            eng.params, self._tokens_dev, self.cache,
-                            self._cur_pos_dev, sa,
-                            jnp.ones(self.rows, bool),
-                            jnp.full(self.rows, -1, np.int32),
-                            n_chunks=nc, n_steps=k, t_bucket=tb,
-                        ),
-                    )
                 _, last_tok, cache, cur_pos, _ = eng._decode_group(
                     eng.params, self._tokens_dev, self.cache,
                     self._cur_pos_dev, sa,
@@ -823,25 +804,6 @@ class ContinuousBatcher:
             for nc in sorted({
                 self.group_chunks * self.chunk_steps, self.chunk_steps_low,
             }):
-                if dt:
-                    # The padded ragged executable computes every chunk
-                    # slot regardless of masks, so its cost includes the
-                    # full nc·rows·CB prefill budget.
-                    eng.devtel_cost(
-                        "ragged_group", (self.rows, nc, CB),
-                        batch=self.rows, steps=nc, kv_len=None,
-                        prefill_tokens=nc * self.rows * CB,
-                        lower_thunk=lambda: eng._ragged_group.lower(
-                            eng.params, self._tokens_dev, self.cache,
-                            self._cur_pos_dev, sa,
-                            jnp.ones(self.rows, bool),
-                            jnp.full(self.rows, -1, np.int32),
-                            jnp.zeros((nc, self.rows, CB), jnp.int32),
-                            jnp.ones((nc, self.rows), jnp.int32),
-                            jnp.zeros((nc, self.rows), bool),
-                            jnp.ones((nc, self.rows), bool),
-                        ),
-                    )
                 _, last_tok, cache, cur_pos, _ = eng._ragged_group(
                     eng.params, self._tokens_dev, self.cache,
                     self._cur_pos_dev, sa,
@@ -876,7 +838,7 @@ class ContinuousBatcher:
         # guard).
         jax.block_until_ready(self.cache.positions)
         _ = int(jnp.zeros((), jnp.int32) + 1)
-        if dt:
+        if devtel.enabled():
             # Every serving-path executable is compiled: from here on any
             # compile is a steady-state recompile — counted by the
             # observer and flagged on /slo.
@@ -949,7 +911,7 @@ class ContinuousBatcher:
             if P + _bucket(
                 len(token_ids) - P, self.engine.max_seq_len
             ) > self.engine.max_seq_len:
-                # Ring-wrap guard (ADVICE.md high): even this request's
+                # Ring-wrap guard: even this request's
                 # own BUCKET-padded suffix would reach past the ring and
                 # wrap over the seeded prefix slots — admit it without the
                 # prefix (from-scratch prefill, identical tokens). Dropping
@@ -1025,7 +987,7 @@ class ContinuousBatcher:
         ``sched.admit`` span ``sp``."""
         n = len(taken)
         if head_prefix is not None:
-            # Ring-wrap guard (ADVICE.md high): the suffix prefill pads to
+            # Ring-wrap guard: the suffix prefill pads to
             # the BATCH's bucket, and padded columns still compute slots
             # (slot = position % max_len) — a prefix start + bucket past
             # the ring would wrap those writes over the seeded prefix
@@ -1812,14 +1774,6 @@ class ContinuousBatcher:
             self.engine.metrics.decode_step.record(
                 (now - self._last_fetch_t) / (nc * k)
             )
-        if self._last_fetch_t is not None and group.cost is not None:
-            # Roofline fold: the same fetch-to-fetch interval against the
-            # executable's derived cost. Unlike decode_step, admission
-            # groups fold too (ragged groups ARE the admission path) —
-            # the included prefill/insert work slightly under-reports
-            # utilization for those samples, a documented caveat
-            # (docs/observability.md).
-            devtel.fold(group.kind, now - self._last_fetch_t, group.cost)
         self._last_fetch_t = now
 
         n = 0
@@ -2038,12 +1992,6 @@ class ContinuousBatcher:
                     packed=packed, n_chunks=nc, k=k, has_admission=True,
                     prefill_firsts=firsts,
                     kind="ragged_group",
-                    cost=self.engine.devtel_cost(
-                        "ragged_group",
-                        (self.rows, nc, self.chunked_prefill),
-                        batch=self.rows, steps=nc, kv_len=None,
-                        prefill_tokens=nc * self.rows * self.chunked_prefill,
-                    ) if devtel.enabled() else None,
                     no=self._step_count,
                 )
             else:
@@ -2064,10 +2012,6 @@ class ContinuousBatcher:
                 group = _InFlightGroup(
                     packed=packed, n_chunks=nc, k=k,
                     has_admission=self._pending_adm is not None,
-                    cost=self.engine.devtel_cost(
-                        "decode_group", (self.rows, nc, k, t_bucket),
-                        batch=self.rows, steps=nc * k, kv_len=t_bucket,
-                    ) if devtel.enabled() else None,
                     no=self._step_count,
                 )
                 sp.set(t_bucket=t_bucket)
@@ -2153,12 +2097,6 @@ class ContinuousBatcher:
             tracks["kv_fragmentation"] = {
                 "largest_free_run": alloc.largest_free_run(), "free": free,
             }
-        util = devtel.last_util()
-        if util:
-            # The roofline gauges ride the counter tracks too, so the
-            # Perfetto timeline shows achieved MFU/MBU next to the spans.
-            tracks["mfu"] = {k: g["mfu"] for k, g in util.items()}
-            tracks["mbu"] = {k: g["mbu"] for k, g in util.items()}
         mem = devtel.device_memory_stats()
         if mem is not None:
             tracks["device_memory"] = mem

@@ -20,10 +20,10 @@ inside ONE jitted program of ``m`` scanned speculative steps per group
 (``spec_group_impl``), with the group's choices/emits/state packed into
 a single flat array inside the jit — one dispatch and one device→host
 fetch per group, exactly the grouped-decode discipline of
-``DecodeEngine._decode_group`` (a host-side draft loop was measured 10x
-SLOWER through a ~100 ms-RTT host link: one round-trip per ~3.5
-tokens; chained per-step dispatch still paid ~10 ms of host exec
-overhead per verify — SPEC_BENCH.json's 0.82x wall-clock).
+``DecodeEngine._decode_group`` (a host-side draft loop pays one host
+round-trip per accepted run of tokens; chained per-step dispatch pays
+the host's dispatch overhead per verify). No time of it on the current
+machine is on record (ROADMAP D4).
 
 Exactness scope: verification is exact *under the verify forward's own
 numerics*. When the S=gamma+1 forward and the S=1 decode step lower to
@@ -31,8 +31,7 @@ the same kernels (the CPU test mesh), output is token-identical to plain
 ``generate`` — asserted in tests/test_speculative.py. On TPU the two
 paths use different attention kernels whose fp32 logits can resolve an
 argmax tie differently, so the two valid greedy decodes may diverge at a
-tie; ``tools/bench_spec.py`` reports the agreement span instead of
-asserting identity.
+tie.
 
 TPU design notes:
 - ``gamma`` and the chunk length are static; drafts are data. Rows with
@@ -458,7 +457,7 @@ def generate_speculative(
         engine.metrics.host_callback.record(time.perf_counter() - t_cb)
 
     # Ring-constrained tail (a full speculative window no longer fits):
-    # plain CHUNKED decode via _decode_many — including past the ring
+    # plain CHUNKED decode via _decode_group — including past the ring
     # boundary, where generate()'s sliding-window wrap semantics apply
     # identically (each row is bounded by max_new_tokens).
     if not done_np.all():
@@ -473,18 +472,20 @@ def generate_speculative(
         eos_dev = engine.canon_vec(jnp.full(B, eos_val, jnp.int32))
         k = 16
         while not done_np.all():
-            toks, cache, cur, _, _ = engine._decode_many(
+            # The group donates the token and position carries.
+            packed, tok_cur, cache, cur, _ = engine._decode_group(
                 engine.params, tok_cur, cache, cur, sa,
                 engine.canon_vec(jnp.asarray(done_np)), eos_dev,
-                n_steps=k, t_bucket=engine.decode_bucket(pos_hi + k),
+                n_chunks=1, n_steps=k,
+                t_bucket=engine.decode_bucket(pos_hi + k),
             )
             cache = engine.canon_cache(cache)
             cur = engine.canon_vec(cur)
-            tok_cur = engine.canon_vec(toks[:, -1])
+            tok_cur = engine.canon_vec(tok_cur)
             pos_hi += k
             # One fetch per k-step tail chunk (same amortization as
             # engine.generate's chunked decode loop).
-            t_np = np.asarray(toks)  # lint: ignore[host-sync-in-loop]
+            t_np = np.asarray(packed)[: B * k].reshape(B, k)  # lint: ignore[host-sync-in-loop]
             for col in range(k):
                 for r in range(B):
                     if not done_np[r]:

@@ -1,10 +1,10 @@
 """Model zoo: pure-function decoders over parameter pytrees.
 
 TPU-native replacement for the reference's ``custom_modeling/`` (GPT-J,
-GPT-BigCode), extended with GPT-2 and Llama for the BASELINE.md config
-ladder, Mistral (sliding-window attention), Qwen2 (split q/kv vs out
-bias granularity), and GPT-NeoX/Pythia (fused head-interleaved QKV,
-partial rotary, NeoX parallel residual), Phi-3 (contiguous fused
+GPT-BigCode), extended with GPT-2 and Llama, Mistral (sliding-window
+attention), Qwen2 (split q/kv vs out bias granularity), and
+GPT-NeoX/Pythia (fused head-interleaved QKV, partial rotary, NeoX
+parallel residual), Phi-3 (contiguous fused
 qkv/gate_up splits via sliced reads), and Gemma ((1+w) RMSNorm, scaled
 embeddings, tied head).
 All models share one unified decoder (``decoder.py``) driven by a

@@ -402,7 +402,6 @@ def _block(
     # by the stacked-cache Pallas kernel (ignores the cache slices) or the
     # sp>1 fresh-KV LSE merge (uses them).
     attn_override=None,
-    ablate: str | None = None,  # profiling only (tools/profile_decode.py)
     sin_cos=None,  # precomputed rope tables, hoisted out of the layer scan
     penalty=None,  # precomputed decode mask penalty, hoisted likewise
     # int8 cache: per-token-per-head dequant scales [B, T, Hkv]; when set,
@@ -451,9 +450,7 @@ def _block(
             style=cfg.rope_style, sin_cos=sin_cos,
         )
 
-    if ablate == "no_attn":
-        attn = q  # passthrough: ablates the cache read + softmax einsums
-    elif defer_write:
+    if defer_write:
         if attn_override is not None:
             attn = attn_override(q, k, v, k_cache, v_cache)
         else:
@@ -705,7 +702,6 @@ def _embed_in(cfg: DecoderConfig, params: Params, input_ids, positions, mesh):
 
 def _head_out(
     cfg: DecoderConfig, params: Params, h, gather_idx, last_only,
-    _ablate=None,
 ):
     """Final norm + hidden-state gather + vocab head — the shared exit of
     the dense and paged forwards. Returns fp32 logits."""
@@ -716,8 +712,6 @@ def _head_out(
     elif last_only:
         h = h[:, -1:, :]
 
-    if _ablate == "no_head":
-        return h[..., :8].astype(jnp.float32)
     if cfg.tie_word_embeddings:
         # Tied head (gpt_bigcode_modeling.py:792-797): contract against the
         # vocab-sharded embedding; constraining the output replicated makes
@@ -747,7 +741,9 @@ def forward(
     kv_write_positions: jax.Array | None = None,  # [B, S]; -1 marks padding
     mesh=None,  # enables the Pallas attention path (shard_map needs a Mesh)
     t_bucket: int | None = None,  # static; decode reads only slots [0, t_bucket)
-    _ablate: str | None = None,  # profiling-only component removal
+    # "no_scatter" drops the deferred decode write (tests/test_ring.py's
+    # receipt that an sp>1 mesh takes the deferred path); dense ring only
+    _ablate: str | None = None,
 ) -> tuple[jax.Array, KVCache]:
     """Run the decoder; returns (logits fp32, updated cache).
 
@@ -777,7 +773,7 @@ def forward(
             cfg, params, input_ids, positions, cache, slots,
             last_only=last_only, gather_idx=gather_idx,
             kv_write_positions=kv_write_positions, mesh=mesh,
-            t_bucket=t_bucket, _ablate=_ablate,
+            t_bucket=t_bucket,
         )
 
     if cfg.ssm is not None:
@@ -940,7 +936,6 @@ def forward(
                     None, mesh=mesh, defer_write=True,
                     attn_override=sp_attn if sp_attn is not None
                     else win_attn,
-                    ablate=_ablate,
                     sin_cos=sin_cos, penalty=penalty,
                     k_scale=ks_l, v_scale=vs_l,
                 )
@@ -1023,7 +1018,7 @@ def forward(
                 body, h, (params["blocks"], cache.k, cache.v)
             )
 
-    logits = _head_out(cfg, params, h, gather_idx, last_only, _ablate)
+    logits = _head_out(cfg, params, h, gather_idx, last_only)
     return logits, KVCache(
         k=k_new, v=v_new, positions=new_kv_positions,
         k_scale=ks_new, v_scale=vs_new,
@@ -1115,7 +1110,6 @@ def _forward_paged(
     kv_write_positions: jax.Array | None = None,
     mesh=None,
     t_bucket: int | None = None,
-    _ablate: str | None = None,
 ) -> tuple[jax.Array, PagedKVCache]:
     """``forward`` over the paged block-pool cache (``kv_layout="paged"``).
 
@@ -1167,15 +1161,11 @@ def _forward_paged(
         Tv = (nb if nb is not None else MB) * bs
         kv_pos_src = cache.positions[:, :Tv]
 
-        kernel_attn = None
-        if _ablate is None:
-            occ = jnp.sum(
-                (cache.positions >= 0).astype(jnp.int32), axis=1
-            )
-            nblk = jnp.clip(-(-occ // bs), 0, MB).astype(jnp.int32)
-            kernel_attn = _make_paged_kernel_attn(
-                cfg, mesh, cache, positions, slots, nblk
-            )
+        occ = jnp.sum((cache.positions >= 0).astype(jnp.int32), axis=1)
+        nblk = jnp.clip(-(-occ // bs), 0, MB).astype(jnp.int32)
+        kernel_attn = _make_paged_kernel_attn(
+            cfg, mesh, cache, positions, slots, nblk
+        )
 
         if kernel_attn is not None:
             def body(h, xs, ssm_in):
@@ -1218,11 +1208,10 @@ def _forward_paged(
                 h, k_f, v_f, ssm_out = _block(
                     cfg, bp, h, positions, None, None, kv_pos_src, slots,
                     None, mesh=mesh, defer_write=True,
-                    attn_override=paged_attn, ablate=_ablate,
+                    attn_override=paged_attn,
                     sin_cos=sin_cos, ssm_in=ssm_in,
                 )
-                ys = None if _ablate == "no_scatter" else (k_f, v_f)
-                return h, ys, ssm_out
+                return h, (k_f, v_f), ssm_out
 
             if quant:
                 xs = (params["blocks"], cache.k, cache.v, cache.k_scale,
@@ -1232,25 +1221,22 @@ def _forward_paged(
             h, ys, state = _layer_scan(cfg, cache, lens, body, h, xs)
 
         ks_new, vs_new = cache.k_scale, cache.v_scale
-        if _ablate == "no_scatter":
-            k_new, v_new = cache.k, cache.v
-        else:
-            k_fresh, v_fresh = ys  # [L, B, 1, Hkv, D]
-            if quant:
-                k_fresh, ks_f = quantize_kv(k_fresh)
-                v_fresh, vs_f = quantize_kv(v_fresh)
-                ks_new = paged_write_stacked(
-                    cache.k_scale, ks_f, cache.block_tables, slots, bs
-                )
-                vs_new = paged_write_stacked(
-                    cache.v_scale, vs_f, cache.block_tables, slots, bs
-                )
-            k_new = paged_write_stacked(
-                cache.k, k_fresh, cache.block_tables, slots, bs
+        k_fresh, v_fresh = ys  # [L, B, 1, Hkv, D]
+        if quant:
+            k_fresh, ks_f = quantize_kv(k_fresh)
+            v_fresh, vs_f = quantize_kv(v_fresh)
+            ks_new = paged_write_stacked(
+                cache.k_scale, ks_f, cache.block_tables, slots, bs
             )
-            v_new = paged_write_stacked(
-                cache.v, v_fresh, cache.block_tables, slots, bs
+            vs_new = paged_write_stacked(
+                cache.v_scale, vs_f, cache.block_tables, slots, bs
             )
+        k_new = paged_write_stacked(
+            cache.k, k_fresh, cache.block_tables, slots, bs
+        )
+        v_new = paged_write_stacked(
+            cache.v, v_fresh, cache.block_tables, slots, bs
+        )
     else:
         kv_valid = new_kv_positions >= 0
         mask = make_causal_mask(positions, new_kv_positions, kv_valid)
@@ -1310,7 +1296,7 @@ def _forward_paged(
                 (params["blocks"], cache.k, cache.v),
             )
 
-    logits = _head_out(cfg, params, h, gather_idx, last_only, _ablate)
+    logits = _head_out(cfg, params, h, gather_idx, last_only)
     ssm_new, conv_new = state if state is not None else (None, None)
     return logits, PagedKVCache(
         k=k_new, v=v_new, block_tables=cache.block_tables,

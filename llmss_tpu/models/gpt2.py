@@ -1,7 +1,6 @@
 """GPT-2: MHA, learned positions, Conv1D checkpoints, tied head.
 
-Not in the reference's registry but first on the BASELINE.md config ladder
-(GPT-2 125M TP=1 / 1.3B TP=2). Structurally GPT-BigCode minus MQA, with HF
+Not in the reference's registry. Structurally GPT-BigCode minus MQA, with HF
 Conv1D weight layout — already [in, out], so no transpose on load — and a
 fused ``c_attn`` of 3×E split by sub-range reads.
 """

@@ -1,7 +1,6 @@
 """Llama family: GQA, rotary (half style), RMSNorm, SwiGLU.
 
-Not in the reference's registry; required by the BASELINE.md north-star
-configs (Llama-2-7B TP=8). Covers Llama 1/2/3-style checkpoints (GQA via
+Not in the reference's registry. Covers Llama 1/2/3-style checkpoints (GQA via
 ``num_key_value_heads``; ``rope_theta``; optional tied embeddings for the
 small Llama-3.2 variants).
 """

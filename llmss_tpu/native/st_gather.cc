@@ -4,7 +4,7 @@
 // TPU-native replacement for the I/O half of the reference's lazy sharded
 // loader (utils/weights.py:72-95 reads each rank's slice through the
 // safetensors Python binding, one GIL-bound call per tensor). Weight loading
-// is cold-start critical (BASELINE.md TTFT ladder), and a TP shard read is
+// is cold-start critical, and a TP shard read is
 // just a strided byte gather — so the data plane is plain C++: one pread(2)
 // per contiguous run, fanned out over a thread pool, no Python in the loop.
 //

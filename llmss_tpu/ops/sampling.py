@@ -66,8 +66,8 @@ def fold_step_outcome(
     non-finite — emit an EOS fill instead of a sampled token, poisoned
     rows are forced done (their later steps are fills the host discards),
     and a row sampling its EOS finishes. One definition for every fused
-    decode scan (``_decode_many``, the grouped decode, prewarm) so the
-    chunked and grouped paths share the carry semantics bit-for-bit.
+    decode scan (``_decode_group``, ``_ragged_group``) so the grouped
+    and ragged paths share the carry semantics bit-for-bit.
 
     Returns the updated ``(tok, done, poisoned)``.
     """

@@ -172,8 +172,8 @@ class Worker:
                 {"series": metrics_mod.series().export(cache_s=1.0)}
                 if trace.enabled() else {}
             ),
-            # Device telemetry (roofline gauges, compile forensics,
-            # counter tracks) rides the same heartbeat.
+            # Device telemetry (compile forensics, counter tracks) rides
+            # the same heartbeat.
             **({"devtel": devtel.export()} if devtel.enabled() else {}),
         }
 
@@ -411,7 +411,7 @@ class Worker:
 
 class ContinuousWorker:
     """Serving loop over the continuous batcher: requests are admitted into
-    the running batch at token granularity (BASELINE.md config #5).
+    the running batch at token granularity.
 
     ``role`` selects this replica's half of the disaggregated
     prefill/decode split (docs/serving.md):

@@ -5,12 +5,15 @@ The brownout ladder (serve/fleet.py) *sheds* load when interactive TTFT
 burns hot; surviving a diurnal trace also needs the other half — *adding
 capacity before shedding*. ``FleetController`` reads the telemetry the
 stack already measures (per-class burn rates and windowed queue depths
-from the SLO plane, per-kernel-class MFU/MBU from devtel) and acts
-through existing machinery: spawn replicas (cold-start modeled), retire
-them via the drain lifecycle, and rebalance the prefill:decode ratio of
-a disaggregated fleet from phase utilization (prefill saturates FLOPs —
-MFU — while decode saturates HBM bandwidth — MBU; the asymmetry that
-motivates P:D ratio tuning).
+from the SLO plane, and a per-role utilization the caller supplies) and
+acts through existing machinery: spawn replicas (cold-start modeled),
+retire them via the drain lifecycle, and rebalance the prefill:decode
+ratio of a disaggregated fleet from phase utilization (prefill saturates
+FLOPs while decode saturates HBM bandwidth; the asymmetry that motivates
+P:D ratio tuning). Phase utilization has NO live source:
+``producer_telemetry`` hands the controller ``util: {}``, so in a live
+fleet the reshape never fires and ``util_high`` / ``util_low`` decide
+nothing; only tests and the simulator (sim/scenario.py) inject one.
 
 Robustness is the design center, not a bolt-on:
 
@@ -96,7 +99,9 @@ class FleetController:
          "util": {"unified": u, "prefill": u, "decode": u}}
 
     ``None``, a missing field, or a stale ``ts`` means the telemetry
-    plane is down or partitioned — the controller holds position.
+    plane is down or partitioned — the controller holds position. An
+    empty ``util`` (all a live producer supplies) turns the
+    phase-utilization reshape off.
     """
 
     def __init__(
@@ -552,9 +557,10 @@ class FleetController:
 def producer_telemetry(server) -> Callable[[], dict | None]:
     """Build a ``read_telemetry`` callable over a live ProducerServer:
     burn from the SLO plane's interactive windows, backlog from the
-    broker, phase utilization from devtel's MFU/MBU gauges (prefill is
-    MFU-bound, decode MBU-bound). Returns None on any telemetry error so
-    the controller holds position instead of acting on garbage."""
+    broker. ``util`` is ``{}``: a live producer has no source of phase
+    utilization (tests and the simulator inject one). Returns None on any
+    telemetry error so the controller holds position instead of acting
+    on garbage."""
     from llmss_tpu.serve.fleet import interactive_burn
 
     def read() -> dict | None:
@@ -566,19 +572,12 @@ def producer_telemetry(server) -> Callable[[], dict | None]:
             handoff += sum(
                 getattr(broker, "handoff_depths", dict)().values()
             )
-            util: dict[str, float] = {}
-            try:
-                from llmss_tpu.utils.devtel import phase_utilization
-
-                util = phase_utilization()
-            except Exception:  # devtel plane optional
-                util = {}
             return {
                 "ts": time.monotonic(),
                 "burn": interactive_burn(server.slo()),
                 "queue_depth": depth,
                 "handoff_depth": handoff,
-                "util": util,
+                "util": {},
             }
         except Exception:
             return None

@@ -2,8 +2,7 @@
 
 ≙ reference ``producer_server.py`` (FastAPI + uvicorn): one route,
 ``POST /generate``, same JSON schema. Implemented on the stdlib threading
-HTTP server so the serving path has zero non-baked dependencies; a FastAPI
-app factory is provided for deployments that have it installed. Unlike the
+HTTP server so the serving path has zero non-baked dependencies. Unlike the
 reference — which busy-polls the shared response queue and can return another
 caller's response (``producer_server.py:50-54``) — each handler waits on its
 own request id.
@@ -210,7 +209,7 @@ def trace_timeline_response(
     """GET /trace/{req_id}: the stitched fleet-wide timeline (404 when no
     process recorded the id). ``fmt == "chrome"`` returns Chrome
     trace-event JSON loadable in Perfetto instead — with the fleet's
-    devtel counter tracks (KV occupancy, queue depth, MFU/MBU, memory)
+    devtel counter tracks (KV occupancy, queue depth, memory)
     alongside the request's spans, so the timeline shows *why* it waited."""
     exports = collect_trace_exports(broker)
     if fmt == "chrome":
@@ -469,9 +468,6 @@ class ProducerServer:
                                 payload,
                                 series=metrics_mod.cumulative_summary(
                                     exports,
-                                ),
-                                util=devtel.merged_gauges(
-                                    collect_devtel_exports(outer.broker),
                                 ),
                             ),
                             _PROM_CONTENT_TYPE,
@@ -756,13 +752,9 @@ class ProducerServer:
             payload["fleet"] = fleet
         dt = collect_devtel_exports(self.broker)
         if dt:
-            # Device telemetry gauges: only present when the plane is on
-            # somewhere in the fleet — the pre-devtel payload stays
-            # byte-identical otherwise.
-            payload["devtel"] = {
-                **devtel.merged_gauges(dt),
-                "compiles": devtel.recompile_flag(dt),
-            }
+            # Only present when the plane is on somewhere in the fleet —
+            # the pre-devtel payload stays byte-identical otherwise.
+            payload["devtel"] = {"compiles": devtel.recompile_flag(dt)}
         return payload
 
     def trace_slowest(
@@ -889,313 +881,6 @@ class ProducerServer:
 
     def serve_forever(self) -> None:
         self._server.serve_forever()
-
-
-def create_fastapi_app(broker: Broker, timeout_s: float = 300.0,
-                       max_queue_depth: int = 1024, router=None,
-                       slo_objectives=None, brownout=None,
-                       controller=None):
-    """FastAPI variant of the producer (optional dependency, gated).
-
-    Full API parity with ``ProducerServer``: POST /generate (JSON or SSE
-    streaming via ``stream: true``, same event format, 429 + Retry-After
-    admission control, lifecycle-aware 503 shedding, deadline stamping,
-    policy routing when a ``router`` is given), POST /cancel,
-    POST /profile, GET /metrics (?format=prometheus), GET /health
-    (fleet-aggregate when a worker registry is populated), GET /fleet,
-    GET /fleet/timeseries, GET /slo, GET /dlq, GET /trace/{req_id}
-    (?format=chrome), GET /trace/slowest (?phase=), and
-    GET /trace/export_workload."""
-    import time as _time
-
-    from fastapi import FastAPI, HTTPException
-    from fastapi.responses import (
-        JSONResponse,
-        PlainTextResponse,
-        StreamingResponse,
-    )
-
-    app = FastAPI()
-    hstate = {"saw_supervisor": False, "memo": None, "memo_until": 0.0}
-    if brownout is None:
-        from llmss_tpu.serve.fleet import (
-            BrownoutController, interactive_burn,
-        )
-
-        def _burn() -> float:
-            exports, _src = collect_series_exports(broker)
-            return interactive_burn(
-                metrics_mod.evaluate_slos(exports, slo_objectives),
-            )
-
-        brownout = BrownoutController(_burn)
-
-    drain_estimator = QueueDrainEstimator()
-
-    def _submit(req: GenerateRequest) -> None:
-        if router is not None:
-            router.submit(req)
-        else:
-            broker.push_request(req)
-        drain_estimator.note_admitted(broker.queue_depth())
-
-    def _worker_unavailable() -> str | None:
-        now = _time.monotonic()
-        if now < hstate["memo_until"]:
-            return hstate["memo"]
-        workers = broker.read_workers()
-        if workers:
-            code, _body = evaluate_fleet_health(
-                workers, ProducerServer.HEARTBEAT_STALE_FACTOR,
-            )
-            hstate["memo"] = (
-                None if code == 200 else "unavailable (no ready replica)"
-            )
-        else:
-            sup = broker.read_metrics().get("supervisor")
-            state = sup.get("state") if isinstance(sup, dict) else None
-            hstate["memo"] = (
-                state if state in (STATE_DRAINING, STATE_DEAD) else None
-            )
-        hstate["memo_until"] = now + ProducerServer.STATE_MEMO_S
-        return hstate["memo"]
-
-    def _sse(req: GenerateRequest):
-        """SSE generator matching ProducerServer._stream_response: one
-        ``data:`` event per token increment, then a ``done`` event with
-        the terminal response. Client disconnect (GeneratorExit) cancels
-        the request so the worker stops spending decode steps on it."""
-        deadline = _time.monotonic() + timeout_s
-        wrote_first = False
-
-        def wrote():
-            # Resumed after a yield: the server has written that token
-            # event; the first one ends the first-token path
-            # (see _stream_response).
-            nonlocal wrote_first
-            if not wrote_first:
-                wrote_first = True
-                trace.record(req.id, "first_write")
-
-        try:
-            while _time.monotonic() < deadline:
-                inc = broker.pop_stream(req.id, timeout=0.1)
-                if inc is not None:
-                    yield (
-                        "data: " + json.dumps({"token_ids": inc}) + "\n\n"
-                    )
-                    wrote()
-                    continue
-                resp = broker.wait_response(req.id, timeout=_DONE_CHECK_S)
-                if resp is not None:
-                    while True:  # drain increments that raced the response
-                        inc = broker.pop_stream(req.id)
-                        if inc is None:
-                            break
-                        yield (
-                            "data: " + json.dumps({"token_ids": inc})
-                            + "\n\n"
-                        )
-                        wrote()
-                    yield "event: done\ndata: " + resp.to_json() + "\n\n"
-                    return
-            broker.cancel_request(req.id)
-            yield 'event: error\ndata: {"error": "timed out"}\n\n'
-        except GeneratorExit:
-            broker.cancel_request(req.id)
-            raise
-        finally:
-            broker.drop_stream(req.id)
-
-    @app.post("/generate")
-    def generate(payload: dict):
-        req = GenerateRequest.from_json(json.dumps(payload))
-        try:
-            req.validate()
-        except ValueError as e:
-            raise HTTPException(400, str(e)) from e
-        trace.ensure_context(req)
-        state = _worker_unavailable()
-        if state is not None:
-            trace.record(
-                req.id, "reject", trace_id=req.trace_id,
-                reason=f"worker {state}",
-            )
-            return JSONResponse(
-                status_code=503,
-                content={"error": f"worker {state}", "id": req.id},
-                headers={"Retry-After": "1"},
-            )
-        brownout.tick()
-        verdict = admission_verdict(
-            req, broker, max_queue_depth, brownout,
-            drain=drain_estimator,
-        )
-        if verdict is not None:
-            code, content, headers = verdict
-            trace.record(
-                req.id, "reject", trace_id=req.trace_id,
-                reason=content.get("error", "shed"),
-                slo_class=req.slo_class,
-            )
-            return JSONResponse(
-                status_code=code, content=content, headers=headers,
-            )
-        if req.deadline_ts is None:
-            req.deadline_ts = _time.time() + timeout_s
-        trace.record(
-            req.id, "accept", trace_id=req.trace_id,
-            timeout_s=timeout_s, stream=req.stream,
-        )
-        _submit(req)
-        if req.stream:
-            return StreamingResponse(
-                _sse(req), media_type="text/event-stream",
-                headers={"Cache-Control": "no-cache"},
-            )
-        resp = broker.wait_response(req.id, timeout_s)
-        if resp is None:
-            broker.cancel_request(req.id)
-            raise HTTPException(504, "timed out")
-        if resp.error:
-            raise HTTPException(500, resp.error)
-        return json.loads(resp.to_json())
-
-    @app.post("/cancel")
-    def cancel(payload: dict):
-        rid = payload.get("id")
-        if not rid:
-            raise HTTPException(400, "missing id")
-        broker.cancel_request(rid)
-        return {"cancelled": rid}
-
-    @app.get("/metrics")
-    def metrics(format: str | None = None):
-        payload = {
-            **broker.read_metrics(),
-            "delivery": broker.delivery_stats(),
-            "queue_depths_by_class": broker.queue_depths_by_class(),
-            "brownout": brownout.state(),
-        }
-        workers = broker.read_workers()
-        if workers or router is not None:
-            keys = (
-                "role", "state", "inflight_rows", "queue_depth",
-                "free_kv_blocks", "free_slots", "kv_blocks_total",
-            )
-            fleet: dict = {
-                "workers": {
-                    wid: {k: info.get(k) for k in keys}
-                    for wid, info in sorted(workers.items())
-                },
-                "routed_depths": broker.routed_depths(),
-                "handoff_depth": broker.handoff_depth(),
-                "handoff_depths": broker.handoff_depths(),
-            }
-            if router is not None:
-                fleet["router"] = router.stats()
-            from llmss_tpu.serve.fleet import aggregate_kv_tiers
-
-            tiers = aggregate_kv_tiers(
-                info.get("kv_tiers") for info in workers.values()
-            )
-            if tiers:
-                fleet["kv_tiers"] = tiers
-            payload["fleet"] = fleet
-        dt = collect_devtel_exports(broker)
-        if dt:
-            payload["devtel"] = {
-                **devtel.merged_gauges(dt),
-                "compiles": devtel.recompile_flag(dt),
-            }
-        if format == "prometheus":
-            exports, _src = collect_series_exports(broker)
-            return PlainTextResponse(
-                render_prometheus(
-                    payload,
-                    series=metrics_mod.cumulative_summary(exports),
-                    util=devtel.merged_gauges(dt),
-                ),
-                media_type=_PROM_CONTENT_TYPE,
-            )
-        return payload
-
-    @app.get("/slo")
-    def slo():
-        exports, _src = collect_series_exports(broker)
-        out = metrics_mod.evaluate_slos(exports, slo_objectives)
-        dt = collect_devtel_exports(broker)
-        if dt:
-            out["compile"] = devtel.recompile_flag(dt)
-        return out
-
-    @app.get("/compiles")
-    def compiles():
-        return devtel.compiles_payload(collect_devtel_exports(broker))
-
-    @app.get("/fleet/timeseries")
-    def fleet_timeseries():
-        exports, sources = collect_series_exports(broker)
-        return metrics_mod.timeseries_payload(exports, sources)
-
-    @app.get("/trace/slowest")
-    def trace_slowest(n: int = 10, phase: str | None = None):
-        return {"slowest": trace.slowest(
-            collect_trace_exports(broker), n=n, phase=phase or None,
-        )}
-
-    @app.get("/trace/export_workload")
-    def trace_export_workload():
-        return trace.export_workload(collect_trace_exports(broker))
-
-    @app.get("/trace/{req_id}")
-    def trace_req(req_id: str, format: str | None = None):
-        code, body = trace_timeline_response(broker, req_id, format or "")
-        return JSONResponse(status_code=code, content=body)
-
-    @app.post("/profile")
-    def profile(payload: dict | None = None):
-        payload = payload or {}
-        code, body = start_profile(
-            payload.get("log_dir"), payload.get("duration_s", 3.0),
-        )
-        return JSONResponse(status_code=code, content=body)
-
-    @app.get("/fleet")
-    def fleet():
-        from llmss_tpu.serve.fleet import fleet_status
-
-        out = fleet_status(
-            broker, router, ProducerServer.HEARTBEAT_STALE_FACTOR,
-        )
-        out["brownout"] = brownout.state()
-        if controller is not None:
-            out["controller"] = controller.state()
-        return out
-
-    @app.get("/dlq")
-    def dlq():
-        return {
-            "depth": broker.dlq_depth(),
-            "requests": broker.read_dlq(),
-        }
-
-    @app.get("/health")
-    def health():
-        workers = broker.read_workers()
-        if workers:
-            code, body = evaluate_fleet_health(
-                workers, ProducerServer.HEARTBEAT_STALE_FACTOR,
-            )
-            return JSONResponse(status_code=code, content=body)
-        sup = broker.read_metrics().get("supervisor")
-        code, body, hstate["saw_supervisor"] = evaluate_worker_health(
-            sup, hstate["saw_supervisor"],
-            ProducerServer.HEARTBEAT_STALE_FACTOR,
-        )
-        return JSONResponse(status_code=code, content=body)
-
-    return app
 
 
 def main(argv=None):

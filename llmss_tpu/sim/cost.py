@@ -1,17 +1,7 @@
 """Pluggable device cost model: virtual seconds for simulated work.
 
-Two seeding paths, both ending in the same five knobs:
-
-- **table**: explicit per-op seconds (``prefill_token_s``,
-  ``decode_step_s``, ...) — what the migrated bench tools use so their
-  receipts stay numerically comparable with their pre-sim runs.
-- **devtel**: derived from the device-telemetry roofline (PR 15) — peak
-  FLOPS / HBM bandwidth from :func:`devtel.device_peaks` (or a
-  CostTable entry priced by XLA's ``cost_analysis``) pushed through
-  :func:`devtel.roofline_seconds`, so sim time and real MFU/MBU
-  accounting share one model. Peaks resolve deterministically (env
-  overrides, else the device_kind table, whose "cpu" row serves CPU), which
-  keeps devtel-seeded scenarios byte-replayable.
+A table of explicit per-op seconds (``prefill_token_s``,
+``decode_step_s``, ...), set by the scenario file or the bench tool.
 
 KV block accounting lives here too (``kv_blocks``): replicas charge and
 release blocks through the invariant checker so the refcounts-balance-
@@ -21,15 +11,6 @@ at-drain invariant has one arithmetic to agree with.
 from __future__ import annotations
 
 import math
-from types import SimpleNamespace
-
-# Default analytical shapes for devtel seeding: a ~1.2B-param decoder
-# (the repo's flagship "1b2" dims) in bf16.
-_DEFAULT_DIMS = dict(
-    n_layers=22, n_heads=16, n_kv_heads=16, head_dim=128,
-    max_position_embeddings=4096,
-)
-_DEFAULT_PARAMS = 1_200_000_000
 
 
 class DeviceCostModel:
@@ -84,80 +65,11 @@ class DeviceCostModel:
     # -- seeding --------------------------------------------------------------
 
     @classmethod
-    def from_devtel(
-        cls,
-        *,
-        batch: int = 8,
-        kv_len: int = 1024,
-        param_count: int = _DEFAULT_PARAMS,
-        kv_itemsize: int = 2,
-        dims: dict | None = None,
-        table=None,
-        **overrides,
-    ) -> "DeviceCostModel":
-        """Seed per-op seconds from devtel's roofline.
-
-        When ``table`` (a :class:`devtel.CostTable`) holds a decode-class
-        entry priced from a real lowering, that entry's FLOPs/bytes win;
-        otherwise the analytical :class:`devtel.EngineCostModel` prices
-        the step. Either way the seconds come from
-        :func:`devtel.roofline_seconds` against ``device_peaks()``.
-        """
-        from llmss_tpu.utils import devtel
-
-        cfg = SimpleNamespace(**{**_DEFAULT_DIMS, **(dims or {})})
-        param_bytes = param_count * kv_itemsize
-        model = devtel.EngineCostModel(
-            cfg, param_count, param_bytes, kv_itemsize=kv_itemsize,
-        )
-        peak_flops, peak_bw = devtel.device_peaks()
-        source = "devtel:analytical"
-
-        flops = nbytes = None
-        if table is not None:
-            for key, cost in sorted(
-                table.export().items(), key=lambda kv: str(kv[0])
-            ):
-                kind = key[0] if isinstance(key, tuple) and key else key
-                if kind in ("decode", "decode_group"):
-                    flops, nbytes = cost["flops"], cost["hbm_bytes"]
-                    source = f"devtel:{cost.get('source', 'cost_analysis')}"
-                    break
-        if flops is None:
-            flops, nbytes = model.step_cost(batch, 1, kv_len)
-        decode_step_s = devtel.roofline_seconds(
-            flops, nbytes, peak_flops, peak_bw,
-        )
-
-        # Marginal prefill token: the same fused dispatch carrying ragged
-        # prompt chunks, minus the pure-decode baseline.
-        chunk = 256
-        f2, b2 = model.step_cost(batch, 1, kv_len, prefill_tokens=chunk)
-        f1, b1 = model.step_cost(batch, 1, kv_len)
-        prefill_token_s = max(
-            devtel.roofline_seconds(f2, b2, peak_flops, peak_bw)
-            - devtel.roofline_seconds(f1, b1, peak_flops, peak_bw),
-            1e-9,
-        ) / chunk
-
-        kw = dict(
-            prefill_token_s=prefill_token_s,
-            decode_step_s=decode_step_s,
-            kv_bytes_per_token=model.kv_bytes_per_token,
-            wire_gbps=peak_bw / 1e9,
-            seeded_from=source,
-        )
-        kw.update(overrides)
-        return cls(**kw)
-
-    @classmethod
     def from_config(cls, cfg: dict | None) -> "DeviceCostModel":
-        """Scenario-file entry point: ``{"kind": "table"|"devtel", ...}``
-        (remaining keys are constructor / from_devtel overrides)."""
+        """Scenario-file entry point: ``{"kind": "table", ...}`` (remaining
+        keys are constructor overrides)."""
         cfg = dict(cfg or {})
         kind = cfg.pop("kind", "table")
-        if kind == "devtel":
-            return cls.from_devtel(**cfg)
         if kind != "table":
             raise ValueError(f"unknown cost model kind {kind!r}")
         return cls(**cfg)

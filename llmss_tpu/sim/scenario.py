@@ -357,9 +357,8 @@ class FleetSim:
         """The controller's signal snapshot: interactive TTFT burn (the
         same sliding window the brownout ladder reads), total queue +
         handoff backlog, and per-role mean utilization from windowed
-        busy-seconds deltas (the sim's stand-in for devtel's MFU/MBU —
-        a saturated prefill replica is MFU-bound, a saturated decode
-        replica MBU-bound). Snapshots are memoized for a minimum window
+        busy-seconds deltas (a live producer has no such source:
+        ``controller.producer_telemetry``). Snapshots are memoized for a minimum window
         so repeated reads within one control interval see one coherent
         sample; a telemetry_stall fault freezes the last snapshot, whose
         aging ``ts`` is exactly what the controller's staleness gate

@@ -5,8 +5,7 @@ The reference's only measurement is a wall-clock print on rank 0
 Here TTFT and per-token latency are first-class: the engine records
 percentile stats for every phase, the serving stack exposes them over
 ``GET /metrics``, and ``profile_trace`` wraps ``jax.profiler`` for on-demand
-TPU traces (the BASELINE.md north-star is stated in exactly these units:
-tokens/sec/chip and p50 TTFT).
+TPU traces.
 """
 
 from __future__ import annotations
@@ -570,10 +569,6 @@ class SeriesRegistry:
         # resolved cost-ingestion sinks (observe_request_cost); rebuilt
         # lazily — a stale read just re-resolves, so no lock needed
         self._cost_sinks: tuple | None = None
-        # bumped on clear(); external sink caches (devtel's MFU/MBU
-        # histograms) compare against this so a cleared registry never
-        # keeps receiving folds into orphaned series objects
-        self._gen = 0  # guarded_by: self._lock
 
     def counter(self, name: str) -> WindowedCounter:
         with self._lock:
@@ -595,17 +590,11 @@ class SeriesRegistry:
         with self._lock:
             return sorted(self._series)
 
-    def generation(self) -> int:
-        """Monotone clear() counter for invalidating cached sink refs."""
-        with self._lock:
-            return self._gen
-
     def clear(self) -> None:
         with self._lock:
             self._series.clear()
             self._cache = None
             self._cache_t = float("-inf")
-            self._gen += 1
         self._cost_sinks = None
 
     def export(self, cache_s: float = 0.0) -> dict:
@@ -989,7 +978,6 @@ def _prom_label_value(v) -> str:
 
 def render_prometheus(
     payload: dict, prefix: str = "llmss", series: dict | None = None,
-    util: dict | None = None,
 ) -> str:
     """Render the ``GET /metrics`` JSON payload in Prometheus text
     exposition format (``?format=prometheus``).
@@ -1004,11 +992,6 @@ def render_prometheus(
     adds real cumulative histogram families — ``_bucket`` with ``le``
     labels plus ``_sum``/``_count`` — so Grafana/alerting can compute
     rates without scraping quantile gauges.
-
-    ``util`` (a ``devtel.merged_gauges`` dict: ``{"mfu": {kernel: v},
-    "mbu": ...}``) adds the roofline gauges ``<prefix>_mfu`` /
-    ``<prefix>_mbu`` labelled by kernel class — the label set is the
-    closed ``devtel.KERNEL_CLASSES`` enum, so cardinality is bounded.
     """
     samples: dict[str, list[tuple[dict | None, object]]] = {}
 
@@ -1055,10 +1038,6 @@ def render_prometheus(
             for wid, snap in workers.items():
                 if isinstance(snap, dict):
                     walk(snap, ["fleet", "worker"], {"worker": wid})
-
-    for fam in ("mfu", "mbu"):
-        for kernel, v in sorted(((util or {}).get(fam) or {}).items()):
-            emit(f"{prefix}_{fam}", v, {"kernel": kernel})
 
     lines: list[str] = []
     for name in samples:

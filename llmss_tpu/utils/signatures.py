@@ -1,28 +1,21 @@
-"""Executable-signature vocabulary shared by devtel and shardcheck.
+"""Executable-signature vocabulary of the static SPMD auditor.
 
 One closed enum of kernel classes and ONE formatting convention for
-executable signatures, so the runtime cost plane (``utils/devtel.py``'s
-``CostTable``) and the static SPMD auditor (``analysis/shardcheck.py``'s
-program registry and ``tools/comms_manifest.json``) can never drift: a
-signature priced at dispatch time and a signature audited at lint time
-render to the same ``kind/part/part`` string.
+executable signatures: ``analysis/shardcheck.py``'s program registry and
+``tools/comms_manifest.json`` name every program ``kind/part/part``.
 
-Pure stdlib on purpose — devtel imports this with tracing off and the
-AST-only lint CI job imports nothing heavier than this module.
+Pure stdlib on purpose — the AST-only lint CI job imports nothing
+heavier than this module.
 """
 
 from __future__ import annotations
 
-#: Every executable class either plane may key by. The first four are the
-#: model-forward classes devtel meters (MFU/MBU series names are
-#: ``mfu_<class>``/``mbu_<class>``); the rest are the state-management
-#: programs shardcheck audits (scatters and merges — roofline-metering
-#: them would be noise, but their sharding/donation/collective contracts
-#: are load-bearing).
+#: Every executable class the registry may key by: the model-forward
+#: programs, then the state-management ones (scatters and merges, whose
+#: sharding/donation/collective contracts are load-bearing).
 KERNEL_CLASSES = (
     "prefill",
     "decode",
-    "decode_many",
     "decode_group",
     "ragged_group",
     "spec_group",
@@ -31,16 +24,12 @@ KERNEL_CLASSES = (
     "import_blocks",
 )
 
-#: The subset devtel prices and exports MFU/MBU series for.
-METERED_CLASSES = ("prefill", "decode", "decode_group", "ragged_group")
-
-
 def signature(kind: str, *key) -> tuple:
     """The canonical executable signature: ``(kind, *shape-key parts)``.
 
     ``kind`` must come from :data:`KERNEL_CLASSES` — an unknown class is a
     programming error at the call site (a new executable family must be
-    added to the enum, where both planes see it), not a new dict key.
+    added to the enum), not a new dict key.
     """
     if kind not in KERNEL_CLASSES:
         raise ValueError(

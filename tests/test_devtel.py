@@ -1,11 +1,10 @@
-"""Device telemetry plane (utils/devtel.py): roofline cost accounting,
-compile forensics, counter tracks, and the observability wiring that
-rides along with it (/profile slot stealing, Prometheus label escaping).
+"""Device telemetry plane (utils/devtel.py): compile forensics, counter
+tracks, and the observability wiring that rides along with it (/profile
+slot stealing, Prometheus label escaping).
 
-CPU-backed like every tier-1 suite: MFU/MBU magnitudes are meaningless
-off-TPU (tiny model vs v5e peaks), but the CONTRACTS under test —
-(0, 1] bounds, cache-vs-fallback provenance, steady-state recompile
-flagging, Chrome counter-event schema — are platform-independent.
+CPU-backed like every tier-1 suite: the CONTRACTS under test —
+steady-state recompile flagging, Chrome counter-event schema — are
+platform-independent.
 """
 
 import time
@@ -29,15 +28,13 @@ from llmss_tpu.parallel import MeshPlan, make_mesh  # noqa: E402
 
 @pytest.fixture(autouse=True)
 def clean_devtel():
-    """Every test starts with tracing+devtel on and empty accumulators."""
+    """Every test starts with tracing on and empty accumulators."""
     trace.set_enabled(True)
     trace.recorder().clear()
-    devtel.set_enabled(True)
     devtel.reset()
     yield
     trace.set_enabled(True)
     trace.recorder().clear()
-    devtel.set_enabled(True)
     devtel.reset()
 
 
@@ -52,7 +49,9 @@ def _tiny_batcher():
     )
     mesh = make_mesh(MeshPlan(dp=2, tp=4))
     params = init_params(cfg, mesh, jax.random.key(0))
-    engine = DecodeEngine(cfg, params, mesh, max_seq_len=64)
+    engine = DecodeEngine(
+        cfg, params, mesh, max_seq_len=64, kv_layout="paged",
+    )
     batcher = ContinuousBatcher(engine, rows=2, chunk_steps=2, group_chunks=2)
     return engine, batcher
 
@@ -62,7 +61,6 @@ def warm(devices):
     """One prewarmed tiny engine+batcher for the whole module (prewarm is
     the expensive part; tests re-enable/reset devtel around it)."""
     trace.set_enabled(True)
-    devtel.set_enabled(True)
     devtel.reset()
     engine, batcher = _tiny_batcher()
     batcher.prewarm()
@@ -82,103 +80,13 @@ def _serve(batcher, n=2, max_new=4, prefix="dv"):
     return got
 
 
-# -- cost table ---------------------------------------------------------------
-
-
-class _FakeLowered:
-    """A ``jax.stages.Lowered``-shaped object with a countable
-    cost_analysis, so provenance and cache behavior are observable."""
-
-    def __init__(self):
-        self.calls = 0
-
-    def cost_analysis(self):
-        self.calls += 1
-        return {"flops": 1.0e9, "bytes accessed": 2.0e8}
-
-
-def test_cost_table_cache_hit_never_relowers():
-    table = devtel.CostTable()
-    lowered = _FakeLowered()
-    c1 = table.derive(("decode", 8, 64), lambda: lowered)
-    assert c1.source == "cost_analysis"
-    assert (c1.flops, c1.hbm_bytes) == (1.0e9, 2.0e8)
-    assert lowered.calls == 1
-    # Hit: the (trace-cost) thunk must not run again.
-    c2 = table.derive(("decode", 8, 64), lambda: lowered)
-    assert c2 is c1 and lowered.calls == 1
-
-
-def test_cost_table_analytical_fallback():
-    table = devtel.CostTable()
-
-    class _Empty:
-        def cost_analysis(self):
-            return {}  # backend returned nothing usable
-
-    c = table.derive(("decode", 4, 32), lambda: _Empty(), fallback=(3.0, 7.0))
-    assert c.source == "analytical" and (c.flops, c.hbm_bytes) == (3.0, 7.0)
-    assert table.derive(("nope",)) is None  # every source absent
-
-
-def test_real_lowering_prices_via_cost_analysis(devices):
-    # The real jax integration: lower() (trace-only, nothing executed)
-    # feeds cost_analysis() and the table records backend provenance.
-    @jax.jit
-    def g(x):
-        return x @ x
-
-    c = devtel.costs().derive(
-        ("unit", "g"), lambda: g.lower(jnp.ones((16, 16))),
-    )
-    assert c is not None and c.source == "cost_analysis"
-    assert c.flops > 0
-
-
-# -- MFU/MBU fold -------------------------------------------------------------
-
-
-def test_mfu_mbu_in_unit_interval_on_real_dispatch(warm):
-    # The cost table was reset after prewarm (fixture scoping), so the
-    # dispatch-site lookup prices these groups via the analytical model
-    # — the fallback path, exercised on a REAL grouped dispatch.
-    engine, batcher = warm
-    _serve(batcher, n=3, max_new=8, prefix="mfu")
-    util = devtel.last_util()
-    assert "decode_group" in util, f"no decode_group fold: {util}"
-    g = util["decode_group"]
-    # Roofline-achieved fractions: strictly positive (real work folded),
-    # clamped at 1.0 by contract. CPU magnitudes are ~1e-9 — the bound,
-    # not the magnitude, is the contract.
-    assert 0.0 < g["mfu"] <= 1.0
-    assert 0.0 < g["mbu"] <= 1.0
-    assert g["source"] in ("cost_analysis", "analytical")
-    # The windowed histograms got the same fold.
-    reg = metrics_mod.series()
-    assert "mfu_decode_group" in reg.names()
-    assert "mbu_decode_group" in reg.names()
-
-
-def test_fold_accumulator_drains_to_histograms():
-    cost = devtel.KernelCost(1.0e9, 2.0e8, "analytical")
-    for _ in range(5):
-        devtel.fold("decode_group", 0.004, cost)
-    util = devtel.last_util()  # reader forces the drain
-    assert util["decode_group"]["dur_s"] == pytest.approx(0.004)
-    assert util["decode_group"]["mfu"] > 0.0
-
-
 # -- counter tracks -----------------------------------------------------------
 
 
 def test_counter_tracks_pass_chrome_schema(warm):
     engine, batcher = warm
+    batcher._devtel_last_t = float("-inf")  # defeat the sampler throttle
     _serve(batcher, n=3, max_new=8, prefix="ctr")
-    # One more serve with the sampler throttle defeated: by now MFU/MBU
-    # folds exist, so the sample deterministically carries those tracks
-    # alongside rows/queue depth.
-    batcher._devtel_last_t = float("-inf")
-    _serve(batcher, n=1, prefix="ctr2")
     # The scheduler's group-boundary sampler recorded counter samples;
     # they ride the same Chrome export as the spans.
     doc = trace.to_chrome_trace(
@@ -188,8 +96,8 @@ def test_counter_tracks_pass_chrome_schema(warm):
     assert {e["ph"] for e in evs} <= {"M", "X", "i", "C"}
     cs = [e for e in evs if e["ph"] == "C"]
     tracks = {e["name"] for e in cs}
-    assert len(tracks) >= 3, f"want >=3 counter tracks, got {tracks}"
-    assert {"rows", "queue_depth"} <= tracks
+    assert {"rows", "queue_depth", "kv_blocks", "kv_fragmentation"} <= tracks
+    assert not {"mfu", "mbu"} & tracks
     for e in cs:
         assert e["ts"] >= 0
         assert e["cat"] == "counter"
@@ -254,7 +162,6 @@ def test_trace_off_devtel_silent_zero_recompiles(warm):
     assert ex["counters"] == []
     assert ex["compiles"]["events"] == []
     assert ex["compiles"]["steady_recompiles"] == 0
-    assert ex["util"] == {}
 
 
 # -- Prometheus rendering -----------------------------------------------------
@@ -272,15 +179,6 @@ def test_prometheus_label_value_escaping():
     # into the sample line (it would truncate the scrape).
     assert '\\"1' in line and "\\\\evil" in line and "\\nid" in line
     assert line.endswith(" 3")
-
-
-def test_prometheus_util_gauges_closed_label_set():
-    text = metrics_mod.render_prometheus(
-        {"uptime_s": 1.0},
-        util={"mfu": {"decode_group": 0.5}, "mbu": {"decode_group": 0.25}},
-    )
-    assert 'llmss_mfu{kernel="decode_group"} 0.5' in text
-    assert 'llmss_mbu{kernel="decode_group"} 0.25' in text
 
 
 # -- /profile slot lifecycle --------------------------------------------------

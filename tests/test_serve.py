@@ -461,3 +461,33 @@ def test_streaming_from_batch_worker_is_incremental(serving):
     assert len(events) >= 2, events  # actually incremental, not one blob
     streamed = [t for inc in events for t in inc]
     assert streamed == done.token_ids == plain.token_ids
+
+
+@pytest.mark.parametrize("tracing", [True, False])
+def test_metrics_devtel_block_is_the_compile_flag(tracing):
+    """``GET /metrics`` of a live producer, both encodings: with tracing on
+    the ``devtel`` block is the steady-state recompile flag and nothing
+    else (the program prices no step: no ``mfu`` / ``mbu`` key or family);
+    with tracing off there is no ``devtel`` key at all."""
+    from llmss_tpu.utils import devtel, trace
+
+    was = trace.enabled()
+    trace.set_enabled(tracing)
+    devtel.reset()  # whatever this process compiled before is not the subject
+    server = ProducerServer(InProcBroker(), host="127.0.0.1", port=0)
+    server.start()
+    try:
+        url = f"http://127.0.0.1:{server.port}/metrics"
+        payload = httpx.get(url, timeout=10).json()
+        text = httpx.get(url + "?format=prometheus", timeout=10).text
+    finally:
+        server.stop()
+        trace.set_enabled(was)
+    if tracing:
+        assert set(payload["devtel"]) == {"compiles"}
+        assert payload["devtel"]["compiles"]["flagged"] is False
+        assert "llmss_devtel_compiles_steady_state_recompiles 0" in text
+    else:
+        assert "devtel" not in payload
+        assert "devtel" not in text
+    assert "mfu" not in text and "mbu" not in text

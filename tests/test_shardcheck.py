@@ -344,20 +344,13 @@ def test_baseline_accepts_existing_findings(env, tmp_path):
     assert (code, findings) == (0, [])
 
 
-# -- shared executable-signature vocabulary (devtel <-> shardcheck) ----------
-
-def test_devtel_and_shardcheck_share_signature_vocabulary():
-    from llmss_tpu.utils import devtel, signatures
-
-    assert devtel.KERNEL_CLASSES is signatures.METERED_CLASSES
-    assert set(signatures.METERED_CLASSES) <= set(signatures.KERNEL_CLASSES)
-    with pytest.raises(ValueError):
-        signatures.signature("warp_drive", 2)
-
+# -- the executable-signature vocabulary --------------------------------------
 
 def test_registry_names_are_signature_strs(env):
-    from llmss_tpu.utils.signatures import KERNEL_CLASSES
+    from llmss_tpu.utils.signatures import KERNEL_CLASSES, signature
 
+    with pytest.raises(ValueError):
+        signature("warp_drive", 2)
     progs = sc.registry()
     assert len(progs) == len({p.name for p in progs})
     for p in progs:

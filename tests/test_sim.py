@@ -230,17 +230,6 @@ def test_trace_workload_inline_rows():
 # -- cost model --------------------------------------------------------------
 
 
-def test_cost_model_devtel_seeding():
-    """Devtel seeding prices sim time from the same roofline as the
-    MFU/MBU accounting; on CPU the peaks resolve deterministically."""
-    m = DeviceCostModel.from_config({"kind": "devtel"})
-    assert m.seeded_from.startswith("devtel")
-    assert m.decode_step_s > 0 and m.prefill_token_s > 0
-    # Seeding is deterministic, so devtel-seeded scenarios replay too.
-    m2 = DeviceCostModel.from_config({"kind": "devtel"})
-    assert m.describe() == m2.describe()
-
-
 def test_cost_model_table_overrides():
     m = DeviceCostModel.from_config(
         {"kind": "table", "decode_step_s": 0.02, "prefill_token_s": 1e-4}

@@ -25,7 +25,7 @@ arrival trace — through the deterministic fleet simulator in five arms:
 
 The headline check: the controlled fleet spends FEWER replica-seconds
 (chip-hours) than the static peak fleet at equal-or-better per-class
-TTFT SLO attainment. Receipt: ``AUTOSCALE_BENCH.json``.
+TTFT SLO attainment. ``--out PATH`` writes the full result.
 
     python tools/bench_autoscale.py
     python tools/bench_autoscale.py --check-determinism --out -
@@ -231,9 +231,9 @@ def main(argv: list[str] | None = None) -> int:
     )
     ap.add_argument("--seed", type=int, default=None)
     ap.add_argument(
-        "--out", default=os.path.join(REPO, "AUTOSCALE_BENCH.json"),
-        help="receipt path (default AUTOSCALE_BENCH.json at repo root); "
-             "'-' skips the write",
+        "--out", default="-",
+        help="where to write the full result; '-' (default) writes "
+             "nothing",
     )
     ap.add_argument(
         "--check-determinism", action="store_true",
@@ -254,21 +254,16 @@ def main(argv: list[str] | None = None) -> int:
         print("determinism: byte-identical same-seed re-run",
               file=sys.stderr)
 
-    from bench import bench_provenance
-
     checks = result["checks"]
     passed = sum(bool(v) for v in checks.values())
     ok = passed == len(checks)
-    receipt = {
+    full = {
         **result,
-        # Flat count for bench_trend's AUTOSCALE_BENCH family: the
-        # regression gate compares this across revisions.
         "checks_passed": passed,
-        "provenance": bench_provenance(),
     }
     if args.out != "-":
         with open(args.out, "w") as f:
-            json.dump(receipt, f, indent=1, sort_keys=True)
+            json.dump(full, f, indent=1, sort_keys=True)
             f.write("\n")
 
     ch = result["chips"]

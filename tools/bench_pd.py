@@ -24,10 +24,10 @@ catalog (exactly-one-terminal, KV balance, …) is asserted at drain.
 Virtual clock: the run is byte-reproducible and takes milliseconds of
 wall time regardless of the simulated seconds.
 
-Runs on CPU in one process (no JAX, no device). Writes PD_BENCH.json;
-prints one JSON line. Asserts the structural claims the subsystem ships
-on: zero lost/errored requests in both modes, every multi-token request
-handed off exactly once, and strictly lower decode step-time variance
+Runs on CPU in one process (no JAX, no device). Writes nothing;
+prints the full result, then one headline JSON line. Asserts the
+structural claims the subsystem ships on: zero lost/errored requests in
+both modes, every multi-token request handed off exactly once, and strictly lower decode step-time variance
 for the disaggregated fleet.
 """
 
@@ -166,8 +166,6 @@ def run_mode(mode: str) -> dict:
 def main():
     unified = run_mode("unified")
     disagg = run_mode("disagg")
-    from bench import bench_provenance
-
     result = {
         "config": {
             "chips": N_CHIPS,
@@ -187,7 +185,6 @@ def main():
         },
         "unified": unified,
         "disagg": disagg,
-        "provenance": bench_provenance(),
     }
     # The claims the subsystem ships on: nothing lost or errored, every
     # multi-token request handed off exactly once, and the decode chip's
@@ -199,13 +196,7 @@ def main():
     assert (
         disagg["decode_step_ms_stdev"] < unified["decode_step_ms_stdev"]
     ), result
-    path = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "PD_BENCH.json",
-    )
-    with open(path, "w") as f:
-        json.dump(result, f, indent=1)
-        f.write("\n")
+    print(json.dumps(result))
     print(json.dumps({
         "metric": "pd_disagg_decode_tok_s_chip",
         "value": disagg["tok_s_chip"],

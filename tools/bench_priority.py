@@ -49,8 +49,8 @@ the PR's capacity claim.
 Also times the scheduler's real ``_maybe_preempt`` no-op paths (idle,
 and pending-but-not-blocked) on a live ContinuousBatcher — the per-step
 host tax every deployment with ``preempt_cb`` set pays — against the
-25 µs budget. Writes PRIORITY_BENCH.json with ``bench_provenance``;
-exits nonzero if any acceptance assertion fails.
+25 µs budget. Writes nothing (prints the full result, then one
+headline JSON line); exits nonzero if any acceptance assertion fails.
 """
 
 from __future__ import annotations
@@ -64,7 +64,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from bench import bench_provenance  # noqa: E402
 from llmss_tpu.serve.protocol import (  # noqa: E402
     SLO_CLASS_BATCH,
     SLO_CLASS_INTERACTIVE,
@@ -392,7 +391,6 @@ def main() -> int:
 
     out = {
         "bench": "priority_scheduling",
-        "provenance": bench_provenance(),
         "config": {
             "seed": SEED, "rows": ROWS, "step_s": STEP_S,
             "chunk_tokens": CHUNK_TOKENS,
@@ -408,13 +406,7 @@ def main() -> int:
         "checks_passed": sum(1 for v in checks.values() if v),
         "ok": all(checks.values()),
     }
-    path = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "PRIORITY_BENCH.json",
-    )
-    with open(path, "w") as f:
-        json.dump(out, f, indent=1)
-        f.write("\n")
+    print(json.dumps(out))
     print(json.dumps({
         "metric": "interactive_ttft_p95_ms",
         "value": bo_i["ttft_p95_ms"],

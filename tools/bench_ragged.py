@@ -21,7 +21,8 @@ prewarmed ladder — so the comparison is deterministic and free of host
 noise; the scheduler arithmetic (admission, chunk metering, head-of-line
 stalls) is the thing being measured, and requests ride the REAL broker
 with the invariant catalog asserted at drain. Runs on CPU in one process
-(no JAX, no device). Writes RAGGED_BENCH.json; prints one JSON line.
+(no JAX, no device). Writes nothing; prints the full result, then one
+headline JSON line.
 Asserts the claims the subsystem ships on: decode step-time stdev no
 worse on the all-decode trace (the ragged program is not allowed to tax
 the steady state) and materially lower TTFT p95 plus lower decode stdev
@@ -174,9 +175,6 @@ def main():
             "ragged": run_mode("ragged", alldec),
         },
     }
-    from bench import bench_provenance
-
-    result["provenance"] = bench_provenance()
 
     ms, mr = result["mixed"]["split"], result["mixed"]["ragged"]
     as_, ar = result["all_decode"]["split"], result["all_decode"]["ragged"]
@@ -189,13 +187,7 @@ def main():
     ), result
     assert mr["buckets_compiled_mid_serve"] == 0, result
 
-    path = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "RAGGED_BENCH.json",
-    )
-    with open(path, "w") as f:
-        json.dump(result, f, indent=1)
-        f.write("\n")
+    print(json.dumps(result))
     print(json.dumps({
         "metric": "ragged_mixed_ttft_p95_ms",
         "value": mr["ttft_p95_ms"],

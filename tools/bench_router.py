@@ -21,7 +21,7 @@ aggregate tokens/s for both modes and asserts the direction of the
 result.
 
 Runs on CPU in one process (no JAX, no device). Writes
-ROUTER_BENCH.json; prints one JSON line.
+nothing; prints one JSON line.
 """
 
 from __future__ import annotations
@@ -131,20 +131,10 @@ def main():
         "shared": shared,
         "affinity": affinity,
     }
-    from bench import bench_provenance
-
-    result["provenance"] = bench_provenance()
     # The claims the policy ships on: strictly better prefix locality, no
     # TTFT regression.
     assert affinity["prefix_hit_rate"] > shared["prefix_hit_rate"], result
     assert affinity["ttft_p50_ms"] <= shared["ttft_p50_ms"], result
-    path = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "ROUTER_BENCH.json",
-    )
-    with open(path, "w") as f:
-        json.dump(result, f, indent=2)
-        f.write("\n")
     print(json.dumps(result))
 
 

@@ -21,8 +21,8 @@ worst case for instrumentation). The microcost is timed directly on the
 respond-path hook over real recorded timelines (deterministic); the
 throughput delta comes from median-of-paired adjacent trace/slo runs
 with DECODE_STEP_COST_S charged per decode chunk, which cancels machine
-drift a best-of comparison cannot. Writes SLO_BENCH.json with the
-standard bench_provenance stamp; prints one JSON line.
+drift a best-of comparison cannot. Writes nothing; prints one JSON
+line.
 """
 
 from __future__ import annotations
@@ -34,7 +34,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from bench import bench_provenance  # noqa: E402
 from llmss_tpu.serve import broker as broker_mod  # noqa: E402
 from llmss_tpu.serve.broker import InProcBroker  # noqa: E402
 from llmss_tpu.serve.chaos import ScriptedEngine  # noqa: E402
@@ -163,7 +162,6 @@ def main() -> int:
     tokens = N_REQUESTS * MAX_NEW
     out = {
         "bench": "slo_plane_overhead",
-        "provenance": bench_provenance(),
         "requests": N_REQUESTS,
         "max_new_tokens": MAX_NEW,
         "repeats": REPEATS,
@@ -183,8 +181,6 @@ def main() -> int:
             and overhead_pct < THROUGHPUT_PCT_BUDGET
         ),
     }
-    with open("SLO_BENCH.json", "w") as f:
-        json.dump(out, f, indent=2)
     print(json.dumps(out))
     return 0 if out["within_budget"] else 1
 

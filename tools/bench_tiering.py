@@ -18,7 +18,7 @@ arms:
 Headline checks: the tiered arm must beat the baseline on fleet prefix
 hit rate AND per-turn TTFT p95, and must avoid a nonzero number of
 re-prefill tokens (the baseline, with no tier store, avoids none).
-Receipt: ``TIER_BENCH.json``.
+``--out PATH`` writes the full result.
 
     python tools/bench_tiering.py
     python tools/bench_tiering.py --check-determinism --out -
@@ -131,9 +131,9 @@ def main(argv: list[str] | None = None) -> int:
     )
     ap.add_argument("--seed", type=int, default=None)
     ap.add_argument(
-        "--out", default=os.path.join(REPO, "TIER_BENCH.json"),
-        help="receipt path (default TIER_BENCH.json at repo root); "
-             "'-' skips the write",
+        "--out", default="-",
+        help="where to write the full result; '-' (default) writes "
+             "nothing",
     )
     ap.add_argument(
         "--check-determinism", action="store_true",
@@ -154,21 +154,16 @@ def main(argv: list[str] | None = None) -> int:
         print("determinism: byte-identical same-seed re-run",
               file=sys.stderr)
 
-    from bench import bench_provenance
-
     checks = result["checks"]
     passed = sum(bool(v) for v in checks.values())
     ok = passed == len(checks)
-    receipt = {
+    full = {
         **result,
-        # Flat count for bench_trend's TIER_BENCH family: the regression
-        # gate compares this across revisions.
         "checks_passed": passed,
-        "provenance": bench_provenance(),
     }
     if args.out != "-":
         with open(args.out, "w") as f:
-            json.dump(receipt, f, indent=1, sort_keys=True)
+            json.dump(full, f, indent=1, sort_keys=True)
             f.write("\n")
 
     t, b = result["tiered"], result["baseline"]

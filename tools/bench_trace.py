@@ -34,7 +34,7 @@ Three numbers come out:
   own compute runs in XLA's threads and is not in it).
 
 The first two passes run on CPU in one process with no JAX and no device;
-the third runs a toy model on JAX's CPU backend. Writes TRACE_BENCH.json;
+the third runs a toy model on JAX's CPU backend. Writes nothing;
 prints one JSON line. Asserts zero lost requests in both modes and that
 the traced mode leaves a complete timeline for a sampled request.
 """
@@ -219,8 +219,6 @@ def main() -> int:
         "loop_wall_us_per_iteration_tracing_off": round(loop["off"][1], 1),
         "loop_wall_us_per_iteration_tracing_on": round(loop["on"][1], 1),
     }
-    with open("TRACE_BENCH.json", "w") as f:
-        json.dump(out, f, indent=2)
     print(json.dumps(out))
     return 0 if out["within_2pct"] else 1
 

@@ -11,9 +11,10 @@ no delivery attempts, KV accounts balance at drain, DLQ holds only
 genuine poison) asserted continuously and at drain.
 
 The run is byte-reproducible: same scenario + same seed produces a
-byte-identical ``STORM_BENCH.json`` (``--check-determinism`` proves it
-by running twice and comparing serialized reports). ``--requests``
-scales the storm down for CI without touching the scenario file.
+byte-identical report (``--check-determinism`` proves it by running
+twice and comparing serialized reports; ``--out PATH`` writes it).
+``--requests`` scales the storm down for CI without touching the
+scenario file.
 
     python tools/sim_storm.py                         # the full 1M storm
     python tools/sim_storm.py --requests 20000 --check-determinism
@@ -43,9 +44,9 @@ def main(argv: list[str] | None = None) -> int:
     )
     ap.add_argument("--seed", type=int, default=None)
     ap.add_argument(
-        "--out", default=os.path.join(REPO, "STORM_BENCH.json"),
-        help="receipt path (default STORM_BENCH.json at repo root); "
-             "'-' skips the write",
+        "--out", default="-",
+        help="where to write the full report; '-' (default) writes "
+             "nothing",
     )
     ap.add_argument(
         "--check-determinism", action="store_true",
@@ -69,17 +70,14 @@ def main(argv: list[str] | None = None) -> int:
             return 1
         print("determinism: byte-identical same-seed re-run", file=sys.stderr)
 
-    from bench import bench_provenance
-
-    receipt = {
+    full = {
         "bench": "fleet_storm",
         "scenario_file": os.path.relpath(args.scenario, REPO),
         "report": report,
-        "provenance": bench_provenance(),
     }
     if args.out != "-":
         with open(args.out, "w") as f:
-            json.dump(receipt, f, indent=1, sort_keys=True)
+            json.dump(full, f, indent=1, sort_keys=True)
             f.write("\n")
 
     r = report["requests"]
