@@ -11,7 +11,7 @@ live or done, so the floor counts them all: the share follows the kernel and
 not how many rows the traffic happened to fill. ``None`` without a mixer, a
 state pool (``cache.state_bytes`` of /metrics), a trace, or a counted step."""
 
-from benchmark.lib import spans, ssm
+from benchmark.lib import reduce, spans, ssm
 
 
 def decode_steps_in_trace(ctx):
@@ -19,13 +19,9 @@ def decode_steps_in_trace(ctx):
     ``sched.dispatch`` spans (``chunks`` x ``k``), else the window's
     ``loop.decode_steps`` scaled by the traced share of the window."""
     trace = ctx["trace"]
-    inside = [
-        s["chunks"] * s["k"]
-        for s in spans.loop_spans(ctx.get("flight_trace"), "sched.dispatch")
-        if trace["t_start"] <= s["t0"] + s["dur"] <= trace["t_stop"]
-    ]
-    if inside:
-        return sum(inside)
+    steps = sum(k for _t, k in reduce.dispatches_in_trace(ctx))
+    if steps:
+        return steps
     d, w = spans.loop_delta(ctx), ctx.get("window") or {}
     if d is None or not d.get("decode_steps") or not w.get("w1", 0) > w.get("w0", 0):
         return None

@@ -1,38 +1,31 @@
 """Reductions that more than one per-layer metric shares: the decode groups
-the scheduler dispatched while the trace was taken (the program's own flight
-recorder), what the request log says the batch looked like meanwhile, and the
-device time of one decode step."""
+the scheduler dispatched while the trace was taken (the loop track's
+``sched.dispatch`` spans), what the request log says the batch looked like
+meanwhile, and the device time of one decode step."""
 
 from __future__ import annotations
 
-# Events of one dispatch are recorded in one loop over the batch's rows, well
-# under a millisecond apart; two dispatches are at least a group apart.
-MERGE_S = 0.005
-# The recorder throttles ``group_dispatch`` to one event per request in 50 ms:
-# groups dispatched faster than that are not all recorded, so not countable.
-THROTTLE_S = 0.05
+from benchmark.lib import spans
 
 
 def _increments(rec: dict) -> list[tuple[float, int]]:
     return rec.get("increments") or []
 
 
-def group_dispatches(flight: dict) -> list[tuple[float, int]]:
-    """``(time, decode steps)`` of every group the scheduler dispatched, in
-    order: the flight recorder's ``group_dispatch`` events (the scheduler
-    stamps one per request in the batch, with the group's ``chunks`` and
-    ``k``, whose product is the steps every row advances), merged into one
-    entry per dispatch. Times are the server's monotonic clock."""
-    events = sorted(
-        (ev["t"], ev["attrs"]["chunks"] * ev["attrs"]["k"])
-        for req in (flight or {}).get("requests", {}).values()
-        for ev in req["events"] if ev["name"] == "group_dispatch"
-    )
-    out: list[tuple[float, int]] = []
-    for t, steps in events:
-        if not out or t - out[-1][0] >= MERGE_S:
-            out.append((t, steps))
-    return out
+def dispatches_in_trace(ctx: dict) -> list[tuple[float, int]]:
+    """``(time, decode steps)`` of every group the scheduler dispatched
+    inside the traced interval, in order: one ``sched.dispatch`` span of the
+    loop track a group, however short the group, placed at its end, with
+    ``chunks`` x ``k`` steps (what every row advances). Times are the
+    server's monotonic clock. Empty where tracing was off."""
+    trace = ctx.get("trace") or {}
+    if "t_start" not in trace:
+        return []
+    return [
+        (s["t0"] + s["dur"], s["chunks"] * s["k"])
+        for s in spans.loop_spans(ctx.get("flight_trace"), "sched.dispatch")
+        if trace["t_start"] <= s["t0"] + s["dur"] <= trace["t_stop"]
+    ]
 
 
 def batch_between(records: list[dict], a: float, b: float, n: int = 16) -> dict:
@@ -74,7 +67,7 @@ def program_seconds(trace: dict, *needles: str) -> tuple[float, float]:
 STEP_PROGRAMS = ("jit__unknown",)
 
 
-def decode_step_seconds(ctx: dict) -> float | None:
+def step_seconds_in_trace(ctx: dict) -> float | None:
     """Device time of the engine's step programs per decode step, while the
     trace was taken: what the device spends for one token of every row, the
     admission prefills between the decode groups included (they run under
@@ -84,19 +77,14 @@ def decode_step_seconds(ctx: dict) -> float | None:
     first to the last of them so that no group is cut at an edge, each with
     the steps the scheduler gave it. A group runs on the device one group
     after its dispatch; if the groups at the two edges differ in steps, the
-    count is off by that difference. Nothing is read when fewer than three
-    dispatches fall inside the trace or when groups come faster than the
-    recorder's throttle lets it record them."""
+    count is off by that difference. Nothing is read without a device in the
+    trace or with fewer than three dispatches inside it."""
     trace = ctx.get("trace")
     if not trace or not trace.get("devices") or not trace.get("window_s"):
         return None
     seconds, _n = program_seconds(trace, *STEP_PROGRAMS)
-    inside = [d for d in group_dispatches(ctx.get("flight_trace"))
-              if trace["t_start"] <= d[0] <= trace["t_stop"]]
+    inside = dispatches_in_trace(ctx)
     if len(inside) < 3 or not seconds:
-        return None
-    gaps = [b[0] - a[0] for a, b in zip(inside, inside[1:])]
-    if min(gaps) < THROTTLE_S:
         return None
     steps = sum(k for _t, k in inside[:-1])
     span = inside[-1][0] - inside[0][0]

@@ -411,9 +411,7 @@ def run_cell(args) -> int:
                 "idle_gaps": label_gaps(trace, recs),
             }
             log("programs: " + json.dumps(trace["programs"]))
-            groups = [k for t, k in reduce.group_dispatches(
-                state.get("flight_trace"))
-                if trace["t_start"] <= t <= trace["t_stop"]]
+            groups = [k for _t, k in reduce.dispatches_in_trace(ctx)]
             log(f"groups dispatched inside the trace: {len(groups)}, of "
                 f"{sorted(set(groups))} steps, {sum(groups)} steps in all")
     else:

@@ -68,7 +68,7 @@ def test_the_manifest_has_the_new_entries_and_nothing_else_moved():
     assert {e["name"] for e in c["per_layer"]} == {
         "decode_step_dev_ms", "host_turn_pct", "loop_host_ms_per_step",
         "host_ms_per_group", "ssm_pct", "ssm_decode_roofline",
-        "first_token_p50_ms"}
+        "first_token_p50_ms", "decode_step_mfu_roofline", "sampler_search_pct"}
     assert [w["name"] for w in m["workloads"]][0] == "starcoderbase-1b.gen"
     old = manifest.cell(m, "starcoderbase-1b.gen")
     assert not {e["name"] for e in old["per_layer"]} & {

@@ -3,6 +3,7 @@ finds its file."""
 
 import copy
 import json
+import re
 import sys
 from pathlib import Path
 
@@ -38,6 +39,33 @@ def test_every_cell_finds_its_files(cell):
     ref = ROOT / "benchmark" / "reference" / f"{c['model']['model_type']}.py"
     assert ref.exists()
     assert len(c["end_to_end"]) >= 2 and c["per_layer"]
+
+
+def test_every_metric_has_a_reader_and_every_reader_an_entry():
+    readers = {p.stem for p in (ROOT / "benchmark" / "layer_metrics").glob("*.py")}
+    assert {e["name"] for e in REAL["per_layer"]} == readers
+
+
+def test_one_share_of_the_whole_step_bounds_every_claim_on_tpot():
+    """Exactly one per-layer metric carries ``mfu`` as a part of its name: a
+    share of the chip's peak in %, it moves ``tpot_p90_ms`` and is read in
+    every cell that reports ``tpot_p90_ms``, so a PR that takes a kernel off
+    the path (and silences that kernel's roofline) still has a share that
+    bounds what it claims."""
+    mfu = [e for e in REAL["per_layer"]
+           if "mfu" in re.split(r"[_.\-]", e["name"])]
+    assert [e["name"] for e in mfu] == ["decode_step_mfu_roofline"]
+    (e,) = mfu
+    assert (e["unit"], e["better"], e["moves"]) == ("%", "higher", "tpot_p90_ms")
+    assert e["source"] == "device_trace" and e["layer"] == "step programs"
+    tpot = next(x for x in REAL["end_to_end"] if x["name"] == "tpot_p90_ms")
+    cells = [w["name"] for w in REAL["workloads"]]
+    assert sorted(e["workloads"]) == sorted(tpot.get("workloads", cells))
+    # every other roofline share moves the same metric in cells it covers
+    for r in REAL["per_layer"]:
+        if r["name"].endswith("_roofline"):
+            assert r["moves"] == e["moves"]
+            assert set(r["workloads"]) <= set(e["workloads"])
 
 
 @pytest.mark.parametrize("path", sorted(

@@ -47,14 +47,15 @@ def test_sampler_search_pct_gives_nothing_without_the_counter(ctx):
     assert READER.read(ctx) is None
 
 
-def test_sampler_search_pct_is_declared_for_the_cell_that_filters():
-    """Appended last, for ``starcoderbase-1b.gen``. Not for
-    ``falcon-h1-34b-1chip.chat``, where it reads 0.0: that cell's set of
-    per-layer metrics is pinned by ``test_bench_falcon_h1.py``, and a
-    ``benchmark`` PR can append the cell with that test."""
+def test_sampler_search_pct_is_declared_for_both_cells():
+    """Appended last. ``starcoderbase-1b.gen`` filters (``top_p`` 0.95);
+    ``falcon-h1-34b-1chip.chat`` (appended by PR 32) samples at ``top_p`` 1
+    with no ``top_k`` and reads 0.0: the proof that no row of that mix pays
+    the keep-set search."""
     entry = json.loads((ROOT / "BENCHMARK.json").read_text())["per_layer"][-1]
     assert entry == {
         "name": "sampler_search_pct", "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "kernels",
-        "moves": "tpot_p90_ms", "workloads": ["starcoderbase-1b.gen"],
+        "moves": "tpot_p90_ms",
+        "workloads": ["starcoderbase-1b.gen", "falcon-h1-34b-1chip.chat"],
     }

@@ -10,7 +10,7 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
-from benchmark.lib import manifest, reduce, spans, stats  # noqa: E402
+from benchmark.lib import costs, manifest, peaks, reduce, spans, stats  # noqa: E402
 
 
 def _reader(name):
@@ -91,60 +91,113 @@ def _loop_track(groups, gap, t0=1.0, steps=(1, 4)):
     return {"spans": out, "dropped": 0}
 
 
-def _per_request_events(track, throttle_s=0.05):
-    """What the scheduler records beside the track: a ``group_dispatch`` a
-    live request at each dispatch, throttled per request. The requests
-    joined the batch one group after another, as requests do."""
-    reqs = {}
-    for joined, rid in enumerate(("x", "y", "z")):
-        last, evs = None, []
-        for sp in track["spans"]:
-            t = sp[3] + sp[4]
-            if sp[2] == "sched.dispatch" and sp[5]["group"] > joined and (
-                    last is None or t - last >= throttle_s):
-                last = t
-                evs.append({"name": "group_dispatch", "t": t, "attrs": {
-                    "chunks": sp[5]["chunks"], "k": sp[5]["k"], "loop": sp[0]}})
-        reqs[rid] = {"events": evs}
-    return reqs
-
-
 TRACE = {"devices": 1, "t_start": 2.0, "t_stop": 8.0, "window_s": 6.0,
          "programs": {"jit__unknown": {"s": 5.4, "n": 30},
                       "jit__admit_merge_impl": {"s": 0.1, "n": 9}}}
 
+PEAKS = peaks.peaks_for("TPU v5 lite")
+# ten layers of keys and values, and 5 MiB of recurrent state a row
+DIMS = {"layers": 10, "heads": 16, "kv_heads": 4, "head_dim": 128,
+        "matmul_params": 900_000_000, "total_params": 1_000_000_000,
+        "state_bytes_per_row": 5 * 2**20}
+CELL = {"config": {"dtype": "bfloat16"}, "entry": {"chips": 1}}
 
-def test_device_step_time_agrees_with_the_old_reader_on_long_groups():
+
+def _records(rows=8, prompt=100, tokens=40):
+    """``rows`` requests decoding all through the trace: first token at 1.0,
+    ``tokens`` more by 1.5, done long after."""
+    return [{"first": 1.0, "done": 99.0, "increments": [(1.0, 1), (1.5, tokens)],
+             "body": {"token_ids": [0] * prompt}} for _ in range(rows)]
+
+
+def _step_ctx(track, trace=TRACE, dims=DIMS, **over):
+    return {"trace": trace, "flight_trace": {"loop": track}, "cell": CELL,
+            "dims": dims, "peaks": PEAKS, "costs": costs,
+            "records": _records(), **over}
+
+
+def test_device_step_time_is_the_traces_share_over_the_tracks_steps():
     track = _loop_track(groups=40, gap=0.289)
-    flight = {"requests": _per_request_events(track), "loop": track}
-    ctx = {"trace": TRACE, "flight_trace": flight}
-    old = reduce.decode_step_seconds(ctx) * 1e3
-    new = _reader("decode_step_dev_ms").read(ctx)
-    assert new == pytest.approx(old, rel=1e-9)
+    ctx = _step_ctx(track)
     # 90% of the window in step programs, 4 steps every 289 ms
-    assert new == pytest.approx(0.9 * 289 / 4, rel=1e-9)
-    inside = [s for s in spans.loop_spans(flight, "sched.dispatch")
-              if 2.0 <= s["t0"] + s["dur"] <= 8.0]
-    assert [s["group"] for s in inside] == list(range(5, 26))
-
-
-def test_device_step_time_reads_groups_the_old_reader_cannot_count():
-    """Groups 20 ms apart: the per-request events are throttled to one in 50
-    ms, so ``decode_step_ms`` gives nothing; the track has every group."""
-    track = _loop_track(groups=400, gap=0.020)
-    flight = {"requests": _per_request_events(track), "loop": track}
-    ctx = {"trace": TRACE, "flight_trace": flight}
-    assert _reader("decode_step_ms").read(ctx) is None
     assert _reader("decode_step_dev_ms").read(ctx) == pytest.approx(
-        0.9 * 20 / 4, rel=1e-9)
-    # fewer than three dispatches inside, no device, or no track: nothing
-    few = {"loop": _loop_track(groups=2, gap=0.289, t0=3.0)}
-    assert _reader("decode_step_dev_ms").read(
-        {"trace": TRACE, "flight_trace": few}) is None
-    assert _reader("decode_step_dev_ms").read(
-        {"trace": {**TRACE, "devices": 0}, "flight_trace": flight}) is None
-    assert _reader("decode_step_dev_ms").read(
-        {"trace": TRACE, "flight_trace": {"requests": flight["requests"]}}) is None
+        0.9 * 289 / 4, rel=1e-9)
+    assert reduce.step_seconds_in_trace(ctx) == pytest.approx(
+        0.9 * 0.289 / 4, rel=1e-9)
+    inside = reduce.dispatches_in_trace(ctx)
+    assert [k for _t, k in inside] == [4] * 21
+    assert inside[0][0] == pytest.approx(1.0 + 4 * 0.289 + 0.002)
+    assert reduce.program_seconds(TRACE, "_admit_merge") == (0.1, 9)
+
+
+@pytest.mark.parametrize("gap_ms", [10, 28, 56])
+def test_the_steps_share_reads_the_same_at_any_group_length(gap_ms):
+    """Groups of 4 steps 10, 28 and 56 ms apart, the device 2 ms in step
+    programs for every step of each: one ``sched.dispatch`` span a group, so
+    the step reads 2 ms and the share the same at all three (the retired
+    reader counted per-request events throttled to one in 50 ms, and read
+    nothing at the first two)."""
+    track = _loop_track(groups=int(9.0 / (gap_ms / 1e3)), gap=gap_ms / 1e3)
+    busy = 6.0 * (4 * 0.002) / (gap_ms / 1e3)
+    trace = {**TRACE, "programs": {"jit__unknown": {"s": busy, "n": 1}}}
+    ctx = _step_ctx(track, trace)
+    assert _reader("decode_step_dev_ms").read(ctx) == pytest.approx(2.0, rel=1e-9)
+    floor = costs.decode_step_floor_s(
+        DIMS, "bfloat16", PEAKS, rows=8, context=141)["floor_s"]
+    assert _reader("decode_step_mfu_roofline").read(ctx) == pytest.approx(
+        100 * floor / 0.002, rel=1e-9)
+
+
+def test_the_steps_share_is_the_floor_over_the_device_step_time_to_the_digit():
+    """``costs.decode_step_floor_s`` at the rows and context the request log
+    gives, over ``decode_step_dev_ms``'s own reading of the same trace: the
+    retired ``decode_group_roofline``'s quotient with the count of steps
+    taken from the spans."""
+    ctx = _step_ctx(_loop_track(groups=40, gap=0.289))
+    batch = reduce.batch_between(ctx["records"], 2.0, 8.0)
+    assert batch == {"rows": 8.0, "context": 141.0}
+    floor = costs.decode_step_floor_s(DIMS, "bfloat16", PEAKS, **batch)
+    step_ms = _reader("decode_step_dev_ms").read(ctx)
+    got = _reader("decode_step_mfu_roofline").read(ctx)
+    assert got == 100.0 * floor["floor_s"] / (step_ms / 1e3)
+    assert floor["bound_by"] == "memory" and 0 < got < 100
+
+
+def test_the_steps_floor_holds_every_live_rows_state_twice():
+    """A configuration with a recurrent state: the floor's bytes are the
+    parameters, the keys and values in flight and 2 x rows x state; without
+    the state the same trace reads a lower share."""
+    ctx = _step_ctx(_loop_track(groups=40, gap=0.289))
+    kv = 10 * 2 * 4 * 128 * 2 * 8 * 141
+    by_hand = (2 * 1_000_000_000 + kv + 2 * 8 * 5 * 2**20) / 819e9
+    step = 0.9 * 0.289 / 4
+    got = _reader("decode_step_mfu_roofline").read(ctx)
+    assert got == pytest.approx(100 * by_hand / step, rel=1e-12)
+    stateless = {k: v for k, v in DIMS.items() if k != "state_bytes_per_row"}
+    less = _reader("decode_step_mfu_roofline").read({**ctx, "dims": stateless})
+    assert got - less == pytest.approx(
+        100 * (2 * 8 * 5 * 2**20 / 819e9) / step, rel=1e-9)
+
+
+@pytest.mark.parametrize("missing", [
+    "no_trace", "no_device", "no_peaks", "two_spans", "no_track", "no_rows"])
+def test_the_steps_share_reads_nothing_where_something_is_missing(missing):
+    """None, never a zero and never an exception: a share of a peak that
+    reads 0 would be a lie."""
+    ctx = _step_ctx(_loop_track(groups=40, gap=0.289))
+    assert _reader("decode_step_mfu_roofline").read(ctx) is not None
+    ctx.update({
+        "no_trace": {"trace": None},
+        "no_device": {"trace": {**TRACE, "devices": 0}},
+        "no_peaks": {"peaks": None},
+        "two_spans": {"flight_trace": {
+            "loop": _loop_track(groups=2, gap=0.289, t0=3.0)}},
+        "no_track": {"flight_trace": {"requests": {}}},
+        "no_rows": {"records": []},
+    }[missing])
+    assert _reader("decode_step_mfu_roofline").read(ctx) is None
+    if missing not in ("no_peaks", "no_rows"):  # the step time needs neither
+        assert _reader("decode_step_dev_ms").read(ctx) is None
 
 
 def _loop_block(**seconds):
