@@ -412,9 +412,18 @@ def paged_write_stacked(
 ) -> jax.Array:
     """One batched all-layer scatter into the pool (the paged analogue of
     the dense post-scan ``cache.k.at[:, b_idx, slots].set``). Writes
-    through unmapped table entries are dropped."""
+    through unmapped table entries are dropped.
+
+    The layer is an INDEX of the scatter, not a window (``pool.at[:, blk,
+    off]``): with the layer axis a window, layout assignment may carry the
+    pool layer-minor through the step loop while the layer scan slices it
+    layer-major, and then transposes the whole pool of k and of v every
+    decode step (it did with one KV head; docs/paged-kv.md)."""
     blk, off = logical_to_physical(block_tables, slots, block_size)
-    return pool.at[:, blk, off].set(new.astype(pool.dtype), mode="drop")
+    layer = jnp.arange(pool.shape[0], dtype=jnp.int32)[:, None, None]
+    return pool.at[layer, blk[None], off[None]].set(
+        new.astype(pool.dtype), mode="drop"
+    )
 
 
 def export_blocks(

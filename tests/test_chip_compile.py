@@ -9,19 +9,33 @@ at the widths of three models the repo serves, at the engine's default
 ``block_size`` and a real cache length. A compile that passes is a compile,
 not a chip run.
 
+Two whole step programs are compiled too, the decode group and the ragged
+group at the widths and envelope of ``starcoderbase-1b`` as the benchmark
+serves it: what layout assignment does to the block pool the step loop
+carries shows only in the compiled text (a transpose of the whole pool every
+step, once: docs/paged-kv.md).
+
 Plus: where ``initialize_runtime()`` puts the persistent compile cache.
 """
 
+import dataclasses
 import functools
+import math
 import os
+import re
+import types
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
 
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
+from llmss_tpu.engine import DecodeEngine
+from llmss_tpu.engine.cache import PagedKVCache
+from llmss_tpu.models.decoder import param_shapes, param_specs
+from llmss_tpu.models.registry import config_from_hf
 from llmss_tpu.ops import (
     pallas_attention, pallas_decode, pallas_paged_decode, pallas_ragged,
 )
@@ -111,6 +125,96 @@ def test_kernel_compiles_for_v5e(v5e, kernel, model):
         functools.partial(fn, interpret=False)
     ).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# bigcode/starcoderbase-1b's config.json, and the envelope the benchmark's
+# first cell serves it in (benchmark/configs/starcoderbase-1b.json).
+STARCODERBASE_1B = dict(
+    model_type="gpt_bigcode", vocab_size=49152, n_positions=8192,
+    n_embd=2048, n_layer=24, n_head=16, n_inner=8192, multi_query=True,
+    activation_function="gelu_pytorch_tanh", layer_norm_epsilon=1e-5,
+)
+ROWS, POSITIONS = 64, 2048
+
+
+def _compile_group(device, program: str, n_kv_heads: int):
+    """``(compiled, pool shape)`` of one step program of the engine on
+    shapes alone: 4 decode steps at a 512-slot read, or 4 mixed steps with a
+    4-token chunk a row."""
+    cfg = dataclasses.replace(
+        config_from_hf(
+            types.SimpleNamespace(**STARCODERBASE_1B), dtype="bfloat16"
+        ),
+        n_kv_heads=n_kv_heads,
+    )
+    mesh = mesh_mod.make_mesh(mesh_mod.MeshPlan(tp=1), devices=[device])
+
+    def arr(shape, dtype, spec=PartitionSpec()):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, spec)
+        )
+
+    params = jax.tree.map(
+        lambda s, spec: arr(s.shape, s.dtype, spec),
+        param_shapes(cfg), param_specs(cfg, 1),
+    )
+    mb = POSITIONS // BS
+    pool = (cfg.n_layers, ROWS * mb, BS, n_kv_heads, cfg.head_dim)
+    cache = PagedKVCache(
+        k=arr(pool, DT), v=arr(pool, DT),
+        block_tables=arr((ROWS, mb), jnp.int32),
+        positions=arr((ROWS, POSITIONS), jnp.int32),
+    )
+    row = functools.partial(arr, (ROWS,))
+    sample_args = dict(
+        seeds=row(jnp.int32), temperature=row(jnp.float32),
+        top_k=row(jnp.int32), top_p=row(jnp.float32), greedy=row(jnp.bool_),
+    )
+    state = (
+        params, row(jnp.int32), cache, row(jnp.int32), sample_args,
+        row(jnp.bool_), row(jnp.int32),
+    )
+    if program == "decode":
+        lowered = jax.jit(
+            functools.partial(DecodeEngine._decode_group_impl, cfg, mesh),
+            donate_argnums=(1, 2, 3),
+            static_argnames=("n_chunks", "n_steps", "t_bucket"),
+        ).lower(*state, n_chunks=1, n_steps=4, t_bucket=512)
+    else:
+        steps = functools.partial(arr, (4, ROWS))
+        lowered = jax.jit(
+            functools.partial(DecodeEngine._ragged_group_impl, cfg, mesh),
+            donate_argnums=(1, 2, 3),
+        ).lower(
+            *state, arr((4, ROWS, 4), jnp.int32), steps(jnp.int32),
+            steps(jnp.bool_), steps(jnp.bool_),
+        )
+    return lowered.compile(), pool
+
+
+def _pool_sized_copies(hlo_text: str, pool: tuple) -> list[str]:
+    """The ``copy`` instructions whose result has as many elements as the
+    pool, whatever dimensions a bitcast gave it."""
+    out = []
+    for line in hlo_text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%copy\S* = \w+\[([\d,]+)\]", line)
+        if m and math.prod(map(int, m[1].split(","))) == math.prod(pool):
+            out.append(line.strip()[:120])
+    return out
+
+
+@pytest.mark.parametrize(
+    "program,n_kv_heads", [("decode", 1), ("ragged", 1), ("decode", 4)]
+)
+def test_step_program_carries_the_pool_in_place(v5e, program, n_kv_heads):
+    """The step loop's carry keeps the layout the layer scan reads: no
+    program copies the whole pool, and with one KV head (where it once did,
+    six times a group) the temporaries are a small part of one pool."""
+    compiled, pool = _compile_group(v5e, program, n_kv_heads)
+    assert _pool_sized_copies(compiled.as_text(), pool) == []
+    if n_kv_heads == 1:
+        pool_bytes = math.prod(pool) * jnp.dtype(DT).itemsize
+        assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 10
 
 
 def test_supports_refuses_what_vmem_cannot_hold():

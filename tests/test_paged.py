@@ -142,6 +142,66 @@ def test_gather_view_matches_identity_pool_and_write_roundtrip(devices):
     np.testing.assert_array_equal(np.asarray(dropped), np.asarray(pool))
 
 
+def _write_reference(pool, new, tables, slots, bs):
+    """``paged_write_stacked`` said plainly: a loop over layers, rows and
+    tokens; a slot past the row's table or a table entry past the pool is
+    no write."""
+    out = pool.copy()
+    n_blocks, mb = pool.shape[1], tables.shape[1]
+    for layer in range(pool.shape[0]):
+        for b in range(slots.shape[0]):
+            for s in range(slots.shape[1]):
+                slot = int(slots[b, s])
+                if slot >= mb * bs:
+                    continue
+                blk = int(tables[b, slot // bs])
+                if blk >= n_blocks:
+                    continue
+                out[layer, blk, slot % bs] = new[layer, b, s].astype(
+                    pool.dtype
+                )
+    return out
+
+
+@pytest.mark.parametrize("head_dim", [8, None], ids=["kv", "scales"])
+@pytest.mark.parametrize("n_tok", [1, 4])
+@pytest.mark.parametrize("n_kv_heads", [1, 4])
+def test_paged_write_stacked_matches_loop(n_kv_heads, n_tok, head_dim):
+    """Bitwise the loop above: pools ``[L, N, bs, Hkv, D]`` (float32 values
+    cast to a bf16 pool) and ``[L, N, bs, Hkv]`` (the int8 pools' scales),
+    one or several tokens a row; a row with a sentinel table and a slot
+    ``>= MB*bs`` write nothing, and nothing else in the pool moves."""
+    L, B, MB, bs = 3, 4, 2, 4
+    N = B * MB + 1
+    tail = (n_kv_heads,) if head_dim is None else (n_kv_heads, head_dim)
+    dtype = jnp.float32 if head_dim is None else jnp.bfloat16
+    rng = np.random.default_rng(n_kv_heads * 10 + n_tok)
+    pool = np.asarray(
+        jnp.asarray(rng.standard_normal((L, N, bs) + tail), dtype)
+    )
+    new = rng.standard_normal((L, B, n_tok) + tail).astype(np.float32)
+    # Rows own distinct blocks, out of order; row 2's table is unmapped.
+    tables = rng.permutation(N)[: B * MB].reshape(B, MB).astype(np.int32)
+    tables[2] = table_sentinel(N)
+    # Distinct slots within a row; row 1's last token is past its table
+    # (the decode loop's write suppression for a done row).
+    slots = np.stack([
+        rng.permutation(MB * bs)[:n_tok] for _ in range(B)
+    ]).astype(np.int32)
+    slots[1, -1] = MB * bs
+    want = _write_reference(pool, new, tables, slots, bs)
+    got = np.asarray(jax.jit(paged_write_stacked, static_argnums=4)(
+        jnp.asarray(pool), jnp.asarray(new), jnp.asarray(tables),
+        jnp.asarray(slots), bs,
+    ))
+    assert got.dtype == pool.dtype
+    bits = np.uint16 if pool.dtype.itemsize == 2 else np.uint32
+    np.testing.assert_array_equal(got.view(bits), want.view(bits))
+    # What landed is what was live: rows 0 and 3 whole, row 1 less one.
+    moved = (got.view(bits) != pool.view(bits)).reshape(L, N, bs, -1)
+    assert moved.any(-1).sum() == L * (3 * n_tok - 1)
+
+
 # -- engine-level equivalence ----------------------------------------------
 
 PROMPTS = [[5, 9, 23, 40], [3, 14, 15, 9, 26, 5]]
