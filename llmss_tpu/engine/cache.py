@@ -206,7 +206,14 @@ def table_sentinel(num_blocks: int) -> int:
 
 class PagedKVCache(NamedTuple):
     k: jax.Array  # [L, N, bs, Hkv, D] global block pool
-    v: jax.Array  # [L, N, bs, Hkv, D]
+    # [L, N, bs, Hkv, D]; None for a LATENT pool (a model with latent
+    # attention, cfg.mla): ``k`` then holds ``[L, N, bs, C + R]``, a token's
+    # normed latent beside its shared rotary key, and values are its first
+    # C columns, so no second pool is allocated, written or copied. No head
+    # axis: a size-1 axis beside the minor one is free for layout
+    # assignment to move, and it then transposes the pool whole
+    # (docs/latent-cache.md)
+    v: jax.Array | None
     block_tables: jax.Array  # [B, MB] int32; >= N = unmapped sentinel
     positions: jax.Array  # [B, MB*bs] int32 per LOGICAL slot, -1 = empty
     # int8 pool variant: per-(layer, block, slot, head) dequant scales —
@@ -253,22 +260,29 @@ class PagedKVCache(NamedTuple):
 
 
 def paged_cache_specs(
-    n_kv_heads: int, tp: int, *, quantized: bool = False,
+    row: tuple[int, ...], tp: int, *, quantized: bool = False,
 ) -> PagedKVCache:
-    """PartitionSpecs for the paged pytree. The pool shards KV heads over
-    ``tp`` exactly like the dense cache; blocks are GLOBAL indices so the
-    block axis cannot shard over dp — the pool replicates across dp (the
-    documented v1 trade: dp>1 meshes pay pool HBM per replica; the paged
-    win is per-ROW HBM, which dp never sharded well under continuous
-    batching anyway). Tables/positions are tiny and replicated."""
-    head_axis = AXIS_TP if n_kv_heads % tp == 0 else None
-    kv = P(None, None, None, head_axis, None)
+    """PartitionSpecs for the paged pytree. ``row`` is what one token holds
+    in one layer of a pool (``cfg.cache_row``): ``(kv heads, head size)``,
+    or ONE vector for a latent pool, which has no head axis to shard
+    (replicated, as MQA's one head is) and no ``v``. The pool shards KV
+    heads over ``tp`` exactly like the dense cache; blocks are GLOBAL
+    indices so the block axis cannot shard over dp — the pool replicates
+    across dp (the documented v1 trade: dp>1 meshes pay pool HBM per
+    replica; the paged win is per-ROW HBM, which dp never sharded well
+    under continuous batching anyway). Tables/positions are tiny and replicated."""
+    latent = len(row) == 1
+    head_axis = AXIS_TP if not latent and row[0] % tp == 0 else None
+    kv = P(None, None, None, None) if latent else P(
+        None, None, None, head_axis, None
+    )
     scale = P(None, None, None, head_axis) if quantized else None
     # The recurrent state is replicated, like the mixer's weights
     # (models/decoder.py: param_specs). Specs of leaves a cache does not
     # hold are skipped by the callers, leaf by leaf.
     return PagedKVCache(
-        k=kv, v=kv, block_tables=P(None, None), positions=P(None, None),
+        k=kv, v=None if latent else kv,
+        block_tables=P(None, None), positions=P(None, None),
         k_scale=scale, v_scale=scale, ssm=P(), conv=P(), state_rows=P(),
     )
 
@@ -288,14 +302,13 @@ def ssm_state_shapes(cfg) -> tuple | None:
 
 
 def paged_cache_specs_for(
-    mesh: Mesh, *, n_kv_heads: int, dtype,
+    mesh: Mesh, *, row: tuple[int, ...], dtype,
 ) -> PagedKVCache:
     """Concrete-mesh spec selection for paged caches (the one policy shared
     by ``init_paged_cache`` and ``DecodeEngine.canon_cache``, mirroring
     ``cache_specs_for``)."""
     return paged_cache_specs(
-        n_kv_heads, mesh.shape[AXIS_TP],
-        quantized=jnp.dtype(dtype) == jnp.int8,
+        row, mesh.shape[AXIS_TP], quantized=jnp.dtype(dtype) == jnp.int8,
     )
 
 
@@ -305,8 +318,7 @@ def init_paged_cache(
     n_layers: int,
     batch: int,
     max_len: int,
-    n_kv_heads: int,
-    head_dim: int,
+    row: tuple[int, ...],
     dtype=jnp.bfloat16,
     block_size: int = 16,
     num_blocks: int | None = None,
@@ -317,6 +329,8 @@ def init_paged_cache(
     blocks ``[b*MB, (b+1)*MB)`` — a dense-equivalent static layout for the
     engine's own generate paths (no allocator in the loop). The scheduler
     passes False and drives tables from its host-side ``BlockAllocator``.
+    ``row`` (``cfg.cache_row``) is the pools' trailing shape: ``(kv heads,
+    head size)``, or one vector for a latent pool (ONE pool, no ``v``).
     ``state_shapes`` (``ssm_state_shapes(cfg)``) adds the zeroed recurrent
     state of ``batch`` rows."""
     if max_len % block_size:
@@ -331,8 +345,15 @@ def init_paged_cache(
             f"identity tables need {batch * mb} blocks, pool has {n}"
         )
     quantized = jnp.dtype(dtype) == jnp.int8
-    specs = paged_cache_specs_for(mesh, n_kv_heads=n_kv_heads, dtype=dtype)
-    pool_shape = (n_layers, n, block_size, n_kv_heads, head_dim)
+    latent = len(row) == 1
+    if latent and quantized:
+        raise ValueError(
+            "the latent pool is not carried in int8: its one 'head' holds "
+            "a normed latent and a rotary key, which want scales of their "
+            "own (docs/latent-cache.md)"
+        )
+    specs = paged_cache_specs_for(mesh, row=row, dtype=dtype)
+    pool_shape = (n_layers, n, block_size, *row)
 
     def put(spec, x):
         return jax.device_put(x, NamedSharding(mesh, spec))
@@ -343,7 +364,7 @@ def init_paged_cache(
         tables = jnp.full((batch, mb), table_sentinel(n), jnp.int32)
     return PagedKVCache(
         k=put(specs.k, jnp.zeros(pool_shape, dtype)),
-        v=put(specs.v, jnp.zeros(pool_shape, dtype)),
+        v=None if latent else put(specs.v, jnp.zeros(pool_shape, dtype)),
         block_tables=put(specs.block_tables, tables),
         positions=put(
             specs.positions, jnp.full((batch, max_len), -1, jnp.int32)
@@ -390,14 +411,23 @@ def gather_block_view(
     pool_layer: jax.Array,  # [N, bs, ...] one layer of the pool
     block_tables: jax.Array,  # [B, MB]
     n_blocks: int | None = None,  # read only the first n_blocks table cols
+    layer=None,  # set: ``pool_layer`` is the WHOLE pool [L, N, bs, ...]
 ) -> jax.Array:
     """Materialize a row-indirected logical view ``[B, n_blocks*bs, ...]``
     of one pool layer — the XLA gather fallback's cache operand. Sentinel
     entries clamp to a real block; their values are garbage that the
-    position mask (−1 = empty) already excludes."""
+    position mask (−1 = empty) already excludes.
+
+    With ``layer`` (a traced scalar) the whole stacked pool is indexed by
+    layer AND block in the one gather, so a layer scan that closes over the
+    pool reads the rows' blocks and never slices a layer out of it."""
     bt = block_tables if n_blocks is None else block_tables[:, :n_blocks]
-    bt = jnp.minimum(bt, pool_layer.shape[0] - 1)
-    view = pool_layer[bt]  # [B, nb, bs, ...]
+    if layer is None:
+        bt = jnp.minimum(bt, pool_layer.shape[0] - 1)
+        view = pool_layer[bt]  # [B, nb, bs, ...]
+    else:
+        bt = jnp.minimum(bt, pool_layer.shape[1] - 1)
+        view = pool_layer[layer, bt]
     return view.reshape(
         (view.shape[0], view.shape[1] * view.shape[2]) + view.shape[3:]
     )

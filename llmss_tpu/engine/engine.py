@@ -144,6 +144,29 @@ class DecodeEngine:
                 "every layer, which lives in the paged cache only: pass "
                 "kv_layout='paged' (docs/recurrent-state.md)"
             )
+        if cfg.mla is not None:
+            # The latent pool (docs/latent-cache.md) is carried by the paged
+            # layout in the compute dtype on one chip's worth of heads;
+            # what is more than that is refused by name, not served wrong.
+            from llmss_tpu.parallel.mesh import AXIS_TP
+
+            if kv_layout != "paged":
+                raise ValueError(
+                    f"model_type {cfg.model_type!r} caches a latent pool, "
+                    "which lives in the paged cache only: pass "
+                    "kv_layout='paged' (docs/latent-cache.md)"
+                )
+            if kv_dtype == "int8":
+                raise ValueError(
+                    "the latent pool is not carried in int8 "
+                    "(kv_dtype='int8'; docs/latent-cache.md)"
+                )
+            if mesh is not None and mesh.shape.get(AXIS_TP, 1) > 1:
+                raise ValueError(
+                    "the latent pool is served at tp == 1 only: nothing "
+                    "divides the experts or measures the replicated latent "
+                    "across chips yet (docs/latent-cache.md)"
+                )
         self.kv_layout = kv_layout
         self.block_size = block_size
         self.kv_blocks = kv_blocks
@@ -302,9 +325,9 @@ class DecodeEngine:
                     pool, new, cache.block_tables, slots, cache.block_size
                 )
 
-            return PagedKVCache(
+            return cache._replace(
                 k=scatter(cache.k, pk), v=scatter(cache.v, pv),
-                block_tables=cache.block_tables, positions=pos,
+                positions=pos,
                 k_scale=scatter(cache.k_scale, pks),
                 v_scale=scatter(cache.v_scale, pvs),
             )
@@ -466,16 +489,20 @@ class DecodeEngine:
         slots = jnp.where(
             done[:, None], cache.max_len, positions % cache.max_len
         )
+        aux = {}
         logits, cache = forward(
             cfg, params, tokens[:, None], positions, cache, slots,
-            last_only=True, mesh=mesh, t_bucket=t_bucket,
+            last_only=True, mesh=mesh, t_bucket=t_bucket, aux=aux,
         )
         tok = sample(logits[:, 0], counters=cur_pos + 1, **sample_args)
         tok, done, poisoned = fold_step_outcome(
             logits[:, 0], tok, done, poisoned, eos
         )
         cur_pos = cur_pos + 1
-        return (tok, cache, cur_pos, done, poisoned), tok
+        # the step's (pairs, experts_hit); None without routed experts
+        return (tok, cache, cur_pos, done, poisoned), (
+            tok, aux.get("moe_counts"),
+        )
 
     @staticmethod
     def _decode_group_impl(
@@ -514,21 +541,33 @@ class DecodeEngine:
         pin = ys_pin(mesh)
 
         def chunk(carry, _):
-            carry, toks = jax.lax.scan(body, carry, None, length=n_steps)
+            carry, (toks, moe) = jax.lax.scan(
+                body, carry, None, length=n_steps
+            )
             # Snapshot per-chunk: toks [n_steps, B] → [B, n_steps]; the
             # poison flags as of this chunk's end.
-            return carry, (pin(toks.T), pin(carry[4]))
+            return carry, (pin(toks.T), pin(carry[4]), moe)
 
         poisoned0 = jnp.zeros_like(done)
-        carry, (toks, pois) = jax.lax.scan(
+        carry, (toks, pois, moe) = jax.lax.scan(
             chunk, (tokens, cache, cur_pos, done, poisoned0), None,
             length=n_chunks,
         )
         tokens, cache, cur_pos, done, _ = carry
-        packed = jnp.concatenate(
-            [toks.reshape(-1), pois.astype(jnp.int32).reshape(-1)]
-        )
+        packed = DecodeEngine._pack_group(toks, pois, moe)
         return packed, tokens, cache, cur_pos, done
+
+    @staticmethod
+    def _pack_group(toks, pois, moe):
+        """The ONE int32 vector a group sends to the host: its tokens, its
+        per-chunk poison flags, and for a model with routed experts two
+        more numbers at the end, ``pairs`` and ``experts_hit`` summed over
+        the expert layers and the group's steps (``moe``: the steps'
+        stacked counts, None without experts)."""
+        parts = [toks.reshape(-1), pois.astype(jnp.int32).reshape(-1)]
+        if moe is not None:
+            parts.append(jnp.sum(moe.reshape(-1, 2), axis=0))
+        return jnp.concatenate(parts)
 
     @staticmethod
     def _ragged_step_body(cfg, mesh, params, sample_args, eos, carry, xs):
@@ -562,9 +601,10 @@ class DecodeEngine:
         # decode step's done-row handling (docs/paged-kv.md).
         slots = jnp.where(live, positions % cache.max_len, cache.max_len)
         kv_pos = jnp.where(live, positions, -1)
+        aux = {}
         logits, cache = forward_ragged(
             cfg, params, ids, positions, cache, slots, q_lens,
-            kv_write_positions=kv_pos, mesh=mesh,
+            kv_write_positions=kv_pos, mesh=mesh, aux=aux,
         )
         tok = sample(logits[:, 0], counters=cur_pos + q_lens, **sample_args)
         tok, done2, poisoned = fold_step_outcome(
@@ -577,7 +617,9 @@ class DecodeEngine:
         tok = jnp.where(emit, tok, tokens)
         done = jnp.where(emit, done2, done)
         cur_pos = cur_pos + q_lens
-        return (tok, cache, cur_pos, done, poisoned), tok
+        return (tok, cache, cur_pos, done, poisoned), (
+            tok, aux.get("moe_counts"),
+        )
 
     @staticmethod
     def _ragged_group_impl(
@@ -603,18 +645,16 @@ class DecodeEngine:
         pin = ys_pin(mesh)
 
         def step(carry, xs):
-            carry, tok = body(carry, xs)
-            return carry, (pin(tok), pin(carry[4]))
+            carry, (tok, moe) = body(carry, xs)
+            return carry, (pin(tok), pin(carry[4]), moe)
 
         poisoned0 = jnp.zeros_like(done)
-        carry, (toks, pois) = jax.lax.scan(
+        carry, (toks, pois, moe) = jax.lax.scan(
             step, (tokens, cache, cur_pos, done, poisoned0),
             (ids_seq, qlens_seq, feed_seq, emit_seq),
         )
         tokens, cache, cur_pos, done, _ = carry
-        packed = jnp.concatenate(
-            [toks.reshape(-1), pois.astype(jnp.int32).reshape(-1)]
-        )
+        packed = DecodeEngine._pack_group(toks, pois, moe)
         return packed, tokens, cache, cur_pos, done
 
     # -- host API -----------------------------------------------------------
@@ -824,8 +864,7 @@ class DecodeEngine:
             n_layers=self.cfg.n_layers,
             batch=b,
             max_len=self.max_seq_len,
-            n_kv_heads=self.cfg.n_kv_heads,
-            head_dim=self.cfg.head_dim,
+            row=self.cfg.cache_row,
             dtype=self._cache_dtype,
             block_size=self.block_size,
             num_blocks=num_blocks,
@@ -865,8 +904,7 @@ class DecodeEngine:
 
         if paged:
             specs = paged_cache_specs_for(
-                self.mesh, n_kv_heads=self.cfg.n_kv_heads,
-                dtype=self._cache_dtype,
+                self.mesh, row=self.cfg.cache_row, dtype=self._cache_dtype,
             )
             out = PagedKVCache(*[
                 NamedSharding(self.mesh, s) if s is not None else None
@@ -1137,7 +1175,8 @@ class DecodeEngine:
                     flat = np.asarray(packed)  # lint: ignore[host-sync-in-loop]
                 self.metrics.add_host_sync()
                 chunk_np = flat[: B * k].reshape(B, k)
-                poisoned_np = flat[B * k:].astype(bool)
+                # (a model with routed experts appends its two counts)
+                poisoned_np = flat[B * k: B * (k + 1)].astype(bool)
                 t1 = time.perf_counter()
                 self.metrics.decode_step.record((t1 - t0) / k)
                 t_cb = time.perf_counter()

@@ -195,6 +195,12 @@ class ContinuousBatcher:
             raise ValueError(
                 f"group_chunks must be >= 1, got {group_chunks}"
             )
+        if prefill_only and engine.cfg.mla is not None:
+            raise ValueError(
+                "prefill_only (the KV hand-off) does not carry the latent "
+                f"pool of model_type {engine.cfg.model_type!r}: its wire "
+                "format names keys and values (docs/latent-cache.md)"
+            )
         if prefill_only and engine.cfg.ssm is not None:
             # What does not carry a model's recurrent state refuses the
             # model, so that nothing runs and is silently wrong
@@ -300,6 +306,11 @@ class ContinuousBatcher:
             # evicted to admit new work.
             self._paged_prefixes: dict[int, tuple] = {}
             engine.metrics.set_kv_blocks(total=n_blocks, in_use=0)
+            if engine.cfg.mla is not None:
+                engine.metrics.set_latent_bytes_per_token(
+                    self.cache.k.nbytes
+                    // (self.cache.num_blocks * self.cache.block_size)
+                )
             if self.cache.ssm is not None:
                 engine.metrics.set_state_bytes(
                     self.cache.ssm.nbytes + self.cache.conv.nbytes
@@ -1389,6 +1400,11 @@ class ContinuousBatcher:
         """
         if not self._paged:
             raise ValueError("adopt requires kv_layout='paged'")
+        if self.engine.cfg.mla is not None:
+            raise ValueError(
+                "the KV hand-off does not carry a latent pool: its wire "
+                "format names keys and values (docs/latent-cache.md)"
+            )
         if self.engine.cfg.ssm is not None:
             raise ValueError(
                 "the KV hand-off does not carry a recurrent state: a row "
@@ -1506,6 +1522,11 @@ class ContinuousBatcher:
         when its row finishes served, ``park_cb`` receives the full token
         sequence (``token_ids`` + the non-replayed outputs) and the row's
         exported KV blocks. Idempotent; a no-op without ``park_cb``."""
+        if self.engine.cfg.mla is not None:
+            raise ValueError(
+                "session parking does not carry a latent pool: the tiered "
+                "store's blobs name keys and values (docs/latent-cache.md)"
+            )
         if self.engine.cfg.ssm is not None:
             raise ValueError(
                 "session parking does not carry a recurrent state "
@@ -1754,8 +1775,27 @@ class ContinuousBatcher:
         with self.loop_span("sched.callback", loop) as sp:
             live = len(self.active)
             n = self._apply_group(group, flat, sp.seq)
-            sp.set(group=group.no, tokens=n, finished=live - len(self.active))
+            sp.set(
+                group=group.no, tokens=n, finished=live - len(self.active),
+                **self._count_moe(group, flat),
+            )
         return n
+
+    def _count_moe(self, group: _InFlightGroup, flat: np.ndarray) -> dict:
+        """A model with routed experts: the group's ``pairs`` and
+        ``experts_hit`` (the last two numbers of its packed fetch,
+        engine.py: ``_pack_group``) added to /metrics' ``loop.moe`` and
+        returned as the attributes the group's ``sched.callback`` span
+        carries; nothing for any other model."""
+        cfg = self.engine.cfg
+        if cfg.moe is None:
+            return {}
+        pairs, hit = int(flat[-2]), int(flat[-1])
+        self.engine.metrics.add_moe(
+            pairs, hit,
+            (cfg.n_layers - cfg.n_lead_layers) * group.n_chunks * group.k,
+        )
+        return {"pairs": pairs, "experts_hit": hit}
 
     def _apply_group(
         self, group: _InFlightGroup, flat: np.ndarray, loop: int | None,
@@ -1764,7 +1804,9 @@ class ContinuousBatcher:
         ``sched.callback`` span): the fetched group applied chunk by chunk."""
         R, k, nc = self.rows, group.k, group.n_chunks
         toks_np = flat[: nc * R * k].reshape(nc, R, k)
-        poisoned_np = flat[nc * R * k:].reshape(nc, R).astype(bool)
+        poisoned_np = flat[nc * R * k: nc * R * (k + 1)].reshape(
+            nc, R
+        ).astype(bool)
         now = time.perf_counter()
         if self._last_fetch_t is not None and not group.has_admission:
             # Fetch-to-fetch interval — but only for groups with no

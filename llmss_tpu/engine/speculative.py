@@ -267,6 +267,12 @@ def generate_speculative(
     window runs out, the tail finishes on plain single-token steps.
 
     Records acceptance stats on ``engine.metrics.spec_stats``."""
+    if engine.cfg.mla is not None:
+        raise ValueError(
+            "speculative decoding is not carried over a latent pool: the "
+            "windowed verify step has no absorbed form yet "
+            "(docs/latent-cache.md)"
+        )
     if engine.cfg.ssm is not None:
         raise ValueError(
             "speculative decoding does not carry a recurrent state: a "

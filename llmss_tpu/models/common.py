@@ -64,6 +64,59 @@ class SSMConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2/V3) without a query
+    low-rank: keys and values of all heads are rebuilt from ONE normed
+    latent of ``kv_lora_rank`` numbers a token, and one rotary key of
+    ``qk_rope_head_dim`` numbers is shared by all heads. What is cached is
+    the latent beside the rotated key (``latent_dim`` numbers a token and
+    layer, one pool: docs/latent-cache.md); the program attends over it in
+    the absorbed form and never rebuilds a head of the context."""
+
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def latent_dim(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def pool_dim(self) -> int:
+        """Width of a token's row in the latent pool: ``latent_dim`` rounded
+        up to whole 128-lane tiles, the tail zero. A minor dimension that
+        is not a multiple of 128 (576 is 4.5 tiles) gets a default device
+        layout with ANOTHER axis minor, and every step program then
+        transposes the pool whole on its way in, through and out (compiled
+        for a described v5e); 640 keeps it row-major and costs a ninth more
+        pool."""
+        return -(-self.latent_dim // 128) * 128
+
+
+@dataclasses.dataclass(frozen=True)
+class MoEConfig:
+    """Routed experts in every layer after the first ``n_dense_layers``
+    (which keep the dense MLP of ``DecoderConfig.intermediate_size``): a
+    sigmoid router in float32, the ``top_k`` experts by score plus a
+    selection-only bias, weights renormalised over the chosen and times
+    ``routed_scaling_factor``, beside ONE shared SwiGLU of ``shared_size``
+    (ops/moe.py)."""
+
+    n_experts: int
+    top_k: int
+    expert_size: int  # moe_intermediate_size
+    shared_size: int  # n_shared_experts * moe_intermediate_size
+    n_dense_layers: int  # first_k_dense_replace
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+
+
+@dataclasses.dataclass(frozen=True)
 class DecoderConfig:
     model_type: str
     vocab_size: int
@@ -137,6 +190,13 @@ class DecoderConfig:
     mlp_multipliers: tuple[float, float] = (1.0, 1.0)
     lm_head_multiplier: float = 1.0
 
+    # deepseek_v3: latent attention (one cached latent a token instead of
+    # keys and values a head) and routed experts after a leading dense
+    # stack. None leaves the block and the tree as every other family has
+    # them.
+    mla: MLAConfig | None = None
+    moe: MoEConfig | None = None
+
     # compute dtype for activations; params are loaded in this dtype too
     dtype: str = "bfloat16"
 
@@ -167,6 +227,23 @@ class DecoderConfig:
     @property
     def kv_size(self) -> int:
         return self.n_kv_heads * self.head_dim
+
+    @property
+    def cache_row(self) -> tuple[int, ...]:
+        """Trailing shape of what ONE token holds in ONE layer of a cache
+        pool: ``(kv heads, head size)``, or ``(pool_dim,)`` for a model
+        with latent attention (the normed latent beside the shared rotary
+        key, zero-padded to whole lane tiles: one vector, no head axis)."""
+        if self.mla is not None:
+            return (self.mla.pool_dim,)
+        return (self.n_kv_heads, self.head_dim)
+
+    @property
+    def n_lead_layers(self) -> int:
+        """Layers of the leading stack (``params["lead"]``): the dense
+        layers before the expert stack; 0 for a model of one kind of
+        layer."""
+        return 0 if self.moe is None else self.moe.n_dense_layers
 
 
 def act_fn(name: str):

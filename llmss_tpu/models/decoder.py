@@ -48,7 +48,7 @@ from llmss_tpu.ops.attention import (
     window_mask_penalty,
 )
 from llmss_tpu.ops.layers import (
-    LinearParams, NormParams, dense, dense_t, embedding,
+    LinearParams, NormParams, dense, dense_t, embedding, rms_norm,
 )
 from llmss_tpu.ops.rope import apply_rope, sin_cos_tables
 from llmss_tpu.ops.ssm import causal_conv, ssd_scan, ssm_step
@@ -110,12 +110,40 @@ def param_specs(cfg: DecoderConfig, tp: int) -> Params:
             w=P(None, AXIS_TP, None), b=P(None) if cfg.o_bias else None
         ),
     }
+    if cfg.mla is not None:
+        # Latent attention: keys and values come from one latent, so the
+        # two projections give way to the latent's down- and up-projection
+        # and its norm. Served at tp == 1 only (DecodeEngine refuses more):
+        # the up-projection carries the head split a later mesh would use,
+        # the one latent "head" replicates as MQA's does.
+        del blocks["k"], blocks["v"]
+        blocks["kv_a"] = LinearParams(w=P(None, None, None), b=None)
+        blocks["kv_norm"] = _norm_specs(True, False)
+        blocks["kv_b"] = LinearParams(w=P(None, None, AXIS_TP), b=None)
     if cfg.has_ln2:
         blocks["ln2"] = _norm_specs(True, norm_bias)
-    if cfg.mlp == "swiglu":
-        blocks["gate"] = LinearParams(w=P(None, None, AXIS_TP), b=None)
-        blocks["up"] = LinearParams(w=P(None, None, AXIS_TP), b=None)
-        blocks["down"] = LinearParams(w=P(None, AXIS_TP, None), b=None)
+    swiglu = {
+        "gate": LinearParams(w=P(None, None, AXIS_TP), b=None),
+        "up": LinearParams(w=P(None, None, AXIS_TP), b=None),
+        "down": LinearParams(w=P(None, AXIS_TP, None), b=None),
+    }
+    lead = None
+    if cfg.moe is not None:
+        # Two kinds of layer, two stacks (``_forward_latent``): the leading
+        # dense layers keep the SwiGLU, the rest hold the router, the
+        # stacked experts (replicated: no mesh axis divides them yet) and
+        # the shared expert.
+        lead = {**blocks, **swiglu}
+        rep3, rep4 = P(None, None, None), P(None, None, None, None)
+        blocks.update({
+            "router": LinearParams(w=rep3, b=P(None, None)),
+            "experts_gate": rep4, "experts_up": rep4, "experts_down": rep4,
+            "shared_gate": LinearParams(w=rep3, b=None),
+            "shared_up": LinearParams(w=rep3, b=None),
+            "shared_down": LinearParams(w=rep3, b=None),
+        })
+    elif cfg.mlp == "swiglu":
+        blocks.update(swiglu)
     else:
         blocks["fc_in"] = LinearParams(
             w=P(None, None, AXIS_TP),
@@ -144,6 +172,8 @@ def param_specs(cfg: DecoderConfig, tp: int) -> Params:
         "blocks": blocks,
         "ln_f": _norm_specs(False, norm_bias),
     }
+    if lead is not None:
+        specs["lead"] = lead
     if cfg.positions == "learned":
         specs["wpe"] = P(AXIS_TP, None)
     if not cfg.tie_word_embeddings:
@@ -170,7 +200,12 @@ def init_params(cfg: DecoderConfig, mesh, key) -> Params:
         treedef, list(jax.random.split(key, len(leaves)))
     )
 
-    draw = _ssm_family_draw(cfg) if cfg.ssm is not None else {}
+    if cfg.ssm is not None:
+        draw = _ssm_family_draw(cfg)
+    elif cfg.moe is not None:
+        draw = _routed_family_draw(cfg)
+    else:
+        draw = {}
 
     def _leaf(path, sds, k):
         name = next(
@@ -236,6 +271,87 @@ def _ssm_family_draw(cfg: DecoderConfig) -> dict:
     }
 
 
+def routing_block(cfg: DecoderConfig) -> int:
+    """Width of the leading block of the hidden dimensions that the seeded
+    draw of a model with routed experts keeps for routing (see
+    ``_routed_family_draw``): an eighth of the hidden size."""
+    return cfg.hidden_size // 8
+
+
+def _routed_family_draw(cfg: DecoderConfig) -> dict:
+    """The seeded draw of a family with routed experts, leaf by leaf (as
+    ``_ssm_family_draw`` is for a mixer). Top-k routing is discontinuous: the
+    6th and 7th of 128 random scores lie a few hundredths of a deviation
+    apart, so the rounding of a bfloat16 residual flips a choice now and
+    then, and one flip swaps a sixth of a layer's routed output: far above
+    any tolerance on the logits, and no fault of the program. So the draw
+    makes the router's input the same numbers on both sides of a comparison:
+
+    - the first ``routing_block(cfg)`` hidden dimensions hold the token's
+      embedding (drawn N(0, 1) there, N(0, 0.02) elsewhere) and NOTHING
+      else: every matrix that writes the residual (attention's output, the
+      dense, shared and routed down-projections) has zeros in those output
+      columns, so the block is carried unchanged, exactly, in any dtype;
+    - the router reads that block alone (zeros elsewhere), and reads the
+      normed input in float32 before it is rounded (``_latent_block``), so its
+      scores differ between dtypes by RMSNorm's one positive factor a
+      token, which reorders nothing.
+
+    Routing is then a fixed pseudo-random function of the token id: uniform
+    over the experts under random ids, ``top_k`` distinct experts a token.
+    Attention, the experts and the shared expert read all of the residual
+    and write the rest of it; every expert's arithmetic and every byte a
+    step reads are as with any other weights. The selection bias is drawn
+    N(0, 0.02), not zero, so selection and weighting differ as published,
+    and SMALL: the chosen scores lie within a few hundredths of each other,
+    and a bias of N(0, 0.1) sent 42% of the tokens to a tenth of the experts
+    (93 of 128 hit by 155 tokens where uniform routing hits all; at 0.02,
+    127: CPU, PR 38), which would falsify the bytes a step reads. Norm
+    scales, not named here, stay N(0, 0.02) (the benchmark's server and the
+    tests add 1)."""
+    R = routing_block(cfg)
+    f32 = jnp.float32
+
+    def normal(c, writes=False, transposed=False):
+        """N(0, (c / sqrt(fan_in))^2) on ``[.., in, out]`` (``transposed``:
+        ``[.., out, in]``); ``writes``: a matrix that writes the residual,
+        its routing-block OUTPUT columns at zero."""
+        def draw(k, shape):
+            fan_in = shape[-1 if transposed else -2]
+            w = jax.random.normal(k, shape, f32) * (c / fan_in ** 0.5)
+            return w.at[..., :R].set(0.0) if writes else w
+        return draw
+
+    def router(k, shape):
+        if len(shape) == 2:  # the selection bias [L, N]
+            return jax.random.normal(k, shape, f32) * 0.02
+        # [L, N, E]: scores of deviation near 1 from the block alone
+        w = jax.random.normal(k, shape, f32) / R ** 0.5
+        return w.at[..., R:].set(0.0)
+
+    def wte(k, shape):
+        w = jax.random.normal(k, shape, f32)
+        return w.at[:, R:].multiply(0.02)
+
+    # c = 0.02 x sqrt(fan_in) at kanana-2-30b-a3b's widths, so that the
+    # deployment's weights are N(0, 0.02) as every other family's are and a
+    # small model (tests) behaves like it; but the queries at 2 (scores of a
+    # deviation near 1.3: a wrong scale or rotation shows) and attention's
+    # output at 2.5.
+    return {
+        "wte": wte, "router": router,
+        "q": normal(2.0, transposed=True), "kv_a": normal(0.9),
+        "kv_b": normal(0.45), "o": normal(2.5, writes=True),
+        "gate": normal(0.9), "up": normal(0.9),
+        "down": normal(1.57, writes=True),
+        "experts_gate": normal(0.9), "experts_up": normal(0.9),
+        "experts_down": normal(0.55, writes=True),
+        "shared_gate": normal(0.9), "shared_up": normal(0.9),
+        "shared_down": normal(0.78, writes=True),
+        "head": normal(0.9),
+    }
+
+
 def param_shapes(cfg: DecoderConfig) -> Params:
     """ShapeDtypeStruct pytree of the full parameter set."""
     L, E, V = cfg.n_layers, cfg.hidden_size, cfg.vocab_size
@@ -246,26 +362,72 @@ def param_shapes(cfg: DecoderConfig) -> Params:
     def sds(*shape):
         return jax.ShapeDtypeStruct(shape, dt)
 
-    def norm_shape(stacked):
-        lead = (L,) if stacked else ()
+    def norm_shape(n):
+        """``n`` stacked layers; None: the final norm."""
+        lead = () if n is None else (n,)
         return NormParams(
             scale=sds(*lead, E), bias=sds(*lead, E) if norm_bias else None
         )
 
-    blocks: Params = {
-        "ln1": norm_shape(True),
-        # q/k transposed storage [L, out, in] (see param_specs).
-        "q": LinearParams(sds(L, Q, E), sds(L, Q) if cfg.attn_bias else None),
-        "k": LinearParams(sds(L, KV, E), sds(L, KV) if cfg.attn_bias else None),
-        "v": LinearParams(sds(L, E, KV), sds(L, KV) if cfg.attn_bias else None),
-        "o": LinearParams(sds(L, Q, E), sds(L, E) if cfg.o_bias else None),
-    }
+    def attn_shapes(n):
+        if cfg.mla is not None:
+            m, H = cfg.mla, cfg.n_heads
+            return {
+                "ln1": norm_shape(n),
+                "q": LinearParams(sds(n, H * m.qk_head_dim, E), None),
+                "kv_a": LinearParams(sds(n, E, m.latent_dim), None),
+                "kv_norm": NormParams(sds(n, m.kv_lora_rank), None),
+                "kv_b": LinearParams(
+                    sds(n, m.kv_lora_rank,
+                        H * (m.qk_nope_head_dim + m.v_head_dim)), None
+                ),
+                "o": LinearParams(sds(n, H * m.v_head_dim, E), None),
+            }
+        return {
+            "ln1": norm_shape(n),
+            # q/k transposed storage [L, out, in] (see param_specs).
+            "q": LinearParams(
+                sds(n, Q, E), sds(n, Q) if cfg.attn_bias else None),
+            "k": LinearParams(
+                sds(n, KV, E), sds(n, KV) if cfg.attn_bias else None),
+            "v": LinearParams(
+                sds(n, E, KV), sds(n, KV) if cfg.attn_bias else None),
+            "o": LinearParams(sds(n, Q, E), sds(n, E) if cfg.o_bias else None),
+        }
+
+    def swiglu_shapes(n):
+        return {
+            "gate": LinearParams(sds(n, E, I), None),
+            "up": LinearParams(sds(n, E, I), None),
+            "down": LinearParams(sds(n, I, E), None),
+        }
+
+    n_lead = cfg.n_lead_layers
+    L = L - n_lead  # the main stack; the leading dense layers are their own
+    blocks: Params = attn_shapes(L)
     if cfg.has_ln2:
-        blocks["ln2"] = norm_shape(True)
-    if cfg.mlp == "swiglu":
-        blocks["gate"] = LinearParams(sds(L, E, I), None)
-        blocks["up"] = LinearParams(sds(L, E, I), None)
-        blocks["down"] = LinearParams(sds(L, I, E), None)
+        blocks["ln2"] = norm_shape(L)
+    lead = None
+    if cfg.moe is not None:
+        x = cfg.moe
+        lead = {
+            **attn_shapes(n_lead), "ln2": norm_shape(n_lead),
+            **swiglu_shapes(n_lead),
+        }
+        blocks.update({
+            # [experts, hidden] as published; the selection bias beside it
+            "router": LinearParams(
+                sds(L, x.n_experts, E), sds(L, x.n_experts)
+            ),
+            "experts_gate": sds(L, x.n_experts, E, x.expert_size),
+            "experts_up": sds(L, x.n_experts, E, x.expert_size),
+            "experts_down": sds(L, x.n_experts, x.expert_size, E),
+            "shared_gate": LinearParams(sds(L, E, x.shared_size), None),
+            "shared_up": LinearParams(sds(L, E, x.shared_size), None),
+            "shared_down": LinearParams(sds(L, x.shared_size, E), None),
+        })
+    elif cfg.mlp == "swiglu":
+        blocks.update(swiglu_shapes(L))
     else:
         blocks["fc_in"] = LinearParams(
             sds(L, E, I), sds(L, I) if cfg.mlp_bias else None
@@ -288,8 +450,10 @@ def param_shapes(cfg: DecoderConfig) -> Params:
         blocks["ssm_out"] = LinearParams(sds(L, m.d_ssm, E), None)
 
     shapes: Params = {
-        "wte": sds(V, E), "blocks": blocks, "ln_f": norm_shape(False)
+        "wte": sds(V, E), "blocks": blocks, "ln_f": norm_shape(None)
     }
+    if lead is not None:
+        shapes["lead"] = lead
     if cfg.positions == "learned":
         shapes["wpe"] = sds(cfg.max_position_embeddings, E)
     if not cfg.tie_word_embeddings:
@@ -302,11 +466,14 @@ def param_shapes(cfg: DecoderConfig) -> Params:
 # -- forward ------------------------------------------------------------------
 
 
-def _norm(cfg: DecoderConfig, x, p: NormParams):
+def _norm(cfg: DecoderConfig, x, p: NormParams, out_dtype=None):
     from llmss_tpu.ops.layers import layer_norm, rms_norm
 
     if cfg.norm == "rmsnorm":
-        return rms_norm(x, p, cfg.norm_eps, cfg.norm_scale_offset)
+        return rms_norm(
+            x, p, cfg.norm_eps, cfg.norm_scale_offset, out_dtype=out_dtype
+        )
+    assert out_dtype is None
     return layer_norm(x, p, cfg.norm_eps)
 
 
@@ -328,6 +495,77 @@ def _mlp(cfg: DecoderConfig, bp: Params, x):
             dense(act(gate) * dense(x, bp["up"]), bp["down"]), m_out
         )
     return dense(act(dense(x, bp["fc_in"])), bp["fc_out"])
+
+
+def _routed_mlp(cfg: DecoderConfig, bp: Params, x, x32, live, experts):
+    """The expert layer: ``x`` [B, S, E] the block's normed input, ``x32``
+    the same before it was rounded to the compute dtype (what the router
+    reads), ``live`` [B, S] which tokens are real, ``experts`` the stacked
+    experts of ALL expert layers and this layer's index among them (the
+    grouped matmul reads the layer's weights in place: ops/moe.py).
+    Returns the layer's output and ``(pairs, experts_hit)`` int32 [2]."""
+    from llmss_tpu.ops import moe
+
+    m = cfg.moe
+    B, S, E = x.shape
+    act = act_fn(cfg.activation)
+    flat = x.reshape(B * S, E)
+    with jax.named_scope("moe.route"):
+        idx, w = moe.route(
+            x32.reshape(B * S, E), bp["router"].w, bp["router"].b,
+            top_k=m.top_k, norm=m.norm_topk_prob,
+            scale=m.routed_scaling_factor,
+        )
+    with jax.named_scope("moe.experts"):
+        stacks, layer = experts
+        y, counts = moe.routed_experts(
+            flat, idx, w, live.reshape(B * S), stacks["experts_gate"],
+            stacks["experts_up"], stacks["experts_down"], act, layer=layer,
+        )
+    with jax.named_scope("moe.shared"):
+        shared = dense(
+            act(dense(x, bp["shared_gate"])) * dense(x, bp["shared_up"]),
+            bp["shared_down"],
+        )
+    return y.reshape(B, S, E) + shared, counts
+
+
+def _latent_attention(cfg: DecoderConfig, bp: Params, x, positions, sin_cos,
+                      attend):
+    """Multi-head latent attention in the ABSORBED form: ``x`` [B, S, E] the
+    block's normed input; ``attend(q [B, S, H, W], latent [B, S, 1, W]) ->
+    [B, S, H, W]`` (W: C + R padded to the pool's row) attention of the
+    queries over the cached latents and these fresh ones, keys and values
+    both the latent itself (multi-query attention with one shared head).
+    Returns the branch's output [B, S, E] and the fresh latent to cache.
+
+    ``W_kv_b`` is split a head into ``W_uk`` and ``W_uv``; the query meets
+    ``W_uk`` before the cache and the weighted sum of latents meets ``W_uv``
+    after it, so no head's keys or values of the context are ever rebuilt:
+    ``q_nope . (c W_uk) == (q_nope W_uk^T) . c``."""
+    m, H = cfg.mla, cfg.n_heads
+    B, S, _ = x.shape
+    C, Dn, Dv = m.kv_lora_rank, m.qk_nope_head_dim, m.v_head_dim
+    q = dense_t(x, bp["q"]).reshape(B, S, H, m.qk_head_dim)
+    q = constrain(q, P(AXIS_DP, None, AXIS_TP, None))
+    kv = dense(x, bp["kv_a"])  # [B, S, C + R]
+    c_kv = rms_norm(kv[..., :C], bp["kv_norm"], cfg.norm_eps)
+    rope = partial(
+        apply_rope, positions=positions, theta=cfg.rope_theta,
+        style=cfg.rope_style, sin_cos=sin_cos,
+    )
+    q_rope = rope(q[..., Dn:])
+    k_rope = rope(kv[..., None, C:])  # ONE rotary key, shared by all heads
+    w_kv = bp["kv_b"].w.astype(x.dtype).reshape(C, H, Dn + Dv)
+    q_lat = jnp.einsum("bshd,chd->bshc", q[..., :Dn], w_kv[..., :Dn])
+    # both padded with zeros to the pool's row (``MLAConfig.pool_dim``):
+    # the scores gain zeros, the weighted sum columns nobody reads
+    pad = [jnp.zeros((B, S, n, m.pool_dim - m.latent_dim), x.dtype)
+           for n in (1, H)]
+    latent = jnp.concatenate([c_kv[:, :, None, :], k_rope, pad[0]], axis=-1)
+    o_lat = attend(jnp.concatenate([q_lat, q_rope, pad[1]], axis=-1), latent)
+    o = jnp.einsum("bshc,chd->bshd", o_lat[..., :C], w_kv[..., Dn:])
+    return dense(o.reshape(B, S, H * Dv), bp["o"]), latent
 
 
 def _mixer(cfg: DecoderConfig, bp: Params, x, ssm, conv, lens):
@@ -384,6 +622,29 @@ def _mixer(cfg: DecoderConfig, bp: Params, x, ssm, conv, lens):
     y = yg.reshape(B, S, m.d_ssm) * bp["ssm_norm"].scale.astype(f32)
     out = _scale(dense(y.astype(x.dtype), bp["ssm_out"]), m.out_multiplier)
     return out, (ssm, conv)
+
+
+def _latent_block(cfg: DecoderConfig, bp: Params, h, positions, sin_cos,
+                  attend, live, experts, mesh=None):
+    """One block of a model with latent attention: pre-norm, sequential
+    residual; the MLP is the dense one, or the routed experts where the
+    layer holds a router. ``attend`` as ``_latent_attention`` takes it,
+    ``live`` [B, S] which tokens are real, ``experts`` as ``_routed_mlp``
+    takes them. Returns ``(h, fresh latent, routing counts or None)``."""
+    spec = P(AXIS_DP, _seq_axis(mesh, h.shape[1]), None)
+    attn, latent = _latent_attention(
+        cfg, bp, _norm(cfg, h, bp["ln1"]), positions, sin_cos, attend
+    )
+    h = h + constrain(attn, spec)
+    # the router reads the normed input before its rounding
+    x32 = _norm(cfg, h, bp["ln2"], jnp.float32)
+    x = x32.astype(h.dtype)
+    counts = None
+    if "router" in bp:
+        mlp, counts = _routed_mlp(cfg, bp, x, x32, live, experts)
+    else:
+        mlp = _mlp(cfg, bp, x)
+    return constrain(h + mlp, spec), latent, counts
 
 
 def _block(
@@ -744,6 +1005,9 @@ def forward(
     # "no_scatter" drops the deferred decode write (tests/test_ring.py's
     # receipt that an sp>1 mesh takes the deferred path); dense ring only
     _ablate: str | None = None,
+    # out-parameter for traced by-products of the call, read by the caller
+    # in the same trace: ``aux["moe_counts"]`` (a model with routed experts)
+    aux: dict | None = None,
 ) -> tuple[jax.Array, KVCache]:
     """Run the decoder; returns (logits fp32, updated cache).
 
@@ -773,9 +1037,14 @@ def forward(
             cfg, params, input_ids, positions, cache, slots,
             last_only=last_only, gather_idx=gather_idx,
             kv_write_positions=kv_write_positions, mesh=mesh,
-            t_bucket=t_bucket,
+            t_bucket=t_bucket, aux=aux,
         )
 
+    if cfg.mla is not None:
+        raise NotImplementedError(
+            "a model with latent attention is served from the paged cache "
+            "only (kv_layout='paged'): the dense ring has no latent pool"
+        )
     if cfg.ssm is not None:
         raise NotImplementedError(
             "a model with a recurrent state is served from the paged cache "
@@ -1110,6 +1379,7 @@ def _forward_paged(
     kv_write_positions: jax.Array | None = None,
     mesh=None,
     t_bucket: int | None = None,
+    aux: dict | None = None,
 ) -> tuple[jax.Array, PagedKVCache]:
     """``forward`` over the paged block-pool cache (``kv_layout="paged"``).
 
@@ -1129,6 +1399,13 @@ def _forward_paged(
     meshes and the speculative window-defer path are dense-only for now:
     S in (1, 8] routes through the general prefill branch here.
     """
+    if cfg.mla is not None:
+        return _forward_latent(
+            cfg, params, input_ids, positions, cache, slots,
+            last_only=last_only, gather_idx=gather_idx,
+            kv_write_positions=kv_write_positions, mesh=mesh,
+            t_bucket=t_bucket, aux=aux,
+        )
     dtype = cfg.compute_dtype
     h = _embed_in(cfg, params, input_ids, positions, mesh)
 
@@ -1305,6 +1582,142 @@ def _forward_paged(
     )
 
 
+def _forward_latent(
+    cfg: DecoderConfig,
+    params: Params,
+    input_ids: jax.Array,  # [B, S]
+    positions: jax.Array,  # [B, S]
+    cache: PagedKVCache,  # a latent pool: ``v`` is None
+    slots: jax.Array,  # [B, S] LOGICAL slots
+    *,
+    q_lens: jax.Array | None = None,  # [B]: the call is a mixed step
+    last_only: bool = False,
+    gather_idx: jax.Array | None = None,
+    kv_write_positions: jax.Array | None = None,
+    mesh=None,
+    t_bucket: int | None = None,
+    aux: dict | None = None,
+) -> tuple[jax.Array, PagedKVCache]:
+    """The paged forwards of a model with latent attention (``cfg.mla``):
+    prefill, the decode step (S == 1) and the mixed step (``q_lens`` set),
+    under the callers' contract of ``_forward_paged`` / ``forward_ragged``.
+
+    One discipline for all three: the layer scans close over the stale pool
+    and READ it (each layer gathers its rows' blocks by layer and block in
+    one gather, ``gather_block_view(layer=)``), every layer hands back its
+    fresh latents, and ONE ``paged_write_stacked`` after the scans writes
+    them. Decode and the mixed step attend over the stale view merged with
+    the fresh latent in one softmax (``paged_decode_attention`` /
+    ``ragged_paged_attention`` with keys and values the same pool); prefill
+    writes its fresh latents into the gathered view (a temporary of the
+    admitted rows, not the pool) and attends write-then-read through
+    ``dispatch_attention``. All in the absorbed form (``_latent_attention``).
+
+    Two kinds of layer: the leading dense stack (``params["lead"]``, layers
+    ``[0, cfg.n_lead_layers)``) and the main stack run as two scans in order
+    over the ONE pool, whose layer axis spans both. Tokens that are not real
+    (padding, done rows: no recorded position, or a slot out of range) are
+    routed nowhere; the expert layers' ``(pairs, experts_hit)``, summed, are
+    left in ``aux["moe_counts"]``."""
+    h = _embed_in(cfg, params, input_ids, positions, mesh)
+    if kv_write_positions is None:
+        kv_write_positions = positions
+    new_kv_positions = write_positions(
+        cache.positions, kv_write_positions, slots
+    )
+    B, S = input_ids.shape
+    bs, MB = cache.block_size, cache.max_blocks
+    # the pool seen with the one "head" the attention functions expect
+    pool, tables = cache.k[:, :, :, None, :], cache.block_tables
+    live = (kv_write_positions >= 0) & (slots < cache.max_len)
+    sin_cos = sin_cos_tables(
+        positions, cfg.mla.qk_rope_head_dim, cfg.rope_theta
+    )
+    nb = None
+    if t_bucket is not None and t_bucket < cache.max_len:
+        nb = min(-(-t_bucket // bs), MB)
+    kv_pos_src = cache.positions[:, : (nb if nb is not None else MB) * bs]
+
+    if q_lens is not None:
+        scope = "mla.decode"
+        q_pos0, slot0 = positions[:, 0], slots[:, 0]
+        cache_vis = ragged_cache_visibility(
+            q_lens, kv_pos_src, slot0, cache.max_len
+        )
+
+        def attend(layer, q, lat):
+            return ragged_paged_attention(
+                q, pool, pool, lat, lat, q_pos0, q_lens, kv_pos_src, tables,
+                slot0, cache.max_len, scale=cfg.attn_scale,
+                cache_vis=cache_vis, n_blocks=nb, layer=layer,
+            )
+    elif S == 1:
+        scope = "mla.decode"
+        penalty = decode_mask_penalty(positions, kv_pos_src, slots, None)
+
+        def attend(layer, q, lat):
+            return paged_decode_attention(
+                q, pool, pool, lat, lat, positions, kv_pos_src, tables, slots,
+                scale=cfg.attn_scale, penalty=penalty, n_blocks=nb,
+                layer=layer,
+            )
+    else:
+        scope = "mla.prefill"
+        mask = make_causal_mask(
+            positions, new_kv_positions, new_kv_positions >= 0
+        )
+        b_idx = jnp.arange(B, dtype=jnp.int32)[:, None]
+
+        def attend(layer, q, lat):
+            view = gather_block_view(pool, tables, layer=layer)
+            view = view.at[b_idx, slots].set(lat.astype(view.dtype))
+            return dispatch_attention(
+                q, view, view, mask=mask, q_positions=positions,
+                kv_positions=new_kv_positions, scale=cfg.attn_scale,
+                mesh=mesh,
+            )
+
+    n_lead = cfg.n_lead_layers
+    # The stacked experts stay out of the scan's xs: the scan would copy a
+    # layer's slice out of the stack for the grouped matmul's kernel.
+    experts = {
+        k: v for k, v in params["blocks"].items() if k.startswith("experts_")
+    }
+    stack = {k: v for k, v in params["blocks"].items() if k not in experts}
+
+    def body(h, xs):
+        bp, layer = xs
+
+        def attn(q, lat):
+            with jax.named_scope(scope):
+                return attend(layer, q, lat)
+
+        h, latent, moe_counts = _latent_block(
+            cfg, bp, h, positions, sin_cos, attn, live,
+            (experts, layer - n_lead), mesh=mesh,
+        )
+        return h, (latent, moe_counts)
+
+    layers = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+    fresh, counts = [], None
+    if n_lead:
+        h, (lat, _) = jax.lax.scan(
+            body, h, (params["lead"], layers[:n_lead])
+        )
+        fresh.append(lat)
+    h, (lat, per_layer) = jax.lax.scan(body, h, (stack, layers[n_lead:]))
+    fresh.append(lat)
+    if per_layer is not None:
+        counts = jnp.sum(per_layer, axis=0)
+    if aux is not None:
+        aux["moe_counts"] = counts
+    pool = paged_write_stacked(
+        cache.k, jnp.concatenate(fresh, axis=0)[:, :, :, 0], tables, slots, bs
+    )
+    logits = _head_out(cfg, params, h, gather_idx, last_only)
+    return logits, cache._replace(k=pool, positions=new_kv_positions)
+
+
 def _make_ragged_kernel_attn(
     cfg, mesh, cache, positions0, q_lens, slot0, nblk,
 ):
@@ -1392,6 +1805,7 @@ def forward_ragged(
     kv_write_positions: jax.Array | None = None,  # [B, CB]; -1 = no write
     mesh=None,
     t_bucket: int | None = None,
+    aux: dict | None = None,
 ) -> tuple[jax.Array, PagedKVCache]:
     """Mixed prefill+decode forward over the paged pool: every row carries
     a ``CB``-token query chunk of which the first ``q_lens[b]`` are live —
@@ -1411,6 +1825,12 @@ def forward_ragged(
     (slots carry ``max_len``, positions −1) and their hidden states are
     never gathered.
     """
+    if cfg.mla is not None:
+        return _forward_latent(
+            cfg, params, input_ids, positions, cache, slots, q_lens=q_lens,
+            gather_idx=q_lens - 1, kv_write_positions=kv_write_positions,
+            mesh=mesh, t_bucket=t_bucket, aux=aux,
+        )
     dtype = cfg.compute_dtype
     del dtype  # same compute-dtype flow as _forward_paged via _block
     h = _embed_in(cfg, params, input_ids, positions, mesh)

@@ -13,8 +13,8 @@ from pathlib import Path
 from jax.sharding import Mesh
 
 from llmss_tpu.models import (
-    falcon_h1, gemma, gpt2, gpt_bigcode, gpt_neox, gptj, llama, mistral, phi3,
-    qwen2,
+    deepseek_v3, falcon_h1, gemma, gpt2, gpt_bigcode, gpt_neox, gptj, llama,
+    mistral, phi3, qwen2,
 )
 from llmss_tpu.models.common import DecoderConfig
 from llmss_tpu.models.decoder import Params
@@ -31,6 +31,7 @@ MODEL_REGISTRY = {
     "phi3": phi3,
     "gemma": gemma,
     "falcon_h1": falcon_h1,
+    "deepseek_v3": deepseek_v3,
 }
 
 

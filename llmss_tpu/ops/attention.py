@@ -381,6 +381,17 @@ def fresh_kv_decode_attention(
     )
 
 
+def _gather_kv(k_pool, v_pool, block_tables, n_blocks, layer):
+    """The rows' logical views of keys and of values; ONE gather where both
+    are the same pool (a latent pool: values are columns of the keys)."""
+    from llmss_tpu.engine.cache import gather_block_view
+
+    k_view = gather_block_view(k_pool, block_tables, n_blocks, layer)
+    if v_pool is k_pool:
+        return k_view, k_view
+    return k_view, gather_block_view(v_pool, block_tables, n_blocks, layer)
+
+
 def paged_decode_attention(
     q: jax.Array,  # [B, 1, Hq, D]
     k_pool_layer: jax.Array,  # [N, bs, Hkv, D] — one layer of the block pool
@@ -398,6 +409,7 @@ def paged_decode_attention(
     k_scale_layer: jax.Array | None = None,  # [N, bs, Hkv] f32 iff int8
     v_scale_layer: jax.Array | None = None,
     n_blocks: int | None = None,  # bucketed read: first n_blocks table cols
+    layer=None,  # set: the pools are the WHOLE stacked pools, read at layer
 ) -> jax.Array:
     """Paged decode attention, XLA gather fallback: materialize the
     row-indirected logical view of one pool layer (``gather_block_view``)
@@ -408,8 +420,9 @@ def paged_decode_attention(
     and the implementation ``LLMSS_ATTN_IMPL`` A/B tests compare with."""
     from llmss_tpu.engine.cache import gather_block_view
 
-    k_view = gather_block_view(k_pool_layer, block_tables, n_blocks)
-    v_view = gather_block_view(v_pool_layer, block_tables, n_blocks)
+    k_view, v_view = _gather_kv(
+        k_pool_layer, v_pool_layer, block_tables, n_blocks, layer
+    )
     ks = vs = None
     if k_scale_layer is not None:
         ks = gather_block_view(k_scale_layer, block_tables, n_blocks)
@@ -551,6 +564,7 @@ def ragged_paged_attention(
     k_scale_layer: jax.Array | None = None,  # [N, bs, Hkv] f32 iff int8
     v_scale_layer: jax.Array | None = None,
     n_blocks: int | None = None,  # bucketed read: first n_blocks table cols
+    layer=None,  # set: the pools are the WHOLE stacked pools, read at layer
 ) -> jax.Array:
     """Ragged chunked attention, XLA gather fallback: materialize the
     row-indirected logical view of one pool layer (``gather_block_view``)
@@ -559,8 +573,9 @@ def ragged_paged_attention(
     path mixed batches take when the kernel envelope doesn't apply."""
     from llmss_tpu.engine.cache import gather_block_view
 
-    k_view = gather_block_view(k_pool_layer, block_tables, n_blocks)
-    v_view = gather_block_view(v_pool_layer, block_tables, n_blocks)
+    k_view, v_view = _gather_kv(
+        k_pool_layer, v_pool_layer, block_tables, n_blocks, layer
+    )
     ks = vs = None
     if k_scale_layer is not None:
         ks = gather_block_view(k_scale_layer, block_tables, n_blocks)

@@ -114,14 +114,17 @@ def layer_norm(x: jax.Array, p: NormParams, eps: float) -> jax.Array:
 
 
 def rms_norm(
-    x: jax.Array, p: NormParams, eps: float, scale_offset: float = 0.0
+    x: jax.Array, p: NormParams, eps: float, scale_offset: float = 0.0,
+    out_dtype=None,
 ) -> jax.Array:
     """RMSNorm (Llama-family; no reference equivalent — new capability).
-    ``scale_offset`` implements Gemma's (1 + weight) parameterization."""
+    ``scale_offset`` implements Gemma's (1 + weight) parameterization;
+    ``out_dtype`` (default: x's) keeps the float32 result for a reader that
+    must not see the rounding (a router)."""
     xf = x.astype(jnp.float32)
     y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
     y = y * (p.scale.astype(jnp.float32) + scale_offset)
-    return y.astype(x.dtype)
+    return y.astype(x.dtype if out_dtype is None else out_dtype)
 
 
 # -- spec builders ------------------------------------------------------------

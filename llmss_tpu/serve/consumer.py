@@ -455,6 +455,15 @@ class ContinuousWorker:
 
         if role not in ("unified", "prefill", "decode"):
             raise ValueError(f"unknown worker role: {role!r}")
+        if getattr(getattr(engine, "cfg", None), "mla", None) is not None and (
+            role != "unified" or kvstore is not None
+        ):
+            raise ValueError(
+                "a model with a latent pool is served by a unified worker "
+                "without a tiered KV store: the hand-off's wire format and "
+                "the store's blobs name keys and values "
+                "(docs/latent-cache.md)"
+            )
         if getattr(getattr(engine, "cfg", None), "ssm", None) is not None and (
             role != "unified" or kvstore is not None
         ):

@@ -160,6 +160,15 @@ class EngineMetrics:
         # pool beside the paged keys and values (a gauge; None = the model
         # has none and /metrics has no ``cache`` block).
         self.cache_state_bytes: int | None = None  # guarded_by: self._lock
+        # A model with latent attention (cfg.mla): the bytes ONE token holds
+        # in the latent pool over all layers (a gauge in the same block).
+        self.cache_latent_bytes_per_token: int | None = None  # guarded_by: self._lock
+        # A model with routed experts (cfg.moe), cumulative like
+        # ``decode_steps``: live (token, expert) pairs, experts with at
+        # least one live token (summed over expert layers and steps), and
+        # expert layers x steps, of the groups FETCHED so far (the counts
+        # come with a group's packed fetch). None = the model has none.
+        self.moe_counts: list[int] | None = None  # guarded_by: self._lock
         self._start = time.monotonic()
 
     def add_tokens(self, n: int) -> None:
@@ -267,6 +276,18 @@ class EngineMetrics:
         with self._lock:
             self.cache_state_bytes = n
 
+    def set_latent_bytes_per_token(self, n: int) -> None:
+        with self._lock:
+            self.cache_latent_bytes_per_token = n
+
+    def add_moe(self, pairs: int, experts_hit: int, layer_steps: int) -> None:
+        """A fetched group's routing counts (engine.py: ``_pack_group``)."""
+        with self._lock:
+            acc = self.moe_counts or [0, 0, 0]
+            self.moe_counts = [
+                acc[0] + pairs, acc[1] + experts_hit, acc[2] + layer_steps,
+            ]
+
     def add_loop_span(self, name: str, seconds: float) -> None:
         """A loop span closed (``trace.loop_span(on_close=...)``)."""
         with self._lock:
@@ -308,9 +329,19 @@ class EngineMetrics:
                     for name, (s, n) in sorted(self.loop_spans.items())
                 },
             }
-            state = {} if self.cache_state_bytes is None else {
-                "cache": {"state_bytes": self.cache_state_bytes},
+            if self.moe_counts is not None:
+                # flat, like every counter of the block: a reader takes the
+                # difference of two reads key by key
+                loop.update(zip(
+                    ("moe.pairs", "moe.experts_hit", "moe.layer_steps"),
+                    self.moe_counts,
+                ))
+            gauges = {
+                "state_bytes": self.cache_state_bytes,
+                "latent_bytes_per_token": self.cache_latent_bytes_per_token,
             }
+            gauges = {k: v for k, v in gauges.items() if v is not None}
+            state = {"cache": gauges} if gauges else {}
         return {
             "uptime_s": round(uptime, 1),
             "requests_served": reqs,

@@ -138,15 +138,20 @@ ROWS, POSITIONS = 64, 2048
 
 
 def _compile_group(device, program: str, n_kv_heads: int):
-    """``(compiled, pool shape)`` of one step program of the engine on
-    shapes alone: 4 decode steps at a 512-slot read, or 4 mixed steps with a
-    4-token chunk a row."""
+    """``_compile_step`` at ``starcoderbase-1b``'s widths and envelope."""
     cfg = dataclasses.replace(
         config_from_hf(
             types.SimpleNamespace(**STARCODERBASE_1B), dtype="bfloat16"
         ),
         n_kv_heads=n_kv_heads,
     )
+    return _compile_step(device, program, cfg, POSITIONS)
+
+
+def _compile_step(device, program: str, cfg, POSITIONS: int):
+    """``(compiled, pool shape)`` of one step program of the engine on
+    shapes alone: 4 decode steps at a 512-slot read, or 4 mixed steps with a
+    4-token chunk a row."""
     mesh = mesh_mod.make_mesh(mesh_mod.MeshPlan(tp=1), devices=[device])
 
     def arr(shape, dtype, spec=PartitionSpec()):
@@ -159,9 +164,9 @@ def _compile_group(device, program: str, n_kv_heads: int):
         param_shapes(cfg), param_specs(cfg, 1),
     )
     mb = POSITIONS // BS
-    pool = (cfg.n_layers, ROWS * mb, BS, n_kv_heads, cfg.head_dim)
+    pool = (cfg.n_layers, ROWS * mb, BS) + cfg.cache_row
     cache = PagedKVCache(
-        k=arr(pool, DT), v=arr(pool, DT),
+        k=arr(pool, DT), v=None if cfg.mla is not None else arr(pool, DT),
         block_tables=arr((ROWS, mb), jnp.int32),
         positions=arr((ROWS, POSITIONS), jnp.int32),
     )
@@ -215,6 +220,53 @@ def test_step_program_carries_the_pool_in_place(v5e, program, n_kv_heads):
     if n_kv_heads == 1:
         pool_bytes = math.prod(pool) * jnp.dtype(DT).itemsize
         assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 10
+
+
+# kakaocorp/kanana-2-30b-a3b-instruct-2601's widths (deepseek_v3: a latent
+# pool, 128 routed experts), cut to one dense and two expert layers, in the
+# envelope of benchmark/configs/kanana-2-30b-a3b-1chip.json.
+KANANA_2_30B_A3B = dict(
+    model_type="deepseek_v3", vocab_size=128256, hidden_size=2048,
+    num_attention_heads=32, num_key_value_heads=32, intermediate_size=6144,
+    kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+    v_head_dim=128, q_lora_rank=None, moe_intermediate_size=768,
+    n_routed_experts=128, num_experts_per_tok=6, n_shared_experts=2,
+    first_k_dense_replace=1, moe_layer_freq=1, n_group=1, topk_group=1,
+    norm_topk_prob=True, routed_scaling_factor=2.448, scoring_func="sigmoid",
+    topk_method="noaux_tc", num_hidden_layers=3, max_position_embeddings=5120,
+    rms_norm_eps=1e-6, rope_theta=1000000, rope_interleave=True,
+    rope_scaling=None, hidden_act="silu", tie_word_embeddings=False,
+)
+
+
+@pytest.mark.parametrize("program", ["decode", "ragged"])
+def test_latent_step_program_carries_the_pool_in_place(
+    v5e, monkeypatch, program,
+):
+    """The latent pool (``[L, N, bs, 640]``: 576 padded to whole lane tiles,
+    no head axis) goes through the step loops as it came: no pool-sized
+    ``copy``. At 576 wide its default device layout had the block axis
+    minor and every program transposed it four times (docs/latent-cache.md).
+    The grouped matmul over the experts compiles as the chip's own kernel."""
+    import importlib
+
+    # the program asks jax.default_backend(), which is the CPU here
+    monkeypatch.setattr(
+        importlib.import_module("llmss_tpu.ops.attention"),
+        "pallas_interpret", lambda: False,
+    )
+    cfg = config_from_hf(
+        types.SimpleNamespace(**KANANA_2_30B_A3B), dtype="bfloat16"
+    )
+    compiled, pool = _compile_step(v5e, program, cfg, 5120)
+    assert pool == (3, 64 * 320, 16, 640)
+    text = compiled.as_text()
+    assert _pool_sized_copies(text, pool) == []
+    assert text.count("tpu_custom_call") >= 3  # gate, up, down
+    # the temporaries are the rows' gathered views (float32 in the mixed
+    # step: 0.63 GB), under this three-layer pool's 1.26 GB
+    pool_bytes = math.prod(pool) * jnp.dtype(DT).itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6 * pool_bytes
 
 
 def test_supports_refuses_what_vmem_cannot_hold():

@@ -112,7 +112,7 @@ def test_gather_view_matches_identity_pool_and_write_roundtrip(devices):
     exactly (slot // bs, slot % bs) of the row's table."""
     mesh = make_mesh(MeshPlan(dp=2, tp=4))
     cache = init_paged_cache(
-        mesh, n_layers=2, batch=2, max_len=32, n_kv_heads=4, head_dim=8,
+        mesh, n_layers=2, batch=2, max_len=32, row=(4, 8),
         dtype=jnp.float32, block_size=8,
     )
     rng = np.random.default_rng(0)

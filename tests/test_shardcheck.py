@@ -100,7 +100,8 @@ def test_reintroduced_decode_many_bug_detected(env):
             DecodeEngine._decode_step_body,
             cfg, mesh, params, sample_args, eos, t_bucket,
         )
-        carry, toks = jax.lax.scan(
+        # (the step body's ys are (tokens, routing counts) since PR 38)
+        carry, (toks, _) = jax.lax.scan(
             body,
             (tokens, cache, cur_pos, done, jnp.zeros_like(done)),
             None,
