@@ -1440,62 +1440,42 @@ def _forward_paged(
 
         occ = jnp.sum((cache.positions >= 0).astype(jnp.int32), axis=1)
         nblk = jnp.clip(-(-occ // bs), 0, MB).astype(jnp.int32)
-        kernel_attn = _make_paged_kernel_attn(
+        # The layer scan closes over the stacked pool and reads it with the
+        # layer as an INDEX (of the kernel's block map, or of the one
+        # gather): never as a slice, which the compiler copies out whole
+        # ahead of a gather (docs/paged-kv.md).
+        attn = _make_paged_kernel_attn(
             cfg, mesh, cache, positions, slots, nblk
         )
-
-        if kernel_attn is not None:
-            def body(h, xs, ssm_in):
-                bp, layer = xs
-                h, k_f, v_f, ssm_out = _block(
-                    cfg, bp, h, positions, None, None, kv_pos_src, slots,
-                    None, mesh=mesh, defer_write=True,
-                    attn_override=partial(kernel_attn, layer=layer),
-                    sin_cos=sin_cos, ssm_in=ssm_in,
-                )
-                return h, (k_f, v_f), ssm_out
-
-            h, ys, state = _layer_scan(
-                cfg, cache, lens, body, h,
-                (params["blocks"],
-                 jnp.arange(cfg.n_layers, dtype=jnp.int32)),
-            )
-        else:
+        if attn is None:
             penalty = decode_mask_penalty(
                 positions, kv_pos_src, slots, cfg.sliding_window
             )
 
-            def body(h, xs, ssm_in):
-                if quant:
-                    bp, kp_l, vp_l, ksp_l, vsp_l = xs
-                else:
-                    bp, kp_l, vp_l = xs
-                    ksp_l = vsp_l = None
-
-                def paged_attn(q, k_new, v_new, k_c, v_c):
-                    del k_c, v_c  # reads the per-layer pool slice
-                    return paged_decode_attention(
-                        q, kp_l, vp_l, k_new, v_new, positions,
-                        kv_pos_src, cache.block_tables, slots,
-                        scale=cfg.attn_scale, window=cfg.sliding_window,
-                        penalty=penalty, k_scale_layer=ksp_l,
-                        v_scale_layer=vsp_l, n_blocks=nb,
-                    )
-
-                h, k_f, v_f, ssm_out = _block(
-                    cfg, bp, h, positions, None, None, kv_pos_src, slots,
-                    None, mesh=mesh, defer_write=True,
-                    attn_override=paged_attn,
-                    sin_cos=sin_cos, ssm_in=ssm_in,
+            def attn(q, k_new, v_new, k_c, v_c, *, layer):
+                del k_c, v_c  # reads the stacked pool directly
+                return paged_decode_attention(
+                    q, cache.k, cache.v, k_new, v_new, positions,
+                    kv_pos_src, cache.block_tables, slots,
+                    scale=cfg.attn_scale, window=cfg.sliding_window,
+                    penalty=penalty, k_scale_layer=cache.k_scale,
+                    v_scale_layer=cache.v_scale, n_blocks=nb, layer=layer,
                 )
-                return h, (k_f, v_f), ssm_out
 
-            if quant:
-                xs = (params["blocks"], cache.k, cache.v, cache.k_scale,
-                      cache.v_scale)
-            else:
-                xs = (params["blocks"], cache.k, cache.v)
-            h, ys, state = _layer_scan(cfg, cache, lens, body, h, xs)
+        def body(h, xs, ssm_in):
+            bp, layer = xs
+            h, k_f, v_f, ssm_out = _block(
+                cfg, bp, h, positions, None, None, kv_pos_src, slots,
+                None, mesh=mesh, defer_write=True,
+                attn_override=partial(attn, layer=layer),
+                sin_cos=sin_cos, ssm_in=ssm_in,
+            )
+            return h, (k_f, v_f), ssm_out
+
+        h, ys, state = _layer_scan(
+            cfg, cache, lens, body, h,
+            (params["blocks"], jnp.arange(cfg.n_layers, dtype=jnp.int32)),
+        )
 
         ks_new, vs_new = cache.k_scale, cache.v_scale
         k_fresh, v_fresh = ys  # [L, B, 1, Hkv, D]
@@ -1865,26 +1845,12 @@ def forward_ragged(
 
     occ = jnp.sum((cache.positions >= 0).astype(jnp.int32), axis=1)
     nblk = jnp.clip(-(-occ // bs), 0, MB).astype(jnp.int32)
-    kernel_attn = _make_ragged_kernel_attn(
+    # Same discipline as the S == 1 branch of _forward_paged: the scan
+    # closes over the stacked pool and the layer is an index of the read.
+    attn = _make_ragged_kernel_attn(
         cfg, mesh, cache, q_pos0, q_lens, slot0, nblk
     )
-
-    if kernel_attn is not None:
-        def body(h, xs, ssm_in):
-            bp, layer = xs
-            h, k_f, v_f, ssm_out = _block(
-                cfg, bp, h, positions, None, None, kv_pos_src, slots,
-                None, mesh=mesh, defer_write=True,
-                attn_override=partial(kernel_attn, layer=layer),
-                sin_cos=sin_cos, ssm_in=ssm_in,
-            )
-            return h, (k_f, v_f), ssm_out
-
-        h, ys, state = _layer_scan(
-            cfg, cache, lens, body, h,
-            (params["blocks"], jnp.arange(cfg.n_layers, dtype=jnp.int32)),
-        )
-    else:
+    if attn is None:
         # Hoist the query-invariant visibility out of the layer scan (the
         # per-query causal bound stays inside the oracle — it is chunk
         # structure, not a [B, T] penalty).
@@ -1892,36 +1858,30 @@ def forward_ragged(
             q_lens, kv_pos_src, slot0, cache.max_len
         )
 
-        def body(h, xs, ssm_in):
-            if quant:
-                bp, kp_l, vp_l, ksp_l, vsp_l = xs
-            else:
-                bp, kp_l, vp_l = xs
-                ksp_l = vsp_l = None
-
-            def ragged_attn(q, k_new, v_new, k_c, v_c):
-                del k_c, v_c  # reads the per-layer pool slice
-                return ragged_paged_attention(
-                    q, kp_l, vp_l, k_new, v_new, q_pos0, q_lens,
-                    kv_pos_src, cache.block_tables, slot0, cache.max_len,
-                    scale=cfg.attn_scale, window=cfg.sliding_window,
-                    cache_vis=cache_vis, k_scale_layer=ksp_l,
-                    v_scale_layer=vsp_l, n_blocks=nb,
-                )
-
-            h, k_f, v_f, ssm_out = _block(
-                cfg, bp, h, positions, None, None, kv_pos_src, slots,
-                None, mesh=mesh, defer_write=True,
-                attn_override=ragged_attn, sin_cos=sin_cos, ssm_in=ssm_in,
+        def attn(q, k_new, v_new, k_c, v_c, *, layer):
+            del k_c, v_c  # reads the stacked pool directly
+            return ragged_paged_attention(
+                q, cache.k, cache.v, k_new, v_new, q_pos0, q_lens,
+                kv_pos_src, cache.block_tables, slot0, cache.max_len,
+                scale=cfg.attn_scale, window=cfg.sliding_window,
+                cache_vis=cache_vis, k_scale_layer=cache.k_scale,
+                v_scale_layer=cache.v_scale, n_blocks=nb, layer=layer,
             )
-            return h, (k_f, v_f), ssm_out
 
-        if quant:
-            xs = (params["blocks"], cache.k, cache.v, cache.k_scale,
-                  cache.v_scale)
-        else:
-            xs = (params["blocks"], cache.k, cache.v)
-        h, ys, state = _layer_scan(cfg, cache, lens, body, h, xs)
+    def body(h, xs, ssm_in):
+        bp, layer = xs
+        h, k_f, v_f, ssm_out = _block(
+            cfg, bp, h, positions, None, None, kv_pos_src, slots,
+            None, mesh=mesh, defer_write=True,
+            attn_override=partial(attn, layer=layer),
+            sin_cos=sin_cos, ssm_in=ssm_in,
+        )
+        return h, (k_f, v_f), ssm_out
+
+    h, ys, state = _layer_scan(
+        cfg, cache, lens, body, h,
+        (params["blocks"], jnp.arange(cfg.n_layers, dtype=jnp.int32)),
+    )
 
     ks_new, vs_new = cache.k_scale, cache.v_scale
     k_fresh, v_fresh = ys  # [L, B, CB, Hkv, D]

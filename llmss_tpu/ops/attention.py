@@ -425,8 +425,8 @@ def paged_decode_attention(
     )
     ks = vs = None
     if k_scale_layer is not None:
-        ks = gather_block_view(k_scale_layer, block_tables, n_blocks)
-        vs = gather_block_view(v_scale_layer, block_tables, n_blocks)
+        ks = gather_block_view(k_scale_layer, block_tables, n_blocks, layer)
+        vs = gather_block_view(v_scale_layer, block_tables, n_blocks, layer)
     return fresh_kv_decode_attention(
         q, k_view, v_view, k_new, v_new, q_pos, kv_pos_old, slots,
         scale=scale, window=window, penalty=penalty, k_scale=ks, v_scale=vs,
@@ -578,8 +578,8 @@ def ragged_paged_attention(
     )
     ks = vs = None
     if k_scale_layer is not None:
-        ks = gather_block_view(k_scale_layer, block_tables, n_blocks)
-        vs = gather_block_view(v_scale_layer, block_tables, n_blocks)
+        ks = gather_block_view(k_scale_layer, block_tables, n_blocks, layer)
+        vs = gather_block_view(v_scale_layer, block_tables, n_blocks, layer)
     return ragged_fresh_kv_attention(
         q, k_view, v_view, k_new, v_new, q_pos, q_len, kv_pos_old, slot0,
         ring_len, scale=scale, window=window, cache_vis=cache_vis,

@@ -10,16 +10,18 @@ at the widths of three models the repo serves, at the engine's default
 not a chip run.
 
 Two whole step programs are compiled too, the decode group and the ragged
-group at the widths and envelope of ``starcoderbase-1b`` as the benchmark
-serves it: what layout assignment does to the block pool the step loop
-carries shows only in the compiled text (a transpose of the whole pool every
-step, once: docs/paged-kv.md).
+group at the widths and envelopes the benchmark serves (``starcoderbase-1b``,
+``falcon-h1-34b-1chip``, ``kanana-2-30b-a3b-1chip``): what the compiler does
+to the block pool the step loop carries shows only in the compiled text (a
+transpose of the whole pool every step, once; a copy of a whole layer out of
+the stack for every layer of every step, once: docs/paged-kv.md).
 
 Plus: where ``initialize_runtime()`` puts the persistent compile cache.
 """
 
 import dataclasses
 import functools
+import json
 import math
 import os
 import re
@@ -33,7 +35,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from llmss_tpu.engine import DecodeEngine
-from llmss_tpu.engine.cache import PagedKVCache
+from llmss_tpu.engine.cache import PagedKVCache, ssm_state_shapes
 from llmss_tpu.models.decoder import param_shapes, param_specs
 from llmss_tpu.models.registry import config_from_hf
 from llmss_tpu.ops import (
@@ -137,15 +139,28 @@ STARCODERBASE_1B = dict(
 ROWS, POSITIONS = 64, 2048
 
 
-def _compile_group(device, program: str, n_kv_heads: int):
-    """``_compile_step`` at ``starcoderbase-1b``'s widths and envelope."""
-    cfg = dataclasses.replace(
-        config_from_hf(
-            types.SimpleNamespace(**STARCODERBASE_1B), dtype="bfloat16"
-        ),
-        n_kv_heads=n_kv_heads,
+def _compile_group(device, program: str, widths):
+    """``_compile_step`` at ``starcoderbase-1b``'s widths and envelope with
+    ``widths`` KV heads, or, for the name of one of the benchmark's
+    configurations (``falcon-h1-34b-1chip``: GQA with 4 KV heads beside a
+    recurrent state, whose leaves ride in the cache), at that file's widths
+    in the envelope its cell serves."""
+    if isinstance(widths, int):
+        cfg = dataclasses.replace(
+            config_from_hf(
+                types.SimpleNamespace(**STARCODERBASE_1B), dtype="bfloat16"
+            ),
+            n_kv_heads=widths,
+        )
+        return _compile_step(device, program, cfg, POSITIONS)
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmark", "configs", widths + ".json",
     )
-    return _compile_step(device, program, cfg, POSITIONS)
+    with open(path) as f:
+        hf = json.load(f)
+    cfg = config_from_hf(types.SimpleNamespace(**hf), dtype=hf["dtype"])
+    return _compile_step(device, program, cfg, hf["serve"]["max_seq_len"])
 
 
 def _compile_step(device, program: str, cfg, POSITIONS: int):
@@ -169,6 +184,10 @@ def _compile_step(device, program: str, cfg, POSITIONS: int):
         k=arr(pool, DT), v=None if cfg.mla is not None else arr(pool, DT),
         block_tables=arr((ROWS, mb), jnp.int32),
         positions=arr((ROWS, POSITIONS), jnp.int32),
+        **dict(zip(("ssm", "conv"), (
+            arr((cfg.n_layers, ROWS) + shape, dtype)
+            for shape, dtype in ssm_state_shapes(cfg) or ()
+        ))),
     )
     row = functools.partial(arr, (ROWS,))
     sample_args = dict(
@@ -197,27 +216,58 @@ def _compile_step(device, program: str, cfg, POSITIONS: int):
     return lowered.compile(), pool
 
 
+def _results(hlo_text: str, name: str):
+    """``(line, result dimensions)`` of the instructions, fused ones too,
+    whose name matches ``name``."""
+    for line in hlo_text.splitlines():
+        m = re.match(rf"\s*(?:ROOT )?%(?:{name})\S* = \w+\[([\d,]+)\]", line)
+        if m:
+            yield line.strip()[:120], tuple(map(int, m[1].split(",")))
+
+
 def _pool_sized_copies(hlo_text: str, pool: tuple) -> list[str]:
     """The ``copy`` instructions whose result has as many elements as the
     pool, whatever dimensions a bitcast gave it."""
-    out = []
-    for line in hlo_text.splitlines():
-        m = re.match(r"\s*(?:ROOT )?%copy\S* = \w+\[([\d,]+)\]", line)
-        if m and math.prod(map(int, m[1].split(","))) == math.prod(pool):
-            out.append(line.strip()[:120])
-    return out
+    return [
+        line for line, dims in _results(hlo_text, "copy")
+        if math.prod(dims) == math.prod(pool)
+    ]
+
+
+def _layer_sized_slices(hlo_text: str, pool: tuple) -> list[str]:
+    """The ``dynamic-slice`` and ``copy`` instructions (and the fusions named
+    after them) whose result is ONE LAYER of the pool: its dimensions, size-1
+    axes apart. By dimensions and not by count: a layer of the MQA pool has as
+    many elements as an MLP matrix, and a mixed step's gathered view
+    ``[rows, slots, ...]`` as many as the layer it was gathered from."""
+    layer = tuple(d for d in pool[1:] if d != 1)
+    return [
+        line
+        for line, dims in _results(
+            hlo_text, r"(?:\w+_)?dynamic[-_]slice|copy"
+        )
+        if tuple(d for d in dims if d != 1) == layer
+    ]
 
 
 @pytest.mark.parametrize(
-    "program,n_kv_heads", [("decode", 1), ("ragged", 1), ("decode", 4)]
+    "program,widths", [
+        ("decode", 1), ("ragged", 1), ("decode", 4),
+        ("decode", "falcon-h1-34b-1chip"), ("ragged", "falcon-h1-34b-1chip"),
+    ],
 )
-def test_step_program_carries_the_pool_in_place(v5e, program, n_kv_heads):
-    """The step loop's carry keeps the layout the layer scan reads: no
-    program copies the whole pool, and with one KV head (where it once did,
-    six times a group) the temporaries are a small part of one pool."""
-    compiled, pool = _compile_group(v5e, program, n_kv_heads)
-    assert _pool_sized_copies(compiled.as_text(), pool) == []
-    if n_kv_heads == 1:
+def test_step_program_carries_the_pool_in_place(v5e, program, widths):
+    """The step loop's carry keeps the layout the layer scan reads, and the
+    layer scan reads the pool by layer AND block in one gather: no program
+    copies the whole pool (with one KV head it once did, six times a group),
+    none slices or copies a whole layer out of it ahead of the gather (both
+    did, for every layer of every step; with 4 KV heads re-tiled besides),
+    and a decode group's temporaries are a small part of one pool."""
+    compiled, pool = _compile_group(v5e, program, widths)
+    text = compiled.as_text()
+    assert _pool_sized_copies(text, pool) == []
+    assert _layer_sized_slices(text, pool) == []
+    if isinstance(widths, int):  # the other's temporaries are the state's
         pool_bytes = math.prod(pool) * jnp.dtype(DT).itemsize
         assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 10
 

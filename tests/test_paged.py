@@ -9,6 +9,7 @@ admitting by block-pool capacity instead of row count and never copying
 shared prefix blocks per row (asserted through ``kv_blocks_in_use``).
 """
 
+import functools
 import importlib
 
 import jax
@@ -140,6 +141,92 @@ def test_gather_view_matches_identity_pool_and_write_roundtrip(devices):
     sent = jnp.full_like(cache.block_tables, table_sentinel(8))
     dropped = paged_write_stacked(pool, tok, sent, slots, cache.block_size)
     np.testing.assert_array_equal(np.asarray(dropped), np.asarray(pool))
+
+
+@pytest.mark.parametrize("n_blocks", [None, 2], ids=["whole", "bucket"])
+@pytest.mark.parametrize(
+    "tail", [(4, 8), (4,), (16,)], ids=["kv", "scales", "latent"]
+)
+def test_gather_view_indexes_the_stack_by_layer(tail, n_blocks):
+    """``gather_block_view(pool, tables, nb, layer=l)`` IS
+    ``gather_block_view(pool[l], tables, nb)``: one gather addressed by layer
+    AND block, which is how every layer scan reads the stacked pool (a slice
+    of it ahead of the gather is copied out whole: docs/paged-kv.md). Pools
+    ``[L, N, bs, Hkv, D]``, ``[L, N, bs, Hkv]`` (an int8 pool's scales) and
+    ``[L, N, bs, C]`` (a latent pool); a sentinel table entry clamps to the
+    same real block either way; the layer is traced, as in the scan."""
+    L, B, MB, bs = 3, 4, 3, 4
+    N = B * MB
+    rng = np.random.default_rng(len(tail) + (n_blocks or 0))
+    pool = jnp.asarray(rng.standard_normal((L, N, bs) + tail), jnp.float32)
+    tables = rng.permutation(N).reshape(B, MB).astype(np.int32)
+    tables[2, 1:] = table_sentinel(N)
+    tables = jnp.asarray(tables)
+    indexed = jax.jit(
+        lambda layer: gather_block_view(pool, tables, n_blocks, layer)
+    )
+    for layer in range(L):
+        want = gather_block_view(pool[layer], tables, n_blocks)
+        got = indexed(jnp.int32(layer))
+        assert got.shape == (B, (n_blocks or MB) * bs) + tail
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("step", ["decode", "mixed"])
+def test_int8_attention_reads_values_and_scales_by_layer(step):
+    """The XLA paged attention of the decode step and of the mixed step,
+    handed the WHOLE stacked int8 pools and their scales with ``layer``,
+    gives what it gives on that layer's slices: the scales follow the
+    values through the indexed gather."""
+    attn = importlib.import_module("llmss_tpu.ops.attention")
+    L, B, MB, bs, Hq, Hkv, D = 3, 2, 2, 4, 4, 2, 8
+    S = 1 if step == "decode" else 3
+    N, T = B * MB, MB * bs
+    rng = np.random.default_rng(7)
+    k8, v8 = (
+        jnp.asarray(rng.integers(-127, 128, (L, N, bs, Hkv, D)), jnp.int8)
+        for _ in range(2)
+    )
+    ks, vs = (
+        jnp.asarray(rng.uniform(0.01, 0.1, (L, N, bs, Hkv)), jnp.float32)
+        for _ in range(2)
+    )
+    q = jnp.asarray(rng.standard_normal((B, S, Hq, D)), jnp.float32)
+    k_new, v_new = (
+        jnp.asarray(rng.standard_normal((B, S, Hkv, D)), jnp.float32)
+        for _ in range(2)
+    )
+    tables = jnp.asarray(rng.permutation(N).reshape(B, MB), jnp.int32)
+    ctx = np.array([5, 3])
+    kv_pos = jnp.asarray(
+        np.where(np.arange(T)[None] < ctx[:, None], np.arange(T)[None], -1),
+        jnp.int32,
+    )
+    at = jnp.asarray(ctx, jnp.int32)
+
+    def run(layer, pools):
+        k_p, v_p, ks_p, vs_p = pools
+        if step == "decode":
+            return attn.paged_decode_attention(
+                q, k_p, v_p, k_new, v_new, at[:, None], kv_pos, tables,
+                at[:, None], k_scale_layer=ks_p, v_scale_layer=vs_p,
+                layer=layer,
+            )
+        return attn.ragged_paged_attention(
+            q, k_p, v_p, k_new, v_new, at, jnp.asarray([S, 1], jnp.int32),
+            kv_pos, tables, at, T, k_scale_layer=ks_p, v_scale_layer=vs_p,
+            layer=layer,
+        )
+
+    outs = []
+    for layer in range(L):
+        want = jax.jit(functools.partial(run, None))(
+            [x[layer] for x in (k8, v8, ks, vs)]
+        )
+        got = jax.jit(run)(jnp.int32(layer), (k8, v8, ks, vs))
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        outs.append(np.asarray(want))
+    assert not np.array_equal(outs[0], outs[1])  # the layers do differ
 
 
 def _write_reference(pool, new, tables, slots, bs):

@@ -205,18 +205,21 @@ def params(cfg, mesh):
     return init_params(cfg, mesh, jax.random.key(0))
 
 
-def _paged_engine(cfg, params, mesh):
+def _paged_engine(cfg, params, mesh, **kw):
     return DecodeEngine(
         cfg, params, mesh, max_seq_len=64, kv_layout="paged", block_size=8,
+        **kw,
     )
 
 
-def test_engine_all_decode_matches_decode_group(cfg, params, mesh):
+@pytest.mark.parametrize("kv_dtype", [None, "int8"])
+def test_engine_all_decode_matches_decode_group(cfg, params, mesh, kv_dtype):
     """An all-decode plan (q_len == 1, no feeds, every step emitting)
     through _ragged_group reproduces _decode_group's packed tokens and
     counters exactly — the unified dispatch costs nothing on the pure
-    decode steady state."""
-    eng = _paged_engine(cfg, params, mesh)
+    decode steady state. With an int8 pool both steps read values AND
+    scales out of the stack by layer, and still agree."""
+    eng = _paged_engine(cfg, params, mesh, kv_dtype=kv_dtype)
     nB = 4
     gen = GenerationParams(max_new_tokens=8, is_greedy=True)
     sa = eng._sample_args([gen] * nB, nB)
