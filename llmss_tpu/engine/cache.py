@@ -205,7 +205,12 @@ def table_sentinel(num_blocks: int) -> int:
 
 
 class PagedKVCache(NamedTuple):
-    k: jax.Array  # [L, N, bs, Hkv, D] global block pool
+    # [L_kv, N, bs, Hkv, D] global block pool. A pool's layer axis counts
+    # the layers of ITS kind: every layer holds keys and values in most
+    # models (L_kv is the depth), a quarter of them where linear-attention
+    # layers alternate with attention (cfg.n_kv_layers), and each layer
+    # indexes a pool by its index within its kind.
+    k: jax.Array
     # [L, N, bs, Hkv, D]; None for a LATENT pool (a model with latent
     # attention, cfg.mla): ``k`` then holds ``[L, N, bs, C + R]``, a token's
     # normed latent beside its shared rotary key, and values are its first
@@ -220,15 +225,21 @@ class PagedKVCache(NamedTuple):
     # same folding contract as the dense cache (ops/attention.py).
     k_scale: jax.Array | None = None  # [L, N, bs, Hkv] f32
     v_scale: jax.Array | None = None
-    # Recurrent state of a model with a Mamba-2 mixer (cfg.ssm), a ROW and
-    # layer, beside the row's paged keys and values: fixed size, so it needs
+    # Recurrent state of a model with a Mamba-2 mixer (cfg.ssm) or with
+    # linear-attention layers (cfg.linear_attn), a ROW and layer that holds
+    # one (L_state = cfg.n_state_layers: its own count, not the block
+    # pool's), beside the row's paged keys and values: fixed size, so it needs
     # no blocks and no allocator, only the row. Donated and returned with
     # the rest of the tuple; a step updates each layer's slice in place. A
     # row's state is rebuilt by the prefill that admits a request into the
     # row (a prompt starts from nothing), so finishing a request frees
     # nothing here and a done row's state just stays until then.
-    ssm: jax.Array | None = None  # [L, rows, H, P, N] float32
-    conv: jax.Array | None = None  # [L, rows, K-1, C] compute dtype
+    # [L_state, rows, H, P, N] float32 (Mamba-2) or [L_state, rows, H, Dk,
+    # Dv] (the delta rule)
+    ssm: jax.Array | None = None
+    # [L_state, rows, K-1, C] compute dtype (Mamba-2) or [L_state, rows,
+    # (K-1) * C] (linear attention: the window flattened likewise)
+    conv: jax.Array | None = None
     # Set on an admission view only ([B] int32): the pool row each of the
     # view's B rows stands for. The prefill then starts every row from a
     # zero state and writes its final state to that pool row; an index out
@@ -288,10 +299,24 @@ def paged_cache_specs(
 
 
 def ssm_state_shapes(cfg) -> tuple | None:
-    """``((shape, dtype) of the SSM state, (shape, dtype) of the convolution
-    window)`` of ONE row and layer for a config with a mixer, else None. The
-    state is float32 whatever the compute dtype (it is summed into for the
-    whole life of a request); the window holds inputs of the compute dtype."""
+    """``((shape, dtype) of the recurrent state, (shape, dtype) of the
+    convolution window)`` of ONE row and layer for a config that holds a
+    state (``cfg.has_state``: either recurrent family), else None. The state
+    is float32 whatever the compute dtype (it is summed into for the whole
+    life of a request); the window holds inputs of the compute dtype."""
+    if cfg.linear_attn is not None:
+        m = cfg.linear_attn
+        # The state as the update computes on it: its device layout pads a
+        # head's 192 values to two lane tiles (a third more memory), and no
+        # program re-tiles or moves it; flattened to whole tiles, every
+        # layer of every step re-tiled its slice on the way in and out. The
+        # window IS flattened: as [rows, K-1, C] the step loop carries it
+        # with the ROWS second-minor, and copies the pool into that layout
+        # and back around every program (compiled for a described v5e)
+        return (
+            ((m.n_heads, m.key_head_dim, m.value_head_dim), jnp.float32),
+            (((m.d_conv - 1) * m.conv_dim,), cfg.compute_dtype),
+        )
     m = cfg.ssm
     if m is None:
         return None
@@ -324,6 +349,7 @@ def init_paged_cache(
     num_blocks: int | None = None,
     identity_tables: bool = True,
     state_shapes: tuple | None = None,
+    state_layers: int = 0,
 ) -> PagedKVCache:
     """Zeroed paged cache. ``identity_tables=True`` pre-maps row ``b`` to
     blocks ``[b*MB, (b+1)*MB)`` — a dense-equivalent static layout for the
@@ -331,8 +357,10 @@ def init_paged_cache(
     passes False and drives tables from its host-side ``BlockAllocator``.
     ``row`` (``cfg.cache_row``) is the pools' trailing shape: ``(kv heads,
     head size)``, or one vector for a latent pool (ONE pool, no ``v``).
+    ``n_layers`` is the block pools' layer axis (``cfg.n_kv_layers``).
     ``state_shapes`` (``ssm_state_shapes(cfg)``) adds the zeroed recurrent
-    state of ``batch`` rows."""
+    state of ``batch`` rows over ``state_layers`` layers
+    (``cfg.n_state_layers``: the state pools' own layer axis)."""
     if max_len % block_size:
         raise ValueError(
             f"max_len {max_len} must be a multiple of block_size "
@@ -378,7 +406,7 @@ def init_paged_cache(
             if quantized else None
         ),
         **({} if state_shapes is None else {
-            name: put(spec, jnp.zeros((n_layers, batch) + shape, dt))
+            name: put(spec, jnp.zeros((state_layers, batch) + shape, dt))
             for name, spec, (shape, dt) in zip(
                 ("ssm", "conv"), (specs.ssm, specs.conv), state_shapes
             )

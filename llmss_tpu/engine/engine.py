@@ -138,12 +138,24 @@ class DecodeEngine:
             raise ValueError(
                 f"kv_layout must be 'dense' or 'paged', got {kv_layout!r}"
             )
-        if cfg.ssm is not None and kv_layout != "paged":
+        if cfg.has_state and kv_layout != "paged":
             raise ValueError(
-                f"model_type {cfg.model_type!r} holds a recurrent state in "
-                "every layer, which lives in the paged cache only: pass "
+                f"model_type {cfg.model_type!r} holds a recurrent state, "
+                "which lives in the paged cache only: pass "
                 "kv_layout='paged' (docs/recurrent-state.md)"
             )
+        if cfg.linear_attn is not None:
+            from llmss_tpu.parallel.mesh import AXIS_TP
+
+            tp = 1 if mesh is None else mesh.shape.get(AXIS_TP, 1)
+            if cfg.n_heads % tp or cfg.pool_kv_heads % tp:
+                raise ValueError(
+                    f"tp={tp} does not divide the {cfg.n_heads} heads of "
+                    f"model_type {cfg.model_type!r}'s attention layers and "
+                    f"the {cfg.pool_kv_heads} its block pool holds (the "
+                    "linear-attention mixer is replicated over tp: "
+                    "docs/recurrent-state.md)"
+                )
         if cfg.mla is not None:
             # The latent pool (docs/latent-cache.md) is carried by the paged
             # layout in the compute dtype on one chip's worth of heads;
@@ -263,7 +275,7 @@ class DecodeEngine:
         from llmss_tpu.models.decoder import forward
 
         B, S = ids.shape
-        if cfg.ssm is not None:
+        if cfg.has_state:
             if start is not None:
                 raise ValueError(
                     "prefix reuse is not carried for a model with a "
@@ -365,7 +377,7 @@ class DecodeEngine:
         the seed scatter compiles once per bucket, not once per prefix
         length — this removed a one-time bespoke-shape compile per
         distinct prefix length."""
-        if self.cfg.ssm is not None:
+        if self.cfg.has_state:
             raise ValueError(
                 "prefix reuse is not carried for a model with a recurrent "
                 "state: a retained segment would need the state at its end "
@@ -861,7 +873,7 @@ class DecodeEngine:
             num_blocks = self.kv_blocks
         return init_paged_cache(
             self.mesh,
-            n_layers=self.cfg.n_layers,
+            n_layers=self.cfg.n_kv_layers,
             batch=b,
             max_len=self.max_seq_len,
             row=self.cfg.cache_row,
@@ -870,6 +882,7 @@ class DecodeEngine:
             num_blocks=num_blocks,
             identity_tables=identity,
             state_shapes=ssm_state_shapes(self.cfg),
+            state_layers=self.cfg.n_state_layers,
         )
 
     # -- canonical state shardings ------------------------------------------

@@ -201,7 +201,7 @@ class ContinuousBatcher:
                 f"pool of model_type {engine.cfg.model_type!r}: its wire "
                 "format names keys and values (docs/latent-cache.md)"
             )
-        if prefill_only and engine.cfg.ssm is not None:
+        if prefill_only and engine.cfg.has_state:
             # What does not carry a model's recurrent state refuses the
             # model, so that nothing runs and is silently wrong
             # (docs/recurrent-state.md).
@@ -312,8 +312,10 @@ class ContinuousBatcher:
                     // (self.cache.num_blocks * self.cache.block_size)
                 )
             if self.cache.ssm is not None:
-                engine.metrics.set_state_bytes(
-                    self.cache.ssm.nbytes + self.cache.conv.nbytes
+                engine.metrics.set_state_pool(
+                    self.cache.ssm.nbytes + self.cache.conv.nbytes,
+                    state_layers=self.cache.ssm.shape[0],
+                    kv_layers=self.cache.k.shape[0],
                 )
                 # Chunked admission runs no prefill program that could start
                 # its rows from nothing, so it zeroes their state itself
@@ -901,7 +903,7 @@ class ContinuousBatcher:
         picks up where it stopped and ``max_new_tokens`` counts only the
         REMAINING tokens."""
         gen.validate()
-        if prefix is not None and self.engine.cfg.ssm is not None:
+        if prefix is not None and self.engine.cfg.has_state:
             raise ValueError(
                 "prefix reuse is not carried for a model with a recurrent "
                 "state (docs/recurrent-state.md)"
@@ -1405,7 +1407,7 @@ class ContinuousBatcher:
                 "the KV hand-off does not carry a latent pool: its wire "
                 "format names keys and values (docs/latent-cache.md)"
             )
-        if self.engine.cfg.ssm is not None:
+        if self.engine.cfg.has_state:
             raise ValueError(
                 "the KV hand-off does not carry a recurrent state: a row "
                 "adopted without it would decode from a wrong state "
@@ -1527,7 +1529,7 @@ class ContinuousBatcher:
                 "session parking does not carry a latent pool: the tiered "
                 "store's blobs name keys and values (docs/latent-cache.md)"
             )
-        if self.engine.cfg.ssm is not None:
+        if self.engine.cfg.has_state:
             raise ValueError(
                 "session parking does not carry a recurrent state "
                 "(docs/recurrent-state.md)"

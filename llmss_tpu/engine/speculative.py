@@ -273,7 +273,7 @@ def generate_speculative(
             "windowed verify step has no absorbed form yet "
             "(docs/latent-cache.md)"
         )
-    if engine.cfg.ssm is not None:
+    if engine.cfg.has_state:
         raise ValueError(
             "speculative decoding does not carry a recurrent state: a "
             "rejected draft would have to roll the state back "

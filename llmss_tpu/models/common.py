@@ -64,6 +64,38 @@ class SSMConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class LinearAttnConfig:
+    """Gated DeltaNet (Yang, Kautz, Hatamizadeh 2024) as the mixer of the
+    ``linear_attention`` layers of a model whose layers ALTERNATE kinds
+    (``DecoderConfig.layer_types``; Olmo-Hybrid): per head a float32 state
+    ``S`` of ``[key_head_dim, value_head_dim]`` that a token decays, corrects
+    by the delta rule and reads (ops/gdn.py), behind one depthwise causal
+    convolution over the concatenated q, k and v channels. Such a layer
+    holds no keys and values."""
+
+    n_heads: int
+    key_head_dim: int
+    value_head_dim: int
+    d_conv: int
+    # beta = 2 * sigmoid(.) in (0, 2): the transition I - beta k k^T may
+    # have a negative eigenvalue (linear_allow_neg_eigval)
+    allow_neg_eigval: bool = True
+
+    @property
+    def key_dim(self) -> int:
+        return self.n_heads * self.key_head_dim
+
+    @property
+    def value_dim(self) -> int:
+        return self.n_heads * self.value_head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels the causal convolution runs over: q, k and v."""
+        return 2 * self.key_dim + self.value_dim
+
+
+@dataclasses.dataclass(frozen=True)
 class MLAConfig:
     """Multi-head latent attention (DeepSeek-V2/V3) without a query
     low-rank: keys and values of all heads are rebuilt from ONE normed
@@ -197,6 +229,17 @@ class DecoderConfig:
     mla: MLAConfig | None = None
     moe: MoEConfig | None = None
 
+    # olmo_hybrid: layers of two KINDS in a repeating pattern. ``layer_types``
+    # names every layer's kind ("linear_attention": a ``linear_attn`` mixer
+    # and no keys and values; "full_attention": the attention block) and is
+    # whole periods of its shortest repeating unit; None = one kind. The
+    # block is Olmo 2's: no pre-norm, ``h + norm(branch(h))`` (``post_norm``),
+    # and an RMSNorm over the WHOLE query and key projection (``qk_norm``).
+    layer_types: tuple[str, ...] | None = None
+    linear_attn: LinearAttnConfig | None = None
+    post_norm: bool = False
+    qk_norm: bool = False
+
     # compute dtype for activations; params are loaded in this dtype too
     dtype: str = "bfloat16"
 
@@ -236,7 +279,64 @@ class DecoderConfig:
         key, zero-padded to whole lane tiles: one vector, no head axis)."""
         if self.mla is not None:
             return (self.mla.pool_dim,)
-        return (self.n_kv_heads, self.head_dim)
+        return (self.pool_kv_heads, self.head_dim)
+
+    @property
+    def pool_kv_heads(self) -> int:
+        """KV heads as a paged pool holds them: the model's, except where
+        layers alternate kinds (``layer_types``). A bfloat16 pool's device
+        layout tiles its two minor axes ``(heads, head size)`` by (16, 128),
+        so a head count over one tile that is not whole tiles (30) is padded
+        in memory anyway, and inside the period scan layout assignment then
+        prefers the block's SLOT axis there and copies the whole pool into
+        that layout and back around every step program (four copies of 1.5 GB
+        a group at 30 heads; none at 32: compiled for a described v5e,
+        PR 40). There the count is rounded up to whole tiles and the block
+        pads its heads with zeros (``models/decoder.py: _block``). Only that
+        program was compiled and measured, so only it is padded: every other
+        family's pool holds ``n_kv_heads`` as it did. Multi-head attention
+        only: a grouped count must keep dividing the query heads."""
+        h = self.n_kv_heads
+        if (self.layer_types is None or h <= 16 or h % 16 == 0
+                or h != self.n_heads):
+            return h
+        return -(-h // 16) * 16
+
+    @property
+    def has_state(self) -> bool:
+        """Whether a row holds a recurrent state beside its keys and values
+        (a Mamba-2 mixer in every block, or linear-attention layers): THE
+        question every feature that does not carry that state asks before it
+        refuses the model (docs/recurrent-state.md)."""
+        return self.ssm is not None or self.linear_attn is not None
+
+    @property
+    def period(self) -> tuple[str, ...] | None:
+        """The shortest repeating unit of ``layer_types``; None for a model
+        of one kind of layer."""
+        t = self.layer_types
+        if t is None:
+            return None
+        return next(
+            t[:n] for n in range(1, len(t) + 1)
+            if len(t) % n == 0 and t[:n] * (len(t) // n) == t
+        )
+
+    @property
+    def n_kv_layers(self) -> int:
+        """Layers that hold keys and values: the layer axis of the block
+        pools and of ``params["blocks"]``."""
+        if self.layer_types is None:
+            return self.n_layers
+        return self.layer_types.count("full_attention")
+
+    @property
+    def n_state_layers(self) -> int:
+        """Layers that hold a recurrent state: the layer axis of the state
+        pools (and of ``params["linear"]`` where the kinds alternate)."""
+        if self.layer_types is None:
+            return self.n_layers if self.ssm is not None else 0
+        return self.layer_types.count("linear_attention")
 
     @property
     def n_lead_layers(self) -> int:

@@ -51,6 +51,7 @@ from llmss_tpu.ops.layers import (
     LinearParams, NormParams, dense, dense_t, embedding, rms_norm,
 )
 from llmss_tpu.ops.rope import apply_rope, sin_cos_tables
+from llmss_tpu.ops.gdn import gdn_chunked, gdn_step, l2_normalize
 from llmss_tpu.ops.ssm import causal_conv, ssd_scan, ssm_step
 from llmss_tpu.parallel.mesh import AXIS_DP, AXIS_SP, AXIS_TP
 from llmss_tpu.parallel.sharding import constrain
@@ -120,6 +121,10 @@ def param_specs(cfg: DecoderConfig, tp: int) -> Params:
         blocks["kv_a"] = LinearParams(w=P(None, None, None), b=None)
         blocks["kv_norm"] = _norm_specs(True, False)
         blocks["kv_b"] = LinearParams(w=P(None, None, AXIS_TP), b=None)
+    if cfg.qk_norm:
+        # over the WHOLE projection (Olmo 2), so over every shard's heads
+        blocks["q_norm"] = _norm_specs(True, False)
+        blocks["k_norm"] = _norm_specs(True, False)
     if cfg.has_ln2:
         blocks["ln2"] = _norm_specs(True, norm_bias)
     swiglu = {
@@ -174,6 +179,19 @@ def param_specs(cfg: DecoderConfig, tp: int) -> Params:
     }
     if lead is not None:
         specs["lead"] = lead
+    if cfg.linear_attn is not None:
+        # The linear-attention layers' own stack (``_layer_scan`` walks the
+        # two stacks by period). The mixer and its state are replicated over
+        # tp as a Mamba-2 mixer's are; the MLP shards as everywhere.
+        rep = LinearParams(w=P(None, None, None), b=None)
+        specs["linear"] = {
+            "ln1": _norm_specs(True, norm_bias),
+            "ln2": _norm_specs(True, norm_bias), **swiglu,
+            "gdn_qkv": rep, "gdn_ab": rep, "gdn_g": rep, "gdn_o": rep,
+            "gdn_conv": rep,
+            "gdn_A_log": P(None, None), "gdn_dt_bias": P(None, None),
+            "gdn_norm": _norm_specs(True, False),
+        }
     if cfg.positions == "learned":
         specs["wpe"] = P(AXIS_TP, None)
     if not cfg.tie_word_embeddings:
@@ -202,6 +220,8 @@ def init_params(cfg: DecoderConfig, mesh, key) -> Params:
 
     if cfg.ssm is not None:
         draw = _ssm_family_draw(cfg)
+    elif cfg.linear_attn is not None:
+        draw = _gdn_family_draw(cfg)
     elif cfg.moe is not None:
         draw = _routed_family_draw(cfg)
     else:
@@ -220,6 +240,16 @@ def init_params(cfg: DecoderConfig, mesh, key) -> Params:
         return jax.tree_util.tree_map_with_path(_leaf, shapes, keys)
 
     return jax.jit(_init, out_shardings=shardings)(keys_tree)
+
+
+def _draw_dt_bias(k, shape):
+    """The inverse softplus of a log-uniform time step in [1e-3, 1e-1]: how
+    the published initialisations of both recurrent families draw the bias
+    of their decay's time step."""
+    dt = jnp.exp(jax.random.uniform(
+        k, shape, jnp.float32, jnp.log(1e-3), jnp.log(1e-1)
+    ))
+    return dt + jnp.log(-jnp.expm1(-dt))
 
 
 def _ssm_family_draw(cfg: DecoderConfig) -> dict:
@@ -250,13 +280,6 @@ def _ssm_family_draw(cfg: DecoderConfig) -> dict:
             k, shape, jnp.float32, lo, hi
         )
 
-    def dt_bias(k, shape):
-        # inverse softplus of a log-uniform time step in [1e-3, 1e-1]
-        dt = jnp.exp(jax.random.uniform(
-            k, shape, jnp.float32, jnp.log(1e-3), jnp.log(1e-1)
-        ))
-        return dt + jnp.log(-jnp.expm1(-dt))
-
     return {
         "q": normal(8.0, E), "k": normal(11.3, E), "v": normal(1.0, E),
         "o": normal(4.5, Q),
@@ -265,9 +288,60 @@ def _ssm_family_draw(cfg: DecoderConfig) -> dict:
         "ssm_in": normal(16.0, E), "ssm_out": normal(0.6, s.d_ssm),
         "ssm_conv": uniform(-0.5, 0.5),  # weight and bias
         "ssm_A_log": lambda k, shape: jnp.log(uniform(1.0, 16.0)(k, shape)),
-        "ssm_dt_bias": dt_bias,
+        "ssm_dt_bias": _draw_dt_bias,
         "ssm_D": lambda k, shape: jnp.ones(shape, jnp.float32),
         "head": normal(1.0, E),
+    }
+
+
+def _gdn_family_draw(cfg: DecoderConfig) -> dict:
+    """The seeded draw of a family whose linear-attention layers run the
+    gated delta rule, leaf by leaf (as ``_ssm_family_draw`` is for a Mamba-2
+    mixer, and for its first reason: ``A_log`` and ``dt_bias`` near 0 make a
+    state that forgets in a few tokens, which hides a wrong recurrence). The
+    block is post-norm, so every branch adds a unit-size vector to the
+    residual whatever its matrices' scale: a lost branch leaves any
+    tolerance by itself. What the scales decide is where each nonlinearity
+    works and how far a rounding travels: matrices are ``N(0, 1 / fan_in)``,
+    so that ``beta`` fills (0, 2), the decay's time step moves by a factor of
+    e either way with the token and the SiLUs leave their linear part; the
+    embedding is N(0, 4^2). With keys drawn at random the delta rule's
+    corrections solve an ill-conditioned system once a head remembers more
+    tokens than it has key dimensions (the slow heads over 512 tokens do), and
+    a branch normed AFTER it hands that on at full size: an embedding of size
+    1 put bfloat16 at 0.10-0.13 of the reference's deviation against a
+    tolerance of 0.15, size 4 at 0.05 (each branch still a tenth of the
+    residual's variance: the lost ``beta`` projection reads 1.4-1.5; CPU,
+    PR 40, 12 layers at the published head sizes, 4 x 512 tokens). This is
+    the benchmark's draw, not the published one: Olmo 2 / Olmo 3 initialise
+    every matrix AND the embedding at N(0, 0.02) and train from there, and no
+    trained size is in the config; a 0.02 embedding under post-norm is
+    swamped by the first branch (the regime below size 1; not measured).
+    Size 4 was chosen for room under the tolerance the harness already had, so the
+    tolerance's upper reading is not this draw's to give: it comes from the
+    precision controls of ``tools/olmo_hybrid_check.py`` (PERF.md section 6).
+    Norm scales, not named here, stay N(0, 0.02) (the benchmark's server and
+    the tests add 1)."""
+
+    def normal(transposed=False):
+        def draw(k, shape):
+            fan_in = shape[-1 if transposed else -2]
+            return jax.random.normal(k, shape, jnp.float32) / fan_in ** 0.5
+        return draw
+
+    w, wt = normal(), normal(transposed=True)
+    return {
+        "wte": lambda k, shape: 4.0 * jax.random.normal(k, shape, jnp.float32),
+        "q": wt, "k": wt, "v": w, "o": w,
+        "gate": w, "up": w, "down": w, "head": w,
+        "gdn_qkv": w, "gdn_ab": w, "gdn_g": w, "gdn_o": w,
+        "gdn_conv": lambda k, shape: jax.random.uniform(
+            k, shape, jnp.float32, -0.5, 0.5
+        ),
+        "gdn_A_log": lambda k, shape: jnp.log(
+            jax.random.uniform(k, shape, jnp.float32, 1.0, 16.0)
+        ),
+        "gdn_dt_bias": _draw_dt_bias,
     }
 
 
@@ -393,6 +467,8 @@ def param_shapes(cfg: DecoderConfig) -> Params:
             "v": LinearParams(
                 sds(n, E, KV), sds(n, KV) if cfg.attn_bias else None),
             "o": LinearParams(sds(n, Q, E), sds(n, E) if cfg.o_bias else None),
+            **({"q_norm": NormParams(sds(n, Q), None),
+                "k_norm": NormParams(sds(n, KV), None)} if cfg.qk_norm else {}),
         }
 
     def swiglu_shapes(n):
@@ -403,7 +479,9 @@ def param_shapes(cfg: DecoderConfig) -> Params:
         }
 
     n_lead = cfg.n_lead_layers
-    L = L - n_lead  # the main stack; the leading dense layers are their own
+    # the main stack: the leading dense layers, and the layers of the other
+    # kind where kinds alternate, are stacks of their own
+    L = cfg.n_kv_layers - n_lead
     blocks: Params = attn_shapes(L)
     if cfg.has_ln2:
         blocks["ln2"] = norm_shape(L)
@@ -454,6 +532,22 @@ def param_shapes(cfg: DecoderConfig) -> Params:
     }
     if lead is not None:
         shapes["lead"] = lead
+    if cfg.linear_attn is not None:
+        m, n = cfg.linear_attn, cfg.n_state_layers
+        shapes["linear"] = {
+            "ln1": norm_shape(n), "ln2": norm_shape(n), **swiglu_shapes(n),
+            # q, k and v in one projection, as the one convolution over
+            # their concatenated channels reads them; a and b likewise
+            "gdn_qkv": LinearParams(sds(n, E, m.conv_dim), None),
+            "gdn_ab": LinearParams(sds(n, E, 2 * m.n_heads), None),
+            "gdn_g": LinearParams(sds(n, E, m.value_dim), None),
+            "gdn_o": LinearParams(sds(n, m.value_dim, E), None),
+            # [K, C]: channels minor, no bias
+            "gdn_conv": LinearParams(sds(n, m.d_conv, m.conv_dim), None),
+            "gdn_A_log": sds(n, m.n_heads),
+            "gdn_dt_bias": sds(n, m.n_heads),
+            "gdn_norm": NormParams(sds(n, m.value_head_dim), None),
+        }
     if cfg.positions == "learned":
         shapes["wpe"] = sds(cfg.max_position_embeddings, E)
     if not cfg.tie_word_embeddings:
@@ -624,6 +718,72 @@ def _mixer(cfg: DecoderConfig, bp: Params, x, ssm, conv, lens):
     return out, (ssm, conv)
 
 
+def _gdn_mixer(cfg: DecoderConfig, bp: Params, x, ssm, conv, lens):
+    """The mixer of a linear-attention layer (Gated DeltaNet): ``x``
+    [B, S, E] the layer's input, ``ssm`` [B, H, Dk, Dv] float32 and
+    ``conv`` [B, (K-1) * C] (the window, flattened as the pool holds it)
+    this layer's state of every row, ``lens`` [B] how many of the
+    S positions are real (0: the row is done and its state stays as it is).
+    Returns the mixer's output [B, S, E] and the new ``(ssm, conv)``.
+
+    S == 1 is the decode update (``gdn.decode``), anything longer the
+    chunked form (``gdn.prefill``); both start from the state handed in and
+    treat positions at or after ``lens`` as no-ops (``g = 0``, ``beta = 0``,
+    window taken at the true length)."""
+    m = cfg.linear_attn
+    B, S, _ = x.shape
+    H, Dk, Dv = m.n_heads, m.key_head_dim, m.value_head_dim
+    f32 = jnp.float32
+    with jax.named_scope("gdn.conv"):
+        qkv, window = causal_conv(
+            dense(x, bp["gdn_qkv"]), conv.reshape(B, m.d_conv - 1, m.conv_dim),
+            bp["gdn_conv"].w, None, lens,
+        )
+        qkv = jax.nn.silu(qkv)
+    q, k, v = jnp.split(qkv, [m.key_dim, 2 * m.key_dim], axis=-1)
+    q = l2_normalize(q.reshape(B, S, H, Dk)) * Dk ** -0.5
+    k = l2_normalize(k.reshape(B, S, H, Dk))
+    v = v.reshape(B, S, H, Dv)
+    a, b = jnp.split(dense(x, bp["gdn_ab"]).astype(f32), 2, axis=-1)
+    live = (jnp.arange(S, dtype=lens.dtype)[None, :] < lens[:, None])[..., None]
+    beta = jax.nn.sigmoid(b) * (2.0 if m.allow_neg_eigval else 1.0)
+    beta = jnp.where(live, beta, 0.0)
+    g = -jnp.exp(bp["gdn_A_log"].astype(f32)) * jax.nn.softplus(
+        a + bp["gdn_dt_bias"].astype(f32)
+    )
+    g = jnp.where(live, g, 0.0)
+    if S == 1:
+        with jax.named_scope("gdn.decode"):
+            o, ssm = gdn_step(
+                q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], ssm
+            )
+            o = o[:, None]
+    else:
+        with jax.named_scope("gdn.prefill"):
+            o, ssm = gdn_chunked(q, k, v, g, beta, ssm)
+    with jax.named_scope("gdn.gate"):
+        # RMSNorm over each head's values, then the output gate
+        o = o * jax.lax.rsqrt(
+            jnp.mean(o * o, axis=-1, keepdims=True) + cfg.norm_eps
+        ) * bp["gdn_norm"].scale.astype(f32)
+        gate = dense(x, bp["gdn_g"]).astype(f32).reshape(B, S, H, Dv)
+        o = (o * jax.nn.silu(gate)).reshape(B, S, H * Dv)
+        out = dense(o.astype(x.dtype), bp["gdn_o"])
+    return out, (ssm, window.reshape(conv.shape))
+
+
+def _linear_block(cfg: DecoderConfig, bp: Params, h, state_in):
+    """One linear-attention layer of a model whose kinds alternate: the
+    mixer then the MLP, each normed AFTER it and added (``cfg.post_norm``,
+    the only form such a model has here). ``state_in`` is ``(ssm, conv,
+    lens)`` as ``_gdn_mixer`` takes them; returns ``(h, (ssm, conv))``."""
+    spec = P(AXIS_DP, None, None)  # the paged layouts have no sp axis
+    mix, state = _gdn_mixer(cfg, bp, h, *state_in)
+    h = h + constrain(_norm(cfg, mix, bp["ln1"]), spec)
+    h = h + _norm(cfg, _mlp(cfg, bp, h), bp["ln2"])
+    return constrain(h, spec), state
+
+
 def _latent_block(cfg: DecoderConfig, bp: Params, h, positions, sin_cos,
                   attend, live, experts, mesh=None):
     """One block of a model with latent attention: pre-norm, sequential
@@ -673,6 +833,10 @@ def _block(
     # (ssm [B, H, P, N], conv [B, K-1, C], lens [B]) of this layer, for a
     # config with a mixer (see ``_mixer``)
     ssm_in=None,
+    # heads of the paged pool this block reads and writes, where they are
+    # more than the model's (``DecoderConfig.pool_kv_heads``): q, k and v
+    # are padded with zero heads up to it and the padding's output dropped
+    pool_heads: int | None = None,
 ):
     """One decoder block. The last element returned is the mixer's new
     ``(ssm, conv)`` state, None for a config without one.
@@ -693,11 +857,18 @@ def _block(
     kv_spec = head_spec if Hkv > 1 else P(AXIS_DP, seq_ax, None, None)
 
     res = h
-    x = _norm(cfg, h, bp["ln1"])
+    # post-norm (Olmo 2): a branch reads the residual as it is and is
+    # normed on its way back into it
+    x = h if cfg.post_norm else _norm(cfg, h, bp["ln1"])
 
     xa = _scale(x, cfg.attn_in_multiplier)
-    q = constrain(dense_t(xa, bp["q"]).reshape(B, S, Hq, D), head_spec)
+    q = dense_t(xa, bp["q"])
+    if cfg.qk_norm:  # over the whole projection, before the head split
+        q = _norm(cfg, q, bp["q_norm"])
+    q = constrain(q.reshape(B, S, Hq, D), head_spec)
     k = _scale(dense_t(xa, bp["k"]), cfg.key_multiplier)
+    if cfg.qk_norm:
+        k = _norm(cfg, k, bp["k_norm"])
     k = constrain(k.reshape(B, S, Hkv, D), kv_spec)
     v = constrain(dense(xa, bp["v"]).reshape(B, S, Hkv, D), kv_spec)
 
@@ -710,6 +881,11 @@ def _block(
             k, positions, rotary_dim=cfg.rotary_dim, theta=cfg.rope_theta,
             style=cfg.rope_style, sin_cos=sin_cos,
         )
+
+    padded = pool_heads is not None and pool_heads != Hkv
+    if padded:
+        pad = [(0, 0), (0, 0), (0, pool_heads - Hkv), (0, 0)]
+        q, k, v = jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad)
 
     if defer_write:
         if attn_override is not None:
@@ -727,9 +903,13 @@ def _block(
             kv_positions=kv_positions, scale=cfg.attn_scale, mesh=mesh,
             window=cfg.sliding_window,
         )
+    if padded:
+        attn = attn[:, :, :Hq]
     attn = _scale(
         dense(attn.reshape(B, S, Hq * D), bp["o"]), cfg.attn_out_multiplier
     )
+    if cfg.post_norm:
+        attn = _norm(cfg, attn, bp["ln1"])
     ssm_out = None
     if cfg.ssm is not None:
         # The second branch reads the same normed input and adds to the
@@ -744,6 +924,9 @@ def _block(
         # own pre-norm (parallel_residual_ln2).
         mlp_in = _norm(cfg, res, bp["ln2"]) if cfg.has_ln2 else x
         h = res + attn + _mlp(cfg, bp, mlp_in)
+    elif cfg.post_norm:
+        h = res + attn
+        h = h + _norm(cfg, _mlp(cfg, bp, h), bp["ln2"])
     else:
         h = res + attn
         x2 = _norm(cfg, h, bp["ln2"])
@@ -762,19 +945,29 @@ def _ssm_lens(cache, kv_write_positions, slots):
     return jnp.sum(live.astype(jnp.int32), axis=1)
 
 
-def _layer_scan(cfg: DecoderConfig, cache, lens, body, h, xs):
+def _layer_scan(cfg: DecoderConfig, cache, lens, body, h, xs, linear=None):
     """``lax.scan`` of ``body(h, xs, ssm_in) -> (h, ys, ssm_out)`` over the
-    stacked layers; returns ``(h, ys, state)``. For a config with a mixer
-    the recurrent state pool ``[L, rows, ...]`` rides the scan's carry and
-    each layer's slice is updated in place: the pool is donated with the
-    rest of the cache, so a step holds one copy of it and copies none.
+    stacked layers; returns ``(h, ys, state)``. For a config with a
+    recurrent state the state pool ``[L_state, rows, ...]`` rides the scan's
+    carry and each layer's slice is updated in place: the pool is donated
+    with the rest of the cache, so a step holds one copy of it and copies
+    none.
+
+    Where the kinds of layer ALTERNATE (``cfg.layer_types``) the scan is over
+    PERIODS of the pattern: ``xs`` (every leaf ``[L_kv, ...]``) is the
+    attention layers' and ``linear`` (``params["linear"]``, ``[L_state,
+    ...]``) the linear-attention layers' stack, both closed over; a step of
+    the scan runs one period's layers in the published order, ``body`` on each attention layer
+    (no state) and ``_linear_block`` on each linear one, every layer indexing
+    its own stack and pool by its index WITHIN its kind. ``ys`` comes back
+    ``[L_kv, ...]``.
 
     ``cache.state_rows`` None: batch row i IS pool row i and goes on from
     the state it has (decode, and a ragged chunk). Set (an admission view):
     every batch row starts from zeros, as a prompt does, and its final state
     is written to pool row ``state_rows[i]``; a row index out of range (the
     view's padding rows) writes nowhere."""
-    if cfg.ssm is None:
+    if not cfg.has_state:
         def plain(h, xs):
             h, ys, _ = body(h, xs, None)
             return h, ys
@@ -783,27 +976,72 @@ def _layer_scan(cfg: DecoderConfig, cache, lens, body, h, xs):
         return h, ys, None
     rows, B = cache.state_rows, h.shape[0]
 
-    def stateful(carry, xs_l):
+    def state_in(ssm, conv, l):
+        if rows is None:
+            return ssm[l], conv[l]
+        return (jnp.zeros((B,) + ssm.shape[2:], ssm.dtype),
+                jnp.zeros((B,) + conv.shape[2:], conv.dtype))
+
+    def state_out(ssm, conv, l, s_l, c_l):
+        if rows is None:
+            return ssm.at[l].set(s_l), conv.at[l].set(c_l)
+        return (ssm.at[l, rows].set(s_l, mode="drop"),
+                conv.at[l, rows].set(c_l, mode="drop"))
+
+    if cfg.layer_types is None:
+        def stateful(carry, xs_l):
+            h, ssm, conv = carry
+            xs, l = xs_l
+            s_in, c_in = state_in(ssm, conv, l)
+            h, ys, (s_l, c_l) = body(h, xs, (s_in, c_in, lens))
+            return (h, *state_out(ssm, conv, l, s_l, c_l)), ys
+
+        (h, ssm, conv), ys = jax.lax.scan(
+            stateful, (h, cache.ssm, cache.conv),
+            (xs, jnp.arange(cfg.n_layers, dtype=jnp.int32)),
+        )
+        return h, ys, (ssm, conv)
+
+    period = cfg.period
+    n_per = cfg.n_layers // len(period)
+    n_lin = period.count("linear_attention")
+    n_kv = len(period) - n_lin
+
+    def one_period(carry, p):
         h, ssm, conv = carry
-        xs, l = xs_l
-        if rows is None:
-            s_in, c_in = ssm[l], conv[l]
-        else:
-            s_in = jnp.zeros((B,) + ssm.shape[2:], ssm.dtype)
-            c_in = jnp.zeros((B,) + conv.shape[2:], conv.dtype)
-        h, ys, (s_l, c_l) = body(h, xs, (s_in, c_in, lens))
-        if rows is None:
-            ssm, conv = ssm.at[l].set(s_l), conv.at[l].set(c_l)
-        else:
-            ssm = ssm.at[l, rows].set(s_l, mode="drop")
-            conv = conv.at[l, rows].set(c_l, mode="drop")
-        return (h, ssm, conv), ys
+        ys, i_lin, i_kv = [], 0, 0
+        for kind in period:
+            # Each layer reads ITS layer of the whole stack, closed over, by
+            # a dynamic index: with a period's layers handed in as one ``xs``
+            # block the compiler copies that block (three layers' matrices)
+            # out of the stack every period of every step.
+            if kind == "linear_attention":
+                l = p * n_lin + i_lin
+                h, (s_l, c_l) = _linear_block(
+                    cfg, _layer_of(linear, l), h,
+                    (*state_in(ssm, conv, l), lens),
+                )
+                ssm, conv = state_out(ssm, conv, l, s_l, c_l)
+                i_lin += 1
+            else:
+                h, y, _ = body(h, _layer_of(xs, p * n_kv + i_kv), None)
+                ys.append(y)
+                i_kv += 1
+        return (h, ssm, conv), jax.tree.map(lambda *a: jnp.stack(a), *ys)
 
     (h, ssm, conv), ys = jax.lax.scan(
-        stateful, (h, cache.ssm, cache.conv),
-        (xs, jnp.arange(cfg.n_layers, dtype=jnp.int32)),
+        one_period, (h, cache.ssm, cache.conv),
+        jnp.arange(n_per, dtype=jnp.int32),
     )
+    ys = jax.tree.map(lambda a: a.reshape(-1, *a.shape[2:]), ys)
     return h, ys, (ssm, conv)
+
+
+def _layer_of(stack, l):
+    """Layer ``l`` (traced) of a pytree of ``[L, ...]`` stacks."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, l, 0, keepdims=False), stack
+    )
 
 
 def _make_decode_kernel_attn(cfg, mesh, cache, positions, slots):
@@ -1045,7 +1283,7 @@ def forward(
             "a model with latent attention is served from the paged cache "
             "only (kv_layout='paged'): the dense ring has no latent pool"
         )
-    if cfg.ssm is not None:
+    if cfg.has_state:
         raise NotImplementedError(
             "a model with a recurrent state is served from the paged cache "
             "only (kv_layout='paged'): the dense ring has no state pool"
@@ -1468,13 +1706,14 @@ def _forward_paged(
                 cfg, bp, h, positions, None, None, kv_pos_src, slots,
                 None, mesh=mesh, defer_write=True,
                 attn_override=partial(attn, layer=layer),
-                sin_cos=sin_cos, ssm_in=ssm_in,
+                sin_cos=sin_cos, ssm_in=ssm_in, pool_heads=cache.k.shape[3],
             )
             return h, (k_f, v_f), ssm_out
 
         h, ys, state = _layer_scan(
             cfg, cache, lens, body, h,
-            (params["blocks"], jnp.arange(cfg.n_layers, dtype=jnp.int32)),
+            (params["blocks"], jnp.arange(cfg.n_kv_layers, dtype=jnp.int32)),
+            linear=params.get("linear"),
         )
 
         ks_new, vs_new = cache.k_scale, cache.v_scale
@@ -1521,6 +1760,7 @@ def _forward_paged(
             h, _, _, k_f, v_f, ssm_out = _block(
                 cfg, bp, h, positions, k_l, v_l, new_kv_positions, slots,
                 mask, mesh=mesh, sin_cos=sin_cos, ssm_in=ssm_in,
+                pool_heads=cache.k.shape[3],
             )
             if quant:
                 # Quantize only the fresh tokens (storage bit-stability —
@@ -1545,12 +1785,14 @@ def _forward_paged(
                 cfg, cache, lens, body, h,
                 (params["blocks"], cache.k, cache.v, cache.k_scale,
                  cache.v_scale),
+                linear=params.get("linear"),
             )
         else:
             ks_new, vs_new = None, None
             h, (k_new, v_new), state = _layer_scan(
                 cfg, cache, lens, body, h,
                 (params["blocks"], cache.k, cache.v),
+                linear=params.get("linear"),
             )
 
     logits = _head_out(cfg, params, h, gather_idx, last_only)
@@ -1874,13 +2116,14 @@ def forward_ragged(
             cfg, bp, h, positions, None, None, kv_pos_src, slots,
             None, mesh=mesh, defer_write=True,
             attn_override=partial(attn, layer=layer),
-            sin_cos=sin_cos, ssm_in=ssm_in,
+            sin_cos=sin_cos, ssm_in=ssm_in, pool_heads=cache.k.shape[3],
         )
         return h, (k_f, v_f), ssm_out
 
     h, ys, state = _layer_scan(
         cfg, cache, lens, body, h,
-        (params["blocks"], jnp.arange(cfg.n_layers, dtype=jnp.int32)),
+        (params["blocks"], jnp.arange(cfg.n_kv_layers, dtype=jnp.int32)),
+        linear=params.get("linear"),
     )
 
     ks_new, vs_new = cache.k_scale, cache.v_scale

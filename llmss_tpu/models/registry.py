@@ -14,7 +14,7 @@ from jax.sharding import Mesh
 
 from llmss_tpu.models import (
     deepseek_v3, falcon_h1, gemma, gpt2, gpt_bigcode, gpt_neox, gptj, llama,
-    mistral, phi3, qwen2,
+    mistral, olmo_hybrid, phi3, qwen2,
 )
 from llmss_tpu.models.common import DecoderConfig
 from llmss_tpu.models.decoder import Params
@@ -32,6 +32,7 @@ MODEL_REGISTRY = {
     "gemma": gemma,
     "falcon_h1": falcon_h1,
     "deepseek_v3": deepseek_v3,
+    "olmo_hybrid": olmo_hybrid,
 }
 
 

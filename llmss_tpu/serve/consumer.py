@@ -464,7 +464,7 @@ class ContinuousWorker:
                 "the store's blobs name keys and values "
                 "(docs/latent-cache.md)"
             )
-        if getattr(getattr(engine, "cfg", None), "ssm", None) is not None and (
+        if getattr(getattr(engine, "cfg", None), "has_state", False) and (
             role != "unified" or kvstore is not None
         ):
             # The hand-off's wire format and the tiered store's blobs hold

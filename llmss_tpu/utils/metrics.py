@@ -156,10 +156,14 @@ class EngineMetrics:
         self.decode_steps = 0  # guarded_by: self._lock
         self.filter_steps = 0  # guarded_by: self._lock
         self.loop_spans: dict[str, list] = {}  # guarded_by: self._lock
-        # A model with a recurrent state (cfg.ssm): the bytes of the state
-        # pool beside the paged keys and values (a gauge; None = the model
-        # has none and /metrics has no ``cache`` block).
+        # A model with a recurrent state (cfg.has_state): the bytes of the
+        # state pool beside the paged keys and values, and the layers that
+        # hold a state and those that hold keys and values (the two pools'
+        # layer axes: what prices a step without the config's keys). Gauges;
+        # None = the model has none and /metrics has no ``cache`` block.
         self.cache_state_bytes: int | None = None  # guarded_by: self._lock
+        self.cache_state_layers: int | None = None  # guarded_by: self._lock
+        self.cache_kv_layers: int | None = None  # guarded_by: self._lock
         # A model with latent attention (cfg.mla): the bytes ONE token holds
         # in the latent pool over all layers (a gauge in the same block).
         self.cache_latent_bytes_per_token: int | None = None  # guarded_by: self._lock
@@ -272,9 +276,12 @@ class EngineMetrics:
             if filtered:
                 self.filter_steps += steps
 
-    def set_state_bytes(self, n: int) -> None:
+    def set_state_pool(self, n_bytes: int, state_layers: int,
+                       kv_layers: int) -> None:
         with self._lock:
-            self.cache_state_bytes = n
+            self.cache_state_bytes = n_bytes
+            self.cache_state_layers = state_layers
+            self.cache_kv_layers = kv_layers
 
     def set_latent_bytes_per_token(self, n: int) -> None:
         with self._lock:
@@ -338,6 +345,8 @@ class EngineMetrics:
                 ))
             gauges = {
                 "state_bytes": self.cache_state_bytes,
+                "state_layers": self.cache_state_layers,
+                "kv_layers": self.cache_kv_layers,
                 "latent_bytes_per_token": self.cache_latent_bytes_per_token,
             }
             gauges = {k: v for k, v in gauges.items() if v is not None}

@@ -11,7 +11,7 @@ not a chip run.
 
 Two whole step programs are compiled too, the decode group and the ragged
 group at the widths and envelopes the benchmark serves (``starcoderbase-1b``,
-``falcon-h1-34b-1chip``, ``kanana-2-30b-a3b-1chip``): what the compiler does
+``falcon-h1-34b-1chip``, ``kanana-2-30b-a3b-1chip``, ``olmo-hybrid-7b-1chip``): what the compiler does
 to the block pool the step loop carries shows only in the compiled text (a
 transpose of the whole pool every step, once; a copy of a whole layer out of
 the stack for every layer of every step, once: docs/paged-kv.md).
@@ -179,13 +179,13 @@ def _compile_step(device, program: str, cfg, POSITIONS: int):
         param_shapes(cfg), param_specs(cfg, 1),
     )
     mb = POSITIONS // BS
-    pool = (cfg.n_layers, ROWS * mb, BS) + cfg.cache_row
+    pool = (cfg.n_kv_layers, ROWS * mb, BS) + cfg.cache_row
     cache = PagedKVCache(
         k=arr(pool, DT), v=None if cfg.mla is not None else arr(pool, DT),
         block_tables=arr((ROWS, mb), jnp.int32),
         positions=arr((ROWS, POSITIONS), jnp.int32),
         **dict(zip(("ssm", "conv"), (
-            arr((cfg.n_layers, ROWS) + shape, dtype)
+            arr((cfg.n_state_layers, ROWS) + shape, dtype)
             for shape, dtype in ssm_state_shapes(cfg) or ()
         ))),
     )
@@ -225,11 +225,12 @@ def _results(hlo_text: str, name: str):
             yield line.strip()[:120], tuple(map(int, m[1].split(",")))
 
 
-def _pool_sized_copies(hlo_text: str, pool: tuple) -> list[str]:
-    """The ``copy`` instructions whose result has as many elements as the
-    pool, whatever dimensions a bitcast gave it."""
+def _pool_sized_copies(hlo_text: str, pool: tuple, ops: str = "copy") -> list[str]:
+    """The ``copy`` instructions (or those named ``ops``, a regex) whose
+    result has as many elements as the pool, whatever dimensions a bitcast
+    gave it."""
     return [
-        line for line, dims in _results(hlo_text, "copy")
+        line for line, dims in _results(hlo_text, ops)
         if math.prod(dims) == math.prod(pool)
     ]
 
@@ -270,6 +271,34 @@ def test_step_program_carries_the_pool_in_place(v5e, program, widths):
     if isinstance(widths, int):  # the other's temporaries are the state's
         pool_bytes = math.prod(pool) * jnp.dtype(DT).itemsize
         assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 10
+
+
+@pytest.mark.parametrize("program", ["decode", "ragged"])
+def test_two_kinds_of_layer_carry_every_pool_in_place(v5e, program):
+    """``olmo-hybrid-7b-1chip`` at the published widths, 64 rows x 1,024
+    positions: the block pool of the 3 attention layers (32 heads for the
+    model's 30: at 30 the program copied it into another layout and back,
+    four copies of 1.5 GB a group), the delta rule's state pool of the 9
+    linear-attention layers (``[.., 30, 96, 192]`` as the update computes on
+    it, 192 padded to two lane tiles on the device: flattened to whole tiles
+    it is re-tiled a layer at a time on the way in and out, three more
+    passes a layer a step) and their window pool (flattened: as ``[rows, 3,
+    C]`` it was copied into a rows-second-minor layout and back) each go
+    through the decode and the mixed group as they came: no pool-sized
+    ``copy``, ``transpose`` or ``dynamic-slice`` of any of the three, no
+    re-tiling ``copy`` of a layer's state, no layer-sized slice of the block
+    pool, and the arguments and temporaries fit the chip."""
+    compiled, pool = _compile_group(v5e, program, "olmo-hybrid-7b-1chip")
+    assert pool == (3, 64 * 64, 16, 32, 128)
+    text = compiled.as_text()
+    moved = r"(?:\w+_)?(?:copy|transpose|dynamic[-_]slice)"
+    for shape in (pool, (9, 64, 30, 96, 192), (9, 64, 3 * 11520)):
+        assert _pool_sized_copies(text, shape, moved) == [], shape
+    assert _pool_sized_copies(text, (64, 30, 96, 192)) == []
+    assert _layer_sized_slices(text, pool) == []
+    ma = compiled.memory_analysis()
+    assert ma.argument_size_in_bytes == pytest.approx(11.50e9, rel=0.01)
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 14.5e9
 
 
 # kakaocorp/kanana-2-30b-a3b-instruct-2601's widths (deepseek_v3: a latent
