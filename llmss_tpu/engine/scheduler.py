@@ -353,6 +353,8 @@ class ContinuousBatcher:
         # garbage nobody consumes and their writes stay within their own
         # row, so only active rows constrain the bucket.
         self._row_pos: dict[int, int] = {}
+        # chunk -> how a step of that many tokens a row reads the paged pool
+        self._attn_reads: dict[int, str] = {}
         # Device-resident decode state (see module docstring), carried in
         # the engine's canonical shardings so every executable keeps one
         # steady-state signature (DecodeEngine.canon_cache/canon_vec).
@@ -1936,6 +1938,32 @@ class ContinuousBatcher:
         )
         return ids, qlens, feed, emit, firsts
 
+    def _pool_read(self, chunk: int, t_bucket: int | None) -> dict:
+        """What the group about to be dispatched reads of the paged pool,
+        for its ``sched.dispatch`` span: ``attn_read``, the read its program
+        is traced with (``models.decoder.attn_read``); ``blocks_read``, the
+        blocks its live rows hold at the group's first step (a read that
+        stops at a row's length visits these); ``blocks_ring``, the table
+        columns a read of every row's whole ring or read bucket visits.
+        Their ratio is the share of the ring that holds anything."""
+        from llmss_tpu.models.decoder import attn_read
+
+        how = self._attn_reads.get(chunk)
+        if how is None:
+            how = self._attn_reads[chunk] = attn_read(
+                self.engine.cfg, self.cache, self.engine.mesh, chunk
+            )
+        bs, mb = self.cache.block_size, self.cache.max_blocks
+        if t_bucket is not None:
+            mb = min(-(-t_bucket // bs), mb)
+        return dict(
+            attn_read=how,
+            blocks_read=sum(
+                min(-(-n // bs), mb) for n in self._row_pos.values()
+            ),
+            blocks_ring=self.rows * mb,
+        )
+
     def step(self, loop: int | None = None) -> int:
         """One scheduler iteration of the pipelined loop:
 
@@ -1960,7 +1988,7 @@ class ContinuousBatcher:
         ``sched.resolve``, ``sched.preempt``, ``sched.admit``,
         ``sched.devtel``), a child of the worker's iteration span ``loop``.
         """
-        ragged = None
+        ragged = t_bucket = None  # a mixed group has no read bucket
         with self.loop_span("sched.plan", loop) as sp:
             self._process_cancellations()
             if self.active:
@@ -2018,6 +2046,10 @@ class ContinuousBatcher:
 
         with self.loop_span("sched.dispatch", loop) as sp:
             live = len(self.active)
+            if self._paged and sp is not trace.NO_LOOP_SPAN:
+                sp.set(**self._pool_read(
+                    1 if ragged is None else ragged[0].shape[-1], t_bucket
+                ))
             if ragged is not None:
                 ids_seq, qlens_seq, feed_seq, emit_seq, firsts = ragged
                 packed, last_tok, cache, cur_pos, _ = (

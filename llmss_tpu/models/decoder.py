@@ -1804,6 +1804,51 @@ def _forward_paged(
     )
 
 
+def _latent_value_cols(m) -> int:
+    """Leading columns of a latent row that are its value: the latent itself
+    (the rotary key and the padding follow), in whole lanes."""
+    return min(-(-m.kv_lora_rank // 128) * 128, m.pool_dim)
+
+
+def attn_read(cfg: DecoderConfig, cache: PagedKVCache, mesh, chunk: int) -> str:
+    """How a decode step (``chunk`` 1) or a mixed step of ``chunk`` tokens a
+    row reads the paged pool, as its program is traced NOW: ``gather`` (the
+    rows' logical views gathered, the XLA oracles), ``mla.kernel`` (a latent
+    pool read in place, ops/pallas_mla.py) or ``kernel`` (the block-table
+    kernels ``LLMSS_ATTN_IMPL=pallas`` opts a pool of keys and values into).
+
+    A latent pool's kernel is chosen by ``dispatch_attention``'s own rule:
+    shapes inside ``pallas_mla.supports`` and compiled on a TPU, or forced
+    (interpreted: the CPU tests); never under ``force == "xla"``."""
+    import importlib
+
+    from llmss_tpu.ops import pallas_mla
+
+    attention_mod = importlib.import_module("llmss_tpu.ops.attention")
+    force = attention_mod.IMPL_OVERRIDE
+    if cfg.mla is None:
+        return "kernel" if force == "pallas" and mesh is not None else "gather"
+    if force == "xla":
+        return "gather"
+    ok = (
+        (mesh is None or mesh.size == 1)
+        and cache.k.dtype == cfg.compute_dtype
+        and pallas_mla.supports(
+            cache.block_size, cfg.n_heads, cfg.mla.pool_dim, chunk,
+            cache.k.dtype, _latent_value_cols(cfg.mla),
+        )
+    )
+    if force == "pallas" and not ok:
+        attention_mod.forced_pallas_miss(
+            "shapes out of the latent read kernel's envelope "
+            f"(bs={cache.block_size}, H={cfg.n_heads}, "
+            f"W={cfg.mla.pool_dim}, chunk={chunk}, {cache.k.dtype})"
+        )
+    if ok and (force == "pallas" or not attention_mod.pallas_interpret()):
+        return "mla.kernel"
+    return "gather"
+
+
 def _forward_latent(
     cfg: DecoderConfig,
     params: Params,
@@ -1860,7 +1905,36 @@ def _forward_latent(
         nb = min(-(-t_bucket // bs), MB)
     kv_pos_src = cache.positions[:, : (nb if nb is not None else MB) * bs]
 
-    if q_lens is not None:
+    if (q_lens is not None or S == 1) and (
+        attn_read(cfg, cache, mesh, S) == "mla.kernel"
+    ):
+        import importlib
+
+        from llmss_tpu.ops import pallas_mla
+
+        scope = "mla.decode"
+        interp = importlib.import_module(
+            "llmss_tpu.ops.attention"
+        ).pallas_interpret()
+        lens = q_lens if q_lens is not None else jnp.ones((B,), jnp.int32)
+        # table columns up to a row's last live slot: where the walk stops
+        last = jnp.max(
+            jnp.where(
+                cache.positions >= 0,
+                jnp.arange(cache.positions.shape[1], dtype=jnp.int32), -1,
+            ),
+            axis=1,
+        )
+
+        def attend(layer, q, lat):
+            # the pool as stored: no unit axis beside its minor dimension
+            return pallas_mla.latent_paged_attention(
+                q, cache.k, lat, positions[:, 0], lens, kv_pos_src, tables,
+                last // bs + 1, slots[:, 0], layer, ring_len=cache.max_len,
+                scale=cfg.attn_scale, v_dim=_latent_value_cols(cfg.mla),
+                interpret=interp,
+            )
+    elif q_lens is not None:
         scope = "mla.decode"
         q_pos0, slot0 = positions[:, 0], slots[:, 0]
         cache_vis = ragged_cache_visibility(

@@ -39,7 +39,8 @@ from llmss_tpu.engine.cache import PagedKVCache, ssm_state_shapes
 from llmss_tpu.models.decoder import param_shapes, param_specs
 from llmss_tpu.models.registry import config_from_hf
 from llmss_tpu.ops import (
-    pallas_attention, pallas_decode, pallas_paged_decode, pallas_ragged,
+    pallas_attention, pallas_decode, pallas_mla, pallas_paged_decode,
+    pallas_ragged,
 )
 from llmss_tpu.parallel import mesh as mesh_mod
 
@@ -102,6 +103,21 @@ def _kernel_call(kernel: str, Hq: int, Hkv: int, D: int):
             ((B, 1, Hkv, D), DT), ((B, 1), i32), ((B, MB * BS), i32),
             ((B, MB), i32), ((B,), i32), ((B, 1), i32), ((), i32),
         ]
+    if kernel == "latent_read":
+        # the latent pool's own kernel at the shapes of the benchmark's
+        # third cell: 64 rows of 320 blocks over [7, 20480, 16, 640], 32
+        # heads, a mixed step's 8 tokens a row (``Hkv``) or a decode step's 1
+        rows, mb, chunk, v_dim = 64, 320, Hkv, 512
+        assert pallas_mla.supports(BS, Hq, D, chunk, DT, v_dim)
+        row = ((rows,), i32)
+        return functools.partial(
+            pallas_mla.latent_paged_attention, ring_len=mb * BS,
+            scale=192 ** -0.5, v_dim=v_dim,
+        ), [
+            ((rows, chunk, Hq, D), DT), ((7, rows * mb, BS, D), DT),
+            ((rows, chunk, 1, D), DT), row, row, ((rows, mb * BS), i32),
+            ((rows, mb), i32), row, row, ((), i32),
+        ]
     assert kernel == "ragged"
     assert pallas_ragged.supports(BS, Hq, Hkv, D, DT)
     return pallas_ragged.ragged_paged_attention, [
@@ -112,12 +128,21 @@ def _kernel_call(kernel: str, Hq: int, Hkv: int, D: int):
     ]
 
 
-@pytest.mark.parametrize("model", WIDTHS)
+# (heads, tokens a row a step, row width) of the latent pool's read
+LATENT_READS = {"mixed-step": (32, 8, 640), "decode-step": (32, 1, 640)}
+
+
 @pytest.mark.parametrize(
-    "kernel", ["flash", "dense_decode", "paged_decode", "ragged"]
+    "kernel,model",
+    [
+        (kernel, model) for model in WIDTHS
+        for kernel in ("flash", "dense_decode", "paged_decode", "ragged")
+    ] + [("latent_read", step) for step in LATENT_READS],
 )
 def test_kernel_compiles_for_v5e(v5e, kernel, model):
-    fn, shapes = _kernel_call(kernel, *WIDTHS[model])
+    fn, shapes = _kernel_call(
+        kernel, *(WIDTHS | LATENT_READS)[model]
+    )
     on_chip = SingleDeviceSharding(v5e)
     args = [
         jax.ShapeDtypeStruct(shape, dtype, sharding=on_chip)
@@ -326,7 +351,10 @@ def test_latent_step_program_carries_the_pool_in_place(
     no head axis) goes through the step loops as it came: no pool-sized
     ``copy``. At 576 wide its default device layout had the block axis
     minor and every program transposed it four times (docs/latent-cache.md).
-    The grouped matmul over the experts compiles as the chip's own kernel."""
+    The grouped matmul over the experts compiles as the chip's own kernel,
+    and so does the read of the pool (ops/pallas_mla.py): no gather of the
+    rows' rings (all 64 x 320 blocks in the mixed step, 64 x 32 at the
+    decode step's 512-slot read), no float32 scores over them in HBM."""
     import importlib
 
     # the program asks jax.default_backend(), which is the CPU here
@@ -341,11 +369,17 @@ def test_latent_step_program_carries_the_pool_in_place(
     assert pool == (3, 64 * 320, 16, 640)
     text = compiled.as_text()
     assert _pool_sized_copies(text, pool) == []
-    assert text.count("tpu_custom_call") >= 3  # gate, up, down
-    # the temporaries are the rows' gathered views (float32 in the mixed
-    # step: 0.63 GB), under this three-layer pool's 1.26 GB
+    assert text.count("tpu_custom_call") >= 4  # gate, up, down, the read
+    views = [
+        line for line in text.splitlines()
+        if re.search(r"bf16\[(20480|2048),16,640\]", line)
+        or re.search(r"f32\[64,[\d,]*5120\]|f32\[64,1,32,\d,512\]", line)
+    ]
+    assert views == []
+    # the temporaries were the rows' gathered views and the scores (0.63 GB
+    # in the mixed step); now 0.035 GB beside this three-layer pool's 1.26
     pool_bytes = math.prod(pool) * jnp.dtype(DT).itemsize
-    assert compiled.memory_analysis().temp_size_in_bytes < 0.6 * pool_bytes
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.05 * pool_bytes
 
 
 def test_supports_refuses_what_vmem_cannot_hold():
@@ -356,6 +390,10 @@ def test_supports_refuses_what_vmem_cannot_hold():
     assert pallas_decode.supports(1024, 16, 16, 256, jnp.float32)
     assert not pallas_decode.supports(1024, 64, 64, 256, jnp.float32)
     assert not pallas_paged_decode.supports(2048, 64, 64, 256, jnp.float32)
+    # the latent read: 256 query rows of 640 fit, 2048 do not
+    assert pallas_mla.supports(BS, 32, 640, 8, DT, 512)
+    assert not pallas_mla.supports(BS, 128, 640, 16, DT, 512)
+    assert not pallas_mla.supports(BS, 32, 576, 8, DT)  # not whole lanes
 
 
 @pytest.fixture

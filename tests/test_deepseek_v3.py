@@ -9,6 +9,7 @@ feature that must carry the latent pool or refuse the model. Weights are the
 family's own seeded draw (``init_params``), norm scales + 1 as the
 benchmark's server makes them."""
 
+import contextlib
 import dataclasses
 import importlib.util
 import types
@@ -25,8 +26,10 @@ from llmss_tpu.models import decoder
 from llmss_tpu.models.decoder import forward, init_params
 from llmss_tpu.models.registry import MODEL_REGISTRY, config_from_hf
 from llmss_tpu.ops import moe
+from llmss_tpu.ops.attention import force_impl
 from llmss_tpu.ops.layers import NormParams
 from llmss_tpu.parallel import MeshPlan, make_mesh
+from llmss_tpu.utils import trace
 
 ROOT = Path(__file__).resolve().parent.parent
 
@@ -214,12 +217,53 @@ def test_batcher_rows_match_isolated_and_metrics_count_the_routing(engine):
     assert 0 < loop["moe.experts_hit"] <= loop["moe.pairs"]
 
 
-def test_the_mixed_step_carries_the_latent_pool(engine):
+@contextlib.contextmanager
+def served_by(engine, mesh, impl):
+    """The engine whose step programs read the pool by ``impl``: the
+    module's own (the gather, as the CPU chooses) or a fresh one traced with
+    the latent read kernel forced on (interpreted)."""
+    if impl is None:
+        yield engine
+        return
+    with force_impl(impl):
+        eng = make_engine(mesh)
+        cache = eng.new_paged_cache(2)
+        assert decoder.attn_read(eng.cfg, cache, mesh, 8) == "mla.kernel"
+        assert decoder.attn_read(eng.cfg, cache, mesh, 1) == "mla.kernel"
+        yield eng
+
+
+IMPLS = pytest.mark.parametrize("impl", [None, "pallas"], ids=["xla", "pallas"])
+
+
+@IMPLS
+def test_the_mixed_step_carries_the_latent_pool(engine, mesh, impl):
     """Prompts streamed through the mixed step, 8 tokens a row a step,
-    beside rows that decode; tokens equal each request's own alone and no
-    executable compiles after prewarm."""
+    beside rows that decode; tokens equal each request's own alone (on the
+    gather, whichever read serves) and no executable compiles after
+    prewarm."""
     prompts, gens = _five(7)
     expected = [engine.generate([p], g)[0] for p, g in zip(prompts, gens)]
+    trace.set_enabled(True)
+    trace.recorder().clear()
+    with served_by(engine, mesh, impl) as eng:
+        _mixed_step(eng, prompts, gens, expected)
+    # every group's span says which read its program was traced with, the
+    # blocks its rows held and the blocks of their rings (2 rows x 8)
+    spans = [sp[5] for sp in trace.recorder().loop_spans()
+             if sp[2] == "sched.dispatch"]
+    assert {a["kind"] for a in spans} == {"ragged_group", "decode_group"}
+    assert {a["attn_read"] for a in spans} == {
+        "mla.kernel" if impl else "gather"}
+    for a in spans:
+        # a forced kernel's decode groups have no read bucket
+        ring = 2 * -(-(a.get("t_bucket") or MAX_LEN) // 16)
+        assert 0 <= a["blocks_read"] <= a["blocks_ring"] == ring
+    reads = [a["blocks_read"] for a in spans]
+    assert reads[0] == 0 and max(reads) == 5, reads  # nothing cached at first
+
+
+def _mixed_step(engine, prompts, gens, expected):
     batcher = ContinuousBatcher(engine, rows=2, chunked_prefill=8)
     batcher.prewarm()
     compiled = []
@@ -230,12 +274,18 @@ def test_the_mixed_step_carries_the_latent_pool(engine):
     assert not compiled
 
 
-def test_preempt_and_replay_equals_uninterrupted(engine):
+@IMPLS
+def test_preempt_and_replay_equals_uninterrupted(engine, mesh, impl):
     gen_low = GenerationParams(max_new_tokens=12, is_greedy=True)
     gen_hi = GenerationParams(max_new_tokens=4, is_greedy=True)
     p_low, p_hi = prompts_of([11, 6], seed=3)
     exp_low = engine.generate([p_low], gen_low)[0]
     exp_hi = engine.generate([p_hi], gen_hi)[0]
+    with served_by(engine, mesh, impl) as eng:
+        _preempt_and_replay(eng, gen_low, gen_hi, p_low, p_hi, exp_low, exp_hi)
+
+
+def _preempt_and_replay(engine, gen_low, gen_hi, p_low, p_hi, exp_low, exp_hi):
     b = ContinuousBatcher(engine, rows=1)
     got, evicted = {}, {}
 
