@@ -224,41 +224,43 @@ class DecodeEngine:
             self._cache_dtype = jnp.int8
         else:
             self._cache_dtype = cfg.compute_dtype
-        self.metrics = EngineMetrics()
-        self._ladder = self.bucket_ladder()
-        self._canon_cache_memo: dict[tuple, KVCache | PagedKVCache] = {}
+        with devtel.setup_span("setup.engine"):
+            self.metrics = EngineMetrics()
+            self._ladder = self.bucket_ladder()
+            self._canon_cache_memo: dict[tuple, KVCache | PagedKVCache] = {}
 
-        # mesh is partial-bound (a compile-time constant, not a traced arg):
-        # it enables the shard_map'd Pallas attention path inside forward.
-        self._prefill = jax.jit(
-            partial(self._prefill_impl, cfg, mesh), donate_argnums=(2,),
-        )
-        self._decode = jax.jit(
-            partial(self._decode_impl, cfg, mesh), donate_argnums=(2,),
-            static_argnames=("t_bucket",),
-        )
-        # Grouped decode: n_chunks fused chunks in ONE program with ONE
-        # packed device→host fetch for the whole group. Donates the token
-        # and position carries as well as the cache — XLA reuses their
-        # storage across every step of the group.
-        self._decode_group = jax.jit(
-            partial(self._decode_group_impl, cfg, mesh),
-            donate_argnums=(1, 2, 3),
-            static_argnames=("n_chunks", "n_steps", "t_bucket"),
-        )
-        # Ragged mixed prefill+decode group (chunked prefill): each scan
-        # step advances decode rows by one token AND streams chunk-budget
-        # slices of in-flight prompts through the same dispatch
-        # (forward_ragged). Executable identity is keyed purely by the xs
-        # shapes [n_chunks, B(, CB)] — no static args, no bucket ladder.
-        self._ragged_group = jax.jit(
-            partial(self._ragged_group_impl, cfg, mesh),
-            donate_argnums=(1, 2, 3),
-        )
-        self._admit_merge = jax.jit(
-            self._admit_merge_impl, donate_argnums=(0, 1)
-        )
-        self._seed = jax.jit(self._seed_impl, donate_argnums=(0,))
+            # mesh is partial-bound (a compile-time constant, not a traced
+            # arg): it enables the shard_map'd Pallas attention path inside
+            # forward.
+            self._prefill = jax.jit(
+                partial(self._prefill_impl, cfg, mesh), donate_argnums=(2,),
+            )
+            self._decode = jax.jit(
+                partial(self._decode_impl, cfg, mesh), donate_argnums=(2,),
+                static_argnames=("t_bucket",),
+            )
+            # Grouped decode: n_chunks fused chunks in ONE program with ONE
+            # packed device→host fetch for the whole group. Donates the token
+            # and position carries as well as the cache — XLA reuses their
+            # storage across every step of the group.
+            self._decode_group = jax.jit(
+                partial(self._decode_group_impl, cfg, mesh),
+                donate_argnums=(1, 2, 3),
+                static_argnames=("n_chunks", "n_steps", "t_bucket"),
+            )
+            # Ragged mixed prefill+decode group (chunked prefill): each scan
+            # step advances decode rows by one token AND streams chunk-budget
+            # slices of in-flight prompts through the same dispatch
+            # (forward_ragged). Executable identity is keyed purely by the xs
+            # shapes [n_chunks, B(, CB)] — no static args, no bucket ladder.
+            self._ragged_group = jax.jit(
+                partial(self._ragged_group_impl, cfg, mesh),
+                donate_argnums=(1, 2, 3),
+            )
+            self._admit_merge = jax.jit(
+                self._admit_merge_impl, donate_argnums=(0, 1)
+            )
+            self._seed = jax.jit(self._seed_impl, donate_argnums=(0,))
 
     # -- jitted bodies ------------------------------------------------------
 
@@ -785,22 +787,33 @@ class DecodeEngine:
         sub-second scatter compile, not a model compile.)"""
         if isinstance(chunk_steps, int):
             chunk_steps = (chunk_steps,)
-        sa = self._sample_args(GenerationParams(), batch)
         if devtel.enabled():
             devtel.install_monitoring_hook()
             devtel.observer().watch_obj(self)
+        with devtel.setup_span("setup.prewarm") as sp:
+            n = self._prewarm(batch, chunk_steps, buckets, prefix_prefill)
+            sp.set(executables=n)
+        return n
+
+    def _prewarm(self, batch, chunk_steps, buckets, prefix_prefill) -> int:
+        warm = devtel.warm
+        sa = self._sample_args(GenerationParams(), batch)
         n = 0
         for S in self.seq_buckets():
             cache = self.new_cache(batch)
             ids = jnp.zeros((batch, S), jnp.int32)
             lens = jnp.ones(batch, jnp.int32)
-            tok, _, cache = self._prefill(self.params, ids, cache, lens, sa)
+            tok, _, cache = warm(
+                "prefill", {"P": batch, "S": S},
+                self._prefill, self.params, ids, cache, lens, sa,
+            )
             del cache
             n += 1
             if prefix_prefill:
                 cache = self.new_cache(batch)
-                tok, _, cache = self._prefill(
-                    self.params, ids, cache, lens, sa,
+                tok, _, cache = warm(
+                    "prefill", {"P": batch, "S": S, "prefix": True},
+                    self._prefill, self.params, ids, cache, lens, sa,
                     jnp.zeros(batch, jnp.int32),
                 )
                 del cache
@@ -810,8 +823,9 @@ class DecodeEngine:
         cache = self.canon_cache(self.new_cache(batch))
         cur = self.canon_vec(jnp.ones(batch, jnp.int32))
         for tb in bucket_set:
-            _, _, c2 = self._decode(
-                self.params, tok, cache, cur, sa, t_bucket=tb
+            _, _, c2 = warm(
+                "decode", {"t_bucket": tb},
+                self._decode, self.params, tok, cache, cur, sa, t_bucket=tb,
             )
             cache = self.canon_cache(c2)
             n += 1
@@ -824,7 +838,9 @@ class DecodeEngine:
                 # generate()'s chunked branch runs the grouped program at
                 # n_chunks=1 — token/position carries are donated, so
                 # rebind them from the outputs before the next compile.
-                _, t2, c2, cur2, _ = self._decode_group(
+                _, t2, c2, cur2, _ = warm(
+                    "decode_group", {"chunks": 1, "k": k, "t_bucket": tb},
+                    self._decode_group,
                     self.params, tok, cache, cur, sa, done, eos,
                     n_chunks=1, n_steps=k, t_bucket=tb,
                 )
@@ -838,8 +854,9 @@ class DecodeEngine:
         # land on the first real request as "TTFT" that is really
         # deferred prewarm work (its size is not measured on the current
         # machine).
-        jax.block_until_ready(cache.positions)
-        _ = int(jnp.zeros((), jnp.int32) + 1)
+        with devtel.setup_span("setup.prewarm.drain"):
+            jax.block_until_ready(cache.positions)
+            _ = int(jnp.zeros((), jnp.int32) + 1)
         del cache
         return n
 

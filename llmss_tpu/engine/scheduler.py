@@ -290,9 +290,11 @@ class ContinuousBatcher:
         if self._paged:
             mb = engine.max_seq_len // engine.block_size
             n_blocks = engine.kv_blocks or rows * mb
-            self.cache = engine.new_paged_cache(
-                rows, num_blocks=n_blocks, identity=False
-            )
+            with devtel.setup_span("setup.cache") as sp:
+                self.cache = engine.new_paged_cache(
+                    rows, num_blocks=n_blocks, identity=False
+                )
+                sp.set(bytes=devtel.tree_bytes(self.cache))
             self.allocator = BlockAllocator(n_blocks)
             self._sentinel = table_sentinel(n_blocks)
             self._host_tables = np.full((rows, mb), self._sentinel, np.int32)
@@ -341,7 +343,9 @@ class ContinuousBatcher:
                 import_blocks, donate_argnums=(0,)
             )
         else:
-            self.cache = engine.new_cache(rows)
+            with devtel.setup_span("setup.cache") as sp:
+                self.cache = engine.new_cache(rows)
+                sp.set(bytes=devtel.tree_bytes(self.cache))
         self.pending: deque = deque()  # guarded_by: self._lock
         self.active: dict[int, _Row] = {}
         self._free = list(range(rows))  # guarded_by: self._lock
@@ -697,6 +701,7 @@ class ContinuousBatcher:
             # programs AND the scheduler's own insert/prefill-row jits.
             devtel.observer().watch_obj(eng)
             devtel.observer().watch_obj(self)
+        warm = devtel.warm
         Ps, p = [], 1
         while p < self.rows:
             Ps.append(p)
@@ -711,7 +716,8 @@ class ContinuousBatcher:
             sa1 = eng._sample_args(GenerationParams(), 1)
             for S in seq_buckets:
                 c1 = eng.new_cache(1)
-                _, _, c1 = eng._prefill(
+                _, _, c1 = warm(
+                    "prefill", {"P": 1, "S": S}, eng._prefill,
                     eng.params, jnp.zeros((1, S), np.int32), c1,
                     jnp.ones(1, np.int32), sa1,
                 )
@@ -732,14 +738,17 @@ class ContinuousBatcher:
                 scratch = self._prewarm_scratch(P)
                 ids = jnp.zeros((P, S), np.int32)
                 lens = jnp.ones(P, np.int32)
-                tok, _, scratch = self._prefill_row(
+                tok, _, scratch = warm(
+                    "prefill_row", {"P": P, "S": S}, self._prefill_row,
                     eng.params, ids, scratch, jnp.asarray(lens), sa,
                 )
                 self._prewarm_absorb_pools(scratch)
                 n_compiled += 1
                 if prefix_prefill:
                     scratch = self._prewarm_scratch(P)
-                    tok, _, scratch = self._prefill_row(
+                    tok, _, scratch = warm(
+                        "prefill_row", {"P": P, "S": S, "prefix": True},
+                        self._prefill_row,
                         eng.params, ids, scratch, jnp.asarray(lens), sa,
                         jnp.zeros(P, np.int32),
                     )
@@ -751,7 +760,8 @@ class ContinuousBatcher:
                     # otherwise compile mid-serve.
                     c1 = eng.new_cache(1)
                     sa1 = eng._sample_args(GenerationParams(), 1)
-                    _, _, c1 = eng._prefill(
+                    _, _, c1 = warm(
+                        "prefill", {"P": 1, "S": S}, eng._prefill,
                         eng.params, jnp.zeros((1, S), np.int32), c1,
                         jnp.ones(1, np.int32), sa1,
                     )
@@ -761,27 +771,37 @@ class ContinuousBatcher:
             # scatter without touching live rows. Once — the live path
             # feeds it exactly these canonical shardings.
             if self._paged:
+                state = {}
+                if self._chunked and self.cache.ssm is not None:
+                    ssm, conv = warm(
+                        "zero_state", {"P": P}, self._zero_state,
+                        self.cache.ssm, self.cache.conv,
+                        jnp.asarray(self._pad_row_idx(P, [])),
+                    )
+                    state = {"ssm": ssm, "conv": conv}
+                    n_compiled += 1
                 self.cache = eng.canon_cache(self.cache._replace(
-                    positions=self._merge_positions(
+                    positions=warm(
+                        "merge_positions", {"P": P}, self._merge_positions,
                         self.cache.positions,
                         eng.canon_vec(
                             jnp.full((P, eng.max_seq_len), -1, jnp.int32)
                         ),
                         jnp.asarray(self._pad_row_idx(P, [])),
                     ),
-                    **(self._zeroed_state(self._pad_row_idx(P, []))
-                       if self._chunked else {}),
+                    **state,
                 ))
-                n_compiled += bool(self._chunked and self.cache.ssm is not None)
             else:
                 scratch = eng.canon_cache(scratch)
-                self.cache = eng.canon_cache(self._insert(
+                self.cache = eng.canon_cache(warm(
+                    "insert", {"P": P}, self._insert,
                     self.cache, scratch,
                     jnp.asarray(self._pad_row_idx(P, [])),
                 ))
             n_compiled += 1
             self._tokens_dev, self._cur_pos_dev = (
-                eng.canon_vec(x) for x in eng._admit_merge(
+                eng.canon_vec(x) for x in warm(
+                    "admit_merge", {"P": P}, eng._admit_merge,
                     self._tokens_dev, self._cur_pos_dev, eng.canon_vec(tok),
                     jnp.ones(P, jnp.int32),
                     jnp.asarray(self._pad_row_idx(P, [])),
@@ -799,7 +819,9 @@ class ContinuousBatcher:
         })
         for nc, k in combos:
             for tb in eng.prewarm_bucket_set():
-                _, last_tok, cache, cur_pos, _ = eng._decode_group(
+                _, last_tok, cache, cur_pos, _ = warm(
+                    "decode_group", {"chunks": nc, "k": k, "t_bucket": tb},
+                    eng._decode_group,
                     eng.params, self._tokens_dev, self.cache,
                     self._cur_pos_dev, sa,
                     jnp.ones(self.rows, bool),
@@ -819,7 +841,9 @@ class ContinuousBatcher:
             for nc in sorted({
                 self.group_chunks * self.chunk_steps, self.chunk_steps_low,
             }):
-                _, last_tok, cache, cur_pos, _ = eng._ragged_group(
+                _, last_tok, cache, cur_pos, _ = warm(
+                    "ragged_group", {"chunks": nc, "k": 1},
+                    eng._ragged_group,
                     eng.params, self._tokens_dev, self.cache,
                     self._cur_pos_dev, sa,
                     jnp.ones(self.rows, bool),
@@ -851,8 +875,9 @@ class ContinuousBatcher:
         # can carry a load cost — queued up, that backlog would otherwise
         # land on the first real admission (engine.prewarm has the same
         # guard).
-        jax.block_until_ready(self.cache.positions)
-        _ = int(jnp.zeros((), jnp.int32) + 1)
+        with devtel.setup_span("setup.prewarm.drain"):
+            jax.block_until_ready(self.cache.positions)
+            _ = int(jnp.zeros((), jnp.int32) + 1)
         if devtel.enabled():
             # Every serving-path executable is compiled: from here on any
             # compile is a steady-state recompile — counted by the
@@ -2113,12 +2138,6 @@ class ContinuousBatcher:
                     for r in self.active.values()
                 ),
             )
-            for r in self.active.values():
-                if r.req_id and not r.awaiting_first:
-                    trace.record(
-                        r.req_id, "group_dispatch", throttle_s=0.05,
-                        chunks=nc, k=k, loop=sp.seq,
-                    )
 
         prev, self._inflight = self._inflight, group
         n = 0
@@ -2140,7 +2159,7 @@ class ContinuousBatcher:
 
     def _devtel_sample(self) -> None:
         """Devtel sampling at a group boundary: counter tracks (throttled
-        to 0.05 s — the group_dispatch trace cadence) and the compile
+        to 0.05 s) and the compile
         observer's ``_cache_size`` sweep (throttled to 0.5 s inside the
         observer). Host counters and host tables only — never a device
         sync (``memory_stats`` reads runtime-owned host counters)."""

@@ -55,6 +55,7 @@ from llmss_tpu.ops.gdn import gdn_chunked, gdn_step, l2_normalize
 from llmss_tpu.ops.ssm import causal_conv, ssd_scan, ssm_step
 from llmss_tpu.parallel.mesh import AXIS_DP, AXIS_SP, AXIS_TP
 from llmss_tpu.parallel.sharding import constrain
+from llmss_tpu.utils import devtel
 
 
 def _seq_axis(mesh, S: int) -> str | None:
@@ -239,7 +240,12 @@ def init_params(cfg: DecoderConfig, mesh, key) -> Params:
     def _init(keys):
         return jax.tree_util.tree_map_with_path(_leaf, shapes, keys)
 
-    return jax.jit(_init, out_shardings=shardings)(keys_tree)
+    # The span times the host's part (tracing, compiling, dispatch): the
+    # device may still be filling the arrays when it closes.
+    with devtel.setup_span("setup.weights") as sp:
+        params = jax.jit(_init, out_shardings=shardings)(keys_tree)
+        sp.set(bytes=devtel.tree_bytes(params))
+    return params
 
 
 def _draw_dt_bias(k, shape):

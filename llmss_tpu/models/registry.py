@@ -18,6 +18,7 @@ from llmss_tpu.models import (
 )
 from llmss_tpu.models.common import DecoderConfig
 from llmss_tpu.models.decoder import Params
+from llmss_tpu.utils import devtel
 from llmss_tpu.weights import CheckpointShards, weight_files
 
 MODEL_REGISTRY = {
@@ -56,7 +57,11 @@ def load_model(
 
     hf_config = AutoConfig.from_pretrained(model_path, revision=revision)
     cfg = config_from_hf(hf_config, dtype=dtype)
-    files = weight_files(str(model_path), revision=revision)
-    ckpt = CheckpointShards(files, dtype=cfg.compute_dtype)
-    params = MODEL_REGISTRY[cfg.model_type].load_params(ckpt, cfg, mesh)
+    # The span times the host's part (reading the shards, placing them): the
+    # device may still be receiving the arrays when it closes.
+    with devtel.setup_span("setup.weights") as sp:
+        files = weight_files(str(model_path), revision=revision)
+        ckpt = CheckpointShards(files, dtype=cfg.compute_dtype)
+        params = MODEL_REGISTRY[cfg.model_type].load_params(ckpt, cfg, mesh)
+        sp.set(bytes=devtel.tree_bytes(params))
     return cfg, params

@@ -34,6 +34,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
+from llmss_tpu.utils import devtel
+
 AXIS_DP = "dp"
 AXIS_SP = "sp"
 AXIS_TP = "tp"
@@ -69,6 +71,16 @@ def initialize_runtime(
     global _initialized
     if _initialized:
         return
+    if devtel.enabled():
+        # From here on JAX's trace / lower / compile seconds are kept
+        # (``setup.jax.*``): the weights' programs come before any prewarm.
+        devtel.install_monitoring_hook()
+    with devtel.setup_span("setup.runtime"):
+        _initialize(coordinator_address, num_processes, process_id)
+    _initialized = True
+
+
+def _initialize(coordinator_address, num_processes, process_id) -> None:
     # Persistent XLA compilation cache: the serving prewarm compiles the
     # whole executable envelope; with the cache a restarted worker reloads
     # those executables instead of recompiling. Where the operator placed
@@ -88,7 +100,6 @@ def initialize_runtime(
             num_processes=num_processes,
             process_id=process_id,
         )
-    _initialized = True
 
 
 @dataclasses.dataclass(frozen=True)
@@ -133,7 +144,13 @@ def make_mesh(
     ICI-friendly device order for the axis shape; falls back to a reshape of
     an explicit device list (used by tests to build submeshes).
     """
-    plan = plan or MeshPlan()
+    with devtel.setup_span("setup.runtime") as sp:
+        mesh = _make_mesh(plan or MeshPlan(), devices)
+        sp.set(devices=mesh.size)
+    return mesh
+
+
+def _make_mesh(plan: MeshPlan, devices: Sequence[jax.Device] | None) -> Mesh:
     # Auto axis types: the classic GSPMD model — parameters carry
     # NamedShardings, activations get with_sharding_constraint hints, XLA
     # propagates and inserts collectives. (JAX 0.9's default is the new

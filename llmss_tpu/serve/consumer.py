@@ -631,9 +631,12 @@ class ContinuousWorker:
         and stops the loop for longer than a short group runs. They are put
         out of the collector's reach here, once, so that a collection while
         serving walks what serving made."""
-        n = self.batcher.prewarm(seq_buckets, prefix_prefill)
-        gc.collect()
-        gc.freeze()
+        with devtel.setup_span("setup.prewarm") as sp:
+            n = self.batcher.prewarm(seq_buckets, prefix_prefill)
+            with devtel.setup_span("setup.prewarm.gc"):
+                gc.collect()
+                gc.freeze()
+            sp.set(executables=n)
         return n
 
     def _drain_broker(self, loop: int | None = None) -> int:

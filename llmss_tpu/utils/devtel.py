@@ -20,6 +20,13 @@ carries the same ``mono_anchor``/``wall_anchor`` pair as the flight
 recorder so ``trace.to_chrome_trace`` can emit them as wall-aligned
 Chrome ``C`` counter events next to the request spans.
 
+**Set-up.** Until ``mark_steady()`` the same hook keeps what JAX says of
+every program it traces, lowers, compiles or fetches from the compile cache
+(``setup.jax.*`` sums on ``/metrics`` ``loop.spans``, and the seconds on
+the set-up span they were spent in); :func:`setup_span` opens a phase of a
+replica's bring-up and :func:`warm` one call of a step program by a prewarm
+(``docs/observability.md``, "Set-up").
+
 The whole plane is inert when tracing is off (``LLMSS_TRACE=0``). What a
 step should cost and what share of the roofline it reaches is the
 benchmark's to say (``benchmark/lib/costs.py``), from a device trace.
@@ -44,7 +51,73 @@ def enabled() -> bool:
     return trace.enabled()
 
 
+# -- set-up spans -------------------------------------------------------------
+
+# The jitted callables a prewarm calls, by the attribute names the engine and
+# the scheduler give them: the closed set of ``setup.prewarm.<family>`` spans.
+PREWARM_SPANS = {
+    family: f"setup.prewarm.{family}"
+    for family in (
+        "prefill", "prefill_row", "decode", "decode_group", "ragged_group",
+        "admit_merge", "merge_positions", "insert", "zero_state",
+    )
+}
+
+
+def setup_span(name: str):
+    """A phase of this replica's bring-up as a span on the loop track
+    (``trace.setup_span``) that is also a ``jax.profiler.TraceAnnotation``,
+    so a profile taken over a start shows it on the host plane. Tracing off:
+    the one shared no-op span."""
+    if not trace.enabled():
+        return trace.NO_LOOP_SPAN
+    import jax
+
+    return trace.setup_span(name, jax.profiler.TraceAnnotation)
+
+
+def tree_bytes(tree) -> int:
+    """Bytes of the arrays of a pytree, from their shapes (no device sync)."""
+    import jax
+
+    return sum(x.nbytes for x in jax.tree.leaves(tree))
+
+
+def warm(family: str, key: dict, fn, *args, **kwargs):
+    """``fn(*args, **kwargs)``: one call of a step program by a prewarm,
+    inside a span ``setup.prewarm.<family>`` that carries the program's
+    ``key``, whether the call compiled (``fn._cache_size()`` grew) and, from
+    the monitoring hook, the seconds JAX spent on it."""
+    if not trace.enabled():
+        return fn(*args, **kwargs)
+    size = getattr(fn, "_cache_size", None)
+    before = size() if size is not None else None
+    with setup_span(PREWARM_SPANS[family]) as sp:
+        out = fn(*args, **kwargs)
+        if size is not None:
+            key = {**key, "compiled": size() > before}
+        sp.set(**key)
+    return out
+
+
 # -- compile forensics --------------------------------------------------------
+
+# JAX's durations over one jitted call's first run -> the ``/metrics`` name
+# of their sum until ``mark_steady()``, and the attribute they add to on the
+# set-up span they were spent in.
+_JAX_SECONDS = {
+    "/jax/core/compile/jaxpr_trace_duration": ("setup.jax.trace", "trace_s"),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": (
+        "setup.jax.lower", "lower_s"),
+    "/jax/core/compile/backend_compile_duration": (
+        "setup.jax.compile", "compile_s"),
+    "/jax/compilation_cache/cache_retrieval_time_sec": (
+        "setup.jax.cache_fetch", "cache_fetch_s"),
+}
+# Those of them that JAX brackets (a scalar at the start, the duration at
+# the end) and that can therefore lie inside one another on a thread.
+_JAX_NESTED = frozenset(k for k in _JAX_SECONDS if "/core/compile/" in k)
+_JAX_DEPTH = threading.local()
 
 
 class CompileObserver:
@@ -107,13 +180,35 @@ class CompileObserver:
 
     # -- sources --------------------------------------------------------
 
+    def on_monitoring_scalar(self, event: str, value: float, **kw):
+        """jax.monitoring scalar listener: JAX reports the START of each
+        trace / lowering / backend compile under the duration's own key. A
+        jitted function traced inside another's trace (or lowering) reports
+        a duration of its own inside the outer one, so only the outermost
+        of a thread is counted: this keeps the depth."""
+        if event in _JAX_NESTED:
+            _JAX_DEPTH.n = getattr(_JAX_DEPTH, "n", 0) + 1
+
     def on_monitoring_event(self, event: str, duration: float, **kw):
-        """jax.monitoring duration listener: one event per backend
-        compile, real duration, no name/req attribution."""
-        if "compile" not in event or not enabled():
+        """jax.monitoring duration listener. Until ``mark_steady()``: the
+        seconds of every outermost trace, lowering and backend compile, and
+        of every fetch from the compile cache (a part of the backend
+        compile that wraps it), go to the ``setup.jax.*`` sums and to the
+        set-up span open on this thread. Always: one compile event per
+        backend compile, real duration, no name/req attribution."""
+        if event in _JAX_NESTED:
+            depth = _JAX_DEPTH.n = max(0, getattr(_JAX_DEPTH, "n", 0) - 1)
+            if depth:
+                return
+        if not enabled():
             return
-        # Trace/lowering sub-phases also carry "compile" in their key;
-        # only the backend compile is the multi-second stall we forensic.
+        names = _JAX_SECONDS.get(event)
+        if names is not None:
+            with self._lock:
+                steady = self.steady
+            if not steady:
+                trace.add_setup_seconds(*names, float(duration))
+        # Only the backend compile is the multi-second stall we forensic.
         if "backend_compile" not in event:
             return
         self._record(
@@ -220,6 +315,7 @@ def install_monitoring_hook() -> bool:
         _jm.register_event_duration_secs_listener(
             _OBSERVER.on_monitoring_event
         )
+        _jm.register_scalar_listener(_OBSERVER.on_monitoring_scalar)
         _HOOK_INSTALLED = True
     except Exception:  # noqa: BLE001 — private-but-stable; degrade quietly
         pass

@@ -17,6 +17,8 @@ import random
 import threading
 import time
 
+from llmss_tpu.utils import trace
+
 
 class LatencyStat:
     """Bounded-reservoir latency recorder with percentile readout."""
@@ -174,6 +176,10 @@ class EngineMetrics:
         # come with a group's packed fetch). None = the model has none.
         self.moe_counts: list[int] | None = None  # guarded_by: self._lock
         self._start = time.monotonic()
+        # What the replica's bring-up recorded before this object existed
+        # (``setup.runtime``, ``setup.weights``, JAX's seconds), and every
+        # set-up sum from here on, goes to ``loop_spans``.
+        trace.recorder().adopt_setup(self.add_loop_span)
 
     def add_tokens(self, n: int) -> None:
         with self._lock:
@@ -295,15 +301,17 @@ class EngineMetrics:
                 acc[0] + pairs, acc[1] + experts_hit, acc[2] + layer_steps,
             ]
 
-    def add_loop_span(self, name: str, seconds: float) -> None:
-        """A loop span closed (``trace.loop_span(on_close=...)``)."""
+    def add_loop_span(self, name: str, seconds: float, count: int = 1) -> None:
+        """A loop span closed (``trace.loop_span(on_close=...)``), or
+        ``count`` of them that closed before this object existed (the
+        set-up sums, ``FlightRecorder.adopt_setup``)."""
         with self._lock:
             acc = self.loop_spans.get(name)
             if acc is None:
-                self.loop_spans[name] = [seconds, 1]
+                self.loop_spans[name] = [seconds, count]
             else:
                 acc[0] += seconds
-                acc[1] += 1
+                acc[1] += count
 
     def to_dict(self) -> dict:
         uptime = time.monotonic() - self._start

@@ -20,6 +20,13 @@ housekeeping) goes on the recorder's second, request-less store: the **loop
 track**, a bounded ring of spans that name their parent. Request events point
 into it with ``attrs["loop"]`` (the ``seq`` of the span that caused them).
 
+What a replica does BEFORE its first iteration (runtime, weights, engine,
+pools, prewarm) is on the loop track too, as **set-up spans**
+(:class:`SetupSpan`, ``setup.*``): they name the set-up span open on their
+thread as parent, take the seconds JAX reports for the programs compiled
+inside them, and their sums wait in the recorder until an ``EngineMetrics``
+exists to adopt them (``docs/observability.md``, "Set-up").
+
 Tracing is ON by default at event granularity. Disable with
 ``LLMSS_TRACE=0`` in the environment or :func:`set_enabled` at runtime;
 the disabled fast path is a single attribute check per call site.
@@ -122,6 +129,13 @@ class LoopSpan:
         else:
             self._attrs.update(attrs)
 
+    def add(self, key: str, value: float) -> None:
+        """Add ``value`` to the numeric attribute ``key`` (0 when unset)."""
+        if self._attrs is None:
+            self._attrs = {key: value}
+        else:
+            self._attrs[key] = self._attrs.get(key, 0.0) + value
+
     def end(self, **attrs) -> None:
         rec = self._rec
         if rec is None:
@@ -148,6 +162,45 @@ class LoopSpan:
         return False
 
 
+# The set-up spans open on each thread, innermost last.
+_OPEN_SETUP = threading.local()
+
+
+def _open_setup() -> list:
+    try:
+        return _OPEN_SETUP.spans
+    except AttributeError:
+        _OPEN_SETUP.spans = []
+        return _OPEN_SETUP.spans
+
+
+class SetupSpan(LoopSpan):
+    """A loop span of a replica's bring-up (``setup.*``): what runs before
+    the first loop iteration. Its parent is the set-up span already open on
+    its thread, and while it is the innermost one it takes the seconds JAX
+    reports for the programs traced, lowered and compiled inside it
+    (:func:`add_setup_seconds`). It closes into the recorder's set-up sums
+    (:meth:`FlightRecorder.add_setup`), which reach ``/metrics``
+    ``loop.spans`` whether or not an ``EngineMetrics`` existed yet."""
+
+    __slots__ = ()
+
+    def __init__(self, rec, seq, name, annotate):
+        stack = _open_setup()
+        super().__init__(
+            rec, seq, stack[-1].seq if stack else None, name,
+            rec.add_setup, annotate,
+        )
+        stack.append(self)
+
+    def end(self, **attrs) -> None:
+        if self._rec is not None:
+            stack = _open_setup()
+            if self in stack:
+                stack.remove(self)
+        super().end(**attrs)
+
+
 class _NoLoopSpan:
     """What every ``loop_span`` call site gets while tracing is off: ONE
     shared object, so the off path makes no span, takes no lock and reads
@@ -158,6 +211,9 @@ class _NoLoopSpan:
     seq = None
 
     def set(self, **attrs) -> None:
+        pass
+
+    def add(self, key: str, value: float) -> None:
         pass
 
     def end(self, **attrs) -> None:
@@ -211,6 +267,12 @@ class FlightRecorder:
         self._loop: deque = deque(maxlen=max_loop_spans)  # guarded_by: self._loop_lock
         self._loop_dropped = 0  # guarded_by: self._loop_lock
         self._loop_seq = itertools.count(1)
+        # Set-up sums ({name: [seconds, count]}) wait here until an
+        # EngineMetrics adopts them (``adopt_setup``): the runtime and the
+        # weights come up before any engine exists. Afterwards they go
+        # straight to the adopter.
+        self._setup_held: dict[str, list] = {}  # guarded_by: self._loop_lock
+        self._setup_sink = None  # guarded_by: self._loop_lock
 
     # -- recording ----------------------------------------------------------
 
@@ -275,6 +337,33 @@ class FlightRecorder:
             self, next(self._loop_seq), parent, name, on_close, annotate,
         )
 
+    def start_setup_span(self, name: str, annotate=None) -> SetupSpan:
+        return SetupSpan(self, next(self._loop_seq), name, annotate)
+
+    def add_setup(self, name: str, seconds: float) -> None:
+        """Seconds of bring-up under ``name`` (a closed ``setup.*`` span, or
+        one of JAX's own durations): to the adopter's ``loop.spans`` sums,
+        or held until there is one."""
+        with self._loop_lock:
+            sink = self._setup_sink
+            if sink is None:
+                acc = self._setup_held.setdefault(name, [0.0, 0])
+                acc[0] += seconds
+                acc[1] += 1
+                return
+        sink(name, seconds)
+
+    def adopt_setup(self, sink) -> None:
+        """``sink(name, seconds, count=1)`` (``EngineMetrics.add_loop_span``)
+        takes over the sums held so far and every later one: a process is
+        one replica, and its set-up is on the ``/metrics`` of the engine
+        built last."""
+        with self._loop_lock:
+            held, self._setup_held = self._setup_held, {}
+            self._setup_sink = sink
+        for name, (seconds, count) in held.items():
+            sink(name, seconds, count)
+
     def _close_loop_span(self, span: tuple) -> None:
         with self._loop_lock:
             if len(self._loop) == self._loop.maxlen:
@@ -311,6 +400,8 @@ class FlightRecorder:
         with self._loop_lock:
             self._loop.clear()
             self._loop_dropped = 0
+            self._setup_held.clear()
+            self._setup_sink = None
 
     def export(
         self,
@@ -404,6 +495,27 @@ def loop_span(
     if not _ENABLED:
         return NO_LOOP_SPAN
     return _RECORDER.start_loop_span(name, parent, on_close, annotate)
+
+
+def setup_span(name: str, annotate=None):
+    """Open a span of this replica's bring-up on the loop track
+    (:class:`SetupSpan`); the shared :data:`NO_LOOP_SPAN` while tracing is
+    off."""
+    if not _ENABLED:
+        return NO_LOOP_SPAN
+    return _RECORDER.start_setup_span(name, annotate)
+
+
+def add_setup_seconds(name: str, attr: str, seconds: float) -> None:
+    """Seconds JAX spent tracing, lowering, compiling or fetching a program
+    during bring-up: to the cumulative sum ``name`` and to attribute
+    ``attr`` of the innermost set-up span open on the calling thread."""
+    if not _ENABLED:
+        return
+    _RECORDER.add_setup(name, seconds)
+    stack = _open_setup()
+    if stack:
+        stack[-1].add(attr, seconds)
 
 
 def ensure_context(req) -> None:

@@ -646,9 +646,10 @@ def test_one_step_is_one_dispatch_span_and_steps_are_counted(
 ):
     """Every ``step()`` that dispatches a group leaves exactly one
     ``sched.dispatch`` span with that group's ``chunks`` x ``k``, however
-    short the group (here far under the 50 ms by which the per-request
-    ``group_dispatch`` events are throttled), and ``EngineMetrics`` counts
-    the same steps with tracing on or off."""
+    short the group, and NO per-row request event (the ``group_dispatch``
+    events went with PR 42: a request's place in a group follows from
+    ``prefill_dispatch.loop`` and ``admit.loop``), and ``EngineMetrics``
+    counts the same steps with tracing on or off."""
     trace.set_enabled(tracing)
     batcher = ContinuousBatcher(
         toy_engine, rows=2, chunk_steps=2, group_chunks=2,
@@ -678,13 +679,15 @@ def test_one_step_is_one_dispatch_span_and_steps_are_counted(
         (g.no, g.n_chunks * g.k) for g in groups]
     assert all(sp[5]["kind"] == "decode_group" and sp[5]["rows_live"] >= 1
                for sp in disp)
-    # the groups came faster than the per-request events are recorded
-    gaps = [b[3] - a[3] for a, b in zip(disp, disp[1:])]
-    assert min(gaps) < 0.05
-    per_request = [e for e in trace.recorder().events_for("s0")
-                   if e["name"] == "group_dispatch"]
-    assert len(per_request) < len(groups)
-    assert {e["attrs"]["loop"] for e in per_request} <= {sp[0] for sp in disp}
+    # a group is on the loop track alone: no request carries an event a
+    # group, and what a request does carry points into the track
+    for rid in ("s0", "s1"):
+        evs = trace.recorder().events_for(rid)
+        assert not [e for e in evs if e["name"].startswith("group_")]
+        assert len(evs) < len(groups)
+        seqs = {sp[0] for sp in trace.recorder().loop_spans()}
+        assert {e["attrs"]["loop"] for e in evs
+                if "loop" in e.get("attrs", {})} <= seqs
     # a group's fetch and callback carry its number, one step later; the
     # wait for an admission's first tokens is a fetch_wait of its own
     waits = [sp[5] for sp in _loop_spans("sched.fetch_wait")]

@@ -27,7 +27,8 @@ Three numbers come out:
 
 - ``loop_overhead_us_per_iteration`` — what tracing adds to ONE iteration
   of ``ContinuousWorker.run_once`` (the loop track's spans and counters,
-  the per-request ``group_dispatch`` events) with ``LOOP_LIVE`` of
+  the request events an iteration causes; until PR 42 also a
+  ``group_dispatch`` event a live row a group) with ``LOOP_LIVE`` of
   ``LOOP_ROWS`` rows decoding, the occupancy of the benchmark's
   ``starcoderbase-1b.gen`` cell: host CPU time of the loop's thread per
   iteration, on less off, over a toy model on the CPU backend (the model's
