@@ -358,7 +358,8 @@ class ContinuousBatcher:
         # row, so only active rows constrain the bucket.
         self._row_pos: dict[int, int] = {}
         # chunk -> how a step of that many tokens a row reads the paged pool
-        self._attn_reads: dict[int, str] = {}
+        # and how it updates a recurrent state (attn_read, state_update)
+        self._attn_reads: dict[int, dict[str, str]] = {}
         # Device-resident decode state (see module docstring), carried in
         # the engine's canonical shardings so every executable keeps one
         # steady-state signature (DecodeEngine.canon_cache/canon_vec).
@@ -1966,23 +1967,28 @@ class ContinuousBatcher:
     def _pool_read(self, chunk: int, t_bucket: int | None) -> dict:
         """What the group about to be dispatched reads of the paged pool,
         for its ``sched.dispatch`` span: ``attn_read``, the read its program
-        is traced with (``models.decoder.attn_read``); ``blocks_read``, the
+        is traced with (``models.decoder.attn_read``), and ``state_update``,
+        how it updates a recurrent state (``models.decoder.state_update``:
+        ``xla`` where there is none); ``blocks_read``, the
         blocks its live rows hold at the group's first step (a read that
         stops at a row's length visits these); ``blocks_ring``, the table
         columns a read of every row's whole ring or read bucket visits.
         Their ratio is the share of the ring that holds anything."""
-        from llmss_tpu.models.decoder import attn_read
+        from llmss_tpu.models.decoder import attn_read, state_update
 
         how = self._attn_reads.get(chunk)
         if how is None:
-            how = self._attn_reads[chunk] = attn_read(
-                self.engine.cfg, self.cache, self.engine.mesh, chunk
-            )
+            how = self._attn_reads[chunk] = {
+                f.__name__: f(
+                    self.engine.cfg, self.cache, self.engine.mesh, chunk
+                )
+                for f in (attn_read, state_update)
+            }
         bs, mb = self.cache.block_size, self.cache.max_blocks
         if t_bucket is not None:
             mb = min(-(-t_bucket // bs), mb)
         return dict(
-            attn_read=how,
+            **how,
             blocks_read=sum(
                 min(-(-n // bs), mb) for n in self._row_pos.values()
             ),

@@ -668,7 +668,7 @@ def _latent_attention(cfg: DecoderConfig, bp: Params, x, positions, sin_cos,
     return dense(o.reshape(B, S, H * Dv), bp["o"]), latent
 
 
-def _mixer(cfg: DecoderConfig, bp: Params, x, ssm, conv, lens):
+def _mixer(cfg: DecoderConfig, bp: Params, x, ssm, conv, lens, layer=None):
     """The Mamba-2 branch of a block: ``x`` [B, S, E] is the block's normed
     input, ``ssm`` [B, H, P, N] float32 and ``conv`` [B, K-1, C] this layer's
     state of every row, ``lens`` [B] how many of the S positions are real
@@ -678,7 +678,12 @@ def _mixer(cfg: DecoderConfig, bp: Params, x, ssm, conv, lens):
     S == 1 is the decode update (``ssm.decode``), anything longer the
     chunked scan (``ssm.prefill``); both start from the state handed in and
     treat positions at or after ``lens`` as no-ops (time step 0, window
-    taken at the true length)."""
+    taken at the true length).
+
+    With ``layer`` set (``state_update`` chose ``ssm.kernel``) ``ssm`` is the
+    WHOLE pool ``[L, rows, H, P, N]``, batch row i its row i: layer ``layer``
+    of it is updated where it lies (ops/pallas_ssm.py, under ``ssm.decode``
+    whatever S is: a step's few positions) and the pool is what comes back."""
     m = cfg.ssm
     B, S, _ = x.shape
     H, Pd, G, N = m.n_heads, m.head_dim, m.n_groups, m.d_state
@@ -704,7 +709,19 @@ def _mixer(cfg: DecoderConfig, bp: Params, x, ssm, conv, lens):
     dt = jax.nn.softplus(dt.astype(f32) * mdt + bp["ssm_dt_bias"].astype(f32))
     dt = jnp.where(live[..., None], dt, 0.0)
     A = -jnp.exp(bp["ssm_A_log"].astype(f32))
-    if S == 1:
+    if layer is not None:
+        import importlib
+
+        from llmss_tpu.ops import pallas_ssm
+
+        interp = importlib.import_module(
+            "llmss_tpu.ops.attention"
+        ).pallas_interpret()
+        with jax.named_scope("ssm.decode"):
+            y, ssm = pallas_ssm.ssm_pool_update(
+                ssm, xs, dt, A, Bm, Cm, lens, layer, interpret=interp
+            )
+    elif S == 1:
         with jax.named_scope("ssm.decode"):
             y, ssm = ssm_step(xs[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0], ssm)
             y = y[:, None]
@@ -837,7 +854,8 @@ def _block(
     k_scale=None,
     v_scale=None,
     # (ssm [B, H, P, N], conv [B, K-1, C], lens [B]) of this layer, for a
-    # config with a mixer (see ``_mixer``)
+    # config with a mixer, or (the ssm POOL, conv, lens, layer) where the
+    # state is updated in place (see ``_mixer``)
     ssm_in=None,
     # heads of the paged pool this block reads and writes, where they are
     # more than the model's (``DecoderConfig.pool_kv_heads``): q, k and v
@@ -951,13 +969,26 @@ def _ssm_lens(cache, kv_write_positions, slots):
     return jnp.sum(live.astype(jnp.int32), axis=1)
 
 
-def _layer_scan(cfg: DecoderConfig, cache, lens, body, h, xs, linear=None):
+def _layer_scan(cfg: DecoderConfig, cache, lens, body, h, xs, linear=None,
+                in_place: bool = False):
     """``lax.scan`` of ``body(h, xs, ssm_in) -> (h, ys, ssm_out)`` over the
     stacked layers; returns ``(h, ys, state)``. For a config with a
     recurrent state the state pool ``[L_state, rows, ...]`` rides the scan's
-    carry and each layer's slice is updated in place: the pool is donated
-    with the rest of the cache, so a step holds one copy of it and copies
-    none.
+    carry: the pool is donated with the rest of the cache, so a step holds
+    one copy of it. What a LAYER costs depends on the branch:
+
+    * ``in_place`` (a Mamba-2 pool in a decode or mixed step, where
+      ``state_update`` chose ``ssm.kernel``): the body gets the whole state
+      pool and the layer's index and returns the pool, updated where it
+      lies by one kernel (ops/pallas_ssm.py): no slice, no update back, one
+      read and one write of the layer's state. The convolution window's
+      pool (3 x ``conv_dim`` a row) is still sliced and set.
+    * every other stateful scan (the XLA oracles, an admission view, the
+      period branch below) SLICES the layer's state out of the pool, which
+      the compiler makes a copy, and writes the new one back with a
+      ``dynamic-update-slice``: two more passes over the layer's state than
+      the update itself makes (cell 2's line of PR 42: 0.755 s and 1.082 s
+      of a 6 s profile).
 
     Where the kinds of layer ALTERNATE (``cfg.layer_types``) the scan is over
     PERIODS of the pattern: ``xs`` (every leaf ``[L_kv, ...]``) is the
@@ -998,6 +1029,9 @@ def _layer_scan(cfg: DecoderConfig, cache, lens, body, h, xs, linear=None):
         def stateful(carry, xs_l):
             h, ssm, conv = carry
             xs, l = xs_l
+            if in_place:  # ``rows`` is None: ``state_update`` saw to it
+                h, ys, (ssm, c_l) = body(h, xs, (ssm, conv[l], lens, l))
+                return (h, ssm, conv.at[l].set(c_l)), ys
             s_in, c_in = state_in(ssm, conv, l)
             h, ys, (s_l, c_l) = body(h, xs, (s_in, c_in, lens))
             return (h, *state_out(ssm, conv, l, s_l, c_l)), ys
@@ -1720,6 +1754,7 @@ def _forward_paged(
             cfg, cache, lens, body, h,
             (params["blocks"], jnp.arange(cfg.n_kv_layers, dtype=jnp.int32)),
             linear=params.get("linear"),
+            in_place=state_update(cfg, cache, mesh, 1) == "ssm.kernel",
         )
 
         ks_new, vs_new = cache.k_scale, cache.v_scale
@@ -1853,6 +1888,46 @@ def attn_read(cfg: DecoderConfig, cache: PagedKVCache, mesh, chunk: int) -> str:
     if ok and (force == "pallas" or not attention_mod.pallas_interpret()):
         return "mla.kernel"
     return "gather"
+
+
+def state_update(cfg: DecoderConfig, cache: PagedKVCache, mesh, chunk: int) -> str:
+    """How a decode step (``chunk`` 1) or a mixed step of ``chunk`` tokens a
+    row updates the recurrent state, as its program is traced NOW:
+    ``ssm.kernel`` (each layer of a Mamba-2 pool updated where it lies,
+    ops/pallas_ssm.py) or ``xla`` (the layer sliced out, ``ops.ssm``'s
+    ``ssm_step`` / ``ssd_scan`` or the delta rule's, and updated back; also
+    what a config with no state says).
+
+    The kernel is for a step in which batch row i IS pool row i (no
+    admission view) on one device (heads are sharded under ``tp``); there,
+    ``attn_read``'s rule: shapes inside ``pallas_ssm.supports`` and compiled
+    on a TPU, or forced (interpreted: the CPU tests); never under ``force ==
+    "xla"``."""
+    import importlib
+
+    from llmss_tpu.ops import pallas_ssm
+
+    attention_mod = importlib.import_module("llmss_tpu.ops.attention")
+    force = attention_mod.IMPL_OVERRIDE
+    if (
+        cfg.ssm is None or cache.ssm is None or force == "xla"
+        or cache.state_rows is not None
+        or not (mesh is None or mesh.size == 1)
+    ):
+        return "xla"
+    m = cfg.ssm
+    ok = pallas_ssm.supports(
+        m.n_heads, m.head_dim, m.d_state, m.n_groups, chunk, cache.ssm.dtype
+    )
+    if force == "pallas" and not ok:
+        attention_mod.forced_pallas_miss(
+            "shapes out of the state update kernel's envelope "
+            f"(H={m.n_heads}, P={m.head_dim}, N={m.d_state}, "
+            f"G={m.n_groups}, chunk={chunk}, {cache.ssm.dtype})"
+        )
+    if ok and (force == "pallas" or not attention_mod.pallas_interpret()):
+        return "ssm.kernel"
+    return "xla"
 
 
 def _forward_latent(
@@ -2204,6 +2279,7 @@ def forward_ragged(
         cfg, cache, lens, body, h,
         (params["blocks"], jnp.arange(cfg.n_kv_layers, dtype=jnp.int32)),
         linear=params.get("linear"),
+        in_place=state_update(cfg, cache, mesh, S) == "ssm.kernel",
     )
 
     ks_new, vs_new = cache.k_scale, cache.v_scale

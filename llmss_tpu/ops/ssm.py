@@ -94,9 +94,16 @@ def ssd_scan(x, dt, A, Bm, Cm, state, chunk: int):
 def ssm_step(x, dt, A, Bm, Cm, state):
     """One position of the recurrence for every row: the decode update.
     ``x`` [B, H, P], ``dt`` [B, H] (0 for a row that is done), ``A`` [H],
-    ``Bm``/``Cm`` [B, G, N], ``state`` [B, H, P, N]; all float32. Reads and
-    writes the whole state once: bandwidth-bound. Returns ``y`` [B, H, P]
-    and the new state."""
+    ``Bm``/``Cm`` [B, G, N], ``state`` [B, H, P, N]; all float32. Returns
+    ``y`` [B, H, P] and the new state.
+
+    The ORACLE of the decode update (the CPU path, the tests' reference, and
+    what a step runs where ``models.decoder.state_update`` says ``xla``), not
+    its one-pass form: as XLA compiles it inside the layer scan the state is
+    passed over about 3.5 times a step (the layer's slice copied out of the
+    pool, the decay and outer product, the read-out, the update back: ledger,
+    PR 42, cell 2). The form that reads and writes the state once, where it
+    lies in the pool, is ``ops.pallas_ssm.ssm_pool_update``."""
     B_, H, P, N = state.shape
     G = Bm.shape[1]
     s = state.reshape(B_, G, H // G, P, N)

@@ -4,8 +4,9 @@ The TPU compiler is installed in the sandbox and compiles for a chip that is
 described, not attached (``topologies.get_topology_desc``). Interpret mode
 cannot show what it shows: a block shape the TPU cannot tile, a kernel that
 wants more VMEM than it may use, a bf16 operand reaching an f32 vector op.
-The four Pallas kernels are compiled here with ``interpret=False`` for v5e
-at the widths of three models the repo serves, at the engine's default
+The Pallas kernels are compiled here with ``interpret=False`` for v5e
+at the widths of three models the repo serves (the latent pool's read and the
+state pool's update at their cells' shapes), at the engine's default
 ``block_size`` and a real cache length. A compile that passes is a compile,
 not a chip run.
 
@@ -40,7 +41,7 @@ from llmss_tpu.models.decoder import param_shapes, param_specs
 from llmss_tpu.models.registry import config_from_hf
 from llmss_tpu.ops import (
     pallas_attention, pallas_decode, pallas_mla, pallas_paged_decode,
-    pallas_ragged,
+    pallas_ragged, pallas_ssm,
 )
 from llmss_tpu.parallel import mesh as mesh_mod
 
@@ -118,6 +119,20 @@ def _kernel_call(kernel: str, Hq: int, Hkv: int, D: int):
             ((rows, chunk, 1, D), DT), row, row, ((rows, mb * BS), i32),
             ((rows, mb), i32), row, row, ((), i32),
         ]
+    if kernel == "state_update":
+        # the Mamba-2 state pool's update at the shapes of the benchmark's
+        # second cell: 64 rows of 32 heads x [128, 256] float32 over 5
+        # layers, two groups, a mixed step's 4 positions a row (``Hkv``) or
+        # a decode step's 1
+        rows, chunk, f32 = 64, Hkv, jnp.float32
+        P_, N_, G = 128, D, 2
+        assert pallas_ssm.supports(Hq, P_, N_, G, chunk)
+        return pallas_ssm.ssm_pool_update, [
+            ((5, rows, Hq, P_, N_), f32), ((rows, chunk, Hq, P_), f32),
+            ((rows, chunk, Hq), f32), ((Hq,), f32),
+            ((rows, chunk, G, N_), f32), ((rows, chunk, G, N_), f32),
+            ((rows,), i32), ((), i32),
+        ]
     assert kernel == "ragged"
     assert pallas_ragged.supports(BS, Hq, Hkv, D, DT)
     return pallas_ragged.ragged_paged_attention, [
@@ -130,6 +145,8 @@ def _kernel_call(kernel: str, Hq: int, Hkv: int, D: int):
 
 # (heads, tokens a row a step, row width) of the latent pool's read
 LATENT_READS = {"mixed-step": (32, 8, 640), "decode-step": (32, 1, 640)}
+# (heads, positions a row a step, d_state) of the state pool's update
+STATE_UPDATES = {"mixed-step-of-4": (32, 4, 256), "one-step": (32, 1, 256)}
 
 
 @pytest.mark.parametrize(
@@ -137,11 +154,12 @@ LATENT_READS = {"mixed-step": (32, 8, 640), "decode-step": (32, 1, 640)}
     [
         (kernel, model) for model in WIDTHS
         for kernel in ("flash", "dense_decode", "paged_decode", "ragged")
-    ] + [("latent_read", step) for step in LATENT_READS],
+    ] + [("latent_read", step) for step in LATENT_READS]
+    + [("state_update", step) for step in STATE_UPDATES],
 )
 def test_kernel_compiles_for_v5e(v5e, kernel, model):
     fn, shapes = _kernel_call(
-        kernel, *(WIDTHS | LATENT_READS)[model]
+        kernel, *(WIDTHS | LATENT_READS | STATE_UPDATES)[model]
     )
     on_chip = SingleDeviceSharding(v5e)
     args = [
@@ -382,6 +400,38 @@ def test_latent_step_program_carries_the_pool_in_place(
     assert compiled.memory_analysis().temp_size_in_bytes < 0.05 * pool_bytes
 
 
+@pytest.mark.parametrize("program", ["decode", "ragged"])
+def test_state_pool_is_updated_where_it_lies(v5e, monkeypatch, program):
+    """``falcon-h1-34b-1chip``'s decode and mixed groups as a TPU traces
+    them (``state_update`` says ``ssm.kernel``): ONE custom call in the layer
+    scan's body takes the state pool ``f32[5,64,32,128,256]`` straight from
+    the carry and its result is the pool (the aliasing held: no ``copy`` of
+    the pool), and nothing else in the program produces the pool's shape, a
+    layer's (the slice the XLA path copies out, 0.27 GB), or the grouped
+    form the oracle computes on."""
+    import importlib
+
+    # the program asks jax.default_backend(), which is the CPU here
+    monkeypatch.setattr(
+        importlib.import_module("llmss_tpu.ops.attention"),
+        "pallas_interpret", lambda: False,
+    )
+    compiled, _ = _compile_group(v5e, program, "falcon-h1-34b-1chip")
+    text = compiled.as_text()
+    state = r"f32\[(?:5,64,32|1,64,32|64,32|64,2,16),128,256\]"
+    made = [
+        line.strip()[:100] for line in text.splitlines()
+        if re.match(rf"\s*(?:ROOT )?%\S+ = \(?{state}", line)
+        and not re.search(r" (?:parameter|get-tuple-element|bitcast)\(", line)
+    ]
+    assert len(made) == 1 and made[0].startswith("%ssm_pool_update"), made
+    assert text.count("tpu_custom_call") == 1
+    assert _pool_sized_copies(text, (5, 64, 32, 128, 256)) == []
+    # the slice's temporary is gone: the mixed group holds 0.11 GB
+    if program == "ragged":
+        assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
+
+
 def test_supports_refuses_what_vmem_cannot_hold():
     """``supports()`` and the compiler agree: a K/V block pair that cannot
     fit the kernels' VMEM budget is refused up front (float32 at GPT-J
@@ -394,6 +444,10 @@ def test_supports_refuses_what_vmem_cannot_hold():
     assert pallas_mla.supports(BS, 32, 640, 8, DT, 512)
     assert not pallas_mla.supports(BS, 128, 640, 16, DT, 512)
     assert not pallas_mla.supports(BS, 32, 576, 8, DT)  # not whole lanes
+    # the state's update: 8 heads of [128, 256] four times over fit, of
+    # [128, 2048] do not
+    assert pallas_ssm.supports(32, 128, 256, 2, 4)
+    assert not pallas_ssm.supports(32, 128, 2048, 2, 4)
 
 
 @pytest.fixture
