@@ -314,7 +314,7 @@ def ssm_state_shapes(cfg) -> tuple | None:
         # with the ROWS second-minor, and copies the pool into that layout
         # and back around every program (compiled for a described v5e)
         return (
-            ((m.n_heads, m.key_head_dim, m.value_head_dim), jnp.float32),
+            ((m.n_v_heads, m.key_head_dim, m.value_head_dim), jnp.float32),
             (((m.d_conv - 1) * m.conv_dim,), cfg.compute_dtype),
         )
     m = cfg.ssm

@@ -156,6 +156,17 @@ class DecodeEngine:
                     "linear-attention mixer is replicated over tp: "
                     "docs/recurrent-state.md)"
                 )
+        if cfg.moe is not None and cfg.mla is None:
+            from llmss_tpu.parallel.mesh import AXIS_TP
+
+            if mesh is not None and mesh.shape.get(AXIS_TP, 1) > 1:
+                raise ValueError(
+                    f"model_type {cfg.model_type!r} has routed experts, "
+                    "which are served at tp == 1 only: no mesh axis divides "
+                    "the experts and nothing exchanges their tokens yet; "
+                    "one chip's share of them is a configuration "
+                    "(docs/recurrent-state.md, 'The experts' share')"
+                )
         if cfg.mla is not None:
             # The latent pool (docs/latent-cache.md) is carried by the paged
             # layout in the compute dtype on one chip's worth of heads;
@@ -513,7 +524,7 @@ class DecodeEngine:
             logits[:, 0], tok, done, poisoned, eos
         )
         cur_pos = cur_pos + 1
-        # the step's (pairs, experts_hit); None without routed experts
+        # the step's routing counts; None without routed experts
         return (tok, cache, cur_pos, done, poisoned), (
             tok, aux.get("moe_counts"),
         )
@@ -574,13 +585,14 @@ class DecodeEngine:
     @staticmethod
     def _pack_group(toks, pois, moe):
         """The ONE int32 vector a group sends to the host: its tokens, its
-        per-chunk poison flags, and for a model with routed experts two
-        more numbers at the end, ``pairs`` and ``experts_hit`` summed over
-        the expert layers and the group's steps (``moe``: the steps'
-        stacked counts, None without experts)."""
+        per-chunk poison flags, and for a model with routed experts three
+        more numbers at the end, ``pairs``, ``experts_hit`` and
+        ``pairs_elsewhere`` (ops/moe.py: ``routed_experts``) summed over the
+        expert layers and the group's steps (``moe``: the steps' stacked
+        counts, None without experts)."""
         parts = [toks.reshape(-1), pois.astype(jnp.int32).reshape(-1)]
         if moe is not None:
-            parts.append(jnp.sum(moe.reshape(-1, 2), axis=0))
+            parts.append(jnp.sum(moe.reshape(-1, 3), axis=0))
         return jnp.concatenate(parts)
 
     @staticmethod

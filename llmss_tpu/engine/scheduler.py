@@ -1812,20 +1812,22 @@ class ContinuousBatcher:
         return n
 
     def _count_moe(self, group: _InFlightGroup, flat: np.ndarray) -> dict:
-        """A model with routed experts: the group's ``pairs`` and
-        ``experts_hit`` (the last two numbers of its packed fetch,
-        engine.py: ``_pack_group``) added to /metrics' ``loop.moe`` and
-        returned as the attributes the group's ``sched.callback`` span
-        carries; nothing for any other model."""
+        """A model with routed experts: the group's ``pairs``,
+        ``experts_hit`` and ``pairs_elsewhere`` (the last three numbers of
+        its packed fetch, engine.py: ``_pack_group``) added to /metrics'
+        ``loop.moe`` and returned as the attributes the group's
+        ``sched.callback`` span carries; nothing for any other model."""
         cfg = self.engine.cfg
         if cfg.moe is None:
             return {}
-        pairs, hit = int(flat[-2]), int(flat[-1])
+        pairs, hit, elsewhere = (int(n) for n in flat[-3:])
         self.engine.metrics.add_moe(
             pairs, hit,
             (cfg.n_layers - cfg.n_lead_layers) * group.n_chunks * group.k,
+            elsewhere,
         )
-        return {"pairs": pairs, "experts_hit": hit}
+        return {"pairs": pairs, "experts_hit": hit,
+                "pairs_elsewhere": elsewhere}
 
     def _apply_group(
         self, group: _InFlightGroup, flat: np.ndarray, loop: int | None,

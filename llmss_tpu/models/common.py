@@ -67,11 +67,16 @@ class SSMConfig:
 class LinearAttnConfig:
     """Gated DeltaNet (Yang, Kautz, Hatamizadeh 2024) as the mixer of the
     ``linear_attention`` layers of a model whose layers ALTERNATE kinds
-    (``DecoderConfig.layer_types``; Olmo-Hybrid): per head a float32 state
-    ``S`` of ``[key_head_dim, value_head_dim]`` that a token decays, corrects
-    by the delta rule and reads (ops/gdn.py), behind one depthwise causal
-    convolution over the concatenated q, k and v channels. Such a layer
-    holds no keys and values."""
+    (``DecoderConfig.layer_types``; Olmo-Hybrid, Qwen3-Next): per VALUE head
+    a float32 state ``S`` of ``[key_head_dim, value_head_dim]`` that a token
+    decays, corrects by the delta rule and reads (ops/gdn.py), behind one
+    depthwise causal convolution over the concatenated q, k and v channels.
+    Such a layer holds no keys and values.
+
+    ``n_heads`` counts the KEY heads; ``n_value_heads`` (None: as many) may
+    be a multiple of it (Qwen3-Next: 16 under 32): value head ``j`` reads
+    key head ``j // (n_v_heads // n_heads)``, and the decay, ``beta``, the
+    state and the output gate are a value head's."""
 
     n_heads: int
     key_head_dim: int
@@ -80,6 +85,11 @@ class LinearAttnConfig:
     # beta = 2 * sigmoid(.) in (0, 2): the transition I - beta k k^T may
     # have a negative eigenvalue (linear_allow_neg_eigval)
     allow_neg_eigval: bool = True
+    n_value_heads: int | None = None
+
+    @property
+    def n_v_heads(self) -> int:
+        return self.n_value_heads or self.n_heads
 
     @property
     def key_dim(self) -> int:
@@ -87,7 +97,7 @@ class LinearAttnConfig:
 
     @property
     def value_dim(self) -> int:
-        return self.n_heads * self.value_head_dim
+        return self.n_v_heads * self.value_head_dim
 
     @property
     def conv_dim(self) -> int:
@@ -134,10 +144,20 @@ class MLAConfig:
 class MoEConfig:
     """Routed experts in every layer after the first ``n_dense_layers``
     (which keep the dense MLP of ``DecoderConfig.intermediate_size``): a
-    sigmoid router in float32, the ``top_k`` experts by score plus a
-    selection-only bias, weights renormalised over the chosen and times
-    ``routed_scaling_factor``, beside ONE shared SwiGLU of ``shared_size``
-    (ops/moe.py)."""
+    router in float32 over ALL ``n_experts``, the ``top_k`` of them a token,
+    beside ONE shared SwiGLU of ``shared_size`` (ops/moe.py).
+
+    ``scoring`` ``"sigmoid"`` (deepseek_v3): sigmoid scores, chosen by score
+    plus a selection-only bias, weights renormalised over the chosen
+    (``norm_topk_prob``) and times ``routed_scaling_factor``. ``"softmax"``
+    (qwen3_next): a softmax over all the experts, the largest ``top_k``,
+    renormalised over the chosen; no bias, no factor. ``shared_gate``: the
+    shared expert's output is times ``sigmoid(x . w)`` a token.
+
+    What is HELD here may be one chip's share of the experts, ``count`` of
+    them from ``first`` (None: all): the stacked experts then have ``count``
+    on their expert axis, the router still scores all ``n_experts``, and a
+    pair routed to an expert held elsewhere adds nothing here."""
 
     n_experts: int
     top_k: int
@@ -146,6 +166,14 @@ class MoEConfig:
     n_dense_layers: int  # first_k_dense_replace
     routed_scaling_factor: float = 1.0
     norm_topk_prob: bool = True
+    scoring: str = "sigmoid"  # "sigmoid" | "softmax"
+    shared_gate: bool = False
+    first: int = 0
+    count: int | None = None
+
+    @property
+    def n_held(self) -> int:
+        return self.n_experts if self.count is None else self.count
 
 
 @dataclasses.dataclass(frozen=True)
@@ -235,10 +263,17 @@ class DecoderConfig:
     # whole periods of its shortest repeating unit; None = one kind. The
     # block is Olmo 2's: no pre-norm, ``h + norm(branch(h))`` (``post_norm``),
     # and an RMSNorm over the WHOLE query and key projection (``qk_norm``).
+    # qwen3_next: the same two kinds, pre-norm, each followed by the routed
+    # experts (``moe``); its QK-norm is over each HEAD (``qk_norm_per_head``,
+    # scales of ``head_dim``), and its query projection is twice as wide, a
+    # query and a gate a head: the heads' output is times ``sigmoid(gate)``
+    # before the output projection (``attn_gate``).
     layer_types: tuple[str, ...] | None = None
     linear_attn: LinearAttnConfig | None = None
     post_norm: bool = False
     qk_norm: bool = False
+    qk_norm_per_head: bool = False
+    attn_gate: bool = False
 
     # compute dtype for activations; params are loaded in this dtype too
     dtype: str = "bfloat16"

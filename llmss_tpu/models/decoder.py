@@ -122,8 +122,9 @@ def param_specs(cfg: DecoderConfig, tp: int) -> Params:
         blocks["kv_a"] = LinearParams(w=P(None, None, None), b=None)
         blocks["kv_norm"] = _norm_specs(True, False)
         blocks["kv_b"] = LinearParams(w=P(None, None, AXIS_TP), b=None)
-    if cfg.qk_norm:
-        # over the WHOLE projection (Olmo 2), so over every shard's heads
+    if cfg.qk_norm or cfg.qk_norm_per_head:
+        # over the WHOLE projection (Olmo 2), so over every shard's heads;
+        # or one scale of ``head_dim`` that every head shares (qwen3_next)
         blocks["q_norm"] = _norm_specs(True, False)
         blocks["k_norm"] = _norm_specs(True, False)
     if cfg.has_ln2:
@@ -134,13 +135,29 @@ def param_specs(cfg: DecoderConfig, tp: int) -> Params:
         "down": LinearParams(w=P(None, AXIS_TP, None), b=None),
     }
     lead = None
-    if cfg.moe is not None:
+    rep3, rep4 = P(None, None, None), P(None, None, None, None)
+    routed = None
+    if cfg.moe is not None and cfg.mla is None:
+        # The expert layer after EVERY layer of both kinds (qwen3_next): a
+        # stack's own router and shared expert, replicated like the latent
+        # family's; the stacked experts of all layers are ONE top-level
+        # stack (``params["experts"]``), indexed by the layer's absolute
+        # index whatever its kind.
+        routed = {
+            "router": LinearParams(w=rep3, b=None),
+            "shared_gate": LinearParams(w=rep3, b=None),
+            "shared_up": LinearParams(w=rep3, b=None),
+            "shared_down": LinearParams(w=rep3, b=None),
+        }
+        if cfg.moe.shared_gate:
+            routed["shared_sig"] = LinearParams(w=rep3, b=None)
+        blocks.update(routed)
+    elif cfg.moe is not None:
         # Two kinds of layer, two stacks (``_forward_latent``): the leading
         # dense layers keep the SwiGLU, the rest hold the router, the
         # stacked experts (replicated: no mesh axis divides them yet) and
         # the shared expert.
         lead = {**blocks, **swiglu}
-        rep3, rep4 = P(None, None, None), P(None, None, None, None)
         blocks.update({
             "router": LinearParams(w=rep3, b=P(None, None)),
             "experts_gate": rep4, "experts_up": rep4, "experts_down": rep4,
@@ -180,14 +197,19 @@ def param_specs(cfg: DecoderConfig, tp: int) -> Params:
     }
     if lead is not None:
         specs["lead"] = lead
+    if routed is not None:
+        specs["experts"] = {
+            "experts_gate": rep4, "experts_up": rep4, "experts_down": rep4,
+        }
     if cfg.linear_attn is not None:
         # The linear-attention layers' own stack (``_layer_scan`` walks the
         # two stacks by period). The mixer and its state are replicated over
-        # tp as a Mamba-2 mixer's are; the MLP shards as everywhere.
+        # tp as a Mamba-2 mixer's are; the MLP shards as everywhere (the
+        # expert layer, where the model has one, is replicated).
         rep = LinearParams(w=P(None, None, None), b=None)
         specs["linear"] = {
             "ln1": _norm_specs(True, norm_bias),
-            "ln2": _norm_specs(True, norm_bias), **swiglu,
+            "ln2": _norm_specs(True, norm_bias), **(routed or swiglu),
             "gdn_qkv": rep, "gdn_ab": rep, "gdn_g": rep, "gdn_o": rep,
             "gdn_conv": rep,
             "gdn_A_log": P(None, None), "gdn_dt_bias": P(None, None),
@@ -221,10 +243,10 @@ def init_params(cfg: DecoderConfig, mesh, key) -> Params:
 
     if cfg.ssm is not None:
         draw = _ssm_family_draw(cfg)
-    elif cfg.linear_attn is not None:
-        draw = _gdn_family_draw(cfg)
     elif cfg.moe is not None:
         draw = _routed_family_draw(cfg)
+    elif cfg.linear_attn is not None:
+        draw = _gdn_family_draw(cfg)
     else:
         draw = {}
 
@@ -405,7 +427,8 @@ def _routed_family_draw(cfg: DecoderConfig) -> dict:
     def router(k, shape):
         if len(shape) == 2:  # the selection bias [L, N]
             return jax.random.normal(k, shape, f32) * 0.02
-        # [L, N, E]: scores of deviation near 1 from the block alone
+        # [L, N, E]: scores of deviation near 1 from the block alone (near 3
+        # before the first branch has written: the block is most of a norm)
         w = jax.random.normal(k, shape, f32) / R ** 0.5
         return w.at[..., R:].set(0.0)
 
@@ -418,7 +441,7 @@ def _routed_family_draw(cfg: DecoderConfig) -> dict:
     # small model (tests) behaves like it; but the queries at 2 (scores of a
     # deviation near 1.3: a wrong scale or rotation shows) and attention's
     # output at 2.5.
-    return {
+    draw = {
         "wte": wte, "router": router,
         "q": normal(2.0, transposed=True), "kv_a": normal(0.9),
         "kv_b": normal(0.45), "o": normal(2.5, writes=True),
@@ -430,6 +453,41 @@ def _routed_family_draw(cfg: DecoderConfig) -> dict:
         "shared_down": normal(0.78, writes=True),
         "head": normal(0.9),
     }
+    if cfg.mla is None:
+        # The experts after layers of two kinds (qwen3_next). The same block
+        # and the same rule: the linear mixer's output projection writes none
+        # of it either. The query projection holds a gate a head (a deviation
+        # of 2: ``sigmoid`` works over its whole range); the QK-norm makes the
+        # scores' size its own. Softmax weights sum to 1 over the chosen (the
+        # sigmoid family's to 2.4) and a chip's share holds a part of them,
+        # and the shared expert is halved by its gate: their down-projections
+        # are drawn larger, so that a fault in one shows in the logits. How
+        # large every branch is beside the block that is carried exactly was
+        # then MEASURED: both mixers read dot products of near-orthogonal
+        # vectors (the delta rule's ``S^T k`` and ``S^T q`` of unit vectors,
+        # attention's scores of normed heads), which turn a bfloat16 input's
+        # 2^-9 into percents of their small result. With the linear mixer's
+        # output at 1.2 that branch alone put bfloat16 at 0.18-0.25 of a
+        # logit's deviation against the harness's 0.15 (CPU, PR 44: 8 layers
+        # at a hidden size of 64); at the published widths with (gdn_o, o,
+        # experts_down, shared_down) at (0.6, 1.0, 6.0, 2.0) the check read
+        # 0.07-0.11 over three seeds, and with all four halved, as drawn
+        # here, 0.06-0.07 (my chip runs, PR 44), beside 0.03-0.04 for the
+        # float32 reference with only its residual rounded to bfloat16. The
+        # delta rule's own leaves as ``_gdn_family_draw`` has them, for its
+        # reason.
+        gdn = _gdn_family_draw(cfg)
+        draw.update({
+            "k": normal(0.9, transposed=True), "v": normal(0.9),
+            "o": normal(0.5, writes=True),
+            "experts_down": normal(3.0, writes=True),
+            "shared_down": normal(1.0, writes=True),
+            "shared_sig": normal(0.9),
+            "gdn_qkv": normal(0.9), "gdn_ab": normal(0.9),
+            "gdn_g": normal(0.9), "gdn_o": normal(0.3, writes=True),
+            **{k: gdn[k] for k in ("gdn_conv", "gdn_A_log", "gdn_dt_bias")},
+        })
+    return draw
 
 
 def param_shapes(cfg: DecoderConfig) -> Params:
@@ -463,19 +521,41 @@ def param_shapes(cfg: DecoderConfig) -> Params:
                 ),
                 "o": LinearParams(sds(n, H * m.v_head_dim, E), None),
             }
+        # a query and a gate a head, side by side ([H, 2, D] on the out axis)
+        Qw = 2 * Q if cfg.attn_gate else Q
+        qk_norms = {}
+        if cfg.qk_norm or cfg.qk_norm_per_head:
+            D = cfg.head_dim
+            qk_norms = {
+                "q_norm": NormParams(sds(n, D if cfg.qk_norm_per_head else Q), None),
+                "k_norm": NormParams(sds(n, D if cfg.qk_norm_per_head else KV), None),
+            }
         return {
             "ln1": norm_shape(n),
             # q/k transposed storage [L, out, in] (see param_specs).
             "q": LinearParams(
-                sds(n, Q, E), sds(n, Q) if cfg.attn_bias else None),
+                sds(n, Qw, E), sds(n, Qw) if cfg.attn_bias else None),
             "k": LinearParams(
                 sds(n, KV, E), sds(n, KV) if cfg.attn_bias else None),
             "v": LinearParams(
                 sds(n, E, KV), sds(n, KV) if cfg.attn_bias else None),
             "o": LinearParams(sds(n, Q, E), sds(n, E) if cfg.o_bias else None),
-            **({"q_norm": NormParams(sds(n, Q), None),
-                "k_norm": NormParams(sds(n, KV), None)} if cfg.qk_norm else {}),
+            **qk_norms,
         }
+
+    def routed_shapes(n):
+        """The expert layer's own leaves in a stack of ``n`` layers (the
+        experts themselves are one stack of all layers)."""
+        x = cfg.moe
+        out = {
+            "router": LinearParams(sds(n, x.n_experts, E), None),
+            "shared_gate": LinearParams(sds(n, E, x.shared_size), None),
+            "shared_up": LinearParams(sds(n, E, x.shared_size), None),
+            "shared_down": LinearParams(sds(n, x.shared_size, E), None),
+        }
+        if x.shared_gate:
+            out["shared_sig"] = LinearParams(sds(n, E, 1), None)
+        return out
 
     def swiglu_shapes(n):
         return {
@@ -492,7 +572,11 @@ def param_shapes(cfg: DecoderConfig) -> Params:
     if cfg.has_ln2:
         blocks["ln2"] = norm_shape(L)
     lead = None
-    if cfg.moe is not None:
+    mlp_shapes = swiglu_shapes
+    if cfg.moe is not None and cfg.mla is None:
+        mlp_shapes = routed_shapes
+        blocks.update(routed_shapes(L))
+    elif cfg.moe is not None:
         x = cfg.moe
         lead = {
             **attn_shapes(n_lead), "ln2": norm_shape(n_lead),
@@ -538,20 +622,28 @@ def param_shapes(cfg: DecoderConfig) -> Params:
     }
     if lead is not None:
         shapes["lead"] = lead
+    if mlp_shapes is routed_shapes:
+        x = cfg.moe
+        shapes["experts"] = {
+            "experts_gate": sds(cfg.n_layers, x.n_held, E, x.expert_size),
+            "experts_up": sds(cfg.n_layers, x.n_held, E, x.expert_size),
+            "experts_down": sds(cfg.n_layers, x.n_held, x.expert_size, E),
+        }
     if cfg.linear_attn is not None:
         m, n = cfg.linear_attn, cfg.n_state_layers
         shapes["linear"] = {
-            "ln1": norm_shape(n), "ln2": norm_shape(n), **swiglu_shapes(n),
+            "ln1": norm_shape(n), "ln2": norm_shape(n), **mlp_shapes(n),
             # q, k and v in one projection, as the one convolution over
-            # their concatenated channels reads them; a and b likewise
+            # their concatenated channels reads them; a and b likewise (a
+            # value head each)
             "gdn_qkv": LinearParams(sds(n, E, m.conv_dim), None),
-            "gdn_ab": LinearParams(sds(n, E, 2 * m.n_heads), None),
+            "gdn_ab": LinearParams(sds(n, E, 2 * m.n_v_heads), None),
             "gdn_g": LinearParams(sds(n, E, m.value_dim), None),
             "gdn_o": LinearParams(sds(n, m.value_dim, E), None),
             # [K, C]: channels minor, no bias
             "gdn_conv": LinearParams(sds(n, m.d_conv, m.conv_dim), None),
-            "gdn_A_log": sds(n, m.n_heads),
-            "gdn_dt_bias": sds(n, m.n_heads),
+            "gdn_A_log": sds(n, m.n_v_heads),
+            "gdn_dt_bias": sds(n, m.n_v_heads),
             "gdn_norm": NormParams(sds(n, m.value_head_dim), None),
         }
     if cfg.positions == "learned":
@@ -598,12 +690,17 @@ def _mlp(cfg: DecoderConfig, bp: Params, x):
 
 
 def _routed_mlp(cfg: DecoderConfig, bp: Params, x, x32, live, experts):
-    """The expert layer: ``x`` [B, S, E] the block's normed input, ``x32``
-    the same before it was rounded to the compute dtype (what the router
-    reads), ``live`` [B, S] which tokens are real, ``experts`` the stacked
-    experts of ALL expert layers and this layer's index among them (the
-    grouped matmul reads the layer's weights in place: ops/moe.py).
-    Returns the layer's output and ``(pairs, experts_hit)`` int32 [2]."""
+    """The expert layer of both families that have one (deepseek_v3 after
+    latent attention; qwen3_next after a linear-attention mixer and after
+    gated attention alike): ``x`` [B, S, E] the block's normed input,
+    ``x32`` the same before it was rounded to the compute dtype (what the
+    router reads), ``live`` [B, S] which tokens are real, ``experts`` the
+    stacked experts of ALL expert layers and this layer's index among them
+    (the grouped matmul reads the layer's weights in place: ops/moe.py).
+    The router scores all ``n_experts``; the stack may hold one chip's share
+    of them (``MoEConfig.first`` / ``count``), and the pairs of the others
+    are left out. Returns the layer's output and ``(pairs, experts_hit,
+    pairs_elsewhere)`` int32 [3]."""
     from llmss_tpu.ops import moe
 
     m = cfg.moe
@@ -611,23 +708,52 @@ def _routed_mlp(cfg: DecoderConfig, bp: Params, x, x32, live, experts):
     act = act_fn(cfg.activation)
     flat = x.reshape(B * S, E)
     with jax.named_scope("moe.route"):
-        idx, w = moe.route(
-            x32.reshape(B * S, E), bp["router"].w, bp["router"].b,
-            top_k=m.top_k, norm=m.norm_topk_prob,
-            scale=m.routed_scaling_factor,
-        )
+        if m.scoring == "softmax":
+            idx, w = moe.route_softmax(
+                x32.reshape(B * S, E), bp["router"].w, top_k=m.top_k,
+                norm=m.norm_topk_prob,
+            )
+        else:
+            idx, w = moe.route(
+                x32.reshape(B * S, E), bp["router"].w, bp["router"].b,
+                top_k=m.top_k, norm=m.norm_topk_prob,
+                scale=m.routed_scaling_factor,
+            )
     with jax.named_scope("moe.experts"):
         stacks, layer = experts
         y, counts = moe.routed_experts(
             flat, idx, w, live.reshape(B * S), stacks["experts_gate"],
             stacks["experts_up"], stacks["experts_down"], act, layer=layer,
+            first=None if m.count is None else m.first,
         )
     with jax.named_scope("moe.shared"):
         shared = dense(
             act(dense(x, bp["shared_gate"])) * dense(x, bp["shared_up"]),
             bp["shared_down"],
         )
+        if m.shared_gate:
+            sig = jax.nn.sigmoid(dense(x, bp["shared_sig"]).astype(jnp.float32))
+            shared = (shared.astype(jnp.float32) * sig).astype(x.dtype)
     return y.reshape(B, S, E) + shared, counts
+
+
+def _mlp_or_experts(cfg: DecoderConfig, bp: Params, h, moe_in):
+    """The second half of a pre-norm block: ``h + MLP(norm(h))``, the MLP
+    the dense one or, where the layer holds a router, the routed experts
+    (``moe_in``: ``(live, experts)`` as ``_routed_mlp`` takes them). Returns
+    ``(h, routing counts or None)``."""
+    if "router" not in bp:
+        return h + _mlp(cfg, bp, _norm(cfg, h, bp["ln2"])), None
+    if moe_in is None:
+        raise NotImplementedError(
+            f"model_type {cfg.model_type!r} has routed experts, which this "
+            "forward does not carry: they are served from the paged cache, "
+            "through the period scan or the latent family's scans"
+        )
+    # the router reads the normed input before its rounding
+    x32 = _norm(cfg, h, bp["ln2"], jnp.float32)
+    mlp, counts = _routed_mlp(cfg, bp, x32.astype(h.dtype), x32, *moe_in)
+    return h + mlp, counts
 
 
 def _latent_attention(cfg: DecoderConfig, bp: Params, x, positions, sin_cos,
@@ -743,7 +869,8 @@ def _mixer(cfg: DecoderConfig, bp: Params, x, ssm, conv, lens, layer=None):
 
 def _gdn_mixer(cfg: DecoderConfig, bp: Params, x, ssm, conv, lens):
     """The mixer of a linear-attention layer (Gated DeltaNet): ``x``
-    [B, S, E] the layer's input, ``ssm`` [B, H, Dk, Dv] float32 and
+    [B, S, E] the layer's input (normed already where the block is pre-norm),
+    ``ssm`` [B, H, Dk, Dv] float32 (H the VALUE heads) and
     ``conv`` [B, (K-1) * C] (the window, flattened as the pool holds it)
     this layer's state of every row, ``lens`` [B] how many of the
     S positions are real (0: the row is done and its state stays as it is).
@@ -755,7 +882,7 @@ def _gdn_mixer(cfg: DecoderConfig, bp: Params, x, ssm, conv, lens):
     window taken at the true length)."""
     m = cfg.linear_attn
     B, S, _ = x.shape
-    H, Dk, Dv = m.n_heads, m.key_head_dim, m.value_head_dim
+    H, Dk, Dv = m.n_v_heads, m.key_head_dim, m.value_head_dim
     f32 = jnp.float32
     with jax.named_scope("gdn.conv"):
         qkv, window = causal_conv(
@@ -764,8 +891,11 @@ def _gdn_mixer(cfg: DecoderConfig, bp: Params, x, ssm, conv, lens):
         )
         qkv = jax.nn.silu(qkv)
     q, k, v = jnp.split(qkv, [m.key_dim, 2 * m.key_dim], axis=-1)
-    q = l2_normalize(q.reshape(B, S, H, Dk)) * Dk ** -0.5
-    k = l2_normalize(k.reshape(B, S, H, Dk))
+    q = l2_normalize(q.reshape(B, S, m.n_heads, Dk)) * Dk ** -0.5
+    k = l2_normalize(k.reshape(B, S, m.n_heads, Dk))
+    if H != m.n_heads:
+        # grouped value heads: value head j reads key head j // (H / Hk)
+        q, k = (jnp.repeat(a, H // m.n_heads, axis=2) for a in (q, k))
     v = v.reshape(B, S, H, Dv)
     a, b = jnp.split(dense(x, bp["gdn_ab"]).astype(f32), 2, axis=-1)
     live = (jnp.arange(S, dtype=lens.dtype)[None, :] < lens[:, None])[..., None]
@@ -795,16 +925,23 @@ def _gdn_mixer(cfg: DecoderConfig, bp: Params, x, ssm, conv, lens):
     return out, (ssm, window.reshape(conv.shape))
 
 
-def _linear_block(cfg: DecoderConfig, bp: Params, h, state_in):
+def _linear_block(cfg: DecoderConfig, bp: Params, h, state_in, moe_in=None):
     """One linear-attention layer of a model whose kinds alternate: the
-    mixer then the MLP, each normed AFTER it and added (``cfg.post_norm``,
-    the only form such a model has here). ``state_in`` is ``(ssm, conv,
-    lens)`` as ``_gdn_mixer`` takes them; returns ``(h, (ssm, conv))``."""
+    mixer, then the MLP or the routed experts (``moe_in`` as
+    ``_mlp_or_experts`` takes it). ``cfg.post_norm`` (olmo_hybrid): each
+    normed AFTER it and added; else (qwen3_next) pre-norm, ``h +
+    mixer(norm(h))``, ``h + experts(norm(h))``. ``state_in`` is ``(ssm,
+    conv, lens)`` as ``_gdn_mixer`` takes them; returns ``(h, (ssm, conv),
+    routing counts or None)``."""
     spec = P(AXIS_DP, None, None)  # the paged layouts have no sp axis
-    mix, state = _gdn_mixer(cfg, bp, h, *state_in)
-    h = h + constrain(_norm(cfg, mix, bp["ln1"]), spec)
-    h = h + _norm(cfg, _mlp(cfg, bp, h), bp["ln2"])
-    return constrain(h, spec), state
+    if cfg.post_norm:
+        mix, state = _gdn_mixer(cfg, bp, h, *state_in)
+        h = h + constrain(_norm(cfg, mix, bp["ln1"]), spec)
+        h = h + _norm(cfg, _mlp(cfg, bp, h), bp["ln2"])
+        return constrain(h, spec), state, None
+    mix, state = _gdn_mixer(cfg, bp, _norm(cfg, h, bp["ln1"]), *state_in)
+    h, counts = _mlp_or_experts(cfg, bp, h + constrain(mix, spec), moe_in)
+    return constrain(h, spec), state, counts
 
 
 def _latent_block(cfg: DecoderConfig, bp: Params, h, positions, sin_cos,
@@ -818,16 +955,10 @@ def _latent_block(cfg: DecoderConfig, bp: Params, h, positions, sin_cos,
     attn, latent = _latent_attention(
         cfg, bp, _norm(cfg, h, bp["ln1"]), positions, sin_cos, attend
     )
-    h = h + constrain(attn, spec)
-    # the router reads the normed input before its rounding
-    x32 = _norm(cfg, h, bp["ln2"], jnp.float32)
-    x = x32.astype(h.dtype)
-    counts = None
-    if "router" in bp:
-        mlp, counts = _routed_mlp(cfg, bp, x, x32, live, experts)
-    else:
-        mlp = _mlp(cfg, bp, x)
-    return constrain(h + mlp, spec), latent, counts
+    h, counts = _mlp_or_experts(
+        cfg, bp, h + constrain(attn, spec), (live, experts)
+    )
+    return constrain(h, spec), latent, counts
 
 
 def _block(
@@ -861,9 +992,13 @@ def _block(
     # more than the model's (``DecoderConfig.pool_kv_heads``): q, k and v
     # are padded with zero heads up to it and the padding's output dropped
     pool_heads: int | None = None,
+    # (live [B, S], (stacked experts, this layer's index among them)) where
+    # the block's MLP is the routed experts (see ``_mlp_or_experts``)
+    moe_in=None,
 ):
-    """One decoder block. The last element returned is the mixer's new
-    ``(ssm, conv)`` state, None for a config without one.
+    """One decoder block. The last two elements returned are the mixer's
+    new ``(ssm, conv)`` state, None for a config without one, and the expert
+    layer's routing counts, None for a block without one.
 
     ``defer_write=False``: current-token KV is scattered into the cache,
     then attention reads the updated cache (``kv_positions`` includes the
@@ -889,11 +1024,19 @@ def _block(
     q = dense_t(xa, bp["q"])
     if cfg.qk_norm:  # over the whole projection, before the head split
         q = _norm(cfg, q, bp["q_norm"])
+    out_gate = None
+    if cfg.attn_gate:  # a query and a gate a head, side by side
+        q = q.reshape(B, S, Hq, 2 * D)
+        q, out_gate = q[..., :D], q[..., D:]
     q = constrain(q.reshape(B, S, Hq, D), head_spec)
     k = _scale(dense_t(xa, bp["k"]), cfg.key_multiplier)
     if cfg.qk_norm:
         k = _norm(cfg, k, bp["k_norm"])
     k = constrain(k.reshape(B, S, Hkv, D), kv_spec)
+    if cfg.qk_norm_per_head:  # over each head's own features
+        with jax.named_scope("attn.qk_norm"):
+            q = _norm(cfg, q, bp["q_norm"])
+            k = _norm(cfg, k, bp["k_norm"])
     v = constrain(dense(xa, bp["v"]).reshape(B, S, Hkv, D), kv_spec)
 
     if cfg.positions == "rotary":
@@ -929,6 +1072,12 @@ def _block(
         )
     if padded:
         attn = attn[:, :, :Hq]
+    if out_gate is not None:
+        with jax.named_scope("attn.gate"):
+            attn = (
+                attn.astype(jnp.float32)
+                * jax.nn.sigmoid(out_gate.astype(jnp.float32))
+            ).astype(attn.dtype)
     attn = _scale(
         dense(attn.reshape(B, S, Hq * D), bp["o"]), cfg.attn_out_multiplier
     )
@@ -942,6 +1091,7 @@ def _block(
         attn = attn + mix
     attn = constrain(attn, P(AXIS_DP, seq_ax, None))
 
+    counts = None
     if cfg.parallel_residual:
         # GPT-J form: one pre-LN feeds both branches; residual adds both
         # (gptj_modeling.py:295-310). GPT-NeoX gives the MLP branch its
@@ -952,13 +1102,12 @@ def _block(
         h = res + attn
         h = h + _norm(cfg, _mlp(cfg, bp, h), bp["ln2"])
     else:
-        h = res + attn
-        x2 = _norm(cfg, h, bp["ln2"])
-        h = h + _mlp(cfg, bp, x2)
+        h, counts = _mlp_or_experts(cfg, bp, res + attn, moe_in)
     h = constrain(h, P(AXIS_DP, seq_ax, None))
     if defer_write:
-        return h, k, v, ssm_out  # fresh KV for the single post-scan scatter
-    return h, k_cache, v_cache, k, v, ssm_out
+        # fresh KV for the single post-scan scatter
+        return h, k, v, ssm_out, counts
+    return h, k_cache, v_cache, k, v, ssm_out, counts
 
 
 def _ssm_lens(cache, kv_write_positions, slots):
@@ -970,9 +1119,11 @@ def _ssm_lens(cache, kv_write_positions, slots):
 
 
 def _layer_scan(cfg: DecoderConfig, cache, lens, body, h, xs, linear=None,
-                in_place: bool = False):
-    """``lax.scan`` of ``body(h, xs, ssm_in) -> (h, ys, ssm_out)`` over the
-    stacked layers; returns ``(h, ys, state)``. For a config with a
+                in_place: bool = False, moe=None):
+    """``lax.scan`` of ``body(h, xs, ssm_in, moe_in) -> (h, ys, ssm_out,
+    counts)`` over the stacked layers; returns ``(h, ys, state, counts)``
+    (``counts``: the expert layers' routing counts summed, None for a model
+    without experts outside the latent family). For a config with a
     recurrent state the state pool ``[L_state, rows, ...]`` rides the scan's
     carry: the pool is donated with the rest of the cache, so a step holds
     one copy of it. What a LAYER costs depends on the branch:
@@ -997,7 +1148,10 @@ def _layer_scan(cfg: DecoderConfig, cache, lens, body, h, xs, linear=None,
     the scan runs one period's layers in the published order, ``body`` on each attention layer
     (no state) and ``_linear_block`` on each linear one, every layer indexing
     its own stack and pool by its index WITHIN its kind. ``ys`` comes back
-    ``[L_kv, ...]``.
+    ``[L_kv, ...]``. Where every layer of both kinds is followed by routed
+    experts (``moe``: ``(live [B, S], the stacked experts of ALL layers)``)
+    each layer is handed ``moe_in`` = ``(live, (experts, its ABSOLUTE
+    index))``, and the counts of a period's layers are summed on the way out.
 
     ``cache.state_rows`` None: batch row i IS pool row i and goes on from
     the state it has (decode, and a ragged chunk). Set (an admission view):
@@ -1006,11 +1160,11 @@ def _layer_scan(cfg: DecoderConfig, cache, lens, body, h, xs, linear=None,
     view's padding rows) writes nowhere."""
     if not cfg.has_state:
         def plain(h, xs):
-            h, ys, _ = body(h, xs, None)
+            h, ys, _, _ = body(h, xs, None, None)
             return h, ys
 
         h, ys = jax.lax.scan(plain, h, xs)
-        return h, ys, None
+        return h, ys, None, None
     rows, B = cache.state_rows, h.shape[0]
 
     def state_in(ssm, conv, l):
@@ -1030,17 +1184,19 @@ def _layer_scan(cfg: DecoderConfig, cache, lens, body, h, xs, linear=None,
             h, ssm, conv = carry
             xs, l = xs_l
             if in_place:  # ``rows`` is None: ``state_update`` saw to it
-                h, ys, (ssm, c_l) = body(h, xs, (ssm, conv[l], lens, l))
+                h, ys, (ssm, c_l), _ = body(
+                    h, xs, (ssm, conv[l], lens, l), None
+                )
                 return (h, ssm, conv.at[l].set(c_l)), ys
             s_in, c_in = state_in(ssm, conv, l)
-            h, ys, (s_l, c_l) = body(h, xs, (s_in, c_in, lens))
+            h, ys, (s_l, c_l), _ = body(h, xs, (s_in, c_in, lens), None)
             return (h, *state_out(ssm, conv, l, s_l, c_l)), ys
 
         (h, ssm, conv), ys = jax.lax.scan(
             stateful, (h, cache.ssm, cache.conv),
             (xs, jnp.arange(cfg.n_layers, dtype=jnp.int32)),
         )
-        return h, ys, (ssm, conv)
+        return h, ys, (ssm, conv), None
 
     period = cfg.period
     n_per = cfg.n_layers // len(period)
@@ -1049,32 +1205,51 @@ def _layer_scan(cfg: DecoderConfig, cache, lens, body, h, xs, linear=None,
 
     def one_period(carry, p):
         h, ssm, conv = carry
-        ys, i_lin, i_kv = [], 0, 0
-        for kind in period:
+        ys, counts, i_lin, i_kv = [], [], 0, 0
+        for i, kind in enumerate(period):
+            moe_in = moe and (moe[0], (moe[1], p * len(period) + i))
             # Each layer reads ITS layer of the whole stack, closed over, by
             # a dynamic index: with a period's layers handed in as one ``xs``
             # block the compiler copies that block (three layers' matrices)
             # out of the stack every period of every step.
             if kind == "linear_attention":
                 l = p * n_lin + i_lin
-                h, (s_l, c_l) = _linear_block(
+                h, (s_l, c_l), c = _linear_block(
                     cfg, _layer_of(linear, l), h,
-                    (*state_in(ssm, conv, l), lens),
+                    (*state_in(ssm, conv, l), lens), moe_in,
                 )
                 ssm, conv = state_out(ssm, conv, l, s_l, c_l)
                 i_lin += 1
             else:
-                h, y, _ = body(h, _layer_of(xs, p * n_kv + i_kv), None)
+                h, y, _, c = body(
+                    h, _layer_of(xs, p * n_kv + i_kv), None, moe_in
+                )
                 ys.append(y)
                 i_kv += 1
-        return (h, ssm, conv), jax.tree.map(lambda *a: jnp.stack(a), *ys)
+            counts.append(c)
+        ys = jax.tree.map(lambda *a: jnp.stack(a), *ys)
+        return (h, ssm, conv), (ys, sum(counts) if moe else None)
 
-    (h, ssm, conv), ys = jax.lax.scan(
+    (h, ssm, conv), (ys, counts) = jax.lax.scan(
         one_period, (h, cache.ssm, cache.conv),
         jnp.arange(n_per, dtype=jnp.int32),
     )
     ys = jax.tree.map(lambda a: a.reshape(-1, *a.shape[2:]), ys)
-    return h, ys, (ssm, conv)
+    if counts is not None:
+        counts = jnp.sum(counts, axis=0)
+    return h, ys, (ssm, conv), counts
+
+
+def _moe_of(params: Params, cache, kv_write_positions, slots):
+    """``_layer_scan``'s ``moe`` for a model whose every layer is followed by
+    routed experts (``params["experts"]``; None for any other): which
+    tokens are real (as ``_forward_latent`` has it: padding and done rows
+    record no position or write no slot, and are routed nowhere) and the
+    stacked experts of all layers."""
+    if "experts" not in params:
+        return None
+    live = (kv_write_positions >= 0) & (slots < cache.max_len)
+    return live, params["experts"]
 
 
 def _layer_of(stack, l):
@@ -1383,7 +1558,7 @@ def forward(
             # copy — the round-5 profile's 0.5 ms/step sink).
             def body(h, xs):
                 bp, layer = xs
-                h, k_f, v_f, _ = _block(
+                h, k_f, v_f, _, _ = _block(
                     cfg, bp, h, positions, None, None, cache.positions,
                     slots, None, mesh=mesh, defer_write=True,
                     attn_override=partial(kernel_attn, layer=layer),
@@ -1478,7 +1653,7 @@ def forward(
                     k_l = dequantize_kv(k_l, ks_l, dtype)
                     v_l = dequantize_kv(v_l, vs_l, dtype)
                     ks_l = vs_l = None
-                h, k_f, v_f, _ = _block(
+                h, k_f, v_f, _, _ = _block(
                     cfg, bp, h, positions, k_l, v_l, kv_pos_src, slots,
                     None, mesh=mesh, defer_write=True,
                     attn_override=sp_attn if sp_attn is not None
@@ -1531,7 +1706,7 @@ def forward(
                 v_l = dequantize_kv(v_q, vs_l, dtype)
             else:
                 bp, k_l, v_l = xs
-            h, k_l, v_l, k_f, v_f, _ = _block(
+            h, k_l, v_l, k_f, v_f, _, _ = _block(
                 cfg, bp, h, positions, k_l, v_l, new_kv_positions, slots,
                 mask, mesh=mesh, sin_cos=sin_cos,
             )
@@ -1697,6 +1872,7 @@ def _forward_paged(
     bs, MB = cache.block_size, cache.max_blocks
     quant = cache.quantized
     lens = _ssm_lens(cache, kv_write_positions, slots)
+    moe = _moe_of(params, cache, kv_write_positions, slots)
 
     sin_cos = None
     if cfg.positions == "rotary":
@@ -1740,21 +1916,23 @@ def _forward_paged(
                     v_scale_layer=cache.v_scale, n_blocks=nb, layer=layer,
                 )
 
-        def body(h, xs, ssm_in):
+        def body(h, xs, ssm_in, moe_in):
             bp, layer = xs
-            h, k_f, v_f, ssm_out = _block(
+            h, k_f, v_f, ssm_out, counts = _block(
                 cfg, bp, h, positions, None, None, kv_pos_src, slots,
                 None, mesh=mesh, defer_write=True,
                 attn_override=partial(attn, layer=layer),
                 sin_cos=sin_cos, ssm_in=ssm_in, pool_heads=cache.k.shape[3],
+                moe_in=moe_in,
             )
-            return h, (k_f, v_f), ssm_out
+            return h, (k_f, v_f), ssm_out, counts
 
-        h, ys, state = _layer_scan(
+        h, ys, state, moe_counts = _layer_scan(
             cfg, cache, lens, body, h,
             (params["blocks"], jnp.arange(cfg.n_kv_layers, dtype=jnp.int32)),
             linear=params.get("linear"),
             in_place=state_update(cfg, cache, mesh, 1) == "ssm.kernel",
+            moe=moe,
         )
 
         ks_new, vs_new = cache.k_scale, cache.v_scale
@@ -1779,7 +1957,7 @@ def _forward_paged(
         mask = make_causal_mask(positions, new_kv_positions, kv_valid)
         blk, off = logical_to_physical(cache.block_tables, slots, bs)
 
-        def body(h, xs, ssm_in):
+        def body(h, xs, ssm_in, moe_in):
             # Write-then-attend over the row-indirected logical view (same
             # values/slot order as a dense ring, so _block is reused
             # verbatim); then persist ONLY the fresh tokens back to the
@@ -1798,10 +1976,10 @@ def _forward_paged(
                 bp, kp_l, vp_l = xs
                 k_l = gather_block_view(kp_l, cache.block_tables)
                 v_l = gather_block_view(vp_l, cache.block_tables)
-            h, _, _, k_f, v_f, ssm_out = _block(
+            h, _, _, k_f, v_f, ssm_out, counts = _block(
                 cfg, bp, h, positions, k_l, v_l, new_kv_positions, slots,
                 mask, mesh=mesh, sin_cos=sin_cos, ssm_in=ssm_in,
-                pool_heads=cache.k.shape[3],
+                pool_heads=cache.k.shape[3], moe_in=moe_in,
             )
             if quant:
                 # Quantize only the fresh tokens (storage bit-stability —
@@ -1812,30 +1990,32 @@ def _forward_paged(
                 vp_l = vp_l.at[blk, off].set(v8, mode="drop")
                 ksp_l = ksp_l.at[blk, off].set(ks_f, mode="drop")
                 vsp_l = vsp_l.at[blk, off].set(vs_f, mode="drop")
-                return h, (kp_l, vp_l, ksp_l, vsp_l), ssm_out
+                return h, (kp_l, vp_l, ksp_l, vsp_l), ssm_out, counts
             kp_l = kp_l.at[blk, off].set(
                 k_f.astype(kp_l.dtype), mode="drop"
             )
             vp_l = vp_l.at[blk, off].set(
                 v_f.astype(vp_l.dtype), mode="drop"
             )
-            return h, (kp_l, vp_l), ssm_out
+            return h, (kp_l, vp_l), ssm_out, counts
 
         if quant:
-            h, (k_new, v_new, ks_new, vs_new), state = _layer_scan(
+            h, (k_new, v_new, ks_new, vs_new), state, moe_counts = _layer_scan(
                 cfg, cache, lens, body, h,
                 (params["blocks"], cache.k, cache.v, cache.k_scale,
                  cache.v_scale),
-                linear=params.get("linear"),
+                linear=params.get("linear"), moe=moe,
             )
         else:
             ks_new, vs_new = None, None
-            h, (k_new, v_new), state = _layer_scan(
+            h, (k_new, v_new), state, moe_counts = _layer_scan(
                 cfg, cache, lens, body, h,
                 (params["blocks"], cache.k, cache.v),
-                linear=params.get("linear"),
+                linear=params.get("linear"), moe=moe,
             )
 
+    if aux is not None:
+        aux["moe_counts"] = moe_counts
     logits = _head_out(cfg, params, h, gather_idx, last_only)
     ssm_new, conv_new = state if state is not None else (None, None)
     return logits, PagedKVCache(
@@ -2265,22 +2445,26 @@ def forward_ragged(
                 v_scale_layer=cache.v_scale, n_blocks=nb, layer=layer,
             )
 
-    def body(h, xs, ssm_in):
+    def body(h, xs, ssm_in, moe_in):
         bp, layer = xs
-        h, k_f, v_f, ssm_out = _block(
+        h, k_f, v_f, ssm_out, counts = _block(
             cfg, bp, h, positions, None, None, kv_pos_src, slots,
             None, mesh=mesh, defer_write=True,
             attn_override=partial(attn, layer=layer),
             sin_cos=sin_cos, ssm_in=ssm_in, pool_heads=cache.k.shape[3],
+            moe_in=moe_in,
         )
-        return h, (k_f, v_f), ssm_out
+        return h, (k_f, v_f), ssm_out, counts
 
-    h, ys, state = _layer_scan(
+    h, ys, state, moe_counts = _layer_scan(
         cfg, cache, lens, body, h,
         (params["blocks"], jnp.arange(cfg.n_kv_layers, dtype=jnp.int32)),
         linear=params.get("linear"),
         in_place=state_update(cfg, cache, mesh, S) == "ssm.kernel",
+        moe=_moe_of(params, cache, kv_write_positions, slots),
     )
+    if aux is not None:
+        aux["moe_counts"] = moe_counts
 
     ks_new, vs_new = cache.k_scale, cache.v_scale
     k_fresh, v_fresh = ys  # [L, B, CB, Hkv, D]

@@ -57,10 +57,12 @@ def config_from_hf(hf, dtype: str = "bfloat16") -> DecoderConfig:
             f"implemented (rope_parameters {rope}); the published model has "
             "none"
         )
-    if hf.linear_num_key_heads != hf.linear_num_value_heads:
+    if hf.linear_num_value_heads % hf.linear_num_key_heads:
         raise ValueError(
-            "olmo_hybrid: grouped value heads (linear_num_value_heads != "
-            "linear_num_key_heads) are not implemented"
+            "olmo_hybrid: linear_num_value_heads "
+            f"{hf.linear_num_value_heads} must be a multiple of "
+            f"linear_num_key_heads {hf.linear_num_key_heads} (each key head "
+            "serves a whole group of value heads)"
         )
     n_heads = hf.num_attention_heads
     return DecoderConfig(
@@ -88,6 +90,7 @@ def config_from_hf(hf, dtype: str = "bfloat16") -> DecoderConfig:
             value_head_dim=hf.linear_value_head_dim,
             d_conv=hf.linear_conv_kernel_dim,
             allow_neg_eigval=bool(hf.linear_allow_neg_eigval),
+            n_value_heads=hf.linear_num_value_heads,
         ),
         post_norm=True,
         qk_norm=True,

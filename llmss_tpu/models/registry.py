@@ -14,7 +14,7 @@ from jax.sharding import Mesh
 
 from llmss_tpu.models import (
     deepseek_v3, falcon_h1, gemma, gpt2, gpt_bigcode, gpt_neox, gptj, llama,
-    mistral, olmo_hybrid, phi3, qwen2,
+    mistral, olmo_hybrid, phi3, qwen2, qwen3_next,
 )
 from llmss_tpu.models.common import DecoderConfig
 from llmss_tpu.models.decoder import Params
@@ -34,6 +34,7 @@ MODEL_REGISTRY = {
     "falcon_h1": falcon_h1,
     "deepseek_v3": deepseek_v3,
     "olmo_hybrid": olmo_hybrid,
+    "qwen3_next": qwen3_next,
 }
 
 

@@ -1,6 +1,13 @@
-"""Routed experts (DeepSeek-V3's layer, ``MoEConfig``): a float32 sigmoid
-router, the top-k experts a token by score plus a selection-only bias, and
-ONE dropless grouped matmul over the (token, expert) pairs sorted by expert.
+"""Routed experts (``MoEConfig``) of two families: a float32 router over ALL
+the model's experts, sigmoid scores chosen by score plus a selection-only
+bias (deepseek_v3, ``route``) or a softmax and its largest (qwen3_next,
+``route_softmax``), and ONE dropless grouped matmul over the (token, expert)
+pairs sorted by expert, of the experts HELD here: all of them, or one chip's
+share of an expert-parallel deployment (``routed_experts``' ``first``). A
+pair whose expert is held elsewhere is a dead pair like those of a token
+that is not live, and what the absent experts would have added is left out:
+the exchange that would fetch it is a mesh axis this repo does not have yet,
+and nothing stands in for it.
 
 The same function serves prefill, decode and the mixed step: it sees a flat
 list of tokens and a ``live`` flag a token. A token that is not live (a row
@@ -50,6 +57,23 @@ def route(x, router_w, bias, *, top_k: int, norm: bool, scale: float):
     return idx.astype(jnp.int32), w * scale
 
 
+def route_softmax(x, router_w, *, top_k: int, norm: bool):
+    """``x`` [T, E] -> ``(experts [T, k] int32, weights [T, k] float32)``:
+    ``softmax(x W^T)`` over ALL the experts in float32 whatever the compute
+    dtype, the ``top_k`` largest, their probabilities renormalised over the
+    chosen (``norm``: ``norm_topk_prob``). No bias, no scaling factor."""
+    f32 = jnp.float32
+    p = jax.nn.softmax(
+        jnp.einsum("te,ne->tn", x.astype(f32), router_w.astype(f32),
+                   precision=jax.lax.Precision.HIGHEST),
+        axis=-1,
+    )
+    w, idx = jax.lax.top_k(p, top_k)
+    if norm:
+        w = w / jnp.sum(w, axis=1, keepdims=True)
+    return idx.astype(jnp.int32), w
+
+
 #: Rows a tile of the grouped matmul holds: the pairs are padded up to a
 #: multiple of it (dead rows, behind the last group).
 TILE_M = 128
@@ -81,13 +105,21 @@ def grouped_ffn(xs, group_sizes, gate, up, down, act):
     return _grouped_matmul(act(g) * u, down, group_sizes)
 
 
-def routed_experts(x, idx, w, live, gate, up, down, act, layer=None):
-    """The weighted sum of each token's chosen experts.
+def routed_experts(x, idx, w, live, gate, up, down, act, layer=None,
+                   first=None):
+    """The weighted sum of each token's chosen experts, of those held here.
 
     ``x`` [T, E]; ``idx``, ``w`` [T, k] from ``route``; ``live`` [T] bool;
     ``gate``/``up`` [N, E, I], ``down`` [N, I, E]. Returns ``(y [T, E] in
-    x's dtype, counts [2] int32)``: ``counts`` is ``(pairs, experts_hit)``,
-    the live (token, expert) pairs and the experts with at least one.
+    x's dtype, counts [3] int32)``: ``counts`` is ``(pairs, experts_hit,
+    pairs_elsewhere)``, the live (token, expert) pairs computed here, the
+    experts with at least one, and the live pairs left out because their
+    expert is held elsewhere.
+
+    ``first`` None: the N experts of the weights are all the model has.
+    Else they are the model's experts ``[first, first + N)``, one chip's
+    share: ``idx`` still names experts of the whole model, a pair outside
+    the range is dead (no weights read, zero back) and is counted.
 
     With ``layer`` (a traced scalar) the weights are the STACKED experts of
     all layers, ``[L, N, E, I]`` / ``[L, N, I, E]``, and the groups are the
@@ -104,7 +136,14 @@ def routed_experts(x, idx, w, live, gate, up, down, act, layer=None):
         )
     else:
         N = gate.shape[0]
-    e = jnp.where(live[:, None], idx, N).reshape(T * K)
+    live = live[:, None]
+    elsewhere = jnp.zeros((), jnp.int32)
+    if first is not None:
+        idx = idx - first
+        mine = (idx >= 0) & (idx < N)
+        elsewhere = jnp.sum((live & ~mine).astype(jnp.int32))
+        live = live & mine
+    e = jnp.where(live, idx, N).reshape(T * K)
     # whole tiles of rows: the padding pairs are dead pairs too
     e = jnp.pad(e, (0, -(T * K) % TILE_M), constant_values=N)
     order = jnp.argsort(e, stable=True)  # pairs by expert, the dead last
@@ -131,5 +170,7 @@ def routed_experts(x, idx, w, live, gate, up, down, act, layer=None):
         "tk,tke->te", w,
         ys[inv[: T * K]].reshape(T, K, -1).astype(jnp.float32),
     )
-    counts = jnp.stack([pairs, jnp.sum((sizes > 0).astype(jnp.int32))])
+    counts = jnp.stack(
+        [pairs, jnp.sum((sizes > 0).astype(jnp.int32)), elsewhere]
+    )
     return y.astype(x.dtype), counts

@@ -293,12 +293,14 @@ class EngineMetrics:
         with self._lock:
             self.cache_latent_bytes_per_token = n
 
-    def add_moe(self, pairs: int, experts_hit: int, layer_steps: int) -> None:
+    def add_moe(self, pairs: int, experts_hit: int, layer_steps: int,
+                pairs_elsewhere: int) -> None:
         """A fetched group's routing counts (engine.py: ``_pack_group``)."""
         with self._lock:
-            acc = self.moe_counts or [0, 0, 0]
+            acc = self.moe_counts or [0, 0, 0, 0]
             self.moe_counts = [
                 acc[0] + pairs, acc[1] + experts_hit, acc[2] + layer_steps,
+                acc[3] + pairs_elsewhere,
             ]
 
     def add_loop_span(self, name: str, seconds: float, count: int = 1) -> None:
@@ -348,7 +350,8 @@ class EngineMetrics:
                 # flat, like every counter of the block: a reader takes the
                 # difference of two reads key by key
                 loop.update(zip(
-                    ("moe.pairs", "moe.experts_hit", "moe.layer_steps"),
+                    ("moe.pairs", "moe.experts_hit", "moe.layer_steps",
+                     "moe.pairs_elsewhere"),
                     self.moe_counts,
                 ))
             gauges = {

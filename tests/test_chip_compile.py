@@ -344,6 +344,37 @@ def test_two_kinds_of_layer_carry_every_pool_in_place(v5e, program):
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 14.5e9
 
 
+def test_a_share_of_the_experts_under_two_kinds_of_layer_fits(v5e, monkeypatch):
+    """``qwen3-next-80b-a3b-1chip`` at the published widths, 64 rows x 5,120
+    positions, the mixed group (the one step program its cell times): the
+    128 held experts of all 8 layers are ONE stack read in place by the
+    grouped matmul's own kernel (three custom calls a layer of the period's
+    four), the block pool of the 2 attention layers keeps its 2 KV heads
+    unpadded (``T(2,128)``: 1.34 GB), the state pool of 32 VALUE heads and
+    the window pool go through as they came, and the arguments are what the
+    configuration's ``memory`` says: 9.50 GB, with under 1.2 GB of
+    temporaries (the gathered rings of the XLA read are most of them)."""
+    import importlib
+
+    # the program asks jax.default_backend(), which is the CPU here
+    monkeypatch.setattr(
+        importlib.import_module("llmss_tpu.ops.attention"),
+        "pallas_interpret", lambda: False,
+    )
+    compiled, pool = _compile_group(v5e, "ragged", "qwen3-next-80b-a3b-1chip")
+    assert pool == (2, 64 * 320, 16, 2, 256)
+    text = compiled.as_text()
+    moved = r"(?:\w+_)?(?:copy|transpose|dynamic[-_]slice)"
+    for shape in (pool, (6, 64, 32, 128, 128), (6, 64, 3 * 8192),
+                  (8, 128, 2048, 512)):
+        assert _pool_sized_copies(text, shape, moved) == [], shape
+    assert _layer_sized_slices(text, pool) == []
+    assert text.count("tpu_custom_call") == 12
+    ma = compiled.memory_analysis()
+    assert ma.argument_size_in_bytes == pytest.approx(9.50e9, rel=0.01)
+    assert ma.temp_size_in_bytes < 1.2e9
+
+
 # kakaocorp/kanana-2-30b-a3b-instruct-2601's widths (deepseek_v3: a latent
 # pool, 128 routed experts), cut to one dense and two expert layers, in the
 # envelope of benchmark/configs/kanana-2-30b-a3b-1chip.json.

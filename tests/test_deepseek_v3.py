@@ -385,13 +385,13 @@ def test_dead_tokens_add_no_pair_and_change_no_live_logits(engine):
     prompts = prompts_of([21, 13, 30], seed=4)
     ids, lens = engine._pad_prompts(prompts)
     alone, counts = _forward_counts(engine, ids, lens)
-    assert tuple(counts) == _routing_count(engine.params, prompts)
+    assert tuple(counts) == (*_routing_count(engine.params, prompts), 0)
     assert counts[0] == 2 * 2 * sum(map(len, prompts))  # top-2, two layers
     # a done row and a row of nothing but padding beside the first two
     ids2 = np.concatenate([ids, np.full((1, ids.shape[1]), 7, np.int32)])
     lens2 = np.asarray([21, 13, 30, 0], np.int32)
     both, counts2 = _forward_counts(engine, ids2, lens2, done_rows=(2,))
-    assert tuple(counts2) == _routing_count(engine.params, prompts[:2])
+    assert tuple(counts2) == (*_routing_count(engine.params, prompts[:2]), 0)
     np.testing.assert_allclose(both[:2], alone[:2], atol=2e-6)
 
 
@@ -425,7 +425,7 @@ def test_grouped_matmul_leaves_rows_behind_the_last_group_alone(stacked):
                 (jax.nn.silu(x[t] @ gate[e]) * (x[t] @ up[e])) @ down[e])
     np.testing.assert_allclose(np.asarray(y), want, atol=1e-5)
     assert not np.asarray(y[31:]).any()
-    assert tuple(np.asarray(counts)) == (62, len(np.unique(idx[:31])))
+    assert tuple(np.asarray(counts)) == (62, len(np.unique(idx[:31])), 0)
 
 
 def _wrong_bias(x, router_w, bias, *, top_k, norm, scale):

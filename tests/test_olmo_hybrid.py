@@ -471,6 +471,60 @@ def test_config_translation_from_the_catalogs_keys():
         config_from_hf(types.SimpleNamespace(**rotary))
 
 
+def test_grouped_value_heads_equal_their_key_heads_repeated(mesh):
+    """The shared mixer serves more value heads than key heads (PR 44 lifted
+    the refusal): 2 key heads under 4 value heads give the logits of the
+    ungrouped model of 4 key heads whose query and key projections (and
+    their convolution's channels) are each key head's repeated for its two
+    value heads, which the reference covers. A count that is no multiple is
+    refused by name."""
+    grouped = {**HF, "linear_num_value_heads": 4, "linear_value_head_dim": 32}
+    cfg = config_from_hf(types.SimpleNamespace(**grouped), dtype="float32")
+    assert (cfg.linear_attn.n_heads, cfg.linear_attn.n_v_heads) == (2, 4)
+    params = unit_norm_scales(init_params(cfg, mesh, jax.random.key(3)))
+    eng = DecodeEngine(cfg, params, mesh, kv_layout="paged", max_seq_len=MAX_LEN)
+    assert eng.new_paged_cache(2).ssm.shape == (6, 2, 4, 32, 32)
+
+    def repeated(w):  # [.., q | k | v] columns: each q and k head twice
+        q, k, v = jnp.split(w, [64, 128], axis=-1)
+        twice = lambda a: jnp.repeat(
+            a.reshape(*a.shape[:-1], 2, 32), 2, axis=-2
+        ).reshape(*a.shape[:-1], 128)
+        return jnp.concatenate([twice(q), twice(k), v], axis=-1)
+
+    lin = params["linear"]
+    wide = {**params, "linear": {
+        **lin, "gdn_qkv": lin["gdn_qkv"]._replace(w=repeated(lin["gdn_qkv"].w)),
+        "gdn_conv": lin["gdn_conv"]._replace(w=repeated(lin["gdn_conv"].w)),
+    }}
+    cfg4 = config_from_hf(types.SimpleNamespace(
+        **{**grouped, "linear_num_key_heads": 4}), dtype="float32")
+    eng4 = DecodeEngine(cfg4, wide, mesh, kv_layout="paged", max_seq_len=MAX_LEN)
+    prompts = prompts_of([21, 9], seed=5)
+    want = prefill(eng4, prompts)[1]
+    assert err(want, ref_logits_of(cfg4, wide, prompts, grouped)) < TOL["float32"]
+    assert err(prefill(eng, prompts)[1], want) < 2e-5
+    with pytest.raises(ValueError, match="multiple of"):
+        config_from_hf(types.SimpleNamespace(
+            **{**HF, "linear_num_value_heads": 3}))
+
+
+def ref_logits_of(cfg, params, seqs, hf):
+    """``ref_logits`` for another config of this family: ``hf`` with the
+    key heads the program's config has."""
+    hf = {**hf, "linear_num_key_heads": cfg.linear_attn.n_heads,
+          "linear_num_value_heads": cfg.linear_attn.n_v_heads}
+    ids = np.zeros((len(seqs), MAX_LEN), np.int32)
+    for i, seq in enumerate(seqs):
+        ids[i, : len(seq)] = seq
+    last = jnp.asarray([len(seq) - 1 for seq in seqs])
+    with jax.default_matmul_precision("highest"):
+        h = REF.embed(hf, params, jnp.asarray(ids))
+        for kind, lp in REF.layers(hf, params):
+            h = REF.layer(hf, kind, lp, h)
+        return np.asarray(REF.head(hf, params, h[jnp.arange(len(seqs)), last]))
+
+
 def test_padded_pool_heads_change_nothing(mesh, monkeypatch):
     """A pool of more heads than the model has (30 -> 32 at the published
     widths; forced here): q, k and v are padded with zero heads and the
