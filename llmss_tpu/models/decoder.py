@@ -867,7 +867,7 @@ def _mixer(cfg: DecoderConfig, bp: Params, x, ssm, conv, lens, layer=None):
     return out, (ssm, conv)
 
 
-def _gdn_mixer(cfg: DecoderConfig, bp: Params, x, ssm, conv, lens):
+def _gdn_mixer(cfg: DecoderConfig, bp: Params, x, ssm, conv, lens, at=None):
     """The mixer of a linear-attention layer (Gated DeltaNet): ``x``
     [B, S, E] the layer's input (normed already where the block is pre-norm),
     ``ssm`` [B, H, Dk, Dv] float32 (H the VALUE heads) and
@@ -879,7 +879,13 @@ def _gdn_mixer(cfg: DecoderConfig, bp: Params, x, ssm, conv, lens):
     S == 1 is the decode update (``gdn.decode``), anything longer the
     chunked form (``gdn.prefill``); both start from the state handed in and
     treat positions at or after ``lens`` as no-ops (``g = 0``, ``beta = 0``,
-    window taken at the true length)."""
+    window taken at the true length).
+
+    With ``at`` set (``state_update`` chose ``gdn.kernel``: ``(layer, the
+    step's live rows)``) ``ssm`` is the WHOLE pool ``[L, rows, H, Dk, Dv]``,
+    batch row i its row i: layer ``layer`` of it is updated where it lies
+    (ops/pallas_gdn.py, under ``gdn.decode`` whatever S is: a step's few
+    positions) and the pool is what comes back."""
     m = cfg.linear_attn
     B, S, _ = x.shape
     H, Dk, Dv = m.n_v_heads, m.key_head_dim, m.value_head_dim
@@ -905,7 +911,21 @@ def _gdn_mixer(cfg: DecoderConfig, bp: Params, x, ssm, conv, lens):
         a + bp["gdn_dt_bias"].astype(f32)
     )
     g = jnp.where(live, g, 0.0)
-    if S == 1:
+    if at is not None:
+        import importlib
+
+        from llmss_tpu.ops import pallas_gdn
+
+        interp = importlib.import_module(
+            "llmss_tpu.ops.attention"
+        ).pallas_interpret()
+        layer, live_rows = at
+        with jax.named_scope("gdn.decode"):
+            o, ssm = pallas_gdn.gdn_pool_update(
+                ssm, q, k, v, g, beta, lens, live_rows, layer,
+                interpret=interp,
+            )
+    elif S == 1:
         with jax.named_scope("gdn.decode"):
             o, ssm = gdn_step(
                 q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], ssm
@@ -931,8 +951,8 @@ def _linear_block(cfg: DecoderConfig, bp: Params, h, state_in, moe_in=None):
     ``_mlp_or_experts`` takes it). ``cfg.post_norm`` (olmo_hybrid): each
     normed AFTER it and added; else (qwen3_next) pre-norm, ``h +
     mixer(norm(h))``, ``h + experts(norm(h))``. ``state_in`` is ``(ssm,
-    conv, lens)`` as ``_gdn_mixer`` takes them; returns ``(h, (ssm, conv),
-    routing counts or None)``."""
+    conv, lens)`` or ``(the ssm POOL, conv, lens, at)`` as ``_gdn_mixer``
+    takes them; returns ``(h, (ssm, conv), routing counts or None)``."""
     spec = P(AXIS_DP, None, None)  # the paged layouts have no sp axis
     if cfg.post_norm:
         mix, state = _gdn_mixer(cfg, bp, h, *state_in)
@@ -1128,14 +1148,17 @@ def _layer_scan(cfg: DecoderConfig, cache, lens, body, h, xs, linear=None,
     carry: the pool is donated with the rest of the cache, so a step holds
     one copy of it. What a LAYER costs depends on the branch:
 
-    * ``in_place`` (a Mamba-2 pool in a decode or mixed step, where
-      ``state_update`` chose ``ssm.kernel``): the body gets the whole state
-      pool and the layer's index and returns the pool, updated where it
-      lies by one kernel (ops/pallas_ssm.py): no slice, no update back, one
-      read and one write of the layer's state. The convolution window's
-      pool (3 x ``conv_dim`` a row) is still sliced and set.
-    * every other stateful scan (the XLA oracles, an admission view, the
-      period branch below) SLICES the layer's state out of the pool, which
+    * ``in_place`` (a decode or mixed step where ``state_update`` chose a
+      kernel: ``ssm.kernel`` for a Mamba-2 pool, ``gdn.kernel`` for the
+      linear-attention layers of the period branch below): the body, or
+      ``_linear_block``, gets the whole state pool and the layer's index
+      and returns the pool, updated where it lies by one kernel
+      (ops/pallas_ssm.py: one read and one write of the layer's state;
+      ops/pallas_gdn.py: of its LIVE rows' state, walking one list of them
+      made here for all layers): no slice, no update back. The convolution
+      window's pool (3 x ``conv_dim`` a row) is still sliced and set.
+    * every other stateful scan (the XLA oracles, an admission view)
+      SLICES the layer's state out of the pool, which
       the compiler makes a copy, and writes the new one back with a
       ``dynamic-update-slice``: two more passes over the layer's state than
       the update itself makes (cell 2's line of PR 42: 0.755 s and 1.082 s
@@ -1202,6 +1225,10 @@ def _layer_scan(cfg: DecoderConfig, cache, lens, body, h, xs, linear=None,
     n_per = cfg.n_layers // len(period)
     n_lin = period.count("linear_attention")
     n_kv = len(period) - n_lin
+    if in_place:  # one list of the step's live rows serves every layer
+        from llmss_tpu.ops import pallas_gdn
+
+        live_rows = pallas_gdn.live_rows(lens)
 
     def one_period(carry, p):
         h, ssm, conv = carry
@@ -1214,11 +1241,18 @@ def _layer_scan(cfg: DecoderConfig, cache, lens, body, h, xs, linear=None,
             # out of the stack every period of every step.
             if kind == "linear_attention":
                 l = p * n_lin + i_lin
-                h, (s_l, c_l), c = _linear_block(
-                    cfg, _layer_of(linear, l), h,
-                    (*state_in(ssm, conv, l), lens), moe_in,
-                )
-                ssm, conv = state_out(ssm, conv, l, s_l, c_l)
+                bp = _layer_of(linear, l)
+                if in_place:  # ``rows`` is None: ``state_update`` saw to it
+                    h, (ssm, c_l), c = _linear_block(
+                        cfg, bp, h, (ssm, conv[l], lens, (l, live_rows)),
+                        moe_in,
+                    )
+                    conv = conv.at[l].set(c_l)
+                else:
+                    h, (s_l, c_l), c = _linear_block(
+                        cfg, bp, h, (*state_in(ssm, conv, l), lens), moe_in
+                    )
+                    ssm, conv = state_out(ssm, conv, l, s_l, c_l)
                 i_lin += 1
             else:
                 h, y, _, c = body(
@@ -1931,7 +1965,7 @@ def _forward_paged(
             cfg, cache, lens, body, h,
             (params["blocks"], jnp.arange(cfg.n_kv_layers, dtype=jnp.int32)),
             linear=params.get("linear"),
-            in_place=state_update(cfg, cache, mesh, 1) == "ssm.kernel",
+            in_place=state_update(cfg, cache, mesh, 1) != "xla",
             moe=moe,
         )
 
@@ -2074,39 +2108,50 @@ def state_update(cfg: DecoderConfig, cache: PagedKVCache, mesh, chunk: int) -> s
     """How a decode step (``chunk`` 1) or a mixed step of ``chunk`` tokens a
     row updates the recurrent state, as its program is traced NOW:
     ``ssm.kernel`` (each layer of a Mamba-2 pool updated where it lies,
-    ops/pallas_ssm.py) or ``xla`` (the layer sliced out, ``ops.ssm``'s
-    ``ssm_step`` / ``ssd_scan`` or the delta rule's, and updated back; also
+    ops/pallas_ssm.py), ``gdn.kernel`` (each linear-attention layer of a
+    delta-rule pool likewise, the live rows only, ops/pallas_gdn.py) or
+    ``xla`` (the layer sliced out, ``ops.ssm``'s ``ssm_step`` / ``ssd_scan``
+    or ``ops.gdn``'s ``gdn_step`` / ``gdn_chunked``, and updated back; also
     what a config with no state says).
 
-    The kernel is for a step in which batch row i IS pool row i (no
-    admission view) on one device (heads are sharded under ``tp``); there,
-    ``attn_read``'s rule: shapes inside ``pallas_ssm.supports`` and compiled
-    on a TPU, or forced (interpreted: the CPU tests); never under ``force ==
-    "xla"``."""
+    A kernel is for a step in which batch row i IS pool row i (no admission
+    view) on one device (heads are sharded under ``tp``); there,
+    ``attn_read``'s rule: shapes inside the kernel's ``supports`` and
+    compiled on a TPU, or forced (interpreted: the CPU tests); never under
+    ``force == "xla"``."""
     import importlib
 
-    from llmss_tpu.ops import pallas_ssm
+    from llmss_tpu.ops import pallas_gdn, pallas_ssm
 
     attention_mod = importlib.import_module("llmss_tpu.ops.attention")
     force = attention_mod.IMPL_OVERRIDE
     if (
-        cfg.ssm is None or cache.ssm is None or force == "xla"
+        not cfg.has_state or cache.ssm is None or force == "xla"
         or cache.state_rows is not None
         or not (mesh is None or mesh.size == 1)
     ):
         return "xla"
-    m = cfg.ssm
-    ok = pallas_ssm.supports(
-        m.n_heads, m.head_dim, m.d_state, m.n_groups, chunk, cache.ssm.dtype
-    )
+    if cfg.linear_attn is not None:
+        m, name = cfg.linear_attn, "gdn.kernel"
+        shapes = dict(
+            n_heads=m.n_v_heads, key_dim=m.key_head_dim,
+            value_dim=m.value_head_dim,
+        )
+        ok = pallas_gdn.supports(**shapes, chunk=chunk, dtype=cache.ssm.dtype)
+    else:
+        m, name = cfg.ssm, "ssm.kernel"
+        shapes = dict(
+            n_heads=m.n_heads, head_dim=m.head_dim, d_state=m.d_state,
+            n_groups=m.n_groups,
+        )
+        ok = pallas_ssm.supports(**shapes, chunk=chunk, dtype=cache.ssm.dtype)
     if force == "pallas" and not ok:
         attention_mod.forced_pallas_miss(
             "shapes out of the state update kernel's envelope "
-            f"(H={m.n_heads}, P={m.head_dim}, N={m.d_state}, "
-            f"G={m.n_groups}, chunk={chunk}, {cache.ssm.dtype})"
+            f"({shapes}, chunk={chunk}, {cache.ssm.dtype})"
         )
     if ok and (force == "pallas" or not attention_mod.pallas_interpret()):
-        return "ssm.kernel"
+        return name
     return "xla"
 
 
@@ -2460,7 +2505,7 @@ def forward_ragged(
         cfg, cache, lens, body, h,
         (params["blocks"], jnp.arange(cfg.n_kv_layers, dtype=jnp.int32)),
         linear=params.get("linear"),
-        in_place=state_update(cfg, cache, mesh, S) == "ssm.kernel",
+        in_place=state_update(cfg, cache, mesh, S) != "xla",
         moe=_moe_of(params, cache, kv_write_positions, slots),
     )
     if aux is not None:

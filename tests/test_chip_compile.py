@@ -40,8 +40,8 @@ from llmss_tpu.engine.cache import PagedKVCache, ssm_state_shapes
 from llmss_tpu.models.decoder import param_shapes, param_specs
 from llmss_tpu.models.registry import config_from_hf
 from llmss_tpu.ops import (
-    pallas_attention, pallas_decode, pallas_mla, pallas_paged_decode,
-    pallas_ragged, pallas_ssm,
+    pallas_attention, pallas_decode, pallas_gdn, pallas_mla,
+    pallas_paged_decode, pallas_ragged, pallas_ssm,
 )
 from llmss_tpu.parallel import mesh as mesh_mod
 
@@ -133,6 +133,27 @@ def _kernel_call(kernel: str, Hq: int, Hkv: int, D: int):
             ((rows, chunk, G, N_), f32), ((rows, chunk, G, N_), f32),
             ((rows,), i32), ((), i32),
         ]
+    if kernel == "delta_update":
+        # the delta rule's state pool's update at the shapes of the
+        # benchmark's fourth and fifth cells: 64 rows of 30 heads x [96, 192]
+        # over 9 layers and of 32 x [128, 128] over 6, a mixed step's 4 or 8
+        # positions a row (``Hkv``) or a decode step's 1
+        rows, chunk, f32, (Dk, Dv) = 64, Hkv, jnp.float32, D
+        assert pallas_gdn.supports(Hq, Dk, Dv, chunk)
+        key = ((rows, chunk, Hq, Dk), f32)
+        lens = ((rows,), i32)
+
+        def update(pool, q, k, v, g, beta, lens, layer, interpret):
+            return pallas_gdn.gdn_pool_update(
+                pool, q, k, v, g, beta, lens, pallas_gdn.live_rows(lens),
+                layer, interpret=interpret,
+            )
+
+        return update, [
+            ((9, rows, Hq, Dk, Dv), f32), key, key,
+            ((rows, chunk, Hq, Dv), f32), ((rows, chunk, Hq), f32),
+            ((rows, chunk, Hq), f32), lens, ((), i32),
+        ]
     assert kernel == "ragged"
     assert pallas_ragged.supports(BS, Hq, Hkv, D, DT)
     return pallas_ragged.ragged_paged_attention, [
@@ -147,6 +168,13 @@ def _kernel_call(kernel: str, Hq: int, Hkv: int, D: int):
 LATENT_READS = {"mixed-step": (32, 8, 640), "decode-step": (32, 1, 640)}
 # (heads, positions a row a step, d_state) of the state pool's update
 STATE_UPDATES = {"mixed-step-of-4": (32, 4, 256), "one-step": (32, 1, 256)}
+# (value heads, positions a row a step, (Dk, Dv)) of the delta rule's update
+DELTA_UPDATES = {
+    "olmo-hybrid-step-of-4": (30, 4, (96, 192)),
+    "olmo-hybrid-one-step": (30, 1, (96, 192)),
+    "qwen3-next-step-of-8": (32, 8, (128, 128)),
+    "qwen3-next-one-step": (32, 1, (128, 128)),
+}
 
 
 @pytest.mark.parametrize(
@@ -155,11 +183,12 @@ STATE_UPDATES = {"mixed-step-of-4": (32, 4, 256), "one-step": (32, 1, 256)}
         (kernel, model) for model in WIDTHS
         for kernel in ("flash", "dense_decode", "paged_decode", "ragged")
     ] + [("latent_read", step) for step in LATENT_READS]
-    + [("state_update", step) for step in STATE_UPDATES],
+    + [("state_update", step) for step in STATE_UPDATES]
+    + [("delta_update", step) for step in DELTA_UPDATES],
 )
 def test_kernel_compiles_for_v5e(v5e, kernel, model):
     fn, shapes = _kernel_call(
-        kernel, *(WIDTHS | LATENT_READS | STATE_UPDATES)[model]
+        kernel, *(WIDTHS | LATENT_READS | STATE_UPDATES | DELTA_UPDATES)[model]
     )
     on_chip = SingleDeviceSharding(v5e)
     args = [
@@ -369,7 +398,8 @@ def test_a_share_of_the_experts_under_two_kinds_of_layer_fits(v5e, monkeypatch):
                   (8, 128, 2048, 512)):
         assert _pool_sized_copies(text, shape, moved) == [], shape
     assert _layer_sized_slices(text, pool) == []
-    assert text.count("tpu_custom_call") == 12
+    # three a layer of the period's four, and the three linear layers' state
+    assert text.count("tpu_custom_call") == 12 + 3
     ma = compiled.memory_analysis()
     assert ma.argument_size_in_bytes == pytest.approx(9.50e9, rel=0.01)
     assert ma.temp_size_in_bytes < 1.2e9
@@ -463,6 +493,53 @@ def test_state_pool_is_updated_where_it_lies(v5e, monkeypatch, program):
         assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
 
 
+@pytest.mark.parametrize("program", ["decode", "ragged"])
+@pytest.mark.parametrize(
+    "config,pool,calls",
+    [
+        ("olmo-hybrid-7b-1chip", (9, 64, 30, 96, 192), 3),
+        # beside the grouped matmul's three calls a layer of the period's four
+        ("qwen3-next-80b-a3b-1chip", (6, 64, 32, 128, 128), 3 + 12),
+    ],
+    ids=["olmo-hybrid", "qwen3-next"],
+)
+def test_delta_rule_state_is_updated_where_it_lies(
+    v5e, monkeypatch, program, config, pool, calls,
+):
+    """Cells 4 and 5's decode and mixed groups as a TPU traces them
+    (``state_update`` says ``gdn.kernel``): the period's three linear layers
+    are three custom calls in the scan's body, each taking the state pool
+    straight from the carry with the pool as its result (the aliasing held:
+    no ``copy`` of the pool), and nothing else in the program produces the
+    pool's shape or a layer's (the slice the XLA path copies out, 0.19 and
+    0.13 GB, its update back, the passes between)."""
+    import importlib
+
+    # the program asks jax.default_backend(), which is the CPU here
+    monkeypatch.setattr(
+        importlib.import_module("llmss_tpu.ops.attention"),
+        "pallas_interpret", lambda: False,
+    )
+    compiled, _ = _compile_group(v5e, program, config)
+    text = compiled.as_text()
+    layer = ",".join(map(str, pool[1:]))
+    state = rf"f32\[(?:{pool[0]},|1,)?{layer}\]"
+    made = [
+        line.strip()[:100] for line in text.splitlines()
+        if re.match(rf"\s*(?:ROOT )?%\S+ = \(?{state}", line)
+        and not re.search(
+            r" (?:parameter|get-tuple-element|bitcast|while|tuple)\(", line)
+    ]
+    assert len(made) == 3, made
+    assert all(line.startswith("%gdn_pool_update") for line in made), made
+    assert text.count("tpu_custom_call") == calls
+    moved = r"(?:\w+_)?(?:copy|transpose|dynamic[-_]slice)"
+    assert _pool_sized_copies(text, pool, moved) == []
+    assert _pool_sized_copies(text, pool[1:], moved) == []
+    ma = compiled.memory_analysis()
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 14.5e9
+
+
 def test_supports_refuses_what_vmem_cannot_hold():
     """``supports()`` and the compiler agree: a K/V block pair that cannot
     fit the kernels' VMEM budget is refused up front (float32 at GPT-J
@@ -479,6 +556,10 @@ def test_supports_refuses_what_vmem_cannot_hold():
     # [128, 2048] do not
     assert pallas_ssm.supports(32, 128, 256, 2, 4)
     assert not pallas_ssm.supports(32, 128, 2048, 2, 4)
+    # the delta rule's: a row's 32 heads of [128, 128] fit whole, 8 heads of
+    # [128, 16384] do not
+    assert pallas_gdn.supports(32, 128, 128, 8)
+    assert not pallas_gdn.supports(32, 128, 16384, 8)
 
 
 @pytest.fixture

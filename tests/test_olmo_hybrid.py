@@ -26,8 +26,10 @@ from llmss_tpu.models import decoder
 from llmss_tpu.models.decoder import init_params
 from llmss_tpu.models.registry import MODEL_REGISTRY, config_from_hf
 from llmss_tpu.ops import gdn
+from llmss_tpu.ops.attention import force_impl
 from llmss_tpu.ops.layers import NormParams
 from llmss_tpu.parallel import MeshPlan, make_mesh
+from llmss_tpu.utils import trace
 
 ROOT = Path(__file__).resolve().parent.parent
 
@@ -264,20 +266,23 @@ def test_the_mixed_step_carries_the_state(engine, chunk):
     assert not compiled
 
 
-def test_the_mixed_step_matches_the_reference(engine):
-    """Logits, not tokens: prompts fed through ``forward_ragged`` four
-    tokens a row a step (rows of unequal length, so late steps mix a row
-    that still feeds with rows that are idle), then each row's first decoded
-    token through the same program: that step's logits against the
-    reference's full forward of prompt + first token."""
+def feed_mixed(engine, prompts, CB):
+    """Prompts fed through ``forward_ragged`` ``CB`` tokens a row a step
+    (rows of unequal length, so late steps mix a row that still feeds with
+    rows that are idle), then each row's first decoded token through the
+    same program. Returns ``(that step's logits [B, V], the sequences, the
+    cache)``; the program is traced anew, under whatever implementation is
+    forced now."""
     from llmss_tpu.models.decoder import forward_ragged
 
-    prompts = prompts_of([21, 12, 18, 9], seed=4)
-    B, CB = len(prompts), 4
+    B = len(prompts)
     cache = engine.new_paged_cache(B)
     fed = [0] * B
     seqs = [list(p) for p in prompts]
     final = {}
+    step = jax.jit(lambda params, ids, positions, cache, slots, q_lens, kv:
+                   forward_ragged(engine.cfg, params, ids, positions, cache,
+                                  slots, q_lens, kv_write_positions=kv))
     while any(f < len(s) for f, s in zip(fed, seqs)):
         ids = np.zeros((B, CB), np.int32)
         q_lens = np.zeros((B,), np.int32)
@@ -287,13 +292,12 @@ def test_the_mixed_step_matches_the_reference(engine):
         rel = np.arange(CB)[None]
         live = rel < q_lens[:, None]
         positions = np.asarray(fed)[:, None] + rel
-        logits, cache = forward_ragged(
-            engine.cfg, engine.params, jnp.asarray(ids),
+        logits, cache = step(
+            engine.params, jnp.asarray(ids),
             jnp.asarray(positions, jnp.int32), cache,
             jnp.asarray(np.where(live, positions, MAX_LEN), jnp.int32),
             jnp.asarray(np.maximum(q_lens, 1)),
-            kv_write_positions=jnp.asarray(
-                np.where(live, positions, -1), jnp.int32),
+            jnp.asarray(np.where(live, positions, -1), jnp.int32),
         )
         for i in range(B):
             fed[i] += int(q_lens[i])
@@ -304,9 +308,91 @@ def test_the_mixed_step_matches_the_reference(engine):
                 seqs[i].append(int(np.argmax(np.asarray(logits)[i, 0])))
             else:
                 final[i] = np.asarray(logits)[i, 0]
-    assert sorted(final) == [0, 1, 2, 3]
-    got = np.stack([final[i] for i in range(B)])
+    assert sorted(final) == list(range(B))
+    return np.stack([final[i] for i in range(B)]), seqs, cache
+
+
+@pytest.mark.filterwarnings("ignore:LLMSS_ATTN_IMPL=pallas")
+@pytest.mark.parametrize("path", ["xla", "pallas"])
+def test_the_mixed_step_matches_the_reference(engine, path):
+    """Logits, not tokens: prompts fed through ``forward_ragged`` four
+    tokens a row a step, then each row's first decoded token through the
+    same program: that step's logits against the reference's full forward
+    of prompt + first token; on the XLA path and with the state updated
+    where it lies (ops/pallas_gdn.py, interpreted)."""
+    with force_impl(path):
+        got, seqs, _ = feed_mixed(engine, prompts_of([21, 12, 18, 9], seed=4), 4)
     assert err(got, ref_logits(engine.params, seqs)) < TOL["float32"]
+
+
+@pytest.mark.filterwarnings("ignore:LLMSS_ATTN_IMPL=pallas")
+@pytest.mark.parametrize("chunk", [4, 8])
+def test_the_kernel_leaves_the_pools_the_xla_path_leaves(engine, chunk):
+    """The same mixed steps on both paths: the logits, the state pool and
+    the window pool agree to float32 rounding (rows that feed, rows that
+    decode one token and rows that idle in one step)."""
+    prompts = prompts_of([21, 12, 18, 9], seed=5)
+    with force_impl("xla"):
+        want, seqs, pools = feed_mixed(engine, prompts, chunk)
+    with force_impl("pallas"):
+        got, seqs_k, pools_k = feed_mixed(engine, prompts, chunk)
+    assert seqs_k == seqs
+    assert err(got, want) < TOL["float32"]
+    assert pools_k.ssm.dtype == jnp.float32
+    for a, b in ((pools_k.ssm, pools.ssm), (pools_k.conv, pools.conv)):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5
+        )
+
+
+@pytest.mark.filterwarnings("ignore:LLMSS_ATTN_IMPL=pallas")
+def test_cached_steps_match_reference_through_the_kernel(mesh):
+    """The decode step with each linear layer's state updated where it lies
+    (the kernel at one position, interpreted): steps 1, 2 and 16 against
+    the reference, as the XLA path above."""
+    with force_impl("pallas"):
+        eng = make_engine(mesh)
+        assert decoder.state_update(
+            eng.cfg, eng.new_paged_cache(4), mesh, 1) == "gdn.kernel"
+        errors = decode_errors(eng, prompts_of([21, 40, 37, 9]), 16, (1, 2, 16))
+    assert max(errors.values()) < TOL["float32"], errors
+
+
+@pytest.mark.filterwarnings("ignore:LLMSS_ATTN_IMPL=pallas")
+def test_mixed_and_decode_groups_update_the_pool_in_place(mesh, engine):
+    """Five requests through two rows, prompts streamed 4 tokens a row a
+    step beside rows that decode, rows done beside rows live, rows freed and
+    admitted again: with the kernel forced on (interpreted) every request's
+    tokens are those of the XLA path, the pools end where the XLA path's
+    do, and every group's ``sched.dispatch`` span says which update its
+    program was traced with."""
+    prompts = prompts_of([21, 40, 37, 9, 30], seed=7)
+
+    def serve(eng, how):
+        batcher = ContinuousBatcher(eng, rows=2, chunked_prefill=4)
+        for chunk in (1, 4):
+            assert decoder.state_update(
+                eng.cfg, batcher.cache, mesh, chunk) == how
+        trace.recorder().clear()
+        got = run_batcher(batcher, prompts, FIVE)
+        spans = [sp[5] for sp in trace.recorder().loop_spans()
+                 if sp[2] == "sched.dispatch"]
+        assert {a["kind"] for a in spans} == {"ragged_group", "decode_group"}
+        assert {a["state_update"] for a in spans} == {how}
+        return got, batcher.cache
+
+    was = trace.enabled()
+    trace.set_enabled(True)
+    try:
+        expected, pools = serve(engine, "xla")
+        with force_impl("pallas"):
+            got, pools_k = serve(make_engine(mesh), "gdn.kernel")
+    finally:
+        trace.set_enabled(was)
+    assert got == expected
+    np.testing.assert_allclose(
+        np.asarray(pools_k.ssm), np.asarray(pools.ssm), rtol=1e-4, atol=1e-5
+    )
 
 
 def test_preempt_and_replay_equals_uninterrupted(engine):

@@ -26,8 +26,10 @@ from llmss_tpu.models import decoder
 from llmss_tpu.models.decoder import forward_ragged, init_params
 from llmss_tpu.models.registry import MODEL_REGISTRY, config_from_hf
 from llmss_tpu.ops import gdn, moe
+from llmss_tpu.ops.attention import force_impl
 from llmss_tpu.ops.layers import NormParams
 from llmss_tpu.parallel import MeshPlan, make_mesh
+from llmss_tpu.utils import trace
 
 ROOT = Path(__file__).resolve().parent.parent
 
@@ -237,14 +239,16 @@ def test_a_bfloat16_state_fails_the_same_comparison(mesh, monkeypatch):
     assert min(errors[1], errors[8]) > TOL["float32"], errors
 
 
-def mixed_step_logits(eng, prompts, CB, extra_rows=0):
+def mixed_step_logits(eng, prompts, CB, extra_rows=0, pools=False):
     """Prompts fed through ``forward_ragged`` ``CB`` tokens a row a step
     (rows of unequal length, so late steps mix a row that still feeds with
     rows that are idle), then each row's first decoded token through the
     same program. ``extra_rows`` rows beside them are never live: a row that
     is done and padding rows (a slot out of range, no position recorded).
     Returns ``(logits of the decoded step [B, V], sequences, counts)``: the
-    routing counts summed over all steps."""
+    routing counts summed over all steps; with ``pools``, the cache after
+    them. The program is traced anew, under whatever implementation is
+    forced now."""
     B, R = len(prompts), len(prompts) + extra_rows
     cache = eng.new_paged_cache(R)
     fed = [0] * B
@@ -279,7 +283,8 @@ def mixed_step_logits(eng, prompts, CB, extra_rows=0):
             else:
                 final[i] = np.asarray(logits)[i, 0]
     assert sorted(final) == list(range(B))
-    return np.stack([final[i] for i in range(B)]), seqs, counts
+    out = np.stack([final[i] for i in range(B)]), seqs, counts
+    return (*out, cache) if pools else out
 
 
 def _ragged(eng, params, cache, ids, positions, slots, q_lens, kv_pos):
@@ -311,6 +316,80 @@ def test_the_mixed_step_matches_the_reference(mesh, dtype, held):
         assert counts[2] == 0
     else:  # a quarter of the experts: about a quarter of the pairs
         assert 0.1 < counts[0] / (tokens * 4 * 8) < 0.4
+
+
+@pytest.mark.filterwarnings("ignore:LLMSS_ATTN_IMPL=pallas")
+def test_the_mixed_step_matches_the_reference_through_the_kernel(mesh):
+    """The same comparison with each linear layer's state updated where it
+    lies (ops/pallas_gdn.py, interpreted; grouped value heads: the kernel
+    takes the key heads repeated): the reference's logits within the
+    float32 tolerance, and the logits, the routing counts, the state pool
+    and the window pool of the XLA path, beside a done row and a padding
+    row that the kernel never visits."""
+    eng = engine_of(mesh)
+    prompts = prompts_of([21, 12, 18, 9], seed=4)
+    with force_impl("xla"):
+        want, seqs, counts, pools = mixed_step_logits(
+            eng, prompts, 8, extra_rows=2, pools=True)
+    with force_impl("pallas"):
+        assert decoder.state_update(eng.cfg, pools, mesh, 8) == "gdn.kernel"
+        got, seqs_k, counts_k, pools_k = mixed_step_logits(
+            eng, prompts, 8, extra_rows=2, pools=True)
+    assert seqs_k == seqs and counts_k.tolist() == counts.tolist()
+    assert err(got, ref_logits(eng.params, seqs)) < TOL["float32"]
+    assert err(got, want) < TOL["float32"]
+    for a, b in ((pools_k.ssm, pools.ssm), (pools_k.conv, pools.conv)):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5
+        )
+    # the two rows that were never live hold the zeros they started with
+    assert not np.asarray(pools_k.ssm)[:, len(prompts):].any()
+
+
+@pytest.mark.filterwarnings("ignore:LLMSS_ATTN_IMPL=pallas")
+def test_cached_steps_match_reference_through_the_kernel(mesh):
+    """The decode step with the kernel at one position (interpreted): steps
+    1, 2 and 8 against the reference, as the XLA path above."""
+    with force_impl("pallas"):
+        eng = make_engine(mesh)
+        assert decoder.state_update(
+            eng.cfg, eng.new_paged_cache(4), mesh, 1) == "gdn.kernel"
+        errors = decode_errors(eng, prompts_of([21, 40, 37, 9]), 8, (1, 2, 8))
+    assert max(errors.values()) < TOL["float32"], errors
+
+
+@pytest.mark.filterwarnings("ignore:LLMSS_ATTN_IMPL=pallas")
+def test_mixed_and_decode_groups_update_the_pool_in_place(mesh, engine):
+    """Five requests through two rows, prompts streamed 8 tokens a row a
+    step beside rows that decode, rows done beside rows live: with the
+    kernel forced on (interpreted) every request's tokens are those of the
+    XLA path, the state pool ends where the XLA path's does, and every
+    group's ``sched.dispatch`` span says which update its program was
+    traced with."""
+    prompts = prompts_of([21, 40, 37, 9, 30], seed=7)
+
+    def serve(eng, how):
+        batcher = ContinuousBatcher(eng, rows=2, chunked_prefill=8)
+        trace.recorder().clear()
+        got = run_batcher(batcher, prompts, FIVE)
+        spans = [sp[5] for sp in trace.recorder().loop_spans()
+                 if sp[2] == "sched.dispatch"]
+        assert {a["kind"] for a in spans} == {"ragged_group", "decode_group"}
+        assert {a["state_update"] for a in spans} == {how}
+        return got, batcher.cache
+
+    was = trace.enabled()
+    trace.set_enabled(True)
+    try:
+        expected, pools = serve(engine, "xla")
+        with force_impl("pallas"):
+            got, pools_k = serve(make_engine(mesh), "gdn.kernel")
+    finally:
+        trace.set_enabled(was)
+    assert got == expected
+    np.testing.assert_allclose(
+        np.asarray(pools_k.ssm), np.asarray(pools.ssm), rtol=1e-4, atol=1e-5
+    )
 
 
 def _expert_layer(eng, hf, x):
