@@ -205,6 +205,9 @@ def table_sentinel(num_blocks: int) -> int:
 
 
 class PagedKVCache(NamedTuple):
+    # Up to three block pools under one block table (keys, values or one
+    # latent for both, and ``idx``, the indexer's keys of a model with
+    # learned sparse attention), and a row-indexed state pool.
     # [L_kv, N, bs, Hkv, D] global block pool. A pool's layer axis counts
     # the layers of ITS kind: every layer holds keys and values in most
     # models (L_kv is the depth), a quarter of them where linear-attention
@@ -246,6 +249,18 @@ class PagedKVCache(NamedTuple):
     # of range (positive: the view's padding rows) writes nowhere. None:
     # batch row i is pool row i and goes on from its state.
     state_rows: jax.Array | None = None
+    # A THIRD block pool, of a model that selects what attention reads
+    # (cfg.indexer, docs/sparse-attention.md): [L_kv, N, bs, W] float32,
+    # the ONE indexer key a token holds in a layer, paged by the same block
+    # table and written by the same post-scan ``paged_write_stacked`` as its
+    # keys and values, read by every later step over the row's whole
+    # context. float32 whatever the compute dtype: the selection it feeds
+    # is discontinuous, and a stored key must be the numbers the reference
+    # computes. W is the key's 64 numbers zero-padded to a whole lane tile
+    # (``IndexerConfig.pool_dim``: 512 B a token and layer for 256 B of
+    # key, what the device's own layout would pad it to). No head axis, as
+    # the latent pool has none. None: the model has no indexer.
+    idx: jax.Array | None = None
 
     @property
     def max_len(self) -> int:
@@ -295,6 +310,7 @@ def paged_cache_specs(
         k=kv, v=None if latent else kv,
         block_tables=P(None, None), positions=P(None, None),
         k_scale=scale, v_scale=scale, ssm=P(), conv=P(), state_rows=P(),
+        idx=P(None, None, None, None),
     )
 
 
@@ -350,6 +366,7 @@ def init_paged_cache(
     identity_tables: bool = True,
     state_shapes: tuple | None = None,
     state_layers: int = 0,
+    index_dim: int | None = None,
 ) -> PagedKVCache:
     """Zeroed paged cache. ``identity_tables=True`` pre-maps row ``b`` to
     blocks ``[b*MB, (b+1)*MB)`` — a dense-equivalent static layout for the
@@ -360,7 +377,9 @@ def init_paged_cache(
     ``n_layers`` is the block pools' layer axis (``cfg.n_kv_layers``).
     ``state_shapes`` (``ssm_state_shapes(cfg)``) adds the zeroed recurrent
     state of ``batch`` rows over ``state_layers`` layers
-    (``cfg.n_state_layers``: the state pools' own layer axis)."""
+    (``cfg.n_state_layers``: the state pools' own layer axis).
+    ``index_dim`` (``cfg.indexer.pool_dim``) adds the zeroed float32 pool
+    of indexer keys, blocks and layers as the keys' (``idx``)."""
     if max_len % block_size:
         raise ValueError(
             f"max_len {max_len} must be a multiple of block_size "
@@ -374,6 +393,12 @@ def init_paged_cache(
         )
     quantized = jnp.dtype(dtype) == jnp.int8
     latent = len(row) == 1
+    if index_dim is not None and quantized:
+        raise ValueError(
+            "an indexer's key pool is not carried beside an int8 pool: the "
+            "selection reads float32 keys and nothing measures it over "
+            "quantized keys and values (docs/sparse-attention.md)"
+        )
     if latent and quantized:
         raise ValueError(
             "the latent pool is not carried in int8: its one 'head' holds "
@@ -411,6 +436,10 @@ def init_paged_cache(
                 ("ssm", "conv"), (specs.ssm, specs.conv), state_shapes
             )
         }),
+        idx=None if index_dim is None else put(
+            specs.idx,
+            jnp.zeros((n_layers, n, block_size, index_dim), jnp.float32),
+        ),
     )
 
 
@@ -500,7 +529,10 @@ def export_blocks(
 
     Returns ``{"k", "v", "k_scale", "v_scale"}`` as host numpy arrays of
     shape ``[L, nb, bs, Hkv, D]`` (scales ``[L, nb, bs, Hkv]``, None on
-    bf16 pools).
+    bf16 pools), and ``"idx"`` (``[L, nb, bs, W]`` float32) where the cache
+    holds an indexer's keys: the blocks' third payload, which
+    ``import_blocks(idx=)`` takes back. (The hand-off's wire format and the
+    tiered store's blobs have no field for it: both refuse such a model.)
     """
     ids = np.asarray(block_ids, np.int32)
     nb = len(ids)
@@ -519,10 +551,13 @@ def export_blocks(
         mask = valid.reshape((1, nb, bs) + (1,) * (seg.ndim - 3))
         return np.where(mask, seg, np.zeros_like(seg))
 
-    return {
+    out = {
         "k": grab(cache.k), "v": grab(cache.v),
         "k_scale": grab(cache.k_scale), "v_scale": grab(cache.v_scale),
     }
+    if cache.idx is not None:
+        out["idx"] = grab(cache.idx)
+    return out
 
 
 def export_dense_row(
@@ -558,7 +593,7 @@ def export_dense_row(
 
 
 def import_blocks(
-    cache: PagedKVCache, k, v, k_scale, v_scale, block_ids,
+    cache: PagedKVCache, k, v, k_scale, v_scale, block_ids, idx=None,
 ) -> PagedKVCache:
     """Scatter exported block payloads into the pool at ``block_ids``
     ([nb] int32; sentinel entries drop under mode="drop", so callers may
@@ -580,6 +615,7 @@ def import_blocks(
         k=put(cache.k, k), v=put(cache.v, v),
         k_scale=put(cache.k_scale, k_scale),
         v_scale=put(cache.v_scale, v_scale),
+        **({} if cache.idx is None else {"idx": put(cache.idx, idx)}),
     )
 
 

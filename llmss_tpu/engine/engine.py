@@ -167,6 +167,35 @@ class DecodeEngine:
                     "one chip's share of them is a configuration "
                     "(docs/recurrent-state.md, 'The experts' share')"
                 )
+        if cfg.indexer is not None:
+            # The pool of indexer keys (docs/sparse-attention.md) is carried
+            # by the paged layout, float32 beside keys and values in the
+            # compute dtype, on one device; what is more than that is refused
+            # by name, not served wrong.
+            from llmss_tpu.parallel.mesh import AXIS_SP, AXIS_TP
+
+            if kv_layout != "paged":
+                raise ValueError(
+                    f"model_type {cfg.model_type!r} selects what attention "
+                    "reads by an indexer whose keys live in the paged cache "
+                    "only: pass kv_layout='paged' (docs/sparse-attention.md)"
+                )
+            if kv_dtype == "int8":
+                raise ValueError(
+                    "an indexer's key pool is not carried beside an int8 "
+                    "pool (kv_dtype='int8'): nothing measures the selection "
+                    "over quantized keys and values "
+                    "(docs/sparse-attention.md)"
+                )
+            if mesh is not None and (
+                mesh.shape.get(AXIS_TP, 1) > 1 or mesh.shape.get(AXIS_SP, 1) > 1
+            ):
+                raise ValueError(
+                    "a model with an indexer is served at tp == 1 and "
+                    "sp == 1 only: its selection is a row's own, over its "
+                    "whole context, and nothing shards the indexer's heads "
+                    "or its pool yet (docs/sparse-attention.md)"
+                )
         if cfg.mla is not None:
             # The latent pool (docs/latent-cache.md) is carried by the paged
             # layout in the compute dtype on one chip's worth of heads;
@@ -288,6 +317,13 @@ class DecodeEngine:
         from llmss_tpu.models.decoder import forward
 
         B, S = ids.shape
+        if cfg.indexer is not None:
+            if start is not None:
+                raise ValueError(
+                    "prefix reuse is not carried for a model with an "
+                    "indexer: a retained segment holds keys and values, not "
+                    "the indexer's keys (docs/sparse-attention.md)"
+                )
         if cfg.has_state:
             if start is not None:
                 raise ValueError(
@@ -395,6 +431,12 @@ class DecodeEngine:
                 "prefix reuse is not carried for a model with a recurrent "
                 "state: a retained segment would need the state at its end "
                 "(docs/recurrent-state.md)"
+            )
+        if self.cfg.indexer is not None:
+            raise ValueError(
+                "prefix reuse is not carried for a model with an indexer: a "
+                "retained segment holds keys and values, not the indexer's "
+                "keys (docs/sparse-attention.md)"
             )
         P = len(token_ids)
         if not 0 < P < self.max_seq_len:
@@ -524,9 +566,10 @@ class DecodeEngine:
             logits[:, 0], tok, done, poisoned, eos
         )
         cur_pos = cur_pos + 1
-        # the step's routing counts; None without routed experts
+        # the step's routing counts and selection counts; None without
+        # routed experts, without an indexer
         return (tok, cache, cur_pos, done, poisoned), (
-            tok, aux.get("moe_counts"),
+            tok, (aux.get("moe_counts"), aux.get("dsa_counts")),
         )
 
     @staticmethod
@@ -566,34 +609,42 @@ class DecodeEngine:
         pin = ys_pin(mesh)
 
         def chunk(carry, _):
-            carry, (toks, moe) = jax.lax.scan(
+            carry, (toks, counts) = jax.lax.scan(
                 body, carry, None, length=n_steps
             )
             # Snapshot per-chunk: toks [n_steps, B] → [B, n_steps]; the
             # poison flags as of this chunk's end.
-            return carry, (pin(toks.T), pin(carry[4]), moe)
+            return carry, (pin(toks.T), pin(carry[4]), counts)
 
         poisoned0 = jnp.zeros_like(done)
-        carry, (toks, pois, moe) = jax.lax.scan(
+        carry, (toks, pois, counts) = jax.lax.scan(
             chunk, (tokens, cache, cur_pos, done, poisoned0), None,
             length=n_chunks,
         )
         tokens, cache, cur_pos, done, _ = carry
-        packed = DecodeEngine._pack_group(toks, pois, moe)
+        packed = DecodeEngine._pack_group(toks, pois, counts)
         return packed, tokens, cache, cur_pos, done
 
+    #: how many numbers each kind of count adds to a group's packed fetch
+    COUNTS = (3, 4)
+
     @staticmethod
-    def _pack_group(toks, pois, moe):
+    def _pack_group(toks, pois, counts):
         """The ONE int32 vector a group sends to the host: its tokens, its
-        per-chunk poison flags, and for a model with routed experts three
-        more numbers at the end, ``pairs``, ``experts_hit`` and
-        ``pairs_elsewhere`` (ops/moe.py: ``routed_experts``) summed over the
-        expert layers and the group's steps (``moe``: the steps' stacked
-        counts, None without experts)."""
+        per-chunk poison flags, and at the end the counts its steps left
+        (``counts``: ``(moe, dsa)``, the steps' stacked counts, each None
+        where the model has none), summed over the group's steps: for a
+        model with routed experts three numbers, ``pairs``, ``experts_hit``
+        and ``pairs_elsewhere`` (ops/moe.py: ``routed_experts``, summed over
+        the expert layers), then for a model with an indexer four, ``scored``,
+        ``kept``, ``dense_rows`` and ``rows`` (models/decoder.py:
+        ``_forward_selected``)."""
         parts = [toks.reshape(-1), pois.astype(jnp.int32).reshape(-1)]
-        if moe is not None:
-            parts.append(jnp.sum(moe.reshape(-1, 3), axis=0))
+        for c, n in zip(counts, DecodeEngine.COUNTS):
+            if c is not None:
+                parts.append(jnp.sum(c.reshape(-1, n), axis=0))
         return jnp.concatenate(parts)
+
 
     @staticmethod
     def _ragged_step_body(cfg, mesh, params, sample_args, eos, carry, xs):
@@ -644,7 +695,7 @@ class DecodeEngine:
         done = jnp.where(emit, done2, done)
         cur_pos = cur_pos + q_lens
         return (tok, cache, cur_pos, done, poisoned), (
-            tok, aux.get("moe_counts"),
+            tok, (aux.get("moe_counts"), aux.get("dsa_counts")),
         )
 
     @staticmethod
@@ -671,16 +722,16 @@ class DecodeEngine:
         pin = ys_pin(mesh)
 
         def step(carry, xs):
-            carry, (tok, moe) = body(carry, xs)
-            return carry, (pin(tok), pin(carry[4]), moe)
+            carry, (tok, counts) = body(carry, xs)
+            return carry, (pin(tok), pin(carry[4]), counts)
 
         poisoned0 = jnp.zeros_like(done)
-        carry, (toks, pois, moe) = jax.lax.scan(
+        carry, (toks, pois, counts) = jax.lax.scan(
             step, (tokens, cache, cur_pos, done, poisoned0),
             (ids_seq, qlens_seq, feed_seq, emit_seq),
         )
         tokens, cache, cur_pos, done, _ = carry
-        packed = DecodeEngine._pack_group(toks, pois, moe)
+        packed = DecodeEngine._pack_group(toks, pois, counts)
         return packed, tokens, cache, cur_pos, done
 
     # -- host API -----------------------------------------------------------
@@ -912,6 +963,9 @@ class DecodeEngine:
             identity_tables=identity,
             state_shapes=ssm_state_shapes(self.cfg),
             state_layers=self.cfg.n_state_layers,
+            index_dim=(
+                self.cfg.indexer.pool_dim if self.cfg.indexer else None
+            ),
         )
 
     # -- canonical state shardings ------------------------------------------

@@ -201,6 +201,12 @@ class ContinuousBatcher:
                 f"pool of model_type {engine.cfg.model_type!r}: its wire "
                 "format names keys and values (docs/latent-cache.md)"
             )
+        if prefill_only and engine.cfg.indexer is not None:
+            raise ValueError(
+                "prefill_only (the KV hand-off) does not carry the indexer's "
+                f"key pool of model_type {engine.cfg.model_type!r}: its wire "
+                "format names keys and values (docs/sparse-attention.md)"
+            )
         if prefill_only and engine.cfg.has_state:
             # What does not carry a model's recurrent state refuses the
             # model, so that nothing runs and is silently wrong
@@ -259,6 +265,8 @@ class ContinuousBatcher:
                 )
         self.chunked_prefill = chunked_prefill
         self._chunked = chunked_prefill is not None
+        # prompts that may feed at once: set below, once the pool exists
+        self._feed_rows: int | None = None
         # row -> remaining prompt tokens to feed / total prompt length
         # (worker-thread state, like ``active``).
         self._inflight_prefill: dict[int, list[int]] = {}
@@ -313,6 +321,17 @@ class ContinuousBatcher:
                     self.cache.k.nbytes
                     // (self.cache.num_blocks * self.cache.block_size)
                 )
+            if self.cache.idx is not None:
+                engine.metrics.set_index_bytes_per_token(
+                    self.cache.idx.nbytes
+                    // (self.cache.num_blocks * self.cache.block_size)
+                )
+                if self._chunked:
+                    from llmss_tpu.models.decoder import feed_rows
+
+                    self._feed_rows = feed_rows(
+                        engine.cfg, self.cache, chunked_prefill
+                    )
             if self.cache.ssm is not None:
                 engine.metrics.set_state_pool(
                     self.cache.ssm.nbytes + self.cache.conv.nbytes,
@@ -472,7 +491,7 @@ class ContinuousBatcher:
             ),
             k_scale=self.cache.k_scale, v_scale=self.cache.v_scale,
             ssm=self.cache.ssm, conv=self.cache.conv,
-            state_rows=state_rows,
+            state_rows=state_rows, idx=self.cache.idx,
         )
 
     def _paged_absorb(self, view: PagedKVCache, row_idx: np.ndarray) -> None:
@@ -497,7 +516,7 @@ class ContinuousBatcher:
             k_scale=view.k_scale, v_scale=view.v_scale,
             # the admitted rows' state is already in the pool: the prefill
             # wrote it there (models/decoder.py: _layer_scan)
-            ssm=view.ssm, conv=view.conv,
+            ssm=view.ssm, conv=view.conv, idx=view.idx,
         ))
 
     def _zeroed_state(self, row_idx: np.ndarray) -> dict:
@@ -676,7 +695,7 @@ class ContinuousBatcher:
         self.cache = eng.canon_cache(self.cache._replace(
             k=scratch.k, v=scratch.v,
             k_scale=scratch.k_scale, v_scale=scratch.v_scale,
-            ssm=scratch.ssm, conv=scratch.conv,
+            ssm=scratch.ssm, conv=scratch.conv, idx=scratch.idx,
         ))
 
     def prewarm(
@@ -936,6 +955,12 @@ class ContinuousBatcher:
                 "prefix reuse is not carried for a model with a recurrent "
                 "state (docs/recurrent-state.md)"
             )
+        if prefix is not None and self.engine.cfg.indexer is not None:
+            raise ValueError(
+                "prefix reuse is not carried for a model with an indexer: a "
+                "retained segment holds keys and values, not the indexer's "
+                "keys (docs/sparse-attention.md)"
+            )
         if replayed and not 0 < replayed < len(token_ids):
             raise ValueError(
                 f"replayed={replayed} must be in [0, len(token_ids))"
@@ -1008,6 +1033,14 @@ class ContinuousBatcher:
                 return None
             head_prefix = self.pending[0][6]
             free_n = len(self._free)
+            if self._feed_rows is not None:
+                # a model whose mixed step works only so many feeding rows
+                # (models/decoder.py: feed_rows): the others wait here
+                free_n = min(
+                    free_n, self._feed_rows - len(self._inflight_prefill)
+                )
+                if free_n <= 0:
+                    return None
             taken, rest = [], deque()
             while self.pending:
                 item = self.pending.popleft()
@@ -1435,6 +1468,12 @@ class ContinuousBatcher:
                 "the KV hand-off does not carry a latent pool: its wire "
                 "format names keys and values (docs/latent-cache.md)"
             )
+        if self.engine.cfg.indexer is not None:
+            raise ValueError(
+                "the KV hand-off does not carry an indexer's key pool: its "
+                "wire format names keys and values "
+                "(docs/sparse-attention.md)"
+            )
         if self.engine.cfg.has_state:
             raise ValueError(
                 "the KV hand-off does not carry a recurrent state: a row "
@@ -1556,6 +1595,12 @@ class ContinuousBatcher:
             raise ValueError(
                 "session parking does not carry a latent pool: the tiered "
                 "store's blobs name keys and values (docs/latent-cache.md)"
+            )
+        if self.engine.cfg.indexer is not None:
+            raise ValueError(
+                "session parking does not carry an indexer's key pool: the "
+                "tiered store's blobs name keys and values "
+                "(docs/sparse-attention.md)"
             )
         if self.engine.cfg.has_state:
             raise ValueError(
@@ -1812,22 +1857,31 @@ class ContinuousBatcher:
         return n
 
     def _count_moe(self, group: _InFlightGroup, flat: np.ndarray) -> dict:
-        """A model with routed experts: the group's ``pairs``,
-        ``experts_hit`` and ``pairs_elsewhere`` (the last three numbers of
-        its packed fetch, engine.py: ``_pack_group``) added to /metrics'
-        ``loop.moe`` and returned as the attributes the group's
-        ``sched.callback`` span carries; nothing for any other model."""
-        cfg = self.engine.cfg
-        if cfg.moe is None:
-            return {}
-        pairs, hit, elsewhere = (int(n) for n in flat[-3:])
-        self.engine.metrics.add_moe(
-            pairs, hit,
-            (cfg.n_layers - cfg.n_lead_layers) * group.n_chunks * group.k,
-            elsewhere,
-        )
-        return {"pairs": pairs, "experts_hit": hit,
-                "pairs_elsewhere": elsewhere}
+        """The counts at the end of a group's packed fetch (engine.py:
+        ``_pack_group``), added to /metrics and returned as the attributes
+        the group's ``sched.callback`` span carries. A model with routed
+        experts: ``pairs``, ``experts_hit`` and ``pairs_elsewhere``
+        (``loop.moe``). A model with an indexer, after them: ``dsa_scored``,
+        ``dsa_kept``, ``dsa_dense_rows`` and ``dsa_rows`` (``loop.dsa``).
+        Nothing for any other model."""
+        cfg, out = self.engine.cfg, {}
+        n_moe, n_dsa = DecodeEngine.COUNTS
+        if cfg.indexer is not None:
+            scored, kept, dense, rows = (int(n) for n in flat[-n_dsa:])
+            flat = flat[:-n_dsa]
+            self.engine.metrics.add_dsa(scored, kept, dense, rows)
+            out.update(dsa_scored=scored, dsa_kept=kept,
+                       dsa_dense_rows=dense, dsa_rows=rows)
+        if cfg.moe is not None:
+            pairs, hit, elsewhere = (int(n) for n in flat[-n_moe:])
+            self.engine.metrics.add_moe(
+                pairs, hit,
+                (cfg.n_layers - cfg.n_lead_layers) * group.n_chunks * group.k,
+                elsewhere,
+            )
+            out.update(pairs=pairs, experts_hit=hit,
+                       pairs_elsewhere=elsewhere)
+        return out
 
     def _apply_group(
         self, group: _InFlightGroup, flat: np.ndarray, loop: int | None,

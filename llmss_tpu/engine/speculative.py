@@ -273,6 +273,12 @@ def generate_speculative(
             "windowed verify step has no absorbed form yet "
             "(docs/latent-cache.md)"
         )
+    if engine.cfg.indexer is not None:
+        raise ValueError(
+            "speculative decoding is not carried for a model with an "
+            "indexer: the windowed verify step has no selection a draft "
+            "position yet (docs/sparse-attention.md)"
+        )
     if engine.cfg.has_state:
         raise ValueError(
             "speculative decoding does not carry a recurrent state: a "

@@ -145,12 +145,14 @@ class MoEConfig:
     """Routed experts in every layer after the first ``n_dense_layers``
     (which keep the dense MLP of ``DecoderConfig.intermediate_size``): a
     router in float32 over ALL ``n_experts``, the ``top_k`` of them a token,
-    beside ONE shared SwiGLU of ``shared_size`` (ops/moe.py).
+    beside ONE shared SwiGLU of ``shared_size`` (ops/moe.py), or beside none
+    (``shared_size`` 0, keye_vl2: the tree then has no leaf for it and the
+    layer no matmul).
 
     ``scoring`` ``"sigmoid"`` (deepseek_v3): sigmoid scores, chosen by score
     plus a selection-only bias, weights renormalised over the chosen
     (``norm_topk_prob``) and times ``routed_scaling_factor``. ``"softmax"``
-    (qwen3_next): a softmax over all the experts, the largest ``top_k``,
+    (qwen3_next, keye_vl2): a softmax over all the experts, the largest ``top_k``,
     renormalised over the chosen; no bias, no factor. ``shared_gate``: the
     shared expert's output is times ``sigmoid(x . w)`` a token.
 
@@ -174,6 +176,37 @@ class MoEConfig:
     @property
     def n_held(self) -> int:
         return self.n_experts if self.count is None else self.count
+
+
+@dataclasses.dataclass(frozen=True)
+class IndexerConfig:
+    """A learned selection of what attention reads (DeepSeek Sparse
+    Attention's lightning indexer, at Keye-VL-2.0's shapes): every layer
+    scores a query's whole visible context with ``n_heads`` small heads of
+    ``head_dim`` against ONE cached key of ``head_dim`` a token, ``I[t, s] =
+    sum_j w[t, j] relu(qI[t, j] . kI[s])``, and every head of the layer then
+    attends over the ``topk`` best positions and nothing else (all of them
+    while the context is at most ``topk``). The key is cached beside the
+    token's keys and values, in a pool of its own (``PagedKVCache.idx``);
+    projections, scores and selection are float32 whatever the compute
+    dtype (ops/sparse_attention.py, docs/sparse-attention.md)."""
+
+    n_heads: int
+    head_dim: int
+    topk: int
+
+    @property
+    def pool_dim(self) -> int:
+        """Width of a token's row in the pool of indexer keys: ``head_dim``
+        rounded up to whole 128-lane tiles, the tail zero (``MLAConfig.
+        pool_dim``'s lesson over again). A float32 pool ``[L, N, 16, 64]``
+        gets a default device layout with the BLOCK axis minor, and every
+        step program then transposes the pool whole on its way in and out
+        (compiled for a described v5e, PR 46); the row-major layout would
+        pad 64 lanes to 128 in memory anyway. 128 keeps it row-major, at the
+        bytes the device would have spent: 512 B a token and layer for 256
+        B of key."""
+        return -(-self.head_dim // 128) * 128
 
 
 @dataclasses.dataclass(frozen=True)
@@ -274,6 +307,10 @@ class DecoderConfig:
     qk_norm: bool = False
     qk_norm_per_head: bool = False
     attn_gate: bool = False
+
+    # keye_vl2: attention over a learned top-k selection of the context.
+    # None leaves every other family's tree, cache and programs as they are.
+    indexer: IndexerConfig | None = None
 
     # compute dtype for activations; params are loaded in this dtype too
     dtype: str = "bfloat16"
@@ -379,6 +416,25 @@ class DecoderConfig:
         layers before the expert stack; 0 for a model of one kind of
         layer."""
         return 0 if self.moe is None else self.moe.n_dense_layers
+
+
+def experts_held(hf, family: str) -> tuple[int, int, int | None]:
+    """``(experts the router scores, first held here, how many; None: all)``
+    of a published config with routed experts. ``num_experts`` counts the
+    experts HELD; a chip's share of an expert-parallel deployment says so in
+    ``expert_parallel``: ``{"num_experts": <the model's>, "chips": <that
+    share each layer's>, "chip": <this one's index>}``, experts in
+    contiguous ranges by chip (``family`` names the refusal)."""
+    ep = getattr(hf, "expert_parallel", None)
+    if ep is None:
+        return hf.num_experts, 0, None
+    total, chips, chip = ep["num_experts"], ep["chips"], ep["chip"]
+    if total != chips * hf.num_experts or not 0 <= chip < chips:
+        raise ValueError(
+            f"{family}: expert_parallel {ep} does not give num_experts "
+            f"{hf.num_experts} held here (the model's experts / chips)"
+        )
+    return total, chip * hf.num_experts, hf.num_experts
 
 
 def act_fn(name: str):

@@ -48,7 +48,7 @@ from llmss_tpu.ops.attention import (
     window_mask_penalty,
 )
 from llmss_tpu.ops.layers import (
-    LinearParams, NormParams, dense, dense_t, embedding, rms_norm,
+    LinearParams, NormParams, dense, dense_t, embedding, layer_norm, rms_norm,
 )
 from llmss_tpu.ops.rope import apply_rope, sin_cos_tables
 from llmss_tpu.ops.gdn import gdn_chunked, gdn_step, l2_normalize
@@ -127,6 +127,12 @@ def param_specs(cfg: DecoderConfig, tp: int) -> Params:
         # or one scale of ``head_dim`` that every head shares (qwen3_next)
         blocks["q_norm"] = _norm_specs(True, False)
         blocks["k_norm"] = _norm_specs(True, False)
+    if cfg.indexer is not None:
+        # The indexer's three projections and the LayerNorm on its key,
+        # replicated (served at tp == 1 only: DecodeEngine refuses more).
+        for name in ("idx_q", "idx_k", "idx_w"):
+            blocks[name] = LinearParams(w=P(None, None, None), b=None)
+        blocks["idx_k_norm"] = _norm_specs(True, True)
     if cfg.has_ln2:
         blocks["ln2"] = _norm_specs(True, norm_bias)
     swiglu = {
@@ -143,12 +149,13 @@ def param_specs(cfg: DecoderConfig, tp: int) -> Params:
         # family's; the stacked experts of all layers are ONE top-level
         # stack (``params["experts"]``), indexed by the layer's absolute
         # index whatever its kind.
-        routed = {
-            "router": LinearParams(w=rep3, b=None),
-            "shared_gate": LinearParams(w=rep3, b=None),
-            "shared_up": LinearParams(w=rep3, b=None),
-            "shared_down": LinearParams(w=rep3, b=None),
-        }
+        routed = {"router": LinearParams(w=rep3, b=None)}
+        if cfg.moe.shared_size:  # 0: no shared expert, no leaf for one
+            routed.update({
+                "shared_gate": LinearParams(w=rep3, b=None),
+                "shared_up": LinearParams(w=rep3, b=None),
+                "shared_down": LinearParams(w=rep3, b=None),
+            })
         if cfg.moe.shared_gate:
             routed["shared_sig"] = LinearParams(w=rep3, b=None)
         blocks.update(routed)
@@ -487,6 +494,21 @@ def _routed_family_draw(cfg: DecoderConfig) -> dict:
             "gdn_g": normal(0.9), "gdn_o": normal(0.3, writes=True),
             **{k: gdn[k] for k in ("gdn_conv", "gdn_A_log", "gdn_dt_bias")},
         })
+    if cfg.indexer is not None:
+        # The indexer's top-k is the router's hazard over again (thousands
+        # of scores a query, neighbours in rank closer than a bfloat16
+        # rounding), and has the router's cure: its three projections read
+        # the routing block ALONE (zero rows elsewhere), from the normed
+        # input in float32 before its rounding. A token's key is then the
+        # same numbers in any compute dtype (its LayerNorm takes out the one
+        # positive factor RMSNorm leaves on the block) and a query's scores
+        # the same up to one positive factor a query, which orders nothing
+        # otherwise. Scores of a deviation of a few units from the block.
+        def reads_block(k, shape):  # [L, E, out]
+            w = jax.random.normal(k, shape, f32) / R ** 0.5
+            return w.at[..., R:, :].set(0.0)
+
+        draw.update(idx_q=reads_block, idx_k=reads_block, idx_w=reads_block)
     return draw
 
 
@@ -523,13 +545,22 @@ def param_shapes(cfg: DecoderConfig) -> Params:
             }
         # a query and a gate a head, side by side ([H, 2, D] on the out axis)
         Qw = 2 * Q if cfg.attn_gate else Q
-        qk_norms = {}
+        extra = {}  # the QK-norms' and the indexer's leaves
         if cfg.qk_norm or cfg.qk_norm_per_head:
             D = cfg.head_dim
-            qk_norms = {
+            extra = {
                 "q_norm": NormParams(sds(n, D if cfg.qk_norm_per_head else Q), None),
                 "k_norm": NormParams(sds(n, D if cfg.qk_norm_per_head else KV), None),
             }
+        if cfg.indexer is not None:
+            x = cfg.indexer
+            extra.update({
+                "idx_q": LinearParams(sds(n, E, x.n_heads * x.head_dim), None),
+                "idx_k": LinearParams(sds(n, E, x.head_dim), None),
+                "idx_w": LinearParams(sds(n, E, x.n_heads), None),
+                "idx_k_norm": NormParams(
+                    sds(n, x.head_dim), sds(n, x.head_dim)),
+            })
         return {
             "ln1": norm_shape(n),
             # q/k transposed storage [L, out, in] (see param_specs).
@@ -540,19 +571,20 @@ def param_shapes(cfg: DecoderConfig) -> Params:
             "v": LinearParams(
                 sds(n, E, KV), sds(n, KV) if cfg.attn_bias else None),
             "o": LinearParams(sds(n, Q, E), sds(n, E) if cfg.o_bias else None),
-            **qk_norms,
+            **extra,
         }
 
     def routed_shapes(n):
         """The expert layer's own leaves in a stack of ``n`` layers (the
         experts themselves are one stack of all layers)."""
         x = cfg.moe
-        out = {
-            "router": LinearParams(sds(n, x.n_experts, E), None),
-            "shared_gate": LinearParams(sds(n, E, x.shared_size), None),
-            "shared_up": LinearParams(sds(n, E, x.shared_size), None),
-            "shared_down": LinearParams(sds(n, x.shared_size, E), None),
-        }
+        out = {"router": LinearParams(sds(n, x.n_experts, E), None)}
+        if x.shared_size:
+            out.update({
+                "shared_gate": LinearParams(sds(n, E, x.shared_size), None),
+                "shared_up": LinearParams(sds(n, E, x.shared_size), None),
+                "shared_down": LinearParams(sds(n, x.shared_size, E), None),
+            })
         if x.shared_gate:
             out["shared_sig"] = LinearParams(sds(n, E, 1), None)
         return out
@@ -726,6 +758,8 @@ def _routed_mlp(cfg: DecoderConfig, bp: Params, x, x32, live, experts):
             stacks["experts_up"], stacks["experts_down"], act, layer=layer,
             first=None if m.count is None else m.first,
         )
+    if "shared_gate" not in bp:  # ``MoEConfig.shared_size`` 0
+        return y.reshape(B, S, E), counts
     with jax.named_scope("moe.shared"):
         shared = dense(
             act(dense(x, bp["shared_gate"])) * dense(x, bp["shared_up"]),
@@ -1015,6 +1049,9 @@ def _block(
     # (live [B, S], (stacked experts, this layer's index among them)) where
     # the block's MLP is the routed experts (see ``_mlp_or_experts``)
     moe_in=None,
+    # out-parameter of a block with an indexer: ``aux["index_key"]`` [B, S,
+    # Di] float32, the tokens' fresh indexer keys for the post-scan write
+    aux: dict | None = None,
 ):
     """One decoder block. The last two elements returned are the mixer's
     new ``(ssm, conv)`` state, None for a config without one, and the expert
@@ -1038,7 +1075,22 @@ def _block(
     res = h
     # post-norm (Olmo 2): a branch reads the residual as it is and is
     # normed on its way back into it
-    x = h if cfg.post_norm else _norm(cfg, h, bp["ln1"])
+    index = None
+    if cfg.indexer is not None:
+        if not defer_write or attn_override is None:
+            raise NotImplementedError(
+                f"model_type {cfg.model_type!r} selects what attention "
+                "reads, which this forward does not carry: it is served "
+                "from the paged cache (kv_layout='paged')"
+            )
+        # the indexer reads the normed input before its rounding
+        x32 = _norm(cfg, h, bp["ln1"], jnp.float32)
+        x = x32.astype(h.dtype)
+        with jax.named_scope("dsa.index"):
+            index = _index_of(cfg, bp, x32)
+        aux["index_key"] = index[2]
+    else:
+        x = h if cfg.post_norm else _norm(cfg, h, bp["ln1"])
 
     xa = _scale(x, cfg.attn_in_multiplier)
     q = dense_t(xa, bp["q"])
@@ -1074,7 +1126,9 @@ def _block(
         pad = [(0, 0), (0, 0), (0, pool_heads - Hkv), (0, 0)]
         q, k, v = jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad)
 
-    if defer_write:
+    if index is not None:
+        attn = attn_override(q, k, v, k_cache, v_cache, index=index)
+    elif defer_write:
         if attn_override is not None:
             attn = attn_override(q, k, v, k_cache, v_cache)
         else:
@@ -1130,6 +1184,28 @@ def _block(
     return h, k_cache, v_cache, k, v, ssm_out, counts
 
 
+def _index_of(cfg: DecoderConfig, bp: Params, x32):
+    """The indexer's side of a block (``IndexerConfig``): from the normed
+    input ``x32`` [B, S, E] float32, the queries ``[B, S, Hi, Di]``, the
+    head weights ``[B, S, Hi]`` (times ``Hi^-1/2 Di^-1/2``) and the ONE key
+    ``[B, S, Di]`` a token (LayerNorm with scale and bias, eps 1e-6), all
+    float32 at ``Precision.HIGHEST`` whatever the compute dtype. No rotary
+    (docs/sparse-attention.md)."""
+    x = cfg.indexer
+    B, S, _ = x32.shape
+    f32, hi = jnp.float32, jax.lax.Precision.HIGHEST
+
+    def proj(name):
+        return jnp.einsum(
+            "bse,eo->bso", x32, bp[name].w.astype(f32), precision=hi
+        )
+
+    qi = proj("idx_q").reshape(B, S, x.n_heads, x.head_dim)
+    ki = layer_norm(proj("idx_k"), bp["idx_k_norm"], 1e-6)
+    wi = proj("idx_w") * (x.n_heads ** -0.5 * x.head_dim ** -0.5)
+    return qi, wi, ki
+
+
 def _ssm_lens(cache, kv_write_positions, slots):
     """How many of a call's positions are real, a row: those that record a
     position (padding carries -1) and write a slot (a done row's slot is
@@ -1181,13 +1257,24 @@ def _layer_scan(cfg: DecoderConfig, cache, lens, body, h, xs, linear=None,
     every batch row starts from zeros, as a prompt does, and its final state
     is written to pool row ``state_rows[i]``; a row index out of range (the
     view's padding rows) writes nowhere."""
-    if not cfg.has_state:
+    if not cfg.has_state and moe is None:
         def plain(h, xs):
             h, ys, _, _ = body(h, xs, None, None)
             return h, ys
 
         h, ys = jax.lax.scan(plain, h, xs)
         return h, ys, None, None
+    if not cfg.has_state:
+        # one kind of layer, each followed by routed experts (keye_vl2)
+        def routed(h, xs_l):
+            xs, l = xs_l
+            h, ys, _, counts = body(h, xs, None, (moe[0], (moe[1], l)))
+            return h, (ys, counts)
+
+        h, (ys, counts) = jax.lax.scan(
+            routed, h, (xs, jnp.arange(cfg.n_layers, dtype=jnp.int32))
+        )
+        return h, ys, None, jnp.sum(counts, axis=0)
     rows, B = cache.state_rows, h.shape[0]
 
     def state_in(ssm, conv, l):
@@ -1536,6 +1623,12 @@ def forward(
         raise NotImplementedError(
             "a model with a recurrent state is served from the paged cache "
             "only (kv_layout='paged'): the dense ring has no state pool"
+        )
+    if cfg.indexer is not None:
+        raise NotImplementedError(
+            "a model that selects what attention reads is served from the "
+            "paged cache only (kv_layout='paged'): the dense ring has no "
+            "pool for its indexer's keys"
         )
     dtype = cfg.compute_dtype
     h = _embed_in(cfg, params, input_ids, positions, mesh)
@@ -1893,6 +1986,13 @@ def _forward_paged(
             kv_write_positions=kv_write_positions, mesh=mesh,
             t_bucket=t_bucket, aux=aux,
         )
+    if cfg.indexer is not None:
+        return _forward_selected(
+            cfg, params, input_ids, positions, cache, slots,
+            last_only=last_only, gather_idx=gather_idx,
+            kv_write_positions=kv_write_positions, mesh=mesh,
+            t_bucket=t_bucket, aux=aux,
+        )
     dtype = cfg.compute_dtype
     h = _embed_in(cfg, params, input_ids, positions, mesh)
 
@@ -2069,8 +2169,15 @@ def attn_read(cfg: DecoderConfig, cache: PagedKVCache, mesh, chunk: int) -> str:
     """How a decode step (``chunk`` 1) or a mixed step of ``chunk`` tokens a
     row reads the paged pool, as its program is traced NOW: ``gather`` (the
     rows' logical views gathered, the XLA oracles), ``mla.kernel`` (a latent
-    pool read in place, ops/pallas_mla.py) or ``kernel`` (the block-table
-    kernels ``LLMSS_ATTN_IMPL=pallas`` opts a pool of keys and values into).
+    pool read in place, ops/pallas_mla.py), ``kernel`` (the block-table
+    kernels ``LLMSS_ATTN_IMPL=pallas`` opts a pool of keys and values into),
+    or, for a model that selects what attention reads (``cfg.indexer``),
+    ``dsa.tokens`` (a decode step: the indexer's keys gathered, keys and
+    values of the kept tokens read by token) and ``dsa.mask`` (a mixed step:
+    every row's first query as a decode step reads it, the rows that feed a
+    prompt through the mask form: their three views gathered, a selection a
+    query position as a mask); ops/sparse_attention.py. A decode step whose read bucket is at most
+    ``topk`` slots drops nothing and reads as ``gather`` does.
 
     A latent pool's kernel is chosen by ``dispatch_attention``'s own rule:
     shapes inside ``pallas_mla.supports`` and compiled on a TPU, or forced
@@ -2081,6 +2188,8 @@ def attn_read(cfg: DecoderConfig, cache: PagedKVCache, mesh, chunk: int) -> str:
 
     attention_mod = importlib.import_module("llmss_tpu.ops.attention")
     force = attention_mod.IMPL_OVERRIDE
+    if cfg.indexer is not None:
+        return "dsa.tokens" if chunk == 1 else "dsa.mask"
     if cfg.mla is None:
         return "kernel" if force == "pallas" and mesh is not None else "gather"
     if force == "xla":
@@ -2102,6 +2211,26 @@ def attn_read(cfg: DecoderConfig, cache: PagedKVCache, mesh, chunk: int) -> str:
     if ok and (force == "pallas" or not attention_mod.pallas_interpret()):
         return "mla.kernel"
     return "gather"
+
+
+def feed_rows(cfg: DecoderConfig, cache: PagedKVCache, chunk: int,
+              t_bucket: int | None = None) -> int | None:
+    """How many rows may feed a prompt through ONE mixed step of ``chunk``
+    tokens a row; None: as many as there are. A model that selects what
+    attention reads (``cfg.indexer``) works a feeding row's whole chunk
+    through the mask form, ``[heads, chunk, context]`` float32 scores a row,
+    and a step works one turn of such rows (``ops/sparse_attention.py:
+    chunk_rows``), so that its cost is the same whichever rows feed; the
+    scheduler admits no more prompts at once, the others wait their turn in
+    the queue."""
+    if cfg.indexer is None:
+        return None
+    from llmss_tpu.ops import sparse_attention as dsa
+
+    B = cache.block_tables.shape[0]
+    T = cache.max_len if t_bucket is None else min(t_bucket, cache.max_len)
+    F = dsa.chunk_rows(B, cfg.n_heads, chunk, T + chunk)
+    return None if F >= B else F
 
 
 def state_update(cfg: DecoderConfig, cache: PagedKVCache, mesh, chunk: int) -> str:
@@ -2320,6 +2449,197 @@ def _forward_latent(
     return logits, cache._replace(k=pool, positions=new_kv_positions)
 
 
+def _forward_selected(
+    cfg: DecoderConfig,
+    params: Params,
+    input_ids: jax.Array,  # [B, S]
+    positions: jax.Array,  # [B, S]
+    cache: PagedKVCache,  # with ``idx``, the pool of indexer keys
+    slots: jax.Array,  # [B, S] LOGICAL slots
+    *,
+    q_lens: jax.Array | None = None,  # [B]: the call is a mixed step
+    last_only: bool = False,
+    gather_idx: jax.Array | None = None,
+    kv_write_positions: jax.Array | None = None,
+    mesh=None,
+    t_bucket: int | None = None,
+    aux: dict | None = None,
+) -> tuple[jax.Array, PagedKVCache]:
+    """The paged forwards of a model that selects what attention reads
+    (``cfg.indexer``, docs/sparse-attention.md): prefill, the decode step
+    (S == 1) and the mixed step (``q_lens`` set), under the callers'
+    contract of ``_forward_paged`` / ``forward_ragged``.
+
+    One discipline for all three, the latent family's: the layer scan closes
+    over the stale pools and READS them, every layer hands back its fresh
+    keys, values and indexer keys, and one ``paged_write_stacked`` a pool
+    after the scan writes them. The decode step scores the row's view of the
+    indexer pool and reads the kept tokens' keys and values by token
+    (``sparse_decode_attention``; a read bucket of at most ``topk`` slots
+    drops nothing and is ``paged_decode_attention`` as every other family
+    runs it). The mixed step and the prefill are ONE form, the prefill a
+    chunk as long as its bucket that starts at the row's first position: the
+    three gathered views with a selection a query position as a mask
+    (``sparse_chunk_attention``); a mixed step of more rows than one turn of
+    that form holds works only the rows that feed a prompt so (``feed_rows``)
+    and every row's first query as a decode step works it.
+
+    ``aux["dsa_counts"]`` int32 [4], over the step's live rows and all
+    layers: cached and fresh positions the indexer scored for a row's LAST
+    live query, positions that query kept, rows whose context was at most
+    ``topk`` (nothing dropped), and the rows counted."""
+    from llmss_tpu.ops import sparse_attention as dsa
+
+    topk = cfg.indexer.topk
+    h = _embed_in(cfg, params, input_ids, positions, mesh)
+    if kv_write_positions is None:
+        kv_write_positions = positions
+    new_kv_positions = write_positions(
+        cache.positions, kv_write_positions, slots
+    )
+    B, S = input_ids.shape
+    bs, MB = cache.block_size, cache.max_blocks
+    tables = cache.block_tables
+    sin_cos = None
+    if cfg.positions == "rotary":
+        sin_cos = sin_cos_tables(
+            positions, cfg.rotary_dim or cfg.head_dim, cfg.rope_theta,
+            cfg.rope_freq_factors, cfg.rope_attn_factor,
+        )
+    nb = None
+    if t_bucket is not None and t_bucket < cache.max_len:
+        nb = min(-(-t_bucket // bs), MB)
+    Tv = (nb if nb is not None else MB) * bs
+    kv_pos_src = cache.positions[:, :Tv]
+    # live positions a row: a mixed step's ``q_lens`` but for done rows
+    lens = _ssm_lens(cache, kv_write_positions, slots)
+    # the cached slots a row's queries may see at all (live, not pending)
+    cache_vis = ragged_cache_visibility(
+        lens, kv_pos_src, slots[:, 0], cache.max_len
+    )
+
+    if S == 1 and q_lens is None and Tv <= topk:
+        penalty = decode_mask_penalty(positions, kv_pos_src, slots, None)
+
+        def attn(q, k_new, v_new, k_c, v_c, *, layer, index):
+            del k_c, v_c, index  # every slot read is kept
+            return paged_decode_attention(
+                q, cache.k, cache.v, k_new, v_new, positions, kv_pos_src,
+                tables, slots, scale=cfg.attn_scale, penalty=penalty,
+                n_blocks=nb, layer=layer,
+            )
+    elif S == 1 and q_lens is None:
+        def attn(q, k_new, v_new, k_c, v_c, *, layer, index):
+            del k_c, v_c  # reads the stacked pools directly
+            qi, wi, ki = index
+            with jax.named_scope("dsa.decode"):
+                return dsa.sparse_decode_attention(
+                    q, cache.k, cache.v, cache.idx, k_new, v_new, ki, qi,
+                    wi, positions, kv_pos_src, tables, slots, layer,
+                    topk=topk, scale=cfg.attn_scale, n_blocks=nb,
+                )
+    else:
+        q_pos0 = positions[:, 0]
+        # A mixed step of more rows than one turn of the mask form holds:
+        # the rows that feed a prompt (at most ``F``: the scheduler admits
+        # no more at once, ``feed_rows``) are worked through all of their
+        # chunk by the mask form, every row's first query as a decode step
+        # works it. The prefill works every row through all of its chunk,
+        # turn after turn.
+        F = feed_rows(cfg, cache, S, t_bucket) if q_lens is not None else None
+        feeding = None if F is None else jnp.nonzero(
+            lens > 1, size=F, fill_value=B
+        )[0]
+
+        def attn(q, k_new, v_new, k_c, v_c, *, layer, index):
+            del k_c, v_c  # reads the stacked pools directly
+            qi, wi, ki = index
+
+            def mask_form(rows, cols):
+                """Rows ``rows`` (None: all) through their first ``cols``
+                queries and fresh tokens. The rows' views are gathered from
+                the pools by THEIR tables: a gather of rows out of all rows'
+                views made layout assignment carry the pools slot-minor and
+                copy them whole around every step (compiled for a described
+                v5e, PR 46)."""
+                r = (lambda a: a) if rows is None else (lambda a: a[rows])
+                c = lambda a: r(a)[:, :cols]
+                views = (
+                    gather_block_view(pool, r(tables), nb, layer)
+                    for pool in (cache.k, cache.v, cache.idx)
+                )
+                return dsa.sparse_chunk_attention(
+                    c(q), *views, c(k_new), c(v_new), c(ki), c(qi), c(wi),
+                    r(q_pos0), jnp.minimum(r(lens), cols), r(kv_pos_src),
+                    r(cache_vis), topk=topk, scale=cfg.attn_scale,
+                )
+
+            if feeding is None:
+                with jax.named_scope("dsa.chunk"):
+                    return mask_form(None, S)
+            # every row's first query as a decode step reads it, by token
+            # (a decoding row has no other; a dense view of all rows' keys
+            # and values was 31 of a step's 85 ms: my chip run, PR 46) ...
+            with jax.named_scope("dsa.decode"):
+                first = dsa.sparse_decode_attention(
+                    q[:, :1], cache.k, cache.v, cache.idx, k_new[:, :1],
+                    v_new[:, :1], ki[:, :1], qi[:, :1], wi[:, :1],
+                    positions[:, :1], kv_pos_src, tables, slots[:, :1],
+                    layer, topk=topk, scale=cfg.attn_scale, n_blocks=nb,
+                )
+            # ... and the rows that feed through all of their chunk
+            with jax.named_scope("dsa.chunk"):
+                whole = mask_form(jnp.minimum(feeding, B - 1), S)
+            out = jnp.zeros_like(q).at[:, :1].set(first)
+            return out.at[feeding].set(whole, mode="drop")
+
+    def body(h, xs, ssm_in, moe_in):
+        bp, layer = xs
+        out = {}
+        h, k_f, v_f, _, counts = _block(
+            cfg, bp, h, positions, None, None, kv_pos_src, slots, None,
+            mesh=mesh, defer_write=True,
+            attn_override=partial(attn, layer=layer), sin_cos=sin_cos,
+            moe_in=moe_in, aux=out,
+        )
+        return h, (k_f, v_f, out["index_key"]), None, counts
+
+    h, (k_f, v_f, ki_f), _, moe_counts = _layer_scan(
+        cfg, cache, lens, body, h,
+        (params["blocks"], jnp.arange(cfg.n_layers, dtype=jnp.int32)),
+        moe=_moe_of(params, cache, kv_write_positions, slots),
+    )
+    if aux is not None:
+        aux["moe_counts"] = moe_counts
+        # what a row's last live query saw: the cached slots that are live,
+        # not pending and not after it, and the step's own tokens up to it
+        last = positions[:, 0] + lens - 1
+        seen = lens + jnp.sum(
+            cache_vis & (kv_pos_src <= last[:, None]), axis=1,
+            dtype=jnp.int32,
+        )
+        live = lens > 0
+        aux["dsa_counts"] = cfg.n_layers * jnp.stack([
+            jnp.sum(jnp.where(live, seen, 0)),
+            jnp.sum(jnp.where(live, jnp.minimum(seen, topk), 0)),
+            jnp.sum(live & (seen <= topk)),
+            jnp.sum(live),
+        ]).astype(jnp.int32)
+
+    write = partial(
+        paged_write_stacked, block_tables=tables, slots=slots, block_size=bs
+    )
+    logits = _head_out(cfg, params, h, gather_idx, last_only)
+    # the keys zero-padded to the pool's row (``IndexerConfig.pool_dim``)
+    ki_f = jnp.pad(
+        ki_f, [(0, 0)] * 3 + [(0, cache.idx.shape[-1] - ki_f.shape[-1])]
+    )
+    return logits, cache._replace(
+        k=write(cache.k, k_f), v=write(cache.v, v_f),
+        idx=write(cache.idx, ki_f), positions=new_kv_positions,
+    )
+
+
 def _make_ragged_kernel_attn(
     cfg, mesh, cache, positions0, q_lens, slot0, nblk,
 ):
@@ -2429,6 +2749,12 @@ def forward_ragged(
     """
     if cfg.mla is not None:
         return _forward_latent(
+            cfg, params, input_ids, positions, cache, slots, q_lens=q_lens,
+            gather_idx=q_lens - 1, kv_write_positions=kv_write_positions,
+            mesh=mesh, t_bucket=t_bucket, aux=aux,
+        )
+    if cfg.indexer is not None:
+        return _forward_selected(
             cfg, params, input_ids, positions, cache, slots, q_lens=q_lens,
             gather_idx=q_lens - 1, kv_write_positions=kv_write_positions,
             mesh=mesh, t_bucket=t_bucket, aux=aux,

@@ -31,31 +31,15 @@ from __future__ import annotations
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from llmss_tpu.models.common import DecoderConfig, LinearAttnConfig, MoEConfig
+from llmss_tpu.models.common import (
+    DecoderConfig, LinearAttnConfig, MoEConfig, experts_held,
+)
 from llmss_tpu.models.decoder import Params, param_specs
 from llmss_tpu.ops.layers import LinearParams, NormParams, load_lm_head
 from llmss_tpu.parallel.mesh import AXIS_TP
 from llmss_tpu.weights.loader import CheckpointShards
 
 KINDS = ("linear_attention", "full_attention")
-
-
-def _experts_held(hf) -> tuple[int, int, int | None]:
-    """``(experts the router scores, first held here, how many; None: all)``.
-    ``num_experts`` counts the experts HELD; a chip's share of an
-    expert-parallel deployment says so in ``expert_parallel``:
-    ``{"num_experts": <the model's>, "chips": <that share each layer's>,
-    "chip": <this one's index>}``, experts in contiguous ranges by chip."""
-    ep = getattr(hf, "expert_parallel", None)
-    if ep is None:
-        return hf.num_experts, 0, None
-    total, chips, chip = ep["num_experts"], ep["chips"], ep["chip"]
-    if total != chips * hf.num_experts or not 0 <= chip < chips:
-        raise ValueError(
-            f"qwen3_next: expert_parallel {ep} does not give num_experts "
-            f"{hf.num_experts} held here (the model's experts / chips)"
-        )
-    return total, chip * hf.num_experts, hf.num_experts
 
 
 def config_from_hf(hf, dtype: str = "bfloat16") -> DecoderConfig:
@@ -89,7 +73,7 @@ def config_from_hf(hf, dtype: str = "bfloat16") -> DecoderConfig:
         hf.hidden_size // hf.num_attention_heads
     )
     rotary = int(head_dim * getattr(hf, "partial_rotary_factor", 1.0))
-    n_experts, first, count = _experts_held(hf)
+    n_experts, first, count = experts_held(hf, "qwen3_next")
     return DecoderConfig(
         model_type="qwen3_next",
         vocab_size=hf.vocab_size,

@@ -13,8 +13,8 @@ from pathlib import Path
 from jax.sharding import Mesh
 
 from llmss_tpu.models import (
-    deepseek_v3, falcon_h1, gemma, gpt2, gpt_bigcode, gpt_neox, gptj, llama,
-    mistral, olmo_hybrid, phi3, qwen2, qwen3_next,
+    deepseek_v3, falcon_h1, gemma, gpt2, gpt_bigcode, gpt_neox, gptj, keye_vl2,
+    llama, mistral, olmo_hybrid, phi3, qwen2, qwen3_next,
 )
 from llmss_tpu.models.common import DecoderConfig
 from llmss_tpu.models.decoder import Params
@@ -35,6 +35,7 @@ MODEL_REGISTRY = {
     "deepseek_v3": deepseek_v3,
     "olmo_hybrid": olmo_hybrid,
     "qwen3_next": qwen3_next,
+    "KeyeVL2": keye_vl2,
 }
 
 

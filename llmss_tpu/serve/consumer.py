@@ -464,6 +464,15 @@ class ContinuousWorker:
                 "the store's blobs name keys and values "
                 "(docs/latent-cache.md)"
             )
+        if getattr(getattr(engine, "cfg", None), "indexer", None) is not None and (
+            role != "unified" or kvstore is not None
+        ):
+            raise ValueError(
+                "a model with an indexer is served by a unified worker "
+                "without a tiered KV store: the hand-off's wire format and "
+                "the store's blobs name keys and values, not the indexer's "
+                "keys (docs/sparse-attention.md)"
+            )
         if getattr(getattr(engine, "cfg", None), "has_state", False) and (
             role != "unified" or kvstore is not None
         ):

@@ -175,6 +175,14 @@ class EngineMetrics:
         # expert layers x steps, of the groups FETCHED so far (the counts
         # come with a group's packed fetch). None = the model has none.
         self.moe_counts: list[int] | None = None  # guarded_by: self._lock
+        # A model with an indexer (cfg.indexer), cumulative likewise and
+        # over the live rows, layers and steps of the groups fetched so far:
+        # positions the indexer scored for a row's last live query,
+        # positions it kept, rows whose context was at most topk (nothing
+        # dropped), and the row-layer-steps counted. The gauge beside them:
+        # the bytes ONE token holds in the pool of indexer keys, all layers.
+        self.dsa_counts: list[int] | None = None  # guarded_by: self._lock
+        self.cache_index_bytes_per_token: int | None = None  # guarded_by: self._lock
         self._start = time.monotonic()
         # What the replica's bring-up recorded before this object existed
         # (``setup.runtime``, ``setup.weights``, JAX's seconds), and every
@@ -293,6 +301,19 @@ class EngineMetrics:
         with self._lock:
             self.cache_latent_bytes_per_token = n
 
+    def set_index_bytes_per_token(self, n: int) -> None:
+        with self._lock:
+            self.cache_index_bytes_per_token = n
+
+    def add_dsa(self, scored: int, kept: int, dense_rows: int,
+                rows: int) -> None:
+        """A fetched group's selection counts (engine.py: ``_pack_group``)."""
+        with self._lock:
+            acc = self.dsa_counts or [0, 0, 0, 0]
+            self.dsa_counts = [
+                a + b for a, b in zip(acc, (scored, kept, dense_rows, rows))
+            ]
+
     def add_moe(self, pairs: int, experts_hit: int, layer_steps: int,
                 pairs_elsewhere: int) -> None:
         """A fetched group's routing counts (engine.py: ``_pack_group``)."""
@@ -354,7 +375,13 @@ class EngineMetrics:
                      "moe.pairs_elsewhere"),
                     self.moe_counts,
                 ))
+            if self.dsa_counts is not None:
+                loop.update(zip(
+                    ("dsa.scored", "dsa.kept", "dsa.dense_rows", "dsa.rows"),
+                    self.dsa_counts,
+                ))
             gauges = {
+                "index_bytes_per_token": self.cache_index_bytes_per_token,
                 "state_bytes": self.cache_state_bytes,
                 "state_layers": self.cache_state_layers,
                 "kv_layers": self.cache_kv_layers,

@@ -235,10 +235,11 @@ def _compile_group(device, program: str, widths):
     return _compile_step(device, program, cfg, hf["serve"]["max_seq_len"])
 
 
-def _compile_step(device, program: str, cfg, POSITIONS: int):
+def _compile_step(device, program: str, cfg, POSITIONS: int, ROWS: int = ROWS,
+                  chunk: int = 4, t_bucket: int | None = 512):
     """``(compiled, pool shape)`` of one step program of the engine on
-    shapes alone: 4 decode steps at a 512-slot read, or 4 mixed steps with a
-    4-token chunk a row."""
+    shapes alone: 4 decode steps at a ``t_bucket``-slot read (None: the whole
+    ring), or 4 mixed steps with a ``chunk``-token chunk a row."""
     mesh = mesh_mod.make_mesh(mesh_mod.MeshPlan(tp=1), devices=[device])
 
     def arr(shape, dtype, spec=PartitionSpec()):
@@ -260,6 +261,8 @@ def _compile_step(device, program: str, cfg, POSITIONS: int):
             arr((cfg.n_state_layers, ROWS) + shape, dtype)
             for shape, dtype in ssm_state_shapes(cfg) or ()
         ))),
+        idx=None if cfg.indexer is None else arr(
+            pool[:3] + (cfg.indexer.pool_dim,), jnp.float32),
     )
     row = functools.partial(arr, (ROWS,))
     sample_args = dict(
@@ -275,14 +278,14 @@ def _compile_step(device, program: str, cfg, POSITIONS: int):
             functools.partial(DecodeEngine._decode_group_impl, cfg, mesh),
             donate_argnums=(1, 2, 3),
             static_argnames=("n_chunks", "n_steps", "t_bucket"),
-        ).lower(*state, n_chunks=1, n_steps=4, t_bucket=512)
+        ).lower(*state, n_chunks=1, n_steps=4, t_bucket=t_bucket)
     else:
         steps = functools.partial(arr, (4, ROWS))
         lowered = jax.jit(
             functools.partial(DecodeEngine._ragged_group_impl, cfg, mesh),
             donate_argnums=(1, 2, 3),
         ).lower(
-            *state, arr((4, ROWS, 4), jnp.int32), steps(jnp.int32),
+            *state, arr((4, ROWS, chunk), jnp.int32), steps(jnp.int32),
             steps(jnp.bool_), steps(jnp.bool_),
         )
     return lowered.compile(), pool
@@ -403,6 +406,64 @@ def test_a_share_of_the_experts_under_two_kinds_of_layer_fits(v5e, monkeypatch):
     ma = compiled.memory_analysis()
     assert ma.argument_size_in_bytes == pytest.approx(9.50e9, rel=0.01)
     assert ma.temp_size_in_bytes < 1.2e9
+
+
+@pytest.mark.parametrize("program", ["decode", "ragged"])
+def test_a_selection_inside_paged_attention_fits_beside_three_pools(
+    v5e, monkeypatch, program,
+):
+    """``keye-vl-2.0-30b-a3b-1chip`` at the published widths, 32 rows x
+    16,896 positions, the cell's mixed group (chunks of its
+    ``chunked_prefill``) and the decode group over the whole ring: the three
+    pools, keys and values ``bf16[6, 33792, 16, 4, 128]`` with their 4 KV
+    heads unpadded (``T(4,128)``) and the indexer's keys ``f32[6, 33792, 16,
+    128]`` row-major (64 numbers of key padded to a lane tile: as 64 wide the
+    default device layout put the block axis minor and transposed the pool
+    whole around every program), go through as they came, in ONE layout
+    each, with no copy or transpose of a pool and no slice of a layer; the
+    32 held experts of all 6 layers are one stack read in place by the
+    grouped matmul's kernel; arguments 10.69 GB, and the temporaries (the
+    mixed step's one turn of feeding rows through the mask form, under
+    ``ops/sparse_attention.py: MAP_BYTES`` of float32 scores a layer) well
+    under what is left of the chip's 15.75 GB."""
+    import importlib
+    import re
+
+    monkeypatch.setattr(
+        importlib.import_module("llmss_tpu.ops.attention"),
+        "pallas_interpret", lambda: False,
+    )
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmark", "configs", "keye-vl-2.0-30b-a3b-1chip.json")) as f:
+        hf = json.load(f)
+    with open(os.path.join(
+            root, "benchmark", "cells",
+            "keye-vl-2.0-30b-a3b-1chip.longdoc.json")) as f:
+        chunk = json.load(f)["serve"]["chunked_prefill"]
+    cfg = config_from_hf(types.SimpleNamespace(**hf), dtype=hf["dtype"])
+    compiled, pool = _compile_step(
+        v5e, program, cfg, hf["serve"]["max_seq_len"],
+        ROWS=hf["serve"]["rows"], chunk=chunk, t_bucket=None,
+    )
+    assert pool == (6, 32 * 1056, 16, 4, 128)
+    text = compiled.as_text()
+    index_pool = pool[:3] + (128,)
+    moved = r"(?:\w+_)?(?:copy|transpose|dynamic[-_]slice)"
+    for shape in (pool, index_pool, (6, 32, 2048, 768)):
+        assert _pool_sized_copies(text, shape, moved) == [], shape
+    assert _layer_sized_slices(text, pool) == []
+    assert _layer_sized_slices(text, index_pool) == []
+    dims = ",".join(map(str, pool))
+    assert set(re.findall(rf"bf16\[{dims}\]\{{([^}}]*)\}}", text)) == {
+        "4,3,2,1,0:T(4,128)(2,1)"}
+    dims = ",".join(map(str, index_pool))
+    assert set(re.findall(rf"f32\[{dims}\]\{{([^}}]*)\}}", text)) == {
+        "3,2,1,0:T(8,128)"}
+    assert text.count("tpu_custom_call") == 3  # a layer of the scan's one body
+    ma = compiled.memory_analysis()
+    assert ma.argument_size_in_bytes == pytest.approx(10.69e9, rel=0.01)
+    assert ma.temp_size_in_bytes < (1.3e9 if program == "ragged" else 0.6e9)
 
 
 # kakaocorp/kanana-2-30b-a3b-instruct-2601's widths (deepseek_v3: a latent

@@ -57,11 +57,13 @@ from llmss_tpu.ops import gdn  # noqa: E402
 from llmss_tpu.parallel import MeshPlan, make_mesh  # noqa: E402
 
 
-def mixed_logits(engine, params, prompts, chunk):
+def mixed_logits(engine, params, prompts, chunk, cap=None):
     """``(logits at each prompt's end, logits at the token decoded after it,
     that token)`` through ``forward_ragged`` alone: every row advances
     ``chunk`` tokens a step until its prompt is in, then one more step of one
-    token (the greedy pick), columns past a row's chunk no-ops."""
+    token (the greedy pick), columns past a row's chunk no-ops. ``cap``: no
+    more rows than that feed several tokens in one step, the others wait
+    (``models/decoder.py: feed_rows``)."""
     cfg, mesh, max_len = engine.cfg, engine.mesh, engine.max_seq_len
     step = jax.jit(
         functools.partial(decoder.forward_ragged, cfg, mesh=mesh),
@@ -74,8 +76,13 @@ def mixed_logits(engine, params, prompts, chunk):
     while len(dec) < B:
         ids = np.zeros((B, chunk), np.int32)
         q = np.zeros((B,), np.int32)
+        feeding = 0
         for i, s in enumerate(seqs):
             c = s[fed[i]: fed[i] + chunk]
+            if len(c) > 1:
+                if feeding == cap:
+                    continue  # waits its turn
+                feeding += 1
             ids[i, :len(c)], q[i] = c, len(c)
         live = rel < q[:, None]
         pos = np.asarray(fed)[:, None] + rel
