@@ -2032,9 +2032,15 @@ def _forward_paged(
         # layer as an INDEX (of the kernel's block map, or of the one
         # gather): never as a slice, which the compiler copies out whole
         # ahead of a gather (docs/paged-kv.md).
-        attn = _make_paged_kernel_attn(
-            cfg, mesh, cache, positions, slots, nblk
-        )
+        if attn_read(cfg, cache, mesh, 1) == "kv.kernel":
+            attn = _make_kv_read(
+                cfg, cache, positions[:, 0], jnp.ones((B,), jnp.int32),
+                slots[:, 0], kv_pos_src,
+            )
+        else:
+            attn = _make_paged_kernel_attn(
+                cfg, mesh, cache, positions, slots, nblk
+            )
         if attn is None:
             penalty = decode_mask_penalty(
                 positions, kv_pos_src, slots, cfg.sliding_window
@@ -2165,12 +2171,65 @@ def _latent_value_cols(m) -> int:
     return min(-(-m.kv_lora_rank // 128) * 128, m.pool_dim)
 
 
+def _kernel_heads(cfg: DecoderConfig, cache: PagedKVCache) -> tuple[int, int]:
+    """``(query heads, KV heads)`` as a read of the pool sees them: the
+    model's, or, where the pool pads its heads (``cfg.pool_kv_heads``: a
+    head a query head), the pool's for both (``_block`` pads the queries)."""
+    pool_heads = cache.k.shape[3]
+    if pool_heads != cfg.n_kv_heads:
+        return pool_heads, pool_heads
+    return cfg.n_heads, pool_heads
+
+
+def _blocks_held(cache: PagedKVCache) -> jax.Array:
+    """``[B]``: a row's table columns up to its last live slot, where a walk
+    of its blocks stops (not a count of live slots: a ring that wrapped, or
+    a window that let early blocks go, leaves holes before the last)."""
+    last = jnp.max(
+        jnp.where(
+            cache.positions >= 0,
+            jnp.arange(cache.positions.shape[1], dtype=jnp.int32), -1,
+        ),
+        axis=1,
+    )
+    return last // cache.block_size + 1
+
+
+def _make_kv_read(cfg, cache, q_pos0, q_lens, slot0, kv_pos_src):
+    """``attn_read``'s ``kv.kernel`` as a ``(q, k_new, v_new, k_cache,
+    v_cache, *, layer) -> attn`` callable for ``_block``: the decode step's
+    (``q_lens`` all 1) and the mixed step's read of the stacked pools where
+    they lie (ops/pallas_kv.py)."""
+    import importlib
+
+    from llmss_tpu.ops import pallas_kv
+
+    interp = importlib.import_module(
+        "llmss_tpu.ops.attention"
+    ).pallas_interpret()
+    n_blocks = _blocks_held(cache)
+
+    def attn(q, k_new, v_new, k_c, v_c, *, layer):
+        del k_c, v_c  # reads the stacked pools directly
+        return pallas_kv.kv_paged_attention(
+            q, cache.k, cache.v, k_new, v_new, q_pos0, q_lens, kv_pos_src,
+            cache.block_tables, n_blocks, slot0, layer,
+            ring_len=cache.max_len, scale=cfg.attn_scale,
+            window=cfg.sliding_window, interpret=interp,
+        )
+
+    return attn
+
+
 def attn_read(cfg: DecoderConfig, cache: PagedKVCache, mesh, chunk: int) -> str:
     """How a decode step (``chunk`` 1) or a mixed step of ``chunk`` tokens a
     row reads the paged pool, as its program is traced NOW: ``gather`` (the
     rows' logical views gathered, the XLA oracles), ``mla.kernel`` (a latent
-    pool read in place, ops/pallas_mla.py), ``kernel`` (the block-table
-    kernels ``LLMSS_ATTN_IMPL=pallas`` opts a pool of keys and values into),
+    pool read in place, ops/pallas_mla.py), ``kv.kernel`` (a pool of keys
+    and values read in place, each row's own blocks up to its length,
+    ops/pallas_kv.py), ``kernel`` (the block-at-a-time kernels that
+    ``LLMSS_ATTN_IMPL=pallas`` opts a pool of keys and values into where
+    ``kv.kernel`` does not apply: a ``tp`` mesh),
     or, for a model that selects what attention reads (``cfg.indexer``),
     ``dsa.tokens`` (a decode step: the indexer's keys gathered, keys and
     values of the kept tokens read by token) and ``dsa.mask`` (a mixed step:
@@ -2179,23 +2238,37 @@ def attn_read(cfg: DecoderConfig, cache: PagedKVCache, mesh, chunk: int) -> str:
     query position as a mask); ops/sparse_attention.py. A decode step whose read bucket is at most
     ``topk`` slots drops nothing and reads as ``gather`` does.
 
-    A latent pool's kernel is chosen by ``dispatch_attention``'s own rule:
-    shapes inside ``pallas_mla.supports`` and compiled on a TPU, or forced
-    (interpreted: the CPU tests); never under ``force == "xla"``."""
+    ``mla.kernel`` and ``kv.kernel`` are chosen by ``dispatch_attention``'s
+    own rule: one device, the pool in the compute dtype (an int8 pool keeps
+    the gather), shapes inside the kernel's ``supports`` and compiled on a
+    TPU, or forced (interpreted: the CPU tests); never under ``force ==
+    "xla"``."""
     import importlib
 
-    from llmss_tpu.ops import pallas_mla
+    from llmss_tpu.ops import pallas_kv, pallas_mla
 
     attention_mod = importlib.import_module("llmss_tpu.ops.attention")
     force = attention_mod.IMPL_OVERRIDE
     if cfg.indexer is not None:
         return "dsa.tokens" if chunk == 1 else "dsa.mask"
-    if cfg.mla is None:
-        return "kernel" if force == "pallas" and mesh is not None else "gather"
     if force == "xla":
         return "gather"
+    one_device = mesh is None or mesh.size == 1
+    if cfg.mla is None:
+        Hq, Hkv = _kernel_heads(cfg, cache)
+        ok = (
+            one_device
+            and not cache.quantized
+            and cache.k.dtype == cfg.compute_dtype
+            and pallas_kv.supports(
+                cache.block_size, Hq, Hkv, cfg.head_dim, chunk, cache.k.dtype
+            )
+        )
+        if ok and (force == "pallas" or not attention_mod.pallas_interpret()):
+            return "kv.kernel"
+        return "kernel" if force == "pallas" and mesh is not None else "gather"
     ok = (
-        (mesh is None or mesh.size == 1)
+        one_device
         and cache.k.dtype == cfg.compute_dtype
         and pallas_mla.supports(
             cache.block_size, cfg.n_heads, cfg.mla.pool_dim, chunk,
@@ -2352,20 +2425,13 @@ def _forward_latent(
             "llmss_tpu.ops.attention"
         ).pallas_interpret()
         lens = q_lens if q_lens is not None else jnp.ones((B,), jnp.int32)
-        # table columns up to a row's last live slot: where the walk stops
-        last = jnp.max(
-            jnp.where(
-                cache.positions >= 0,
-                jnp.arange(cache.positions.shape[1], dtype=jnp.int32), -1,
-            ),
-            axis=1,
-        )
+        held = _blocks_held(cache)
 
         def attend(layer, q, lat):
             # the pool as stored: no unit axis beside its minor dimension
             return pallas_mla.latent_paged_attention(
                 q, cache.k, lat, positions[:, 0], lens, kv_pos_src, tables,
-                last // bs + 1, slots[:, 0], layer, ring_len=cache.max_len,
+                held, slots[:, 0], layer, ring_len=cache.max_len,
                 scale=cfg.attn_scale, v_dim=_latent_value_cols(cfg.mla),
                 interpret=interp,
             )
@@ -2795,9 +2861,12 @@ def forward_ragged(
     nblk = jnp.clip(-(-occ // bs), 0, MB).astype(jnp.int32)
     # Same discipline as the S == 1 branch of _forward_paged: the scan
     # closes over the stacked pool and the layer is an index of the read.
-    attn = _make_ragged_kernel_attn(
-        cfg, mesh, cache, q_pos0, q_lens, slot0, nblk
-    )
+    if attn_read(cfg, cache, mesh, S) == "kv.kernel":
+        attn = _make_kv_read(cfg, cache, q_pos0, q_lens, slot0, kv_pos_src)
+    else:
+        attn = _make_ragged_kernel_attn(
+            cfg, mesh, cache, q_pos0, q_lens, slot0, nblk
+        )
     if attn is None:
         # Hoist the query-invariant visibility out of the layer scan (the
         # per-query causal bound stays inside the oracle — it is chunk

@@ -40,7 +40,7 @@ from llmss_tpu.engine.cache import PagedKVCache, ssm_state_shapes
 from llmss_tpu.models.decoder import param_shapes, param_specs
 from llmss_tpu.models.registry import config_from_hf
 from llmss_tpu.ops import (
-    pallas_attention, pallas_decode, pallas_gdn, pallas_mla,
+    pallas_attention, pallas_decode, pallas_gdn, pallas_kv, pallas_mla,
     pallas_paged_decode, pallas_ragged, pallas_ssm,
 )
 from llmss_tpu.parallel import mesh as mesh_mod
@@ -119,6 +119,22 @@ def _kernel_call(kernel: str, Hq: int, Hkv: int, D: int):
             ((rows, chunk, 1, D), DT), row, row, ((rows, mb * BS), i32),
             ((rows, mb), i32), row, row, ((), i32),
         ]
+    if kernel == "kv_read":
+        # the read of a pool of keys and values at the shapes of the
+        # benchmark's first, second, fourth and fifth cells: 64 rows of
+        # ``mb`` blocks, a mixed step's ``chunk`` tokens a row or a decode
+        # step's 1 (``D`` carries the three beside the head size)
+        rows, (D, layers, mb, chunk) = 64, D
+        assert pallas_kv.supports(BS, Hq, Hkv, D, chunk, DT)
+        row = ((rows,), i32)
+        pool = ((layers, rows * mb, BS, Hkv, D), DT)
+        fresh = ((rows, chunk, Hkv, D), DT)
+        return functools.partial(
+            pallas_kv.kv_paged_attention, ring_len=mb * BS, scale=D ** -0.5,
+        ), [
+            ((rows, chunk, Hq, D), DT), pool, pool, fresh, fresh, row, row,
+            ((rows, mb * BS), i32), ((rows, mb), i32), row, row, ((), i32),
+        ]
     if kernel == "state_update":
         # the Mamba-2 state pool's update at the shapes of the benchmark's
         # second cell: 64 rows of 32 heads x [128, 256] float32 over 5
@@ -177,6 +193,20 @@ DELTA_UPDATES = {
 }
 
 
+# (query heads, KV heads, (head size, layers, blocks a row, tokens a row a
+# step)) of the read of a pool of keys and values
+KV_READS = {
+    "kv-starcoderbase-decode": (16, 1, (128, 24, 128, 1)),
+    "kv-starcoderbase-step-of-4": (16, 1, (128, 24, 128, 4)),
+    "kv-falcon-h1-decode": (20, 4, (128, 5, 64, 1)),
+    "kv-falcon-h1-step-of-4": (20, 4, (128, 5, 64, 4)),
+    "kv-olmo-hybrid-decode": (32, 32, (128, 3, 64, 1)),
+    "kv-olmo-hybrid-step-of-4": (32, 32, (128, 3, 64, 4)),
+    "kv-qwen3-next-decode": (16, 2, (256, 2, 320, 1)),
+    "kv-qwen3-next-step-of-8": (16, 2, (256, 2, 320, 8)),
+}
+
+
 @pytest.mark.parametrize(
     "kernel,model",
     [
@@ -184,11 +214,15 @@ DELTA_UPDATES = {
         for kernel in ("flash", "dense_decode", "paged_decode", "ragged")
     ] + [("latent_read", step) for step in LATENT_READS]
     + [("state_update", step) for step in STATE_UPDATES]
-    + [("delta_update", step) for step in DELTA_UPDATES],
+    + [("delta_update", step) for step in DELTA_UPDATES]
+    + [("kv_read", step) for step in KV_READS],
 )
 def test_kernel_compiles_for_v5e(v5e, kernel, model):
     fn, shapes = _kernel_call(
-        kernel, *(WIDTHS | LATENT_READS | STATE_UPDATES | DELTA_UPDATES)[model]
+        kernel,
+        *(WIDTHS | LATENT_READS | STATE_UPDATES | DELTA_UPDATES | KV_READS)[
+            model
+        ],
     )
     on_chip = SingleDeviceSharding(v5e)
     args = [
@@ -211,7 +245,7 @@ STARCODERBASE_1B = dict(
 ROWS, POSITIONS = 64, 2048
 
 
-def _compile_group(device, program: str, widths):
+def _compile_group(device, program: str, widths, **kw):
     """``_compile_step`` at ``starcoderbase-1b``'s widths and envelope with
     ``widths`` KV heads, or, for the name of one of the benchmark's
     configurations (``falcon-h1-34b-1chip``: GQA with 4 KV heads beside a
@@ -224,7 +258,7 @@ def _compile_group(device, program: str, widths):
             ),
             n_kv_heads=widths,
         )
-        return _compile_step(device, program, cfg, POSITIONS)
+        return _compile_step(device, program, cfg, POSITIONS, **kw)
     path = os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         "benchmark", "configs", widths + ".json",
@@ -232,7 +266,9 @@ def _compile_group(device, program: str, widths):
     with open(path) as f:
         hf = json.load(f)
     cfg = config_from_hf(types.SimpleNamespace(**hf), dtype=hf["dtype"])
-    return _compile_step(device, program, cfg, hf["serve"]["max_seq_len"])
+    return _compile_step(
+        device, program, cfg, hf["serve"]["max_seq_len"], **kw
+    )
 
 
 def _compile_step(device, program: str, cfg, POSITIONS: int, ROWS: int = ROWS,
@@ -348,6 +384,57 @@ def test_step_program_carries_the_pool_in_place(v5e, program, widths):
         assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 10
 
 
+@pytest.mark.parametrize(
+    "program,widths,chunk",
+    [
+        (program, widths, chunk)
+        for widths, chunk in (
+            (1, 4), ("falcon-h1-34b-1chip", 4), ("olmo-hybrid-7b-1chip", 4),
+            ("qwen3-next-80b-a3b-1chip", 8),
+        )
+        for program in ("decode", "ragged")
+    ],
+)
+def test_step_program_reads_the_pool_where_it_lies(
+    v5e, monkeypatch, program, widths, chunk,
+):
+    """With ``kv.kernel`` (as on a TPU: ``ops/pallas_kv.py`` compiled) the
+    decode group and the mixed group of the four cells that hold keys and
+    values take both pools AS STORED: beside the kernel's custom call and
+    the write there is no ``copy``, ``transpose``, ``bitcast-convert``,
+    ``dynamic-slice`` or ``gather`` of a pool's size, no slice of a layer,
+    no row's gathered ring ``[rows, slots, heads, head size]``, and the pool
+    keeps ONE device layout (one KV head: the slots second-minor, dense
+    tiles, read through a free reshape; two, four: ``T(2,128)`` /
+    ``T(4,128)``, a slot's heads packed in a sublane pair; thirty-two:
+    ``T(8,128)``)."""
+    import importlib
+
+    # the program asks jax.default_backend(), which is the CPU here
+    monkeypatch.setattr(
+        importlib.import_module("llmss_tpu.ops.attention"),
+        "pallas_interpret", lambda: False,
+    )
+    compiled, pool = _compile_group(v5e, program, widths, chunk=chunk)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "kv_paged_attention" in text
+    moved = (
+        r"(?:\w+_)?(?:copy|transpose|dynamic[-_]slice|gather|"
+        r"bitcast[-_]convert)"
+    )
+    assert _pool_sized_copies(text, pool, moved) == []
+    assert _layer_sized_slices(text, pool) == []
+    slots = pool[1] // ROWS * BS
+    for t in {slots, min(slots, 512)}:  # the ring, the decode read bucket
+        ring = ",".join(map(str, (ROWS, t) + pool[3:]))
+        assert f"[{ring}]" not in text, ring
+    dims = ",".join(map(str, pool))
+    layouts = set(re.findall(rf"bf16\[{dims}\]\{{([^}}]*T[^}}]*)\}}", text))
+    if pool[3] > 1:
+        tile = min(pool[3], 8)
+        assert layouts == {f"4,3,2,1,0:T({tile},128)(2,1)"}
+
+
 @pytest.mark.parametrize("program", ["decode", "ragged"])
 def test_two_kinds_of_layer_carry_every_pool_in_place(v5e, program):
     """``olmo-hybrid-7b-1chip`` at the published widths, 64 rows x 1,024
@@ -384,8 +471,9 @@ def test_a_share_of_the_experts_under_two_kinds_of_layer_fits(v5e, monkeypatch):
     four), the block pool of the 2 attention layers keeps its 2 KV heads
     unpadded (``T(2,128)``: 1.34 GB), the state pool of 32 VALUE heads and
     the window pool go through as they came, and the arguments are what the
-    configuration's ``memory`` says: 9.50 GB, with under 1.2 GB of
-    temporaries (the gathered rings of the XLA read are most of them)."""
+    configuration's ``memory`` says: 9.50 GB, with under 0.2 GB of
+    temporaries (the gathered rings of the XLA read, most of 1.1 GB until
+    the block pool was read where it lies, are gone)."""
     import importlib
 
     # the program asks jax.default_backend(), which is the CPU here
@@ -401,11 +489,12 @@ def test_a_share_of_the_experts_under_two_kinds_of_layer_fits(v5e, monkeypatch):
                   (8, 128, 2048, 512)):
         assert _pool_sized_copies(text, shape, moved) == [], shape
     assert _layer_sized_slices(text, pool) == []
-    # three a layer of the period's four, and the three linear layers' state
-    assert text.count("tpu_custom_call") == 12 + 3
+    # three a layer of the period's four, the three linear layers' state,
+    # and the attention layer's read of the block pool (ops/pallas_kv.py)
+    assert text.count("tpu_custom_call") == 12 + 3 + 1
     ma = compiled.memory_analysis()
     assert ma.argument_size_in_bytes == pytest.approx(9.50e9, rel=0.01)
-    assert ma.temp_size_in_bytes < 1.2e9
+    assert ma.temp_size_in_bytes < 0.2e9
 
 
 @pytest.mark.parametrize("program", ["decode", "ragged"])
@@ -547,7 +636,8 @@ def test_state_pool_is_updated_where_it_lies(v5e, monkeypatch, program):
         and not re.search(r" (?:parameter|get-tuple-element|bitcast)\(", line)
     ]
     assert len(made) == 1 and made[0].startswith("%ssm_pool_update"), made
-    assert text.count("tpu_custom_call") == 1
+    # the state's update, and the read of the block pool (ops/pallas_kv.py)
+    assert text.count("tpu_custom_call") == 1 + 1
     assert _pool_sized_copies(text, (5, 64, 32, 128, 256)) == []
     # the slice's temporary is gone: the mixed group holds 0.11 GB
     if program == "ragged":
@@ -558,9 +648,10 @@ def test_state_pool_is_updated_where_it_lies(v5e, monkeypatch, program):
 @pytest.mark.parametrize(
     "config,pool,calls",
     [
-        ("olmo-hybrid-7b-1chip", (9, 64, 30, 96, 192), 3),
-        # beside the grouped matmul's three calls a layer of the period's four
-        ("qwen3-next-80b-a3b-1chip", (6, 64, 32, 128, 128), 3 + 12),
+        # beside the attention layer's read of the block pool
+        ("olmo-hybrid-7b-1chip", (9, 64, 30, 96, 192), 3 + 1),
+        # and the grouped matmul's three calls a layer of the period's four
+        ("qwen3-next-80b-a3b-1chip", (6, 64, 32, 128, 128), 3 + 1 + 12),
     ],
     ids=["olmo-hybrid", "qwen3-next"],
 )
