@@ -2231,29 +2231,29 @@ def attn_read(cfg: DecoderConfig, cache: PagedKVCache, mesh, chunk: int) -> str:
     ``LLMSS_ATTN_IMPL=pallas`` opts a pool of keys and values into where
     ``kv.kernel`` does not apply: a ``tp`` mesh),
     or, for a model that selects what attention reads (``cfg.indexer``),
-    ``dsa.tokens`` (a decode step: the indexer's keys gathered, keys and
-    values of the kept tokens read by token) and ``dsa.mask`` (a mixed step:
-    every row's first query as a decode step reads it, the rows that feed a
-    prompt through the mask form: their three views gathered, a selection a
-    query position as a mask); ops/sparse_attention.py. A decode step whose read bucket is at most
-    ``topk`` slots drops nothing and reads as ``gather`` does.
+    ``dsa.kernel`` (both pools read in place under the selection as bits,
+    live rows only, ops/pallas_dsa.py) and its XLA forms ``dsa.tokens`` (a
+    decode step: the kept tokens read by token) and ``dsa.mask`` (a mixed
+    step: every row's first query by token, the feeding rows through the
+    mask form over gathered views); ops/sparse_attention.py. A decode step
+    whose read bucket is at most ``topk`` slots reads as ``gather`` does.
 
-    ``mla.kernel`` and ``kv.kernel`` are chosen by ``dispatch_attention``'s
-    own rule: one device, the pool in the compute dtype (an int8 pool keeps
-    the gather), shapes inside the kernel's ``supports`` and compiled on a
-    TPU, or forced (interpreted: the CPU tests); never under ``force ==
-    "xla"``."""
+    ``mla.kernel``, ``kv.kernel`` and ``dsa.kernel`` are chosen by
+    ``dispatch_attention``'s own rule: one device, the pool in the compute
+    dtype (an int8 pool keeps the XLA read), shapes inside the kernel's
+    ``supports`` and compiled on a TPU, or forced (interpreted: the CPU
+    tests); never under ``force == "xla"``."""
     import importlib
 
     from llmss_tpu.ops import pallas_kv, pallas_mla
 
     attention_mod = importlib.import_module("llmss_tpu.ops.attention")
     force = attention_mod.IMPL_OVERRIDE
+    one_device = mesh is None or mesh.size == 1
     if cfg.indexer is not None:
-        return "dsa.tokens" if chunk == 1 else "dsa.mask"
+        return _selected_read(cfg, cache, one_device, chunk, attention_mod)
     if force == "xla":
         return "gather"
-    one_device = mesh is None or mesh.size == 1
     if cfg.mla is None:
         Hq, Hkv = _kernel_heads(cfg, cache)
         ok = (
@@ -2289,13 +2289,13 @@ def attn_read(cfg: DecoderConfig, cache: PagedKVCache, mesh, chunk: int) -> str:
 def feed_rows(cfg: DecoderConfig, cache: PagedKVCache, chunk: int,
               t_bucket: int | None = None) -> int | None:
     """How many rows may feed a prompt through ONE mixed step of ``chunk``
-    tokens a row; None: as many as there are. A model that selects what
-    attention reads (``cfg.indexer``) works a feeding row's whole chunk
-    through the mask form, ``[heads, chunk, context]`` float32 scores a row,
-    and a step works one turn of such rows (``ops/sparse_attention.py:
-    chunk_rows``), so that its cost is the same whichever rows feed; the
-    scheduler admits no more prompts at once, the others wait their turn in
-    the queue."""
+    tokens a row; None: as many as there are. For a model that selects what
+    attention reads (``cfg.indexer``): what one turn of the mask form holds
+    of ``[heads, chunk, context]`` float32 scores (``ops/sparse_attention.py:
+    chunk_rows``). Under ``dsa.kernel`` no score leaves VMEM and the cap no
+    longer rests on them; the NUMBER stays (the selection's ``[rows, chunk,
+    context]`` passes still scale with it, and it sets the traffic a step
+    carries: changing it is the scheduler's token cap, ROADMAP R9 (c))."""
     if cfg.indexer is None:
         return None
     from llmss_tpu.ops import sparse_attention as dsa
@@ -2539,16 +2539,16 @@ def _forward_selected(
     One discipline for all three, the latent family's: the layer scan closes
     over the stale pools and READS them, every layer hands back its fresh
     keys, values and indexer keys, and one ``paged_write_stacked`` a pool
-    after the scan writes them. The decode step scores the row's view of the
-    indexer pool and reads the kept tokens' keys and values by token
-    (``sparse_decode_attention``; a read bucket of at most ``topk`` slots
-    drops nothing and is ``paged_decode_attention`` as every other family
-    runs it). The mixed step and the prefill are ONE form, the prefill a
-    chunk as long as its bucket that starts at the row's first position: the
-    three gathered views with a selection a query position as a mask
-    (``sparse_chunk_attention``); a mixed step of more rows than one turn of
-    that form holds works only the rows that feed a prompt so (``feed_rows``)
-    and every row's first query as a decode step works it.
+    after the scan writes them. The selection is made ONE way whatever reads
+    (``decode_selection`` for a row's one query, ``chunk_selection`` a query
+    position for the rows that feed: at most ``feed_rows`` of a mixed step's,
+    every row of the prefill). Under ``attn_read``'s ``dsa.kernel`` the
+    decode and mixed steps hand it as bits to one kernel call a layer
+    (``_make_selected_read``); the XLA forms read the kept tokens by token
+    (``sparse_decode_attention``) or mask gathered views
+    (``sparse_chunk_attention``: also the prefill, a chunk as long as its
+    bucket). A decode read bucket of at most ``topk`` slots drops nothing
+    and is ``paged_decode_attention`` as every other family runs it.
 
     ``aux["dsa_counts"]`` int32 [4], over the step's live rows and all
     layers: cached and fresh positions the indexer scored for a row's LAST
@@ -2584,6 +2584,14 @@ def _forward_selected(
         lens, kv_pos_src, slots[:, 0], cache.max_len
     )
 
+    # A mixed step of more rows than ``feed_rows`` gives (the scheduler
+    # admits no more prompts at once) selects a query position only for the
+    # rows that feed; every row's first query is selected as a decode step's.
+    F = feed_rows(cfg, cache, S, t_bucket) if q_lens is not None else None
+    feeding = None if F is None else jnp.nonzero(
+        lens > 1, size=F, fill_value=B
+    )[0]
+    a_step = S == 1 or q_lens is not None  # not the prefill
     if S == 1 and q_lens is None and Tv <= topk:
         penalty = decode_mask_penalty(positions, kv_pos_src, slots, None)
 
@@ -2594,6 +2602,11 @@ def _forward_selected(
                 tables, slots, scale=cfg.attn_scale, penalty=penalty,
                 n_blocks=nb, layer=layer,
             )
+    elif a_step and attn_read(cfg, cache, mesh, S) == "dsa.kernel":
+        attn = _make_selected_read(
+            cfg, cache, positions, slots, lens, kv_pos_src, cache_vis, nb,
+            feeding,
+        )
     elif S == 1 and q_lens is None:
         def attn(q, k_new, v_new, k_c, v_c, *, layer, index):
             del k_c, v_c  # reads the stacked pools directly
@@ -2606,46 +2619,33 @@ def _forward_selected(
                 )
     else:
         q_pos0 = positions[:, 0]
-        # A mixed step of more rows than one turn of the mask form holds:
-        # the rows that feed a prompt (at most ``F``: the scheduler admits
-        # no more at once, ``feed_rows``) are worked through all of their
-        # chunk by the mask form, every row's first query as a decode step
-        # works it. The prefill works every row through all of its chunk,
-        # turn after turn.
-        F = feed_rows(cfg, cache, S, t_bucket) if q_lens is not None else None
-        feeding = None if F is None else jnp.nonzero(
-            lens > 1, size=F, fill_value=B
-        )[0]
 
         def attn(q, k_new, v_new, k_c, v_c, *, layer, index):
             del k_c, v_c  # reads the stacked pools directly
             qi, wi, ki = index
 
-            def mask_form(rows, cols):
-                """Rows ``rows`` (None: all) through their first ``cols``
-                queries and fresh tokens. The rows' views are gathered from
-                the pools by THEIR tables: a gather of rows out of all rows'
-                views made layout assignment carry the pools slot-minor and
-                copy them whole around every step (compiled for a described
-                v5e, PR 46)."""
+            def mask_form(rows):
+                """Rows ``rows`` (None: all) through their chunk. The rows'
+                views are gathered from the pools by THEIR tables: a gather
+                of rows out of all rows' views made layout assignment carry
+                the pools slot-minor and copy them whole around every step
+                (compiled for a described v5e, PR 46)."""
                 r = (lambda a: a) if rows is None else (lambda a: a[rows])
-                c = lambda a: r(a)[:, :cols]
                 views = (
                     gather_block_view(pool, r(tables), nb, layer)
                     for pool in (cache.k, cache.v, cache.idx)
                 )
                 return dsa.sparse_chunk_attention(
-                    c(q), *views, c(k_new), c(v_new), c(ki), c(qi), c(wi),
-                    r(q_pos0), jnp.minimum(r(lens), cols), r(kv_pos_src),
-                    r(cache_vis), topk=topk, scale=cfg.attn_scale,
+                    r(q), *views, r(k_new), r(v_new), r(ki), r(qi), r(wi),
+                    r(q_pos0), r(lens), r(kv_pos_src), r(cache_vis),
+                    topk=topk, scale=cfg.attn_scale,
                 )
 
-            if feeding is None:
+            if feeding is None:  # the prefill: turn after turn
                 with jax.named_scope("dsa.chunk"):
-                    return mask_form(None, S)
-            # every row's first query as a decode step reads it, by token
-            # (a decoding row has no other; a dense view of all rows' keys
-            # and values was 31 of a step's 85 ms: my chip run, PR 46) ...
+                    return mask_form(None)
+            # every row's first query by token (a decoding row has no other;
+            # a dense view of all rows was 31 of a step's 85 ms: PR 46) ...
             with jax.named_scope("dsa.decode"):
                 first = dsa.sparse_decode_attention(
                     q[:, :1], cache.k, cache.v, cache.idx, k_new[:, :1],
@@ -2655,7 +2655,7 @@ def _forward_selected(
                 )
             # ... and the rows that feed through all of their chunk
             with jax.named_scope("dsa.chunk"):
-                whole = mask_form(jnp.minimum(feeding, B - 1), S)
+                whole = mask_form(jnp.minimum(feeding, B - 1))
             out = jnp.zeros_like(q).at[:, :1].set(first)
             return out.at[feeding].set(whole, mode="drop")
 
@@ -2931,3 +2931,97 @@ def forward_ragged(
         positions=new_kv_positions, k_scale=ks_new, v_scale=vs_new,
         ssm=ssm_new, conv=conv_new,
     )
+
+
+def _selected_read(cfg, cache, one_device, chunk, attention_mod) -> str:
+    """``attn_read`` for a model that selects what attention reads:
+    ``dsa.kernel`` by the rule of the other two kernels, else the XLA forms
+    (a ``tp`` mesh, an int8 pool, the CPU, ``force == "xla"``)."""
+    from llmss_tpu.ops import pallas_dsa
+
+    force = attention_mod.IMPL_OVERRIDE
+    ok = (
+        force != "xla"
+        and one_device
+        and not cache.quantized
+        and cache.k.dtype == cfg.compute_dtype
+        and pallas_dsa.supports(
+            cache.block_size, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim,
+            chunk, cache.k.dtype,
+        )
+    )
+    if force == "pallas" and not ok:
+        attention_mod.forced_pallas_miss(
+            "shapes out of the selected read kernel's envelope "
+            f"(bs={cache.block_size}, Hq={cfg.n_heads}, "
+            f"Hkv={cfg.n_kv_heads}, D={cfg.head_dim}, chunk={chunk}, "
+            f"{cache.k.dtype})"
+        )
+    if ok and (force == "pallas" or not attention_mod.pallas_interpret()):
+        return "dsa.kernel"
+    return "dsa.tokens" if chunk == 1 else "dsa.mask"
+
+
+def _make_selected_read(
+    cfg, cache, positions, slots, lens, kv_pos_src, cache_vis, nb, feeding,
+):
+    """``attn_read``'s ``dsa.kernel`` as a ``(q, k_new, v_new, k_cache,
+    v_cache, *, layer, index) -> attn`` callable for ``_block``: the decode
+    step's and the mixed step's read of the stacked pools where they lie
+    under the layer's selection (ops/pallas_dsa.py). The selection is the XLA
+    forms': ``decode_selection`` for every row's first query (a decoding row
+    has no other), ``chunk_selection`` a query position for the rows
+    ``feeding`` (None: every row), packed a bit a query; the kernel walks the
+    live rows' blocks (``lens`` > 0) and reads nothing else of the pools."""
+    import importlib
+
+    from llmss_tpu.ops import pallas_dsa
+    from llmss_tpu.ops import sparse_attention as dsa
+
+    interp = importlib.import_module(
+        "llmss_tpu.ops.attention"
+    ).pallas_interpret()
+    topk, tables = cfg.indexer.topk, cache.block_tables
+    S, Tv = positions.shape[1], kv_pos_src.shape[1]
+    n_blocks = _blocks_held(cache)
+    some = feeding is not None
+    r = (lambda a: a[jnp.minimum(feeding, a.shape[0] - 1)]) if some else (
+        lambda a: a
+    )
+
+    def attn(q, k_new, v_new, k_c, v_c, *, layer, index):
+        del k_c, v_c  # reads the stacked pools directly
+        qi, wi, ki = index
+
+        def read(keep_c, keep_w):
+            return pallas_dsa.dsa_paged_attention(
+                q, cache.k, cache.v, k_new, v_new, keep_c, keep_w, lens,
+                tables, n_blocks, layer, scale=cfg.attn_scale,
+                interpret=interp,
+            )
+
+        if S == 1 or some:
+            with jax.named_scope("dsa.decode"):
+                first = dsa.decode_selection(
+                    cache.idx, ki[:, :1], qi[:, :1], wi[:, :1],
+                    positions[:, :1], kv_pos_src, tables, slots[:, :1],
+                    layer, topk=topk, n_blocks=nb,
+                ).astype(jnp.int32)  # [B, Tv + 1]: bit 0 of a word
+                keep_c = first[:, :Tv]
+                keep_w = jnp.pad(first[:, Tv:], ((0, 0), (0, S - 1)))
+                if S == 1:
+                    return read(keep_c, keep_w)
+        with jax.named_scope("dsa.chunk"):
+            words = pallas_dsa.pack_queries(dsa.chunk_selection(
+                gather_block_view(cache.idx, r(tables), nb, layer), r(ki),
+                r(qi), r(wi), r(positions[:, 0]), r(lens), r(kv_pos_src),
+                r(cache_vis), topk=topk,
+            ))  # [rows, Tv + S]
+            if not some:
+                return read(words[:, :Tv], words[:, Tv:])
+            return read(
+                keep_c.at[feeding].set(words[:, :Tv], mode="drop"),
+                keep_w.at[feeding].set(words[:, Tv:], mode="drop"),
+            )
+
+    return attn

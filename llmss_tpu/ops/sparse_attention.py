@@ -23,7 +23,14 @@ compare-and-count passes, no sort) and cuts the tie group at it by a running
 count, so the rule does not rest on how a sort or a ``top_k`` primitive
 orders equal values.
 
-Two forms of the read:
+The SELECTION is made here whatever reads the keys and values:
+``decode_selection`` (a row's one query over its view of the indexer pool)
+and ``chunk_selection`` (a query position of a chunk, the step's own fresh
+tokens competing), both ending in ``keep_topk``. On a TPU the decode and
+mixed steps hand it as bits to ``ops/pallas_dsa.py``, which walks both pools
+where they lie (``models/decoder.py: attn_read``'s ``dsa.kernel``); the two
+XLA forms of the read below are its oracles, the CPU's path, and the
+dedicated prefill's:
 
 * ``sparse_chunk_attention`` - the MASK form, for the mixed step, the
   prefill and the decode step alike: the rows' gathered logical views (keys,
@@ -117,6 +124,38 @@ def chunk_rows(B: int, Hq: int, S: int, N: int) -> int:
     return max(1, min(B, MAP_BYTES // (4 * Hq * _query_block(S) * N)))
 
 
+def chunk_selection(
+    ki_view,  # [B, T, W] float32: the rows' view of the indexer pool
+    ki_new,  # [B, S, Di or W] float32: the step's own fresh keys
+    qi, wi,  # [B, QB, Hi, Di or W], [B, QB, Hi] float32: queries [i0, i0 + QB)
+    q_pos0, q_len, kv_pos_old, cache_vis,  # [B], [B], [B, T], [B, T]
+    *, topk: int, i0=0,
+):
+    """bool ``[B, QB, T + S]``: what each of a row's queries ``[i0, i0 +
+    QB)`` keeps of the cached slots and of the step's ``S`` fresh tokens (the
+    mask form's selection). Query ``i`` sees the cached slots ``cache_vis &
+    kv_pos <= q_pos0 + i`` and the fresh tokens ``j <= i, j < q_len``, and
+    keeps its ``topk`` best of those by the indexer; a padding query (``i >=
+    q_len``) keeps all it sees."""
+    qi, ki_new = _to_pool_width(ki_view.shape[-1], qi, ki_new)
+    QB, S = qi.shape[1], ki_new.shape[1]
+    rel_k = jnp.arange(S, dtype=jnp.int32)
+    rel_q = i0 + jnp.arange(QB, dtype=jnp.int32)
+    qpos = q_pos0[:, None] + rel_q[None, :]  # [B, QB]
+    see_c = cache_vis[:, None, :] & (kv_pos_old[:, None, :] <= qpos[:, :, None])
+    see_w = (rel_k[None, None, :] <= rel_q[None, :, None]) & (
+        rel_k[None, None, :] < q_len[:, None, None]
+    )
+    score = jnp.concatenate([
+        jnp.where(see_c, index_scores(qi, wi, ki_view), -jnp.inf),
+        jnp.where(see_w, index_scores(qi, wi, ki_new), -jnp.inf),
+    ], axis=-1)  # [B, QB, T + S]
+    return keep_topk(score, topk) | (
+        (rel_q[None, :] >= q_len[:, None])[:, :, None]
+        & jnp.concatenate([see_c, see_w], -1)
+    )
+
+
 def sparse_chunk_attention(
     q,  # [B, S, Hq, D] compute dtype
     k_view, v_view,  # [B, T, Hkv, D] the rows' stale logical views
@@ -140,29 +179,16 @@ def sparse_chunk_attention(
     G = Hq // Hkv
     if scale is None:
         scale = 1.0 / (D ** 0.5)
-    qi, ki_new = _to_pool_width(ki_view.shape[-1], qi, ki_new)
     QB = _query_block(S)
     nq = S // QB
-    rel_k = jnp.arange(S, dtype=jnp.int32)
 
     def block(rows, blk):
         """Some rows' queries ``[i0, i0 + QB)`` against all of their keys."""
         k_c, v_c, ki_c, k_w, v_w, ki_w, pos0, n, kv_pos, vis = rows
         q_b, qi_b, wi_b, i0 = blk
         R = q_b.shape[0]
-        rel_q = i0 + jnp.arange(QB, dtype=jnp.int32)
-        qpos = pos0[:, None] + rel_q[None, :]  # [R, QB]
-        see_c = vis[:, None, :] & (kv_pos[:, None, :] <= qpos[:, :, None])
-        see_w = (rel_k[None, None, :] <= rel_q[None, :, None]) & (
-            rel_k[None, None, :] < n[:, None, None]
-        )
-        score = jnp.concatenate([
-            jnp.where(see_c, index_scores(qi_b, wi_b, ki_c), -jnp.inf),
-            jnp.where(see_w, index_scores(qi_b, wi_b, ki_w), -jnp.inf),
-        ], axis=-1)  # [R, QB, T + S]
-        keep = keep_topk(score, topk) | (
-            (rel_q[None, :] >= n[:, None])[:, :, None]
-            & jnp.concatenate([see_c, see_w], -1)
+        keep = chunk_selection(
+            ki_c, ki_w, qi_b, wi_b, pos0, n, kv_pos, vis, topk=topk, i0=i0
         )
         keep_c, keep_w = keep[..., :T], keep[..., T:]
 
@@ -219,6 +245,38 @@ def sparse_chunk_attention(
     return out.reshape((n_turns * per, S, Hq, D))[:B]
 
 
+def decode_selection(
+    idx_pool,  # [L, N, bs, W] float32, W >= Di
+    ki_new,  # [B, 1, Di] float32
+    qi, wi,  # [B, 1, Hi, Di], [B, 1, Hi] float32
+    q_pos,  # [B, 1]
+    kv_pos_old,  # [B, nb * bs] pre-write positions of the slots read
+    block_tables,  # [B, MB]
+    slots,  # [B, 1] the slot the token will occupy
+    layer,
+    *, topk: int, n_blocks: int | None = None,
+):
+    """bool ``[B, Tv + 1]``: what a row's ONE query keeps of the ``Tv``
+    cached slots read and of its own new token (the last column): scores
+    over the row's view of the indexer pool alone. The new token competes
+    with the cached ones for its place among the ``topk`` (it is the latest
+    position: a tie goes against it)."""
+    from llmss_tpu.engine.cache import gather_block_view
+
+    Tv = kv_pos_old.shape[1]
+    ki_view = gather_block_view(idx_pool, block_tables, n_blocks, layer)
+    qi, ki_new = _to_pool_width(ki_view.shape[-1], qi, ki_new)
+    see = (
+        (kv_pos_old >= 0) & (kv_pos_old <= q_pos)
+        & (jnp.arange(Tv, dtype=jnp.int32)[None, :] != slots)
+    )
+    score = jnp.concatenate([
+        jnp.where(see, index_scores(qi, wi, ki_view)[:, 0], -jnp.inf),
+        index_scores(qi, wi, ki_new)[:, 0],
+    ], axis=-1)  # [B, Tv + 1]
+    return keep_topk(score, min(topk, Tv))
+
+
 def sparse_decode_attention(
     q,  # [B, 1, Hq, D]
     k_pool, v_pool,  # [L, N, bs, Hkv, D] the stacked pools
@@ -237,23 +295,14 @@ def sparse_decode_attention(
     competes with the cached ones for its place among the ``topk`` (it is
     the latest position: a tie goes against it) and is merged into the one
     softmax after the kept cached tokens."""
-    from llmss_tpu.engine.cache import gather_block_view
-
     B = q.shape[0]
     bs = k_pool.shape[2]
     Tv = kv_pos_old.shape[1]
     topk = min(topk, Tv)
-    ki_view = gather_block_view(idx_pool, block_tables, n_blocks, layer)
-    qi, ki_new = _to_pool_width(ki_view.shape[-1], qi, ki_new)
-    see = (
-        (kv_pos_old >= 0) & (kv_pos_old <= q_pos)
-        & (jnp.arange(Tv, dtype=jnp.int32)[None, :] != slots)
+    keep = decode_selection(
+        idx_pool, ki_new, qi, wi, q_pos, kv_pos_old, block_tables, slots,
+        layer, topk=topk, n_blocks=n_blocks,
     )
-    score = jnp.concatenate([
-        jnp.where(see, index_scores(qi, wi, ki_view)[:, 0], -jnp.inf),
-        index_scores(qi, wi, ki_new)[:, 0],
-    ], axis=-1)  # [B, Tv + 1]
-    keep = keep_topk(score, topk)
     # the kept cached slots, ascending: the topk largest of a key that is
     # Tv - slot where the slot is kept and 0 where it is not. The keys are
     # distinct, so this top_k has no tie to break. (A binary search a row for

@@ -40,8 +40,8 @@ from llmss_tpu.engine.cache import PagedKVCache, ssm_state_shapes
 from llmss_tpu.models.decoder import param_shapes, param_specs
 from llmss_tpu.models.registry import config_from_hf
 from llmss_tpu.ops import (
-    pallas_attention, pallas_decode, pallas_gdn, pallas_kv, pallas_mla,
-    pallas_paged_decode, pallas_ragged, pallas_ssm,
+    pallas_attention, pallas_decode, pallas_dsa, pallas_gdn, pallas_kv,
+    pallas_mla, pallas_paged_decode, pallas_ragged, pallas_ssm,
 )
 from llmss_tpu.parallel import mesh as mesh_mod
 
@@ -135,6 +135,23 @@ def _kernel_call(kernel: str, Hq: int, Hkv: int, D: int):
             ((rows, chunk, Hq, D), DT), pool, pool, fresh, fresh, row, row,
             ((rows, mb * BS), i32), ((rows, mb), i32), row, row, ((), i32),
         ]
+    if kernel == "selected_read":
+        # the read of both pools under a selection at the shapes of the
+        # benchmark's sixth cell: 32 rows of 1,056 blocks over [6, 33792, 16,
+        # 4, 128], a mixed step's 32 tokens a row or a decode step's 1 (``D``
+        # carries them beside the head size), a keep word a slot
+        rows, (D, layers, mb, chunk) = 32, D
+        assert pallas_dsa.supports(BS, Hq, Hkv, D, chunk, DT)
+        row = ((rows,), i32)
+        pool = ((layers, rows * mb, BS, Hkv, D), DT)
+        fresh = ((rows, chunk, Hkv, D), DT)
+        return functools.partial(
+            pallas_dsa.dsa_paged_attention, scale=D ** -0.5,
+        ), [
+            ((rows, chunk, Hq, D), DT), pool, pool, fresh, fresh,
+            ((rows, mb * BS), i32), ((rows, chunk), i32), row,
+            ((rows, mb), i32), row, ((), i32),
+        ]
     if kernel == "state_update":
         # the Mamba-2 state pool's update at the shapes of the benchmark's
         # second cell: 64 rows of 32 heads x [128, 256] float32 over 5
@@ -206,6 +223,13 @@ KV_READS = {
     "kv-qwen3-next-step-of-8": (16, 2, (256, 2, 320, 8)),
 }
 
+# (query heads, KV heads, (head size, layers, blocks a row, tokens a row a
+# step)) of the read of both pools under a selection
+SELECTED_READS = {
+    "dsa-keye-vl2-decode": (32, 4, (128, 6, 1056, 1)),
+    "dsa-keye-vl2-step-of-32": (32, 4, (128, 6, 1056, 32)),
+}
+
 
 @pytest.mark.parametrize(
     "kernel,model",
@@ -215,14 +239,14 @@ KV_READS = {
     ] + [("latent_read", step) for step in LATENT_READS]
     + [("state_update", step) for step in STATE_UPDATES]
     + [("delta_update", step) for step in DELTA_UPDATES]
-    + [("kv_read", step) for step in KV_READS],
+    + [("kv_read", step) for step in KV_READS]
+    + [("selected_read", step) for step in SELECTED_READS],
 )
 def test_kernel_compiles_for_v5e(v5e, kernel, model):
     fn, shapes = _kernel_call(
         kernel,
-        *(WIDTHS | LATENT_READS | STATE_UPDATES | DELTA_UPDATES | KV_READS)[
-            model
-        ],
+        *(WIDTHS | LATENT_READS | STATE_UPDATES | DELTA_UPDATES | KV_READS
+          | SELECTED_READS)[model],
     )
     on_chip = SingleDeviceSharding(v5e)
     args = [
@@ -511,10 +535,15 @@ def test_a_selection_inside_paged_attention_fits_beside_three_pools(
     whole around every program), go through as they came, in ONE layout
     each, with no copy or transpose of a pool and no slice of a layer; the
     32 held experts of all 6 layers are one stack read in place by the
-    grouped matmul's kernel; arguments 10.69 GB, and the temporaries (the
-    mixed step's one turn of feeding rows through the mask form, under
-    ``ops/sparse_attention.py: MAP_BYTES`` of float32 scores a layer) well
-    under what is left of the chip's 15.75 GB."""
+    grouped matmul's kernel; arguments 10.69 GB. Since PR 48 the keys and
+    values are read where they lie by ``dsa.kernel`` (ops/pallas_dsa.py: one
+    more custom call in the scan's one body), so beside it stands no gather
+    of the kept tokens (``[rows * topk, 4, 128]``), no row's gathered ring,
+    no float32 attention score over the ring (feeding rows x query heads x
+    chunk of them) and no ``sort`` of a ring; the temporaries are what the selection
+    still holds: the indexer's view of all rows (277 MB) and the feeding
+    rows' indexer scores (242 MB), 0.32 / 0.35 GB where the mask form's
+    scores made the mixed group's 0.80."""
     import importlib
     import re
 
@@ -544,15 +573,30 @@ def test_a_selection_inside_paged_attention_fits_beside_three_pools(
     assert _layer_sized_slices(text, pool) == []
     assert _layer_sized_slices(text, index_pool) == []
     dims = ",".join(map(str, pool))
-    assert set(re.findall(rf"bf16\[{dims}\]\{{([^}}]*)\}}", text)) == {
+    # (the custom call's operand constraints name the pools with no tiling)
+    assert set(re.findall(rf"bf16\[{dims}\]\{{([^}}]*T[^}}]*)\}}", text)) == {
         "4,3,2,1,0:T(4,128)(2,1)"}
     dims = ",".join(map(str, index_pool))
     assert set(re.findall(rf"f32\[{dims}\]\{{([^}}]*)\}}", text)) == {
         "3,2,1,0:T(8,128)"}
-    assert text.count("tpu_custom_call") == 3  # a layer of the scan's one body
+    # a layer of the scan's one body: the experts' three and the read
+    assert text.count("tpu_custom_call") == 4 and "dsa_paged_attention" in text
+    rows, ring, topk = hf["serve"]["rows"], hf["serve"]["max_seq_len"], 2048
+    heads, fed = cfg.n_heads, 7  # ``feed_rows`` at this envelope
+    for gathered in ((rows * topk,), (rows, topk), (rows, ring), (fed, ring)):
+        dims = ",".join(map(str, gathered + pool[3:]))
+        assert f"[{dims}]" not in text, dims
+    scores = [
+        line for line, dims in _results(text, r"\S")
+        if "f32[" in line and dims[-1] in (ring, ring + chunk)
+        and math.prod(dims[:-1]) >= fed * heads * chunk
+    ]
+    assert scores == []
+    # the ``top_k`` that compacted a row's kept slots (the experts sort pairs)
+    assert [d for _, d in _results(text, "sort") if ring in d] == []
     ma = compiled.memory_analysis()
     assert ma.argument_size_in_bytes == pytest.approx(10.69e9, rel=0.01)
-    assert ma.temp_size_in_bytes < (1.3e9 if program == "ragged" else 0.6e9)
+    assert ma.temp_size_in_bytes < (0.45e9 if program == "ragged" else 0.4e9)
 
 
 # kakaocorp/kanana-2-30b-a3b-instruct-2601's widths (deepseek_v3: a latent

@@ -12,6 +12,7 @@ seeded draw (``init_params``), norm scales + 1 as the benchmark's server
 makes them."""
 
 import dataclasses
+import importlib
 import importlib.util
 import json
 import types
@@ -31,6 +32,8 @@ from llmss_tpu.models.registry import MODEL_REGISTRY, config_from_hf
 from llmss_tpu.ops import sparse_attention as dsa
 from llmss_tpu.ops.layers import NormParams
 from llmss_tpu.parallel import MeshPlan, make_mesh
+
+attention_mod = importlib.import_module("llmss_tpu.ops.attention")
 
 ROOT = Path(__file__).resolve().parent.parent
 
@@ -61,6 +64,14 @@ def share(chip, chips=4):
     return {**HF, "num_experts": HF["num_experts"] // chips,
             "expert_parallel": {"num_experts": HF["num_experts"],
                                 "chips": chips, "chip": chip}}
+
+
+def wide(hf=HF):
+    """``hf`` with heads of 128, which ``ops/pallas_dsa.py`` takes: the
+    engine that ``force_impl("pallas")`` serves through ``dsa.kernel``."""
+    return {**hf, "head_dim": 128,
+            "rope_scaling": {**hf["rope_scaling"],
+                             "mrope_section": [16, 24, 24]}}
 
 
 def dense(hf=HF):
@@ -110,13 +121,25 @@ def make_engine(mesh, dtype="float32", hf=HF, seed=3, params=None):
 _ENGINES = {}
 
 
-def engine_of(mesh, dtype="float32", held="all"):
-    """One engine a compute type and share for the whole module (its jits
-    compile once): all 8 experts held, or chip 1 of 4's experts 2-3."""
-    if (dtype, held) not in _ENGINES:
-        _ENGINES[dtype, held] = make_engine(
-            mesh, dtype, HF if held == "all" else share(1))
-    return _ENGINES[dtype, held]
+def hf_of(held="all", read="xla"):
+    hf = HF if held == "all" else share(1)
+    return wide(hf) if read == "kernel" else hf
+
+
+def engine_of(mesh, dtype="float32", held="all", read="xla"):
+    """One engine a compute type, share and read for the whole module (its
+    jits compile once): all 8 experts held, or chip 1 of 4's experts 2-3;
+    ``read`` "kernel": heads of 128, to be driven under ``reading(read)``."""
+    if (dtype, held, read) not in _ENGINES:
+        _ENGINES[dtype, held, read] = make_engine(
+            mesh, dtype, hf_of(held, read))
+    return _ENGINES[dtype, held, read]
+
+
+def reading(read):
+    """The context a test drives ``engine_of(.., read=read)`` in: the kernel
+    forced (interpreted on the CPU), or the XLA forms as the CPU chooses."""
+    return attention_mod.force_impl("pallas" if read == "kernel" else None)
 
 
 @pytest.fixture(scope="module")
@@ -207,19 +230,24 @@ def decode_run(eng, prompts, steps, at, t_bucket=None):
     return out
 
 
+@pytest.mark.parametrize("read", ["xla", "kernel"])
 @pytest.mark.parametrize("held", ["all", "a_share"])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-def test_prefill_then_cached_steps_match_reference(mesh, dtype, held):
+def test_prefill_then_cached_steps_match_reference(mesh, dtype, held, read):
     """Prompts of unequal length, all longer than ``topk`` but one, through
     one bucketed prefill (the selection as a mask within the prompt), then
     12 decode steps through the three pools (the selection's kept tokens
-    read by token): the logits of the prefill and of steps 1, 2 and 12
-    against the reference's full forward of prompt + tokens so far; with all
-    8 experts held, and as chip 1 of 4 (the reference given the same
-    share)."""
-    hf = HF if held == "all" else share(1)
-    eng = engine_of(mesh, dtype, held)
-    run = decode_run(eng, prompts_of([21, 60, 37, 9]), 12, (1, 2, 12))
+    read by token, or, ``read`` "kernel", both pools walked in place under
+    the selection as bits: ``dsa.kernel`` forced, interpreted): the logits
+    of the prefill and of steps 1, 2 and 12 against the reference's full
+    forward of prompt + tokens so far; with all 8 experts held, and as chip
+    1 of 4 (the reference given the same share)."""
+    hf = hf_of(held, read)
+    eng = engine_of(mesh, dtype, held, read)
+    with reading(read):
+        assert decoder.attn_read(eng.cfg, eng.new_paged_cache(1), mesh, 1) == (
+            "dsa.kernel" if read == "kernel" else "dsa.tokens")
+        run = decode_run(eng, prompts_of([21, 60, 37, 9]), 12, (1, 2, 12))
     errors = {step: err(logits, ref_logits(eng.params, seqs, hf))
               for step, (logits, seqs) in run.items()}
     assert max(errors.values()) < TOL[dtype], errors
@@ -301,20 +329,25 @@ def _ragged(eng, params, cache, ids, positions, slots, q_lens, kv_pos):
     return logits, cache, aux["moe_counts"], aux["dsa_counts"]
 
 
+@pytest.mark.parametrize("read", ["xla", "kernel"])
 @pytest.mark.parametrize("held", ["all", "a_share"])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-def test_the_mixed_step_matches_the_reference(mesh, dtype, held):
+def test_the_mixed_step_matches_the_reference(mesh, dtype, held, read):
     """Logits, not tokens, of the one step program a cell with
     ``chunked_prefill`` times: chunks of 8, so that the chunk ``[16, 24)``
     straddles ``topk`` (its first position keeps all 16 it sees but one, its
     last drops 8) and the chunk's own fresh tokens compete with the cached
     ones, beside a done row and a padding row. The counts: every live token
     adds ``top_k`` pairs a layer; a row's last live query scored its whole
-    context and kept at most ``topk`` of it."""
-    hf = HF if held == "all" else share(1)
-    eng = engine_of(mesh, dtype, held)
+    context and kept at most ``topk`` of it. ``read`` "kernel": the same
+    through ``dsa.kernel`` (forced, interpreted), every row's words made by
+    ``chunk_selection``."""
+    hf = hf_of(held, read)
+    eng = engine_of(mesh, dtype, held, read)
     prompts = prompts_of([45, 12, 61, 30], seed=4)
-    got, seqs, (moe, sel) = mixed_step_logits(eng, prompts, 8, extra_rows=2)
+    with reading(read):
+        got, seqs, (moe, sel) = mixed_step_logits(
+            eng, prompts, 8, extra_rows=2)
     assert err(got, ref_logits(eng.params, seqs, hf)) < TOL[dtype]
     tokens = sum(map(len, seqs))
     assert moe[0] + moe[2] == tokens * 2 * 3
@@ -590,17 +623,25 @@ def test_batcher_rows_match_isolated_and_count_the_selection(mesh, chunk):
     assert eng.metrics.to_dict()["cache"]["index_bytes_per_token"] == 3 * 128 * 4
 
 
-def test_a_mixed_step_works_one_turn_of_feeding_rows(mesh, monkeypatch):
+@pytest.mark.parametrize("read", ["xla", "kernel"])
+def test_a_mixed_step_works_one_turn_of_feeding_rows(mesh, monkeypatch, read):
     """Where one turn of the mask form holds fewer rows than the batch (the
     byte budget made small here: one row of chunks of 8 over 128 slots), a
     mixed step works every row's first query and ONE row through its whole
     chunk, the batcher admits one prompt at a time (the others wait in the
     queue, rows free or not), and every request's tokens are still its own
-    alone."""
+    alone. ``read`` "kernel": the same cap under ``dsa.kernel`` (forced,
+    interpreted: the one row's words among every row's first-query bits),
+    against the tokens the XLA forms give the same weights."""
     monkeypatch.setattr(dsa, "MAP_BYTES", 4 * 4 * 8 * (MAX_LEN + 8))
-    eng = make_engine(mesh, hf=share(1))
+    eng = make_engine(mesh, hf=hf_of("a_share", read))
     prompts = prompts_of([21, 40, 37, 9, 30], seed=2)
     expected = [eng.generate([p], g)[0] for p, g in zip(prompts, FIVE)]
+    if read == "kernel":
+        eng = make_engine(mesh, hf=hf_of("a_share", read), params=eng.params)
+        monkeypatch.setattr(attention_mod, "IMPL_OVERRIDE", "pallas")
+        cache = eng.new_paged_cache(4)
+        assert decoder.attn_read(eng.cfg, cache, mesh, 8) == "dsa.kernel"
     batcher = ContinuousBatcher(eng, rows=4, chunked_prefill=8)
     assert batcher._feed_rows == 1
     assert decoder.feed_rows(eng.cfg, batcher.cache, 8) == 1
@@ -751,6 +792,14 @@ def test_the_named_scopes_are_in_the_lowered_programs(engine):
     assert "moe.shared" not in text
     assert decoder.attn_read(engine.cfg, cache, engine.mesh, 1) == "dsa.tokens"
     assert decoder.attn_read(engine.cfg, cache, engine.mesh, 8) == "dsa.mask"
+    # under ``dsa.kernel`` the two scopes wrap the selection and ONE call
+    eng = engine_of(engine.mesh, read="kernel")
+    with reading("kernel"):
+        tok, _, cache, pos, sa = prefill(eng, prompts_of([9, 12]))
+        text = eng._decode.lower(
+            eng.params, eng.canon_vec(tok), eng.canon_cache(cache),
+            eng.canon_vec(pos), sa).as_text(debug_info=True)
+    assert "dsa.index" in text and "dsa.decode" in text
 
 
 def test_checkpoint_round_trip_under_the_published_names(mesh, tmp_path):

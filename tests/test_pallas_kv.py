@@ -4,6 +4,7 @@ mode on the CPU) against the XLA oracle
 benchmark's cells serve, and the rule that chooses it
 (``models.decoder.attn_read``)."""
 
+import dataclasses
 import importlib
 
 import jax
@@ -14,7 +15,7 @@ import pytest
 from llmss_tpu.engine import DecodeEngine, GenerationParams
 from llmss_tpu.engine.scheduler import ContinuousBatcher
 from llmss_tpu.models import decoder
-from llmss_tpu.models.common import DecoderConfig
+from llmss_tpu.models.common import DecoderConfig, IndexerConfig
 from llmss_tpu.models.decoder import init_params
 from llmss_tpu.ops import pallas_kv
 from llmss_tpu.parallel import MeshPlan, make_mesh
@@ -294,3 +295,27 @@ def test_attn_read_chooses_the_kernel_by_what_it_can_see(
     with attn.force_impl("pallas"):
         assert decoder.attn_read(GQA, cache, tp, 1) == "kernel"
         assert decoder.attn_read(GQA, cache, one_device, 1) == "kv.kernel"
+
+    # a model that selects what attention reads (``cfg.indexer``): the same
+    # rule names ``dsa.kernel`` (ops/pallas_dsa.py), up to 32 tokens a row a
+    # step; what it cannot take keeps the XLA forms by their names
+    picks = dataclasses.replace(
+        GQA, indexer=IndexerConfig(n_heads=4, head_dim=64, topk=16)
+    )
+    xla = {1: "dsa.tokens", 4: "dsa.mask", 32: "dsa.mask"}
+    for chunk, form in xla.items():
+        assert decoder.attn_read(picks, cache, one_device, chunk) == "dsa.kernel"
+        assert decoder.attn_read(picks, cache, None, chunk) == "dsa.kernel"
+        assert decoder.attn_read(picks, quantized, one_device, chunk) == form
+        assert decoder.attn_read(picks, cache, tp, chunk) == form
+        with attn.force_impl("xla"):
+            assert decoder.attn_read(picks, cache, one_device, chunk) == form
+    assert decoder.attn_read(picks, cache, one_device, 33) == "dsa.mask"
+    monkeypatch.undo()  # the CPU again: nothing is chosen unless forced
+    for chunk, form in xla.items():
+        assert decoder.attn_read(picks, cache, one_device, chunk) == form
+        with attn.force_impl("pallas"):
+            assert decoder.attn_read(
+                picks, cache, one_device, chunk) == "dsa.kernel"
+    with attn.force_impl("pallas"), pytest.warns(UserWarning, match="selected"):
+        assert decoder.attn_read(picks, cache, one_device, 33) == "dsa.mask"

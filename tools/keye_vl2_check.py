@@ -7,7 +7,11 @@ then by token): after ``tools/qwen3_next_check.py``.
 ``chunked_prefill`` times, at the cell's chunk and the published widths
 against the float32 reference's logits (prompts longer than ``topk``, so that
 every late query drops positions; no more rows feed at once than a step
-works: ``models/decoder.py: feed_rows``).
+works: ``models/decoder.py: feed_rows``; with ``--mixed-rows`` above that cap
+the step runs as the cell's does: the feeding rows' selection laid over every
+row's first query's). The row says which read the step was traced with
+(``attn_read``: ``dsa.kernel`` on a TPU since PR 48); ``--control`` adds the
+same step on the reference module's faulty parameters.
 
 ``--steps 16,32,64,128``: what one mixed step costs at each chunk, at the
 cell's rows and ring with every row live at ``--context`` tokens and as many
@@ -183,6 +187,9 @@ def main():
     ap.add_argument("--mixed", action="store_true")
     ap.add_argument("--mixed-lens", type=int, nargs=2, default=(2200, 3000))
     ap.add_argument("--mixed-rows", type=int, default=3)
+    ap.add_argument("--control", action="store_true",
+                    help="with --mixed: the same step on the reference "
+                    "module's faulty parameters, which must miss the limit")
     ap.add_argument("--steps", default="")
     ap.add_argument("--context", type=int, default=8192)
     ap.add_argument("--select", action="store_true")
@@ -231,7 +238,16 @@ def main():
         want = check.reference_logits(ref, hf, params, prompts, first)
         errs = [check.logits_error(pre, want[0]),
                 check.logits_error(dec, want[1])]
+        row = {}
+        if args.control:  # the reference module's one fault must FAIL
+            fault, faulty = ref.control(params)
+            lost = mixed_logits(engine, faulty, prompts, args.chunk, cap=cap)
+            row = {"control": check.logits_error(lost[0], want[0]),
+                   "control_fault": fault}
         say({"what": "program", "path": "mixed", "chunk": args.chunk,
+             "attn_read": decoder.attn_read(
+                 cfg, engine.new_paged_cache(1), mesh, args.chunk),
+             "rows_feeding_at_once": cap or len(prompts), **row,
              "prompt_lens": [len(p) for p in prompts], "logits": errs,
              "rms": [check.logits_error(pre, want[0], rms=True),
                      check.logits_error(dec, want[1], rms=True)],
