@@ -627,9 +627,9 @@ def audit_program(
             Finding(rule, SRC_PATH, prog.line, 1, f"{prog.name}: {msg}")
         )
 
-    # Audit the default XLA lowering: an ambient LLMSS_ATTN_IMPL override
-    # (tests force "pallas") would change the HLO under audit and diff
-    # the manifest for reasons that are not program changes.
+    # Audit ONE deterministic lowering, whatever the platform would
+    # choose and whatever pin the caller holds (tests force "pallas"):
+    # the manifest must not diff for reasons that are not program changes.
     with attention.force_impl("xla"):
         fn, args, kwargs = prog.build(env)
         with warnings.catch_warnings(record=True) as wrec:

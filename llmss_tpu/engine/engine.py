@@ -772,27 +772,20 @@ class DecodeEngine:
 
     def _bucketable(self) -> bool:
         """Whether this engine's decode path can bucket cache reads at
-        all: sp>1 meshes and the Pallas decode override read the full
-        cache by construction. IMPL_OVERRIDE is re-read each call (tests
-        monkeypatch it) — the mesh check is the cheap early-out."""
-        import importlib
-
+        all: an sp>1 mesh reads the full cache by construction."""
         from llmss_tpu.parallel.mesh import AXIS_SP
 
-        if self.mesh is not None and AXIS_SP in self.mesh.shape and (
-            self.mesh.shape[AXIS_SP] > 1
-        ):
-            return False
-        _att = importlib.import_module("llmss_tpu.ops.attention")
-        return _att.IMPL_OVERRIDE != "pallas"
+        return not (
+            self.mesh is not None and AXIS_SP in self.mesh.shape
+            and self.mesh.shape[AXIS_SP] > 1
+        )
 
     def decode_bucket(self, pos_bound: int) -> int | None:
         """Pick the cache-read bucket for a decode call whose rows' ring
         positions (current + steps in the call) are all < ``pos_bound``.
         Returns None — read the full ring — when no ladder entry covers it,
-        when any row may have wrapped (pos_bound > max_seq_len), or on the
-        sp>1 / Pallas-kernel decode paths (which read the full cache by
-        construction)."""
+        when any row may have wrapped (pos_bound > max_seq_len), or on an
+        sp>1 mesh (which reads the full cache by construction)."""
         if not self._ladder or pos_bound > self.max_seq_len:
             return None  # wrapped rows: full-ring semantics
         if not self._bucketable():
@@ -805,8 +798,8 @@ class DecodeEngine:
     def prewarm_bucket_set(self) -> "list[int | None]":
         """Every ``t_bucket`` value the live decode path can pick — what a
         prewarm must compile. Skips the ladder when this engine's decode
-        path can't bucket at all (sp>1 mesh / pallas override): every
-        ladder value would compile a byte-identical full-ring program."""
+        path can't bucket at all (an sp>1 mesh): every ladder value would
+        compile a byte-identical full-ring program."""
         out: list[int | None] = [None]
         if self.decode_bucket(1) is not None:
             out += self._ladder

@@ -1380,81 +1380,6 @@ def _layer_of(stack, l):
     )
 
 
-def _make_decode_kernel_attn(cfg, mesh, cache, positions, slots):
-    """Dispatch for the stacked-cache Pallas decode kernel: returns a
-    ``(q, k_new, v_new, *, layer) -> attn`` callable, else None (XLA
-    ``fresh_kv_decode_attention`` stays the implementation — also the CPU
-    oracle the kernel is parity-tested against,
-    tests/test_pallas_decode.py).
-
-    **Opt-in only** (``LLMSS_ATTN_IMPL=pallas``), never auto-dispatched;
-    shapes or a cache dtype the kernel cannot take raise on TPU
-    (``forced_pallas_miss``). Measured on v5e in round 5 (an earlier JAX;
-    under JAX 0.9.0 the kernel did not compile in bf16 until PR 21 and has
-    not been timed since) it was *slower* than the XLA einsum path (6.4 vs
-    4.25 ms/step) — per-call overhead across 20
-    layer invocations and strided per-head VMEM reads outweigh the
-    dynamic-slice copy it eliminates. Kept because the scalar-prefetch
-    stacked-cache read is the right building block for future paged /
-    quantized cache layouts (see PROFILE.md@e57f952)."""
-    import importlib
-
-    from llmss_tpu.ops import pallas_decode
-
-    # ops/__init__ rebinds the ``attention`` attribute to the function, so
-    # the module (whose IMPL_OVERRIDE tests monkeypatch) needs importlib.
-    attention_mod = importlib.import_module("llmss_tpu.ops.attention")
-    force = attention_mod.IMPL_OVERRIDE
-    if mesh is None or force != "pallas":
-        return None
-    dp, sp, tp = (
-        mesh.shape[AXIS_DP], mesh.shape[AXIS_SP], mesh.shape[AXIS_TP]
-    )
-    B = cache.k.shape[1]
-    T, Hq, Hkv, D = cache.max_len, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    kv_shard, heads_ok, kv_ax = attention_mod.tp_head_plan(Hq, Hkv, tp)
-    local_Hq = Hq // tp
-    local_Hkv = Hkv // tp if kv_shard else Hkv
-    # The kernel reads the cache as values: an int8 cache's scales have no
-    # way in, so a quantized cache is out of envelope like a bad shape.
-    if cache.quantized or sp != 1 or B % dp or not heads_ok or not (
-        pallas_decode.supports(T, local_Hq, local_Hkv, D, cache.k.dtype)
-    ):
-        attention_mod.forced_pallas_miss(
-            "decode shapes out of the stacked-cache kernel envelope "
-            f"(sp={sp}, B={B}, dp={dp}, T={T}, Hq={Hq}, Hkv={Hkv}, D={D}, "
-            f"{cache.k.dtype})"
-        )
-        return None
-    qs = P(AXIS_DP, None, AXIS_TP, None)
-    ks = P(None, AXIS_DP, None, kv_ax, None)
-    kns = P(AXIS_DP, None, kv_ax, None)
-    ps = P(AXIS_DP, None)
-    interp = attention_mod.pallas_interpret()
-
-    def local(q, kc, vc, kn, vn, qp, kvp, sl, layer):
-        return pallas_decode.decode_attention(
-            q, kc, vc, kn, vn, qp, kvp, sl, layer,
-            scale=cfg.attn_scale, window=cfg.sliding_window,
-            interpret=interp,
-        )
-
-    sharded = jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(qs, ks, ks, kns, kns, ps, ps, ps, P()),
-        out_specs=qs, check_vma=False,
-    )
-
-    def attn(q, k_new, v_new, k_cache, v_cache, *, layer):
-        del k_cache, v_cache  # reads the stacked cache directly
-        return sharded(
-            q, cache.k, cache.v, k_new, v_new, positions,
-            cache.positions, slots, layer,
-        )
-
-    return attn
-
-
 def _make_sp_decode_attn(cfg, mesh, cache, positions, slots):
     """Dispatch for sp>1 deferred-write decode: returns a
     ``(q, k_new, v_new, k_cache, v_cache) -> attn`` callable running
@@ -1466,8 +1391,7 @@ def _make_sp_decode_attn(cfg, mesh, cache, positions, slots):
     from llmss_tpu.ops import ring_attention as ring_mod
 
     attention_mod = importlib.import_module("llmss_tpu.ops.attention")
-    force = attention_mod.IMPL_OVERRIDE
-    if force not in (None, "ring"):
+    if attention_mod.IMPL_OVERRIDE is not None:
         return None
     B, T = cache.k.shape[1], cache.max_len
     ok, kv_ax = attention_mod.sp_plan(
@@ -1675,133 +1599,109 @@ def forward(
 
     quant = cache.quantized
     if defer_write:
-        kernel_attn = None if S > 1 else _make_decode_kernel_attn(
-            cfg, mesh, cache, positions, slots
+        # Bucketed cache read: in bucket mode the per-layer KV (and
+        # scales) is fetched with a hand-emitted ``lax.dynamic_slice``
+        # of size [1, B, t_bucket, Hkv, D] from the full stacked cache
+        # (a scan *constant*, not an xs operand) — only live-context
+        # bytes ever stream from HBM. This slicing must be explicit:
+        # XLA does NOT fold a static T-slice into the scan's
+        # per-iteration layer dynamic-slice — a pre-scan slice of the
+        # stacked cache materializes a fresh [L, B, tb, H, D] operand
+        # (+1.3 ms/step at bench scale) and an in-body slice adds an
+        # HBM round-trip after the full-T copy (+0.3 ms/step); both
+        # measured slower than just reading the full ring. The
+        # post-scan scatter below still writes the full buffers.
+        bucket = (
+            t_bucket
+            if t_bucket is not None and t_bucket < cache.max_len
+            and sp_attn is None
+            else None
         )
-        if kernel_attn is not None and _ablate is None:
-            # Stacked-cache Pallas path: the scan carries only params + the
-            # layer index; the kernel's block DMAs read the layer's KV
-            # directly from the stacked buffer (no per-layer dynamic-slice
-            # copy — the round-5 profile's 0.5 ms/step sink).
-            def body(h, xs):
-                bp, layer = xs
-                h, k_f, v_f, _, _ = _block(
-                    cfg, bp, h, positions, None, None, cache.positions,
-                    slots, None, mesh=mesh, defer_write=True,
-                    attn_override=partial(kernel_attn, layer=layer),
-                    sin_cos=sin_cos,
+        kv_pos_src = (
+            cache.positions[:, :bucket]
+            if bucket is not None else cache.positions
+        )
+        penalty = None
+        win_attn = None
+        if sp_attn is None:
+            if S == 1:
+                penalty = decode_mask_penalty(
+                    positions, kv_pos_src, slots, cfg.sliding_window
                 )
-                return h, (k_f, v_f)
-
-            h, ys = jax.lax.scan(
-                body, h,
-                (params["blocks"],
-                 jnp.arange(cfg.n_layers, dtype=jnp.int32)),
-            )
-        else:
-            # Bucketed cache read: in bucket mode the per-layer KV (and
-            # scales) is fetched with a hand-emitted ``lax.dynamic_slice``
-            # of size [1, B, t_bucket, Hkv, D] from the full stacked cache
-            # (a scan *constant*, not an xs operand) — only live-context
-            # bytes ever stream from HBM. This slicing must be explicit:
-            # XLA does NOT fold a static T-slice into the scan's
-            # per-iteration layer dynamic-slice — a pre-scan slice of the
-            # stacked cache materializes a fresh [L, B, tb, H, D] operand
-            # (+1.3 ms/step at bench scale) and an in-body slice adds an
-            # HBM round-trip after the full-T copy (+0.3 ms/step); both
-            # measured slower than just reading the full ring. The
-            # post-scan scatter below still writes the full buffers.
-            bucket = (
-                t_bucket
-                if t_bucket is not None and t_bucket < cache.max_len
-                and sp_attn is None
-                else None
-            )
-            kv_pos_src = (
-                cache.positions[:, :bucket]
-                if bucket is not None else cache.positions
-            )
-            penalty = None
-            win_attn = None
-            if sp_attn is None:
-                if S == 1:
-                    penalty = decode_mask_penalty(
-                        positions, kv_pos_src, slots, cfg.sliding_window
-                    )
-                else:
-                    # Windowed fresh-KV merge: one [B, T] cache penalty
-                    # (every pre-window slot is visible to all window
-                    # queries) + a compile-time triangular intra-window
-                    # mask inside the attention itself.
-                    penalty_w = window_mask_penalty(
-                        positions[:, :1], kv_pos_src, slots
-                    )
-
-                    def win_attn(q, k_new, v_new, k_c, v_c):
-                        return fresh_kv_window_attention(
-                            q, k_c, v_c, k_new, v_new, penalty_w,
-                            scale=cfg.attn_scale,
-                        )
-            B = input_ids.shape[0]
-            Hkv, D = cfg.n_kv_heads, cfg.head_dim
-
-            def layer_kv(l):
-                """[B, bucket, ...] KV (+scale) slices of layer ``l``."""
-                def sl(buf, *feat):
-                    return jax.lax.dynamic_slice(
-                        buf, (l,) + (0,) * (2 + len(feat)),
-                        (1, B, bucket) + feat,
-                    )[0]
-
-                k_l = sl(cache.k, Hkv, D)
-                v_l = sl(cache.v, Hkv, D)
-                if not quant:
-                    return k_l, v_l, None, None
-                return k_l, v_l, sl(cache.k_scale, Hkv), sl(
-                    cache.v_scale, Hkv
-                )
-
-            def body(h, xs):
-                ks_l = vs_l = None
-                if bucket is not None:
-                    bp, l = xs
-                    k_l, v_l, ks_l, vs_l = layer_kv(l)
-                elif quant:
-                    bp, k_l, v_l, ks_l, vs_l = xs
-                else:
-                    bp, k_l, v_l = xs
-                if quant and sp_attn is not None:
-                    # The sp shard_map path expects compute-dtype chunks:
-                    # pre-dequantize (materializes a bf16 copy of the
-                    # layer — the price of int8 on sp meshes). Otherwise
-                    # the raw int8 slices ride: the scales fold into the
-                    # attention contractions (fresh_kv_decode_attention)
-                    # so no dequantized copy ever materializes.
-                    k_l = dequantize_kv(k_l, ks_l, dtype)
-                    v_l = dequantize_kv(v_l, vs_l, dtype)
-                    ks_l = vs_l = None
-                h, k_f, v_f, _, _ = _block(
-                    cfg, bp, h, positions, k_l, v_l, kv_pos_src, slots,
-                    None, mesh=mesh, defer_write=True,
-                    attn_override=sp_attn if sp_attn is not None
-                    else win_attn,
-                    sin_cos=sin_cos, penalty=penalty,
-                    k_scale=ks_l, v_scale=vs_l,
-                )
-                ys = None if _ablate == "no_scatter" else (k_f, v_f)
-                return h, ys
-
-            if bucket is not None:
-                xs = (
-                    params["blocks"],
-                    jnp.arange(cfg.n_layers, dtype=jnp.int32),
-                )
-            elif quant:
-                xs = (params["blocks"], cache.k, cache.v, cache.k_scale,
-                      cache.v_scale)
             else:
-                xs = (params["blocks"], cache.k, cache.v)
-            h, ys = jax.lax.scan(body, h, xs)
+                # Windowed fresh-KV merge: one [B, T] cache penalty
+                # (every pre-window slot is visible to all window
+                # queries) + a compile-time triangular intra-window
+                # mask inside the attention itself.
+                penalty_w = window_mask_penalty(
+                    positions[:, :1], kv_pos_src, slots
+                )
+
+                def win_attn(q, k_new, v_new, k_c, v_c):
+                    return fresh_kv_window_attention(
+                        q, k_c, v_c, k_new, v_new, penalty_w,
+                        scale=cfg.attn_scale,
+                    )
+        B = input_ids.shape[0]
+        Hkv, D = cfg.n_kv_heads, cfg.head_dim
+
+        def layer_kv(l):
+            """[B, bucket, ...] KV (+scale) slices of layer ``l``."""
+            def sl(buf, *feat):
+                return jax.lax.dynamic_slice(
+                    buf, (l,) + (0,) * (2 + len(feat)),
+                    (1, B, bucket) + feat,
+                )[0]
+
+            k_l = sl(cache.k, Hkv, D)
+            v_l = sl(cache.v, Hkv, D)
+            if not quant:
+                return k_l, v_l, None, None
+            return k_l, v_l, sl(cache.k_scale, Hkv), sl(
+                cache.v_scale, Hkv
+            )
+
+        def body(h, xs):
+            ks_l = vs_l = None
+            if bucket is not None:
+                bp, l = xs
+                k_l, v_l, ks_l, vs_l = layer_kv(l)
+            elif quant:
+                bp, k_l, v_l, ks_l, vs_l = xs
+            else:
+                bp, k_l, v_l = xs
+            if quant and sp_attn is not None:
+                # The sp shard_map path expects compute-dtype chunks:
+                # pre-dequantize (materializes a bf16 copy of the
+                # layer — the price of int8 on sp meshes). Otherwise
+                # the raw int8 slices ride: the scales fold into the
+                # attention contractions (fresh_kv_decode_attention)
+                # so no dequantized copy ever materializes.
+                k_l = dequantize_kv(k_l, ks_l, dtype)
+                v_l = dequantize_kv(v_l, vs_l, dtype)
+                ks_l = vs_l = None
+            h, k_f, v_f, _, _ = _block(
+                cfg, bp, h, positions, k_l, v_l, kv_pos_src, slots,
+                None, mesh=mesh, defer_write=True,
+                attn_override=sp_attn if sp_attn is not None
+                else win_attn,
+                sin_cos=sin_cos, penalty=penalty,
+                k_scale=ks_l, v_scale=vs_l,
+            )
+            ys = None if _ablate == "no_scatter" else (k_f, v_f)
+            return h, ys
+
+        if bucket is not None:
+            xs = (
+                params["blocks"],
+                jnp.arange(cfg.n_layers, dtype=jnp.int32),
+            )
+        elif quant:
+            xs = (params["blocks"], cache.k, cache.v, cache.k_scale,
+                  cache.v_scale)
+        else:
+            xs = (params["blocks"], cache.k, cache.v)
+        h, ys = jax.lax.scan(body, h, xs)
         ks_new, vs_new = cache.k_scale, cache.v_scale
         if _ablate == "no_scatter":
             k_new, v_new = cache.k, cache.v
@@ -1874,78 +1774,6 @@ def forward(
     )
 
 
-def _make_paged_kernel_attn(cfg, mesh, cache, positions, slots, nblk):
-    """Paged analogue of ``_make_decode_kernel_attn``: returns a
-    ``(q, k_new, v_new, k_cache, v_cache, *, layer) -> attn`` callable
-    running the ragged block-table kernel (ops/pallas_paged_decode.py), or
-    None — the XLA gather fallback (``ops.attention.paged_decode_attention``)
-    stays the implementation and the parity oracle.
-
-    Same opt-in contract as the dense kernel: only under
-    ``LLMSS_ATTN_IMPL=pallas``; shapes outside the kernel envelope raise on
-    TPU (``forced_pallas_miss``) so an A/B run never measures the XLA path
-    under the kernel's name. The pool rides replicated over dp (block
-    indices are global — see ``paged_cache_specs``) while q/fresh-KV/tables
-    shard over dp as usual.
-    """
-    import importlib
-
-    from llmss_tpu.ops import pallas_paged_decode
-
-    attention_mod = importlib.import_module("llmss_tpu.ops.attention")
-    force = attention_mod.IMPL_OVERRIDE
-    if mesh is None or force != "pallas":
-        return None
-    dp, sp, tp = (
-        mesh.shape[AXIS_DP], mesh.shape[AXIS_SP], mesh.shape[AXIS_TP]
-    )
-    B = cache.block_tables.shape[0]
-    Hq, Hkv, D = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    kv_shard, heads_ok, kv_ax = attention_mod.tp_head_plan(Hq, Hkv, tp)
-    local_Hq = Hq // tp
-    local_Hkv = Hkv // tp if kv_shard else Hkv
-    if cache.quantized or sp != 1 or B % dp or not heads_ok or not (
-        pallas_paged_decode.supports(
-            cache.block_size, local_Hq, local_Hkv, D, cache.k.dtype
-        )
-    ):
-        attention_mod.forced_pallas_miss(
-            "shapes out of the paged decode kernel envelope "
-            f"(sp={sp}, B={B}, dp={dp}, bs={cache.block_size}, Hq={Hq}, "
-            f"Hkv={Hkv}, D={D}, {cache.k.dtype})"
-        )
-        return None
-    qs = P(AXIS_DP, None, AXIS_TP, None)
-    pool_s = P(None, None, None, kv_ax, None)
-    kns = P(AXIS_DP, None, kv_ax, None)
-    ps = P(AXIS_DP, None)
-    interp = attention_mod.pallas_interpret()
-
-    def local(q, kp, vp, kn, vn, qp, kvp, bt, nb, sl, layer):
-        return pallas_paged_decode.paged_decode_attention(
-            q, kp, vp, kn, vn, qp, kvp, bt, nb, sl, layer,
-            scale=cfg.attn_scale, window=cfg.sliding_window,
-            interpret=interp,
-        )
-
-    sharded = jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(
-            qs, pool_s, pool_s, kns, kns, ps, ps, ps, P(AXIS_DP), ps, P()
-        ),
-        out_specs=qs, check_vma=False,
-    )
-
-    def attn(q, k_new, v_new, k_cache, v_cache, *, layer):
-        del k_cache, v_cache  # reads the stacked pool directly
-        return sharded(
-            q, cache.k, cache.v, k_new, v_new, positions, cache.positions,
-            cache.block_tables, nblk, slots, layer,
-        )
-
-    return attn
-
-
 def _forward_paged(
     cfg: DecoderConfig,
     params: Params,
@@ -1968,8 +1796,8 @@ def _forward_paged(
     only the storage under a row's logical slot axis is indirected through
     its block table. Decode (S == 1) keeps the deferred-write structure:
     attention runs over the stale pool (XLA: per-row gathered logical views,
-    identical values and slot order to the dense ring — or the ragged
-    Pallas kernel reading blocks in place), and the fresh KV lands in one
+    identical values and slot order to the dense ring — or
+    ops/pallas_kv.py reading blocks in place), and the fresh KV lands in one
     batched all-layer pool scatter after the scan. Prefill gathers each
     layer's logical view, runs the dense write-then-attend block over it,
     and persists the fresh tokens through ``(block, offset)`` scatters.
@@ -2026,8 +1854,6 @@ def _forward_paged(
         Tv = (nb if nb is not None else MB) * bs
         kv_pos_src = cache.positions[:, :Tv]
 
-        occ = jnp.sum((cache.positions >= 0).astype(jnp.int32), axis=1)
-        nblk = jnp.clip(-(-occ // bs), 0, MB).astype(jnp.int32)
         # The layer scan closes over the stacked pool and reads it with the
         # layer as an INDEX (of the kernel's block map, or of the one
         # gather): never as a slice, which the compiler copies out whole
@@ -2038,10 +1864,6 @@ def _forward_paged(
                 slots[:, 0], kv_pos_src,
             )
         else:
-            attn = _make_paged_kernel_attn(
-                cfg, mesh, cache, positions, slots, nblk
-            )
-        if attn is None:
             penalty = decode_mask_penalty(
                 positions, kv_pos_src, slots, cfg.sliding_window
             )
@@ -2227,10 +2049,7 @@ def attn_read(cfg: DecoderConfig, cache: PagedKVCache, mesh, chunk: int) -> str:
     rows' logical views gathered, the XLA oracles), ``mla.kernel`` (a latent
     pool read in place, ops/pallas_mla.py), ``kv.kernel`` (a pool of keys
     and values read in place, each row's own blocks up to its length,
-    ops/pallas_kv.py), ``kernel`` (the block-at-a-time kernels that
-    ``LLMSS_ATTN_IMPL=pallas`` opts a pool of keys and values into where
-    ``kv.kernel`` does not apply: a ``tp`` mesh),
-    or, for a model that selects what attention reads (``cfg.indexer``),
+    ops/pallas_kv.py) or, for a model that selects what attention reads:
     ``dsa.kernel`` (both pools read in place under the selection as bits,
     live rows only, ops/pallas_dsa.py) and its XLA forms ``dsa.tokens`` (a
     decode step: the kept tokens read by token) and ``dsa.mask`` (a mixed
@@ -2264,9 +2083,16 @@ def attn_read(cfg: DecoderConfig, cache: PagedKVCache, mesh, chunk: int) -> str:
                 cache.block_size, Hq, Hkv, cfg.head_dim, chunk, cache.k.dtype
             )
         )
+        if force == "pallas" and not ok:
+            attention_mod.forced_pallas_miss(
+                "shapes out of the pool read kernel's envelope "
+                f"(devices={1 if mesh is None else mesh.size}, "
+                f"bs={cache.block_size}, Hq={Hq}, Hkv={Hkv}, "
+                f"D={cfg.head_dim}, chunk={chunk}, {cache.k.dtype})"
+            )
         if ok and (force == "pallas" or not attention_mod.pallas_interpret()):
             return "kv.kernel"
-        return "kernel" if force == "pallas" and mesh is not None else "gather"
+        return "gather"
     ok = (
         one_device
         and cache.k.dtype == cfg.compute_dtype
@@ -2706,81 +2532,6 @@ def _forward_selected(
     )
 
 
-def _make_ragged_kernel_attn(
-    cfg, mesh, cache, positions0, q_lens, slot0, nblk,
-):
-    """Ragged analogue of ``_make_paged_kernel_attn``: returns a
-    ``(q, k_new, v_new, k_cache, v_cache, *, layer) -> attn`` callable
-    running the mixed prefill+decode block-table kernel
-    (ops/pallas_ragged.py), or None — the XLA gather fallback
-    (``ops.attention.ragged_paged_attention``) stays the implementation
-    and the parity oracle.
-
-    Same opt-in contract as the paged decode kernel: only under
-    ``LLMSS_ATTN_IMPL=pallas``; shapes outside the kernel envelope raise on
-    TPU (``forced_pallas_miss``).
-    """
-    import importlib
-
-    from llmss_tpu.ops import pallas_ragged
-
-    attention_mod = importlib.import_module("llmss_tpu.ops.attention")
-    force = attention_mod.IMPL_OVERRIDE
-    if mesh is None or force != "pallas":
-        return None
-    dp, sp, tp = (
-        mesh.shape[AXIS_DP], mesh.shape[AXIS_SP], mesh.shape[AXIS_TP]
-    )
-    B = cache.block_tables.shape[0]
-    Hq, Hkv, D = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    kv_shard, heads_ok, kv_ax = attention_mod.tp_head_plan(Hq, Hkv, tp)
-    local_Hq = Hq // tp
-    local_Hkv = Hkv // tp if kv_shard else Hkv
-    # (The kernel itself takes int8 scales — tests/test_ragged.py — but
-    # this dispatch does not pass them, so a quantized pool is refused.)
-    if cache.quantized or sp != 1 or B % dp or not heads_ok or not (
-        pallas_ragged.supports(
-            cache.block_size, local_Hq, local_Hkv, D, cache.k.dtype
-        )
-    ):
-        attention_mod.forced_pallas_miss(
-            "shapes out of the ragged mixed-batch kernel envelope "
-            f"(sp={sp}, B={B}, dp={dp}, bs={cache.block_size}, Hq={Hq}, "
-            f"Hkv={Hkv}, D={D}, {cache.k.dtype})"
-        )
-        return None
-    qs = P(AXIS_DP, None, AXIS_TP, None)
-    pool_s = P(None, None, None, kv_ax, None)
-    kns = P(AXIS_DP, None, kv_ax, None)
-    ps = P(AXIS_DP, None)
-    row = P(AXIS_DP)
-    interp = attention_mod.pallas_interpret()
-
-    def local(q, kp, vp, kn, vn, qp, ql, kvp, bt, nb, sl0, layer):
-        return pallas_ragged.ragged_paged_attention(
-            q, kp, vp, kn, vn, qp, ql, kvp, bt, nb, sl0, layer,
-            scale=cfg.attn_scale, window=cfg.sliding_window,
-            interpret=interp,
-        )
-
-    sharded = jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(
-            qs, pool_s, pool_s, kns, kns, row, row, ps, ps, row, row, P()
-        ),
-        out_specs=qs, check_vma=False,
-    )
-
-    def attn(q, k_new, v_new, k_cache, v_cache, *, layer):
-        del k_cache, v_cache  # reads the stacked pool directly
-        return sharded(
-            q, cache.k, cache.v, k_new, v_new, positions0, q_lens,
-            cache.positions, cache.block_tables, nblk, slot0, layer,
-        )
-
-    return attn
-
-
 def forward_ragged(
     cfg: DecoderConfig,
     params: Params,
@@ -2803,10 +2554,10 @@ def forward_ragged(
     prefill program (ISSUE 10; "Ragged Paged Attention", PAPERS.md).
 
     Deferred-write structure exactly like the S == 1 decode branch of
-    ``_forward_paged``: attention runs over the stale pool (ragged Pallas
-    kernel reading blocks in place, or per-row gathered logical views
-    through the XLA oracle), and the chunk's fresh KV lands in one batched
-    all-layer pool scatter after the scan. Logits gather at each row's
+    ``_forward_paged``: attention runs over the stale pool (ops/pallas_kv.py
+    reading blocks in place, or per-row gathered logical views through the
+    XLA oracle), and the chunk's fresh KV lands in one batched all-layer
+    pool scatter after the scan. Logits gather at each row's
     last live chunk position (``q_lens - 1``) — for a prompt's final chunk
     that is the prefill sampling position, for a decode row it is the
     usual last-token gather. Padding columns (``>= q_lens``) write nowhere
@@ -2857,17 +2608,11 @@ def forward_ragged(
     q_pos0 = positions[:, 0]
     slot0 = slots[:, 0]
 
-    occ = jnp.sum((cache.positions >= 0).astype(jnp.int32), axis=1)
-    nblk = jnp.clip(-(-occ // bs), 0, MB).astype(jnp.int32)
     # Same discipline as the S == 1 branch of _forward_paged: the scan
     # closes over the stacked pool and the layer is an index of the read.
     if attn_read(cfg, cache, mesh, S) == "kv.kernel":
         attn = _make_kv_read(cfg, cache, q_pos0, q_lens, slot0, kv_pos_src)
     else:
-        attn = _make_ragged_kernel_attn(
-            cfg, mesh, cache, q_pos0, q_lens, slot0, nblk
-        )
-    if attn is None:
         # Hoist the query-invariant visibility out of the layer scan (the
         # per-query causal bound stays inside the oracle — it is chunk
         # structure, not a [B, T] penalty).

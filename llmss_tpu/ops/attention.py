@@ -17,8 +17,6 @@ replicated sharding spec on the KV projection).
 
 from __future__ import annotations
 
-import os
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
@@ -26,15 +24,14 @@ from jax.sharding import PartitionSpec as P
 
 _NEG_INF = float(jnp.finfo(jnp.float32).min)
 
-# Attention implementation override: "xla" | "pallas" | "ring" | None (auto).
-# Env var LLMSS_ATTN_IMPL or set directly (tests force "pallas" to exercise
-# the kernel in interpret mode on CPU). "pallas" disables the sp ring path
-# (the kernel is single-shard: A/B it against "xla" on an sp=1 mesh);
-# "ring" requires an sp>1 mesh. A forced implementation that cannot take
-# the shapes raises (``forced_pallas_miss``, and the ring check in
-# ``dispatch_attention``): a run under a kernel's name never measures
-# another implementation.
-IMPL_OVERRIDE: str | None = os.environ.get("LLMSS_ATTN_IMPL") or None
+# Attention implementation pin: "xla" | "pallas" | None (the program
+# chooses from platform, mesh, dtype and each kernel's ``supports``). Set
+# only through ``force_impl``; nothing in the environment is read. "pallas"
+# disables the sp ring path (the kernels are single-shard: A/B them against
+# "xla" on an sp=1 mesh). A pinned kernel that cannot take the shapes
+# raises on a TPU (``forced_pallas_miss``): a run under a kernel's name
+# never measures another implementation.
+IMPL_OVERRIDE: str | None = None
 
 
 def pallas_interpret() -> bool:
@@ -54,12 +51,12 @@ def pallas_interpret() -> bool:
 
 
 def forced_pallas_miss(msg: str) -> None:
-    """``LLMSS_ATTN_IMPL=pallas`` named a kernel that cannot take these
+    """``force_impl("pallas")`` named a kernel that cannot take these
     shapes. Compiled (TPU) that is an error — the XLA path never runs
     under the kernel's name. Interpreted (the CPU parity tests, which
-    force the override across whole engines at toy widths) the caller
-    continues on the XLA oracle, with a warning saying so."""
-    msg = "LLMSS_ATTN_IMPL=pallas: " + msg
+    pin across whole engines at toy widths) the caller continues on the
+    XLA oracle, with a warning saying so."""
+    msg = "pallas forced: " + msg
     if not pallas_interpret():
         raise ValueError(msg)
     import warnings
@@ -68,16 +65,20 @@ def forced_pallas_miss(msg: str) -> None:
 
 
 class force_impl:
-    """Scoped IMPL_OVERRIDE: ``with force_impl("xla"): ...`` traces every
-    program inside the block with one pinned attention implementation and
-    restores the previous override on exit. shardcheck audits lowered HLO
-    under this pin — the collective inventory in tools/comms_manifest.json
-    is only golden against ONE deterministic lowering, and an ambient
-    LLMSS_ATTN_IMPL=pallas would silently diff every program. Also the
-    right tool for A/B benches that previously mutated the global by hand.
+    """The one scoped pin: ``with force_impl("xla"): ...`` traces every
+    program inside the block with one pinned implementation (``"xla"``,
+    ``"pallas"`` or ``None``) and restores the previous pin on exit.
+    shardcheck audits lowered HLO under it (the collective inventory in
+    tools/comms_manifest.json is golden against ONE deterministic
+    lowering), the CPU tests run a kernel interpreted under it, and a
+    builder's A/B script compares the two sides with it.
     """
 
     def __init__(self, impl: str | None):
+        if impl not in (None, "xla", "pallas"):
+            raise ValueError(
+                f"force_impl takes 'xla', 'pallas' or None, not {impl!r}"
+            )
         self.impl = impl
         self._saved: str | None = None
 
@@ -415,9 +416,9 @@ def paged_decode_attention(
     row-indirected logical view of one pool layer (``gather_block_view``)
     and run the exact fresh-KV merged softmax over it. The view has
     IDENTICAL values and slot order to the dense ring a row would hold, so
-    this is token-for-token the dense decode path — the parity oracle the
-    Pallas paged kernel (ops/pallas_paged_decode.py) is tested against,
-    and the implementation ``LLMSS_ATTN_IMPL`` A/B tests compare with."""
+    this is token-for-token the dense decode path — the parity oracle
+    ``ops/pallas_kv.py`` is tested against, and what ``attn_read`` calls
+    ``gather``."""
     from llmss_tpu.engine.cache import gather_block_view
 
     k_view, v_view = _gather_kv(
@@ -484,9 +485,8 @@ def ragged_fresh_kv_attention(
     triangular mask is clipped at ``q_len`` so padding query rows (``i >=
     q_len``) still attend fresh key 0 and keep a positive denominator (no
     NaN; their outputs are garbage the head gather never reads). This is
-    the XLA gather oracle the ragged Pallas kernel
-    (ops/pallas_ragged.py) is parity-tested against. Int8 scales fold
-    exactly as in ``fresh_kv_decode_attention``."""
+    the XLA oracle ``ops/pallas_kv.py``'s mixed step is parity-tested
+    against. Int8 scales fold exactly as in ``fresh_kv_decode_attention``."""
     B, S, Hq, D = q.shape
     T, Hkv = k_cache.shape[1], k_cache.shape[2]
     G = Hq // Hkv
@@ -569,8 +569,8 @@ def ragged_paged_attention(
     """Ragged chunked attention, XLA gather fallback: materialize the
     row-indirected logical view of one pool layer (``gather_block_view``)
     and run the exact ragged fresh-KV merged softmax over it — the parity
-    oracle for the ragged Pallas kernel (ops/pallas_ragged.py) and the
-    path mixed batches take when the kernel envelope doesn't apply."""
+    oracle of ``ops/pallas_kv.py``'s mixed step and the path mixed
+    batches take wherever ``attn_read`` says ``gather``."""
     from llmss_tpu.engine.cache import gather_block_view
 
     k_view, v_view = _gather_kv(
@@ -624,15 +624,7 @@ def dispatch_attention(
         kv_shard, heads_ok, kv_ax = tp_head_plan(Hq, Hkv, tp)
 
         sp_shardable, _ = sp_plan(mesh, B, T, Hq, Hkv)
-        sp_ok = force in (None, "ring") and sp_shardable
-        if force == "ring" and not sp_ok:
-            # A silent fallback would make an A/B run measure the wrong
-            # implementation; forcing ring demands a satisfiable sp mesh.
-            raise ValueError(
-                "LLMSS_ATTN_IMPL=ring requires sp>1, T % sp == 0, "
-                f"B % dp == 0 and shardable heads; got sp={sp}, T={T}, "
-                f"B={B}, dp={dp}, Hq={Hq}, Hkv={Hkv}, tp={tp}"
-            )
+        sp_ok = force is None and sp_shardable
         if sp_ok:
             # Sequence-parallel path: KV (the cache) sharded over sp.
             ring = S > 1 and S % sp == 0

@@ -35,7 +35,7 @@ included, so every denominator is positive and no row's output is NaN
 
 bfloat16 operands, float32 accumulation of both products, float32 softmax
 state; the probabilities are rounded to the pool's dtype for the second
-product, as ``pallas_ragged`` and the flash kernel do.
+product, as ``pallas_kv`` and the flash kernel do.
 """
 
 from __future__ import annotations

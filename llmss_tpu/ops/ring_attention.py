@@ -203,9 +203,9 @@ def lse_merge_fresh_kv_attention(
     l_g = lax.psum(l * w, axis_name)
     acc_g = lax.psum(acc * w[..., None], axis_name)
 
-    # Fresh-token term (same math as fresh_kv_decode_attention's s_s /
-    # pallas_decode's epilogue): the token always attends itself, so an
-    # empty cache degenerates to out = v_new with no l == 0 guard.
+    # Fresh-token term (same math as fresh_kv_decode_attention's s_s):
+    # the token always attends itself, so an empty cache degenerates to
+    # out = v_new with no l == 0 guard.
     qf = q.astype(jnp.float32).reshape(B, S, Hkv, G, D) * scale
     s_new = jnp.einsum(
         "bskgd,bskd->bkgs", qf, k_new.astype(jnp.float32)

@@ -22,6 +22,7 @@ Plus: where ``initialize_runtime()`` puts the persistent compile cache.
 
 import dataclasses
 import functools
+import importlib
 import json
 import math
 import os
@@ -37,11 +38,11 @@ from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from llmss_tpu.engine import DecodeEngine
 from llmss_tpu.engine.cache import PagedKVCache, ssm_state_shapes
-from llmss_tpu.models.decoder import param_shapes, param_specs
+from llmss_tpu.models.decoder import attn_read, param_shapes, param_specs
 from llmss_tpu.models.registry import config_from_hf
 from llmss_tpu.ops import (
-    pallas_attention, pallas_decode, pallas_dsa, pallas_gdn, pallas_kv,
-    pallas_mla, pallas_paged_decode, pallas_ragged, pallas_ssm,
+    pallas_attention, pallas_dsa, pallas_gdn, pallas_kv, pallas_mla,
+    pallas_ssm,
 )
 from llmss_tpu.parallel import mesh as mesh_mod
 
@@ -51,7 +52,7 @@ WIDTHS = {
     "mistral-7b": (32, 8, 128),  # GQA
     "gpt-j-6b": (16, 16, 256),  # MHA, head_dim 256: Hkv*D = 4096
 }
-B, S, T, L, CB = 4, 512, 1024, 2, 8
+B, S, T = 4, 512, 1024
 BS = 16  # DecodeEngine's default block_size
 DT = jnp.bfloat16  # what the chip serves in
 
@@ -81,28 +82,11 @@ def v5e():
 def _kernel_call(kernel: str, Hq: int, Hkv: int, D: int):
     """(function, argument shapes) of one kernel at one width set."""
     i32 = jnp.int32
-    MB, N = T // BS, B * (T // BS)
-    pool = ((L, N, BS, Hkv, D), DT)
     if kernel == "flash":
         assert pallas_attention.supports(S, T, Hq, Hkv)
         return pallas_attention.flash_attention, [
             ((B, S, Hq, D), DT), ((B, T, Hkv, D), DT), ((B, T, Hkv, D), DT),
             ((B, S), i32), ((B, T), i32),
-        ]
-    if kernel == "dense_decode":
-        assert pallas_decode.supports(T, Hq, Hkv, D, DT)
-        return pallas_decode.decode_attention, [
-            ((B, 1, Hq, D), DT), ((L, B, T, Hkv, D), DT),
-            ((L, B, T, Hkv, D), DT), ((B, 1, Hkv, D), DT),
-            ((B, 1, Hkv, D), DT), ((B, 1), i32), ((B, T), i32),
-            ((B, 1), i32), ((), i32),
-        ]
-    if kernel == "paged_decode":
-        assert pallas_paged_decode.supports(BS, Hq, Hkv, D, DT)
-        return pallas_paged_decode.paged_decode_attention, [
-            ((B, 1, Hq, D), DT), pool, pool, ((B, 1, Hkv, D), DT),
-            ((B, 1, Hkv, D), DT), ((B, 1), i32), ((B, MB * BS), i32),
-            ((B, MB), i32), ((B,), i32), ((B, 1), i32), ((), i32),
         ]
     if kernel == "latent_read":
         # the latent pool's own kernel at the shapes of the benchmark's
@@ -187,14 +171,7 @@ def _kernel_call(kernel: str, Hq: int, Hkv: int, D: int):
             ((rows, chunk, Hq, Dv), f32), ((rows, chunk, Hq), f32),
             ((rows, chunk, Hq), f32), lens, ((), i32),
         ]
-    assert kernel == "ragged"
-    assert pallas_ragged.supports(BS, Hq, Hkv, D, DT)
-    return pallas_ragged.ragged_paged_attention, [
-        ((B, CB, Hq, D), DT), pool, pool, ((B, CB, Hkv, D), DT),
-        ((B, CB, Hkv, D), DT), ((B,), i32), ((B,), i32),
-        ((B, MB * BS), i32), ((B, MB), i32), ((B,), i32), ((B,), i32),
-        ((), i32),
-    ]
+    raise AssertionError(kernel)
 
 
 # (heads, tokens a row a step, row width) of the latent pool's read
@@ -221,6 +198,11 @@ KV_READS = {
     "kv-olmo-hybrid-step-of-4": (32, 32, (128, 3, 64, 4)),
     "kv-qwen3-next-decode": (16, 2, (256, 2, 320, 1)),
     "kv-qwen3-next-step-of-8": (16, 2, (256, 2, 320, 8)),
+} | {
+    # the other two width sets above, at 64 rows of 1,024 slots over 2 layers
+    f"kv-{model}-{step}": (Hq, Hkv, (D, 2, 64, chunk))
+    for model, (Hq, Hkv, D) in WIDTHS.items() if model != "starcoderbase-1b"
+    for step, chunk in (("decode", 1), ("step-of-4", 4))
 }
 
 # (query heads, KV heads, (head size, layers, blocks a row, tokens a row a
@@ -233,10 +215,8 @@ SELECTED_READS = {
 
 @pytest.mark.parametrize(
     "kernel,model",
-    [
-        (kernel, model) for model in WIDTHS
-        for kernel in ("flash", "dense_decode", "paged_decode", "ragged")
-    ] + [("latent_read", step) for step in LATENT_READS]
+    [("flash", model) for model in WIDTHS]
+    + [("latent_read", step) for step in LATENT_READS]
     + [("state_update", step) for step in STATE_UPDATES]
     + [("delta_update", step) for step in DELTA_UPDATES]
     + [("kv_read", step) for step in KV_READS]
@@ -269,37 +249,101 @@ STARCODERBASE_1B = dict(
 ROWS, POSITIONS = 64, 2048
 
 
+def _bench_config(name: str):
+    """``(hf, cfg)`` of ``benchmark/configs/<name>.json``."""
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmark", "configs", name + ".json",
+    )
+    with open(path) as f:
+        hf = json.load(f)
+    return hf, config_from_hf(types.SimpleNamespace(**hf), dtype=hf["dtype"])
+
+
+def _widths_config(Hq: int, Hkv: int, D: int = 128):
+    """``starcoderbase-1b``'s configuration with other attention widths."""
+    return dataclasses.replace(
+        config_from_hf(
+            types.SimpleNamespace(**STARCODERBASE_1B), dtype="bfloat16"
+        ),
+        n_heads=Hq, n_kv_heads=Hkv, head_dim=D,
+    )
+
+
+# tokens a row a mixed step, where a configuration's cell sets other than 4
+# (``serve.chunked_prefill`` of its file under benchmark/cells/)
+CELL_CHUNK = {
+    "kanana-2-30b-a3b-1chip": 8, "qwen3-next-80b-a3b-1chip": 8,
+    "keye-vl-2.0-30b-a3b-1chip": 32,
+}
+
+
 def _compile_group(device, program: str, widths, **kw):
     """``_compile_step`` at ``starcoderbase-1b``'s widths and envelope with
     ``widths`` KV heads, or, for the name of one of the benchmark's
     configurations (``falcon-h1-34b-1chip``: GQA with 4 KV heads beside a
     recurrent state, whose leaves ride in the cache), at that file's widths
-    in the envelope its cell serves."""
+    in the envelope its cell serves, a mixed step at its cell's chunk."""
     if isinstance(widths, int):
-        cfg = dataclasses.replace(
-            config_from_hf(
-                types.SimpleNamespace(**STARCODERBASE_1B), dtype="bfloat16"
-            ),
-            n_kv_heads=widths,
+        return _compile_step(
+            device, program, _widths_config(16, widths), POSITIONS, **kw
         )
-        return _compile_step(device, program, cfg, POSITIONS, **kw)
-    path = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "benchmark", "configs", widths + ".json",
-    )
-    with open(path) as f:
-        hf = json.load(f)
-    cfg = config_from_hf(types.SimpleNamespace(**hf), dtype=hf["dtype"])
+    hf, cfg = _bench_config(widths)
+    kw.setdefault("chunk", CELL_CHUNK.get(widths, 4))
     return _compile_step(
-        device, program, cfg, hf["serve"]["max_seq_len"], **kw
+        device, program, cfg, hf["serve"]["max_seq_len"],
+        ROWS=hf["serve"]["rows"], **kw
     )
+
+
+def _cache_shapes(cfg, arr, POSITIONS: int, ROWS: int):
+    """``(cache, pool shape)``: a paged cache of ``arr(shape, dtype)``s in
+    the envelope ``ROWS`` x ``POSITIONS``, every pool the configuration has."""
+    mb = POSITIONS // BS
+    pool = (cfg.n_kv_layers, ROWS * mb, BS) + cfg.cache_row
+    return PagedKVCache(
+        k=arr(pool, DT), v=None if cfg.mla is not None else arr(pool, DT),
+        block_tables=arr((ROWS, mb), jnp.int32),
+        positions=arr((ROWS, POSITIONS), jnp.int32),
+        **dict(zip(("ssm", "conv"), (
+            arr((cfg.n_state_layers, ROWS) + shape, dtype)
+            for shape, dtype in ssm_state_shapes(cfg) or ()
+        ))),
+        idx=None if cfg.indexer is None else arr(
+            pool[:3] + (cfg.indexer.pool_dim,), jnp.float32),
+    ), pool
+
+
+# every step program this file compiled, by what ``_compile_step`` was called
+# with: each is compiled once and every test reads its text
+_STEP_PROGRAMS: dict = {}
 
 
 def _compile_step(device, program: str, cfg, POSITIONS: int, ROWS: int = ROWS,
-                  chunk: int = 4, t_bucket: int | None = 512):
+                  chunk: int = 4, t_bucket: int | None = 512,
+                  as_tpu: bool = False):
     """``(compiled, pool shape)`` of one step program of the engine on
     shapes alone: 4 decode steps at a ``t_bucket``-slot read (None: the whole
-    ring), or 4 mixed steps with a ``chunk``-token chunk a row."""
+    ring), or 4 mixed steps with a ``chunk``-token chunk a row; ``as_tpu``:
+    traced as a TPU traces it (the program asks ``jax.default_backend()``,
+    which is the CPU here, so ``pallas_interpret`` answers for it)."""
+    key = (cfg, POSITIONS, ROWS, as_tpu) + (
+        ("decode", t_bucket) if program == "decode" else ("ragged", chunk)
+    )
+    if key not in _STEP_PROGRAMS:
+        with pytest.MonkeyPatch.context() as mp:
+            if as_tpu:
+                mp.setattr(
+                    importlib.import_module("llmss_tpu.ops.attention"),
+                    "pallas_interpret", lambda: False,
+                )
+            _STEP_PROGRAMS[key] = _lower_step(
+                device, program, cfg, POSITIONS, ROWS, chunk, t_bucket
+            )
+    return _STEP_PROGRAMS[key]
+
+
+def _lower_step(device, program, cfg, POSITIONS, ROWS, chunk, t_bucket):
     mesh = mesh_mod.make_mesh(mesh_mod.MeshPlan(tp=1), devices=[device])
 
     def arr(shape, dtype, spec=PartitionSpec()):
@@ -311,19 +355,7 @@ def _compile_step(device, program: str, cfg, POSITIONS: int, ROWS: int = ROWS,
         lambda s, spec: arr(s.shape, s.dtype, spec),
         param_shapes(cfg), param_specs(cfg, 1),
     )
-    mb = POSITIONS // BS
-    pool = (cfg.n_kv_layers, ROWS * mb, BS) + cfg.cache_row
-    cache = PagedKVCache(
-        k=arr(pool, DT), v=None if cfg.mla is not None else arr(pool, DT),
-        block_tables=arr((ROWS, mb), jnp.int32),
-        positions=arr((ROWS, POSITIONS), jnp.int32),
-        **dict(zip(("ssm", "conv"), (
-            arr((cfg.n_state_layers, ROWS) + shape, dtype)
-            for shape, dtype in ssm_state_shapes(cfg) or ()
-        ))),
-        idx=None if cfg.indexer is None else arr(
-            pool[:3] + (cfg.indexer.pool_dim,), jnp.float32),
-    )
+    cache, pool = _cache_shapes(cfg, arr, POSITIONS, ROWS)
     row = functools.partial(arr, (ROWS,))
     sample_args = dict(
         seeds=row(jnp.int32), temperature=row(jnp.float32),
@@ -420,7 +452,7 @@ def test_step_program_carries_the_pool_in_place(v5e, program, widths):
     ],
 )
 def test_step_program_reads_the_pool_where_it_lies(
-    v5e, monkeypatch, program, widths, chunk,
+    v5e, program, widths, chunk,
 ):
     """With ``kv.kernel`` (as on a TPU: ``ops/pallas_kv.py`` compiled) the
     decode group and the mixed group of the four cells that hold keys and
@@ -432,14 +464,9 @@ def test_step_program_reads_the_pool_where_it_lies(
     tiles, read through a free reshape; two, four: ``T(2,128)`` /
     ``T(4,128)``, a slot's heads packed in a sublane pair; thirty-two:
     ``T(8,128)``)."""
-    import importlib
-
-    # the program asks jax.default_backend(), which is the CPU here
-    monkeypatch.setattr(
-        importlib.import_module("llmss_tpu.ops.attention"),
-        "pallas_interpret", lambda: False,
+    compiled, pool = _compile_group(
+        v5e, program, widths, chunk=chunk, as_tpu=True
     )
-    compiled, pool = _compile_group(v5e, program, widths, chunk=chunk)
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "kv_paged_attention" in text
     moved = (
@@ -487,7 +514,7 @@ def test_two_kinds_of_layer_carry_every_pool_in_place(v5e, program):
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 14.5e9
 
 
-def test_a_share_of_the_experts_under_two_kinds_of_layer_fits(v5e, monkeypatch):
+def test_a_share_of_the_experts_under_two_kinds_of_layer_fits(v5e):
     """``qwen3-next-80b-a3b-1chip`` at the published widths, 64 rows x 5,120
     positions, the mixed group (the one step program its cell times): the
     128 held experts of all 8 layers are ONE stack read in place by the
@@ -498,14 +525,9 @@ def test_a_share_of_the_experts_under_two_kinds_of_layer_fits(v5e, monkeypatch):
     configuration's ``memory`` says: 9.50 GB, with under 0.2 GB of
     temporaries (the gathered rings of the XLA read, most of 1.1 GB until
     the block pool was read where it lies, are gone)."""
-    import importlib
-
-    # the program asks jax.default_backend(), which is the CPU here
-    monkeypatch.setattr(
-        importlib.import_module("llmss_tpu.ops.attention"),
-        "pallas_interpret", lambda: False,
+    compiled, pool = _compile_group(
+        v5e, "ragged", "qwen3-next-80b-a3b-1chip", as_tpu=True
     )
-    compiled, pool = _compile_group(v5e, "ragged", "qwen3-next-80b-a3b-1chip")
     assert pool == (2, 64 * 320, 16, 2, 256)
     text = compiled.as_text()
     moved = r"(?:\w+_)?(?:copy|transpose|dynamic[-_]slice)"
@@ -523,7 +545,7 @@ def test_a_share_of_the_experts_under_two_kinds_of_layer_fits(v5e, monkeypatch):
 
 @pytest.mark.parametrize("program", ["decode", "ragged"])
 def test_a_selection_inside_paged_attention_fits_beside_three_pools(
-    v5e, monkeypatch, program,
+    v5e, program,
 ):
     """``keye-vl-2.0-30b-a3b-1chip`` at the published widths, 32 rows x
     16,896 positions, the cell's mixed group (chunks of its
@@ -544,25 +566,15 @@ def test_a_selection_inside_paged_attention_fits_beside_three_pools(
     still holds: the indexer's view of all rows (277 MB) and the feeding
     rows' indexer scores (242 MB), 0.32 / 0.35 GB where the mask form's
     scores made the mixed group's 0.80."""
-    import importlib
-    import re
-
-    monkeypatch.setattr(
-        importlib.import_module("llmss_tpu.ops.attention"),
-        "pallas_interpret", lambda: False,
-    )
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(
-            root, "benchmark", "configs", "keye-vl-2.0-30b-a3b-1chip.json")) as f:
-        hf = json.load(f)
     with open(os.path.join(
             root, "benchmark", "cells",
             "keye-vl-2.0-30b-a3b-1chip.longdoc.json")) as f:
         chunk = json.load(f)["serve"]["chunked_prefill"]
-    cfg = config_from_hf(types.SimpleNamespace(**hf), dtype=hf["dtype"])
-    compiled, pool = _compile_step(
-        v5e, program, cfg, hf["serve"]["max_seq_len"],
-        ROWS=hf["serve"]["rows"], chunk=chunk, t_bucket=None,
+    assert chunk == CELL_CHUNK["keye-vl-2.0-30b-a3b-1chip"]
+    hf, cfg = _bench_config("keye-vl-2.0-30b-a3b-1chip")
+    compiled, pool = _compile_group(
+        v5e, program, "keye-vl-2.0-30b-a3b-1chip", t_bucket=None, as_tpu=True,
     )
     assert pool == (6, 32 * 1056, 16, 4, 128)
     text = compiled.as_text()
@@ -617,9 +629,7 @@ KANANA_2_30B_A3B = dict(
 
 
 @pytest.mark.parametrize("program", ["decode", "ragged"])
-def test_latent_step_program_carries_the_pool_in_place(
-    v5e, monkeypatch, program,
-):
+def test_latent_step_program_carries_the_pool_in_place(v5e, program):
     """The latent pool (``[L, N, bs, 640]``: 576 padded to whole lane tiles,
     no head axis) goes through the step loops as it came: no pool-sized
     ``copy``. At 576 wide its default device layout had the block axis
@@ -628,17 +638,10 @@ def test_latent_step_program_carries_the_pool_in_place(
     and so does the read of the pool (ops/pallas_mla.py): no gather of the
     rows' rings (all 64 x 320 blocks in the mixed step, 64 x 32 at the
     decode step's 512-slot read), no float32 scores over them in HBM."""
-    import importlib
-
-    # the program asks jax.default_backend(), which is the CPU here
-    monkeypatch.setattr(
-        importlib.import_module("llmss_tpu.ops.attention"),
-        "pallas_interpret", lambda: False,
-    )
     cfg = config_from_hf(
         types.SimpleNamespace(**KANANA_2_30B_A3B), dtype="bfloat16"
     )
-    compiled, pool = _compile_step(v5e, program, cfg, 5120)
+    compiled, pool = _compile_step(v5e, program, cfg, 5120, as_tpu=True)
     assert pool == (3, 64 * 320, 16, 640)
     text = compiled.as_text()
     assert _pool_sized_copies(text, pool) == []
@@ -656,7 +659,7 @@ def test_latent_step_program_carries_the_pool_in_place(
 
 
 @pytest.mark.parametrize("program", ["decode", "ragged"])
-def test_state_pool_is_updated_where_it_lies(v5e, monkeypatch, program):
+def test_state_pool_is_updated_where_it_lies(v5e, program):
     """``falcon-h1-34b-1chip``'s decode and mixed groups as a TPU traces
     them (``state_update`` says ``ssm.kernel``): ONE custom call in the layer
     scan's body takes the state pool ``f32[5,64,32,128,256]`` straight from
@@ -664,14 +667,9 @@ def test_state_pool_is_updated_where_it_lies(v5e, monkeypatch, program):
     the pool), and nothing else in the program produces the pool's shape, a
     layer's (the slice the XLA path copies out, 0.27 GB), or the grouped
     form the oracle computes on."""
-    import importlib
-
-    # the program asks jax.default_backend(), which is the CPU here
-    monkeypatch.setattr(
-        importlib.import_module("llmss_tpu.ops.attention"),
-        "pallas_interpret", lambda: False,
+    compiled, _ = _compile_group(
+        v5e, program, "falcon-h1-34b-1chip", as_tpu=True
     )
-    compiled, _ = _compile_group(v5e, program, "falcon-h1-34b-1chip")
     text = compiled.as_text()
     state = r"f32\[(?:5,64,32|1,64,32|64,32|64,2,16),128,256\]"
     made = [
@@ -700,7 +698,7 @@ def test_state_pool_is_updated_where_it_lies(v5e, monkeypatch, program):
     ids=["olmo-hybrid", "qwen3-next"],
 )
 def test_delta_rule_state_is_updated_where_it_lies(
-    v5e, monkeypatch, program, config, pool, calls,
+    v5e, program, config, pool, calls,
 ):
     """Cells 4 and 5's decode and mixed groups as a TPU traces them
     (``state_update`` says ``gdn.kernel``): the period's three linear layers
@@ -709,14 +707,7 @@ def test_delta_rule_state_is_updated_where_it_lies(
     no ``copy`` of the pool), and nothing else in the program produces the
     pool's shape or a layer's (the slice the XLA path copies out, 0.19 and
     0.13 GB, its update back, the passes between)."""
-    import importlib
-
-    # the program asks jax.default_backend(), which is the CPU here
-    monkeypatch.setattr(
-        importlib.import_module("llmss_tpu.ops.attention"),
-        "pallas_interpret", lambda: False,
-    )
-    compiled, _ = _compile_group(v5e, program, config)
+    compiled, _ = _compile_group(v5e, program, config, as_tpu=True)
     text = compiled.as_text()
     layer = ",".join(map(str, pool[1:]))
     state = rf"f32\[(?:{pool[0]},|1,)?{layer}\]"
@@ -737,13 +728,28 @@ def test_delta_rule_state_is_updated_where_it_lies(
 
 
 def test_supports_refuses_what_vmem_cannot_hold():
-    """``supports()`` and the compiler agree: a K/V block pair that cannot
-    fit the kernels' VMEM budget is refused up front (float32 at GPT-J
-    widths needs a 128-slot chunk of 32 KB slots, double-buffered, x2)."""
-    assert pallas_decode._pick_block_k(1024, 16, 256, jnp.bfloat16) == 256
-    assert pallas_decode.supports(1024, 16, 16, 256, jnp.float32)
-    assert not pallas_decode.supports(1024, 64, 64, 256, jnp.float32)
-    assert not pallas_paged_decode.supports(2048, 64, 64, 256, jnp.float32)
+    """``supports()`` and the compiler agree: shapes whose working set
+    cannot fit a kernel's VMEM budget, or that it cannot tile, are refused
+    up front."""
+    # the read of keys and values: 64 heads of 256 overflow the budget at
+    # the narrowest chunk; a head of 64 is not whole lanes; a 16-bit pool's
+    # heads come out of their words in pairs; 16 fresh tokens a row at most
+    assert pallas_kv.chunk_slots(BS, 16, 16, 256, 4, DT) == 128
+    assert pallas_kv.supports(BS, 16, 16, 256, 4, jnp.float32)
+    assert not pallas_kv.supports(BS, 64, 64, 256, 1, DT)
+    assert not pallas_kv.supports(BS, 14, 2, 64, 1, DT)
+    assert not pallas_kv.supports(BS, 9, 3, 128, 1, DT)
+    assert not pallas_kv.supports(BS, 16, 1, 128, 32, DT)
+    assert not pallas_kv.supports(BS, 16, 1, 128, 1, jnp.int8)
+    # the selected read: the same walk with a query a bit of a 32-bit word
+    assert pallas_dsa.supports(BS, 32, 4, 128, 32, DT)
+    assert not pallas_dsa.supports(BS, 32, 4, 128, 33, DT)
+    assert not pallas_dsa.supports(BS, 64, 64, 256, 1, DT)
+    # the prefill's flash kernel: a chunk of 16 queries or more over
+    # lane-friendly lengths (a decode step stays with XLA)
+    assert pallas_attention.supports(S, T, 32, 8)
+    assert not pallas_attention.supports(1, T, 32, 8)
+    assert not pallas_attention.supports(S, T + 1, 32, 8)
     # the latent read: 256 query rows of 640 fit, 2048 do not
     assert pallas_mla.supports(BS, 32, 640, 8, DT, 512)
     assert not pallas_mla.supports(BS, 128, 640, 16, DT, 512)
@@ -756,6 +762,82 @@ def test_supports_refuses_what_vmem_cannot_hold():
     # [128, 16384] do not
     assert pallas_gdn.supports(32, 128, 128, 8)
     assert not pallas_gdn.supports(32, 128, 16384, 8)
+
+
+# What reads a paged pool in a decode step and in a mixed step of ``chunk``
+# tokens a row, as a TPU traces them, for every file under benchmark/configs/
+# (on one device and, where the file's mesh is wider, on that mesh) and this
+# file's three width sets: PERF.md section 3's account, and the document of
+# what still reads through the gather.
+POOL_READS = {
+    # name: (chunk, decode step, mixed step[, both on the file's own mesh])
+    "starcoderbase-1b": (4, "kv.kernel", "kv.kernel"),
+    # ``starcoderbase-1b.complete-sat``'s chunk: over the kernel's 16 tokens
+    "starcoderbase-1b@64": (64, "kv.kernel", "gather"),
+    "falcon-h1-34b-1chip": (4, "kv.kernel", "kv.kernel"),
+    "kanana-2-30b-a3b-1chip": (8, "mla.kernel", "mla.kernel"),
+    "olmo-hybrid-7b-1chip": (4, "kv.kernel", "kv.kernel"),
+    "qwen3-next-80b-a3b-1chip": (8, "kv.kernel", "kv.kernel"),
+    "keye-vl-2.0-30b-a3b-1chip": (32, "dsa.kernel", "dsa.kernel"),
+    "gpt-j-6b-l16": (4, "kv.kernel", "kv.kernel"),
+    "starcoder-15b-tp4": (4, "kv.kernel", "kv.kernel", "gather"),
+    "widths:starcoderbase-1b": (4, "kv.kernel", "kv.kernel"),
+    "widths:mistral-7b": (4, "kv.kernel", "kv.kernel"),
+    "widths:gpt-j-6b": (4, "kv.kernel", "kv.kernel"),
+}
+READS = {"gather", "kv.kernel", "mla.kernel", "dsa.kernel", "dsa.tokens",
+         "dsa.mask"}
+
+
+def test_every_configuration_file_has_its_read_in_the_table():
+    configs = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmark", "configs",
+    )
+    files = {f[:-len(".json")] for f in os.listdir(configs)}
+    assert files == {n.split("@")[0] for n in POOL_READS if ":" not in n}
+    assert {n[len("widths:"):] for n in POOL_READS if ":" in n} == set(WIDTHS)
+    assert {w for row in POOL_READS.values() for w in row[1:]} <= READS
+
+
+@pytest.mark.parametrize("name", POOL_READS)
+def test_attn_read_names_the_read_of_every_configuration(v5e, name):
+    """``attn_read`` on one device, as a TPU, for a decode step and for the
+    cell's chunk, says the read the table names and never a word outside the
+    six; on a wider mesh of the file's own, the gather. On the CPU, with
+    nothing pinned, no kernel is chosen."""
+    from jax.experimental import topologies
+
+    chunk, decode, mixed, *on_mesh = POOL_READS[name]
+    if name.startswith("widths:"):
+        hf = {"serve": {"rows": ROWS, "max_seq_len": POSITIONS}}
+        cfg = _widths_config(*WIDTHS[name[len("widths:"):]])
+    else:
+        hf, cfg = _bench_config(name.split("@")[0])
+    cache, _ = _cache_shapes(
+        cfg, jax.ShapeDtypeStruct, hf["serve"]["max_seq_len"],
+        hf["serve"]["rows"],
+    )
+    mesh = mesh_mod.make_mesh(mesh_mod.MeshPlan(tp=1), devices=[v5e])
+    on_cpu = {attn_read(cfg, cache, mesh, c) for c in (1, chunk)}
+    assert on_cpu <= {"gather", "dsa.tokens", "dsa.mask"}, on_cpu
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(
+            importlib.import_module("llmss_tpu.ops.attention"),
+            "pallas_interpret", lambda: False,
+        )
+        assert attn_read(cfg, cache, mesh, 1) == decode
+        assert attn_read(cfg, cache, mesh, chunk) == mixed
+        assert attn_read(cfg, cache, None, chunk) == mixed
+        if on_mesh:
+            tp = hf["mesh"]["tp"]
+            wide = mesh_mod.make_mesh(
+                mesh_mod.MeshPlan(tp=tp),
+                devices=topologies.get_topology_desc(
+                    platform="tpu", topology_name="v5e:2x2").devices[:tp],
+            )
+            assert {attn_read(cfg, cache, wide, c) for c in (1, chunk)} == {
+                on_mesh[0]}
 
 
 @pytest.fixture

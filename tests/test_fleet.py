@@ -546,8 +546,12 @@ def test_fleet_chaos_kill_mid_decode(kind):
     leased work; the machine never comes back. Failover + lease
     redelivery must get every request exactly one terminal response with
     an uncorrupted payload — zero lost, zero double-answered."""
+    # A lease of 1 s: a LIVE replica that stalls past its lease (six xdist
+    # workers compiling beside this test: 0.25 s was twice not enough in PR
+    # 49's full runs) has its work redelivered and answers twice, which is
+    # the at-least-once contract and not what this test is about.
     producer, mk = make_brokers(
-        kind, lease_s=0.25, max_delivery_attempts=6,
+        kind, lease_s=1.0, max_delivery_attempts=6,
     )
     wids = ["w0", "w1", "w2"]
     switches = {wid: threading.Event() for wid in wids}
@@ -559,11 +563,11 @@ def test_fleet_chaos_kill_mid_decode(kind):
             worker_id=wid, snapshot_interval_s=0.04,
         )
 
-    # stale_factor 10 × 0.04s heartbeats: a live replica would have to
-    # stall 0.4s to be misjudged (heartbeats refresh every decode chunk),
+    # stale_factor 25 × 0.04s heartbeats: a live replica would have to
+    # stall 1s to be misjudged (heartbeats refresh every decode chunk),
     # while the killed one reads stale well inside the test budget.
     router = Router(
-        producer, "least_loaded", stale_factor=10.0, failover_check_s=0.05,
+        producer, "least_loaded", stale_factor=25.0, failover_check_s=0.05,
     )
     reqs = [req(i) for i in range(18)]
     stop_pump = threading.Event()

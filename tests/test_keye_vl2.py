@@ -599,12 +599,16 @@ def test_batcher_rows_match_isolated_and_count_the_selection(mesh, chunk):
     is freed and re-admitted, groups run with rows that are done. Each
     request's tokens equal its own alone; no executable compiles after
     prewarm; /metrics counts what the indexer scored and kept over the live
-    rows, and the bytes a token holds in the third pool."""
+    rows, and the bytes a token holds in the third pool. The dedicated
+    admission's prompts share ONE bucket (16: a row decodes from inside
+    ``topk`` to past it), the only one prewarmed: a prefill program an
+    admission count, not four (PR 49)."""
     eng = engine_of(mesh, held="a_share")
-    prompts = prompts_of([21, 40, 37, 9, 30], seed=2)
+    lens = [21, 40, 37, 9, 30] if chunk else [9, 14, 12, 16, 11]
+    prompts = prompts_of(lens, seed=2)
     expected = [eng.generate([p], g)[0] for p, g in zip(prompts, FIVE)]
     batcher = ContinuousBatcher(eng, rows=2, chunked_prefill=chunk)
-    batcher.prewarm()
+    batcher.prewarm(seq_buckets=[16])
     before = dict(eng.metrics.to_dict()["loop"])
     compiled = []
     jax.monitoring.register_event_duration_secs_listener(
@@ -637,24 +641,26 @@ def test_a_mixed_step_works_one_turn_of_feeding_rows(mesh, monkeypatch, read):
     eng = make_engine(mesh, hf=hf_of("a_share", read))
     prompts = prompts_of([21, 40, 37, 9, 30], seed=2)
     expected = [eng.generate([p], g)[0] for p, g in zip(prompts, FIVE)]
-    if read == "kernel":
-        eng = make_engine(mesh, hf=hf_of("a_share", read), params=eng.params)
-        monkeypatch.setattr(attention_mod, "IMPL_OVERRIDE", "pallas")
-        cache = eng.new_paged_cache(4)
-        assert decoder.attn_read(eng.cfg, cache, mesh, 8) == "dsa.kernel"
-    batcher = ContinuousBatcher(eng, rows=4, chunked_prefill=8)
-    assert batcher._feed_rows == 1
-    assert decoder.feed_rows(eng.cfg, batcher.cache, 8) == 1
-    feeding = []
-    plan = batcher._plan_ragged
+    with attention_mod.force_impl("pallas" if read == "kernel" else None):
+        if read == "kernel":
+            eng = make_engine(
+                mesh, hf=hf_of("a_share", read), params=eng.params
+            )
+            cache = eng.new_paged_cache(4)
+            assert decoder.attn_read(eng.cfg, cache, mesh, 8) == "dsa.kernel"
+        batcher = ContinuousBatcher(eng, rows=4, chunked_prefill=8)
+        assert batcher._feed_rows == 1
+        assert decoder.feed_rows(eng.cfg, batcher.cache, 8) == 1
+        feeding = []
+        plan = batcher._plan_ragged
 
-    def watched(*a, **k):
-        feeding.append(len(batcher._inflight_prefill))
-        return plan(*a, **k)
+        def watched(*a, **k):
+            feeding.append(len(batcher._inflight_prefill))
+            return plan(*a, **k)
 
-    monkeypatch.setattr(batcher, "_plan_ragged", watched)
-    assert run_batcher(batcher, prompts, FIVE) == expected
-    assert feeding and max(feeding) == 1
+        monkeypatch.setattr(batcher, "_plan_ragged", watched)
+        assert run_batcher(batcher, prompts, FIVE) == expected
+        assert feeding and max(feeding) == 1
     # the budget as shipped holds all four rows: no cap
     monkeypatch.undo()
     assert ContinuousBatcher(eng, rows=4, chunked_prefill=8)._feed_rows is None

@@ -312,7 +312,7 @@ def feed_mixed(engine, prompts, CB):
     return np.stack([final[i] for i in range(B)]), seqs, cache
 
 
-@pytest.mark.filterwarnings("ignore:LLMSS_ATTN_IMPL=pallas")
+@pytest.mark.filterwarnings("ignore:pallas forced")
 @pytest.mark.parametrize("path", ["xla", "pallas"])
 def test_the_mixed_step_matches_the_reference(engine, path):
     """Logits, not tokens: prompts fed through ``forward_ragged`` four
@@ -325,7 +325,7 @@ def test_the_mixed_step_matches_the_reference(engine, path):
     assert err(got, ref_logits(engine.params, seqs)) < TOL["float32"]
 
 
-@pytest.mark.filterwarnings("ignore:LLMSS_ATTN_IMPL=pallas")
+@pytest.mark.filterwarnings("ignore:pallas forced")
 @pytest.mark.parametrize("chunk", [4, 8])
 def test_the_kernel_leaves_the_pools_the_xla_path_leaves(engine, chunk):
     """The same mixed steps on both paths: the logits, the state pool and
@@ -345,7 +345,7 @@ def test_the_kernel_leaves_the_pools_the_xla_path_leaves(engine, chunk):
         )
 
 
-@pytest.mark.filterwarnings("ignore:LLMSS_ATTN_IMPL=pallas")
+@pytest.mark.filterwarnings("ignore:pallas forced")
 def test_cached_steps_match_reference_through_the_kernel(mesh):
     """The decode step with each linear layer's state updated where it lies
     (the kernel at one position, interpreted): steps 1, 2 and 16 against
@@ -358,7 +358,7 @@ def test_cached_steps_match_reference_through_the_kernel(mesh):
     assert max(errors.values()) < TOL["float32"], errors
 
 
-@pytest.mark.filterwarnings("ignore:LLMSS_ATTN_IMPL=pallas")
+@pytest.mark.filterwarnings("ignore:pallas forced")
 def test_mixed_and_decode_groups_update_the_pool_in_place(mesh, engine):
     """Five requests through two rows, prompts streamed 4 tokens a row a
     step beside rows that decode, rows done beside rows live, rows freed and

@@ -531,86 +531,6 @@ def test_kv_gauges_flow_to_producer_metrics(paged_engine):
     assert got["kv_blocks_in_use"] == d["kv_blocks_in_use"]
 
 
-# -- Pallas ragged block-table kernel ---------------------------------------
-
-
-def test_pallas_paged_kernel_matches_xla_oracle(devices):
-    """Direct kernel parity (interpret mode): the Pallas grid
-    (rows x blocks) flash loop over block tables must match the XLA
-    gather-based paged attention on ragged row lengths."""
-    from llmss_tpu.ops.attention import (
-        paged_decode_attention as xla_paged,
-    )
-    from llmss_tpu.ops.pallas_paged_decode import (
-        paged_decode_attention as pallas_paged, supports,
-    )
-
-    B, MB, bs, Hq, Hkv, D, N = 2, 4, 16, 4, 2, 128, 8
-    assert supports(bs, Hq, Hkv, D, jnp.float32)
-    rng = np.random.default_rng(3)
-    k_pool = jnp.asarray(
-        rng.standard_normal((N, bs, Hkv, D)) * 0.3, jnp.float32
-    )
-    v_pool = jnp.asarray(
-        rng.standard_normal((N, bs, Hkv, D)) * 0.3, jnp.float32
-    )
-    q = jnp.asarray(rng.standard_normal((B, 1, Hq, D)) * 0.3, jnp.float32)
-    kn = jnp.asarray(rng.standard_normal((B, 1, Hkv, D)) * 0.3, jnp.float32)
-    vn = jnp.asarray(rng.standard_normal((B, 1, Hkv, D)) * 0.3, jnp.float32)
-    tables = jnp.asarray([[4, 2, 7, 1], [0, 5, 3, 6]], jnp.int32)
-    # ragged: row 0 has 19 tokens (2 blocks), row 1 has 40 (3 blocks)
-    occ = np.full((B, MB * bs), -1, np.int32)
-    occ[0, :19] = np.arange(19)
-    occ[1, :40] = np.arange(40)
-    kv_pos = jnp.asarray(occ)
-    q_pos = jnp.asarray([19, 40], jnp.int32)
-    slots = q_pos  # append position == logical slot
-    nblk = jnp.asarray([2, 3], jnp.int32)
-
-    want = xla_paged(
-        q, k_pool, v_pool, kn, vn, q_pos[:, None], kv_pos, tables,
-        slots[:, None],
-    )
-    got = pallas_paged(
-        q, k_pool[None], v_pool[None], kn, vn, q_pos, kv_pos, tables,
-        nblk, slots, jnp.int32(0), interpret=True,
-    )
-    np.testing.assert_allclose(
-        np.asarray(got, np.float32), np.asarray(want, np.float32),
-        rtol=2e-5, atol=2e-5,
-    )
-
-
-def test_paged_forward_kernel_vs_xla_integration(devices):
-    """Full fused decode with the paged Pallas kernel forced on
-    (IMPL_OVERRIDE='pallas', interpret): same greedy tokens as the paged
-    XLA gather path AND the dense engine."""
-    attn_mod = importlib.import_module("llmss_tpu.ops.attention")
-    cfg = _cfg(
-        vocab_size=128, hidden_size=256, n_heads=8, n_kv_heads=4,
-        head_dim=128, intermediate_size=128, rotary_dim=128,
-    )
-    mesh = make_mesh(MeshPlan(dp=2, tp=4))
-    params = init_params(cfg, mesh, jax.random.key(3))
-    gen = GenerationParams(max_new_tokens=8, is_greedy=True)
-
-    outs = {}
-    old = attn_mod.IMPL_OVERRIDE
-    for impl in ("xla", "pallas"):
-        attn_mod.IMPL_OVERRIDE = impl
-        try:
-            eng = DecodeEngine(
-                cfg, params, mesh, max_seq_len=64, kv_layout="paged",
-                block_size=16,
-            )
-            outs[impl] = eng.generate_fused(PROMPTS, gen)
-        finally:
-            attn_mod.IMPL_OVERRIDE = old
-    dense = DecodeEngine(cfg, params, mesh, max_seq_len=64)
-    outs["dense"] = dense.generate_fused(PROMPTS, gen)
-    assert outs["xla"] == outs["pallas"] == outs["dense"], outs
-
-
 def test_batcher_paged_grouped_matches_dense(dense_engine, paged_engine):
     """Grouped dispatch rides the paged layout unchanged: a paged batcher
     at group_chunks>1 must produce every request's solo dense tokens, with

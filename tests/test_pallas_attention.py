@@ -88,7 +88,7 @@ def test_supports_gating():
 
 
 def test_sharded_dispatch_matches_xla(devices):
-    """dispatch_attention under IMPL_OVERRIDE='pallas' runs the kernel inside
+    """dispatch_attention under force_impl("pallas") runs the kernel inside
     shard_map over dp×tp on the CPU mesh and must match the XLA path."""
     from llmss_tpu.parallel import MeshPlan, make_mesh
 
@@ -105,17 +105,13 @@ def test_sharded_dispatch_matches_xla(devices):
     mask = make_causal_mask(q_pos, kv_pos, kv_pos >= 0)
 
     ref = attention(q, k, v, mask)
-    old = attn_mod.IMPL_OVERRIDE
-    attn_mod.IMPL_OVERRIDE = "pallas"
-    try:
+    with attn_mod.force_impl("pallas"):
         out = jax.jit(
             lambda q, k, v: attn_mod.dispatch_attention(
                 q, k, v, mask=mask, q_positions=q_pos, kv_positions=kv_pos,
                 mesh=mesh,
             )
         )(q, k, v)
-    finally:
-        attn_mod.IMPL_OVERRIDE = old
     np.testing.assert_allclose(out, ref, atol=2e-2)
 
 
@@ -177,17 +173,13 @@ def test_gqa_replicated_kv_falls_back(devices):
     pos = jnp.asarray(np.broadcast_to(np.arange(T), (B, T)), jnp.int32)
     mask = make_causal_mask(pos, pos, pos >= 0)
     ref = attention(q, k, v, mask)
-    old = attn_mod.IMPL_OVERRIDE
-    attn_mod.IMPL_OVERRIDE = "pallas"
-    try:
+    with attn_mod.force_impl("pallas"):
         out = jax.jit(
             lambda q, k, v: attn_mod.dispatch_attention(
                 q, k, v, mask=mask, q_positions=pos, kv_positions=pos,
                 mesh=mesh,
             )
         )(q, k, v)
-    finally:
-        attn_mod.IMPL_OVERRIDE = old
     np.testing.assert_allclose(out, ref, atol=1e-5)
 
 
@@ -214,11 +206,7 @@ def test_engine_generate_with_pallas_attention(devices):
     engine = DecodeEngine(cfg, params, mesh, max_seq_len=64)
     ref = engine.generate(prompts, gen)
 
-    old = attn_mod.IMPL_OVERRIDE
-    attn_mod.IMPL_OVERRIDE = "pallas"
-    try:
+    with attn_mod.force_impl("pallas"):
         engine2 = DecodeEngine(cfg, params, mesh, max_seq_len=64)
         out = engine2.generate(prompts, gen)
-    finally:
-        attn_mod.IMPL_OVERRIDE = old
     assert out == ref

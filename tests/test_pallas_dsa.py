@@ -29,6 +29,9 @@ KEYE = (32, 4, 128)  # the cell's heads: G = 8, 256 query rows at chunk 32
 
 # Each case: the heads, the chunk, ``topk``, the tokens each row has been fed
 # so far (``ctx``; more than RING: the ring has wrapped) and its live queries.
+# A case reads the LAST layer of the stack (interpretation costs by the grid
+# step, and a walk of 1,152 slots is 288 of them); ``layers`` says otherwise
+# where every layer is read, so that the layer index is held too.
 CASES = {
     # decoding, feeding, done and empty (padding) rows in one call
     "mixed-step": dict(cb=8, topk=128, ctx=[300, 1040, 77, 0],
@@ -37,7 +40,7 @@ CASES = {
                                         qlen=[3, 1, 8, 5]),
     # a context under topk keeps all it sees; one over it drops most
     "under-topk": dict(cb=8, topk=128, ctx=[45, 100, 127, 129],
-                       qlen=[8, 1, 8, 8]),
+                       qlen=[8, 1, 8, 8], layers=range(L)),
     # every cached indexer key the same: their scores tie, the earliest win
     "tie": dict(cb=4, topk=64, ctx=[300, 200, 70, 64], qlen=[4, 1, 4, 2],
                 tie=True),
@@ -51,7 +54,7 @@ CASES = {
     "decode-step": dict(cb=1, topk=128, ctx=[300, 45, 1025, 600],
                         qlen=[1, 1, 1, 1]),
     "decode-step-wrapped": dict(cb=1, topk=128, ctx=[1300, 0, 2303, 1152],
-                                qlen=[1, 1, 1, 1]),
+                                qlen=[1, 1, 1, 1], layers=range(L)),
     "decode-step-bucketed": dict(cb=1, topk=128, ctx=[300, 45, 600, 77],
                                  qlen=[1, 1, 1, 1], t_bucket=608),
     # what the chip serves: the cell's heads and chunk, bfloat16 pools
@@ -122,7 +125,7 @@ def test_kernel_matches_the_oracles(name):
     nb = -(-T // BS) if T < RING else None
     assert pallas_dsa.supports(BS, Hq, Hkv, D, cb, x["k"].dtype)
     vis = attn.ragged_cache_visibility(x["q_len"], x["kv_pos"], x["slot0"], RING)
-    for layer in range(L):
+    for layer in case.get("layers", (L - 1,)):
         views = [
             gather_block_view(pool, x["bt"], nb, layer)
             for pool in (x["k"], x["v"], x["idx"])

@@ -23,9 +23,9 @@ from llmss_tpu.utils import trace
 
 attn = importlib.import_module("llmss_tpu.ops.attention")
 
-L, BS = 2, 16
-B, MB = 4, 72
-RING = MB * BS  # 1,152 slots: four 256-slot chunks of the walk and a half
+BS = 16
+# 72 blocks a row unless a case says: 1,152 slots, four 256-slot chunks of
+# the walk and a half; four rows and two layers unless a case says
 N = 96
 SENTINEL = N + 5
 
@@ -37,6 +37,9 @@ MQA_1x128 = (16, 1, 128)  # starcoderbase: one head, G = 16
 
 # Each case: the heads, the chunk budget, the tokens each row has been fed
 # so far (``ctx``; more than RING: the ring has wrapped) and its live queries.
+# A case over the long ring reads the LAST layer of its stack (interpretation
+# costs by the grid step); the cases over a short ring (``mb``) read every
+# layer, and hold that each layer index reads its own layer.
 CASES = {
     "gqa-hkv2-d256-chunk8": dict(
         heads=GQA_2x256, cb=8, ctx=[300, 45, 1040, 77], qlen=[0, 1, 8, 3]
@@ -99,6 +102,31 @@ CASES = {
         heads=MHA_32x128, cb=1, ctx=[300, 0, 513, 1500], qlen=[1, 1, 1, 1],
         dtype=jnp.bfloat16, tol=2e-2,
     ),
+    # a ring of two blocks (``mb``), shorter than one chunk of the walk: the
+    # rows end inside a block (20, 30), at its edge (16) and past the ring
+    # (40: wrapped); the same with one KV head, under a window of 8, and a
+    # mixed step whose chunk crosses a block's edge and the ring's end
+    "ring-of-32-mha-decode": dict(
+        heads=(4, 4, 128), cb=1, mb=2, ctx=[20, 30, 40, 16], qlen=[1] * 4
+    ),
+    "ring-of-32-mqa-decode": dict(
+        heads=(4, 1, 128), cb=1, mb=2, ctx=[20, 0, 40, 31], qlen=[1] * 4
+    ),
+    "ring-of-32-window-8": dict(
+        heads=(4, 4, 128), cb=1, mb=2, ctx=[30, 5, 40, 8], qlen=[1] * 4,
+        window=8,
+    ),
+    "ring-of-32-chunk4": dict(
+        heads=(4, 2, 128), cb=4, mb=2, ctx=[13, 0, 27, 30], qlen=[3, 4, 1, 4]
+    ),
+    # one row alone, its ring of four blocks full: 8 query heads over 2
+    "one-row-gqa-8-over-2": dict(
+        heads=(8, 2, 128), cb=1, mb=4, ctx=[64], qlen=[1]
+    ),
+    # three rows of a stack of three layers (every case reads each layer)
+    "three-layers-three-rows": dict(
+        heads=(8, 2, 128), cb=1, mb=4, layers=3, ctx=[13, 5, 27], qlen=[1] * 3
+    ),
 }
 
 
@@ -107,6 +135,8 @@ def _inputs(case, seed=0):
     (Hq, Hkv, D), cb = case["heads"], case["cb"]
     dtype = case.get("dtype", jnp.float32)
     ctx, qlen = np.asarray(case["ctx"]), np.asarray(case["qlen"])
+    B, MB, L = len(ctx), case.get("mb", 72), case.get("layers", 2)
+    RING = MB * BS
     # every row its own blocks, interleaved so a row's are not contiguous
     bt = (np.arange(MB)[None, :] * 2 + np.arange(B)[:, None] * 3) % N
     bt = bt.astype(np.int32)
@@ -145,10 +175,12 @@ def test_kernel_matches_the_oracle(name):
     (Hq, Hkv, D), cb = case["heads"], case["cb"]
     window, scale = case.get("window"), D ** -0.5
     tol = case.get("tol", 2e-5)  # float32: that of tests/test_ragged.py
-    T = x["kv_pos"].shape[1]
+    T, (L, B) = x["kv_pos"].shape[1], (x["k"].shape[0], x["q"].shape[0])
+    RING = x["bt"].shape[1] * BS
     nb = -(-T // BS) if T < RING else None
     assert pallas_kv.supports(BS, Hq, Hkv, D, cb, x["k"].dtype)
-    for layer in range(L):
+    outs = []
+    for layer in (range(L) if "mb" in case else (L - 1,)):
         got = pallas_kv.kv_paged_attention(
             x["q"], x["k"], x["v"], x["kn"], x["vn"], x["q_pos"], x["q_len"],
             x["kv_pos"], x["bt"], x["nblk"], x["slot0"], jnp.int32(layer),
@@ -176,6 +208,10 @@ def test_kernel_matches_the_oracle(name):
             np.testing.assert_allclose(
                 got, np.asarray(dec, np.float32), rtol=tol, atol=tol
             )
+        outs.append(got)
+    if len(outs) > 1:  # each layer index read its own layer of the stack
+        live = (np.asarray(case["qlen"]) > 0) & (np.asarray(case["ctx"]) > 0)
+        assert not np.allclose(outs[0][live, 0], outs[-1][live, 0], atol=1e-3)
 
 
 def test_the_chunk_width_follows_the_slot_bytes():
@@ -222,10 +258,18 @@ GQA = DecoderConfig(
 )
 
 
-def _engine(mesh, **kw):
-    params = init_params(GQA, mesh, jax.random.key(3))
+# the same with one KV head, and under a window shorter than the prompts
+MODELS = {
+    "gqa": GQA,
+    "mqa": dataclasses.replace(GQA, n_kv_heads=1),
+    "sliding-window": dataclasses.replace(GQA, sliding_window=8),
+}
+
+
+def _engine(mesh, cfg=GQA, **kw):
+    params = init_params(cfg, mesh, jax.random.key(3))
     return DecodeEngine(
-        GQA, params, mesh, max_seq_len=64, kv_layout="paged", block_size=8,
+        cfg, params, mesh, max_seq_len=64, kv_layout="paged", block_size=8,
         **kw,
     )
 
@@ -235,7 +279,10 @@ def one_device(devices):
     return make_mesh(MeshPlan(tp=1), devices=devices[:1])
 
 
-def test_greedy_tokens_equal_under_the_kernel_and_the_gather(one_device):
+@pytest.mark.parametrize("model", MODELS)
+def test_greedy_tokens_equal_under_the_kernel_and_the_gather(
+    one_device, model,
+):
     """Prompts streamed through the mixed step (4 tokens a row a step)
     beside rows that decode, then decode groups alone: the same greedy
     tokens whether the step programs read the pool through ``kv.kernel``
@@ -248,7 +295,7 @@ def test_greedy_tokens_equal_under_the_kernel_and_the_gather(one_device):
     for impl in ("xla", "pallas"):
         trace.recorder().clear()
         with attn.force_impl(impl):
-            eng = _engine(one_device)
+            eng = _engine(one_device, MODELS[model])
             b = ContinuousBatcher(
                 eng, rows=2, chunk_steps=2, group_chunks=2, chunked_prefill=4
             )
@@ -265,6 +312,103 @@ def test_greedy_tokens_equal_under_the_kernel_and_the_gather(one_device):
             assert 0 <= a["blocks_read"] <= a["blocks_ring"]
     assert reads == {"xla": {"gather"}, "pallas": {"kv.kernel"}}
     assert outs["xla"] == outs["pallas"], outs
+
+
+@pytest.mark.filterwarnings("ignore:pallas forced")
+def test_a_mixed_step_of_one_token_a_row_is_the_decode_step(one_device):
+    """Through ``kv.kernel`` (forced, interpreted) a group of mixed steps in
+    which every row feeds nothing and decodes one token IS the decode group:
+    the same tokens, and both pools equal BIT FOR BIT afterwards (every
+    layer's fresh keys and values come out of the read below them), at a
+    chunk budget of one token a row and of four."""
+    nB, nc = 4, 5
+    prompts = [[5, 9, 23, 40], [3, 14, 15, 9], [7, 7, 7, 7], [1, 2, 3, 4]]
+    gen = GenerationParams(max_new_tokens=8, is_greedy=True)
+    eos = jnp.full(nB, -1, jnp.int32)
+
+    def start(eng):
+        lens = jnp.asarray([len(p) for p in prompts], jnp.int32)
+        sa = eng._sample_args([gen] * nB, nB)
+        cache = eng.new_paged_cache(nB, num_blocks=64, identity=True)
+        tok, _, cache = eng._prefill(
+            eng.params, jnp.asarray(prompts, jnp.int32), cache, lens, sa
+        )
+        # ``lens`` is donated into the group: a copy of its own each
+        return tok, cache, lens + 0, sa, jnp.zeros(nB, bool), eos
+
+    with attn.force_impl("pallas"):
+        eng = _engine(one_device)
+        tok, cache, cur, sa, done, _ = start(eng)
+        assert decoder.attn_read(GQA, cache, one_device, 1) == "kv.kernel"
+        packed, _, want, cur_d, _ = eng._decode_group(
+            eng.params, tok, cache, cur, sa, done, eos,
+            n_chunks=nc, n_steps=1, t_bucket=None,
+        )
+        want_toks = np.asarray(packed)[: nc * nB]
+        want_k, want_v = np.asarray(want.k), np.asarray(want.v)
+        for cb in (1, 4):
+            tok, cache, cur, sa, done, _ = start(eng)
+            packed, _, got, cur_r, _ = eng._ragged_group(
+                eng.params, tok, cache, cur, sa, done, eos,
+                jnp.zeros((nc, nB, cb), jnp.int32),
+                jnp.ones((nc, nB), jnp.int32), jnp.zeros((nc, nB), bool),
+                jnp.ones((nc, nB), bool),
+            )
+            assert np.array_equal(np.asarray(packed)[: nc * nB], want_toks)
+            assert np.array_equal(np.asarray(cur_r), np.asarray(cur_d))
+            assert np.array_equal(np.asarray(got.k), want_k), cb
+            assert np.array_equal(np.asarray(got.v), want_v), cb
+
+
+def test_nothing_in_the_environment_chooses_a_read():
+    """``LLMSS_ATTN_IMPL`` named an implementation until PR 49: a fresh
+    interpreter with it set imports ``ops.attention`` with nothing pinned,
+    and ``force_impl`` is the one way to pin, to ``xla`` or ``pallas``."""
+    import os
+    import subprocess
+    import sys
+
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import importlib; a = importlib.import_module("
+         "'llmss_tpu.ops.attention'); print(a.IMPL_OVERRIDE)"],
+        env={**os.environ, "LLMSS_ATTN_IMPL": "pallas", "JAX_PLATFORMS": "cpu"},
+        capture_output=True, text=True, check=True,
+    )
+    assert out.stdout.strip() == "None"
+    assert attn.IMPL_OVERRIDE is None
+    with pytest.raises(ValueError, match="xla"):
+        attn.force_impl("ring")
+    with attn.force_impl("pallas"):
+        assert attn.IMPL_OVERRIDE == "pallas"
+        with attn.force_impl(None):
+            assert attn.IMPL_OVERRIDE is None
+    assert attn.IMPL_OVERRIDE is None
+
+
+@pytest.mark.parametrize("what", ["tp-mesh", "int8-pool", "chunk-of-32"])
+def test_a_pinned_kernel_that_cannot_read_says_so(
+    one_device, devices, monkeypatch, what,
+):
+    """Under ``force_impl("pallas")`` a ``tp`` mesh, an int8 pool and a
+    chunk over the kernel's 16 tokens keep the gather: interpreted (here)
+    with a warning that names the shapes, compiled (a TPU) as an error: a
+    run under a kernel's name never measures another read."""
+    mesh = one_device
+    if what == "tp-mesh":
+        mesh = make_mesh(MeshPlan(tp=2), devices=devices[:2])
+    cache = _engine(
+        one_device, kv_dtype="int8" if what == "int8-pool" else None
+    ).new_paged_cache(2)
+    chunk = 32 if what == "chunk-of-32" else 1
+    with attn.force_impl("pallas"):
+        with pytest.warns(UserWarning, match="pallas forced: .*pool read"):
+            assert decoder.attn_read(GQA, cache, mesh, chunk) == "gather"
+        monkeypatch.setattr(attn, "pallas_interpret", lambda: False)
+        with pytest.raises(ValueError, match="pallas forced: .*pool read"):
+            decoder.attn_read(GQA, cache, mesh, chunk)
+    # nothing pinned: the program chooses, and says ``gather`` quietly
+    assert decoder.attn_read(GQA, cache, mesh, chunk) == "gather"
 
 
 def test_attn_read_chooses_the_kernel_by_what_it_can_see(
@@ -291,9 +435,7 @@ def test_attn_read_chooses_the_kernel_by_what_it_can_see(
     assert decoder.attn_read(GQA, quantized, one_device, 1) == "gather"
     tp = make_mesh(MeshPlan(tp=2), devices=devices[:2])
     assert decoder.attn_read(GQA, cache, tp, 1) == "gather"
-    # forced on a mesh the kernel does not serve: the block-at-a-time ones
     with attn.force_impl("pallas"):
-        assert decoder.attn_read(GQA, cache, tp, 1) == "kernel"
         assert decoder.attn_read(GQA, cache, one_device, 1) == "kv.kernel"
 
     # a model that selects what attention reads (``cfg.indexer``): the same

@@ -122,7 +122,7 @@ def _engine(mesh):
     )
 
 
-@pytest.mark.filterwarnings("ignore:LLMSS_ATTN_IMPL=pallas")
+@pytest.mark.filterwarnings("ignore:pallas forced")
 def test_mixed_and_decode_groups_update_the_pool_in_place(devices):
     """Five requests through two rows, prompts streamed 4 tokens a row a
     step beside rows that decode, rows done beside rows live, rows freed and

@@ -1,14 +1,11 @@
 """Ragged mixed prefill+decode dispatch (ISSUE 10).
 
-Three layers of evidence that one ragged program can serve rows at
+Two layers of evidence that one ragged program can serve rows at
 arbitrary positions — decode rows (``q_len == 1``) and mid-prefill rows
-(``q_len`` up to the chunk budget) in the same dispatch:
+(``q_len`` up to the chunk budget) in the same dispatch (the kernel that
+reads the pool in place under it, ``ops/pallas_kv.py``, has its parity
+suite in tests/test_pallas_kv.py):
 
-- **kernel**: ``ops.pallas_ragged`` (interpret mode) vs the XLA gather
-  oracle ``ops.attention.ragged_paged_attention`` — GQA/MQA, int8 KV with
-  scales, partial tail block, a chunk crossing a block boundary, an empty
-  cache; plus bit-for-bit identity with ``pallas_paged_decode`` when every
-  row is a decode row at ``CB == 1``;
 - **engine**: ``_ragged_group`` on an all-decode plan reproduces
   ``_decode_group`` token-for-token, and a chunked 32-token feed
   reproduces the ``_prefill`` + ``_decode_group`` stream;
@@ -31,149 +28,9 @@ from llmss_tpu.engine import DecodeEngine, GenerationParams
 from llmss_tpu.engine.scheduler import ContinuousBatcher
 from llmss_tpu.models.common import DecoderConfig
 from llmss_tpu.models.decoder import init_params
-from llmss_tpu.ops import pallas_paged_decode, pallas_ragged
 from llmss_tpu.parallel import MeshPlan, make_mesh
 
 attn = importlib.import_module("llmss_tpu.ops.attention")
-
-
-# --------------------------------------------------------------------------
-# Kernel vs oracle (no mesh; interpret mode on CPU)
-# --------------------------------------------------------------------------
-
-L, N, BS, HKV, D = 2, 16, 8, 2, 128
-HQ = 4
-B, MB, CB = 3, 4, 4
-RING = MB * BS
-
-# Row 0: partial tail block; row 1: empty cache, whole prompt in-chunk;
-# row 2: decode row whose chunk crosses a block boundary (27 + 1 = 28).
-CTX = np.array([13, 0, 27])
-QLEN = np.array([3, 4, 1])
-BT = np.asarray([[1, 5, 9, 13], [2, 6, 10, 14], [3, 7, 11, 15]], np.int32)
-
-
-def _ragged_inputs(rng, ctx, qlen, Hq=HQ, Hkv=HKV):
-    nblk = np.asarray(
-        [max(-(-int(c + q) // BS), 1) for c, q in zip(ctx, qlen)], np.int32
-    )
-    kv_pos = np.full((B, RING), -1, np.int32)
-    for b in range(B):
-        kv_pos[b, : ctx[b]] = np.arange(ctx[b])
-    q = jnp.asarray(rng.normal(size=(B, CB, Hq, D)), jnp.float32)
-    kn = jnp.asarray(rng.normal(size=(B, CB, Hkv, D)), jnp.float32)
-    vn = jnp.asarray(rng.normal(size=(B, CB, Hkv, D)), jnp.float32)
-    return (
-        q, kn, vn, jnp.asarray(ctx, jnp.int32),
-        jnp.asarray(qlen, jnp.int32), jnp.asarray(kv_pos),
-        jnp.asarray(BT), jnp.asarray(nblk),
-        jnp.asarray(ctx % RING, jnp.int32),
-    )
-
-
-def _assert_live_rows_close(got, want, qlen):
-    for b in range(B):
-        np.testing.assert_allclose(
-            np.asarray(got)[b, : qlen[b]], np.asarray(want)[b, : qlen[b]],
-            rtol=2e-5, atol=2e-5,
-        )
-
-
-def test_kernel_parity_vs_oracle_gqa():
-    """Mixed rows (partial tail / empty ctx / boundary-crossing chunk) on
-    every layer of the stacked pool match the XLA gather oracle."""
-    rng = np.random.default_rng(0)
-    k_pool = jnp.asarray(rng.normal(size=(L, N, BS, HKV, D)), jnp.float32)
-    v_pool = jnp.asarray(rng.normal(size=(L, N, BS, HKV, D)), jnp.float32)
-    q, kn, vn, q_pos, qlen, kv_pos, bt, nblk, slot0 = _ragged_inputs(
-        rng, CTX, QLEN
-    )
-    assert pallas_ragged.supports(BS, HQ, HKV, D, k_pool.dtype)
-    for layer in range(L):
-        got = pallas_ragged.ragged_paged_attention(
-            q, k_pool, v_pool, kn, vn, q_pos, qlen, kv_pos, bt, nblk,
-            slot0, jnp.int32(layer), interpret=True,
-        )
-        want = attn.ragged_paged_attention(
-            q, k_pool[layer], v_pool[layer], kn, vn, q_pos, qlen, kv_pos,
-            bt, slot0, RING,
-        )
-        _assert_live_rows_close(got, want, QLEN)
-
-
-def test_kernel_all_decode_bit_identity_vs_paged_decode():
-    """At CB == 1 with every q_len == 1 the ragged kernel IS the grouped
-    decode kernel: identical block loop, identical merge order, so the
-    outputs must match bit for bit (np.array_equal, not allclose)."""
-    rng = np.random.default_rng(0)
-    k_pool = jnp.asarray(rng.normal(size=(L, N, BS, HKV, D)), jnp.float32)
-    v_pool = jnp.asarray(rng.normal(size=(L, N, BS, HKV, D)), jnp.float32)
-    ctx = np.array([13, 5, 27])
-    kv_pos = np.full((B, RING), -1, np.int32)
-    for b in range(B):
-        kv_pos[b, : ctx[b]] = np.arange(ctx[b])
-    nblk = jnp.asarray([-(-int(c + 1) // BS) for c in ctx], jnp.int32)
-    q1 = jnp.asarray(rng.normal(size=(B, 1, HQ, D)), jnp.float32)
-    kn1 = jnp.asarray(rng.normal(size=(B, 1, HKV, D)), jnp.float32)
-    vn1 = jnp.asarray(rng.normal(size=(B, 1, HKV, D)), jnp.float32)
-    slots = jnp.asarray(ctx % RING, jnp.int32)
-    out_r = pallas_ragged.ragged_paged_attention(
-        q1, k_pool, v_pool, kn1, vn1, jnp.asarray(ctx, jnp.int32),
-        jnp.ones(B, jnp.int32), jnp.asarray(kv_pos), jnp.asarray(BT),
-        nblk, slots, jnp.int32(0), interpret=True,
-    )
-    out_d = pallas_paged_decode.paged_decode_attention(
-        q1, k_pool, v_pool, kn1, vn1,
-        jnp.asarray(ctx, jnp.int32).reshape(B, 1), jnp.asarray(kv_pos),
-        jnp.asarray(BT), nblk, slots.reshape(B, 1), jnp.int32(0),
-        interpret=True,
-    )
-    assert np.array_equal(np.asarray(out_r)[:, 0], np.asarray(out_d)[:, 0])
-
-
-def test_kernel_int8_scales_parity():
-    """Quantized pool with per-(block, slot, head) scales matches the
-    oracle's dequantized gather."""
-    rng = np.random.default_rng(1)
-    k8 = jnp.asarray(rng.integers(-127, 127, size=(L, N, BS, HKV, D)),
-                     jnp.int8)
-    v8 = jnp.asarray(rng.integers(-127, 127, size=(L, N, BS, HKV, D)),
-                     jnp.int8)
-    ks = jnp.asarray(rng.uniform(0.01, 0.03, size=(L, N, BS, HKV)),
-                     jnp.float32)
-    vs = jnp.asarray(rng.uniform(0.01, 0.03, size=(L, N, BS, HKV)),
-                     jnp.float32)
-    q, kn, vn, q_pos, qlen, kv_pos, bt, nblk, slot0 = _ragged_inputs(
-        rng, CTX, QLEN
-    )
-    got = pallas_ragged.ragged_paged_attention(
-        q, k8, v8, kn, vn, q_pos, qlen, kv_pos, bt, nblk, slot0,
-        jnp.int32(1), k_scale_pool=ks, v_scale_pool=vs, interpret=True,
-    )
-    want = attn.ragged_paged_attention(
-        q, k8[1], v8[1], kn, vn, q_pos, qlen, kv_pos, bt, slot0, RING,
-        k_scale_layer=ks[1], v_scale_layer=vs[1],
-    )
-    _assert_live_rows_close(got, want, QLEN)
-
-
-def test_kernel_mqa_parity():
-    rng = np.random.default_rng(2)
-    Hkv = 1
-    k_pool = jnp.asarray(rng.normal(size=(L, N, BS, Hkv, D)), jnp.float32)
-    v_pool = jnp.asarray(rng.normal(size=(L, N, BS, Hkv, D)), jnp.float32)
-    q, kn, vn, q_pos, qlen, kv_pos, bt, nblk, slot0 = _ragged_inputs(
-        rng, CTX, QLEN, Hkv=Hkv
-    )
-    got = pallas_ragged.ragged_paged_attention(
-        q, k_pool, v_pool, kn, vn, q_pos, qlen, kv_pos, bt, nblk, slot0,
-        jnp.int32(0), interpret=True,
-    )
-    want = attn.ragged_paged_attention(
-        q, k_pool[0], v_pool[0], kn, vn, q_pos, qlen, kv_pos, bt, slot0,
-        RING,
-    )
-    _assert_live_rows_close(got, want, QLEN)
 
 
 # --------------------------------------------------------------------------
@@ -187,9 +44,8 @@ def mesh(devices):
 
 @pytest.fixture(scope="module")
 def cfg():
-    # head_dim=8 is outside the kernel envelope, so the engine runs the
-    # XLA ragged oracle — the numerics under test are the dispatch
-    # structure, not the kernel (covered above in interpret mode).
+    # The engine runs the XLA ragged oracle: the numerics under test are
+    # the dispatch structure, not the kernel (tests/test_pallas_kv.py).
     return DecoderConfig(
         model_type="llama", vocab_size=128, hidden_size=64, n_layers=2,
         n_heads=8, n_kv_heads=4, head_dim=8, intermediate_size=128,
@@ -407,43 +263,3 @@ def test_chunked_prefill_requires_paged(cfg, params, mesh):
     eng = _paged_engine(cfg, params, mesh)
     with pytest.raises(ValueError):
         ContinuousBatcher(eng, rows=2, chunked_prefill=0)
-
-
-def test_ragged_kernel_forward_integration(devices):
-    """Chunked-admission serving with the ragged Pallas kernel forced on
-    (IMPL_OVERRIDE='pallas', interpret): same greedy tokens as the XLA
-    ragged oracle path on a kernel-envelope config (D=128)."""
-    attn_mod = importlib.import_module("llmss_tpu.ops.attention")
-    kcfg = DecoderConfig(
-        model_type="llama", vocab_size=128, hidden_size=256, n_layers=2,
-        n_heads=8, n_kv_heads=4, head_dim=128, intermediate_size=128,
-        max_position_embeddings=64, activation="silu", norm="rmsnorm",
-        norm_eps=1e-5, mlp="swiglu", positions="rotary", rope_style="half",
-        rotary_dim=128, attn_bias=False, mlp_bias=False,
-        tie_word_embeddings=False, dtype="float32",
-    )
-    mesh = make_mesh(MeshPlan(dp=2, tp=4))
-    kparams = init_params(kcfg, mesh, jax.random.key(3))
-    prompts = [list(range(2, 22)), [3, 14, 15, 9, 26, 5]]
-    gen = GenerationParams(max_new_tokens=6, is_greedy=True)
-
-    outs = {}
-    old = attn_mod.IMPL_OVERRIDE
-    for impl in ("xla", "pallas"):
-        attn_mod.IMPL_OVERRIDE = impl
-        try:
-            eng = DecodeEngine(
-                kcfg, kparams, mesh, max_seq_len=64, kv_layout="paged",
-                block_size=8,
-            )
-            b = ContinuousBatcher(eng, rows=2, chunk_steps=2,
-                                  group_chunks=2, chunked_prefill=4)
-            res = {}
-            for i, p in enumerate(prompts):
-                b.submit(p, gen,
-                         lambda toks, i=i, **kw: res.__setitem__(i, toks))
-            b.run_until_idle()
-            outs[impl] = res
-        finally:
-            attn_mod.IMPL_OVERRIDE = old
-    assert outs["xla"] == outs["pallas"], outs
