@@ -2021,16 +2021,16 @@ class ContinuousBatcher:
         return ids, qlens, feed, emit, firsts
 
     def _pool_read(self, chunk: int, t_bucket: int | None) -> dict:
-        """What the group about to be dispatched reads of the paged pool,
-        for its ``sched.dispatch`` span: ``attn_read``, the read its program
-        is traced with (``models.decoder.attn_read``), and ``state_update``,
-        how it updates a recurrent state (``models.decoder.state_update``:
-        ``xla`` where there is none); ``blocks_read``, the
-        blocks its live rows hold at the group's first step (a read that
-        stops at a row's length visits these); ``blocks_ring``, the table
-        columns a read of every row's whole ring or read bucket visits.
-        Their ratio is the share of the ring that holds anything."""
-        from llmss_tpu.models.decoder import attn_read, state_update
+        """What the group about to be dispatched reads of the paged pool, for
+        its ``sched.dispatch`` span: ``models.decoder``'s ``attn_read`` (the
+        read its program is traced with), ``index_read`` (how it scores an
+        indexer's pool: ``none`` without one) and ``state_update`` (how it
+        updates a recurrent state: ``xla`` without one);
+        ``blocks_read``, the blocks its live rows hold at the group's first
+        step (a read that stops at a row's length visits these), over
+        ``blocks_ring``, the columns a read of every row's whole ring or read
+        bucket visits: the share of the ring that holds anything."""
+        from llmss_tpu.models import decoder as d
 
         how = self._attn_reads.get(chunk)
         if how is None:
@@ -2038,7 +2038,7 @@ class ContinuousBatcher:
                 f.__name__: f(
                     self.engine.cfg, self.cache, self.engine.mesh, chunk
                 )
-                for f in (attn_read, state_update)
+                for f in (d.attn_read, d.index_read, d.state_update)
             }
         bs, mb = self.cache.block_size, self.cache.max_blocks
         if t_bucket is not None:

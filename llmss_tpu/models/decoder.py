@@ -2431,7 +2431,7 @@ def _forward_selected(
     elif a_step and attn_read(cfg, cache, mesh, S) == "dsa.kernel":
         attn = _make_selected_read(
             cfg, cache, positions, slots, lens, kv_pos_src, cache_vis, nb,
-            feeding,
+            feeding, mesh,
         )
     elif S == 1 and q_lens is None:
         def attn(q, k_new, v_new, k_c, v_c, *, layer, index):
@@ -2707,8 +2707,34 @@ def _selected_read(cfg, cache, one_device, chunk, attention_mod) -> str:
     return "dsa.tokens" if chunk == 1 else "dsa.mask"
 
 
+def index_read(cfg: DecoderConfig, cache: PagedKVCache, mesh, chunk: int) -> str:
+    """How a decode step (``chunk`` 1) or a mixed step of ``chunk`` tokens a
+    row makes the indexer's scores over the cached slots, as its program is
+    traced NOW: ``none`` (no indexer), ``idx.kernel`` (the indexer's pool
+    walked where it lies, the live rows' blocks only,
+    ``ops/pallas_dsa.py: idx_paged_scores``) or ``gather`` (every row's view
+    of the pool gathered and ``index_scores`` over it, XLA). The kernel goes
+    with ``attn_read``'s ``dsa.kernel`` (its rule: one device, compiled on a
+    TPU or forced) where the pool's shapes are inside its own ``supports``.
+    Like ``attn_read`` it does not know of a decode read bucket of at most
+    ``topk`` slots, which selects nothing and scores nothing."""
+    from llmss_tpu.ops import pallas_dsa
+
+    if cfg.indexer is None:
+        return "none"
+    if attn_read(cfg, cache, mesh, chunk) == "dsa.kernel" and (
+        pallas_dsa.index_supports(
+            cache.block_size, cfg.indexer.n_heads, cache.idx.shape[-1],
+            chunk, cache.max_len, cache.idx.dtype,
+        )
+    ):
+        return "idx.kernel"
+    return "gather"
+
+
 def _make_selected_read(
     cfg, cache, positions, slots, lens, kv_pos_src, cache_vis, nb, feeding,
+    mesh,
 ):
     """``attn_read``'s ``dsa.kernel`` as a ``(q, k_new, v_new, k_cache,
     v_cache, *, layer, index) -> attn`` callable for ``_block``: the decode
@@ -2717,7 +2743,11 @@ def _make_selected_read(
     forms': ``decode_selection`` for every row's first query (a decoding row
     has no other), ``chunk_selection`` a query position for the rows
     ``feeding`` (None: every row), packed a bit a query; the kernel walks the
-    live rows' blocks (``lens`` > 0) and reads nothing else of the pools."""
+    live rows' blocks (``lens`` > 0) and reads nothing else of the pools.
+    Under ``index_read``'s ``idx.kernel`` the scores both selections start
+    from come from the same walk over the indexer's pool
+    (``pallas_dsa.idx_paged_scores``: a feeding slot that holds no row walks
+    nothing) and no view of that pool is gathered."""
     import importlib
 
     from llmss_tpu.ops import pallas_dsa
@@ -2727,12 +2757,15 @@ def _make_selected_read(
         "llmss_tpu.ops.attention"
     ).pallas_interpret()
     topk, tables = cfg.indexer.topk, cache.block_tables
-    S, Tv = positions.shape[1], kv_pos_src.shape[1]
+    B, S, Tv = *positions.shape, kv_pos_src.shape[1]
     n_blocks = _blocks_held(cache)
     some = feeding is not None
     r = (lambda a: a[jnp.minimum(feeding, a.shape[0] - 1)]) if some else (
         lambda a: a
     )
+    walk = index_read(cfg, cache, mesh, S) == "idx.kernel"
+    # a feeding slot that holds no row (``feeding`` == B) walks nothing
+    fed_lens = jnp.where(feeding < B, r(lens), 0) if some else lens
 
     def attn(q, k_new, v_new, k_c, v_c, *, layer, index):
         del k_c, v_c  # reads the stacked pools directly
@@ -2745,22 +2778,36 @@ def _make_selected_read(
                 interpret=interp,
             )
 
+        def scores(qi, wi, lens, pick):
+            """The rows' (``pick``: which) queries' scores over their cached
+            slots by the walk, or None: the selection gathers and scores."""
+            if not walk:
+                return None
+            return pallas_dsa.idx_paged_scores(
+                qi, wi, cache.idx, lens, pick(tables), pick(n_blocks), layer,
+                n_slots=Tv, interpret=interp,
+            )
+
         if S == 1 or some:
             with jax.named_scope("dsa.decode"):
                 first = dsa.decode_selection(
                     cache.idx, ki[:, :1], qi[:, :1], wi[:, :1],
                     positions[:, :1], kv_pos_src, tables, slots[:, :1],
                     layer, topk=topk, n_blocks=nb,
+                    scores=scores(qi[:, :1], wi[:, :1], lens, lambda a: a),
                 ).astype(jnp.int32)  # [B, Tv + 1]: bit 0 of a word
                 keep_c = first[:, :Tv]
                 keep_w = jnp.pad(first[:, Tv:], ((0, 0), (0, S - 1)))
                 if S == 1:
                     return read(keep_c, keep_w)
         with jax.named_scope("dsa.chunk"):
+            view = None if walk else gather_block_view(
+                cache.idx, r(tables), nb, layer
+            )
             words = pallas_dsa.pack_queries(dsa.chunk_selection(
-                gather_block_view(cache.idx, r(tables), nb, layer), r(ki),
-                r(qi), r(wi), r(positions[:, 0]), r(lens), r(kv_pos_src),
-                r(cache_vis), topk=topk,
+                view, r(ki), r(qi), r(wi), r(positions[:, 0]), r(lens),
+                r(kv_pos_src), r(cache_vis), topk=topk,
+                scores=scores(r(qi), r(wi), fed_lens, r),
             ))  # [rows, Tv + S]
             if not some:
                 return read(words[:, :Tv], words[:, Tv:])

@@ -1,6 +1,8 @@
-"""The read of a pool of keys and values by the decode and mixed steps of a
-model that SELECTS what attention reads (``IndexerConfig``;
-docs/sparse-attention.md).
+"""The reads of the pools by the decode and mixed steps of a model that
+SELECTS what attention reads (``IndexerConfig``; docs/sparse-attention.md):
+the keys and values under the selection (``dsa_paged_attention``, below) and,
+before it, the indexer's scores over ITS pool, which the selection is made
+from (``idx_paged_scores``, at the end of the file).
 
 The XLA forms (``ops/sparse_attention.py``) fetch a decoding row's kept
 tokens one by one (a gather of ``topk`` 1 KB slices a row and pool, behind a
@@ -301,6 +303,24 @@ def pack_queries(keep: jax.Array) -> jax.Array:
     return jax.lax.bitcast_convert_type(words, jnp.int32)
 
 
+def _walk_plan(block_tables, n_blocks, q_len, N, cols, NC, K):
+    """A walk's scalar operands, for rows of ``NC`` chunks of ``K`` blocks
+    over the first ``cols`` table columns: the clamped table padded to whole
+    chunks and flattened, the chunks each row walks (none where ``q_len`` is
+    0), the chunks walked by the rows before it (buffer parity) and the next
+    row that walks any (or the row count)."""
+    B = block_tables.shape[0]
+    bt = jnp.minimum(block_tables[:, :cols], N - 1).astype(jnp.int32)
+    bt = jnp.pad(bt, ((0, 0), (0, NC * K - bt.shape[1])))
+    nblk = jnp.clip(n_blocks.astype(jnp.int32).reshape(B), 0, cols)
+    nc = jnp.where(q_len.reshape(B) > 0, -(-nblk // K), 0)
+    start = jnp.cumsum(nc) - nc
+    rows = jnp.arange(B, dtype=jnp.int32)
+    nxt = jax.lax.cummin(jnp.where(nc > 0, rows, B), reverse=True)
+    nxt = jnp.concatenate([nxt[1:], jnp.full((1,), B, jnp.int32)])
+    return bt.reshape(-1), nc, start.astype(jnp.int32), nxt
+
+
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def dsa_paged_attention(
     q: jax.Array,  # [B, CB, Hq, D] — a CB-token query chunk a row
@@ -353,14 +373,7 @@ def dsa_paged_attention(
     kw = keep_w.astype(jnp.int32) & live
     kw = kw.at[:, 0].set(kw[:, 0] | ~live[:, 0])
     kw = jnp.pad(kw, ((0, 0), (0, F - CB))).reshape(B, 1, F)
-    bt = jnp.minimum(block_tables[:, :cols], N - 1).astype(jnp.int32)
-    bt = jnp.pad(bt, ((0, 0), (0, NC * K - bt.shape[1])))
-    nblk = jnp.clip(n_blocks.astype(jnp.int32).reshape(B), 0, cols)
-    nc = jnp.where(q_len > 0, -(-nblk // K), 0)
-    start = jnp.cumsum(nc) - nc
-    rows = jnp.arange(B, dtype=jnp.int32)
-    nxt = jax.lax.cummin(jnp.where(nc > 0, rows, B), reverse=True)
-    nxt = jnp.concatenate([nxt[1:], jnp.full((1,), B, jnp.int32)])
+    walk = _walk_plan(block_tables, n_blocks, q_len, N, cols, NC, K)
 
     def by_head(x, n):  # [B, CB, Hkv * n, D] -> [B, Hkv, CB * n, D]
         x = x.reshape(B, CB, Hkv, n, D).transpose(0, 2, 1, 3, 4)
@@ -413,10 +426,225 @@ def dsa_paged_attention(
         ),
         interpret=interpret,
     )(
-        jnp.asarray(layer, jnp.int32).reshape(1),
-        bt.reshape(-1),
-        nc, start.astype(jnp.int32), nxt, q_len,
+        jnp.asarray(layer, jnp.int32).reshape(1), *walk, q_len,
         kc, kw, by_head(q, G), k_pool, v_pool, fresh(k_new), fresh(v_new),
     )
     out = out.reshape(B, Hkv, CB, G, D).transpose(0, 2, 1, 3, 4)
     return out.reshape(B, CB, Hq, D)
+
+
+# --- the indexer's scores, made where its pool lies -------------------------
+#
+# ``I[t, s] = sum_j w[t, j] * relu(qI[t, j] . kI[s])`` over the cached slots
+# (``ops/sparse_attention.py: index_scores``) by the walk above over ONE
+# pool, ``PagedKVCache.idx`` (``f32[L, N, bs, W]``: a key zero-padded to a
+# lane tile a slot), and no softmax: a grid step a row, the row's occupied
+# blocks a chunk at a time through two VMEM buffers, rows with nothing live
+# or nothing cached walk nothing. A slot is 512 B where a slot of keys and
+# values is 2 KB, so a chunk is wider than the read's. All of a row's queries
+# and indexer heads are ONE product a tile of a chunk (``[S * Hi, W] x [W,
+# tile]``), float32 operands at ``Precision.HIGHEST`` (a top-k is
+# discontinuous: docs/sparse-attention.md, "The hazard"); ``relu``, the
+# weights and the sum over heads float32 on the vector unit. It returns
+# SCORES, not bits: the masks, ``keep_topk`` and the tie rule stay with the
+# selection, which looks at a score only where a query may see the slot.
+
+#: Slots of a chunk of the indexer pool's walk, widest first, and of the tile
+#: of a chunk one product scores. On a v5e, cell 6's pool and traffic, a
+#: layer's two calls took 0.29-0.31 ms at chunks of 512, 1,024 and 2,048
+#: alike (256 lost 3-9% before the scores were written in place): a chunk is
+#: 64 copies of 8 KB whatever its turn costs, and their issue is what binds
+#: (PERF.md section 6, PR 52).
+_INDEX_CHUNK_SLOTS = (1024, 512, 256)
+_INDEX_TILE = 512
+
+
+def index_chunk_slots(
+    block_size: int, n_heads: int, width: int, chunk: int, n_slots: int,
+    dtype,
+) -> int | None:
+    """Slots of one chunk of the indexer pool's walk at these shapes; None
+    where the kernel does not take them: a float32 pool of whole lane tiles
+    a slot, blocks of whole sublane tiles that divide a chunk, indexer heads
+    in whole sublane tiles (the sum over heads is a reduction of ``[chunk,
+    heads, tile]``), at most ``MAX_CHUNK`` queries a row, and a row's scores
+    over the ``n_slots`` it may hold within the VMEM budget beside the
+    walk's working set (a grid step owns them)."""
+    if (
+        jnp.dtype(dtype) != jnp.dtype(jnp.float32) or width % 128
+        or block_size % 8 or n_heads % 8 or not 0 < chunk <= MAX_CHUNK
+    ):
+        return None
+    rows = chunk * n_heads
+    for ks in _INDEX_CHUNK_SLOTS:
+        tile = min(ks, _INDEX_TILE)
+        if (
+            ks % (block_size * _COPIES_UNROLLED) == 0
+            and 2 * ks * width * 4 <= _BUFFER_BYTES
+            and 2 * rows * (width + 128) * 4  # queries and weights, twice
+            + 2 * ks * width * 4  # the two buffers
+            + 3 * rows * tile * 4  # a tile's products, weighted, summed
+            # the row's scores, twice, in whole chunks and sublane tiles
+            + 2 * -(-n_slots // ks) * ks * -(-chunk // 8) * 8 * 4
+            <= _VMEM_BUDGET
+        ):
+            return ks
+    return None
+
+
+def index_supports(
+    block_size: int, n_heads: int, width: int, chunk: int, n_slots: int,
+    dtype,
+) -> bool:
+    """Whether ``idx_paged_scores`` takes these shapes (``index_chunk_slots``
+    says which conditions)."""
+    return index_chunk_slots(
+        block_size, n_heads, width, chunk, n_slots, dtype
+    ) is not None
+
+
+def _index_kernel(
+    layer_ref,  # [1] — layer of the stacked pool
+    bt_ref,  # [R * NC * K] — flattened clamped block table
+    nc_ref,  # [R] — chunks this row walks
+    start_ref,  # [R] — chunks walked by the rows before it (buffer parity)
+    next_ref,  # [R] — the next row that walks any, or R
+    q_ref,  # [1, S * Hi, W] f32 — query-major: row t * Hi + j
+    w_ref,  # [1, S * Hi, 1] f32
+    idx_ref,  # [L, N, bs, W] f32 in HBM
+    o_ref,  # [1, S, NC * KS] f32 — the row's view, whole chunks
+    buf_ref,  # [2, KS, W] f32
+    sem_ref,  # DMA [2]
+    *,
+    block_size: int,
+    heads: int,
+    tile: int,
+):
+    b = pl.program_id(0)
+    n_rows = pl.num_programs(0)
+    KS, S = buf_ref.shape[1], o_ref.shape[1]
+    K = KS // block_size
+    cols = o_ref.shape[2] // block_size  # table columns a row has here
+    layer = layer_ref[0]
+    n, base = nc_ref[b], start_ref[b]
+
+    def fetch(row, c, slot):
+        def some(j, carry):
+            for u in range(_COPIES_UNROLLED):
+                i = j * _COPIES_UNROLLED + u
+                pltpu.make_async_copy(
+                    idx_ref.at[layer, bt_ref[row * cols + c * K + i]],
+                    buf_ref.at[slot, pl.ds(i * block_size, block_size)],
+                    sem_ref.at[slot],
+                ).start()
+            return carry
+
+        jax.lax.fori_loop(0, K // _COPIES_UNROLLED, some, 0)
+
+    # the first row that walks anything starts its own first chunk
+    @pl.when((base == 0) & (n > 0))
+    def _():
+        fetch(b, 0, 0)
+
+    def chunk(c, carry):
+        slot = (base + c) % 2
+        nxt = next_ref[b]
+        # the row's next chunk, or the first of the next row with any
+        more = c + 1 < n
+
+        @pl.when(more | (nxt < n_rows))
+        def _():
+            fetch(jnp.where(more, b, nxt), jnp.where(more, c + 1, 0), 1 - slot)
+
+        # one wait for the K copies of a chunk
+        pltpu.make_async_copy(
+            buf_ref.at[slot], buf_ref.at[slot], sem_ref.at[slot]
+        ).wait()
+        for j in range(0, KS, tile):
+            s = jax.lax.dot_general(
+                q_ref[0], buf_ref[slot, j:j + tile],
+                (((1,), (1,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32,
+            )  # [S * Hi, tile]
+            s = jnp.maximum(s, 0.0) * w_ref[0]
+            o_ref[0, :, pl.ds(pl.multiple_of(c * KS + j, tile), tile)] = (
+                jnp.sum(s.reshape(S, heads, tile), axis=1)
+            )
+        return carry
+
+    jax.lax.fori_loop(0, n, chunk, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("n_slots", "interpret"))
+def idx_paged_scores(
+    qi: jax.Array,  # [R, S, Hi, Di <= W] float32 — a row's S queries
+    wi: jax.Array,  # [R, S, Hi] float32
+    idx_pool: jax.Array,  # [L, N, bs, W] float32 — the stale stacked pool
+    q_len: jax.Array,  # [R] — live queries (0: the row walks nothing)
+    block_tables: jax.Array,  # [R, MB] int32 (sentinel >= N = unmapped)
+    n_blocks: jax.Array,  # [R] — table columns that hold any token
+    layer: jax.Array,  # int32 scalar — pool layer to read
+    *,
+    n_slots: int,
+    interpret: bool = False,
+) -> jax.Array:
+    """float32 ``[R, S, n_slots]``: every query's indexer score over the
+    first ``n_slots`` slots of its row's logical view, ``index_scores(qi, wi,
+    gather_block_view(idx_pool, ..))`` up to float32 reduction order wherever
+    the walk went: the slots of a row's first ``n_blocks`` blocks, for rows
+    with ``q_len > 0``. Elsewhere the result is UNDEFINED (NaN included): its
+    reader masks by what a query may see, which is false there."""
+    R, S, Hi, Di = qi.shape
+    L, N, bs, W = idx_pool.shape
+    KS = index_chunk_slots(bs, Hi, W, S, n_slots, idx_pool.dtype)
+    if KS is None or Di > W:
+        raise ValueError(
+            f"pallas_dsa does not score bs={bs}, Hi={Hi}, Di={Di}, W={W}, "
+            f"chunk={S}, slots={n_slots}, {idx_pool.dtype}"
+        )
+    K = KS // bs
+    NC = -(-n_slots // KS)
+    cols = -(-n_slots // bs)
+
+    q = jnp.pad(qi.astype(jnp.float32), ((0, 0),) * 3 + ((0, W - Di),))
+
+    def row(shape):
+        return pl.BlockSpec(
+            (1,) + shape, lambda b, *_: (b,) + (0,) * len(shape),
+            memory_space=pltpu.VMEM,
+        )
+
+    out = pl.pallas_call(
+        functools.partial(
+            _index_kernel, block_size=bs, heads=Hi,
+            tile=min(KS, _INDEX_TILE),
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(R,),
+            in_specs=[
+                row((S * Hi, W)),
+                row((S * Hi, 1)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=row((S, NC * KS)),
+            scratch_shapes=[
+                pltpu.VMEM((2, KS, W), idx_pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((R, S, NC * KS), jnp.float32),
+        # rows in order: a row's last chunk starts the next row's first
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+        ),
+        interpret=interpret,
+    )(
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        *_walk_plan(block_tables, n_blocks, q_len, N, cols, NC, K),
+        q.reshape(R, S * Hi, W),
+        wi.astype(jnp.float32).reshape(R, S * Hi, 1),
+        idx_pool,
+    )
+    return out[:, :, :n_slots]

@@ -28,9 +28,11 @@ The SELECTION is made here whatever reads the keys and values:
 and ``chunk_selection`` (a query position of a chunk, the step's own fresh
 tokens competing), both ending in ``keep_topk``. On a TPU the decode and
 mixed steps hand it as bits to ``ops/pallas_dsa.py``, which walks both pools
-where they lie (``models/decoder.py: attn_read``'s ``dsa.kernel``); the two
-XLA forms of the read below are its oracles, the CPU's path, and the
-dedicated prefill's:
+where they lie (``models/decoder.py: attn_read``'s ``dsa.kernel``), and give
+both selections the scores over the cached slots from the same walk over the
+indexer's pool (``scores=``: ``index_read``'s ``idx.kernel``); the two XLA
+forms of the read below are its oracles, the CPU's path, and the dedicated
+prefill's:
 
 * ``sparse_chunk_attention`` - the MASK form, for the mixed step, the
   prefill and the decode step alike: the rows' gathered logical views (keys,
@@ -129,15 +131,20 @@ def chunk_selection(
     ki_new,  # [B, S, Di or W] float32: the step's own fresh keys
     qi, wi,  # [B, QB, Hi, Di or W], [B, QB, Hi] float32: queries [i0, i0 + QB)
     q_pos0, q_len, kv_pos_old, cache_vis,  # [B], [B], [B, T], [B, T]
-    *, topk: int, i0=0,
+    *, topk: int, i0=0, scores=None,
 ):
     """bool ``[B, QB, T + S]``: what each of a row's queries ``[i0, i0 +
     QB)`` keeps of the cached slots and of the step's ``S`` fresh tokens (the
     mask form's selection). Query ``i`` sees the cached slots ``cache_vis &
     kv_pos <= q_pos0 + i`` and the fresh tokens ``j <= i, j < q_len``, and
     keeps its ``topk`` best of those by the indexer; a padding query (``i >=
-    q_len``) keeps all it sees."""
-    qi, ki_new = _to_pool_width(ki_view.shape[-1], qi, ki_new)
+    q_len``) keeps all it sees. ``scores`` float32 ``[B, QB, T]``: the
+    queries' scores over the cached slots, made elsewhere (``ki_view`` None:
+    ``ops/pallas_dsa.py: idx_paged_scores``, the pool walked where it lies);
+    one is looked at only where the query sees the slot, so it may be
+    anything elsewhere, NaN included."""
+    if scores is None:
+        qi, ki_new = _to_pool_width(ki_view.shape[-1], qi, ki_new)
     QB, S = qi.shape[1], ki_new.shape[1]
     rel_k = jnp.arange(S, dtype=jnp.int32)
     rel_q = i0 + jnp.arange(QB, dtype=jnp.int32)
@@ -147,7 +154,10 @@ def chunk_selection(
         rel_k[None, None, :] < q_len[:, None, None]
     )
     score = jnp.concatenate([
-        jnp.where(see_c, index_scores(qi, wi, ki_view), -jnp.inf),
+        jnp.where(
+            see_c, index_scores(qi, wi, ki_view) if scores is None else scores,
+            -jnp.inf,
+        ),
         jnp.where(see_w, index_scores(qi, wi, ki_new), -jnp.inf),
     ], axis=-1)  # [B, QB, T + S]
     return keep_topk(score, topk) | (
@@ -254,24 +264,31 @@ def decode_selection(
     block_tables,  # [B, MB]
     slots,  # [B, 1] the slot the token will occupy
     layer,
-    *, topk: int, n_blocks: int | None = None,
+    *, topk: int, n_blocks: int | None = None, scores=None,
 ):
     """bool ``[B, Tv + 1]``: what a row's ONE query keeps of the ``Tv``
     cached slots read and of its own new token (the last column): scores
     over the row's view of the indexer pool alone. The new token competes
     with the cached ones for its place among the ``topk`` (it is the latest
-    position: a tie goes against it)."""
+    position: a tie goes against it). ``scores`` float32 ``[B, 1, Tv]``: the
+    query's scores over the cached slots, made elsewhere (``chunk_selection``
+    says where and what they may hold); the pool is then not read."""
     from llmss_tpu.engine.cache import gather_block_view
 
     Tv = kv_pos_old.shape[1]
-    ki_view = gather_block_view(idx_pool, block_tables, n_blocks, layer)
-    qi, ki_new = _to_pool_width(ki_view.shape[-1], qi, ki_new)
+    if scores is None:
+        ki_view = gather_block_view(idx_pool, block_tables, n_blocks, layer)
+        qi, ki_new = _to_pool_width(ki_view.shape[-1], qi, ki_new)
     see = (
         (kv_pos_old >= 0) & (kv_pos_old <= q_pos)
         & (jnp.arange(Tv, dtype=jnp.int32)[None, :] != slots)
     )
     score = jnp.concatenate([
-        jnp.where(see, index_scores(qi, wi, ki_view)[:, 0], -jnp.inf),
+        jnp.where(
+            see,
+            (index_scores(qi, wi, ki_view) if scores is None else scores)[:, 0],
+            -jnp.inf,
+        ),
         index_scores(qi, wi, ki_new)[:, 0],
     ], axis=-1)  # [B, Tv + 1]
     return keep_topk(score, min(topk, Tv))

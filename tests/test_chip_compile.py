@@ -38,7 +38,9 @@ from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from llmss_tpu.engine import DecodeEngine
 from llmss_tpu.engine.cache import PagedKVCache, ssm_state_shapes
-from llmss_tpu.models.decoder import attn_read, param_shapes, param_specs
+from llmss_tpu.models.decoder import (
+    attn_read, index_read, param_shapes, param_specs,
+)
 from llmss_tpu.models.registry import config_from_hf
 from llmss_tpu.ops import (
     pallas_attention, pallas_dsa, pallas_gdn, pallas_kv, pallas_mla,
@@ -136,6 +138,21 @@ def _kernel_call(kernel: str, Hq: int, Hkv: int, D: int):
             ((rows, mb * BS), i32), ((rows, chunk), i32), row,
             ((rows, mb), i32), row, ((), i32),
         ]
+    if kernel == "index_scores":
+        # the indexer's scores over its pool at the shapes of the benchmark's
+        # sixth cell: [6, 33792, 16, 128] float32 under the same table, every
+        # row's first query (32 rows x 1) or the feeding slots' chunk (7 x 32)
+        rows, (heads, Di, W, layers, mb), chunk = Hq, D, Hkv
+        assert pallas_dsa.index_supports(BS, heads, W, chunk, mb * BS, jnp.float32)
+        row = ((rows,), i32)
+        return functools.partial(
+            pallas_dsa.idx_paged_scores, n_slots=mb * BS,
+        ), [
+            ((rows, chunk, heads, Di), jnp.float32),
+            ((rows, chunk, heads), jnp.float32),
+            ((layers, 32 * mb, BS, W), jnp.float32), row, ((rows, mb), i32),
+            row, ((), i32),
+        ]
     if kernel == "state_update":
         # the Mamba-2 state pool's update at the shapes of the benchmark's
         # second cell: 64 rows of 32 heads x [128, 256] float32 over 5
@@ -212,6 +229,13 @@ SELECTED_READS = {
     "dsa-keye-vl2-step-of-32": (32, 4, (128, 6, 1056, 32)),
 }
 
+# (rows of the call, queries a row, (indexer heads, key width, the pool's row,
+# layers, blocks a row)) of the indexer's scores by the walk
+INDEX_SCORES = {
+    "idx-keye-vl2-first-query": (32, 1, (16, 64, 128, 6, 1056)),
+    "idx-keye-vl2-fed-chunk-of-32": (7, 32, (16, 64, 128, 6, 1056)),
+}
+
 
 @pytest.mark.parametrize(
     "kernel,model",
@@ -220,13 +244,14 @@ SELECTED_READS = {
     + [("state_update", step) for step in STATE_UPDATES]
     + [("delta_update", step) for step in DELTA_UPDATES]
     + [("kv_read", step) for step in KV_READS]
-    + [("selected_read", step) for step in SELECTED_READS],
+    + [("selected_read", step) for step in SELECTED_READS]
+    + [("index_scores", step) for step in INDEX_SCORES],
 )
 def test_kernel_compiles_for_v5e(v5e, kernel, model):
     fn, shapes = _kernel_call(
         kernel,
         *(WIDTHS | LATENT_READS | STATE_UPDATES | DELTA_UPDATES | KV_READS
-          | SELECTED_READS)[model],
+          | SELECTED_READS | INDEX_SCORES)[model],
     )
     on_chip = SingleDeviceSharding(v5e)
     args = [
@@ -562,10 +587,14 @@ def test_a_selection_inside_paged_attention_fits_beside_three_pools(
     more custom call in the scan's one body), so beside it stands no gather
     of the kept tokens (``[rows * topk, 4, 128]``), no row's gathered ring,
     no float32 attention score over the ring (feeding rows x query heads x
-    chunk of them) and no ``sort`` of a ring; the temporaries are what the selection
-    still holds: the indexer's view of all rows (277 MB) and the feeding
-    rows' indexer scores (242 MB), 0.32 / 0.35 GB where the mask form's
-    scores made the mixed group's 0.80."""
+    chunk of them) and no ``sort`` of a ring. Since PR 52 the indexer's
+    scores come from the same walk over ITS pool (``idx.kernel``,
+    ``%idx_paged_scores``: one call for every row's first query, in the
+    mixed group one more for the feeding slots' chunk), so no view of the
+    indexer pool is gathered (all rows' 277 MB, the feeding slots' 61 MB) and
+    no score a head stands in HBM (``f32[7,32,16,16896]``, 242 MB): the
+    temporaries are 0.04 / 0.06 GB where they were 0.32 / 0.35 (and the mask
+    form's scores made the mixed group's 0.80)."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(
             root, "benchmark", "cells",
@@ -589,12 +618,22 @@ def test_a_selection_inside_paged_attention_fits_beside_three_pools(
     assert set(re.findall(rf"bf16\[{dims}\]\{{([^}}]*T[^}}]*)\}}", text)) == {
         "4,3,2,1,0:T(4,128)(2,1)"}
     dims = ",".join(map(str, index_pool))
-    assert set(re.findall(rf"f32\[{dims}\]\{{([^}}]*)\}}", text)) == {
+    assert set(re.findall(rf"f32\[{dims}\]\{{([^}}]*T[^}}]*)\}}", text)) == {
         "3,2,1,0:T(8,128)"}
-    # a layer of the scan's one body: the experts' three and the read
-    assert text.count("tpu_custom_call") == 4 and "dsa_paged_attention" in text
     rows, ring, topk = hf["serve"]["rows"], hf["serve"]["max_seq_len"], 2048
-    heads, fed = cfg.n_heads, 7  # ``feed_rows`` at this envelope
+    heads, fed, mb = cfg.n_heads, 7, ring // 16  # ``feed_rows`` here: 7
+    # a layer of the scan's one body: the experts' three, the read and the
+    # indexer's scores (every row's first query; the feeding slots' chunk)
+    calls = {name: len(re.findall(rf"%{name}\S* = ", text))
+             for name in ("dsa_paged_attention", "idx_paged_scores")}
+    walks = 1 if program == "decode" else 2
+    assert calls == {"dsa_paged_attention": 1, "idx_paged_scores": walks}
+    assert text.count("tpu_custom_call") == 4 + walks
+    # no view of the indexer pool: all rows' (its gather's result as the
+    # profile names it, and as a view), the feeding slots'
+    for view in ((rows * mb, 16, 128), (rows, ring, 128), (fed, ring, 128),
+                 (fed * mb, 16, 128), (fed, chunk, 16, ring)):
+        assert f"f32[{','.join(map(str, view))}]" not in text, view
     for gathered in ((rows * topk,), (rows, topk), (rows, ring), (fed, ring)):
         dims = ",".join(map(str, gathered + pool[3:]))
         assert f"[{dims}]" not in text, dims
@@ -787,6 +826,10 @@ POOL_READS = {
 }
 READS = {"gather", "kv.kernel", "mla.kernel", "dsa.kernel", "dsa.tokens",
          "dsa.mask"}
+# ``index_read`` beside it, as a TPU traces either step: how the indexer's
+# pool is scored; ``none`` for every configuration without an indexer, and
+# ``gather`` on the CPU for the one that has it
+INDEX_READS = {"keye-vl-2.0-30b-a3b-1chip": "idx.kernel"}
 
 
 def test_every_configuration_file_has_its_read_in_the_table():
@@ -821,6 +864,9 @@ def test_attn_read_names_the_read_of_every_configuration(v5e, name):
     mesh = mesh_mod.make_mesh(mesh_mod.MeshPlan(tp=1), devices=[v5e])
     on_cpu = {attn_read(cfg, cache, mesh, c) for c in (1, chunk)}
     assert on_cpu <= {"gather", "dsa.tokens", "dsa.mask"}, on_cpu
+    scored = INDEX_READS.get(name, "none")
+    assert {index_read(cfg, cache, mesh, c) for c in (1, chunk)} == {
+        "none" if cfg.indexer is None else "gather"}
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(
             importlib.import_module("llmss_tpu.ops.attention"),
@@ -829,6 +875,7 @@ def test_attn_read_names_the_read_of_every_configuration(v5e, name):
         assert attn_read(cfg, cache, mesh, 1) == decode
         assert attn_read(cfg, cache, mesh, chunk) == mixed
         assert attn_read(cfg, cache, None, chunk) == mixed
+        assert {index_read(cfg, cache, mesh, c) for c in (1, chunk)} == {scored}
         if on_mesh:
             tp = hf["mesh"]["tp"]
             wide = mesh_mod.make_mesh(

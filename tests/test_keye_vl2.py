@@ -32,6 +32,7 @@ from llmss_tpu.models.registry import MODEL_REGISTRY, config_from_hf
 from llmss_tpu.ops import sparse_attention as dsa
 from llmss_tpu.ops.layers import NormParams
 from llmss_tpu.parallel import MeshPlan, make_mesh
+from llmss_tpu.utils import trace
 
 attention_mod = importlib.import_module("llmss_tpu.ops.attention")
 
@@ -66,12 +67,16 @@ def share(chip, chips=4):
                                 "chips": chips, "chip": chip}}
 
 
-def wide(hf=HF):
-    """``hf`` with heads of 128, which ``ops/pallas_dsa.py`` takes: the
-    engine that ``force_impl("pallas")`` serves through ``dsa.kernel``."""
+def wide(hf=HF, indexer_heads=8):
+    """``hf`` with heads of 128 and 8 indexer heads, which
+    ``ops/pallas_dsa.py`` takes: the engine that ``force_impl("pallas")``
+    serves through ``dsa.kernel`` and ``idx.kernel`` (with 4 indexer heads,
+    out of the walk's envelope: ``dsa.kernel`` over gathered scores)."""
     return {**hf, "head_dim": 128,
             "rope_scaling": {**hf["rope_scaling"],
-                             "mrope_section": [16, 24, 24]}}
+                             "mrope_section": [16, 24, 24]},
+            "sa_config": {**hf["sa_config"],
+                          "indexer_num_heads": indexer_heads}}
 
 
 def dense(hf=HF):
@@ -247,6 +252,8 @@ def test_prefill_then_cached_steps_match_reference(mesh, dtype, held, read):
     with reading(read):
         assert decoder.attn_read(eng.cfg, eng.new_paged_cache(1), mesh, 1) == (
             "dsa.kernel" if read == "kernel" else "dsa.tokens")
+        assert decoder.index_read(eng.cfg, eng.new_paged_cache(1), mesh, 1) == (
+            "idx.kernel" if read == "kernel" else "gather")
         run = decode_run(eng, prompts_of([21, 60, 37, 9]), 12, (1, 2, 12))
     errors = {step: err(logits, ref_logits(eng.params, seqs, hf))
               for step, (logits, seqs) in run.items()}
@@ -341,11 +348,13 @@ def test_the_mixed_step_matches_the_reference(mesh, dtype, held, read):
     adds ``top_k`` pairs a layer; a row's last live query scored its whole
     context and kept at most ``topk`` of it. ``read`` "kernel": the same
     through ``dsa.kernel`` (forced, interpreted), every row's words made by
-    ``chunk_selection``."""
+    ``chunk_selection`` from the scores of the walk (``idx.kernel``)."""
     hf = hf_of(held, read)
     eng = engine_of(mesh, dtype, held, read)
     prompts = prompts_of([45, 12, 61, 30], seed=4)
     with reading(read):
+        assert decoder.index_read(eng.cfg, eng.new_paged_cache(1), mesh, 8) == (
+            "idx.kernel" if read == "kernel" else "gather")
         got, seqs, (moe, sel) = mixed_step_logits(
             eng, prompts, 8, extra_rows=2)
     assert err(got, ref_logits(eng.params, seqs, hf)) < TOL[dtype]
@@ -360,6 +369,29 @@ def test_the_mixed_step_matches_the_reference(mesh, dtype, held, read):
     assert scored == 3 * sum(ends)
     assert kept == 3 * sum(min(e, TOPK) for e in ends)
     assert dense_rows == 3 * sum(e <= TOPK for e in ends)
+
+
+@pytest.mark.parametrize("step", ["decode", "mixed"])
+def test_indexer_heads_out_of_the_walks_envelope_score_gathered_views(
+    mesh, step,
+):
+    """``index_read`` adapts to shapes by itself: 4 indexer heads are not
+    whole sublane tiles, so under ``dsa.kernel`` the selection gathers the
+    rows' views of the indexer pool and scores them in XLA, as it did before
+    the walk (PR 48), and the step still matches the reference."""
+    hf = wide(indexer_heads=4)
+    eng = make_engine(mesh, hf=hf)
+    with reading("kernel"):
+        cache = eng.new_paged_cache(1)
+        for chunk in (1, 8):
+            assert decoder.attn_read(eng.cfg, cache, mesh, chunk) == "dsa.kernel"
+            assert decoder.index_read(eng.cfg, cache, mesh, chunk) == "gather"
+        if step == "decode":
+            logits, seqs = decode_run(eng, prompts_of([21, 37]), 2, (2,))[2]
+        else:
+            logits, seqs, _ = mixed_step_logits(
+                eng, prompts_of([29, 12], seed=4), 8, extra_rows=1)
+    assert err(logits, ref_logits(eng.params, seqs, hf)) < TOL["float32"]
 
 
 def test_a_context_of_at_most_topk_is_dense_attention_bit_for_bit(mesh, engine):
@@ -648,6 +680,7 @@ def test_a_mixed_step_works_one_turn_of_feeding_rows(mesh, monkeypatch, read):
             )
             cache = eng.new_paged_cache(4)
             assert decoder.attn_read(eng.cfg, cache, mesh, 8) == "dsa.kernel"
+            assert decoder.index_read(eng.cfg, cache, mesh, 8) == "idx.kernel"
         batcher = ContinuousBatcher(eng, rows=4, chunked_prefill=8)
         assert batcher._feed_rows == 1
         assert decoder.feed_rows(eng.cfg, batcher.cache, 8) == 1
@@ -659,8 +692,16 @@ def test_a_mixed_step_works_one_turn_of_feeding_rows(mesh, monkeypatch, read):
             return plan(*a, **k)
 
         monkeypatch.setattr(batcher, "_plan_ragged", watched)
+        trace.set_enabled(True)
+        trace.recorder().clear()
         assert run_batcher(batcher, prompts, FIVE) == expected
         assert feeding and max(feeding) == 1
+        # every group's span says how its program read the three pools
+        spans = [sp[5] for sp in trace.recorder().loop_spans()
+                 if sp[2] == "sched.dispatch"]
+        assert spans and {(a["attn_read"], a["index_read"]) for a in spans} <= (
+            {("dsa.kernel", "idx.kernel")} if read == "kernel"
+            else {("dsa.tokens", "gather"), ("dsa.mask", "gather")})
     # the budget as shipped holds all four rows: no cap
     monkeypatch.undo()
     assert ContinuousBatcher(eng, rows=4, chunked_prefill=8)._feed_rows is None

@@ -247,3 +247,177 @@ def test_a_call_the_kernel_cannot_take_raises():
             jnp.ones((1,), jnp.int32), jnp.zeros((1, 4), jnp.int32),
             jnp.ones((1,), jnp.int32), jnp.int32(0), interpret=True,
         )
+
+
+# --- the indexer's scores by the walk (``idx_paged_scores``) ----------------
+
+HI_K = 8  # indexer heads the walk takes: whole sublane tiles
+TOPK_K = 16
+# rows 0-3 of the pool's table: a last block part full (300 = 18.75 blocks),
+# a ring that wrapped (every block held, slot order not position order), a
+# row with nothing live, a row with nothing cached
+INDEX_CTX = [300, 1300, 520, 0]
+INDEX_CALLS = {
+    # every row's first query (the decode group has only this call)
+    "first-query": dict(s=1, lens=[1, 1, 0, 1]),
+    # the rows that feed, taken through ``feeding``: slot 2 holds no row
+    "fed-chunk": dict(s=8, lens=[8, 5, 0, 0], feeding=[1, 0, B]),
+    # narrower chunks and tiles than the cell's: five chunks of two tiles
+    "fed-chunk-narrow": dict(s=8, lens=[8, 5, 0, 0], feeding=[1, 0, B],
+                             widths=((256,), 128)),
+    "first-query-bucketed": dict(s=1, lens=[1, 1, 1, 0], t_bucket=608),
+}
+
+
+def _index_inputs(call, seed=0):
+    """The pool and one call's operands: ``(x, rows)``, ``rows`` the batch
+    rows the call's rows are (``feeding``; B: no row)."""
+    rng = np.random.default_rng(seed)
+    S = call["s"]
+    x = _inputs(dict(cb=S, topk=TOPK_K, ctx=INDEX_CTX, qlen=call["lens"]), seed)
+    T = call.get("t_bucket", RING)
+    x["kv_pos"] = x["kv_pos"][:, :T]
+    x["qi"] = jnp.asarray(rng.normal(size=(B, S, HI_K, DI)), jnp.float32)
+    x["wi"] = jnp.asarray(rng.normal(size=(B, S, HI_K)), jnp.float32)
+    rows = np.asarray(call.get("feeding", range(B)))
+    return x, rows
+
+
+def _walked(x, rows, layer, widths=None):
+    """``idx_paged_scores`` as ``_make_selected_read`` calls it: the rows
+    ``rows`` of the batch, a slot that holds no row walking nothing."""
+    r = np.minimum(rows, B - 1)
+    lens = jnp.where(jnp.asarray(rows) < B, x["q_len"][r], 0)
+    fn = pallas_dsa.idx_paged_scores
+    if widths is not None:  # traced anew: the widths are read when tracing
+        fn = fn.__wrapped__
+    return fn(
+        x["qi"][r], x["wi"][r], x["idx"], lens, x["bt"][r], x["nblk"][r],
+        jnp.int32(layer), n_slots=x["kv_pos"].shape[1], interpret=True,
+    )
+
+
+@pytest.mark.parametrize("name", list(INDEX_CALLS))
+def test_index_scores_by_the_walk_match_the_gathered_views(name, monkeypatch):
+    """Wherever the walk goes (the slots of a live row's held blocks) its
+    scores are ``index_scores`` over the gathered view's up to float32
+    reduction order: a score is 8 weighted ``relu``s of 64-term products of
+    unit normals, some tens in size, summed in another order by the kernel's
+    tiles than by XLA's einsum: 1e-4 is a few hundred roundings of 2^-24 of
+    that size (read: 8e-6), and 1e4 times under the gap of neighbours in rank
+    that would move a bit (the bits are held below)."""
+    call = INDEX_CALLS[name]
+    if "widths" in call:
+        monkeypatch.setattr(pallas_dsa, "_INDEX_CHUNK_SLOTS", call["widths"][0])
+        monkeypatch.setattr(pallas_dsa, "_INDEX_TILE", call["widths"][1])
+    x, rows = _index_inputs(call)
+    T = x["kv_pos"].shape[1]
+    nb = -(-T // BS) if T < RING else None
+    assert pallas_dsa.index_supports(BS, HI_K, W, call["s"], T, jnp.float32)
+    pad = jnp.pad(x["qi"], ((0, 0),) * 3 + ((0, W - DI),))
+    for layer in range(L):
+        got = np.asarray(_walked(x, rows, layer, call.get("widths")))
+        want = np.asarray(dsa.index_scores(
+            pad, x["wi"], gather_block_view(x["idx"], x["bt"], nb, layer)))
+        assert got.shape == (len(rows), call["s"], T)
+        for i, b in enumerate(rows):
+            if b == B or call["lens"][b] == 0:
+                continue
+            held = min(int(x["nblk"][b]) * BS, T)
+            assert held or INDEX_CTX[b] == 0
+            np.testing.assert_allclose(
+                got[i, :, :held], want[b, :, :held], rtol=0, atol=1e-4)
+
+
+def _selected(x, rows, layer, scores):
+    """The selection of the call's rows as the served step makes it, from
+    the pool (``scores`` None: the XLA forms) or from ``scores``."""
+    S, T = x["qi"].shape[1], x["kv_pos"].shape[1]
+    nb = -(-T // BS) if T < RING else None
+    r = np.minimum(rows, B - 1)
+    if S == 1:
+        return np.asarray(dsa.decode_selection(
+            x["idx"], x["ki"][r], x["qi"][r], x["wi"][r], x["q_pos"][r, None],
+            x["kv_pos"][r], x["bt"][r], x["slot0"][r, None], layer,
+            topk=TOPK_K, n_blocks=nb, scores=scores,
+        ))[:, None]
+    vis = attn.ragged_cache_visibility(x["q_len"], x["kv_pos"], x["slot0"], RING)
+    view = None if scores is not None else gather_block_view(
+        x["idx"], x["bt"][r], nb, layer)
+    return np.asarray(dsa.chunk_selection(
+        view, x["ki"][r], x["qi"][r], x["wi"][r], x["q_pos"][r],
+        x["q_len"][r], x["kv_pos"][r], vis[r], topk=TOPK_K, scores=scores,
+    ))
+
+
+@pytest.mark.parametrize("name", list(INDEX_CALLS))
+def test_the_walks_scores_select_the_same_bits(name, monkeypatch):
+    """``keep_topk`` over the kernel's scores against ``decode_selection`` /
+    ``chunk_selection`` over the gathered view, distinct keys at ``topk`` 16:
+    0 positions of a live query chosen otherwise (docs/sparse-attention.md's
+    count); and NaN planted wherever the walk need not go (past a row's held
+    blocks, a row with nothing live, a feeding slot that holds no row)
+    changes no bit of a live row: a score is looked at under ``see`` alone."""
+    call = INDEX_CALLS[name]
+    if "widths" in call:
+        monkeypatch.setattr(pallas_dsa, "_INDEX_CHUNK_SLOTS", call["widths"][0])
+        monkeypatch.setattr(pallas_dsa, "_INDEX_TILE", call["widths"][1])
+    x, rows = _index_inputs(call)
+    T = x["kv_pos"].shape[1]
+    layer = L - 1
+    got = _walked(x, rows, layer, call.get("widths"))
+    held = np.where(
+        (rows < B) & (np.asarray(call["lens"] + [0])[rows] > 0),
+        np.minimum(np.asarray(x["nblk"])[np.minimum(rows, B - 1)] * BS, T), 0,
+    )
+    unvisited = np.arange(T)[None, :] >= held[:, None]  # [rows, T]
+    planted = jnp.where(unvisited[:, None, :], jnp.nan, got)
+    assert np.isnan(np.asarray(planted)).any()
+    want = _selected(x, rows, layer, None)
+    kept = 0
+    for scores in (got, planted):
+        keep = _selected(x, rows, layer, scores)
+        for i, b in enumerate(rows):
+            n = 0 if b == B else call["lens"][b]
+            np.testing.assert_array_equal(keep[i, :n], want[i, :n])
+            kept += int(want[i, :n].sum())
+    assert kept > 0
+
+
+def test_the_index_walks_chunk_at_the_cells_shapes():
+    """The cell's indexer pool holds 512 B a slot, a quarter of a slot of
+    keys and values: a chunk of the walk is 1,024 slots at both call shapes
+    (PERF.md section 6, PR 52: the widths tried on the chip), a row's
+    scores over its ring of 16,896 beside it in VMEM."""
+    f32 = jnp.float32
+    assert pallas_dsa.index_chunk_slots(16, 16, 128, 32, 16896, f32) == 1024
+    assert pallas_dsa.index_chunk_slots(16, 16, 128, 1, 16896, f32) == 1024
+
+
+@pytest.mark.parametrize(
+    "kwargs",
+    [
+        dict(n_heads=4),  # heads that are not whole sublane tiles
+        dict(width=64),  # a slot that is not whole lanes
+        dict(block_size=4),
+        dict(chunk=33),  # more queries than a keep word has bits
+        dict(chunk=0),
+        dict(n_slots=262144),  # a row's scores over its ring: VMEM
+        dict(dtype=jnp.bfloat16),  # the selection is float32's
+    ],
+)
+def test_index_supports_refuses_what_the_walk_cannot_take(kwargs):
+    shapes = dict(block_size=16, n_heads=16, width=128, chunk=32,
+                  n_slots=16896, dtype=jnp.float32)
+    assert pallas_dsa.index_supports(**shapes)
+    assert not pallas_dsa.index_supports(**(shapes | kwargs))
+    if "n_slots" not in kwargs:
+        return
+    pool = jnp.zeros((1, 4, 16, 128), jnp.float32)
+    with pytest.raises(ValueError, match="pallas_dsa does not score"):
+        pallas_dsa.idx_paged_scores(
+            jnp.zeros((1, 32, 16, 64)), jnp.zeros((1, 32, 16)), pool,
+            jnp.ones((1,), jnp.int32), jnp.zeros((1, 4), jnp.int32),
+            jnp.ones((1,), jnp.int32), jnp.int32(0),
+            n_slots=kwargs["n_slots"], interpret=True,
+        )

@@ -308,6 +308,7 @@ def test_greedy_tokens_equal_under_the_kernel_and_the_gather(
                  if sp[2] == "sched.dispatch"]
         assert {a["kind"] for a in spans} == {"ragged_group", "decode_group"}
         reads[impl] = {a["attn_read"] for a in spans}
+        assert {a["index_read"] for a in spans} == {"none"}  # no indexer
         for a in spans:
             assert 0 <= a["blocks_read"] <= a["blocks_ring"]
     assert reads == {"xla": {"gather"}, "pallas": {"kv.kernel"}}

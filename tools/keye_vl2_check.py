@@ -10,8 +10,9 @@ every late query drops positions; no more rows feed at once than a step
 works: ``models/decoder.py: feed_rows``; with ``--mixed-rows`` above that cap
 the step runs as the cell's does: the feeding rows' selection laid over every
 row's first query's). The row says which read the step was traced with
-(``attn_read``: ``dsa.kernel`` on a TPU since PR 48); ``--control`` adds the
-same step on the reference module's faulty parameters.
+(``attn_read``: ``dsa.kernel`` on a TPU since PR 48) and how it scored the
+indexer's pool (``index_read``: ``idx.kernel`` there since PR 52); ``--control``
+adds the same step on the reference module's faulty parameters.
 
 ``--steps 16,32,64,128``: what one mixed step costs at each chunk, at the
 cell's rows and ring with every row live at ``--context`` tokens and as many
@@ -78,6 +79,12 @@ def profiled(run, say, what, n_steps):
                              for name, s in out.get("ops", [])[:25]]})
 
 
+def reads(engine, cache, chunk):
+    """How a step of ``chunk`` tokens a row reads the pools, as traced here."""
+    return {f.__name__: f(engine.cfg, cache, engine.mesh, chunk)
+            for f in (decoder.attn_read, decoder.index_read)}
+
+
 def step_times(engine, rows, chunks, context, say, n_steps=4, reps=3,
                profile=False):
     """Milliseconds a step of the mixed group at each chunk (``rows`` live
@@ -124,8 +131,7 @@ def step_times(engine, rows, chunks, context, say, n_steps=4, reps=3,
         eng.params, tok, cache, pos, sa, live, eos,
         n_chunks=1, n_steps=n_steps, t_bucket=None), "the decode group")
     say({"what": "decode group", "rows": R, "context": context,
-         "attn_read": decoder.attn_read(eng.cfg, fresh()[1], eng.mesh, 1),
-         "ms_per_step": ms})
+         **reads(eng, fresh()[1], 1), "ms_per_step": ms})
     for C in chunks:
         cap = decoder.feed_rows(eng.cfg, fresh()[1], C) or R
         q = np.ones((n_steps, R), np.int32)
@@ -137,7 +143,7 @@ def step_times(engine, rows, chunks, context, say, n_steps=4, reps=3,
             jnp.asarray(feed), jnp.asarray(~feed)),
             f"the mixed group at {C}")
         say({"what": "mixed group", "rows": R, "context": context, "chunk": C,
-             "rows_feeding": cap, "tokens_fed_a_step": cap * C,
+             **reads(eng, fresh()[1], C), "rows_feeding": cap, "tokens_fed_a_step": cap * C,
              "ms_per_step": ms,
              "ms_per_fed_token": min(ms) / (cap * C)})
 
@@ -245,8 +251,7 @@ def main():
             row = {"control": check.logits_error(lost[0], want[0]),
                    "control_fault": fault}
         say({"what": "program", "path": "mixed", "chunk": args.chunk,
-             "attn_read": decoder.attn_read(
-                 cfg, engine.new_paged_cache(1), mesh, args.chunk),
+             **reads(engine, engine.new_paged_cache(1), args.chunk),
              "rows_feeding_at_once": cap or len(prompts), **row,
              "prompt_lens": [len(p) for p in prompts], "logits": errs,
              "rms": [check.logits_error(pre, want[0], rms=True),
