@@ -19,9 +19,12 @@ wins (cached slots ascending, then the step's own fresh tokens: position
 order for a row that has not wrapped its ring). ``keep_topk`` finds the
 ``topk``-th largest score by a bisection over the 32 bits of a float's place
 in the order of floats (``ops/sampling.py``'s idea: a fixed number of
-compare-and-count passes, no sort) and cuts the tie group at it by a running
-count, so the rule does not rest on how a sort or a ``top_k`` primitive
-orders equal values.
+compare-and-count passes, no sort) and cuts the tie group at it by a SECOND
+bisection, over the bits of an index: the greatest index with no more of the
+tie group before it than there is room for. ``32 + N.bit_length()`` passes
+over ``N`` candidates (47 at a ring of 16,896) and no running count: on the
+chip a ``cumsum`` over the same array cost what 175 passes do. The rule does
+not rest on how a sort or a ``top_k`` primitive orders equal values.
 
 The SELECTION is made here whatever reads the keys and values:
 ``decode_selection`` (a row's one query over its view of the indexer pool)
@@ -104,8 +107,16 @@ def keep_topk(scores, k: int):
     )
     above, tie = key > thr, key == thr
     room = k - count(above)
-    keep = above | (tie & (jnp.cumsum(tie, axis=-1, dtype=jnp.int32) <= room))
-    return keep & (scores > -jnp.inf)
+    N = key.shape[-1]
+    idx, nb = jnp.arange(N, dtype=jnp.int32), N.bit_length()
+
+    def cut(i, lo):
+        # the greatest index with at most ``room`` of the tie group before it
+        cand = lo | (jnp.int32(1 << (nb - 1)) >> i)
+        return jnp.where(count(tie & (idx < cand)) <= room, cand, lo)
+
+    stop = jax.lax.fori_loop(0, nb, cut, jnp.zeros_like(room))
+    return (above | (tie & (idx < stop))) & (scores > -jnp.inf)
 
 
 def _to_pool_width(W: int, qi, ki_new):

@@ -594,7 +594,9 @@ def test_a_selection_inside_paged_attention_fits_beside_three_pools(
     indexer pool is gathered (all rows' 277 MB, the feeding slots' 61 MB) and
     no score a head stands in HBM (``f32[7,32,16,16896]``, 242 MB): the
     temporaries are 0.04 / 0.06 GB where they were 0.32 / 0.35 (and the mask
-    form's scores made the mixed group's 0.80)."""
+    form's scores made the mixed group's 0.80). Since PR 53 the selection
+    holds no running count over a ring (``s32[7,32,133,128]``: it was the
+    cell's largest device op)."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(
             root, "benchmark", "cells",
@@ -645,6 +647,15 @@ def test_a_selection_inside_paged_attention_fits_beside_three_pools(
     assert scores == []
     # the ``top_k`` that compacted a row's kept slots (the experts sort pairs)
     assert [d for _, d in _results(text, "sort") if ring in d] == []
+    # no running count over a ring (PR 53: ``keep_topk`` cuts its tie group
+    # at an index found by bisection): what is left of ``reduce-window`` is
+    # the walks' and the experts' offsets, a few hundred numbers
+    lanes = -(-(ring + 1) // 128)
+    for counted in ((fed, rows, lanes, 128), (rows, lanes, 128)):
+        assert f"s32[{','.join(map(str, counted))}]" not in text, counted
+    windows = re.findall(r"= \w+\[([\d,]+)\]\S* reduce-window\(", text)
+    assert windows and all(
+        math.prod(map(int, d.split(","))) <= 1024 for d in windows), windows
     ma = compiled.memory_analysis()
     assert ma.argument_size_in_bytes == pytest.approx(10.69e9, rel=0.01)
     assert ma.temp_size_in_bytes < (0.45e9 if program == "ragged" else 0.4e9)

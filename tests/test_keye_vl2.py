@@ -445,6 +445,74 @@ def test_keep_topk_counts_exactly_and_ties_go_to_the_earlier():
     np.testing.assert_array_equal(keep, (rank < 40) & np.isfinite(x))
 
 
+def _running_count_keep(scores, k):
+    """The oracle of ``keep_topk``'s tie cut: the ``k``-th largest value from
+    a sort, and of the values equal to it the first ``room`` by a RUNNING
+    COUNT (``keep_topk``'s own last line until PR 53)."""
+    x = np.where(scores == 0, np.float32(0), scores)  # the two zeros are one
+    N = x.shape[-1]
+    thr = np.sort(x, axis=-1)[..., ::-1][..., min(k, N) - 1:min(k, N)]
+    above, tie = x > thr, x == thr
+    room = k - above.sum(-1, keepdims=True)
+    return (above | (tie & (np.cumsum(tie, axis=-1) <= room))) & (x > -np.inf)
+
+
+def _tie_cases():
+    rng = np.random.default_rng(53)
+    normal = lambda *shape: rng.standard_normal(shape).astype(np.float32)
+    straddle = normal(6, 40)
+    straddle[:, 3::4] = 0.25  # ten equal scores a row, and the cut among them
+    straddle[:, ::4] = np.abs(straddle[:, ::4]) + 1.0
+    few = normal(5, 50)
+    few[:, 7:] = -np.inf
+    few[2, :] = -np.inf
+    return {
+        "tie_group_straddles_the_cut": (straddle, 14),
+        "every_score_equal": (np.full((3, 77), 1.5, np.float32), 20),
+        "fewer_candidates_than_k": (few, 16),
+        "k_at_least_n": (np.round(normal(4, 24), 1), 24),
+        "k_beyond_n": (np.round(normal(4, 24), 1), 100),
+        "n_is_one": (np.asarray([[0.5], [-np.inf], [0.0]], np.float32), 3),
+        "n_one_under_a_power_of_two": (np.round(normal(4, 127), 0), 50),
+        "n_a_power_of_two": (np.round(normal(4, 128), 0), 50),
+        "n_one_over_a_power_of_two": (np.round(normal(4, 129), 0), 50),
+        "the_cells_width_rounded_to_two_places": (
+            np.round(normal(2, 3, 16928), 2), 2048),
+        "tie_free_normal": (normal(8, 1000), 64),
+    }
+
+
+_TIE_CASES = _tie_cases()
+
+
+@pytest.mark.parametrize("scores,k", _TIE_CASES.values(), ids=list(_TIE_CASES))
+def test_keep_topk_is_the_running_count_bit_for_bit(scores, k):
+    """``keep_topk`` cuts its tie group at an INDEX found by a second
+    bisection (PR 53); the set is the running count's, bit for bit."""
+    keep = np.asarray(jax.jit(dsa.keep_topk, static_argnums=1)(
+        jnp.asarray(scores), k))
+    np.testing.assert_array_equal(keep, _running_count_keep(scores, k))
+    assert (keep.sum(-1) == np.minimum(np.isfinite(scores).sum(-1), k)).all()
+
+
+def test_keep_topk_holds_no_running_count():
+    """No ``cumsum`` / ``cumlogsumexp`` / ``reduce_window`` at any depth of
+    ``keep_topk``'s jaxpr: on the chip one running count over a mixed
+    step's ``[7, 32, 16,928]`` cost what 175 compare-and-count passes do."""
+    def primitives(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn.primitive.name
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from primitives(sub)
+
+    jaxpr = jax.make_jaxpr(lambda s: dsa.keep_topk(s, 2048))(
+        jnp.zeros((7, 32, 16928), jnp.float32))
+    names = set(primitives(jaxpr.jaxpr))
+    assert {"scan", "reduce_sum"} <= names, names  # the walk sees the loops
+    assert not [n for n in names
+                if n.startswith(("cum", "reduce_window"))], names
+
+
 def _recorded_selections(eng, prompts, monkeypatch):
     """The keep masks the program's prefill computes, ``[L][B, S, S]``
     (within the prompt: the cache is empty), recorded through a callback in
