@@ -2023,9 +2023,9 @@ class ContinuousBatcher:
     def _pool_read(self, chunk: int, t_bucket: int | None) -> dict:
         """What the group about to be dispatched reads of the paged pool, for
         its ``sched.dispatch`` span: ``models.decoder``'s ``attn_read`` (the
-        read its program is traced with), ``index_read`` (how it scores an
-        indexer's pool: ``none`` without one) and ``state_update`` (how it
-        updates a recurrent state: ``xla`` without one);
+        read its program is traced with), ``attn_form`` (``kv.kernel``'s work
+        on a chunk, or ``none``), ``index_read`` (an indexer's pool's, or
+        ``none``) and ``state_update`` (a state's: ``xla`` without one);
         ``blocks_read``, the blocks its live rows hold at the group's first
         step (a read that stops at a row's length visits these), over
         ``blocks_ring``, the columns a read of every row's whole ring or read
@@ -2038,7 +2038,7 @@ class ContinuousBatcher:
                 f.__name__: f(
                     self.engine.cfg, self.cache, self.engine.mesh, chunk
                 )
-                for f in (d.attn_read, d.index_read, d.state_update)
+                for f in (d.attn_read, d.attn_form, d.index_read, d.state_update)
             }
         bs, mb = self.cache.block_size, self.cache.max_blocks
         if t_bucket is not None:

@@ -2817,3 +2817,18 @@ def _make_selected_read(
             )
 
     return attn
+
+
+def attn_form(cfg: DecoderConfig, cache: PagedKVCache, mesh, chunk: int) -> str:
+    """What ``attn_read``'s ``kv.kernel`` does with a landed chunk of the
+    walk in a decode step (``chunk`` 1) or a mixed step, as its program is
+    traced NOW: ``heads`` (all heads of a chunk in one product: a pool with
+    a KV head a query head or nearly, whose head alone cannot fill a tile),
+    ``head`` (a product a KV head) or ``none`` (another read). The rule is
+    the kernel's own, on shapes alone (``ops/pallas_kv.py: attn_form``)."""
+    from llmss_tpu.ops import pallas_kv
+
+    if attn_read(cfg, cache, mesh, chunk) != "kv.kernel":
+        return "none"
+    Hq, Hkv = _kernel_heads(cfg, cache)
+    return pallas_kv.attn_form(Hq, Hkv, chunk, cache.k.dtype)

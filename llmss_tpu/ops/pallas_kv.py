@@ -20,6 +20,14 @@ reads both pools where they lie, with the walk of ``ops/pallas_mla.py``:
 * per KV head: scores ``q_h . k_h^T`` over the chunk for the head's ``CB *
   G`` query rows, online softmax with the running max, sum and accumulator
   in float32 in VMEM, ``p . v_h``: no score reaches HBM;
+* where a KV head has too few query rows for its product to fill a tile
+  (``attn_form``'s ``heads``: a KV head a query head, or nearly), ALL heads
+  of a landed chunk in one product instead: the chunk ``[KS, Hkv, D]`` is
+  ``[KS * Hkv, D]`` byte for byte, so ``Q [CB * Hq, D] . K^T`` gives every
+  query row's scores over every (slot, head) along the lanes, of which a
+  row keeps its own head's (the mask) and the rest is computed and thrown
+  away; the same online softmax over a row's lanes, then ``P . V [KS *
+  Hkv, D]``. No strided load, no shift, no loop over heads;
 * a row that decodes in a mixed step (``q_len == 1``) scores its first
   query's heads alone, not the chunk's ``CB * G`` query rows a head.
 
@@ -63,23 +71,46 @@ _BUFFER_BYTES = 4 * 2**20  # the two buffer pairs: 128 slots of 8 KB a pool
 _COPIES_UNROLLED = 2  # blocks a pool written out in a turn of the issue loop
 # Head pairs (or heads) written out in a turn of their loop: at 32 heads of
 # 128 the same read took 1.84 ms at one, 1.32 at two and 1.21 at four, which
-# lowers 0.2 s a program slower.
+# lowers 0.2 s a program slower (that shape has no turns since PR 56:
+# ``attn_form``).
 _TURNS_UNROLLED = 2
 # Fresh keys are padded to one sublane tile of the widest dtype served.
 _FRESH_ROWS = 16
 _VMEM_BUDGET = 12 * 2**20  # under the 16 MiB a v5e kernel may scope
 
 
-def _vmem_bytes(ks: int, rows: int, Hkv: int, D: int, itemsize: int) -> int:
+def attn_form(n_heads: int, n_kv_heads: int, chunk: int, dtype) -> str:
+    """What is done with a landed chunk at these shapes. ``head``: a product
+    a KV head (a head pair a turn), which hands the matrix unit a tile or
+    more a head where a KV head has a tile of query rows. ``heads``: ONE
+    product a chunk over all its heads, where a KV head has fewer query rows
+    than the 8 sublanes of a float32 tile, all heads' rows together are
+    within 128, and a slot's heads are whole tiles (so that ``[slots, Hkv,
+    D]`` is ``[slots * Hkv, D]`` byte for byte)."""
+    rows = chunk * (n_heads // n_kv_heads)
+    sublanes = 32 // jnp.dtype(dtype).itemsize
+    if rows < 8 and n_kv_heads * rows <= 128 and n_kv_heads % sublanes == 0:
+        return "heads"
+    return "head"
+
+
+def _vmem_bytes(
+    ks: int, rows: int, Hkv: int, D: int, itemsize: int, heads: bool = False
+) -> int:
     """The working set at a chunk of ``ks`` slots; ``rows`` query rows a KV
-    head. A tile is 8 sublanes of 32 bits: fewer rows still fill one."""
-    rows = -(-rows // 8) * 8
+    head. A tile is 8 sublanes of 32 bits: fewer rows still fill one. Under
+    ``heads`` the scores are every query row's over every (slot, head)."""
+    scores = 3 * rows * ks * 4  # one head's scores, probabilities, mask
+    if heads:
+        scores *= Hkv * Hkv
+    else:
+        rows = -(-rows // 8) * 8
     return (
         4 * Hkv * rows * D * itemsize  # q and out, double-buffered
         + 4 * Hkv * _FRESH_ROWS * D * itemsize  # fresh keys and values
         + 4 * ks * Hkv * D * itemsize  # the two buffer pairs
         + Hkv * rows * (D + 2 * 128) * 4  # accumulator, running max and sum
-        + 3 * rows * ks * 4  # one head's scores, probabilities, mask
+        + scores
     )
 
 
@@ -103,13 +134,15 @@ def chunk_slots(
     ):
         return None
     rows = chunk * (n_heads // n_kv_heads)
+    heads = attn_form(n_heads, n_kv_heads, chunk, dtype) == "heads"
     for ks in _CHUNK_SLOTS:
         if (
             ks % (block_size * _COPIES_UNROLLED) == 0
             and 4 * ks * n_kv_heads * head_dim * dtype.itemsize
             <= _BUFFER_BYTES
-            and _vmem_bytes(ks, rows, n_kv_heads, head_dim, dtype.itemsize)
-            <= _VMEM_BUDGET
+            and _vmem_bytes(
+                ks, rows, n_kv_heads, head_dim, dtype.itemsize, heads
+            ) <= _VMEM_BUDGET
         ):
             return ks
     return None
@@ -156,11 +189,22 @@ def _kernel(
     group: int,
     block_size: int,
     ring_len: int,
+    heads: bool,
 ):
+    """``heads`` (``attn_form``'s ``heads``): the operands come as the pool
+    lies, slots major and a slot's heads dense, with no head axis of their
+    own: ``kvp_ref`` [1, NC, KS * Hkv] (a slot's position under each of its
+    heads), ``q_ref`` / ``o_ref`` [1, CB * Hq, D] (query-major), ``kn_ref``
+    / ``vn_ref`` [1, F, Hkv, D], ``m_ref`` / ``l_ref`` [CB * Hq, 128],
+    ``acc_ref`` [CB * Hq, D]."""
     b = pl.program_id(0)
     n_rows = pl.num_programs(0)
-    KS, Hkv = kbuf_ref.shape[1], q_ref.shape[1]
-    R, F = q_ref.shape[2], kn_ref.shape[2]
+    KS = kbuf_ref.shape[1]
+    if heads:
+        F, Hkv = kn_ref.shape[1:3]
+        R = q_ref.shape[1] // Hkv
+    else:
+        Hkv, R, F = q_ref.shape[1], q_ref.shape[2], kn_ref.shape[2]
     K = KS // block_size
     cols = kvp_ref.shape[1] * K  # table columns a row has here
     layer = layer_ref[0]
@@ -199,29 +243,37 @@ def _kernel(
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # query row r = i * group + g of a KV head belongs to query i, at qp + i
-    q_idx = jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0) // group
-    q_pos = qp + q_idx  # [R, 1]
+    # query row r = i * group + g of a KV head belongs to query i, at qp + i;
+    # under ``heads`` row i * Hq + h * group + g does, and to KV head h
+    Hq = Hkv * group
+    q_row = jax.lax.broadcasted_iota(
+        jnp.int32, (R * Hkv if heads else R, 1), 0
+    )
+    q_idx = q_row // (Hq if heads else group)
+    q_head = q_row % Hq // group if heads else None
+    q_pos = qp + q_idx  # [R, 1]; [R * Hkv, 1]
 
-    def update(h, rows, keys, vals, mask):
-        """One online-softmax step of KV head ``h``'s first ``rows`` query
-        rows over ``keys`` / ``vals`` [T', D] under ``mask`` [rows, T']."""
+    def update(at, rows, keys, vals, mask):
+        """One online-softmax step of the first ``rows`` query rows ``at``
+        a KV head ``(h,)``, or of all heads ``()``, over ``keys`` / ``vals``
+        [T', D] under ``mask`` [rows, T']."""
+        top = (*at, slice(rows))
         s = jax.lax.dot_general(
-            q_ref[0, h, :rows], keys, (((1,), (1,)), ((), ())),
+            q_ref[(0, *top)], keys, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale
         s = jnp.where(mask, s, _NEG_INF)
-        m_prev, l_prev = m_ref[h, :rows, :1], l_ref[h, :rows, :1]
+        m_prev, l_prev = m_ref[(*top, slice(1))], l_ref[(*top, slice(1))]
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         # a row that sees nothing yet has its max at the float32 min, and
         # exp(s - m) would be exp(0): zero what the mask hides
         p = jnp.where(mask, jnp.exp(s - m_next), 0.0)
         alpha = jnp.exp(m_prev - m_next)
-        l_ref[h, :rows, :1] = alpha * l_prev + jnp.sum(
+        l_ref[(*top, slice(1))] = alpha * l_prev + jnp.sum(
             p, axis=1, keepdims=True
         )
-        m_ref[h, :rows, :1] = m_next
-        acc_ref[h, :rows] = acc_ref[h, :rows] * alpha + jax.lax.dot_general(
+        m_ref[(*top, slice(1))] = m_next
+        acc_ref[top] = acc_ref[top] * alpha + jax.lax.dot_general(
             p.astype(vals.dtype), vals, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
@@ -290,6 +342,64 @@ def _kernel(
 
         jax.lax.fori_loop(0, count // u, some, 0)
 
+    def lanes(slots):
+        """``(slot, KV head)`` of every lane of the scores over ``slots``
+        slots, [1, lanes] each: a lane a slot (no head) or, under ``heads``,
+        a slot's heads side by side."""
+        if not heads:
+            return jax.lax.broadcasted_iota(jnp.int32, (1, slots), 1), None
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, slots * Hkv), 1)
+        return lane // Hkv, lane % Hkv
+
+    def flat(x):  # [T', Hkv, D] as [T' * Hkv, D]: the same bytes
+        return x.reshape(-1, x.shape[-1])
+
+    def visible(rows, c):
+        """What the first ``rows`` query rows see of chunk ``c``'s lanes."""
+        kvp = kvp_ref[0, pl.ds(c, 1), :]  # [1, lanes]
+        slot_idx = c * KS  # the chunk's first slot, then each lane's
+        t, h = lanes(KS)
+        slot_idx += t
+        d = slot_idx - slot0
+        d = jnp.where(d < 0, d + ring_len, d)
+        seen = (kvp >= 0) & (d >= qlen)
+        # hidden slots are given a position no query reaches
+        mask = jnp.where(seen, kvp, _BIG) <= q_pos[:rows]  # [rows, lanes]
+        if window is not None:
+            mask &= kvp > q_pos[:rows] - window
+        if heads:  # of every (slot, head), a row keeps its own head's
+            mask &= h == q_head[:rows]
+        return mask
+
+    def head_by_head(rows, c, slot):
+        mask = visible(rows, c)
+        if rows == 1:
+            # [1, KS] along the lanes to [KS, 1] down the sublanes, by a
+            # transpose of whole tiles, once for the chunk's heads
+            down = jnp.broadcast_to(mask.astype(jnp.float32), (KS, KS))
+            mask = down.T[:, :1] > 0.5
+        dtype = jnp.float32 if rows == 1 else kbuf_ref.dtype
+
+        def turn(j):
+            for (h, keys), (_, vals) in zip(
+                heads_of(kbuf_ref, slot, j, dtype),
+                heads_of(vbuf_ref, slot, j, dtype),
+            ):
+                if rows == 1:
+                    update_one(h, keys, vals, mask)
+                else:
+                    update((h,), rows, keys, vals, mask)
+
+        each(turns, turn)
+
+    def all_heads(rows, c, slot):
+        """The landed chunk as ONE [KS * Hkv, D] operand a pool: every
+        query row against every (slot, head)."""
+        update(
+            (), rows, flat(kbuf_ref[slot]), flat(vbuf_ref[slot]),
+            visible(rows, c),
+        )
+
     # the first row that walks anything starts its own first chunk
     @pl.when((base == 0) & (n > 0))
     def _():
@@ -311,58 +421,36 @@ def _kernel(
                 )
 
             land(slot)
-
-            kvp = kvp_ref[0, pl.ds(c, 1), :]  # [1, KS]
-            slot_idx = c * KS + jax.lax.broadcasted_iota(
-                jnp.int32, (1, KS), 1
-            )
-            d = slot_idx - slot0
-            d = jnp.where(d < 0, d + ring_len, d)
-            seen = (kvp >= 0) & (d >= qlen)
-            # hidden slots are given a position no query reaches
-            mask = jnp.where(seen, kvp, _BIG) <= q_pos[:rows]  # [rows, KS]
-            if window is not None:
-                mask &= kvp > q_pos[:rows] - window
-            if rows == 1:
-                # [1, KS] along the lanes to [KS, 1] down the sublanes, by
-                # a transpose of whole tiles, once for the chunk's heads
-                down = jnp.broadcast_to(mask.astype(jnp.float32), (KS, KS))
-                mask = down.T[:, :1] > 0.5
-            dtype = jnp.float32 if rows == 1 else kbuf_ref.dtype
-
-            def turn(j):
-                for (h, keys), (_, vals) in zip(
-                    heads_of(kbuf_ref, slot, j, dtype),
-                    heads_of(vbuf_ref, slot, j, dtype),
-                ):
-                    if rows == 1:
-                        update_one(h, keys, vals, mask)
-                    else:
-                        update(h, rows, keys, vals, mask)
-
-            each(turns, turn)
+            (all_heads if heads else head_by_head)(rows, c, slot)
             return carry
 
         jax.lax.fori_loop(0, n, chunk, 0)
 
-    if R > group:
+    # the query rows of a chunk, and those of its first query
+    every, first = (R * Hkv, Hq) if heads else (R, group)
+    if every > first:
         # a row that decodes (one live query) scores its first query's
         # heads alone: the other CB - 1 queries are padding
-        pl.when(qlen == 1)(lambda: walk(group))
-        pl.when(qlen != 1)(lambda: walk(R))
+        pl.when(qlen == 1)(lambda: walk(first))
+        pl.when(qlen != 1)(lambda: walk(every))
     else:
-        walk(R)
+        walk(every)
 
     # fresh key j is seen by query i iff j <= i and j < q_len (and inside
     # the window); key 0 by every query past q_len, so that none sees nothing
-    j = jax.lax.broadcasted_iota(jnp.int32, (1, F), 1)
+    j, h = lanes(F)
     tri = (j <= q_idx) & (j < qlen)
     if window is not None:
         tri &= q_idx - j < window
     tri |= (j == 0) & (q_idx >= qlen)
 
+    if heads:
+        update((), every, flat(kn_ref[0]), flat(vn_ref[0]), tri & (h == q_head))
+        o_ref[0] = (acc_ref[...] / l_ref[:, :1]).astype(o_ref.dtype)
+        return
+
     def merged(h):
-        update(h, R, kn_ref[0, h], vn_ref[0, h], tri)
+        update((h,), R, kn_ref[0, h], vn_ref[0, h], tri)
         o_ref[0, h] = (acc_ref[h] / l_ref[h, :, :1]).astype(o_ref.dtype)
 
     each(Hkv, merged)
@@ -406,6 +494,7 @@ def kv_paged_attention(
             f"pallas_kv does not take bs={bs}, Hq={Hq}, Hkv={Hkv}, D={D}, "
             f"chunk={CB}, {k_pool.dtype}"
         )
+    heads = attn_form(Hq, Hkv, CB, k_pool.dtype) == "heads"
     if scale is None:
         scale = 1.0 / (D**0.5)
     K = KS // bs
@@ -432,6 +521,8 @@ def kv_paged_attention(
         return x.reshape(B, Hkv, CB * n, D)
 
     def fresh(x):
+        if heads:  # as the pool lies: [B, F, Hkv, D]
+            return jnp.pad(x, ((0, 0), (0, F - CB), (0, 0), (0, 0)))
         return jnp.pad(by_head(x, 1), ((0, 0), (0, 0), (0, F - CB), (0, 0)))
 
     # One head: its unit axis is not the pool's second-minor one on the
@@ -440,6 +531,11 @@ def kv_paged_attention(
     buf = (2, KS, D) if Hkv == 1 else (2, KS, Hkv, D)
     if Hkv == 1:
         k_pool, v_pool = (x.reshape(L, N, bs, D) for x in (k_pool, v_pool))
+    # the query rows and the softmax state: a KV head's, or all heads'
+    # query-major as they come (no transpose around the call)
+    rows_of = (CB * Hq,) if heads else (Hkv, R)
+    if heads:
+        kvp = jnp.repeat(kvp, Hkv, axis=2)
 
     def row(shape):
         return pl.BlockSpec(
@@ -447,33 +543,34 @@ def kv_paged_attention(
             memory_space=pltpu.VMEM,
         )
 
+    fresh_block = row((F, Hkv, D) if heads else (Hkv, F, D))
     out = pl.pallas_call(
         functools.partial(
             _kernel, scale=float(scale), window=window, group=G,
-            block_size=bs, ring_len=ring_len,
+            block_size=bs, ring_len=ring_len, heads=heads,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=8,
             grid=(B,),
             in_specs=[
-                row((NC, KS)),
-                row((Hkv, R, D)),
+                row(kvp.shape[1:]),
+                row(rows_of + (D,)),
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
-                row((Hkv, F, D)),
-                row((Hkv, F, D)),
+                fresh_block,
+                fresh_block,
             ],
-            out_specs=row((Hkv, R, D)),
+            out_specs=row(rows_of + (D,)),
             scratch_shapes=[
                 pltpu.VMEM(buf, k_pool.dtype),
                 pltpu.VMEM(buf, v_pool.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
-                pltpu.VMEM((Hkv, R, 128), jnp.float32),
-                pltpu.VMEM((Hkv, R, 128), jnp.float32),
-                pltpu.VMEM((Hkv, R, D), jnp.float32),
+                pltpu.VMEM(rows_of + (128,), jnp.float32),
+                pltpu.VMEM(rows_of + (128,), jnp.float32),
+                pltpu.VMEM(rows_of + (D,), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, R, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B,) + rows_of + (D,), q.dtype),
         # rows in order: a row's last chunk starts the next row's first
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
@@ -485,7 +582,10 @@ def kv_paged_attention(
         nc, start.astype(jnp.int32), nxt,
         q_pos.astype(jnp.int32).reshape(B), q_len,
         slot0.astype(jnp.int32).reshape(B),
-        kvp, by_head(q, G), k_pool, v_pool, fresh(k_new), fresh(v_new),
+        kvp, q.reshape(B, -1, D) if heads else by_head(q, G),
+        k_pool, v_pool, fresh(k_new), fresh(v_new),
     )
+    if heads:
+        return out.reshape(B, CB, Hq, D)
     out = out.reshape(B, Hkv, CB, G, D).transpose(0, 2, 1, 3, 4)
     return out.reshape(B, CB, Hq, D)

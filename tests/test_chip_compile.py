@@ -23,6 +23,7 @@ Plus: where ``initialize_runtime()`` puts the persistent compile cache.
 import dataclasses
 import functools
 import importlib
+import inspect
 import json
 import math
 import os
@@ -39,7 +40,7 @@ from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 from llmss_tpu.engine import DecodeEngine
 from llmss_tpu.engine.cache import PagedKVCache, ssm_state_shapes
 from llmss_tpu.models.decoder import (
-    attn_read, index_read, param_shapes, param_specs,
+    attn_form, attn_read, index_read, param_shapes, param_specs,
 )
 from llmss_tpu.models.registry import config_from_hf
 from llmss_tpu.ops import (
@@ -222,6 +223,13 @@ KV_READS = {
     for step, chunk in (("decode", 1), ("step-of-4", 4))
 }
 
+# the reads above whose chunks are worked all heads at once (``attn_form``):
+# a KV head a query head, whole tiles of them; the others a head at a time
+ALL_HEADS = {
+    "kv-olmo-hybrid-decode", "kv-olmo-hybrid-step-of-4", "kv-gpt-j-6b-decode",
+    "kv-gpt-j-6b-step-of-4",
+}
+
 # (query heads, KV heads, (head size, layers, blocks a row, tokens a row a
 # step)) of the read of both pools under a selection
 SELECTED_READS = {
@@ -248,6 +256,15 @@ INDEX_SCORES = {
     + [("index_scores", step) for step in INDEX_SCORES],
 )
 def test_kernel_compiles_for_v5e(v5e, kernel, model):
+    """Each kernel at its cells' shapes, compiled as the chip compiles it;
+    none asks for more VMEM than a v5e kernel may scope by default (no
+    ``vmem_limit_bytes`` but ``pallas_gdn``'s), so the compile holds that
+    too: the read of keys and values in both its forms."""
+    if kernel == "kv_read":
+        Hq, Hkv, (*_, chunk) = KV_READS[model]
+        assert pallas_kv.attn_form(Hq, Hkv, chunk, DT) == (
+            "heads" if model in ALL_HEADS else "head")
+        assert "vmem_limit_bytes" not in inspect.getsource(pallas_kv)
     fn, shapes = _kernel_call(
         kernel,
         *(WIDTHS | LATENT_READS | STATE_UPDATES | DELTA_UPDATES | KV_READS
@@ -837,6 +854,13 @@ POOL_READS = {
 }
 READS = {"gather", "kv.kernel", "mla.kernel", "dsa.kernel", "dsa.tokens",
          "dsa.mask"}
+# ``attn_form`` beside it: the configurations whose ``kv.kernel`` works all
+# heads of a chunk in one product (a KV head a query head, whole tiles of
+# them); ``head`` under every other ``kv.kernel``, ``none`` under another read
+ATTN_FORMS = {
+    "olmo-hybrid-7b-1chip": "heads", "gpt-j-6b-l16": "heads",
+    "widths:gpt-j-6b": "heads",
+}
 # ``index_read`` beside it, as a TPU traces either step: how the indexer's
 # pool is scored; ``none`` for every configuration without an indexer, and
 # ``gather`` on the CPU for the one that has it
@@ -852,6 +876,7 @@ def test_every_configuration_file_has_its_read_in_the_table():
     assert files == {n.split("@")[0] for n in POOL_READS if ":" not in n}
     assert {n[len("widths:"):] for n in POOL_READS if ":" in n} == set(WIDTHS)
     assert {w for row in POOL_READS.values() for w in row[1:]} <= READS
+    assert set(ATTN_FORMS) <= set(POOL_READS)
 
 
 @pytest.mark.parametrize("name", POOL_READS)
@@ -875,6 +900,7 @@ def test_attn_read_names_the_read_of_every_configuration(v5e, name):
     mesh = mesh_mod.make_mesh(mesh_mod.MeshPlan(tp=1), devices=[v5e])
     on_cpu = {attn_read(cfg, cache, mesh, c) for c in (1, chunk)}
     assert on_cpu <= {"gather", "dsa.tokens", "dsa.mask"}, on_cpu
+    assert {attn_form(cfg, cache, mesh, c) for c in (1, chunk)} == {"none"}
     scored = INDEX_READS.get(name, "none")
     assert {index_read(cfg, cache, mesh, c) for c in (1, chunk)} == {
         "none" if cfg.indexer is None else "gather"}
@@ -885,6 +911,9 @@ def test_attn_read_names_the_read_of_every_configuration(v5e, name):
         )
         assert attn_read(cfg, cache, mesh, 1) == decode
         assert attn_read(cfg, cache, mesh, chunk) == mixed
+        for c, read in ((1, decode), (chunk, mixed)):
+            assert attn_form(cfg, cache, mesh, c) == (
+                ATTN_FORMS.get(name, "head") if read == "kv.kernel" else "none")
         assert attn_read(cfg, cache, None, chunk) == mixed
         assert {index_read(cfg, cache, mesh, c) for c in (1, chunk)} == {scored}
         if on_mesh:

@@ -127,6 +127,55 @@ CASES = {
     "three-layers-three-rows": dict(
         heads=(8, 2, 128), cb=1, mb=4, layers=3, ctx=[13, 5, 27], qlen=[1] * 3
     ),
+    # All heads of a chunk in one product (``attn_form``'s ``heads``: a KV
+    # head a query head, whole tiles of them), over a ring of ten blocks, two
+    # chunks and a half of the walk in float32: wrapped, rows that share their
+    # first blocks, nothing cached, a read bucket that ends inside a chunk,
+    # windows narrower than the rows and than the chunk, what the chip
+    # serves in, and two smaller pools that still take the form
+    "heads-ring-wrapped": dict(
+        heads=MHA_32x128, cb=4, mb=10, ctx=[170, 330, 95, 160],
+        qlen=[4, 1, 4, 3],
+    ),
+    "heads-shared-prefix": dict(
+        heads=MHA_32x128, cb=4, mb=10, ctx=[100, 64, 150, 33],
+        qlen=[3, 4, 1, 4], shared=True,
+    ),
+    "heads-nothing-cached": dict(
+        heads=MHA_32x128, cb=4, mb=10, ctx=[0, 0, 5, 0], qlen=[4, 1, 2, 0],
+        sentinel=True,
+    ),
+    "heads-bucketed-read": dict(
+        heads=MHA_32x128, cb=1, mb=10, ctx=[100, 45, 140, 77], qlen=[1] * 4,
+        t_bucket=112,
+    ),
+    "heads-sliding-window": dict(
+        heads=MHA_32x128, cb=4, mb=10, ctx=[150, 45, 300, 9],
+        qlen=[4, 1, 3, 4], window=20,
+    ),
+    "heads-sliding-window-3": dict(
+        heads=MHA_32x128, cb=4, mb=10, ctx=[150, 45, 2, 0], qlen=[4, 1, 3, 4],
+        window=3,
+    ),
+    "heads-bfloat16-chunk4": dict(
+        heads=MHA_32x128, cb=4, mb=10, ctx=[150, 0, 330, 77],
+        qlen=[0, 1, 4, 2], dtype=jnp.bfloat16, tol=2e-2,
+    ),
+    "heads-8x8-chunk4": dict(
+        heads=(8, 8, 128), cb=4, mb=10, ctx=[150, 45, 330, 77],
+        qlen=[0, 1, 4, 2],
+    ),
+    "heads-8x8-decode": dict(
+        heads=(8, 8, 128), cb=1, mb=10, ctx=[150, 0, 330, 16], qlen=[1] * 4
+    ),
+    "heads-bfloat16-16x16-d256-chunk2": dict(
+        heads=(16, 16, 256), cb=2, mb=10, ctx=[150, 45, 330, 77],
+        qlen=[2, 1, 0, 2], dtype=jnp.bfloat16, tol=2e-2,
+    ),
+}
+# the cases above that take ``heads``; every other takes ``head``
+ALL_HEADS = {n for n in CASES if n.startswith(("heads-", "mha-32x128"))} | {
+    "bfloat16-mha-decode"
 }
 
 
@@ -179,6 +228,8 @@ def test_kernel_matches_the_oracle(name):
     RING = x["bt"].shape[1] * BS
     nb = -(-T // BS) if T < RING else None
     assert pallas_kv.supports(BS, Hq, Hkv, D, cb, x["k"].dtype)
+    form = pallas_kv.attn_form(Hq, Hkv, cb, x["k"].dtype)
+    assert form == ("heads" if name in ALL_HEADS else "head")
     outs = []
     for layer in (range(L) if "mb" in case else (L - 1,)):
         got = pallas_kv.kv_paged_attention(
@@ -224,6 +275,44 @@ def test_the_chunk_width_follows_the_slot_bytes():
     assert pallas_kv.chunk_slots(16, 32, 32, 128, 4, bf16) == 128
 
 
+# (query heads, KV heads, head size) by (tokens a row a step, dtype): the
+# form ``attn_form`` gives. The benchmark's cells by name, then the corners
+# of the rule.
+bf16, f32 = jnp.bfloat16, jnp.float32
+FORMS = {
+    "starcoderbase-1b": (MQA_1x128, {(1, bf16): "head", (4, bf16): "head"}),
+    "falcon-h1-34b-1chip": (GQA_4x128, {(1, bf16): "head", (4, bf16): "head"}),
+    "qwen3-next-80b-a3b-1chip": (
+        GQA_2x256, {(1, bf16): "head", (8, bf16): "head"}),
+    "olmo-hybrid-7b-1chip": (
+        MHA_32x128, {(1, bf16): "heads", (4, bf16): "heads", (1, f32): "heads",
+                     # eight query rows a head fill a float32 tile
+                     (7, bf16): "head", (8, bf16): "head"}),
+    "gpt-j-6b": ((16, 16, 256), {(1, bf16): "heads", (4, bf16): "heads"}),
+    "mistral-7b": ((32, 8, 128), {(1, bf16): "head", (4, bf16): "head"}),
+    # all heads' rows within 128: 64 x 2, not 64 x 4
+    "mha-64": ((64, 64, 128), {(2, bf16): "heads", (4, bf16): "head"}),
+    # two query heads a KV head: 16 x 2 x 2 rows, each head's 4 under a tile
+    "gqa-32-over-16": ((32, 16, 128), {(2, bf16): "heads", (4, bf16): "head"}),
+    # a slot's heads in whole tiles: 16 of bfloat16, 8 of float32
+    "mha-8": ((8, 8, 128), {(1, bf16): "head", (1, f32): "heads"}),
+    "mha-4": ((4, 4, 128), {(1, bf16): "head", (1, f32): "head"}),
+}
+
+
+@pytest.mark.parametrize("name", FORMS)
+def test_the_form_follows_the_shapes(name):
+    """``heads`` where a KV head's query rows are under a float32 tile's 8
+    sublanes, all heads' rows within 128 and a slot's heads whole tiles of
+    the pool's dtype; ``head`` everywhere else, the cells with grouped or
+    single KV heads among them. Nothing but shapes goes in."""
+    (Hq, Hkv, D), forms = FORMS[name]
+    for (chunk, dtype), form in forms.items():
+        assert pallas_kv.supports(BS, Hq, Hkv, D, chunk, dtype)
+        assert pallas_kv.attn_form(Hq, Hkv, chunk, dtype) == form, (
+            chunk, dtype)
+
+
 @pytest.mark.parametrize(
     "kwargs",
     [
@@ -258,11 +347,16 @@ GQA = DecoderConfig(
 )
 
 
-# the same with one KV head, and under a window shorter than the prompts
+# the same with one KV head, under a window shorter than the prompts, and
+# with a KV head a query head (float32: eight heads are a slot's whole tile,
+# and ``kv.kernel`` works all heads of a chunk in one product)
+MHA = dataclasses.replace(GQA, n_kv_heads=8)
 MODELS = {
     "gqa": GQA,
     "mqa": dataclasses.replace(GQA, n_kv_heads=1),
     "sliding-window": dataclasses.replace(GQA, sliding_window=8),
+    "mha": MHA,
+    "mha-sliding-window": dataclasses.replace(MHA, sliding_window=8),
 }
 
 
@@ -287,10 +381,10 @@ def test_greedy_tokens_equal_under_the_kernel_and_the_gather(
     beside rows that decode, then decode groups alone: the same greedy
     tokens whether the step programs read the pool through ``kv.kernel``
     (forced, interpreted) or the gather, and every group's ``sched.dispatch``
-    span says which."""
+    span says which, and what the kernel does with a chunk (``attn_form``)."""
     prompts = [list(range(2, 22)), [3, 14, 15, 9, 26, 5], [7] * 11]
     gen = GenerationParams(max_new_tokens=6, is_greedy=True)
-    outs, reads = {}, {}
+    outs, reads, forms = {}, {}, {}
     trace.set_enabled(True)
     for impl in ("xla", "pallas"):
         trace.recorder().clear()
@@ -308,20 +402,26 @@ def test_greedy_tokens_equal_under_the_kernel_and_the_gather(
                  if sp[2] == "sched.dispatch"]
         assert {a["kind"] for a in spans} == {"ragged_group", "decode_group"}
         reads[impl] = {a["attn_read"] for a in spans}
+        forms[impl] = {a["attn_form"] for a in spans}
         assert {a["index_read"] for a in spans} == {"none"}  # no indexer
         for a in spans:
             assert 0 <= a["blocks_read"] <= a["blocks_ring"]
     assert reads == {"xla": {"gather"}, "pallas": {"kv.kernel"}}
+    form = "heads" if model.startswith("mha") else "head"
+    assert forms == {"xla": {"none"}, "pallas": {form}}
     assert outs["xla"] == outs["pallas"], outs
 
 
 @pytest.mark.filterwarnings("ignore:pallas forced")
-def test_a_mixed_step_of_one_token_a_row_is_the_decode_step(one_device):
+@pytest.mark.parametrize("model", ["gqa", "mha"])
+def test_a_mixed_step_of_one_token_a_row_is_the_decode_step(one_device, model):
     """Through ``kv.kernel`` (forced, interpreted) a group of mixed steps in
     which every row feeds nothing and decodes one token IS the decode group:
     the same tokens, and both pools equal BIT FOR BIT afterwards (every
     layer's fresh keys and values come out of the read below them), at a
-    chunk budget of one token a row and of four."""
+    chunk budget of one token a row and of four; under either form of the
+    kernel's work on a chunk."""
+    cfg = MODELS[model]
     nB, nc = 4, 5
     prompts = [[5, 9, 23, 40], [3, 14, 15, 9], [7, 7, 7, 7], [1, 2, 3, 4]]
     gen = GenerationParams(max_new_tokens=8, is_greedy=True)
@@ -338,9 +438,11 @@ def test_a_mixed_step_of_one_token_a_row_is_the_decode_step(one_device):
         return tok, cache, lens + 0, sa, jnp.zeros(nB, bool), eos
 
     with attn.force_impl("pallas"):
-        eng = _engine(one_device)
+        eng = _engine(one_device, cfg)
         tok, cache, cur, sa, done, _ = start(eng)
-        assert decoder.attn_read(GQA, cache, one_device, 1) == "kv.kernel"
+        assert decoder.attn_read(cfg, cache, one_device, 1) == "kv.kernel"
+        assert {decoder.attn_form(cfg, cache, one_device, c) for c in (1, 4)} == {
+            "heads" if model == "mha" else "head"}
         packed, _, want, cur_d, _ = eng._decode_group(
             eng.params, tok, cache, cur, sa, done, eos,
             n_chunks=nc, n_steps=1, t_bucket=None,
